@@ -14,12 +14,22 @@ Rebuilt from the reference's TPUAcceleratorManager
     every host in a pod advertises `{pod_name}: 1`; worker 0 additionally
     advertises `TPU-{pod_type}-head: 1`. A job targets the head resource,
     then fans out one whole-host task per worker via the pod-name resource.
+  * one process per chip: libtpu gives a chip to the first process that
+    opens it, and a second one fails or hangs. The raylet spawns every
+    worker with JAX held to the CPU (`hide_chips`), hands chip indices out
+    with a `TPU` grant (`ChipPool`), and the granted worker turns to its
+    chips before its first backend initialisation (`take_chips`).
+
+Nothing here initialises a JAX backend: detection reads device files and
+the environment only, because the process that detects is the driver's.
 """
 
 from __future__ import annotations
 
 import glob
+import math
 import os
+import sys
 from typing import Dict, List, Optional
 
 from ray_tpu._private.accelerators.accelerator import AcceleratorManager
@@ -36,6 +46,16 @@ GKE_TPU_WORKER_HOSTNAMES_ENV = "TPU_WORKER_HOSTNAMES"
 TPU_CHIPS_PER_HOST_BOUNDS = {"v2": 4, "v3": 4, "v4": 4, "v5p": 4, "v5litepod": 8, "v6e": 8}
 
 _VALID_CHIP_COUNTS = (1, 2, 4, 8)
+# libtpu lays a process's chips out on these bounds; a process confined to
+# one or two chips of a larger host needs them to match what it can see
+# (reference tpu.py TPU_CHIPS_PER_HOST_BOUNDS_*_CHIP_CONFIG).
+TPU_CHIPS_PER_HOST_BOUNDS_ENV = "TPU_CHIPS_PER_HOST_BOUNDS"
+TPU_HOST_BOUNDS_ENV = "TPU_HOST_BOUNDS"
+_SUBSET_BOUNDS = {1: "1,1,1", 2: "1,2,1"}
+JAX_PLATFORMS_ENV = "JAX_PLATFORMS"
+# The platform list the node itself runs under, kept in a worker's
+# environment for the day it is granted chips.
+NODE_JAX_PLATFORMS_ENV = "RT_NODE_JAX_PLATFORMS"
 
 
 class TPUAcceleratorManager(AcceleratorManager):
@@ -49,27 +69,15 @@ class TPUAcceleratorManager(AcceleratorManager):
 
     @staticmethod
     def get_current_node_num_accelerators() -> int:
-        """Chip count: explicit env > JAX local devices > device files."""
+        """Chip count: explicit env > device files. One `/dev/vfio/<n>`
+        group per chip beside the `/dev/vfio/vfio` control node (v5e, v5p,
+        v6e), or one `/dev/accel<n>` per chip (v2-v4)."""
         explicit = os.environ.get("RT_TPU_CHIPS")
         if explicit:
             return int(explicit)
-        try:
-            vfio = glob.glob("/dev/vfio/*")
-            accel = glob.glob("/dev/accel*")
-            n = len([p for p in vfio if os.path.basename(p) != "vfio"]) or len(accel)
-            if n:
-                return n
-        except OSError:
-            pass
-        # Last resort: a live jax runtime on a TPU VM.
-        if os.environ.get("RT_DETECT_TPU_VIA_JAX") == "1":
-            try:
-                import jax
-
-                return len([d for d in jax.devices() if d.platform == "tpu"])
-            except Exception:  # noqa: BLE001
-                return 0
-        return 0
+        vfio = [p for p in glob.glob("/dev/vfio/*")
+                if os.path.basename(p) != "vfio"]
+        return len(vfio) or len(glob.glob("/dev/accel*"))
 
     @staticmethod
     def get_current_node_accelerator_type() -> Optional[str]:
@@ -152,18 +160,99 @@ class TPUAcceleratorManager(AcceleratorManager):
         return raw.split(",")
 
     @staticmethod
-    def set_current_process_visible_accelerator_ids(ids: List[str]) -> None:
+    def set_current_process_visible_accelerator_ids(
+        ids: List[str], node_total: Optional[int] = None
+    ) -> None:
         """Confine this process to specific chips.
 
         The all-chips passthrough (reference tpu.py:158): when the process
         takes every chip on the host we *unset* the variable so libtpu owns
-        the full host — the whole-host lease JAX SPMD needs.
+        the full host — the whole-host lease JAX SPMD needs. `node_total`
+        is the count the lease came with (a node started with an explicit
+        `num_tpus` need not match what this process would detect).
         """
-        total = TPUAcceleratorManager.get_current_node_num_accelerators()
+        total = (node_total if node_total is not None
+                 else TPUAcceleratorManager.get_current_node_num_accelerators())
         if total and len(ids) >= total:
             os.environ.pop(TPU_VISIBLE_CHIPS_ENV, None)
             return
         os.environ[TPU_VISIBLE_CHIPS_ENV] = ",".join(str(i) for i in ids)
+        bounds = _SUBSET_BOUNDS.get(len(ids))
+        if bounds:
+            os.environ[TPU_CHIPS_PER_HOST_BOUNDS_ENV] = bounds
+            os.environ[TPU_HOST_BOUNDS_ENV] = "1,1,1"
+
+
+def chips_wanted(resources: Optional[Dict[str, float]]) -> int:
+    """Whole chips a resource request holds: a chip is one process's, so a
+    fraction takes the chip."""
+    return math.ceil((resources or {}).get(TPU_RESOURCE_NAME, 0))
+
+
+class ChipPool:
+    """The chip indices of one node. The raylet takes them with a `TPU`
+    grant and gives them back when the worker that held them is gone."""
+
+    def __init__(self, total: int):
+        self.total = total
+        self._free = list(range(total))
+
+    @property
+    def free(self) -> int:
+        return len(self._free)
+
+    def take(self, n: int) -> List[int]:
+        if n > len(self._free):
+            raise ValueError(
+                f"{n} chip(s) wanted, {len(self._free)} of {self.total} free"
+            )
+        taken, self._free = self._free[:n], self._free[n:]
+        return taken
+
+    def give_back(self, chips: List[int]) -> None:
+        self._free = sorted(set(self._free) | set(chips))
+
+    def lease(self, chips: List[int]) -> Dict[str, object]:
+        """What the granted worker is sent (see take_chips)."""
+        return {"chips": chips, "node_chips": self.total}
+
+
+def hide_chips(env: Dict[str, str]) -> None:
+    """Spawn environment of a worker: JAX held to the CPU, so that a
+    worker that was granted no chip (a serve proxy importing the engine,
+    a data task, an env runner) cannot open one first."""
+    env[NODE_JAX_PLATFORMS_ENV] = env.get(JAX_PLATFORMS_ENV, "")
+    env[JAX_PLATFORMS_ENV] = "cpu"
+
+
+def take_chips(lease: Dict[str, object]) -> None:
+    """In the worker a `TPU` grant reached: turn this process to its
+    chips. Runs before the task or the actor's constructor, hence before
+    user code can initialise a backend; a process that already has one
+    cannot switch, which is why the raylet grants chips to fresh workers
+    only."""
+    platforms = os.environ.get(NODE_JAX_PLATFORMS_ENV, "")
+    jax = sys.modules.get("jax")
+    if jax is not None:
+        from jax._src import xla_bridge
+
+        if xla_bridge.backends_are_initialized():
+            raise RuntimeError(
+                "this worker initialised a JAX backend before it was "
+                f"granted chips {lease['chips']}; it cannot take them"
+            )
+        jax.config.update("jax_platforms", platforms or None)
+    if platforms:
+        os.environ[JAX_PLATFORMS_ENV] = platforms
+    else:
+        os.environ.pop(JAX_PLATFORMS_ENV, None)
+    TPUAcceleratorManager.set_current_process_visible_accelerator_ids(
+        [str(c) for c in lease["chips"]], lease["node_chips"]
+    )
+    if platforms != "cpu":  # a node held to the CPU has no chip to compile for
+        from ray_tpu.util.compile_cache import place_compile_cache
+
+        place_compile_cache()
 
 
 def get_current_pod_name() -> Optional[str]:

@@ -200,6 +200,7 @@ class GcsServer:
         # actors
         r("register_actor", self.h_register_actor)
         r("actor_ready", self.h_actor_ready)
+        r("actor_unplaceable", self.h_actor_unplaceable)
         r("get_actor", self.h_get_actor)
         r("get_named_actor", self.h_get_named_actor)
         r("list_actors", self.h_list_actors)

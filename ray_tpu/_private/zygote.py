@@ -14,12 +14,13 @@ Protocol — line-delimited JSON over the zygote's stdin/stdout:
     <- {"op": "dead", "pid": N, "rc": N} # interleaved as children reap
 
 Fork-safety rules: the zygote is strictly single-threaded, runs no event
-loop, and never imports jax (workers attach the TPU backend lazily — see
-worker_main.ensure_tpu_backend). stdin is consumed with raw os.read into
-an explicit line buffer — buffered TextIO.readline over a selector
-silently strands any second line that arrived in the same pipe read.
-Children are reaped with waitpid(WNOHANG) between protocol reads (<=1s
-select timeout) and death notices stream to the raylet, which owns
+loop, and never imports jax (a child granted chips must reach them before
+its first backend initialisation — see accelerators.tpu.take_chips).
+stdin is consumed with raw os.read into an explicit line buffer —
+buffered TextIO.readline over a selector silently strands any second
+line that arrived in the same pipe read.
+Children are reaped with waitpid(WNOHANG) between protocol reads (<=0.2s
+select timeout: a raylet that stops waits for these notices) and death notices stream to the raylet, which owns
 worker-failure handling.
 """
 
@@ -141,7 +142,7 @@ def main() -> None:
     sel.register(fd, selectors.EVENT_READ)
     buf = b""
     while True:
-        events = sel.select(timeout=1.0)
+        events = sel.select(timeout=0.2)
         _reap()
         if not events:
             continue

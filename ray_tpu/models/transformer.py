@@ -24,6 +24,7 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import apply_rope, flash_attention, rmsnorm, rope_frequencies, softmax_cross_entropy
 
@@ -88,6 +89,12 @@ class TransformerConfig:
         return self.custom_head_dim or self.d_model // self.n_heads
 
 
+# Where parallel.mesh.DEFAULT_RULES put activations, for the kernels that
+# run on each device's block (ops.per_shard).
+_ACT_SPEC = P(("dp", "fsdp"), "sp", None)          # [B, L, D]
+_HEADS_SPEC = P(("dp", "fsdp"), None, "tp", None)  # [B, L, H, head_dim]
+
+
 def _dense_init(key, shape, scale, dtype):
     return (jax.random.normal(key, shape, dtype=jnp.float32) * scale).astype(dtype)
 
@@ -135,8 +142,12 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
                 "w_down": stack(sub[2], (E, ff, d), scale * (2 * L) ** -0.5),
             }
         )
+    # A tied table is also the output head, so it takes the head's scale:
+    # at unit scale every token predicts itself with a logit of about d
+    # (a first loss of 1,887 at Qwen3-4B widths, where ln(vocab) is 11.9).
     params = {
-        "embed": _dense_init(keys[8], (cfg.vocab_size, d), 1.0, cfg.dtype),
+        "embed": _dense_init(keys[8], (cfg.vocab_size, d),
+                             scale if cfg.tie_embeddings else 1.0, cfg.dtype),
         "layers": layer,
         "final_norm": jnp.ones((d,), dtype=cfg.dtype),
     }
@@ -224,20 +235,19 @@ def project_logits(x, params, cfg: TransformerConfig):
 def _attention(cfg: TransformerConfig, q, k, v, mesh, positions):
     if cfg.attn_impl == "ring" and mesh is not None and mesh.shape.get("sp", 1) > 1:
         from ray_tpu.parallel.ring_attention import ring_attention
-        from jax.sharding import PartitionSpec as P
 
         spec = P(("dp", "fsdp"), "sp", "tp", None)
         return ring_attention(q, k, v, mesh, axis_name="sp", causal=True,
                               query_spec=spec)
     if cfg.attn_impl == "ulysses" and mesh is not None and mesh.shape.get("sp", 1) > 1:
         from ray_tpu.parallel.ulysses import ulysses_attention
-        from jax.sharding import PartitionSpec as P
 
         spec = P(("dp", "fsdp"), "sp", "tp", None)
         return ulysses_attention(q, k, v, mesh, axis_name="sp", causal=True,
                                  query_spec=spec)
     return flash_attention(q, k, v, causal=True,
-                           block_q=cfg.attn_block_q, block_k=cfg.attn_block_k)
+                           block_q=cfg.attn_block_q, block_k=cfg.attn_block_k,
+                           mesh=mesh, spec=_HEADS_SPEC)
 
 
 def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
@@ -245,7 +255,8 @@ def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
 
     def body(x, lp):
         # x: [B, L, D]
-        h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+        h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh,
+                    spec=_ACT_SPEC)
         b, l, d = h.shape
         q = (h @ lp["wq"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
         k = (h @ lp["wk"]).reshape(b, l, cfg.n_kv_heads, cfg.head_dim)
@@ -260,7 +271,8 @@ def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
         attn = _attention(cfg, q, k, v, mesh, positions)
         x = x + (attn.reshape(b, l, -1) @ lp["wo"]).astype(x.dtype)
 
-        h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+        h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh,
+                    spec=_ACT_SPEC)
         act = _act(cfg)
         if cfg.num_experts == 0:
             gate = act((h @ lp["w_gate"]).astype(jnp.float32))
@@ -322,7 +334,8 @@ def forward(
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
     body = _layer_fn(cfg, mesh, cos, sin, positions)
     x, auxes = jax.lax.scan(body, x, params["layers"])
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh,
+                spec=_ACT_SPEC)
     if return_hidden:
         return x, auxes.sum()
     return project_logits(x, params, cfg), auxes.sum()
@@ -389,8 +402,6 @@ def forward_pipelined(
     # pp composes with data parallelism: each microbatch's batch dim
     # splits over dp/fsdp inside the pipeline shard_map, so a dp×pp mesh
     # runs dp-many replicas of every pipeline stage.
-    from jax.sharding import PartitionSpec as P
-
     dp_axes = tuple(
         a for a in ("dp", "fsdp") if mesh.shape.get(a, 1) > 1
     )
@@ -399,7 +410,8 @@ def forward_pipelined(
         stage_fn, params["layers"], xm, mesh, axis_name="pp", x_spec=x_spec
     )
     x = ym.reshape(b, l, x.shape[-1])
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh,
+                spec=_ACT_SPEC)
     return project_logits(x, params, cfg), jnp.zeros((), dtype=jnp.float32)
 
 

@@ -30,15 +30,11 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.ops.per_shard import per_shard
 
 NEG_INF = -1e30
-
-
-def _on_tpu() -> bool:
-    try:
-        return jax.devices()[0].platform == "tpu"
-    except Exception:  # noqa: BLE001
-        return False
 
 
 def _pad_to(x, length, axis):
@@ -402,21 +398,29 @@ def flash_attention(
     block_k: int = 256,
     use_pallas: Optional[bool] = None,
     interpret: bool = False,
+    mesh=None,
+    spec: P = P(),
 ):
     """Fused attention. q,k,v: [batch, seq, heads, head_dim].
 
     GQA/MQA: if k/v have fewer heads than q, they are broadcast per group
     (the repeat happens outside the kernel, so its VJP sums the per-group
     gradients back onto the shared kv heads).
+
+    mesh, spec: under a sharded jit, the mesh and the PartitionSpec q, k
+    and v share (batch and heads may be sharded, sequence and head_dim
+    not); the kernels then run on each device's block.
     """
     if k.shape[2] != q.shape[2]:
         group = q.shape[2] // k.shape[2]
         k = jnp.repeat(k, group, axis=2)
         v = jnp.repeat(v, group, axis=2)
     if use_pallas is None:
-        use_pallas = _on_tpu()
-    if use_pallas or interpret:
-        return _flash_attention_pallas(
-            q, k, v, causal, block_q, block_k, interpret=interpret
-        )
-    return _flash_attention_xla(q, k, v, causal)
+        use_pallas = jax.default_backend() == "tpu"
+    if not (use_pallas or interpret):
+        return _flash_attention_xla(q, k, v, causal)
+    kernel = functools.partial(
+        _flash_attention_pallas, causal=causal, block_q=block_q,
+        block_k=block_k, interpret=interpret,
+    )
+    return per_shard(kernel, mesh, (spec, spec, spec), spec)(q, k, v)

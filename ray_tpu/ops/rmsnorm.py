@@ -12,6 +12,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec as P
+
+from ray_tpu.ops.per_shard import per_shard
 
 
 def _rmsnorm_ref(x, w, eps):
@@ -153,10 +156,33 @@ def _pallas_core_bwd(eps, block_rows, interpret, res, g):
 _rmsnorm_pallas_core.defvjp(_pallas_core_fwd, _pallas_core_bwd)
 
 
-def _rmsnorm_pallas(x, w, eps, block_rows: int = 256, interpret: bool = False):
+# A TPU kernel's blocks and temporaries live in scoped VMEM, 16 MiB by
+# default on v5e ("Scoped allocation ... limit 16.00M" is the compiler's
+# refusal). Half of it is budgeted for what _block_rows_for counts; the
+# rest is headroom for what Mosaic adds.
+_VMEM_BUDGET = 8 << 20
+_MAX_BLOCK_ROWS = 256
+
+
+def _block_rows_for(d: int, dtype) -> int:
+    """Rows per block such that the backward kernel, the larger of the
+    two, fits the budget: x, g and dx blocks, double-buffered by the
+    pipeline, plus three float32 temporaries of the block's shape. The
+    forward uses the same block so that one number is tested."""
+    itemsize = jnp.dtype(dtype).itemsize
+    sublane = 8 * (4 // itemsize)  # packed rows per (8, 128) tile
+    per_row = d * (3 * 2 * itemsize + 3 * 4)
+    rows = _VMEM_BUDGET // per_row // sublane * sublane
+    return max(sublane, min(_MAX_BLOCK_ROWS, rows))
+
+
+def _rmsnorm_pallas(x, w, eps, block_rows: Optional[int] = None,
+                    interpret: bool = False):
     orig_shape = x.shape
     d = x.shape[-1]
     rows = int(np_prod(orig_shape[:-1]))  # rtlint: disable=RT001 — static shape math: fine at trace time
+    if block_rows is None:
+        block_rows = _block_rows_for(d, x.dtype)
     out = _rmsnorm_pallas_core(x.reshape(rows, d), w, eps, block_rows, interpret)
     return out.reshape(orig_shape)
 
@@ -169,13 +195,15 @@ def np_prod(shape):
 
 
 def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-6,
-            use_pallas: Optional[bool] = None, interpret: bool = False):
-    """RMS normalization over the last axis, scaled by w."""
+            use_pallas: Optional[bool] = None, interpret: bool = False,
+            mesh=None, spec: P = P()):
+    """RMS normalization over the last axis, scaled by w.
+
+    mesh, spec: under a sharded jit, the mesh and x's PartitionSpec (last
+    axis unsharded); the kernel then runs on each device's rows."""
     if use_pallas is None:
-        try:
-            use_pallas = jax.devices()[0].platform == "tpu"
-        except Exception:  # noqa: BLE001  # rtlint: disable=RT007 — backend probe: no TPU visible means fall back to XLA path
-            use_pallas = False
-    if (use_pallas or interpret):
-        return _rmsnorm_pallas(x, w, eps, interpret=interpret)
-    return _rmsnorm_xla(x, w, eps)
+        use_pallas = jax.default_backend() == "tpu"
+    if not (use_pallas or interpret):
+        return _rmsnorm_xla(x, w, eps)
+    kernel = functools.partial(_rmsnorm_pallas, eps=eps, interpret=interpret)
+    return per_shard(kernel, mesh, (spec, P()), spec)(x, w)

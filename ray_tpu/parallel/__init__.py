@@ -13,6 +13,7 @@ PP, and capacity-based top-k routing for EP.
 from ray_tpu.parallel.mesh import (
     MeshConfig,
     build_mesh,
+    logical_shardings,
     logical_to_physical,
     shard_params,
     with_sharding_constraint,
@@ -25,6 +26,7 @@ from ray_tpu.parallel.moe import moe_layer, top_k_routing
 __all__ = [
     "MeshConfig",
     "build_mesh",
+    "logical_shardings",
     "logical_to_physical",
     "shard_params",
     "with_sharding_constraint",

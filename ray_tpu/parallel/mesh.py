@@ -132,6 +132,19 @@ def logical_to_physical(logical_axes: Sequence[Optional[str]],
     return P(*spec)
 
 
+def logical_shardings(logical_axes_tree, mesh: Mesh, rules=None):
+    """A NamedSharding on `mesh` for every leaf of a logical axes tree
+    (tuples of logical names, or None for replicated): what `shard_params`
+    places by, and what `jax.jit(init, out_shardings=...)` takes to build
+    parameters where they live."""
+    def to_sharding(axes):
+        spec = P() if axes is None else logical_to_physical(axes, rules)
+        return NamedSharding(mesh, spec)
+
+    return jax.tree.map(to_sharding, logical_axes_tree,
+                        is_leaf=lambda x: x is None or isinstance(x, tuple))
+
+
 def shard_params(params, logical_axes_tree, mesh: Mesh, rules=None):
     """Device-put a parameter pytree according to its logical axes tree.
 
@@ -139,15 +152,8 @@ def shard_params(params, logical_axes_tree, mesh: Mesh, rules=None):
     None for replicated). This is the explicit analog of flax's
     `nn.with_partitioning` + `logical_to_mesh`.
     """
-    def place(leaf, axes):
-        if axes is None:
-            sharding = NamedSharding(mesh, P())
-        else:
-            sharding = NamedSharding(mesh, logical_to_physical(axes, rules))
-        return jax.device_put(leaf, sharding)
-
-    return jax.tree.map(place, params, logical_axes_tree,
-                        is_leaf=lambda x: x is None)
+    return jax.device_put(
+        params, logical_shardings(logical_axes_tree, mesh, rules))
 
 
 def with_sharding_constraint(x, logical_axes, mesh: Optional[Mesh] = None,

@@ -20,7 +20,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from ray_tpu._private.jax_compat import shard_map
+from jax import shard_map
 
 
 def pipeline_stages(

@@ -10,10 +10,12 @@ TPU-aware replica placement comes from ray_actor_options resources (e.g.
 from __future__ import annotations
 
 import logging
+import time
 from typing import Optional
 
 import ray_tpu as rt
 from ray_tpu._private.config import get_config
+from ray_tpu.exceptions import ActorUnavailableError
 from ray_tpu.serve.controller import CONTROLLER_NAME, get_or_create_controller
 from ray_tpu.serve.deployment import (
     Application,
@@ -79,15 +81,36 @@ def _run_app(app: Application, name: Optional[str], controller,
     init_args = tuple(resolve(a) for a in app.init_args)
     init_kwargs = {k: resolve(v) for k, v in app.init_kwargs.items()}
     _reject_buried_applications((init_args, init_kwargs), app_name)
+    deadline = time.monotonic() + get_config().serve_deploy_timeout_s
     rt.get(
         controller.deploy.remote(
             app_name, app.deployment, init_args, init_kwargs
         ),
         timeout=get_config().serve_deploy_timeout_s,
     )
+    _wait_constructed(controller, app_name, deadline)
     handle = DeploymentHandle(app_name)
     resolved[id(app)] = handle
     return handle
+
+
+def _wait_constructed(controller, app_name: str, deadline: float):
+    """The controller returns once replica actors are requested; a
+    replica that loads a model and compiles its programs then constructs
+    for minutes, and a request sent meanwhile fails as unavailable. So
+    serve.run returns when every replica has answered once. A
+    constructor that raised surfaces here as the actor's death."""
+    replicas = rt.get(controller.get_replicas.remote(app_name),
+                      timeout=get_config().serve_admin_timeout_s)["replicas"]
+    for replica in replicas:
+        while True:
+            try:
+                rt.get(replica.health_check.remote(),
+                       timeout=max(0.1, deadline - time.monotonic()))
+                break
+            except ActorUnavailableError:  # still constructing
+                if time.monotonic() >= deadline:
+                    raise
 
 
 def _reject_buried_applications(obj, app_name: str, _seen=None, _depth=0):

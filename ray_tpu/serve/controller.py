@@ -923,10 +923,18 @@ class ServeController:
                         get_config().serve_health_fail_threshold,
                         exc_info=True,
                     )
-            elif self._actor_state(key) == "DEAD":
-                # Probe never completed AND the GCS already declared the
-                # actor dead (its worker lost the raylet connection).
-                actor_dead = True
+            else:
+                state = self._actor_state(key)
+                if state == "DEAD":
+                    # Probe never completed AND the GCS already declared
+                    # the actor dead (its worker lost the raylet
+                    # connection).
+                    actor_dead = True
+                elif state in ("PENDING", "RESTARTING"):
+                    # Still constructing: a replica that loads a model
+                    # and compiles its programs takes minutes, and a
+                    # constructor that fails arrives as DEAD.
+                    continue
             if healthy:
                 self._health_fails.pop(key, None)
                 continue

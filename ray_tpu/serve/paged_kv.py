@@ -287,7 +287,9 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
     if mesh is not None:
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        kv_sharding = NamedSharding(mesh, P(None, None, None, "tp", None))
+        # No trailing None: the spec a step's output comes back with, so
+        # that the first call and every later one are one jit cache entry.
+        kv_sharding = NamedSharding(mesh, P(None, None, None, "tp"))
         rep = NamedSharding(mesh, P())
         cache = {
             "k": jax.device_put(cache["k"], kv_sharding),
@@ -300,7 +302,7 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
 
 def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
                  block_tables, temps, top_ks, top_ps, key,
-                 cfg: TransformerConfig, max_len: int):
+                 cfg: TransformerConfig, max_len: int, mesh=None):
     """One decode step for every slot, K/V gathered through the block
     table — the paged twin of llm._decode_slots (same contract: same
     inputs plus the table, same outputs).
@@ -347,14 +349,14 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
         lp, k_cache_l, v_cache_l = inputs
         x, k_cache_l, v_cache_l = _layer_body(
             x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
-            write_kv, valid,
+            write_kv, valid, mesh,
         )
         return x, (k_cache_l, v_cache_l)
 
     x, (k_new, v_new) = jax.lax.scan(
         layer, x, (params["layers"], k_pages, v_pages)
     )
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
     logits = project_logits(x[:, -1], params, cfg)
     new_lengths = jnp.where(active, lengths + 1, lengths)
     if temps is None:
@@ -366,7 +368,7 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
 
 def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
                         v_pages, lengths, block_tables,
-                        cfg: TransformerConfig, max_len: int):
+                        cfg: TransformerConfig, max_len: int, mesh=None):
     """Chunked prefill into pages — the paged twin of
     llm._prefill_chunk. Chunk rows scatter into the pages the slot's
     block-table row names (padding rows and anything past `max_len`
@@ -408,14 +410,14 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
         lp, k_cache_l, v_cache_l = inputs
         x, k_cache_l, v_cache_l = _layer_body(
             x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
-            write_kv, valid,
+            write_kv, valid, mesh,
         )
         return x, (k_cache_l, v_cache_l)
 
     x, (k_new, v_new) = jax.lax.scan(
         layer, x, (params["layers"], k_pages, v_pages)
     )
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
+    x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
     last = jax.lax.dynamic_slice(x, (0, n_valid - 1, 0), (1, 1, x.shape[-1]))
     logits = project_logits(last[:, 0], params, cfg)
     new_lengths = lengths.at[slot].set(offset + n_valid)

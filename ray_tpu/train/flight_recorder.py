@@ -43,7 +43,6 @@ land in "other_s", so the breakdown always sums to the step wall time.
 from __future__ import annotations
 
 import collections
-import os
 import threading
 import time
 from typing import Any, Dict, List, Optional, Sequence
@@ -54,19 +53,6 @@ from ray_tpu.util import journal
 #: Phase keys every record carries (plus "other_s" for the remainder).
 PHASES = ("data", "compute", "collective", "checkpoint")
 
-# Dense peak-flops table (bf16, per chip) for the MFU estimate; matched
-# by substring against jax's device_kind. Overridable (and extendable to
-# unlisted hardware) via RT_PEAK_FLOPS_PER_S.
-_PEAK_FLOPS_BY_KIND = (
-    ("v6e", 918e12),
-    ("v5p", 459e12),
-    ("v5e", 197e12),
-    ("v5litepod", 197e12),
-    ("v4", 275e12),
-    ("v3", 123e12),
-    ("v2", 45e12),
-)
-
 _tls = threading.local()  # .step = the thread's in-flight _StepHandle
 
 _metrics_lock = threading.Lock()
@@ -75,24 +61,14 @@ _collective_hooked = False
 
 
 def peak_flops_per_s() -> Optional[float]:
-    """Per-device peak flops/s for MFU: RT_PEAK_FLOPS_PER_S env override,
-    else the device-kind table; None when unknown (CPU test meshes)."""
-    env = os.environ.get("RT_PEAK_FLOPS_PER_S")
-    if env:
-        try:
-            return float(env)
-        except ValueError:
-            pass
-    try:
-        import jax
+    """This process's per-device peak flops/s for MFU, from the one table
+    (util.device_peaks): None on a CPU test mesh, an error on a TPU the
+    table does not know."""
+    import jax
 
-        kind = jax.devices()[0].device_kind.lower()
-    except Exception:  # rtlint: disable=RT007 — no backend: no MFU
-        return None
-    for sub, flops in _PEAK_FLOPS_BY_KIND:
-        if sub in kind:
-            return flops
-    return None
+    from ray_tpu.util import device_peaks
+
+    return device_peaks.peak_flops_per_s(jax.devices()[0])
 
 
 def _recorder_metrics() -> Dict:
@@ -238,7 +214,8 @@ class StepProfiler:
     ring: records kept in memory (old steps fall off — flight-recorder
       discipline: always on, bounded, overwrite-oldest).
     flops_per_step / peak_flops: MFU estimate inputs; peak defaults to
-      the device table (RT_PEAK_FLOPS_PER_S override). No flops → no MFU.
+      the device table (util.device_peaks). No flops → no MFU, and the
+      device is not asked.
     rank: tag for the exported metrics; defaults to the active train
       session's world rank (standalone use: pass explicitly).
     emit_metrics: also observe per-step aggregates into rank-tagged
@@ -261,7 +238,9 @@ class StepProfiler:
         self._pending: "collections.deque" = collections.deque(maxlen=ring)
         self._lock = threading.Lock()
         self._flops_per_step = flops_per_step
-        self._peak_flops = peak_flops or peak_flops_per_s()
+        self._peak_flops = peak_flops or (
+            peak_flops_per_s() if flops_per_step else None
+        )
         self._emit = emit_metrics
         self._watched: List[Any] = []
         self._last_compiles = 0

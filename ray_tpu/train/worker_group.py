@@ -209,15 +209,12 @@ class WorkerGroup:
                 f"worker group placement group not ready within 120s "
                 f"(bundles={bundles}, strategy={placement_strategy})"
             )
+        # A worker takes its bundle's chips by name: the raylet binds
+        # chip indices to the process that is granted `TPU`, and a worker
+        # that asked for none stays on the CPU.
+        self._worker_tpus = resources_per_worker.get("TPU")
         self.workers = [
-            TrainWorker.options(
-                num_cpus=0,
-                scheduling_strategy=PlacementGroupSchedulingStrategy(
-                    placement_group=self._pg,
-                    placement_group_bundle_index=i,
-                ),
-            ).remote(i, num_workers)
-            for i in range(num_workers)
+            self._spawn_worker(i, i, num_workers) for i in range(num_workers)
         ]
         # Elastic resize bookkeeping: rank i lives in bundle
         # bundle_for_rank[i] (identity at birth; shrink/grow make it
@@ -225,6 +222,16 @@ class WorkerGroup:
         # the freed indices).
         self.bundle_for_rank: List[int] = list(range(num_workers))
         self._released_bundles: List[int] = []
+
+    def _spawn_worker(self, rank: int, bundle_index: int, world_size: int):
+        return TrainWorker.options(
+            num_cpus=0,
+            num_tpus=self._worker_tpus,
+            scheduling_strategy=PlacementGroupSchedulingStrategy(
+                placement_group=self._pg,
+                placement_group_bundle_index=bundle_index,
+            ),
+        ).remote(rank, world_size)
 
     def __len__(self):
         return self.num_workers
@@ -299,13 +306,7 @@ class WorkerGroup:
         for j, bundle_index in enumerate(indices):
             rank = self.num_workers + j
             self.workers.append(
-                TrainWorker.options(
-                    num_cpus=0,
-                    scheduling_strategy=PlacementGroupSchedulingStrategy(
-                        placement_group=self._pg,
-                        placement_group_bundle_index=bundle_index,
-                    ),
-                ).remote(rank, target)
+                self._spawn_worker(rank, bundle_index, target)
             )
             self.bundle_for_rank.append(bundle_index)
             new_ranks.append(rank)

@@ -119,36 +119,17 @@ class Topology:
 
     # -- construction ----------------------------------------------------
     @classmethod
-    def detect(cls, n_procs: int, n_local: Optional[int] = None) -> "Topology":
+    def detect(cls, n_procs: int, n_local: int) -> "Topology":
         """Build the topology at group creation: DCN width from the
-        gang's world size, local width from TPU accelerator metadata
-        (chip count) falling back to jax's local device count (the
-        virtual CPU mesh in tests), falling back to flat."""
-        if n_local is None:
-            n_local = cls._detect_n_local()
+        gang's world size, local width from the group that knows it (the
+        ICI tier's own world size; 1 for a flat DCN ring). Nothing here
+        asks JAX: the caller may be a process that holds no chip."""
         return cls(
             n_procs=max(1, int(n_procs)),
             n_local=max(1, int(n_local)),
             ici=ici_tier(),
             dcn=dcn_tier(),
         )
-
-    @staticmethod
-    def _detect_n_local() -> int:
-        try:
-            from ray_tpu._private.accelerators.tpu import TPUAcceleratorManager
-
-            chips = TPUAcceleratorManager.get_current_node_num_accelerators()
-            if chips:
-                return int(chips)
-        except Exception:  # rtlint: disable=RT007 — metadata probe only
-            pass
-        try:
-            import jax
-
-            return len(jax.local_devices())
-        except Exception:  # rtlint: disable=RT007 — no backend: flat topo
-            return 1
 
     # -- cost model ------------------------------------------------------
     def cost_ring_allreduce(self, nbytes: float, n: Optional[int] = None,

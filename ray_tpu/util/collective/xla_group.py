@@ -67,7 +67,7 @@ class XlaLocalGroup:
         import jax.numpy as jnp
         from jax.sharding import NamedSharding, PartitionSpec as P
 
-        from ray_tpu._private.jax_compat import shard_map
+        from jax import shard_map
 
         reducer = {
             ReduceOp.SUM: jax.lax.psum,

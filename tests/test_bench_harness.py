@@ -1,65 +1,4 @@
-"""Bench supervisor harness tests (no TPU, no jax): JSON-line parsing
-and the cached live-TPU artifact gate (bench.py phases)."""
-
-import json
-import time
-
-import bench
-
-
-def test_last_json_line_parses_tail():
-    text = "noise\n{broken\n" + json.dumps({"a": 1}) + "\n[bench] done\n"
-    assert bench._last_json_line(text) == {"a": 1}
-    assert bench._last_json_line("no json here") is None
-
-
-def _write_live(tmp_path, device="TPU_0(process=0)", age_s=60.0,
-                measured_at=None):
-    p = tmp_path / "BENCH_TPU_LIVE.json"
-    stamp = measured_at or time.strftime(
-        "%Y-%m-%dT%H:%M:%SZ", time.gmtime(time.time() - age_s)
-    )
-    p.write_text(json.dumps({
-        "metric": "llama2(0.8B) train-step tokens/s/chip",
-        "value": 12345.0,
-        "vs_baseline": 1.5,
-        "device": device,
-        "measured_at": stamp,
-    }))
-    return str(p)
-
-
-def test_live_artifact_fresh_tpu_is_labeled_cached(tmp_path):
-    path = _write_live(tmp_path, age_s=3600)
-    live = bench.load_live_artifact(path, max_age=14 * 3600)
-    assert live is not None
-    assert live["cached"] is True
-    assert "tpu_live.py" in live["cache_note"]
-    assert live["value"] == 12345.0
-
-
-def test_live_artifact_stale_is_rejected(tmp_path):
-    """An artifact older than the round window (e.g. committed last
-    round) must never be replayed as this round's number."""
-    path = _write_live(tmp_path, age_s=20 * 3600)
-    assert bench.load_live_artifact(path, max_age=14 * 3600) is None
-    # Future timestamps (clock skew) are rejected too.
-    path = _write_live(tmp_path, age_s=-3600)
-    assert bench.load_live_artifact(path, max_age=14 * 3600) is None
-
-
-def test_live_artifact_non_tpu_is_rejected(tmp_path):
-    path = _write_live(tmp_path, device="TFRT_CPU_0")
-    assert bench.load_live_artifact(path) is None
-
-
-def test_live_artifact_garbage_is_rejected(tmp_path):
-    p = tmp_path / "BENCH_TPU_LIVE.json"
-    p.write_text("{not json")
-    assert bench.load_live_artifact(str(p)) is None
-    p.write_text(json.dumps({"device": "TPU_0"}))  # no timestamp
-    assert bench.load_live_artifact(str(p)) is None
-    assert bench.load_live_artifact(str(tmp_path / "missing.json")) is None
+"""Documents against artifacts: every quoted number matches its record."""
 
 
 def test_doc_claims_match_artifacts():

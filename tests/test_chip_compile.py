@@ -1,0 +1,140 @@
+"""The Pallas kernels of the main path, compiled for a TPU v5e that is
+described and not attached (the `on-chip-measurement` guide, section 2).
+
+Interpret mode (tests/test_ops.py) checks a kernel's arithmetic; it
+cannot see what the chip's compiler refuses: a block that overflows
+scoped VMEM, a slice off the tiling, a custom call under a sharded jit.
+Nothing runs here, so these say nothing about results or speed.
+"""
+
+import os
+
+os.environ.setdefault("TPU_LOG_DIR", "disabled")
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
+
+from ray_tpu.models import configs
+from ray_tpu.ops.flash_attention import flash_attention
+from ray_tpu.ops.rmsnorm import rmsnorm
+
+QWEN = configs.get_config("qwen3-4b")
+# Every width a production-scale configuration trains at.
+WIDTHS = sorted({c.d_model for c in configs.NAMED_CONFIGS.values()
+                 if c.d_model >= 2048})
+TRAIN_ROWS = 8 * 1024
+
+
+@pytest.fixture(scope="module")
+def v5e():
+    """The four devices of a described v5e 2x2 host, with the persistent
+    compile cache off: an entry compiled for a described chip is written
+    but cannot be read back without one, and the next compile warns."""
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — no TPU compiler in this install
+        pytest.skip(f"cannot describe a v5e topology: {e}")
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    compilation_cache.reset_cache()
+    yield topo.devices
+    jax.config.update("jax_enable_compilation_cache", was)
+    compilation_cache.reset_cache()
+
+
+def _compile(fn, *args):
+    return jax.jit(fn).lower(*args).compile()
+
+
+def _kernel_count(compiled) -> int:
+    return compiled.as_text().count("tpu_custom_call")
+
+
+@pytest.mark.parametrize("batch,seq", [(8, 1024), (2, 4096)])
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+def test_flash_attention_compiles_at_qwen3_shapes(v5e, batch, seq, backward):
+    one = SingleDeviceSharding(v5e[0])
+    q = jax.ShapeDtypeStruct((batch, seq, QWEN.n_heads, QWEN.head_dim),
+                             jnp.bfloat16, sharding=one)
+    kv = jax.ShapeDtypeStruct((batch, seq, QWEN.n_kv_heads, QWEN.head_dim),
+                              jnp.bfloat16, sharding=one)
+
+    def attend(q, k, v):
+        return flash_attention(q, k, v, block_q=QWEN.attn_block_q,
+                               block_k=QWEN.attn_block_k, use_pallas=True)
+
+    def loss(q, k, v):
+        return attend(q, k, v).astype(jnp.float32).sum()
+
+    fn = jax.grad(loss, argnums=(0, 1, 2)) if backward else attend
+    assert _kernel_count(_compile(fn, q, kv, kv)) >= (3 if backward else 1)
+
+
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("d_model", WIDTHS)
+def test_rmsnorm_compiles_at_every_width(v5e, d_model, dtype):
+    """Forward and backward at a train step's row count. Width 4096 and
+    up overflowed scoped VMEM while the row block was a constant 256."""
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((TRAIN_ROWS, d_model), dtype, sharding=one)
+    w = jax.ShapeDtypeStruct((d_model,), dtype, sharding=one)
+
+    def loss(x, w):
+        return rmsnorm(x, w, use_pallas=True).astype(jnp.float32).sum()
+
+    fn = jax.value_and_grad(loss, argnums=(0, 1))
+    assert _kernel_count(_compile(fn, x, w)) == 2
+
+
+@pytest.mark.parametrize("rows", [4, 64])
+def test_rmsnorm_compiles_at_engine_rows(v5e, rows):
+    """The engine norms one row per decode slot and one prefill chunk."""
+    one = SingleDeviceSharding(v5e[0])
+    x = jax.ShapeDtypeStruct((rows, 1, QWEN.d_model), jnp.bfloat16,
+                             sharding=one)
+    w = jax.ShapeDtypeStruct((QWEN.d_model,), jnp.bfloat16, sharding=one)
+    fn = lambda x, w: rmsnorm(x, w, use_pallas=True)  # noqa: E731
+    assert _kernel_count(_compile(fn, x, w)) == 1
+
+
+def test_kernels_compile_under_a_sharded_jit(v5e):
+    """GSPMD cannot partition a Mosaic kernel; given the mesh, the ops
+    run it on each device's block, and the gradient of the replicated
+    scale comes back through a collective."""
+    from ray_tpu.models.transformer import _ACT_SPEC, _HEADS_SPEC
+    from ray_tpu.parallel import MeshConfig, build_mesh
+
+    mesh = build_mesh(MeshConfig(fsdp=2, tp=2), v5e)
+
+    def struct(shape, spec):
+        return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
+                                    sharding=NamedSharding(mesh, spec))
+
+    x = struct((8, 1024, QWEN.d_model), _ACT_SPEC)
+    w = struct((QWEN.d_model,), P())
+    q = struct((8, 1024, QWEN.n_heads, QWEN.head_dim), _HEADS_SPEC)
+    kv = struct((8, 1024, QWEN.n_kv_heads, QWEN.head_dim), _HEADS_SPEC)
+
+    def loss(x, w, q, k, v):
+        h = rmsnorm(x, w, use_pallas=True, mesh=mesh, spec=_ACT_SPEC)
+        a = flash_attention(q, k, v, use_pallas=True, mesh=mesh,
+                            spec=_HEADS_SPEC)
+        return (h.astype(jnp.float32).sum() + a.astype(jnp.float32).sum())
+
+    compiled = _compile(jax.grad(loss, argnums=(0, 1, 2, 3, 4)),
+                        x, w, q, kv, kv)
+    assert _kernel_count(compiled) >= 4
+    assert "all-reduce" in compiled.as_text()
+
+    def unwrapped(x, w):
+        return rmsnorm(x, w, use_pallas=True)
+
+    with pytest.raises(NotImplementedError, match="shard_map"):
+        _compile(unwrapped, x, w)

@@ -28,7 +28,7 @@ def test_two_node_scheduling(rt_cluster):
         import os
         import time as _t
 
-        _t.sleep(2)  # hold the slot so later tasks must spill
+        _t.sleep(1)  # hold the slot so later tasks must spill
         return os.environ["RT_NODE_ID"]
 
     # Saturate: 2-CPU tasks on 2-CPU nodes; overlap forces spillover.

@@ -126,14 +126,13 @@ def test_compile_counting_flags_retraces():
     assert prof.records()[-1]["compiles"] == 1
 
 
-def test_mfu_estimate_uses_env_peak(monkeypatch):
+def test_mfu_estimate_uses_given_peak():
     from ray_tpu.train import StepProfiler
     from ray_tpu.train import flight_recorder
 
-    monkeypatch.setenv("RT_PEAK_FLOPS_PER_S", "1e12")
-    assert flight_recorder.peak_flops_per_s() == 1e12
+    assert flight_recorder.peak_flops_per_s() is None  # CPU test mesh
     prof = StepProfiler(ring=4, rank=0, emit_metrics=False,
-                        flops_per_step=1e9)
+                        flops_per_step=1e9, peak_flops=1e12)
     with prof.step():
         time.sleep(0.002)
     rec = prof.records()[-1]

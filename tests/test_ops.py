@@ -281,3 +281,37 @@ def test_chunked_lm_head_ce_parity():
         np.testing.assert_allclose(
             np.asarray(a), np.asarray(b), rtol=2e-4, atol=2e-5
         )
+
+
+def test_kernels_per_shard_match_whole():
+    """Under a mesh the ops run each kernel on a device's block
+    (ops.per_shard). Values and gradients equal the unsharded call,
+    including the replicated scale's, which sums over batch shards."""
+    from jax.sharding import NamedSharding
+
+    from ray_tpu.models.transformer import _ACT_SPEC, _HEADS_SPEC
+    from ray_tpu.parallel import MeshConfig, build_mesh
+
+    mesh = build_mesh(MeshConfig(fsdp=2, tp=2), jax.devices()[:4])
+    ks = jax.random.split(jax.random.PRNGKey(20), 5)
+    x = jax.random.normal(ks[0], (4, 16, 64))
+    w = jax.random.normal(ks[1], (64,)) * 0.1 + 1.0
+    q = jax.random.normal(ks[2], (4, 16, 4, 32))
+    k = jax.random.normal(ks[3], (4, 16, 2, 32))
+    v = jax.random.normal(ks[4], (4, 16, 2, 32))
+
+    def loss(x, w, q, k, v, mesh=None):
+        h = rmsnorm(x, w, interpret=True, mesh=mesh, spec=_ACT_SPEC)
+        a = flash_attention(q, k, v, block_q=8, block_k=8, interpret=True,
+                            mesh=mesh, spec=_HEADS_SPEC)
+        return (h ** 2).sum() + (a ** 2).sum()
+
+    whole = jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))(x, w, q, k, v)
+    put = lambda a, spec: jax.device_put(a, NamedSharding(mesh, spec))  # noqa: E731
+    args = (put(x, _ACT_SPEC), put(w, jax.sharding.PartitionSpec()),
+            put(q, _HEADS_SPEC), put(k, _HEADS_SPEC), put(v, _HEADS_SPEC))
+    sharded = jax.jit(jax.value_and_grad(
+        lambda *a: loss(*a, mesh=mesh), argnums=(0, 1, 2, 3, 4)))(*args)
+    for a, b in zip(jax.tree.leaves(sharded), jax.tree.leaves(whole)):
+        np.testing.assert_allclose(np.asarray(a), np.asarray(b),
+                                   rtol=1e-4, atol=1e-4)

@@ -99,7 +99,7 @@ def test_freed_object_get_fails(rt_start):
     client._in_store.discard(oid)  # the borrower resolves via the cluster
     with pytest.raises((rt.exceptions.ObjectLostError,
                         rt.exceptions.GetTimeoutError)):
-        rt.get(borrowed, timeout=5)
+        rt.get(borrowed, timeout=1.5)
 
 
 # ---------------------------------------------------------------------------

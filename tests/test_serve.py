@@ -344,3 +344,37 @@ def test_shared_application_object_deploys_once(serve_session):
     assert rt.get(handle.remote(), timeout=60) == [1, 2]
     st = serve.status()
     assert "Counter" in st and "Counter_1" not in st
+
+
+def test_replica_that_constructs_for_a_while_is_waited_for_not_replaced(
+        monkeypatch, tmp_path):
+    """A replica that loads a model constructs for minutes. serve.run
+    returns when it has answered once, and the controller's health pass
+    leaves a replica alone while it is still constructing: it used to
+    count three unanswered probes and replace it, for ever."""
+    # Probes give up fast, so three of them fit inside the constructor.
+    monkeypatch.setenv("RT_SERVE_HEALTH_WAIT_S", "0.2")
+    births = tmp_path / "births"
+
+    @serve.deployment
+    class Slow:
+        def __init__(self, log):
+            with open(log, "a") as f:
+                f.write("born\n")
+            time.sleep(4.0)
+
+        def __call__(self):
+            return "ready"
+
+    rt.init(num_cpus=4)
+    try:
+        t0 = time.monotonic()
+        handle = serve.run(Slow.bind(str(births)), name="slow")
+        assert time.monotonic() - t0 >= 4.0
+        t0 = time.monotonic()
+        assert rt.get(handle.remote(), timeout=30) == "ready"
+        assert time.monotonic() - t0 < 2.0
+        assert births.read_text() == "born\n"
+    finally:
+        serve.shutdown()
+        rt.shutdown()

@@ -236,7 +236,7 @@ def test_worker_stacks(rt_start):
         def busy(self):
             import time
 
-            time.sleep(5)
+            time.sleep(1.5)
             return 1
 
     s = Sleeper.remote()

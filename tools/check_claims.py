@@ -317,8 +317,8 @@ CLAIMS = [
     # COMPONENTS flagship MFU <- live TPU artifact.
     Claim("COMPONENTS.md", r"MFU (0\.\d+)", _bench_r("mfu"), rel_tol=0.08),
     # Serving-engine hot-loop numbers <- BENCH_INFER stepwise probe.
-    # Quoted in MIGRATION.md and the bench_infer.py probe docstring;
-    # tight tolerance — docs and artifact are committed together.
+    # Quoted in MIGRATION.md; tight tolerance — docs and artifact are
+    # committed together.
     Claim("MIGRATION.md", r"engine step (\d+\.\d+) ms",
           _bench_infer("engine step breakdown", "engine_step_ms"),
           rel_tol=0.02),
@@ -338,25 +338,6 @@ CLAIMS = [
           _bench_infer_r5_implied_step_ms(), rel_tol=0.02,
           note="r5 engine step implied by 4 slots / continuous tok/s"),
     Claim("MIGRATION.md", r"a (\d+\.\d+) ms raw batch-8 decode",
-          _bench_infer("llama2(0.8B) decode", "ms_per_decode_step",
-                       batch=8),
-          rel_tol=0.02),
-    Claim("bench_infer.py", r"step (\d+\.\d+) ms vs raw floor",
-          _bench_infer("engine step breakdown", "engine_step_ms"),
-          rel_tol=0.02),
-    Claim("bench_infer.py", r"vs raw floor (\d+\.\d+) ms",
-          _bench_infer("engine step breakdown", "raw_decode_step_ms"),
-          rel_tol=0.02),
-    Claim("bench_infer.py", r"overhead (-?\d+\.\d+) ms",
-          _bench_infer("engine step breakdown", "engine_overhead_ms"),
-          rel_tol=0.05),
-    Claim("bench_infer.py", r"ratio of (\d+\.\d+)",
-          _bench_infer("engine vs raw decode throughput",
-                       "engine_vs_raw_throughput_ratio"),
-          rel_tol=0.02),
-    Claim("bench_infer.py", r"implied (\d+\.\d+) ms engine step",
-          _bench_infer_r5_implied_step_ms(), rel_tol=0.02),
-    Claim("bench_infer.py", r"artifact's (\d+\.\d+) ms raw batch-8",
           _bench_infer("llama2(0.8B) decode", "ms_per_decode_step",
                        batch=8),
           rel_tol=0.02),
