@@ -91,7 +91,6 @@ def probe_recorder_overhead(results, quick: bool):
     g, x, n, work_ms = _make_work(TARGET_WORK_MS)
 
     prof = StepProfiler(ring=512, rank=0, flops_per_step=n * 2 * 512**3)
-    prof.watch_jit(g)
     # Warm both paths, then run the arms INTERLEAVED (off, on, off, on,
     # ...) so load/clock drift lands on both equally.
     _steps_off(g, x, n, 5)

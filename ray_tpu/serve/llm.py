@@ -25,10 +25,11 @@ tokens as they are produced, and free their slot the moment they finish
     back through an async double-buffered copy (dispatch step k+1,
     drain step k's already-landed buffer), both decode variants compile
     at engine construction (greedy<->sampled traffic flips never
-    compile mid-serving), and stats() exposes the per-step breakdown
-    (dispatch/fetch/host ms, compile and upload counters) that proves
-    it — the T3-style overlap discipline (arXiv:2401.16677) applied to
-    decode, with EQuARX-style step decomposition (arXiv:2506.17615).
+    compile mid-serving), and stats() exposes the loop's phase ledger
+    (work/wait/other ms per turn, compile and upload counters) that
+    proves it — the T3-style overlap discipline (arXiv:2401.16677)
+    applied to decode, with EQuARX-style step decomposition
+    (arXiv:2506.17615).
 
 Reference provenance: serve/batching.py (the mechanism surpassed);
 BASELINE.json configs[4] (the serving north-star).
@@ -62,12 +63,11 @@ from ray_tpu.models.transformer import (
     project_logits,
 )
 from ray_tpu.ops import apply_rope, rmsnorm, rope_frequencies
+from ray_tpu.util.compile_cache import compile_events
 from ray_tpu.util.device_peaks import device_report
 
 NEG_INF = -1e30
 
-_STEP_MS_BOUNDARIES = (0.1, 0.25, 0.5, 1.0, 2.5, 5.0, 10.0, 25.0, 50.0,
-                       100.0, 250.0)
 _metrics_lock = threading.Lock()
 _metrics: Optional[Dict] = None
 
@@ -84,33 +84,6 @@ def _engine_metrics() -> Dict:
             from ray_tpu.util.metrics import Counter, Gauge, Histogram
 
             _metrics = {
-                "dispatch_ms": Histogram(
-                    "serve_llm_step_dispatch_ms",
-                    "Decode-step dispatch time (enqueue the jitted step)",
-                    boundaries=_STEP_MS_BOUNDARIES,
-                ),
-                "fetch_ms": Histogram(
-                    "serve_llm_step_fetch_ms",
-                    "Blocking time draining the previous step's async "
-                    "device->host token copy",
-                    boundaries=_STEP_MS_BOUNDARIES,
-                ),
-                "host_ms": Histogram(
-                    "serve_llm_step_host_ms",
-                    "Host-side engine work per step (scheduling, token "
-                    "distribution, locking)",
-                    boundaries=_STEP_MS_BOUNDARIES,
-                ),
-                "recompiles": Counter(
-                    "serve_llm_recompiles_total",
-                    "Jit compilations observed AFTER engine warmup "
-                    "(steady-state traffic should never compile)",
-                ),
-                "param_uploads": Counter(
-                    "serve_llm_param_uploads_total",
-                    "Host->device sampling-param/active-mask refreshes "
-                    "(only on slot admission/eviction, never per step)",
-                ),
                 # Request-level latency (flight recorder): TTFT is
                 # submit->first token (queue wait + prefill), TPOT the
                 # mean inter-token interval after the first. Seconds,
@@ -432,6 +405,18 @@ class GenerationHandle:
         first = self._first_token_t is None
         if first:
             self._first_token_t = now
+        obs = self.obs
+        if obs is not None:
+            # The card is written before the token can be taken: a
+            # streamed chunk's delivery lag is measured from its push
+            # stamp, and the record closes when a poll has the last one
+            # (replica.next_chunks).
+            obs.push_t.append(now)
+            if first:
+                obs.marks["first_token"] = now
+            if done:
+                obs.marks["engine_done"] = now
+                obs.tokens_out = self.produced
         with self._cond:
             self._tokens.append(int(token))
             self._done = self._done or done
@@ -445,13 +430,6 @@ class GenerationHandle:
             m["tpot_s"].observe(
                 (now - self._first_token_t) / (self.produced - 1)
             )
-        obs = self.obs
-        if obs is not None:
-            if first:
-                obs.marks["first_token"] = now
-            if done:
-                obs.marks["engine_done"] = now
-                obs.tokens_out = self.produced
 
     def _fail(self, err: BaseException):
         with self._cond:
@@ -463,6 +441,7 @@ class GenerationHandle:
 
     # -- caller side --
     def __iter__(self):
+        taken = 0
         while True:
             with self._cond:
                 while not self._tokens and not self._done:
@@ -470,7 +449,11 @@ class GenerationHandle:
                 if self._error is not None:
                     raise self._error
                 if self._tokens:
-                    yield self._tokens.popleft()
+                    tok = self._tokens.popleft()
+                    taken += 1
+                    if self.obs is not None:
+                        self.obs.taken = taken
+                    yield tok
                     continue
                 if self._done:
                     return
@@ -489,6 +472,97 @@ class GenerationHandle:
             out.extend(self._tokens)
             self._tokens.clear()
         return out
+
+
+# The children of an engine turn, by class: `work` is the loop thread
+# doing host work, `wait` is the loop thread blocked on the device.
+_TURN_PHASES = {
+    "admit": "work",
+    "prefill_dispatch": "work",
+    "prefill_first_token_wait": "wait",
+    "prefill_publish": "work",
+    "upload": "work",
+    "decode_dispatch": "work",
+    "decode_fetch_wait": "wait",
+    "distribute": "work",
+}
+
+
+class _PhaseLedger:
+    """Where the engine loop's time goes, on two clocks at once:
+    `with ledger(key):` opens `jax.profiler.TraceAnnotation(
+    "engine.<key>")` (a host span on the profiler's clock while a session
+    is on, about 0.4 us when none is) and adds the elapsed `perf_counter`
+    time and a count to the key's totals. Every loop iteration with work
+    is one `turn` whose children are `_TURN_PHASES`; what a turn spends
+    outside them is `other` = turn - children, so work + wait + other is
+    the turn total by construction. `wait_for_work` (idle) lies beside
+    the turns and in no total. Loop-thread-only: stats() reads the copy
+    the loop publishes after each turn."""
+
+    def __init__(self):
+        keys = ("turn", "wait_for_work", *_TURN_PHASES)
+        self.names = {k: f"engine.{k}" for k in keys}
+        self.n = dict.fromkeys(keys, 0)
+        self.s = dict.fromkeys(keys, 0.0)
+        self.prefill_passes = 0  # turns in which _advance_prefills ran
+        self.t = 0.0             # the newest stamp any phase read
+
+    def __call__(self, key: str) -> "_Phase":
+        return _Phase(self, key)
+
+    def snapshot(self) -> Dict:
+        return {"n": dict(self.n), "s": dict(self.s),
+                "prefill_passes": self.prefill_passes}
+
+
+class _Phase:
+    """One span of the ledger (class-based: this runs a dozen times a
+    turn). `elapsed` is set on exit."""
+
+    __slots__ = ("_ledger", "_key", "_span", "_t0", "elapsed")
+
+    def __init__(self, ledger: _PhaseLedger, key: str):
+        self._ledger = ledger
+        self._key = key
+        self.elapsed = 0.0
+
+    def __enter__(self):
+        self._span = jax.profiler.TraceAnnotation(
+            self._ledger.names[self._key])
+        self._span.__enter__()
+        self._t0 = self._ledger.t = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc):
+        ledger = self._ledger
+        ledger.t = now = time.perf_counter()
+        self._span.__exit__(*exc)
+        self.elapsed = now - self._t0
+        ledger.s[self._key] += self.elapsed
+        ledger.n[self._key] += 1
+        return False
+
+
+def _timing_of(ledger: Dict) -> Dict:
+    """stats()["timing"]'s view of a published ledger: cumulative, so two
+    snapshots give a window. Keys carry no dot (readers walk a.b.c)."""
+    n, s = ledger["n"], ledger["s"]
+    by_class = {"work": 0.0, "wait": 0.0}
+    for key, cls in _TURN_PHASES.items():
+        by_class[cls] += s[key]
+    return {
+        "turns": n["turn"],
+        "turn_ms_total": s["turn"] * 1e3,
+        "prefill_passes": ledger["prefill_passes"],
+        "prefill_chunks": n["prefill_dispatch"],
+        "work_ms_total": by_class["work"] * 1e3,
+        "wait_ms_total": by_class["wait"] * 1e3,
+        "other_ms_total": (s["turn"] - by_class["work"]
+                           - by_class["wait"]) * 1e3,
+        "phases": {k: {"n": n[k], "ms_total": s[k] * 1e3}
+                   for k in (*_TURN_PHASES, "wait_for_work")},
+    }
 
 
 class ContinuousBatchingEngine:
@@ -660,6 +734,9 @@ class ContinuousBatchingEngine:
         # replaces it: warm-up then compiles the program the loop runs.
         self._tokens_dev = self._replicated(
             np.zeros(num_slots, dtype=np.int32))
+        # The decode step dispatched and not yet drained (loop-thread-
+        # only): (snapshot [(slot, gen, handle)], tokens_dev, lengths_dev).
+        self._inflight = None
         # Per-slot admission generation: suppresses the one in-flight
         # token a just-evicted slot still produces under the lag.
         self._gen = np.zeros(num_slots, dtype=np.int64)
@@ -680,7 +757,13 @@ class ContinuousBatchingEngine:
         self._params_dirty = False
         self._sampled_active = False
         self._param_uploads = 0  # refresh events (tests pin steady state)
-        # Per-step timing breakdown (loop thread writes, stats() reads).
+        # The loop's phase ledger (loop-thread-only) and the copy the
+        # loop publishes under the lock after each turn for stats().
+        self._phase = _PhaseLedger()
+        self._ledger_pub = self._phase.snapshot()
+        # Decode-step breakdown over the turns that dispatched a decode
+        # step, from the ledger's spans (loop thread writes under the
+        # lock, stats() reads).
         self._t_dispatch = 0.0
         self._t_fetch = 0.0
         self._t_host = 0.0
@@ -688,7 +771,6 @@ class ContinuousBatchingEngine:
         self._rng = jax.random.PRNGKey(seed)
         self._next_id = 0
         self._steps = 0  # decode-step counter (observability + tests)
-        self._recompiles = 0  # compilations observed after warmup
         # Head-of-line ledger (engine thread writes, stats() reads under
         # the lock): recent prefill passes that stalled active decode
         # slots past serve_hol_threshold_s, blamed on the prefilling
@@ -696,12 +778,15 @@ class ContinuousBatchingEngine:
         self._hol_events: deque = deque(maxlen=64)
         self._hol_blocked_s = 0.0
         self._last_prefill_work: list = []
+        # Compilations are differences of the process's one counter of
+        # JAX's own compile events: every program warm-up compiled, the
+        # small eager ones included, and anything after it is a recompile.
+        self._compiles_base = compile_events()
         t0 = time.monotonic()
         self._warmup()
         self._warmup_s = time.monotonic() - t0
-        self._warm_compiles = self._compile_count()
+        self._warm_compiles = compile_events() - self._compiles_base
         self._device = device_report()
-        self._last_compiles = self._warm_compiles
         # Event, not a bare bool: set by shutdown() on the caller thread,
         # polled by the engine thread (RT006).
         self._stop_evt = threading.Event()
@@ -713,14 +798,18 @@ class ContinuousBatchingEngine:
     def _warmup(self):  # rtlint: disable=RT010 — runs before the loop thread starts; Thread.start() is the happens-before
         """Compile every steady-state program up front — BOTH decode
         variants (greedy and sampled), the prefill chunk, and the
-        prefill-token picker — so traffic flipping between greedy and
-        sampled never compiles mid-serving. All warmup calls run with
-        `active` all-False: decode writes land in each slot's parking
-        row (lmax - 1, never unmasked) and the prefill rows it touches
-        are re-written by any real occupant before its length exposes
-        them, so cache contents stay semantically untouched."""
-        self._rng, k1, k2 = jax.random.split(self._rng, 3)
-        pad = jnp.zeros((1, self.prefill_chunk), dtype=jnp.int32)
+        first-token path with its small eager programs (key split, pick
+        or argmax, the token buffer's update) — so traffic flipping
+        between greedy and sampled never compiles mid-serving. All
+        warmup calls run with `active` all-False: decode writes land in
+        each slot's parking row (lmax - 1, never unmasked) and the
+        prefill rows it touches are re-written by any real occupant
+        before its length exposes them, so cache contents stay
+        semantically untouched."""
+        # The loop's own two-way split (and the unpacking's unstack).
+        self._rng, k1 = jax.random.split(self._rng)
+        pad = np.zeros((1, self.prefill_chunk), dtype=np.int32)
+        one, zero = np.int32(1), np.int32(0)
         if self._paged:
             (_, self._k, self._v, self._lengths) = self._decode_greedy(
                 self.params, self._tokens_dev, self._k, self._v,
@@ -732,14 +821,12 @@ class ContinuousBatchingEngine:
                 self._temps_dev, self._top_ks_dev, self._top_ps_dev, k1,
             )
             logits, self._k, self._v, self._lengths = self._prefill(
-                self.params, pad, jnp.int32(1), jnp.int32(0), jnp.int32(0),
+                self.params, pad, one, zero, zero,
                 self._k, self._v, self._lengths, self._bt_dev,
             )
             # Warm the copy-on-write page fork too (NULL page onto
             # itself: contents never observable).
-            self._k, self._v = self._cow(
-                self._k, self._v, jnp.int32(0), jnp.int32(0)
-            )
+            self._k, self._v = self._cow(self._k, self._v, zero, zero)
         else:
             (_, self._k, self._v, self._lengths) = self._decode_greedy(
                 self.params, self._tokens_dev, self._k, self._v,
@@ -751,35 +838,40 @@ class ContinuousBatchingEngine:
                 self._top_ks_dev, self._top_ps_dev, k1,
             )
             logits, self._k, self._v, self._lengths = self._prefill(
-                self.params, pad, jnp.int32(1), jnp.int32(0), jnp.int32(0),
+                self.params, pad, one, zero, zero,
                 self._k, self._v, self._lengths,
             )
-        self._pick(
-            logits, jnp.full(1, 0.5, jnp.float32),
-            jnp.full(1, 1, jnp.int32), jnp.full(1, 1.0, jnp.float32), k2,
-        )
+        # Both first-token variants, then the token buffer as it was.
+        tokens = self._tokens_dev
+        self._first_token(logits, 0, 0.5, 1, 1.0)
+        self._first_token(logits, 0, 0.0, 0, 1.0)
+        self._tokens_dev = tokens
         # Undo the warmup prefill's lengths[0] = 1 (device-side, keeps
         # the mesh sharding of the lengths array).
         self._lengths = self._lengths * 0
         jax.block_until_ready(self._lengths)
 
-    def _compile_count(self) -> int:
-        """Total compiled-program count across the engine's jitted
-        callables (the wrapper-counter the recompile guard pins: jit
-        cache growth == a recompilation happened)."""
-        n = 0
-        fns = [self._decode_greedy, self._decode_sampled,
-               self._prefill, self._pick]
-        if self._cow is not None:
-            fns.append(self._cow)
-        for f in fns:
-            try:
-                n += f._cache_size()
-            except (AttributeError, TypeError):
-                # Introspection-only: a jax version without _cache_size
-                # just disables the recompile guard's counter.
-                pass
-        return n
+    # Single-writer: rng and token buffer are engine-thread-owned.
+    def _first_token(self, logits, slot, temperature, top_k, top_p):  # rtlint: disable=RT006 — loop-thread-only (and warm-up, before the thread starts)
+        """A request's first token under its sampling, from its final
+        prefill chunk's logits, ON DEVICE: it feeds the decode loop's
+        token buffer device-to-device and starts the non-blocking copy
+        the handle push drains. Returns the token's device array [1]."""
+        if temperature > 0:
+            self._rng, key = jax.random.split(self._rng)
+            tok_dev = self._pick(
+                logits, np.full(1, temperature, np.float32),
+                np.full(1, top_k, np.int32), np.full(1, top_p, np.float32),
+                key,
+            )
+        else:
+            tok_dev = jnp.argmax(logits, -1).astype(jnp.int32)
+        self._tokens_dev = self._tokens_dev.at[slot].set(tok_dev[0])
+        try:
+            tok_dev.copy_to_host_async()
+        except Exception:  # rtlint: disable=RT007 — optional prefetch; sharded layouts fetch at the drain
+            pass
+        return tok_dev
 
     # Single-writer: every *_dev array is owned by the engine thread
     # (this runs on it); submit() only flips _params_dirty under
@@ -789,14 +881,15 @@ class ContinuousBatchingEngine:
         Called only when slot membership changed (admission/eviction) —
         the steady-state decode step reads the device-resident copies
         and does zero uploads."""
-        self._temps_dev = jnp.asarray(self._temps)
-        self._top_ks_dev = jnp.asarray(self._top_ks)
-        self._top_ps_dev = jnp.asarray(self._top_ps)
-        self._active_dev = jnp.asarray(self._active)
-        self._sampled_active = bool((self._temps[self._active] > 0).any())
-        self._params_dirty = False
-        self._param_uploads += 1
-        _engine_metrics()["param_uploads"].inc(1)
+        with self._phase("upload"):
+            self._temps_dev = jnp.asarray(self._temps)
+            self._top_ks_dev = jnp.asarray(self._top_ks)
+            self._top_ps_dev = jnp.asarray(self._top_ps)
+            self._active_dev = jnp.asarray(self._active)
+            self._sampled_active = bool(
+                (self._temps[self._active] > 0).any())
+            self._params_dirty = False
+            self._param_uploads += 1
 
     # Single-writer: _bt_dev is engine-thread-owned device state.
     def _upload_block_table(self):  # rtlint: disable=RT006,RT010 — loop-thread-only; the lock is for submit()-side visibility
@@ -805,9 +898,10 @@ class ContinuousBatchingEngine:
         membership does — never per decode step (the paged analog of
         _upload_sampling_state, with its own counter so tests can pin
         the steady state)."""
-        self._bt_dev = self._replicated(self._bt_host)
-        self._bt_dirty = False
-        self._bt_uploads += 1
+        with self._phase("upload"):
+            self._bt_dev = self._replicated(self._bt_host)
+            self._bt_dirty = False
+            self._bt_uploads += 1
 
     def _replicated(self, host_array):
         """On the device, whole on every chip of the engine's mesh."""
@@ -1022,12 +1116,13 @@ class ContinuousBatchingEngine:
             padded = np.zeros((1, c), dtype=np.int32)
             padded[0, :len(chunk)] = chunk
             logits, k, v, lengths = self._prefill(
-                self.params, jnp.asarray(padded), jnp.int32(len(chunk)),
-                jnp.int32(0), jnp.int32(off), k, v, lengths, *table,
+                self.params, padded, np.int32(len(chunk)),
+                np.int32(0), np.int32(off), k, v, lengths, *table,
             )
-        return np.asarray(logits[0], dtype=np.float32)
+        return np.asarray(logits, dtype=np.float32)[0]
 
     def stats(self) -> Dict:
+        compiles = compile_events() - self._compiles_base
         with self._lock:
             ts = max(self._timed_steps, 1)
             return {
@@ -1047,16 +1142,18 @@ class ContinuousBatchingEngine:
                 "prefilling": len(self._prefilling),
                 "free_slots": len(self._free),
                 # Hot-loop hygiene (tests pin these in steady state).
-                "compiles": self._compile_count(),
+                "compiles": compiles,
                 "warm_compiles": self._warm_compiles,
-                "recompiles_post_warm": self._recompiles,
+                "recompiles_post_warm": compiles - self._warm_compiles,
                 "param_uploads": self._param_uploads,
-                # Per-step wall-time decomposition: where an engine step
-                # goes beyond the raw decode step (EQuARX discipline —
-                # you cannot shrink a step you cannot decompose).
-                # _total fields are cumulative: probes delta two stats()
-                # snapshots for a clean steady-state window (the avgs
-                # include admission/prefill-heavy iterations).
+                # Where the loop's time goes (EQuARX discipline — you
+                # cannot shrink a step you cannot decompose). _total
+                # fields are cumulative: probes delta two stats()
+                # snapshots for a clean steady-state window. The first
+                # seven keys cover only turns that dispatched a decode
+                # step, `host` being such a turn less its dispatch and
+                # its fetch (prefill and its device waits included); the
+                # ledger's keys cover every turn.
                 "timing": {
                     "steps_timed": self._timed_steps,
                     "dispatch_ms_avg": self._t_dispatch / ts * 1e3,
@@ -1065,6 +1162,7 @@ class ContinuousBatchingEngine:
                     "dispatch_ms_total": self._t_dispatch * 1e3,
                     "fetch_ms_total": self._t_fetch * 1e3,
                     "host_ms_total": self._t_host * 1e3,
+                    **_timing_of(self._ledger_pub),
                 },
                 # Request-level latency (flight recorder): process-wide
                 # lifetime summaries of the TTFT/TPOT histograms, plus
@@ -1268,7 +1366,7 @@ class ContinuousBatchingEngine:
                 self._pool.release(pages)
                 return None
             self._k, self._v = self._cow(
-                self._k, self._v, jnp.int32(pages[fw]), jnp.int32(fork)
+                self._k, self._v, np.int32(pages[fw]), np.int32(fork)
             )
             self._pool.release([pages[fw]])
             pages[fw] = fork
@@ -1331,48 +1429,37 @@ class ContinuousBatchingEngine:
                     if self._paged:
                         self._pool.release(entry["pages"])
                 continue
-            chunk = h.prompt[off:off + c]
-            n = len(chunk)
-            padded = np.zeros((1, c), dtype=np.int32)
-            padded[0, :n] = chunk
-            if self._paged:
+            with self._phase("prefill_dispatch"):
+                chunk = h.prompt[off:off + c]
+                n = len(chunk)
+                padded = np.zeros((1, c), dtype=np.int32)
+                padded[0, :n] = chunk
+                table = (self._bt_dev,) if self._paged else ()
                 logits, self._k, self._v, self._lengths = self._prefill(
-                    self.params, jnp.asarray(padded),
-                    jnp.int32(n), jnp.int32(slot), jnp.int32(off),
-                    self._k, self._v, self._lengths, self._bt_dev,
+                    self.params, padded,
+                    np.int32(n), np.int32(slot), np.int32(off),
+                    self._k, self._v, self._lengths, *table,
                 )
-            else:
-                logits, self._k, self._v, self._lengths = self._prefill(
-                    self.params, jnp.asarray(padded),
-                    jnp.int32(n), jnp.int32(slot), jnp.int32(off),
-                    self._k, self._v, self._lengths,
-                )
-            entry["offset"] = off + n
-            if entry["offset"] < len(h.prompt):
-                continue
-            # Final chunk: first token under the request's sampling.
-            if h.temperature > 0:
-                self._rng, key = jax.random.split(self._rng)
-                tok_dev = self._pick(
-                    logits,
-                    jnp.full(1, h.temperature, jnp.float32),
-                    jnp.full(1, h.top_k, jnp.int32),
-                    jnp.full(1, h.top_p, jnp.float32),
-                    key,
-                )
-            else:
-                tok_dev = jnp.argmax(logits, -1).astype(jnp.int32)
-            # Feed the decode loop device-side (no host round trip) and
-            # start the non-blocking copy for the handle push below.
-            self._tokens_dev = self._tokens_dev.at[slot].set(tok_dev[0])
-            try:
-                tok_dev.copy_to_host_async()
-            except Exception:  # rtlint: disable=RT007 — optional prefetch; sharded layouts fetch below
-                pass
-            finished.append((slot, h, tok_dev, entry))
+                entry["offset"] = off + n
+                if entry["offset"] < len(h.prompt):
+                    continue
+                # Final chunk: the first token, fed to the decode loop
+                # device-side (no host round trip), its copy started for
+                # the handle push below.
+                tok_dev = self._first_token(
+                    logits, slot, h.temperature, h.top_k, h.top_p)
+                finished.append((slot, h, tok_dev, entry))
         if not finished:
             return
-        toks_np = jax.device_get([t for _, _, t, _ in finished])
+        with self._phase("prefill_first_token_wait"):
+            toks_np = jax.device_get([t for _, _, t, _ in finished])
+        with self._phase("prefill_publish"):
+            self._publish_first_tokens(finished, toks_np)
+
+    def _publish_first_tokens(self, finished, toks_np):  # rtlint: disable=RT006 — loop-thread-only, see _advance_prefills
+        """Push this pass's first tokens to their handles and move each
+        request from the prefilling set to the decode set (or free its
+        slot if that token finished it)."""
         for (slot, h, _, entry), tok_arr in zip(finished, toks_np):
             tok = int(tok_arr[0])
             h.produced = 1
@@ -1440,6 +1527,158 @@ class ContinuousBatchingEngine:
             "hol_blocking", prefill_s=round(prefill_s, 4),
             victims=n_active)
 
+    # Single-writer: the loop thread owns every *_dev array, the rng and
+    # the ledger; shared host state is touched under self._lock.
+    def _turn(self):  # rtlint: disable=RT006,RT010
+        """One loop iteration with work, inside the ledger's `turn` span:
+        admit, advance prefills by a chunk, dispatch decode step k+1,
+        drain and distribute step k. Returns, for a turn that dispatched
+        a decode step, its (dispatch, fetch) seconds."""
+        phase = self._phase
+        with phase("admit"):
+            if self._paged:
+                self._apply_kv_chaos()
+            with self._lock:
+                self._admit_locked()
+                n_active = len(self._slots)
+        # HOL watchdog: a prefill pass that stalls active decode slots
+        # past serve_hol_threshold_s is recorded with the prefilling
+        # request(s) to blame. The pass is timed on the ledger's own
+        # stamps (admit's exit to the pass's last phase's exit, the chaos
+        # stretch in between included), not on a second clock.
+        if self._prefilling:
+            phase.prefill_passes += 1
+            t_pass = phase.t
+            self._advance_prefills()
+            self._note_hol(phase.t - t_pass, n_active)
+        with self._lock:
+            snapshot = [
+                (s, int(self._gen[s]), h) for s, h in self._slots.items()
+            ]
+        new_inflight, dispatch_s, fetch_s = None, 0.0, 0.0
+        if snapshot:
+            if self._params_dirty:
+                self._upload_sampling_state()
+            if self._paged and self._bt_dirty:
+                self._upload_block_table()
+            with phase("decode_dispatch") as dispatch:
+                table = (self._bt_dev,) if self._paged else ()
+                if self._sampled_active:
+                    self._rng, step_key = jax.random.split(self._rng)
+                    (next_dev, self._k, self._v,
+                     self._lengths) = self._decode_sampled(
+                        self.params, self._tokens_dev,
+                        self._k, self._v, self._lengths,
+                        self._active_dev, *table,
+                        self._temps_dev, self._top_ks_dev,
+                        self._top_ps_dev, step_key,
+                    )
+                else:
+                    (next_dev, self._k, self._v,
+                     self._lengths) = self._decode_greedy(
+                        self.params, self._tokens_dev,
+                        self._k, self._v, self._lengths,
+                        self._active_dev, *table,
+                    )
+                self._tokens_dev = next_dev
+                # Start the D2H copy NOW: it lands while this thread
+                # distributes the previous step's tokens and the next
+                # turn dispatches — the drain below then finds a
+                # finished buffer instead of blocking.
+                try:
+                    next_dev.copy_to_host_async()
+                    self._lengths.copy_to_host_async()
+                except Exception:  # rtlint: disable=RT007 — optional prefetch; device_get covers it
+                    pass
+                # Dropped inside the phase: see the drained step's arrays
+                # below (0.24 ms a turn on the v5e).
+                step_key = None
+            new_inflight = (snapshot, next_dev, self._lengths)
+            dispatch_s = dispatch.elapsed
+        if self._inflight is not None:
+            prev_snapshot, prev_tokens, prev_lengths = self._inflight
+            with phase("decode_fetch_wait") as fetch:
+                # Intentional single drain: copy_to_host_async above
+                # started this transfer a full step ago, so this is the
+                # double-buffered collect, not a per-step sync.
+                toks, lengths_np = jax.device_get(  # rtlint: disable=RT001
+                    (prev_tokens, prev_lengths)
+                )
+            fetch_s = fetch.elapsed
+            with phase("distribute"):
+                self._distribute(prev_snapshot, toks, lengths_np)
+                # The drained step's device arrays die here, inside a
+                # phase, not at scope exit: releasing two buffers the
+                # in-flight step still reads costs 1.6 ms a turn on the
+                # v5e (chip run, PR 24), which the ledger first showed
+                # as `other`.
+                self._inflight = prev_tokens = prev_lengths = None
+        self._inflight = new_inflight
+        if snapshot:
+            m = _engine_metrics()
+            m["occupancy"].set(len(snapshot) / self.num_slots)
+            m["waiting"].set(float(self._waiting_n))  # gauge snapshot: a stale int is fine
+            if self._paged:
+                m["kv_pages"].set(float(self._pool.in_use))
+            return dispatch_s, fetch_s
+        return None
+
+    def _evict_locked(self, s: int):
+        """Free decode slot `s` (finished, cancelled or expired): the
+        generation bump suppresses the one in-flight token it still
+        produces under the lag."""
+        del self._slots[s]
+        self._free.append(s)
+        self._gen[s] += 1
+        self._active[s] = False
+        self._temps[s] = 0.0
+        self._top_ks[s] = 0
+        self._top_ps[s] = 1.0
+        self._params_dirty = True
+        if self._paged:
+            self._release_slot_pages_locked(s)
+
+    def _distribute(self, prev_snapshot, toks, lengths_np):
+        """Push a drained step's tokens to their handles; evict what
+        finished, was cancelled or ran out of deadline."""
+        now_wall = time.time()
+        with self._lock:
+            self._steps += 1
+            for s, gen, h in prev_snapshot:
+                if self._gen[s] != gen or self._slots.get(s) is not h:
+                    continue  # evicted under the lag
+                if h.cancelled or (
+                    h.deadline_ts and now_wall > h.deadline_ts
+                ):
+                    # Dead work never holds a TPU slot: evict mid-decode
+                    # and fail the handle (cancel() already did for the
+                    # cancelled case).
+                    if not h.cancelled:
+                        self._deadline_expired += 1
+                        h._fail(RequestCancelledError(
+                            f"deadline expired mid-decode "
+                            f"(request {h.request_id}, "
+                            f"{h.produced} tokens produced)",
+                            reason="deadline", rid=str(h.request_id),
+                        ))
+                        observatory.record_deadline_expired(
+                            "", "engine_decode"
+                        )
+                    self._evict_locked(s)
+                    continue
+                tok = int(toks[s])
+                h.produced += 1
+                done = (
+                    (self.eos_id is not None and tok == self.eos_id)
+                    or h.produced >= h.max_new_tokens
+                    # One in-flight step may still write: keep a row of
+                    # margin.
+                    or int(lengths_np[s]) >= self.max_len - 2
+                )
+                h._push(tok, done)
+                if done:
+                    self._evict_locked(s)
+
     def _loop(self):
         """Pipelined decode loop with ASYNC double-buffered fetch:
         dispatch step k+1 (inputs taken from step k's ON-DEVICE pick),
@@ -1453,184 +1692,35 @@ class ContinuousBatchingEngine:
         buffer and the loop does ZERO avoidable host<->device traffic
         per step (sampling params device-resident, no per-step
         uploads)."""
-        inflight = None  # (snapshot [(slot, gen, handle)], tokens_dev, lengths_dev)
+        phase = self._phase
         while not self._stop_evt.is_set():
             try:
-                t_iter = time.perf_counter()
-                if self._paged:
-                    self._apply_kv_chaos()
-                with self._lock:
-                    self._admit_locked()
-                # HOL watchdog: prefill passes (never the bare decode
-                # path) are timed, and a pass that stalls active decode
-                # slots past serve_hol_threshold_s is recorded with the
-                # prefilling request(s) to blame. Zero cost when nothing
-                # is prefilling.
-                if self._prefilling:  # rtlint: disable=RT010 — _prefilling is only mutated on this loop thread; the lock covers submit()-side readers
-                    n_active = len(self._slots)
-                    t_pf = time.perf_counter()
-                    self._advance_prefills()
-                    self._note_hol(time.perf_counter() - t_pf, n_active)
-                with self._lock:
-                    snapshot = [
-                        (s, int(self._gen[s]), h)
-                        for s, h in self._slots.items()
-                    ]
-                dispatch_s = 0.0
-                if snapshot:
-                    if self._params_dirty:
-                        self._upload_sampling_state()
-                    if self._paged and self._bt_dirty:
-                        self._upload_block_table()
-                    t0 = time.perf_counter()
-                    if self._paged:
-                        if self._sampled_active:
-                            self._rng, step_key = jax.random.split(self._rng)
-                            (next_dev, self._k, self._v,
-                             self._lengths) = self._decode_sampled(
-                                self.params, self._tokens_dev,
-                                self._k, self._v, self._lengths,
-                                self._active_dev, self._bt_dev,
-                                self._temps_dev, self._top_ks_dev,
-                                self._top_ps_dev, step_key,
-                            )
-                        else:
-                            (next_dev, self._k, self._v,
-                             self._lengths) = self._decode_greedy(
-                                self.params, self._tokens_dev,
-                                self._k, self._v, self._lengths,
-                                self._active_dev, self._bt_dev,
-                            )
-                    elif self._sampled_active:
-                        self._rng, step_key = jax.random.split(self._rng)
-                        (next_dev, self._k, self._v,
-                         self._lengths) = self._decode_sampled(
-                            self.params, self._tokens_dev,
-                            self._k, self._v, self._lengths,
-                            self._active_dev, self._temps_dev,
-                            self._top_ks_dev, self._top_ps_dev, step_key,
-                        )
-                    else:
-                        (next_dev, self._k, self._v,
-                         self._lengths) = self._decode_greedy(
-                            self.params, self._tokens_dev,
-                            self._k, self._v, self._lengths,
-                            self._active_dev,
-                        )
-                    self._tokens_dev = next_dev
-                    # Start the D2H copy NOW: it lands while this thread
-                    # distributes the previous step's tokens and the
-                    # next iteration dispatches — the drain below then
-                    # finds a finished buffer instead of blocking.
-                    try:
-                        next_dev.copy_to_host_async()
-                        self._lengths.copy_to_host_async()
-                    except Exception:  # rtlint: disable=RT007 — optional prefetch; device_get covers it
-                        pass
-                    dispatch_s = time.perf_counter() - t0
-                    new_inflight = (snapshot, next_dev, self._lengths)
-                else:
-                    new_inflight = None
-                fetch_s = 0.0
-                if inflight is not None:
-                    prev_snapshot, prev_tokens, prev_lengths = inflight
-                    t0 = time.perf_counter()
-                    # Intentional single drain: copy_to_host_async above
-                    # started this transfer a full step ago, so this is
-                    # the double-buffered collect, not a per-step sync.
-                    toks, lengths_np = jax.device_get(  # rtlint: disable=RT001
-                        (prev_tokens, prev_lengths)
-                    )
-                    fetch_s = time.perf_counter() - t0
-                    now_wall = time.time()
+                if self._inflight is None and not self._prefilling:  # rtlint: disable=RT010 — _prefilling is only mutated on this loop thread; the lock covers submit()-side readers
+                    # Nothing on the device and nothing to prefill: idle
+                    # beside the turns until submit() (or the poll that
+                    # chaos injections and shutdown ride) wakes the loop.
+                    with phase("wait_for_work"):
+                        self._work.wait(timeout=0.5)
+                        self._work.clear()
                     with self._lock:
-                        self._steps += 1
-                        for s, gen, h in prev_snapshot:
-                            if (self._gen[s] != gen
-                                    or self._slots.get(s) is not h):
-                                continue  # evicted under the lag
-                            if h.cancelled or (
-                                h.deadline_ts and now_wall > h.deadline_ts
-                            ):
-                                # Dead work never holds a TPU slot: evict
-                                # mid-decode, fail the handle (cancel()
-                                # already did for the cancelled case),
-                                # and let the one in-flight step's token
-                                # be suppressed by the generation bump.
-                                if not h.cancelled:
-                                    self._deadline_expired += 1
-                                    h._fail(RequestCancelledError(
-                                        f"deadline expired mid-decode "
-                                        f"(request {h.request_id}, "
-                                        f"{h.produced} tokens produced)",
-                                        reason="deadline",
-                                        rid=str(h.request_id),
-                                    ))
-                                    observatory.record_deadline_expired(
-                                        "", "engine_decode"
-                                    )
-                                del self._slots[s]
-                                self._free.append(s)
-                                self._gen[s] += 1
-                                self._active[s] = False
-                                self._temps[s] = 0.0
-                                self._top_ks[s] = 0
-                                self._top_ps[s] = 1.0
-                                self._params_dirty = True
-                                if self._paged:
-                                    self._release_slot_pages_locked(s)
-                                continue
-                            tok = int(toks[s])
-                            h.produced += 1
-                            done = (
-                                (self.eos_id is not None
-                                 and tok == self.eos_id)
-                                or h.produced >= h.max_new_tokens
-                                # One in-flight step may still write:
-                                # keep a row of margin.
-                                or int(lengths_np[s]) >= self.max_len - 2
-                            )
-                            h._push(tok, done)
-                            if done:
-                                del self._slots[s]
-                                self._free.append(s)
-                                self._gen[s] += 1
-                                self._active[s] = False
-                                self._temps[s] = 0.0
-                                self._top_ks[s] = 0
-                                self._top_ps[s] = 1.0
-                                self._params_dirty = True
-                                if self._paged:
-                                    self._release_slot_pages_locked(s)
-                inflight = new_inflight
-                if snapshot:
-                    host_s = max(
-                        time.perf_counter() - t_iter - dispatch_s - fetch_s,
-                        0.0,
-                    )
-                    m = _engine_metrics()
-                    m["dispatch_ms"].observe(dispatch_s * 1e3)
-                    m["fetch_ms"].observe(fetch_s * 1e3)
-                    m["host_ms"].observe(host_s * 1e3)
-                    m["occupancy"].set(len(snapshot) / self.num_slots)
-                    m["waiting"].set(float(self._waiting_n))  # rtlint: disable=RT010 — gauge snapshot: a stale int is fine
-                    if self._paged:
-                        m["kv_pages"].set(float(self._pool.in_use))
-                    compiles = self._compile_count()
-                    grew = compiles - self._last_compiles
-                    if grew > 0:
-                        self._last_compiles = compiles
-                        m["recompiles"].inc(grew)
-                    with self._lock:
+                        self._ledger_pub = phase.snapshot()
+                        # submit() raises it before setting _work: a
+                        # miss here is caught by the next wait.
+                        idle = not self._waiting_n
+                    if idle:
+                        if self._paged:
+                            self._apply_kv_chaos()
+                        continue
+                with phase("turn") as turn:
+                    timed = self._turn()
+                with self._lock:
+                    self._ledger_pub = phase.snapshot()
+                    if timed is not None:
+                        dispatch_s, fetch_s = timed
                         self._t_dispatch += dispatch_s
                         self._t_fetch += fetch_s
-                        self._t_host += host_s
+                        self._t_host += turn.elapsed - dispatch_s - fetch_s
                         self._timed_steps += 1
-                        if grew > 0:
-                            self._recompiles += grew
-                if inflight is None and not self._prefilling:
-                    self._work.wait(timeout=0.5)
-                    self._work.clear()
             except BaseException as e:  # noqa: BLE001 — fail all, keep serving
                 with self._lock:
                     pending = (
@@ -1670,7 +1760,7 @@ class ContinuousBatchingEngine:
                     self._top_ks[:] = 0
                     self._top_ps[:] = 1.0
                     self._params_dirty = True
-                inflight = None
+                self._inflight = None
                 time.sleep(0.1)
 
 

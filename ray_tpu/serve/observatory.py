@@ -5,8 +5,9 @@ item; the request-path mirror of the train-side flight recorder):
 
   1. ALWAYS-ON phase attribution. Every request is stamped at handle
      enqueue, router dispatch, replica receive, engine admission (slot
-     grant), prefill completion (first token) and terminal token; the
-     finished request yields a phase vector
+     grant), prefill completion (first token), terminal token and, for a
+     stream, the poll that picks up its last chunk (where the record
+     closes); the finished request yields a phase vector
 
          {handle_queue, dispatch, engine_admission_wait,
           prefill, decode, stream}
@@ -16,7 +17,11 @@ item; the request-path mirror of the train-side flight recorder):
      stamp-wiring regression, not float drift). Finished vectors ride a
      per-replica ring (same design as the StepProfiler ring) and feed
      process-wide labeled metrics. Non-engine deployments collapse the
-     engine phases into one ``exec`` phase.
+     engine phases into one ``exec`` phase. A streamed request's record
+     also carries ``deliver``: how long its chunks lay between being
+     produced (the engine's push stamp) and being handed to a poll, both
+     stamps ``perf_counter`` in the replica process; the actor call's
+     return leg to the client is not covered.
 
   2. Per-tenant / per-deployment SLO accounting. Deployments declare
      optional targets (``SloConfig``: TTFT / TPOT / e2e p-latency
@@ -181,7 +186,8 @@ class RequestContext:
 
     __slots__ = ("rid", "tenant", "app", "method", "sampled",
                  "enq_t", "disp_t", "recv_t", "recv_p",
-                 "marks", "tokens_in", "tokens_out", "finished")
+                 "marks", "tokens_in", "tokens_out", "finished",
+                 "push_t", "taken", "deliver")
 
     def __init__(self, rid: str, tenant: str, app: str, method: str,
                  sampled: bool, enq_t: Optional[float],
@@ -199,6 +205,36 @@ class RequestContext:
         self.tokens_in = 0
         self.tokens_out = 0
         self.finished = False
+        # Delivery: the engine thread appends one push stamp a token,
+        # the thread iterating the engine's handle counts what it took,
+        # and the replica's polls add each handed chunk's lag.
+        self.push_t: List[float] = []
+        self.taken = 0
+        self.deliver: Optional[Dict] = None
+
+    def produced_at(self) -> float:
+        """When the chunk a generator just yielded was produced (perf
+        clock): the push stamp of the newest engine token its thread
+        took, or now for a generator that is not fed by the engine."""
+        if self.taken:
+            return self.push_t[self.taken - 1]
+        return time.perf_counter()
+
+    def note_delivery(self, produced: List[float], now: float) -> None:
+        """Chunks produced at these stamps were handed to a poll at
+        `now` (one poller at a time: the caller holds the stream's
+        lock)."""
+        d = self.deliver
+        if d is None:
+            d = self.deliver = {"chunks": 0, "lag_s_sum": 0.0,
+                                "lag_s_max": 0.0, "first_lag_s": None}
+        for t in produced:
+            lag = max(now - t, 0.0)
+            if d["first_lag_s"] is None:
+                d["first_lag_s"] = lag
+            d["chunks"] += 1
+            d["lag_s_sum"] += lag
+            d["lag_s_max"] = max(d["lag_s_max"], lag)
 
     def mark(self, name: str, at: Optional[float] = None) -> None:
         self.marks[name] = time.perf_counter() if at is None else at
@@ -414,6 +450,8 @@ class RequestProfiler:
             "tokens_in": ctx.tokens_in,
             "tokens_out": ctx.tokens_out,
         }
+        if ctx.deliver is not None:
+            rec["deliver"] = ctx.deliver
         queue_s = (phases["handle_queue"] + phases["dispatch"]
                    + phases.get("engine_admission_wait", 0.0))
         verdicts = self._score_slo(ttft, tpot, e2e)

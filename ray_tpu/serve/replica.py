@@ -32,6 +32,13 @@ class _StreamBuf:
 
     def __init__(self):
         self.chunks: list = []
+        # When each chunk was produced (perf clock) and how many have
+        # been handed to a poll: a chunk's delivery lag is its pickup
+        # less its stamp, counted once.
+        self.produced_t: list = []
+        self.handed = 0
+        # The request's observatory card, until its record is closed.
+        self.octx = None
         self.done = False
         self.error: Optional[str] = None
         self.cond = threading.Condition()
@@ -244,6 +251,8 @@ class ReplicaActor:
             # engine submit()) executes here, so thread-local capture
             # lands the engine's marks on this request's card.
             octx = observatory.begin(obs_ctx, self._app_name, method)
+            with buf.cond:
+                buf.octx = octx
             try:
                 _set_request_model_id(model_id)
                 with tracing.activate(
@@ -274,18 +283,25 @@ class ReplicaActor:
                                 reason="deadline", app=self._app_name,
                                 rid=rmeta.rid,
                             )
+                        produced = (octx.produced_at() if octx is not None
+                                    else time.perf_counter())
                         with buf.cond:
                             buf.chunks.append(chunk)
+                            buf.produced_t.append(produced)
                             buf.cond.notify_all()
             except BaseException as e:  # noqa: BLE001 — crosses the wire
                 with buf.cond:
                     buf.error = f"{type(e).__name__}: {e}"
             finally:
-                observatory.finish(octx)
                 _set_request_model_id("")
                 with buf.cond:
                     buf.done = True
+                    cancelled = buf.cancelled
                     buf.cond.notify_all()
+                # The record stays open until a poll has the last chunk
+                # (next_chunks); nobody polls a cancelled stream again.
+                if cancelled:
+                    self._close_record(buf)
                 with self._lock:
                     self.ongoing -= 1
                     self.total_served += 1
@@ -307,8 +323,21 @@ class ReplicaActor:
             return False
         with buf.cond:
             buf.cancelled = True
+            done = buf.done
             buf.cond.notify_all()
+        if done:  # else the producer closes it at its next chunk boundary
+            self._close_record(buf)
         return True
+
+    def _close_record(self, buf: _StreamBuf) -> None:
+        """Finish the stream's observatory record, once: at the last
+        pickup, at cancellation, or when the stream is collected as
+        abandoned — whichever thread gets there first."""
+        from ray_tpu.serve import observatory
+
+        with buf.cond:
+            octx, buf.octx = buf.octx, None
+        observatory.finish(octx)
 
     def next_chunks(self, stream_id: int, start: int,
                     max_wait_s: float = 2.0) -> Dict:
@@ -327,7 +356,16 @@ class ReplicaActor:
             done = buf.done and start + len(out) >= len(buf.chunks)
             err = buf.error
             buf.last_read = time.monotonic()
+            # Delivery stamp: chunks this poll is the first to be handed
+            # (a resumed stream skips [0:start]; a repeated poll adds
+            # nothing).
+            first_new = max(start, buf.handed)
+            if buf.octx is not None and first_new < len(buf.chunks):
+                buf.octx.note_delivery(buf.produced_t[first_new:],
+                                       time.perf_counter())
+            buf.handed = len(buf.chunks)
         if done:
+            self._close_record(buf)
             with self._lock:
                 self._streams.pop(stream_id, None)
         else:
@@ -341,8 +379,9 @@ class ReplicaActor:
                 sid for sid, b in self._streams.items()
                 if b.done and now - b.last_read > idle_s
             ]
-            for sid in stale:
-                self._streams.pop(sid, None)
+            abandoned = [self._streams.pop(sid) for sid in stale]
+        for buf in abandoned:
+            self._close_record(buf)
 
     def queue_len(self) -> int:
         """Queue-length probe (reference: power-of-two router probes)."""

@@ -25,7 +25,6 @@ Usage (inside a train_loop_per_worker):
     from ray_tpu import train
 
     prof = train.StepProfiler(flops_per_step=model_flops)
-    prof.watch_jit(train_step)              # compile/retrace counting
     prof.attach_feed(it)                    # data-wait from FeedStats
     for batch in it:
         with prof.step(tokens=batch_tokens) as s:
@@ -38,6 +37,9 @@ Collective time needs no annotation: the eager collective wrappers
 (util/collective) report op wall time into the active step through an
 observer hook. Phases not covered by an explicit `phase(...)`/`fence`
 land in "other_s", so the breakdown always sums to the step wall time.
+A step's `compiles` is what JAX compiled in this process while the step
+ran, from JAX's own compile events (util.compile_cache.compile_events):
+a steady-state loop records 0, anything else is a retrace.
 """
 
 from __future__ import annotations
@@ -49,6 +51,7 @@ from typing import Any, Dict, List, Optional, Sequence
 
 from ray_tpu._private import chaos
 from ray_tpu.util import journal
+from ray_tpu.util.compile_cache import compile_events
 
 #: Phase keys every record carries (plus "other_s" for the remainder).
 PHASES = ("data", "compute", "collective", "checkpoint")
@@ -148,7 +151,8 @@ class _StepHandle:
     device time still inside the XLA queue at step exit lands in the
     NEXT step's wall)."""
 
-    __slots__ = ("_prof", "_phases", "tokens", "samples", "_t0", "_prev")
+    __slots__ = ("_prof", "_phases", "tokens", "samples", "_t0", "_prev",
+                 "_compiles0")
 
     def __init__(self, prof, tokens=None, samples=None):
         self._prof = prof
@@ -157,10 +161,12 @@ class _StepHandle:
         self.samples = samples
         self._t0 = 0.0
         self._prev = None
+        self._compiles0 = 0
 
     def __enter__(self):
         self._prev = getattr(_tls, "step", None)
         _tls.step = self
+        self._compiles0 = compile_events()
         self._t0 = time.perf_counter()
         # Chaos straggler injection sleeps INSIDE the timed window — the
         # recorder must see the slowness it models (as other_s: a real
@@ -242,8 +248,6 @@ class StepProfiler:
             peak_flops_per_s() if flops_per_step else None
         )
         self._emit = emit_metrics
-        self._watched: List[Any] = []
-        self._last_compiles = 0
         self._feed = None
         self._feed_last: Dict[str, float] = {}
         self._steps = 0
@@ -284,14 +288,6 @@ class StepProfiler:
             pass
 
     # -- loop-side API ---------------------------------------------------
-    def watch_jit(self, *fns: Any) -> "StepProfiler":
-        """Track compiled-program cache growth of these jitted callables:
-        any growth during a step is recorded as that step's `compiles`
-        (a steady-state loop should record 0 — growth means a retrace)."""
-        self._watched.extend(fns)
-        self._last_compiles = self._compile_count()
-        return self
-
     def attach_feed(self, source: Any) -> "StepProfiler":
         """Wire data-wait accounting to an input pipeline: `source` is a
         FeedStats, or anything with feed_stats()/stats (DataIterator,
@@ -316,17 +312,6 @@ class StepProfiler:
         return _PhaseTimer(name)
 
     # -- record assembly -------------------------------------------------
-    def _compile_count(self) -> int:
-        n = 0
-        for f in self._watched:
-            try:
-                n += f._cache_size()
-            except (AttributeError, TypeError):
-                # A callable without jit cache introspection just
-                # disables retrace counting for itself.
-                pass
-        return n
-
     def _feed_snapshot(self) -> Optional[Dict[str, float]]:
         src = self._feed
         if src is None:
@@ -374,11 +359,7 @@ class StepProfiler:
                 rec[f"{k}_s"] = v
                 named += v
         rec["other_s"] = max(wall - named, 0.0)
-        compiles = 0
-        if self._watched:
-            n = self._compile_count()
-            compiles = max(n - self._last_compiles, 0)
-            self._last_compiles = n
+        compiles = compile_events() - handle._compiles0
         rec["compiles"] = compiles
         tokens = handle.tokens if handle.tokens is not None else handle.samples
         if tokens is not None and wall > 0:

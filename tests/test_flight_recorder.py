@@ -114,15 +114,20 @@ def test_compile_counting_flags_retraces():
 
     f = jax.jit(lambda x: x * 2)
     prof = StepProfiler(ring=8, rank=0, emit_metrics=False)
-    prof.watch_jit(f)
+    # Counted from JAX's own compile events, whatever compiles: make the
+    # inputs outside the steps (jnp.ones is a small program of its own).
+    four, eight = jnp.ones((4,)), jnp.ones((8,))
     with prof.step():
-        f(jnp.ones((4,)))
+        f(four)
     assert prof.records()[-1]["compiles"] == 1
     with prof.step():
-        f(jnp.ones((4,)))
+        f(four)
     assert prof.records()[-1]["compiles"] == 0
     with prof.step():
-        f(jnp.ones((8,)))  # new shape: retrace
+        f(eight)  # new shape: retrace
+    assert prof.records()[-1]["compiles"] == 1
+    with prof.step():
+        jax.jit(lambda x: x + 3)(four)  # nobody registered it: counted too
     assert prof.records()[-1]["compiles"] == 1
 
 

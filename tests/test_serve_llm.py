@@ -276,13 +276,15 @@ def test_prefill_logits_are_the_first_step_of_the_served_path():
                                    prefill_chunk=8)
     try:
         served = eng.submit(prompt, max_new_tokens=4).result(timeout=180)
+        # The engine counts every compilation in the process (JAX's own
+        # events): run the eager reference before the snapshot.
+        reference, _ = forward(params, jnp.asarray([prompt]), cfg)
+        reference = np.asarray(reference[0, -1])
         compiles = eng.stats()["compiles"]
         logits = eng.prefill_logits(prompt)
-        reference, _ = forward(params, jnp.asarray([prompt]), cfg)
-        np.testing.assert_allclose(logits, np.asarray(reference[0, -1]),
-                                   rtol=1e-4, atol=1e-4)
-        assert int(logits.argmax()) == served[0]
         assert eng.stats()["compiles"] == compiles
+        np.testing.assert_allclose(logits, reference, rtol=1e-4, atol=1e-4)
+        assert int(logits.argmax()) == served[0]
         again = eng.submit(prompt, max_new_tokens=4).result(timeout=180)
         assert again == served
         with pytest.raises(ValueError, match="prompt length"):
@@ -409,6 +411,149 @@ def test_continuous_batching_step_timing_breakdown():
             assert avg == pytest.approx(total / t["steps_timed"])
     finally:
         eng.shutdown()
+
+
+# -- the loop's phase ledger --------------------------------------------
+
+_LEDGER_CHILDREN = ("admit", "prefill_dispatch", "prefill_first_token_wait",
+                    "prefill_publish", "upload", "decode_dispatch",
+                    "decode_fetch_wait", "distribute")
+_LEDGER_CHUNK = 8
+# Distinct prompts under a page in common: the prefix cache skips nothing.
+_LEDGER_PROMPTS = [[(7 * i + j) % 250 + 1 for j in range(n)]
+                   for i, n in enumerate((20, 3, 29, 10, 17, 8))]
+
+
+@pytest.fixture(scope="module")
+def ledger_run(tmp_path_factory):
+    """Mixed traffic through one engine under a CPU profiler trace
+    (python tracer off): a request alone, so that its prefill turns have
+    no decoding slot, then greedy and sampled requests together, then an
+    idle stretch. Returns stats() at each point and the host plane's
+    `engine.*` spans per thread as (name, start, end)."""
+    import glob
+
+    import jax
+    from jax.profiler import ProfileData
+
+    from ray_tpu.serve.llm import ContinuousBatchingEngine
+
+    params, cfg = _tiny_model()
+    eng = ContinuousBatchingEngine(params, cfg, num_slots=3, max_len=128,
+                                   prefill_chunk=_LEDGER_CHUNK)
+    trace_dir = str(tmp_path_factory.mktemp("ledger_trace"))
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=opts)
+    try:
+        before = eng.stats()
+        eng.submit(_LEDGER_PROMPTS[0], max_new_tokens=6).result(timeout=180)
+        alone = eng.stats()
+        sampling = [{}, {"temperature": 0.8, "top_p": 0.9}, {},
+                    {"temperature": 1.1, "top_k": 8}, {}]
+        handles = [eng.submit(p, max_new_tokens=12, **kw)
+                   for p, kw in zip(_LEDGER_PROMPTS[1:], sampling)]
+        for h in handles:
+            assert len(h.result(timeout=180)) == 12
+        time.sleep(0.7)  # the loop goes idle: a wait_for_work closes
+        after = eng.stats()
+    finally:
+        jax.profiler.stop_trace()
+        eng.shutdown()
+    threads = []
+    (xplane,) = glob.glob(f"{trace_dir}/plugins/profile/*/*.xplane.pb")
+    for plane in ProfileData.from_file(xplane).planes:
+        if not plane.name.startswith("/host:"):
+            continue
+        for line in plane.lines:
+            spans = [(ev.name, ev.start_ns, ev.start_ns + ev.duration_ns)
+                     for ev in line.events if ev.name.startswith("engine.")]
+            if spans:
+                threads.append(spans)
+    return {"before": before, "alone": alone, "after": after,
+            "threads": threads}
+
+
+def _ledger_sums_to_the_turn_total(run):
+    t = run["after"]["timing"]
+    assert t["turns"] > 0 and t["turn_ms_total"] > 0
+    assert (t["work_ms_total"] + t["wait_ms_total"] + t["other_ms_total"]
+            == pytest.approx(t["turn_ms_total"], rel=1e-9))
+    assert t["other_ms_total"] >= 0
+    phases = t["phases"]
+    assert set(phases) == set(_LEDGER_CHILDREN) | {"wait_for_work"}
+    for key in _LEDGER_CHILDREN:
+        assert phases[key]["n"] > 0 and phases[key]["ms_total"] > 0, key
+    children = sum(phases[k]["ms_total"] for k in _LEDGER_CHILDREN)
+    assert children == pytest.approx(
+        t["work_ms_total"] + t["wait_ms_total"], rel=1e-9)
+    # Idle time is beside the turns: in `phases`, in no total.
+    assert phases["wait_for_work"]["ms_total"] >= 500.0
+    assert phases["wait_for_work"]["ms_total"] > t["turn_ms_total"] - children
+
+
+def _ledger_counts_prefill_chunks_and_passes(run):
+    t0, t1 = run["before"]["timing"], run["after"]["timing"]
+    chunks = sum(-(-len(p) // _LEDGER_CHUNK) for p in _LEDGER_PROMPTS)
+    assert t1["prefill_chunks"] - t0["prefill_chunks"] == chunks
+    assert t1["phases"]["prefill_dispatch"]["n"] == t1["prefill_chunks"]
+    assert 0 < t1["prefill_passes"] <= t1["turns"]
+    assert t1["prefill_passes"] <= t1["prefill_chunks"]
+    # Six requests finished prefill in at most six passes' drains.
+    assert 0 < t1["phases"]["prefill_first_token_wait"]["n"] <= 6
+
+
+def _ledger_counts_turns_without_a_decoding_slot(run):
+    """A 20-token prompt alone prefills in three turns; only the last
+    leaves a slot decoding, and the old clock counted none of them."""
+    t0, t1 = run["before"]["timing"], run["alone"]["timing"]
+    turns = t1["turns"] - t0["turns"]
+    timed = t1["steps_timed"] - t0["steps_timed"]
+    assert t1["prefill_passes"] - t0["prefill_passes"] == 3
+    assert turns - timed >= 2
+    # The old keys keep their meaning: turns that dispatched a decode step.
+    assert timed == t1["phases"]["decode_dispatch"]["n"]
+    assert t1["dispatch_ms_total"] == pytest.approx(
+        t1["phases"]["decode_dispatch"]["ms_total"])
+
+
+def _ledger_spans_nest_in_the_profilers_trace(run):
+    """On the profiler's clock, in the loop's thread: every child lies
+    inside an `engine.turn`, and `engine.wait_for_work` inside none."""
+    loop = [spans for spans in run["threads"]
+            if any(n == "engine.turn" for n, _s, _e in spans)]
+    assert len(loop) == 1, "engine.turn spans on one thread, the loop's"
+    spans = loop[0]
+    turns = [(s, e) for n, s, e in spans if n == "engine.turn"]
+    assert len(turns) == (run["after"]["timing"]["turns"]
+                          - run["before"]["timing"]["turns"])
+
+    def inside_a_turn(s, e):
+        return any(ts <= s and e <= te for ts, te in turns)
+
+    names = {n for n, _s, _e in spans}
+    assert names == ({f"engine.{k}" for k in _LEDGER_CHILDREN}
+                     | {"engine.turn", "engine.wait_for_work"})
+    for n, s, e in spans:
+        if n == "engine.turn":
+            continue
+        assert inside_a_turn(s, e) == (n != "engine.wait_for_work"), n
+    # The spans are the ledger's: as many of each as it counted.
+    t0, t1 = run["before"]["timing"], run["after"]["timing"]
+    for key in _LEDGER_CHILDREN:
+        in_trace = sum(1 for n, _s, _e in spans if n == f"engine.{key}")
+        assert in_trace == (t1["phases"][key]["n"]
+                            - t0["phases"][key]["n"]), key
+
+
+@pytest.mark.parametrize("check", [
+    _ledger_sums_to_the_turn_total,
+    _ledger_counts_prefill_chunks_and_passes,
+    _ledger_counts_turns_without_a_decoding_slot,
+    _ledger_spans_nest_in_the_profilers_trace,
+], ids=lambda f: f.__name__.lstrip("_"))
+def test_phase_ledger(ledger_run, check):
+    check(ledger_run)
 
 
 def test_continuous_batching_tp_sharded():

@@ -298,3 +298,145 @@ def test_phase_metrics_flow_through_handle(serve_session):
     # Caller-side stamps crossed the wire: handle_queue attributed.
     assert "handle_queue" in snap["phases"]
     assert snap["phases"]["exec"]["count"] == 4
+
+
+# -- delivery stamps and where a stream's record closes ------------------
+
+def _local_replica(target, *init_args, **init_kwargs):
+    """The replica's class, constructed in this process (no runtime): its
+    stream threads, buffers and observatory are what a worker would run."""
+    from ray_tpu.serve.replica import ReplicaActor
+
+    return ReplicaActor._cls(target, init_args, init_kwargs, app_name="s")
+
+
+def _count_to(n, gap=0.0):
+    for i in range(n):
+        if gap:
+            time.sleep(gap)
+        yield i
+
+
+def _wait_until(cond, what, timeout=60.0):
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        if cond():
+            return
+        time.sleep(0.005)
+    pytest.fail(f"timed out waiting until {what}")
+
+
+def _poll_to_the_end(replica, sid, sleep_s=0.0):
+    got, start = [], 0
+    while True:
+        out = replica.next_chunks(sid, start, max_wait_s=5.0)
+        got += out["chunks"]
+        start += len(out["chunks"])
+        if out["done"]:
+            return got, out["error"]
+        time.sleep(sleep_s)
+
+
+def test_slow_poller_shows_as_delivery_lag(fresh_observatory):
+    """An engine stream polled every 0.25 s: the tokens wait in the buffer
+    and the record says so, measured from the engine's push stamps; the
+    first poll was already waiting, so the first token does not. The
+    record closes at the last pickup, so `stream` holds the tail and the
+    six phases still sum to e2e."""
+    from ray_tpu.serve.llm import LLMReplica
+
+    replica = _local_replica(LLMReplica, _tiny_model, num_slots=2,
+                             max_len=64)
+    try:
+        w = observatory.make_wire_ctx("acme")
+        w["disp_t"] = time.time()
+        sid = replica.start_stream("stream", ([3, 7, 11, 2], 24), {},
+                                   obs_ctx=w)
+        toks, err = _poll_to_the_end(replica, sid, sleep_s=0.25)
+    finally:
+        replica.callable.engine.shutdown()
+    assert err is None and len(toks) == 24
+    (rec,) = observatory.profiler().records()
+    d = rec["deliver"]
+    assert d["chunks"] == rec["tokens_out"] == 24
+    assert d["lag_s_max"] >= 0.2
+    assert d["first_lag_s"] < 0.1
+    assert d["lag_s_max"] <= d["lag_s_sum"] <= 24 * d["lag_s_max"]
+    assert set(rec["phases"]) == {"handle_queue", "dispatch",
+                                  "engine_admission_wait", "prefill",
+                                  "decode", "stream"}
+    assert abs(sum(rec["phases"].values()) - rec["e2e_s"]) < 1e-9
+    # The engine is far faster than the poller: most of the request's
+    # life is the stream phase, which the old closing point read as ~0.
+    assert rec["phases"]["stream"] >= 0.1
+
+
+def test_plain_generator_is_stamped_at_append(fresh_observatory):
+    """A generator the engine does not feed: chunks are stamped when they
+    are appended, the record still closes at the last pickup."""
+    replica = _local_replica(_count_to)
+    sid = replica.start_stream("", (5,), {})
+    _wait_until(lambda: replica.ongoing == 0, "the producer ended")
+    assert replica.total_served == 1
+    assert observatory.profiler().records() == [], (
+        "the record closed when the producer ended, before any pickup")
+    time.sleep(0.1)
+    toks, err = _poll_to_the_end(replica, sid)
+    assert (toks, err) == ([0, 1, 2, 3, 4], None)
+    (rec,) = observatory.profiler().records()
+    assert rec["deliver"]["chunks"] == 5
+    assert rec["deliver"]["first_lag_s"] >= 0.1
+    assert set(rec["phases"]) == {"handle_queue", "dispatch", "exec"}
+    assert abs(sum(rec["phases"].values()) - rec["e2e_s"]) < 1e-9
+    assert rec["phases"]["exec"] >= 0.1
+
+
+def _close_by_last_pickup(replica):
+    sid = replica.start_stream("", (4,), {})
+    assert _poll_to_the_end(replica, sid) == ([0, 1, 2, 3], None)
+    return sid
+
+
+def _close_by_cancel_mid_stream(replica):
+    sid = replica.start_stream("", (200, 0.01), {})
+    assert replica.next_chunks(sid, 0, max_wait_s=5.0)["chunks"]
+    assert replica.cancel_stream(sid)
+    _wait_until(lambda: replica.ongoing == 0, "the producer noticed")
+    return sid
+
+
+def _close_by_cancel_after_the_end(replica):
+    sid = replica.start_stream("", (4,), {})
+    _wait_until(lambda: replica.ongoing == 0, "the producer ended")
+    assert observatory.profiler().records() == []
+    assert replica.cancel_stream(sid)
+    return sid
+
+
+def _close_by_collection_as_abandoned(replica):
+    sid = replica.start_stream("", (4,), {})
+    _wait_until(lambda: replica.ongoing == 0, "the producer ended")
+    assert observatory.profiler().records() == []
+    replica._gc_streams(idle_s=0.0)
+    assert sid not in replica._streams
+    return sid
+
+
+@pytest.mark.parametrize("close", [
+    _close_by_last_pickup, _close_by_cancel_mid_stream,
+    _close_by_cancel_after_the_end, _close_by_collection_as_abandoned,
+], ids=lambda f: f.__name__.lstrip("_"))
+def test_stream_record_closes_exactly_once(fresh_observatory, close):
+    """However a stream ends, its record is written once: later polls,
+    cancels and collections find it closed."""
+    replica = _local_replica(_count_to)
+    sid = close(replica)
+    _wait_until(lambda: len(observatory.profiler().records()) == 1,
+                "the record closed")
+    replica.cancel_stream(sid)
+    replica.next_chunks(sid, 0, max_wait_s=0.01)
+    replica._gc_streams(idle_s=0.0)
+    time.sleep(0.05)
+    (rec,) = observatory.profiler().records()
+    assert abs(sum(rec["phases"].values()) - rec["e2e_s"]) < 1e-9
+    assert replica.total_served == 1 and replica.ongoing == 0
