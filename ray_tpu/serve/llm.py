@@ -17,8 +17,12 @@ tokens as they are produced, and free their slot the moment they finish
   * Per-slot sequence lengths live in device memory; attention masks by
     each slot's own length, so one batched decode serves slots whose
     sequences started at different times.
-  * Cache buffers are donated through the step, so decode updates the
-    KV cache in place (no per-step reallocation of the big buffer).
+  * Cache buffers are donated to the step and ride whole in the layer
+    scan's carry (`_scan_layers`), each layer writing and reading them
+    at its own index, so decode and prefill update the KV cache in
+    place. Donation alone does not do that: scanned over as the scan's
+    inputs and stacked back as its outputs, a cache is two buffers and
+    every step copies all of it twice.
   * The steady-state hot loop does ZERO avoidable host<->device traffic
     per step: sampling params and the active mask are device-resident
     (re-uploaded only on slot admission/eviction), step outputs come
@@ -37,6 +41,7 @@ BASELINE.json configs[4] (the serving north-star).
 
 from __future__ import annotations
 
+import functools
 import threading
 import time
 from collections import deque
@@ -209,6 +214,35 @@ def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
     return x, k_cache_l, v_cache_l
 
 
+def _scan_layers(params, x, k_cache, v_cache, write_kv, cfg, cos, sin,
+                 positions, valid, mesh=None):
+    """Every layer in turn, the whole KV cache `[layers, ...]` riding in
+    the scan's carry: the one way a step threads its cache through the
+    layers, slotted or paged. `write_kv(i, kc, vc, k, v)` is
+    `_layer_body`'s with the layer index in front; it writes and reads
+    the whole cache at `[i, ...]`.
+
+    A carry is one buffer from the first layer to the last, so with the
+    caches donated each layer's rows are scattered into the caller's own
+    buffer. Scanned over as `xs` and stacked back as `ys` a cache is two
+    buffers: every layer is sliced out of one and written into the
+    other, and the result copied back over the donated argument."""
+
+    def layer(carry, inputs):
+        x, kc, vc = carry
+        lp, i = inputs
+        return _layer_body(
+            x, lp, kc, vc, cfg, cos, sin, positions,
+            functools.partial(write_kv, i), valid, mesh,
+        ), None
+
+    index = jnp.arange(k_cache.shape[0], dtype=jnp.int32)
+    (x, k_cache, v_cache), _ = jax.lax.scan(
+        layer, (x, k_cache, v_cache), (params["layers"], index)
+    )
+    return x, k_cache, v_cache
+
+
 MAX_TOP_K = 64  # per-slot top-k cap (static shape for lax.top_k)
 
 
@@ -266,22 +300,14 @@ def _decode_slots(params, tokens, k_cache, v_cache, lengths, active,
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (s_, 1, lmax), 2)
     valid = k_pos <= positions[:, :, None]
 
-    def write_kv(kc, vc, k, v):
-        kc = kc.at[slot_idx, write_at].set(k[:, 0].astype(kc.dtype))
-        vc = vc.at[slot_idx, write_at].set(v[:, 0].astype(vc.dtype))
-        return kc, vc, kc, vc  # attend against the full cache
+    def write_kv(i, kc, vc, k, v):
+        kc = kc.at[i, slot_idx, write_at].set(k[:, 0].astype(kc.dtype))
+        vc = vc.at[i, slot_idx, write_at].set(v[:, 0].astype(vc.dtype))
+        return kc, vc, kc[i], vc[i]  # attend against the layer's cache
 
-    def layer(carry, inputs):
-        x = carry
-        lp, k_cache_l, v_cache_l = inputs
-        x, k_cache_l, v_cache_l = _layer_body(
-            x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
-            write_kv, valid, mesh,
-        )
-        return x, (k_cache_l, v_cache_l)
-
-    x, (k_new, v_new) = jax.lax.scan(
-        layer, x, (params["layers"], k_cache, v_cache)
+    x, k_new, v_new = _scan_layers(
+        params, x, k_cache, v_cache, write_kv, cfg, cos, sin, positions,
+        valid, mesh,
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
     logits = project_logits(x[:, -1], params, cfg)
@@ -328,25 +354,15 @@ def _prefill_chunk(params, tokens, n_valid, slot, offset, k_cache, v_cache,
     # are bounded by max_len - 2 at submit.
     rows = offset + jnp.arange(c, dtype=jnp.int32)
 
-    def write_kv(kc, vc, k, v):
-        kc = kc.at[slot, rows].set(k[0].astype(kc.dtype), mode="drop")
-        vc = vc.at[slot, rows].set(v[0].astype(vc.dtype), mode="drop")
+    def write_kv(i, kc, vc, k, v):
+        kc = kc.at[i, slot, rows].set(k[0].astype(kc.dtype), mode="drop")
+        vc = vc.at[i, slot, rows].set(v[0].astype(vc.dtype), mode="drop")
         # Attend against the slot's whole cache row range (masked).
-        k_att = jax.lax.dynamic_slice_in_dim(kc, slot, 1, axis=0)
-        v_att = jax.lax.dynamic_slice_in_dim(vc, slot, 1, axis=0)
-        return kc, vc, k_att, v_att
+        return kc, vc, kc[i, slot][None], vc[i, slot][None]
 
-    def layer(carry, inputs):
-        x = carry
-        lp, k_cache_l, v_cache_l = inputs
-        x, k_cache_l, v_cache_l = _layer_body(
-            x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
-            write_kv, valid, mesh,
-        )
-        return x, (k_cache_l, v_cache_l)
-
-    x, (k_new, v_new) = jax.lax.scan(
-        layer, x, (params["layers"], k_cache, v_cache)
+    x, k_new, v_new = _scan_layers(
+        params, x, k_cache, v_cache, write_kv, cfg, cos, sin, positions,
+        valid, mesh,
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
     last = jax.lax.dynamic_slice(x, (0, n_valid - 1, 0), (1, 1, x.shape[-1]))

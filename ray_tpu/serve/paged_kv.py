@@ -14,6 +14,10 @@ shapes and zero steady-state host traffic:
     per layer inside the jitted step); prefill scatters rows into the
     pages the table names. Program shapes depend only on the pool and
     table geometry, so compilation stays bounded exactly as before.
+    The pool is donated to the step and carried whole through the scan
+    over layers; layer i scatters into `pool[i, pages, rows]` and
+    gathers `pool[i, block_tables]`, so a step touches the rows it
+    writes and the pages it attends to, and never copies the pool.
   * A host-side free-list allocator with REFCOUNTED pages. Admission
     reserves every page a request can ever touch up front
     (ceil((prompt + max_new + 1) / page_size)); decode then never
@@ -314,7 +318,7 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
     pages_per_slot * page_size) and masks by length, exactly like the
     slotted step masks its `max_len` row."""
     from ray_tpu.serve.llm import (  # local import: llm imports us too
-        _grouped_attention, _layer_body, _pick_tokens,
+        _pick_tokens, _scan_layers,
     )
 
     s_ = tokens.shape[0]
@@ -333,28 +337,21 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (s_, 1, width), 2)
     valid = k_pos <= positions[:, :, None]
 
-    def write_kv(kc, vc, k, v):
-        # kc [pages, ps, kvh, hd]: scatter one row per slot, then gather
-        # each slot's pages back as a contiguous [width] view. Inactive
-        # slots all target (NULL_PAGE, 0); whichever lands is never
-        # unmasked.
-        kc = kc.at[pages_w, rows_w].set(k[:, 0].astype(kc.dtype))
-        vc = vc.at[pages_w, rows_w].set(v[:, 0].astype(vc.dtype))
-        k_att = kc[block_tables].reshape(s_, width, kvh, hd)
-        v_att = vc[block_tables].reshape(s_, width, kvh, hd)
+    def write_kv(i, kc, vc, k, v):
+        # kc is the whole pool [layers, pages, ps, kvh, hd]: scatter one
+        # row per slot into layer i, then gather each slot's pages back
+        # as a contiguous [width] view, both through the layer index
+        # (slicing kc[i] out first would copy the layer). Inactive slots
+        # all target (NULL_PAGE, 0); whichever lands is never unmasked.
+        kc = kc.at[i, pages_w, rows_w].set(k[:, 0].astype(kc.dtype))
+        vc = vc.at[i, pages_w, rows_w].set(v[:, 0].astype(vc.dtype))
+        k_att = kc[i, block_tables].reshape(s_, width, kvh, hd)
+        v_att = vc[i, block_tables].reshape(s_, width, kvh, hd)
         return kc, vc, k_att, v_att
 
-    def layer(carry, inputs):
-        x = carry
-        lp, k_cache_l, v_cache_l = inputs
-        x, k_cache_l, v_cache_l = _layer_body(
-            x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
-            write_kv, valid, mesh,
-        )
-        return x, (k_cache_l, v_cache_l)
-
-    x, (k_new, v_new) = jax.lax.scan(
-        layer, x, (params["layers"], k_pages, v_pages)
+    x, k_new, v_new = _scan_layers(
+        params, x, k_pages, v_pages, write_kv, cfg, cos, sin, positions,
+        valid, mesh,
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
     logits = project_logits(x[:, -1], params, cfg)
@@ -378,7 +375,7 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     Prefix-cache resumption needs nothing special here: the engine
     starts `offset` at the shared-prefix boundary and the gathered
     pages already hold the donor's K/V rows below it."""
-    from ray_tpu.serve.llm import _layer_body  # local import (cycle)
+    from ray_tpu.serve.llm import _scan_layers  # local import (cycle)
 
     _, c = tokens.shape
     ps = k_pages.shape[2]
@@ -398,24 +395,16 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     pages_w = jnp.where(in_range, bt_row[page_of], NULL_PAGE)
     rows_w = pos % ps
 
-    def write_kv(kc, vc, k, v):
-        kc = kc.at[pages_w, rows_w].set(k[0].astype(kc.dtype))
-        vc = vc.at[pages_w, rows_w].set(v[0].astype(vc.dtype))
-        k_att = kc[bt_row].reshape(1, width, kvh, hd)
-        v_att = vc[bt_row].reshape(1, width, kvh, hd)
+    def write_kv(i, kc, vc, k, v):
+        kc = kc.at[i, pages_w, rows_w].set(k[0].astype(kc.dtype))
+        vc = vc.at[i, pages_w, rows_w].set(v[0].astype(vc.dtype))
+        k_att = kc[i, bt_row].reshape(1, width, kvh, hd)
+        v_att = vc[i, bt_row].reshape(1, width, kvh, hd)
         return kc, vc, k_att, v_att
 
-    def layer(carry, inputs):
-        x = carry
-        lp, k_cache_l, v_cache_l = inputs
-        x, k_cache_l, v_cache_l = _layer_body(
-            x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
-            write_kv, valid, mesh,
-        )
-        return x, (k_cache_l, v_cache_l)
-
-    x, (k_new, v_new) = jax.lax.scan(
-        layer, x, (params["layers"], k_pages, v_pages)
+    x, k_new, v_new = _scan_layers(
+        params, x, k_pages, v_pages, write_kv, cfg, cos, sin, positions,
+        valid, mesh,
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
     last = jax.lax.dynamic_slice(x, (0, n_valid - 1, 0), (1, 1, x.shape[-1]))

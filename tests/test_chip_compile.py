@@ -1,5 +1,6 @@
-"""The Pallas kernels of the main path, compiled for a TPU v5e that is
-described and not attached (the `on-chip-measurement` guide, section 2).
+"""The Pallas kernels of the main path and the serving engine's step
+programs, compiled for a TPU v5e that is described and not attached (the
+`on-chip-measurement` guide, section 2).
 
 Interpret mode (tests/test_ops.py) checks a kernel's arithmetic; it
 cannot see what the chip's compiler refuses: a block that overflows
@@ -7,7 +8,9 @@ scoped VMEM, a slice off the tiling, a custom call under a sharded jit.
 Nothing runs here, so these say nothing about results or speed.
 """
 
+import dataclasses
 import os
+import re
 
 os.environ.setdefault("TPU_LOG_DIR", "disabled")
 
@@ -138,3 +141,78 @@ def test_kernels_compile_under_a_sharded_jit(v5e):
 
     with pytest.raises(NotImplementedError, match="shard_map"):
         _compile(unwrapped, x, w)
+
+
+# The serving cell's geometry (bench/configs/qwen3-4b-serve.json): 24 slots
+# of 1024 positions, pages of 16 rows and the NULL page, chunks of 64. Depth
+# is cut to 4: what is asserted does not depend on it.
+SLOTS, MAX_LEN, PAGE, CHUNK, DEPTH = 24, 1024, 16, 64, 4
+ENGINE_PROGRAMS = ["decode_paged", "prefill_chunk_paged",
+                   "decode_slots", "prefill_chunk"]
+
+
+def _engine_program(name, cfg, one):
+    """(function, donated arguments, argument shapes, the cache's shape)
+    of one of the engine's four step programs, as `ContinuousBatchingEngine`
+    jits it on one chip; decode is the sampled variant."""
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.serve import llm, paged_kv
+
+    def struct(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = jax.tree.map(
+        lambda a: struct(a.shape, a.dtype),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    per_slot = MAX_LEN // PAGE
+    kv = (cfg.n_kv_heads, cfg.head_dim)
+    paged = name.endswith("_paged")
+    shape = ((cfg.n_layers, SLOTS * per_slot + 1, PAGE, *kv) if paged
+             else (cfg.n_layers, SLOTS, MAX_LEN, *kv))
+    cache = struct(shape, cfg.dtype)
+    lengths = struct((SLOTS,))
+    table = (struct((SLOTS, per_slot)),) if paged else ()
+    if name.startswith("decode"):
+        sampling = (struct((SLOTS,), jnp.float32), struct((SLOTS,)),
+                    struct((SLOTS,), jnp.float32), struct((2,), jnp.uint32))
+        args = (params, struct((SLOTS,)), cache, cache, lengths,
+                struct((SLOTS,), jnp.bool_), *table, *sampling)
+        if paged:
+            fn = lambda p, t, k, v, ln, a, bt, *s: paged_kv.decode_paged(  # noqa: E731
+                p, t, k, v, ln, a, bt, *s, cfg, MAX_LEN)
+        else:
+            fn = lambda p, t, k, v, ln, a, *s: llm._decode_slots(  # noqa: E731
+                p, t, k, v, ln, a, *s, cfg)
+        return fn, (2, 3), args, shape
+    scalar = struct(())
+    args = (params, struct((1, CHUNK)), scalar, scalar, scalar, cache, cache,
+            lengths, *table)
+    if paged:
+        fn = lambda p, t, n, s, o, k, v, ln, bt: paged_kv.prefill_chunk_paged(  # noqa: E731
+            p, t, n, s, o, k, v, ln, bt, cfg, MAX_LEN)
+    else:
+        fn = lambda p, t, n, s, o, k, v, ln: llm._prefill_chunk(  # noqa: E731
+            p, t, n, s, o, k, v, ln, cfg)
+    return fn, (5, 6), args, shape
+
+
+@pytest.mark.parametrize("program", ENGINE_PROGRAMS)
+def test_engine_step_updates_its_cache_in_place(v5e, program):
+    """The KV cache rides in the layer scan's carry, so a step with its
+    caches donated scatters into the caller's buffers: no second cache
+    among the temporaries (scanned over and stacked back a cache is two
+    buffers, 3.73 GB of temporaries at 36 layers) and no copy of a whole
+    one."""
+    cfg = dataclasses.replace(QWEN, n_layers=DEPTH)
+    fn, donated, args, shape = _engine_program(
+        program, cfg, SingleDeviceSharding(v5e[0]))
+    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
+    one_cache = 2 * DEPTH * SLOTS * MAX_LEN * cfg.n_kv_heads * cfg.head_dim
+    memory = compiled.memory_analysis()
+    assert memory.temp_size_in_bytes < one_cache // 2
+    # Both caches come back in the buffers they came in.
+    assert memory.alias_size_in_bytes >= 2 * one_cache
+    dims = ",".join(map(str, shape))
+    whole_cache_copies = re.findall(
+        rf"= bf16\[{dims}\]\S* copy\(", compiled.as_text())
+    assert not whole_cache_copies

@@ -121,6 +121,126 @@ def test_paged_vs_slotted_bit_exact_mixed_lengths():
     assert outs["paged"] == outs["slotted"]
 
 
+def _layerwise(params, cfg, x, k_pool, v_pool, write_kv, positions, valid,
+               max_len):
+    """The plain reference for a step's layers: a loop that hands
+    `_layer_body` one layer's pages `pool[i]` at a time and restacks the
+    pool; `write_kv` scatters into and gathers from that layer, with no
+    layer index anywhere. The loop is a `lax.scan` over the pools: the
+    same loop unrolled in Python compiles to other fusions, whose float32
+    results differ from a scan's in the last bit (1e-6 on the logits)."""
+    import jax
+
+    from ray_tpu.ops import rmsnorm, rope_frequencies
+    from ray_tpu.serve.llm import _layer_body
+
+    cos, sin = rope_frequencies(cfg.head_dim, max_len, cfg.rope_theta)
+
+    def layer(x, inputs):
+        lp, k_layer, v_layer = inputs
+        x, k_layer, v_layer = _layer_body(
+            x, lp, k_layer, v_layer, cfg, cos, sin, positions, write_kv,
+            valid)
+        return x, (k_layer, v_layer)
+
+    x, (k_pool, v_pool) = jax.lax.scan(
+        layer, x, (params["layers"], k_pool, v_pool))
+    return rmsnorm(x, params["final_norm"], cfg.norm_eps), k_pool, v_pool
+
+
+@pytest.mark.parametrize("program", ["decode", "prefill_final_chunk"])
+def test_carried_pool_matches_a_layer_by_layer_reference(program):
+    """`decode_paged` and `prefill_chunk_paged` carry the whole pool
+    through the layer scan and index it by layer; the arithmetic is that
+    of a loop over per-layer pools. Slots 0 and 1 share their first page
+    (a prefix-cache hit), slot 2 is inactive, and the prefill chunk is a
+    final one: 5 real rows and 3 of padding, starting mid-page. Each side
+    is one jitted program, as the engine runs it."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import configs, init_params
+    from ray_tpu.models.transformer import _embed_tokens, project_logits
+
+    cfg = configs.tiny_qwen
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    slots, ps, mp, max_len = 3, 4, 4, 16
+    width, kvh, hd = mp * ps, cfg.n_kv_heads, cfg.head_dim
+    shape = (cfg.n_layers, 1 + slots * mp, ps, kvh, hd)
+    kk, kv = jax.random.split(jax.random.PRNGKey(1))
+    k_pool = jax.random.normal(kk, shape, cfg.dtype)
+    v_pool = jax.random.normal(kv, shape, cfg.dtype)
+    tables = jnp.asarray([[1, 2, 3, 4], [1, 5, 6, 7], [0, 0, 0, 0]], jnp.int32)
+    lengths = jnp.asarray([6, 9, 0], jnp.int32)
+    k_pos = jnp.arange(width)[None, None, :]
+
+    if program == "decode":
+        tokens = jnp.asarray([7, 11, 0], jnp.int32)
+        active = jnp.asarray([True, True, False])
+
+        def step(k, v):
+            return paged_kv.decode_paged(
+                params, tokens, k, v, lengths, active, tables,
+                None, None, None, None, cfg, max_len)
+
+        def reference(k, v):
+            pages_w = jnp.where(
+                active, tables[jnp.arange(slots), lengths // ps], NULL_PAGE)
+            rows_w = jnp.where(active, lengths % ps, 0)
+
+            def write_kv(kc, vc, k_new, v_new):
+                kc = kc.at[pages_w, rows_w].set(k_new[:, 0])
+                vc = vc.at[pages_w, rows_w].set(v_new[:, 0])
+                return (kc, vc, kc[tables].reshape(slots, width, kvh, hd),
+                        vc[tables].reshape(slots, width, kvh, hd))
+
+            positions = lengths[:, None]
+            x, k, v = _layerwise(
+                params, cfg, _embed_tokens(params, tokens[:, None], cfg),
+                k, v, write_kv, positions, k_pos <= positions[:, :, None],
+                max_len)
+            logits = project_logits(x[:, -1], params, cfg)
+            return (jnp.argmax(logits, axis=-1).astype(jnp.int32), k, v,
+                    jnp.where(active, lengths + 1, lengths))
+    else:
+        slot, offset, n_valid, c = 1, 9, 5, 8
+        tokens = jnp.asarray([[3, 1, 4, 1, 5, 0, 0, 0]], jnp.int32)
+
+        def step(k, v):
+            return paged_kv.prefill_chunk_paged(
+                params, tokens, jnp.int32(n_valid), jnp.int32(slot),
+                jnp.int32(offset), k, v, lengths, tables, cfg, max_len)
+
+        def reference(k, v):
+            pos = offset + jnp.arange(c)
+            real = (pos < offset + n_valid) & (pos < max_len)
+            bt_row = tables[slot]
+            pages_w = jnp.where(
+                real, bt_row[jnp.minimum(pos // ps, mp - 1)], NULL_PAGE)
+            rows_w = pos % ps
+
+            def write_kv(kc, vc, k_new, v_new):
+                kc = kc.at[pages_w, rows_w].set(k_new[0])
+                vc = vc.at[pages_w, rows_w].set(v_new[0])
+                return (kc, vc, kc[bt_row].reshape(1, width, kvh, hd),
+                        vc[bt_row].reshape(1, width, kvh, hd))
+
+            valid = (k_pos <= pos[None, :, None]) & (k_pos < offset + n_valid)
+            x, k, v = _layerwise(
+                params, cfg, _embed_tokens(params, tokens, cfg), k, v,
+                write_kv, pos[None, :], valid, max_len)
+            return (project_logits(x[:, n_valid - 1], params, cfg), k, v,
+                    lengths.at[slot].set(offset + n_valid))
+
+    got = jax.jit(step)(k_pool, v_pool)
+    want = jax.jit(reference)(k_pool, v_pool)
+    for name, g, w in zip(("out", "k", "v", "lengths"), got, want):
+        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
+                                      err_msg=name)
+    # Both wrote: the comparison above is not of two untouched pools.
+    assert not np.array_equal(np.asarray(got[1]), np.asarray(k_pool))
+
+
 # -- engine: page accounting ----------------------------------------------
 def test_zero_page_leak_over_1k_admit_evict_cycles():
     """1000 admissions/evictions leave the pool exactly empty. Prompts

@@ -402,6 +402,12 @@ def test_continuous_batching_step_timing_breakdown():
     eng = ContinuousBatchingEngine(params, cfg, num_slots=2, max_len=64)
     try:
         eng.submit([3, 7, 11], max_new_tokens=12).result(timeout=180)
+        # The last token is pushed inside the turn that drains it, and
+        # the turn's times are added when it ends: give it that moment.
+        deadline = time.monotonic() + 10
+        while (eng.stats()["timing"]["steps_timed"] < 12
+               and time.monotonic() < deadline):
+            time.sleep(0.01)
         t = eng.stats()["timing"]
         assert t["steps_timed"] >= 12
         for part in ("dispatch", "fetch", "host"):
