@@ -7,9 +7,10 @@
                         matching it (programs have no stable names yet: the
                         engine's decode and prefill are both `jit__lambda`
                         and are told apart by their activations' shape)
-  op_time_share         device time of the operations matching
-                        spec["match"] over busy time (first ten ops only
-                        are kept by name; custom calls are summed apart)
+  op_time_share         device time of the operations whose name (own HLO
+                        name and result shape, as `breakdown.device_ops`
+                        prints them) matches spec["match"], over busy
+                        time, both summed over the devices
   custom_call_share     time in custom calls (Pallas kernels) over busy
   collective_exposed_share   collective time with no other operation
                         running on that device, over the window, the worst
@@ -32,6 +33,11 @@ def read(sources, spec):
         return 100.0 * sum(d["custom_call_s"] for d in devs) / busy if busy else None
     if q == "collective_exposed_share":
         return 100.0 * max(d["collective_exposed_s"] for d in devs) / tr["window_s"]
+    if q == "op_time_share":
+        pat = re.compile(spec["match"])
+        busy = tr["busy_s"]  # a chip's mean, as the times in `op_s` are
+        return 100.0 * sum(s for n, s in tr["op_s"].items()
+                           if pat.search(n)) / busy if busy else None
     if q == "module_ms_per_launch":
         pat = re.compile(spec["match"])
         op = spec.get("contains_op")

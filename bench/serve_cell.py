@@ -1,7 +1,7 @@
 """A serving cell: deploy the engine through `serve.run`, warm the request
 path, offer the mix's load for the window through
 `handle.options(stream=True, method_name="stream")`, and check the served
-model against the float32 reference outside the window.
+model against the configuration's float32 reference outside the window.
 
 `BenchReplica` is the benchmark's subclass of the program's `LLMReplica`:
 the same engine, constructed the same way, plus what a measurement needs
@@ -42,7 +42,8 @@ class BenchReplica(LLMReplica):
         import weights
 
         cfg = spec.program_config(config, platform)
-        self._dims = spec.dims_of(cfg)
+        self._dims = spec.dims_of(cfg, config)
+        self._reference = spec.named_module(config, "reference")
         t0 = time.monotonic()
 
         def loader():
@@ -61,6 +62,7 @@ class BenchReplica(LLMReplica):
         peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
                  for d in jax.local_devices()]
         return {"pid": os.getpid(), "construct_s": self._ready_s,
+                "dims": self._dims,
                 "memory_peak_bytes": max(peaks),
                 "device": self.engine.stats()["device"]}
 
@@ -163,18 +165,16 @@ class BenchReplica(LLMReplica):
         import jax.numpy as jnp
         import numpy as np
 
-        from reference import qwen3
-
-        params, out = self.engine.params, []
+        params, out, reference = self.engine.params, [], self._reference
         # Every sample padded to one length (causal: what follows a
         # position cannot reach it), so the reference compiles one layer.
         width = -(-max(len(p) + len(t) for p, t in zip(prompts, served)) // 64) * 64
         for prompt, tokens in zip(prompts, served):
             seq = list(prompt) + list(tokens[:-1])
             padded = jnp.asarray(seq + [0] * (width - len(seq)), jnp.int32)
-            hidden = qwen3.hidden_layerwise(params, padded, self._dims)
+            hidden = reference.hidden_layerwise(params, padded, self._dims)
             rows = hidden[len(prompt) - 1:len(seq)]
-            ref = np.asarray(qwen3.logits_rows(params, rows, self._dims))
+            ref = np.asarray(reference.logits_rows(params, rows, self._dims))
             sys_first = self.prefill_logits(prompt)
             rms = float(np.sqrt(np.mean(ref[0] ** 2)))
             rel = float(np.sqrt(np.mean((sys_first - ref[0]) ** 2))) / rms
@@ -350,6 +350,7 @@ def run(ctx: Dict) -> Dict:
                          and r["method"] == "stream"]
                         if ctx["trace"] else []),
         "model": {"num_slots": config["engine"]["num_slots"],
+                  "dims": about["dims"], "operations": config["operations"],
                   "device": about["device"]},
     }
     return {

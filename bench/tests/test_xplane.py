@@ -57,6 +57,11 @@ def test_reduce_a_made_up_trace():
                              "contains_op": r"bf16\[8,128\]"}) == pytest.approx(4000.0)
     assert reader.read(src, {"quantity": "collective_exposed_share"}) == pytest.approx(20.0)
     assert reader.read(src, {"quantity": "custom_call_share"}) == pytest.approx(25.0)
+    # An operand called %all-reduce.2 does not make the fusion a match.
+    for match, share in ((r"^%fusion", 50.0), ("all-reduce", 25.0),
+                         (r"bf16\[8,128\]", 100.0), ("while", 0.0)):
+        assert reader.read(src, {"quantity": "op_time_share",
+                                 "match": match}) == pytest.approx(share)
 
 
 def test_reduce_the_recorded_trace():
@@ -76,3 +81,15 @@ def test_reduce_the_recorded_trace():
     assert [n for n, _ in out["by_opcode"]] == [n for n, _ in want["by_opcode"]]
     # The scan's `while` spans its body and is not counted as work itself.
     assert "while" not in dict(out["by_opcode"])
+    # Every operation's time is kept by name, the printed breakdown its
+    # first ten; a share over a pattern sums whatever matches.
+    assert len(out["op_s"]) == 14 and len(out["device_ops"]) == 10
+    assert out["device_ops"] == [[n, s] for n, s in out["op_s"].items()][:10]
+    from readers import trace as reader
+    fusions = dict(out["by_opcode"])["fusion"]  # all of them named *fusion*
+    assert reader.read({"trace": out}, {
+        "quantity": "op_time_share", "match": "fusion"}) == pytest.approx(
+            100.0 * fusions / out["busy_s"])
+    assert reader.read({"trace": out}, {
+        "quantity": "op_time_share", "match": r"^%slice-(start|done)"}
+    ) == pytest.approx(100.0 * 5.2e-08 / out["busy_s"])  # past the tenth

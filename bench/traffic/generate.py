@@ -3,8 +3,9 @@ parameters under bench/traffic/, and this turns it and `--seed` into the
 requests of a run. The program receives only the generated requests.
 
 The arrival and length laws are those of `ray_tpu/loadgen` (`arrival.py`
-exponential and Pareto gaps, `workload.py` `LengthMix` bounded lognormal),
-copied here so that no later PR can change the yardstick.
+exponential and Pareto gaps, `workload.py` `LengthMix` bounded lognormal
+and `RateCurve`'s flash-crowd step), copied here so that no later PR can
+change the yardstick.
 
 Steadiness rule: every seed offers the SAME schedule. The request shapes
 (prompt and output lengths), the arrival gaps and their order are all drawn
@@ -18,7 +19,12 @@ another schedule is another mix file with another `pool_seed`.
 Mix file, serving (`"kind": "open"` or `"closed"`):
 
     arrival   {"process": "poisson" | "pareto", "rate_per_s": r,
-               "pareto_alpha": a}                      (open loop)
+               "pareto_alpha": a,
+               "flash": {"start_share": 0..1, "length_s": s, "mult": m}}
+              (open loop) `flash` is a flash crowd inside the window: from
+              start_share of the window on, for length_s seconds, arrivals
+              come at m times the rate outside the step, and `rate_per_s`
+              stays the mean over the window (the ramp has no step)
     clients   n                                        (closed loop)
     stagger_s the callers' first requests spread over this long
     ramp_s    seconds of the same traffic before the window, not measured
@@ -70,6 +76,32 @@ def _gaps(rng: random.Random, n: int, arrival: Dict) -> List[float]:
     raise ValueError(f"unknown arrival process {process!r}")
 
 
+def _flash_warp(flash: Dict, span: float):
+    """`RateCurve`'s flash-crowd step as a change of clock: inside
+    [start, start + length) the rate is `mult` times the rate outside, and
+    the mean over the span is unchanged. Returns the map from an arrival's
+    time at the even rate to its time under the step; both run over
+    [0, span). Applied to exponential gaps it gives a Poisson process of
+    that rate, and to Pareto gaps the same bursts squeezed and stretched."""
+    a = float(flash["start_share"]) * span
+    b = min(a + float(flash["length_s"]), span)
+    mult = float(flash["mult"])
+    if not (0.0 <= a < b and mult > 0.0):
+        raise ValueError(f"flash step {flash!r} is empty or outside the "
+                         f"window of {span:g} s")
+    outside = span / (span + (mult - 1.0) * (b - a))  # share of the mean
+    ua, ub = a * outside, (a + (b - a) * mult) * outside
+
+    def warp(u: float) -> float:
+        if u < ua:
+            return u / outside
+        if u < ub:
+            return a + (u - ua) / (outside * mult)
+        return b + (u - ub) / outside
+
+    return warp
+
+
 def _shapes(pool: random.Random, n: int, mix: Dict) -> List[Dict]:
     return [{"prompt_len": _draw_length(pool, mix["prompt"]),
              "max_new": _draw_length(pool, mix["output"])}
@@ -118,9 +150,11 @@ def serve_requests(mix: Dict, seed: int, seconds: float, vocab: int) -> Dict:
         gaps = _gaps(pool, n, mix["arrival"])
         # Scaled so that the n arrivals fill the span exactly.
         scale = span / sum(gaps) if gaps else 0.0
+        flash = mix["arrival"].get("flash") if part == "window" else None
+        warp = _flash_warp(flash, span) if flash else (lambda u: u)
         t = 0.0
         for s, g in zip(shapes, gaps):
-            s["t"] = t - (span if part == "ramp" else 0.0)
+            s["t"] = warp(t) - (span if part == "ramp" else 0.0)
             t += g * scale
         _fill_tokens(rng, shapes, mix, vocab)
         out[part] = shapes

@@ -35,13 +35,15 @@ GRAD_COSINE_FLOOR = 0.995
 FIRST_LOSS_DISTANCE = 1.0
 
 
-def _reference_check(params, cfg, dims, mesh, seed: int, check: Dict) -> Dict:
+def _reference_check(reference, params, cfg, dims, mesh, seed: int,
+                     check: Dict) -> Dict:
+    """The system's loss (and gradients) on one seeded sequence against
+    `reference`, the module the configuration file names."""
     import jax
     import jax.numpy as jnp
     import numpy as np
 
     from ray_tpu.models import loss_fn
-    from reference import qwen3
 
     import weights
 
@@ -54,7 +56,7 @@ def _reference_check(params, cfg, dims, mesh, seed: int, check: Dict) -> Dict:
         # compiled program serves every seed.
         sys_loss, sys_grads = jax.jit(jax.value_and_grad(
             lambda p, b: loss_fn(p, b, cfg, mesh)))(params, batch)
-        ref_loss, ref_grads = qwen3.loss_and_grads(params, tokens, dims)
+        ref_loss, ref_grads = reference.loss_and_grads(params, tokens, dims)
         flat_s = jax.tree.leaves(sys_grads)
         flat_r = jax.tree.leaves(ref_grads)
         norm = lambda t: math.sqrt(sum(  # noqa: E731
@@ -71,7 +73,7 @@ def _reference_check(params, cfg, dims, mesh, seed: int, check: Dict) -> Dict:
                      and out["grad_cosine_min"] >= GRAD_COSINE_FLOOR)
     else:
         sys_loss = jax.jit(lambda p, b: loss_fn(p, b, cfg, mesh))(params, batch)
-        ref_loss = qwen3.loss_layerwise(params, tokens, dims)
+        ref_loss = reference.loss_layerwise(params, tokens, dims)
         out = {"ok": True}
     out["loss_system"], out["loss_reference"] = float(sys_loss), float(ref_loss)
     out["ok"] = bool(out["ok"] and abs(out["loss_system"]
@@ -111,14 +113,15 @@ def train_loop(config: Dict):
         spec.program_config(doc, config["platform"]), max_seq=mix["seq"],
         remat=True, remat_policy=step_doc["remat_policy"],
         ce_chunk=step_doc["ce_chunk"])
-    dims = spec.dims_of(cfg)
+    dims = spec.dims_of(cfg, doc)
     mesh = build_mesh(MeshConfig(**doc["deployment"]["mesh"]), jax.devices())
     replicated = NamedSharding(mesh, P())
     params = weights.make_params(
         cfg, seed, logical_shardings(param_logical_axes(cfg), mesh))
     jax.block_until_ready(params)
     took("mesh_and_weights_s")
-    check = _reference_check(params, cfg, dims, mesh, seed, doc["check"])
+    check = _reference_check(spec.named_module(doc, "reference"), params,
+                             cfg, dims, mesh, seed, doc["check"])
     took("reference_check_s")
     optimizer = optax.adamw(step_doc["lr"])
     opt_state = optimizer.init(params)
@@ -275,7 +278,7 @@ def run(ctx: Dict) -> Dict:
             "recorder": out["recorder"], "trace": out["trace"],
             "listener": out["listener"],
             "model": {"dims": out["dims"], "seq": mix["seq"],
-                      "device": device},
+                      "operations": config["operations"], "device": device},
         },
         "device": device,
     }

@@ -228,14 +228,18 @@ def reduce(planes: List[Dict], window_s: float, top: int = 10) -> Dict:
         m["total_s"] /= n_dev
         m["ops"] = sorted(m["ops"])
     busy_mean = sum(d["busy_s"] for d in per_device.values()) / n_dev
+    # Every operation's time by name, a chip's mean, longest first; the
+    # printed breakdown keeps the first `top`.
+    op_s = {n: s / n_dev for n, s in sorted(
+        ops_total.items(), key=lambda kv: -kv[1])}
     return {
         "window_s": window_s,
         "n_devices": len(devices),
         "busy_s": busy_mean,
         "devices": per_device,
         "modules": modules,
-        "device_ops": [[n, s / n_dev] for n, s in sorted(
-            ops_total.items(), key=lambda kv: -kv[1])[:top]],
+        "op_s": op_s,
+        "device_ops": [[n, s] for n, s in list(op_s.items())[:top]],
         "by_opcode": [[n, s / n_dev] for n, s in sorted(
             by_opcode.items(), key=lambda kv: -kv[1])[:top]],
         "idle_gaps": _name_gaps(gaps, host_lines, top),
