@@ -148,6 +148,7 @@ mixtral_8x7b = TransformerConfig(
     max_seq=4096,
     num_experts=8,
     experts_per_token=2,
+    norm_topk_prob=True,
 )
 
 tiny_qwen = TransformerConfig(
@@ -180,6 +181,44 @@ qwen3_4b = TransformerConfig(
     tie_embeddings=True,
 )
 
+tiny_olmoe = TransformerConfig(
+    vocab_size=256,
+    d_model=64,
+    n_layers=2,
+    n_heads=4,
+    n_kv_heads=4,
+    d_ff=32,  # one expert's width
+    max_seq=128,
+    norm_eps=1e-5,
+    dtype=jnp.float32,
+    remat=False,
+    num_experts=8,
+    experts_per_token=2,
+    qk_norm=True,
+    qk_norm_extent="projection",
+)
+
+# OLMoE-1B-7B-0125-Instruct (arXiv:2409.02060; the model's public
+# config.json): 64 experts of width 1024, 8 a token, none shared, router
+# weights not renormalised; multi-head attention with a QK-norm over the
+# whole q and k projections; untied 50,304-row vocabulary. 6.92 B
+# parameters, 1.28 B used by a token.
+olmoe_1b_7b = TransformerConfig(
+    vocab_size=50304,
+    d_model=2048,
+    n_layers=16,
+    n_heads=16,
+    n_kv_heads=16,
+    d_ff=1024,  # one expert's width
+    max_seq=4096,
+    rope_theta=10000.0,
+    norm_eps=1e-5,
+    num_experts=64,
+    experts_per_token=8,
+    qk_norm=True,
+    qk_norm_extent="projection",
+)
+
 NAMED_CONFIGS = {
     "tiny": tiny,
     "tiny_gqa": tiny_gqa,
@@ -194,6 +233,8 @@ NAMED_CONFIGS = {
     "mixtral-8x7b": mixtral_8x7b,
     "tiny_qwen": tiny_qwen,
     "qwen3-4b": qwen3_4b,
+    "tiny_olmoe": tiny_olmoe,
+    "olmoe-1b-7b": olmoe_1b_7b,
 }
 
 

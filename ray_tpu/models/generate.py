@@ -24,8 +24,10 @@ from ray_tpu.models.transformer import (
     _act,
     _embed_tokens,
     project_logits,
+    project_qkv,
 )
 from ray_tpu.ops import apply_rope, rmsnorm, rope_frequencies
+from ray_tpu.parallel.moe import moe_block
 
 NEG_INF = -1e30
 
@@ -75,8 +77,6 @@ def _cached_attention(q, k_cache, v_cache, cache_len):
 def _forward_with_cache(params, tokens, cache, cfg: TransformerConfig):
     """Forward over `tokens` (appended at cache['length']); returns
     (logits for the final position, updated cache)."""
-    if cfg.num_experts:
-        raise ValueError("generation supports dense configs (MoE TBD)")
     x = _embed_tokens(params, tokens, cfg)
     b, lq = tokens.shape
     lmax = cache["k"].shape[2]
@@ -88,12 +88,7 @@ def _forward_with_cache(params, tokens, cache, cfg: TransformerConfig):
         x = carry
         lp, k_cache_l, v_cache_l = inputs
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q = (h @ lp["wq"]).reshape(b, lq, cfg.n_heads, cfg.head_dim)
-        k = (h @ lp["wk"]).reshape(b, lq, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ lp["wv"]).reshape(b, lq, cfg.n_kv_heads, cfg.head_dim)
-        if cfg.qk_norm:
-            q = rmsnorm(q, lp["q_norm"], cfg.norm_eps, use_pallas=False)
-            k = rmsnorm(k, lp["k_norm"], cfg.norm_eps, use_pallas=False)
+        q, k, v = project_qkv(h, lp, cfg)
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
         k_cache_l = jax.lax.dynamic_update_slice(
@@ -105,6 +100,9 @@ def _forward_with_cache(params, tokens, cache, cfg: TransformerConfig):
         attn = _cached_attention(q, k_cache_l, v_cache_l, start + lq)
         x = x + (attn.reshape(b, lq, -1) @ lp["wo"]).astype(x.dtype)
         h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
+        if cfg.num_experts:
+            y, _ = moe_block(h.reshape(b * lq, -1), lp, cfg)
+            return x + y.reshape(b, lq, -1), (k_cache_l, v_cache_l)
         gate = _act(cfg)((h @ lp["w_gate"]).astype(jnp.float32))
         up = (h @ lp["w_up"]).astype(jnp.float32)
         x = x + (((gate * up).astype(x.dtype)) @ lp["w_down"])

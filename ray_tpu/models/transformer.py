@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import apply_rope, flash_attention, rmsnorm, rope_frequencies, softmax_cross_entropy
+from ray_tpu.parallel.moe import load_balancing_loss, moe_block
 
 
 @dataclass(frozen=True)
@@ -44,6 +45,10 @@ class TransformerConfig:
     # MoE (0 experts = dense)
     num_experts: int = 0
     experts_per_token: int = 2
+    # Whether a token's top-k router probabilities are renormalised to sum
+    # to one (Mixtral) or used as the softmax over all experts gave them
+    # (OLMoE: `norm_topk_prob` false in its published config).
+    norm_topk_prob: bool = False
     # attention implementation: "flash" | "ring" | "ulysses"
     attn_impl: str = "flash"
     # Flash-attention Pallas block sizes. bk=512 benches ~7% faster than
@@ -80,6 +85,11 @@ class TransformerConfig:
     # RMSNorm on q and k before RoPE, stabilizing attention logits at
     # scale (replaces Qwen2's QKV bias).
     qk_norm: bool = False
+    # What one QK-norm spans: "head" (Qwen3: each head's head_dim, scales
+    # [head_dim]) or "projection" (OLMoE, arXiv:2409.02060: the whole q or
+    # k projection before the split into heads, scales [n_heads * head_dim]
+    # and [n_kv_heads * head_dim]).
+    qk_norm_extent: str = "head"
     # Explicit head dim when it differs from d_model/n_heads (Qwen3
     # uses 128-wide heads at every scale). 0 = derive from d_model.
     custom_head_dim: int = 0
@@ -121,8 +131,10 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
         "mlp_norm": jnp.ones((L, d), dtype=cfg.dtype),
     }
     if cfg.qk_norm:
-        layer["q_norm"] = jnp.ones((L, hd), dtype=cfg.dtype)
-        layer["k_norm"] = jnp.ones((L, hd), dtype=cfg.dtype)
+        full = cfg.qk_norm_extent == "projection"
+        layer["q_norm"] = jnp.ones((L, h * hd if full else hd), dtype=cfg.dtype)
+        layer["k_norm"] = jnp.ones((L, kvh * hd if full else hd),
+                                   dtype=cfg.dtype)
     if cfg.num_experts == 0:
         layer.update(
             {
@@ -232,6 +244,32 @@ def project_logits(x, params, cfg: TransformerConfig):
     return logits
 
 
+def project_qkv(h, lp, cfg: TransformerConfig):
+    """The layer's q, k, v heads `[B, L, heads, head_dim]` of normed
+    activations `h [B, L, D]`, QK-norm applied at the config's extent
+    (elementwise, so XLA fuses it into the rope/attention pipeline; the
+    pallas rmsnorm kernel targets [.., D] rows)."""
+    b, l, _ = h.shape
+    extent = cfg.qk_norm_extent if cfg.qk_norm else None
+    if extent not in (None, "head", "projection"):
+        raise ValueError(f"unknown qk_norm_extent {extent!r}: expected "
+                         "'head' or 'projection'")
+
+    def normed(q, k):
+        return (rmsnorm(q, lp["q_norm"], cfg.norm_eps, use_pallas=False),
+                rmsnorm(k, lp["k_norm"], cfg.norm_eps, use_pallas=False))
+
+    q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+    if extent == "projection":
+        q, k = normed(q, k)
+    q = q.reshape(b, l, cfg.n_heads, cfg.head_dim)
+    k = k.reshape(b, l, cfg.n_kv_heads, cfg.head_dim)
+    v = v.reshape(b, l, cfg.n_kv_heads, cfg.head_dim)
+    if extent == "head":
+        q, k = normed(q, k)
+    return q, k, v
+
+
 def _attention(cfg: TransformerConfig, q, k, v, mesh, positions):
     if cfg.attn_impl == "ring" and mesh is not None and mesh.shape.get("sp", 1) > 1:
         from ray_tpu.parallel.ring_attention import ring_attention
@@ -258,14 +296,7 @@ def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh,
                     spec=_ACT_SPEC)
         b, l, d = h.shape
-        q = (h @ lp["wq"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
-        k = (h @ lp["wk"]).reshape(b, l, cfg.n_kv_heads, cfg.head_dim)
-        v = (h @ lp["wv"]).reshape(b, l, cfg.n_kv_heads, cfg.head_dim)
-        if cfg.qk_norm:
-            # Elementwise over head_dim: XLA fuses it into the rope/attn
-            # pipeline (the pallas rmsnorm kernel targets [.., D] rows).
-            q = rmsnorm(q, lp["q_norm"], cfg.norm_eps, use_pallas=False)
-            k = rmsnorm(k, lp["k_norm"], cfg.norm_eps, use_pallas=False)
+        q, k, v = project_qkv(h, lp, cfg)
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
         attn = _attention(cfg, q, k, v, mesh, positions)
@@ -273,31 +304,16 @@ def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
 
         h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh,
                     spec=_ACT_SPEC)
-        act = _act(cfg)
         if cfg.num_experts == 0:
-            gate = act((h @ lp["w_gate"]).astype(jnp.float32))
+            gate = _act(cfg)((h @ lp["w_gate"]).astype(jnp.float32))
             up = (h @ lp["w_up"]).astype(jnp.float32)
             mlp_out = ((gate * up).astype(x.dtype)) @ lp["w_down"]
-            aux = jnp.zeros((), dtype=jnp.float32)
+            routing = None
         else:
-            from ray_tpu.parallel.moe import moe_layer
-
-            def expert_fn(w, xin):  # xin: [E, C, D]
-                g = act(jnp.einsum("ecd,edf->ecf", xin, w["gate"]))
-                u = jnp.einsum("ecd,edf->ecf", xin, w["up"])
-                return jnp.einsum("ecf,efd->ecd", g * u, w["down"])
-
-            flat = h.reshape(b * l, d)
-            mlp_flat, aux = moe_layer(
-                flat.astype(jnp.float32),
-                lp["router"].astype(jnp.float32),
-                expert_fn,
-                {"gate": lp["w_gate"], "up": lp["w_up"], "down": lp["w_down"]},
-                k=cfg.experts_per_token,
-            )
-            mlp_out = mlp_flat.reshape(b, l, d).astype(x.dtype)
+            mlp_flat, routing = moe_block(h.reshape(b * l, d), lp, cfg)
+            mlp_out = mlp_flat.reshape(b, l, d)
         x = x + mlp_out
-        return x, aux
+        return x, routing
 
     if cfg.remat:
         if cfg.remat_policy == "dots_nobatch":
@@ -319,6 +335,15 @@ def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
     return body
 
 
+def _aux_loss(routing, tokens: int):
+    """The load-balancing term of a forward pass over `tokens` tokens from
+    the layers' stacked routing statistics (zero for a dense model)."""
+    if routing is None:
+        return jnp.zeros((), dtype=jnp.float32)
+    return load_balancing_loss(routing["prob_mean"], routing["counts"],
+                               tokens)
+
+
 def forward(
     params: Dict,
     tokens: jax.Array,  # [batch, seq] int32
@@ -333,12 +358,13 @@ def forward(
     x = _embed_tokens(params, tokens, cfg)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
     body = _layer_fn(cfg, mesh, cos, sin, positions)
-    x, auxes = jax.lax.scan(body, x, params["layers"])
+    x, routing = jax.lax.scan(body, x, params["layers"])
+    aux = _aux_loss(routing, tokens.size)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh,
                 spec=_ACT_SPEC)
     if return_hidden:
-        return x, auxes.sum()
-    return project_logits(x, params, cfg), auxes.sum()
+        return x, aux
+    return project_logits(x, params, cfg), aux
 
 
 def forward_pipelined(

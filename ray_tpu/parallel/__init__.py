@@ -21,7 +21,7 @@ from ray_tpu.parallel.mesh import (
 from ray_tpu.parallel.ring_attention import ring_attention
 from ray_tpu.parallel.ulysses import ulysses_attention
 from ray_tpu.parallel.pipeline import pipeline_stages
-from ray_tpu.parallel.moe import moe_layer, top_k_routing
+from ray_tpu.parallel.moe import grouped_matmul, moe_block
 
 __all__ = [
     "MeshConfig",
@@ -33,6 +33,6 @@ __all__ = [
     "ring_attention",
     "ulysses_attention",
     "pipeline_stages",
-    "moe_layer",
-    "top_k_routing",
+    "grouped_matmul",
+    "moe_block",
 ]

@@ -1,91 +1,128 @@
-"""Mixture-of-experts with expert parallelism.
+"""The mixture-of-experts block: dropless, sorted, grouped matmuls.
 
-The reference has no MoE/EP support (SURVEY.md §2.4: EP "Absent"). This is
-the TPU-native design: experts shard over the "ep" mesh axis; tokens are
-routed top-k with a capacity factor and dispatched via einsum against
-one-hot combine tensors (the Switch/GShard formulation), which XLA lowers
-to all-to-alls over ICI when the expert dim is sharded.
+The reference has no MoE support (SURVEY.md §2.4: EP "Absent"). One
+function serves the training layer, the cached forward and the engine's
+step programs. A token's router picks its `experts_per_token` experts in
+float32; the `tokens x k` assignments are sorted by expert, so that each
+expert's rows lie together; the three expert products run as grouped
+matmuls over the `[E, d, ff]` stacks in the weights' dtype with float32
+accumulation; the rows go back to token order, weighted, and are summed per
+token. Every assignment is computed: there is no capacity and nothing is
+dropped.
+
+The grouped matmul is the Pallas kernel JAX ships
+(`jax.experimental.pallas.ops.tpu.megablox`): it visits only the groups
+that have rows, so a decode step reads the experts it hit and no others.
+`jax.lax.ragged_dot` lowers to the same kind of kernel on the TPU with a
+row tile of the compiler's choosing, which at a prefill chunk's 512 rows
+is compute-bound on masked rows: 2.53 against 1.15 ms a layer at OLMoE's
+sizes, and 1.15 against 0.96 ms at a decode step's 128 rows (chip, PR 27;
+PERF.md section 6). Off the TPU the kernel runs in Pallas's interpreter,
+so the CPU tests run the same code.
+
+Under a mesh with `ep > 1` the same function runs on expert stacks sharded
+over "ep"; placing the kernel per shard belongs to the expert-parallel
+work (ROADMAP.md B6).
 """
 
 from __future__ import annotations
 
-from typing import Callable, Tuple
+from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 
-
-def top_k_routing(
-    router_logits: jax.Array,  # [tokens, num_experts]
-    k: int,
-    capacity: int,
-) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """Top-k token->expert assignment with per-expert capacity.
-
-    Returns:
-      dispatch: [tokens, num_experts, capacity] one-hot dispatch mask
-      combine:  [tokens, num_experts, capacity] combine weights
-      aux_loss: load-balancing auxiliary loss (Switch-style)
-    """
-    tokens, num_experts = router_logits.shape
-    probs = jax.nn.softmax(router_logits, axis=-1)
-
-    # Load-balance loss: mean prob * mean assignment fraction per expert.
-    top1 = jnp.argmax(probs, axis=-1)
-    me = probs.mean(axis=0)
-    ce = jnp.mean(jax.nn.one_hot(top1, num_experts), axis=0)
-    aux_loss = num_experts * jnp.sum(me * ce)
-
-    dispatch = jnp.zeros((tokens, num_experts, capacity), dtype=probs.dtype)
-    combine = jnp.zeros((tokens, num_experts, capacity), dtype=probs.dtype)
-    remaining = probs
-    # Track how many slots each expert has filled so far across the k picks.
-    fill = jnp.zeros((num_experts,), dtype=jnp.int32)
-    for _ in range(k):
-        choice = jnp.argmax(remaining, axis=-1)  # [tokens]
-        gate = jnp.take_along_axis(remaining, choice[:, None], axis=-1)[:, 0]
-        onehot = jax.nn.one_hot(choice, num_experts, dtype=jnp.int32)
-        # Position of each token within its chosen expert's queue.
-        pos_in_expert = (jnp.cumsum(onehot, axis=0) - 1) * onehot
-        pos = (pos_in_expert.sum(axis=-1) + fill[choice]).astype(jnp.int32)
-        keep = pos < capacity
-        pos = jnp.clip(pos, 0, capacity - 1)
-        tok_idx = jnp.arange(tokens)
-        dispatch = dispatch.at[tok_idx, choice, pos].add(
-            keep.astype(probs.dtype)
-        )
-        combine = combine.at[tok_idx, choice, pos].add(
-            keep.astype(probs.dtype) * gate
-        )
-        fill = fill + (onehot * keep[:, None]).sum(axis=0)
-        # Mask out the chosen expert for the next pick.
-        remaining = remaining * (1.0 - onehot.astype(probs.dtype))
-    return dispatch, combine, aux_loss
+EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+# The kernel's tiles: rows of one tile belong to at most a few experts, and
+# the weight tile is what a group with rows costs to read. Of (128, 1024,
+# 1024), (128, 2048, 512), (128, 512, 1024) and (128, 1024, 512) the first
+# was fastest at 128 and at 512 rows (chip, PR 27).
+_ROW_TILE = 128
+_WEIGHT_TILE = 1024
 
 
-def moe_layer(
-    x: jax.Array,  # [tokens, d_model]
-    router_w: jax.Array,  # [d_model, num_experts]
-    expert_fn: Callable,  # (expert_params, [num_experts, capacity, d]) -> same
-    expert_params,  # leaves with leading num_experts axis (sharded over "ep")
-    k: int = 2,
-    capacity_factor: float = 1.25,
-):
-    """Dense-dispatch MoE layer (GShard formulation).
+def grouped_matmul(x: jax.Array, w: jax.Array,
+                   group_sizes: jax.Array) -> jax.Array:
+    """`x [m, k]` whose rows lie grouped, in the order of `w [G, k, n]`'s
+    groups and `group_sizes [G]` long each, times each row's own group's
+    matrix: `[m, n]` float32. Groups without rows are not read."""
+    from jax.experimental.pallas.ops.tpu.megablox import gmm
 
-    The einsum dispatch produces [num_experts, capacity, d_model]; with
-    expert_params sharded over "ep", XLA inserts the all-to-alls.
-    """
-    tokens, d_model = x.shape
-    num_experts = router_w.shape[-1]
-    capacity = max(1, int(capacity_factor * tokens * k / num_experts))
+    m, k = x.shape
+    tile_m = min(_ROW_TILE, -(-m // 8) * 8)
+    # Rows past the groups' total belong to no group and come back zero.
+    x = jnp.pad(x, ((0, -m % tile_m), (0, 0)))
+    out = gmm(x, w, group_sizes, preferred_element_type=jnp.float32,
+              tiling=(tile_m, min(k, _WEIGHT_TILE),
+                      min(w.shape[-1], _WEIGHT_TILE)),
+              interpret=jax.default_backend() != "tpu")
+    return out[:m]
 
-    logits = x @ router_w
-    dispatch, combine, aux_loss = top_k_routing(logits, k, capacity)
 
-    # Dispatch: [E, C, D]
-    expert_in = jnp.einsum("tec,td->ecd", dispatch, x)
-    expert_out = expert_fn(expert_params, expert_in)
-    # Combine: [T, D]
-    out = jnp.einsum("tec,ecd->td", combine, expert_out)
-    return out, aux_loss
+def load_balancing_loss(prob_mean: jax.Array, counts: jax.Array,
+                        tokens: int) -> jax.Array:
+    """E * sum_e P_e * sum_j f_{j,e}: the published load-balancing term
+    (Switch's, as Mixtral's and OLMoE's modelling code has it, before its
+    coefficient). `prob_mean [.., E]` is the router probability of each
+    expert averaged over the tokens, `counts [.., E]` the assignments each
+    expert received from those `tokens` tokens, so counts / tokens is
+    sum_j f_{j,e}, the shares of tokens whose j-th choice is e. Leading
+    axes (layers) are averaged first: the published code concatenates every
+    layer's router outputs and takes one mean. Even routing gives k."""
+    num_experts = prob_mean.shape[-1]
+    p = prob_mean.reshape(-1, num_experts).mean(0)
+    f = (counts.astype(jnp.float32) / tokens).reshape(-1, num_experts).mean(0)
+    return num_experts * jnp.sum(p * f)
+
+
+def moe_block(h: jax.Array, lp: Dict, cfg,
+              layer: Optional[jax.Array] = None) -> Tuple[jax.Array, Dict]:
+    """The expert half of a layer on normed activations `h [tokens, d]`.
+
+    `lp` holds the layer's `router [d, E]` and the expert stacks `w_gate`,
+    `w_up [E, d, ff]`, `w_down [E, ff, d]`. A caller that walks the depth
+    axis by index passes `layer` and the whole model's stacks `[L, E, ..]`
+    instead: they are then read in place as `L * E` groups of which only
+    this layer's have rows. A kernel takes its operands whole, so a layer
+    sliced out of the stack inside a loop is first copied: 0.8 GB a layer
+    at OLMoE's sizes, 3.3 against 0.96 ms (compiler and chip, PR 27).
+
+    Returns `(y [tokens, d] in h's dtype, stats)`; `stats["counts"]` is the
+    assignments each expert received `[E] int32`, `stats["prob_mean"]` its
+    mean router probability `[E] float32` (what the load-balancing term is
+    made of) and `stats["experts"]` each token's choices `[tokens, k]`."""
+    from ray_tpu.models.transformer import _act
+
+    tokens = h.shape[0]
+    k, num_experts = cfg.experts_per_token, cfg.num_experts
+    with jax.named_scope("moe.route"):
+        logits = jnp.dot(h, lp["router"], preferred_element_type=jnp.float32)
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, chosen = jax.lax.top_k(probs, k)            # [tokens, k]
+        if cfg.norm_topk_prob:
+            weights = weights / weights.sum(-1, keepdims=True)
+        flat = chosen.reshape(-1)
+        # Assignments in expert order; ties keep token order (stable).
+        order = jnp.argsort(flat, stable=True)
+        counts = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+        rows = h[order // k]                                 # [tokens*k, d]
+    with jax.named_scope("moe.experts"):
+        stacks = [lp[name] for name in EXPERT_LEAVES]
+        group_sizes = counts
+        if layer is not None:
+            depth = stacks[0].shape[0]
+            stacks = [w.reshape((depth * num_experts,) + w.shape[2:])
+                      for w in stacks]
+            group_sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((depth * num_experts,), jnp.int32), counts,
+                (layer * num_experts,))
+        w_gate, w_up, w_down = stacks
+        inner = (_act(cfg)(grouped_matmul(rows, w_gate, group_sizes))
+                 * grouped_matmul(rows, w_up, group_sizes))
+        out = grouped_matmul(inner.astype(h.dtype), w_down, group_sizes)
+    with jax.named_scope("moe.combine"):
+        # Back to (token, choice) order, weighted, summed over the choices.
+        out = out[jnp.argsort(order)].reshape(tokens, k, -1)
+        y = jnp.einsum("tk,tkd->td", weights, out).astype(h.dtype)
+    return y, {"counts": counts, "prob_mean": probs.mean(0),
+               "experts": chosen}

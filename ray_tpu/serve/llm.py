@@ -66,8 +66,10 @@ from ray_tpu.models.transformer import (
     _act,
     _embed_tokens,
     project_logits,
+    project_qkv,
 )
 from ray_tpu.ops import apply_rope, rmsnorm, rope_frequencies
+from ray_tpu.parallel.moe import EXPERT_LEAVES, moe_block
 from ray_tpu.util.compile_cache import compile_events
 from ray_tpu.util.device_peaks import device_report
 
@@ -184,22 +186,21 @@ def _grouped_attention(q, kf, vf, valid):
 
 
 def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
-                write_kv, valid, mesh=None):
+                write_kv, valid, mesh=None, layer=None):
     """One transformer layer shared by slotted decode and prefill.
 
     The two callers differ only in how K/V land in the cache and what
     the attention source/mask is: `write_kv(kc, vc, k, v) -> (kc, vc,
     k_att, v_att)` encapsulates that, `valid` is the caller's mask over
     (B, Lq, Lk_att). `mesh` is the engine's: activations are replicated
-    over it, so the norm kernel runs whole on every device."""
+    over it, so the norm kernel runs whole on every device. Returns the
+    layer's output, its caches and, for a model with experts, the
+    assignments each expert received `[E]` (else None); `layer` is
+    `moe_block`'s: the index at which `lp`'s expert stacks, then the
+    whole model's, are read in place."""
     b, l = x.shape[:2]
     h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh)
-    q = (h @ lp["wq"]).reshape(b, l, cfg.n_heads, cfg.head_dim)
-    k = (h @ lp["wk"]).reshape(b, l, cfg.n_kv_heads, cfg.head_dim)
-    v = (h @ lp["wv"]).reshape(b, l, cfg.n_kv_heads, cfg.head_dim)
-    if cfg.qk_norm:
-        q = rmsnorm(q, lp["q_norm"], cfg.norm_eps, use_pallas=False)
-        k = rmsnorm(k, lp["k_norm"], cfg.norm_eps, use_pallas=False)
+    q, k, v = project_qkv(h, lp, cfg)
     q = apply_rope(q, cos, sin, positions)
     k = apply_rope(k, cos, sin, positions)
     k_cache_l, v_cache_l, k_att, v_att = write_kv(k_cache_l, v_cache_l, k, v)
@@ -208,10 +209,14 @@ def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
     )
     x = x + (attn.reshape(b, l, -1) @ lp["wo"]).astype(x.dtype)
     h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh)
+    if cfg.num_experts:
+        y, routing = moe_block(h.reshape(b * l, -1), lp, cfg, layer)
+        return (x + y.reshape(b, l, -1), k_cache_l, v_cache_l,
+                routing["counts"])
     gate = _act(cfg)((h @ lp["w_gate"]).astype(jnp.float32))
     up = (h @ lp["w_up"]).astype(jnp.float32)
     x = x + (((gate * up).astype(x.dtype)) @ lp["w_down"])
-    return x, k_cache_l, v_cache_l
+    return x, k_cache_l, v_cache_l, None
 
 
 def _scan_layers(params, x, k_cache, v_cache, write_kv, cfg, cos, sin,
@@ -226,21 +231,60 @@ def _scan_layers(params, x, k_cache, v_cache, write_kv, cfg, cos, sin,
     caches donated each layer's rows are scattered into the caller's own
     buffer. Scanned over as `xs` and stacked back as `ys` a cache is two
     buffers: every layer is sliced out of one and written into the
-    other, and the result copied back over the donated argument."""
+    other, and the result copied back over the donated argument.
+
+    The expert stacks of a model that has them stay out of the scan for
+    the same reason: every layer reads them whole at its own index
+    (`moe_block`), where a layer sliced out for a grouped matmul would be
+    copied first. Also returns the assignments each layer's experts
+    received in this call `[layers, E]`, None for a dense model."""
+    layers, experts = params["layers"], {}
+    if cfg.num_experts:
+        experts = {n: layers[n] for n in EXPERT_LEAVES}
+        layers = {n: w for n, w in layers.items() if n not in experts}
 
     def layer(carry, inputs):
         x, kc, vc = carry
         lp, i = inputs
-        return _layer_body(
-            x, lp, kc, vc, cfg, cos, sin, positions,
+        x, kc, vc, counts = _layer_body(
+            x, {**lp, **experts}, kc, vc, cfg, cos, sin, positions,
             functools.partial(write_kv, i), valid, mesh,
-        ), None
+            i if experts else None,
+        )
+        return (x, kc, vc), counts
 
     index = jnp.arange(k_cache.shape[0], dtype=jnp.int32)
-    (x, k_cache, v_cache), _ = jax.lax.scan(
-        layer, (x, k_cache, v_cache), (params["layers"], index)
+    (x, k_cache, v_cache), counts = jax.lax.scan(
+        layer, (x, k_cache, v_cache), (layers, index)
     )
-    return x, k_cache, v_cache
+    return x, k_cache, v_cache, counts
+
+
+def init_routing_counters(cfg: TransformerConfig) -> Dict:
+    """The device-resident accumulator of a model with experts: what its
+    step programs add to at every call and `engine.stats()["moe"]` fetches,
+    so that nothing about routing leaves the device inside the loop."""
+    per_layer = jnp.zeros((cfg.n_layers,), jnp.int32)
+    return {
+        "assignments": jnp.zeros((cfg.n_layers, cfg.num_experts), jnp.int32),
+        "calls": jnp.zeros((), jnp.int32),
+        "experts_hit_sum": per_layer,
+        "max_load_sum": per_layer,
+    }
+
+
+def _count_routing(out, moe, counts):
+    """A step program's results with the routing accumulator `moe`
+    advanced by this call's `counts [layers, E]` appended; a caller that
+    passed no accumulator gets `out` as it is."""
+    if moe is None:
+        return out
+    return (*out, {
+        "assignments": moe["assignments"] + counts,
+        "calls": moe["calls"] + 1,
+        "experts_hit_sum": moe["experts_hit_sum"] + (counts > 0).sum(-1),
+        "max_load_sum": moe["max_load_sum"] + counts.max(-1),
+    })
 
 
 MAX_TOP_K = 64  # per-slot top-k cap (static shape for lax.top_k)
@@ -278,7 +322,7 @@ def _pick_tokens(logits, temps, top_ks, top_ps, key):
 
 def _decode_slots(params, tokens, k_cache, v_cache, lengths, active,
                   temps, top_ks, top_ps, key,
-                  cfg: TransformerConfig, mesh=None):
+                  cfg: TransformerConfig, mesh=None, moe=None):
     """One decode step for every slot at once.
 
     tokens [S] int32 (last emitted per slot; 0 for inactive), lengths
@@ -286,6 +330,8 @@ def _decode_slots(params, tokens, k_cache, v_cache, lengths, active,
     (next_tokens [S], k_cache, v_cache, new_lengths): caches updated
     in place at each ACTIVE slot's own position; inactive slots write
     into their top spare row (masked out forever) and keep their length.
+    With `moe`, a model with experts' routing accumulator, the advanced
+    accumulator comes back as a fifth result (`_count_routing`).
     """
     s_ = tokens.shape[0]
     lmax = k_cache.shape[2]
@@ -305,7 +351,7 @@ def _decode_slots(params, tokens, k_cache, v_cache, lengths, active,
         vc = vc.at[i, slot_idx, write_at].set(v[:, 0].astype(vc.dtype))
         return kc, vc, kc[i], vc[i]  # attend against the layer's cache
 
-    x, k_new, v_new = _scan_layers(
+    x, k_new, v_new, counts = _scan_layers(
         params, x, k_cache, v_cache, write_kv, cfg, cos, sin, positions,
         valid, mesh,
     )
@@ -321,11 +367,12 @@ def _decode_slots(params, tokens, k_cache, v_cache, lengths, active,
         next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     else:
         next_tokens = _pick_tokens(logits, temps, top_ks, top_ps, key)
-    return next_tokens, k_new, v_new, new_lengths
+    return _count_routing((next_tokens, k_new, v_new, new_lengths), moe,
+                          counts)
 
 
 def _prefill_chunk(params, tokens, n_valid, slot, offset, k_cache, v_cache,
-                   lengths, cfg: TransformerConfig, mesh=None):
+                   lengths, cfg: TransformerConfig, mesh=None, moe=None):
     """CHUNKED prefill: process one fixed-size chunk of a prompt into
     slot `slot` at row `offset` — the scheme that lets a long prompt's
     prefill interleave with other slots' decode steps instead of
@@ -360,7 +407,7 @@ def _prefill_chunk(params, tokens, n_valid, slot, offset, k_cache, v_cache,
         # Attend against the slot's whole cache row range (masked).
         return kc, vc, kc[i, slot][None], vc[i, slot][None]
 
-    x, k_new, v_new = _scan_layers(
+    x, k_new, v_new, counts = _scan_layers(
         params, x, k_cache, v_cache, write_kv, cfg, cos, sin, positions,
         valid, mesh,
     )
@@ -368,7 +415,7 @@ def _prefill_chunk(params, tokens, n_valid, slot, offset, k_cache, v_cache,
     last = jax.lax.dynamic_slice(x, (0, n_valid - 1, 0), (1, 1, x.shape[-1]))
     logits = project_logits(last[:, 0], params, cfg)
     new_lengths = lengths.at[slot].set(offset + n_valid)
-    return logits, k_new, v_new, new_lengths
+    return _count_routing((logits, k_new, v_new, new_lengths), moe, counts)
 
 
 class GenerationHandle:
@@ -675,27 +722,36 @@ class ContinuousBatchingEngine:
         cache = self._fresh_cache()
         self._k, self._v = cache["k"], cache["v"]
         self._lengths = cache["lengths"]
+        # A model with experts: its routing accumulator, which every step
+        # program takes last and returns last (`*moe` below; nothing for a
+        # dense model, whose programs are then what they were). Carried
+        # like the cache but not donated: stats() reads it from another
+        # thread, and an array no program consumes can be fetched at any
+        # time. Written by the loop thread only.
+        self._moe = ([jax.tree.map(self._replicated,
+                                   init_routing_counters(cfg))]
+                     if cfg.num_experts else [])
         if self._paged:
             self._bt_dev = cache["block_tables"]
             self._decode_sampled = jax.jit(
-                lambda p, t, k, v, ln, a, bt, tp, tk, tpp, key:
+                lambda p, t, k, v, ln, a, bt, tp, tk, tpp, key, *moe:
                 paged_kv.decode_paged(
                     p, t, k, v, ln, a, bt, tp, tk, tpp, key, cfg, max_len,
-                    mesh,
+                    mesh, *moe,
                 ),
                 donate_argnums=(2, 3),
             )
             self._decode_greedy = jax.jit(
-                lambda p, t, k, v, ln, a, bt: paged_kv.decode_paged(
+                lambda p, t, k, v, ln, a, bt, *moe: paged_kv.decode_paged(
                     p, t, k, v, ln, a, bt, None, None, None, None, cfg,
-                    max_len, mesh,
+                    max_len, mesh, *moe,
                 ),
                 donate_argnums=(2, 3),
             )
             self._prefill = jax.jit(
-                lambda p, t, n, s, o, k, v, ln, bt:
+                lambda p, t, n, s, o, k, v, ln, bt, *moe:
                 paged_kv.prefill_chunk_paged(
-                    p, t, n, s, o, k, v, ln, bt, cfg, max_len, mesh
+                    p, t, n, s, o, k, v, ln, bt, cfg, max_len, mesh, *moe
                 ),
                 donate_argnums=(5, 6),
             )
@@ -704,20 +760,22 @@ class ContinuousBatchingEngine:
             )
         else:
             self._decode_sampled = jax.jit(
-                lambda p, t, k, v, ln, a, tp, tk, tpp, key: _decode_slots(
-                    p, t, k, v, ln, a, tp, tk, tpp, key, cfg, mesh
+                lambda p, t, k, v, ln, a, tp, tk, tpp, key, *moe:
+                _decode_slots(
+                    p, t, k, v, ln, a, tp, tk, tpp, key, cfg, mesh, *moe
                 ),
                 donate_argnums=(2, 3),
             )
             self._decode_greedy = jax.jit(
-                lambda p, t, k, v, ln, a: _decode_slots(
-                    p, t, k, v, ln, a, None, None, None, None, cfg, mesh
+                lambda p, t, k, v, ln, a, *moe: _decode_slots(
+                    p, t, k, v, ln, a, None, None, None, None, cfg, mesh,
+                    *moe
                 ),
                 donate_argnums=(2, 3),
             )
             self._prefill = jax.jit(
-                lambda p, t, n, s, o, k, v, ln: _prefill_chunk(
-                    p, t, n, s, o, k, v, ln, cfg, mesh
+                lambda p, t, n, s, o, k, v, ln, *moe: _prefill_chunk(
+                    p, t, n, s, o, k, v, ln, cfg, mesh, *moe
                 ),
                 donate_argnums=(5, 6),
             )
@@ -827,44 +885,53 @@ class ContinuousBatchingEngine:
         pad = np.zeros((1, self.prefill_chunk), dtype=np.int32)
         one, zero = np.int32(1), np.int32(0)
         if self._paged:
-            (_, self._k, self._v, self._lengths) = self._decode_greedy(
+            (_, self._k, self._v, self._lengths,
+             *self._moe) = self._decode_greedy(
                 self.params, self._tokens_dev, self._k, self._v,
-                self._lengths, self._active_dev, self._bt_dev,
+                self._lengths, self._active_dev, self._bt_dev, *self._moe,
             )
-            (_, self._k, self._v, self._lengths) = self._decode_sampled(
+            (_, self._k, self._v, self._lengths,
+             *self._moe) = self._decode_sampled(
                 self.params, self._tokens_dev, self._k, self._v,
                 self._lengths, self._active_dev, self._bt_dev,
                 self._temps_dev, self._top_ks_dev, self._top_ps_dev, k1,
+                *self._moe,
             )
-            logits, self._k, self._v, self._lengths = self._prefill(
+            (logits, self._k, self._v, self._lengths,
+             *self._moe) = self._prefill(
                 self.params, pad, one, zero, zero,
-                self._k, self._v, self._lengths, self._bt_dev,
+                self._k, self._v, self._lengths, self._bt_dev, *self._moe,
             )
             # Warm the copy-on-write page fork too (NULL page onto
             # itself: contents never observable).
             self._k, self._v = self._cow(self._k, self._v, zero, zero)
         else:
-            (_, self._k, self._v, self._lengths) = self._decode_greedy(
+            (_, self._k, self._v, self._lengths,
+             *self._moe) = self._decode_greedy(
                 self.params, self._tokens_dev, self._k, self._v,
-                self._lengths, self._active_dev,
+                self._lengths, self._active_dev, *self._moe,
             )
-            (_, self._k, self._v, self._lengths) = self._decode_sampled(
+            (_, self._k, self._v, self._lengths,
+             *self._moe) = self._decode_sampled(
                 self.params, self._tokens_dev, self._k, self._v,
                 self._lengths, self._active_dev, self._temps_dev,
-                self._top_ks_dev, self._top_ps_dev, k1,
+                self._top_ks_dev, self._top_ps_dev, k1, *self._moe,
             )
-            logits, self._k, self._v, self._lengths = self._prefill(
+            (logits, self._k, self._v, self._lengths,
+             *self._moe) = self._prefill(
                 self.params, pad, one, zero, zero,
-                self._k, self._v, self._lengths,
+                self._k, self._v, self._lengths, *self._moe,
             )
         # Both first-token variants, then the token buffer as it was.
         tokens = self._tokens_dev
         self._first_token(logits, 0, 0.5, 1, 1.0)
         self._first_token(logits, 0, 0.0, 0, 1.0)
         self._tokens_dev = tokens
-        # Undo the warmup prefill's lengths[0] = 1 (device-side, keeps
-        # the mesh sharding of the lengths array).
+        # Undo the warmup prefill's lengths[0] = 1 and what warm-up
+        # counted of routing (device-side, keeps the mesh sharding of
+        # the arrays).
         self._lengths = self._lengths * 0
+        self._moe = jax.tree.map(lambda a: a * 0, self._moe)
         jax.block_until_ready(self._lengths)
 
     # Single-writer: rng and token buffer are engine-thread-owned.
@@ -1131,17 +1198,41 @@ class ContinuousBatchingEngine:
             chunk = prompt[off:off + c]
             padded = np.zeros((1, c), dtype=np.int32)
             padded[0, :len(chunk)] = chunk
-            logits, k, v, lengths = self._prefill(
+            logits, k, v, lengths, *_ = self._prefill(
                 self.params, padded, np.int32(len(chunk)),
                 np.int32(0), np.int32(off), k, v, lengths, *table,
+                *self._moe,
             )
         return np.asarray(logits, dtype=np.float32)[0]
 
+    def _moe_stats(self) -> Dict:
+        """stats()["moe"]: the routing accumulator, fetched now (the one
+        place it leaves the device). Cumulative since warm-up, over every
+        call of a step program and every row it computed, a prefill
+        chunk's padding and idle slots included, since those rows' experts
+        are read too: `assignments` rows x k x layers; `calls`;
+        `experts_hit_sum` and `max_load_sum` summed over calls and layers
+        (over calls x layers: a layer's mean experts hit and its largest
+        expert's mean load); `per_expert [E]` assignments summed over
+        layers."""
+        acc = jax.device_get(self._moe[0])
+        per_layer_expert = acc["assignments"].astype(np.int64)
+        return {
+            "assignments": int(per_layer_expert.sum()),
+            "calls": int(acc["calls"]),
+            "experts_hit_sum": int(acc["experts_hit_sum"].sum()),
+            "max_load_sum": int(acc["max_load_sum"].sum()),
+            "per_expert": per_layer_expert.sum(0).tolist(),
+        }
+
     def stats(self) -> Dict:
         compiles = compile_events() - self._compiles_base
+        # Before the lock: the fetch waits for the step in flight.
+        moe = {"moe": self._moe_stats()} if self._moe else {}
         with self._lock:
             ts = max(self._timed_steps, 1)
             return {
+                **moe,
                 # The device this engine's programs run on, as JAX
                 # reports it in this process, and what warm-up cost.
                 "device": self._device,
@@ -1451,10 +1542,11 @@ class ContinuousBatchingEngine:
                 padded = np.zeros((1, c), dtype=np.int32)
                 padded[0, :n] = chunk
                 table = (self._bt_dev,) if self._paged else ()
-                logits, self._k, self._v, self._lengths = self._prefill(
+                (logits, self._k, self._v, self._lengths,
+                 *self._moe) = self._prefill(
                     self.params, padded,
                     np.int32(n), np.int32(slot), np.int32(off),
-                    self._k, self._v, self._lengths, *table,
+                    self._k, self._v, self._lengths, *table, *self._moe,
                 )
                 entry["offset"] = off + n
                 if entry["offset"] < len(h.prompt):
@@ -1581,20 +1673,20 @@ class ContinuousBatchingEngine:
                 table = (self._bt_dev,) if self._paged else ()
                 if self._sampled_active:
                     self._rng, step_key = jax.random.split(self._rng)
-                    (next_dev, self._k, self._v,
-                     self._lengths) = self._decode_sampled(
+                    (next_dev, self._k, self._v, self._lengths,
+                     *self._moe) = self._decode_sampled(
                         self.params, self._tokens_dev,
                         self._k, self._v, self._lengths,
                         self._active_dev, *table,
                         self._temps_dev, self._top_ks_dev,
-                        self._top_ps_dev, step_key,
+                        self._top_ps_dev, step_key, *self._moe,
                     )
                 else:
-                    (next_dev, self._k, self._v,
-                     self._lengths) = self._decode_greedy(
+                    (next_dev, self._k, self._v, self._lengths,
+                     *self._moe) = self._decode_greedy(
                         self.params, self._tokens_dev,
                         self._k, self._v, self._lengths,
-                        self._active_dev, *table,
+                        self._active_dev, *table, *self._moe,
                     )
                 self._tokens_dev = next_dev
                 # Start the D2H copy NOW: it lands while this thread

@@ -306,7 +306,7 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
 
 def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
                  block_tables, temps, top_ks, top_ps, key,
-                 cfg: TransformerConfig, max_len: int, mesh=None):
+                 cfg: TransformerConfig, max_len: int, mesh=None, moe=None):
     """One decode step for every slot, K/V gathered through the block
     table — the paged twin of llm._decode_slots (same contract: same
     inputs plus the table, same outputs).
@@ -318,7 +318,7 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
     pages_per_slot * page_size) and masks by length, exactly like the
     slotted step masks its `max_len` row."""
     from ray_tpu.serve.llm import (  # local import: llm imports us too
-        _pick_tokens, _scan_layers,
+        _count_routing, _pick_tokens, _scan_layers,
     )
 
     s_ = tokens.shape[0]
@@ -349,7 +349,7 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
         v_att = vc[i, block_tables].reshape(s_, width, kvh, hd)
         return kc, vc, k_att, v_att
 
-    x, k_new, v_new = _scan_layers(
+    x, k_new, v_new, counts = _scan_layers(
         params, x, k_pages, v_pages, write_kv, cfg, cos, sin, positions,
         valid, mesh,
     )
@@ -360,12 +360,14 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
         next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     else:
         next_tokens = _pick_tokens(logits, temps, top_ks, top_ps, key)
-    return next_tokens, k_new, v_new, new_lengths
+    return _count_routing((next_tokens, k_new, v_new, new_lengths), moe,
+                          counts)
 
 
 def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
                         v_pages, lengths, block_tables,
-                        cfg: TransformerConfig, max_len: int, mesh=None):
+                        cfg: TransformerConfig, max_len: int, mesh=None,
+                        moe=None):
     """Chunked prefill into pages — the paged twin of
     llm._prefill_chunk. Chunk rows scatter into the pages the slot's
     block-table row names (padding rows and anything past `max_len`
@@ -375,7 +377,9 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     Prefix-cache resumption needs nothing special here: the engine
     starts `offset` at the shared-prefix boundary and the gathered
     pages already hold the donor's K/V rows below it."""
-    from ray_tpu.serve.llm import _scan_layers  # local import (cycle)
+    from ray_tpu.serve.llm import (  # local import (cycle)
+        _count_routing, _scan_layers,
+    )
 
     _, c = tokens.shape
     ps = k_pages.shape[2]
@@ -402,7 +406,7 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
         v_att = vc[i, bt_row].reshape(1, width, kvh, hd)
         return kc, vc, k_att, v_att
 
-    x, k_new, v_new = _scan_layers(
+    x, k_new, v_new, counts = _scan_layers(
         params, x, k_pages, v_pages, write_kv, cfg, cos, sin, positions,
         valid, mesh,
     )
@@ -410,7 +414,7 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     last = jax.lax.dynamic_slice(x, (0, n_valid - 1, 0), (1, 1, x.shape[-1]))
     logits = project_logits(last[:, 0], params, cfg)
     new_lengths = lengths.at[slot].set(offset + n_valid)
-    return logits, k_new, v_new, new_lengths
+    return _count_routing((logits, k_new, v_new, new_lengths), moe, counts)
 
 
 def cow_copy_page(k_pages, v_pages, src, dst):

@@ -216,3 +216,34 @@ def test_engine_step_updates_its_cache_in_place(v5e, program):
     whole_cache_copies = re.findall(
         rf"= bf16\[{dims}\]\S* copy\(", compiled.as_text())
     assert not whole_cache_copies
+
+
+@pytest.mark.parametrize("program", ["decode_paged", "prefill_chunk_paged"])
+def test_expert_model_step_reads_its_expert_stacks_in_place(
+        v5e, program, monkeypatch):
+    """OLMoE's step programs at its published widths (depth cut to 2): the
+    three grouped products of a layer are the Mosaic kernel, and no layer's
+    experts are copied out of the stack for it. A kernel takes its operands
+    whole, so a layer sliced out of `[L, E, d, ff]` inside the scan is a
+    268 MB temporary a matrix, 0.8 GB a layer (3.3 against 0.96 ms on the
+    chip, PERF.md section 6); `_scan_layers` keeps the stacks out of the
+    scan and `moe_block` reads them at the layer's index."""
+    # The program asks the backend which branch to take; the chip's is
+    # the kernel outside Pallas's interpreter.
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(configs.get_config("olmoe-1b-7b"), n_layers=2)
+    fn, donated, args, _ = _engine_program(
+        program, cfg, SingleDeviceSharding(v5e[0]))
+    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = f32\[", text)) == 3
+    # Sliced out for a kernel, a layer's matrix is the result of a fusion
+    # (`dynamic-slice_bitcast_fusion`) or a copy, `bf16[E, d, ff]`.
+    for inner in (f"{cfg.d_model},{cfg.d_ff}", f"{cfg.d_ff},{cfg.d_model}"):
+        stack = f"{cfg.num_experts},{inner}"
+        assert not re.findall(
+            rf"= bf16\[(?:1,)?{stack}\]\S* (?:copy|fusion)\(", text)
+    # What is left among the temporaries is attention's: the gathered
+    # cache in float32, under one expert matrix's 268 MB at these slots.
+    one_matrix = 2 * cfg.num_experts * cfg.d_model * cfg.d_ff
+    assert compiled.memory_analysis().temp_size_in_bytes < one_matrix

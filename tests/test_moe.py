@@ -1,0 +1,326 @@
+"""The expert block and the model built on it (OLMoE's layer at toy size,
+`tiny_olmoe`), on the CPU with seeded float32 weights: the block against a
+per-token loop, the engine and the train step against the benchmark's plain
+reference `bench/reference/olmoe.py`, and the routing counters.
+
+Tolerances: both sides compute in float32 here, so they differ by the order
+of accumulation alone: 1e-6 of a logit's size, 1e-5 after two layers of
+sums. Each limit below is 1e-4 or tighter: a hundred times that, and a
+hundred times under what bfloat16 anywhere on the path (3e-3 a rounding,
+1e-2 after two layers) or a wrong term would give."""
+
+import os
+import sys
+from dataclasses import replace
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from ray_tpu.models import configs, forward, init_params, loss_fn
+from ray_tpu.models.generate import generate
+from ray_tpu.parallel.moe import load_balancing_loss, moe_block
+from ray_tpu.serve.llm import ContinuousBatchingEngine
+
+BENCH = os.path.join(os.path.dirname(os.path.dirname(
+    os.path.abspath(__file__))), "bench")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+from reference import olmoe as reference  # noqa: E402
+
+CFG = configs.get_config("tiny_olmoe")
+DIMS = {
+    "vocab_size": CFG.vocab_size, "d_model": CFG.d_model, "d_ff": CFG.d_ff,
+    "n_layers": CFG.n_layers, "n_heads": CFG.n_heads,
+    "n_kv_heads": CFG.n_kv_heads, "head_dim": CFG.head_dim,
+    "norm_eps": CFG.norm_eps, "rope_theta": CFG.rope_theta,
+    "num_experts": CFG.num_experts,
+    "experts_per_token": CFG.experts_per_token,
+    "norm_topk_prob": CFG.norm_topk_prob,
+}
+TOLERANCE = 1e-4
+
+
+def seeded_params(cfg=CFG, seed=0):
+    """`init_params` with the norm scales drawn too (ones would hide a
+    QK-norm taken over the wrong extent behind its scale's symmetry)."""
+    params = init_params(jax.random.PRNGKey(seed), cfg)
+    key = jax.random.PRNGKey(seed + 1)
+    for i, name in enumerate(("attn_norm", "mlp_norm", "q_norm", "k_norm")):
+        leaf = params["layers"][name]
+        params["layers"][name] = jax.random.uniform(
+            jax.random.fold_in(key, i), leaf.shape, leaf.dtype, 0.5, 1.5)
+    return params
+
+
+def layer_of(params, i=0):
+    return jax.tree.map(lambda a: a[i], params["layers"])
+
+
+def per_token_loop(h, lp, cfg):
+    """sum_j w_j * expert_{e_j}(h_t), a token and a choice at a time."""
+    h = np.asarray(h, np.float64)
+    lp = jax.tree.map(lambda a: np.asarray(a, np.float64), lp)
+    logits = h @ lp["router"]
+    probs = np.exp(logits - logits.max(-1, keepdims=True))
+    probs /= probs.sum(-1, keepdims=True)
+    out = np.zeros_like(h)
+    counts = np.zeros(cfg.num_experts, np.int64)
+    for t in range(h.shape[0]):
+        top = np.argsort(-probs[t], kind="stable")[:cfg.experts_per_token]
+        w = probs[t, top]
+        if cfg.norm_topk_prob:
+            w = w / w.sum()
+        for e, w_e in zip(top, w):
+            gate = h[t] @ lp["w_gate"][e]
+            inner = gate / (1.0 + np.exp(-gate)) * (h[t] @ lp["w_up"][e])
+            out[t] += w_e * (inner @ lp["w_down"][e])
+            counts[e] += 1
+    return out, counts
+
+
+def routed(lp, favoured, strength=8.0):
+    """`lp` with a router that sends every token whose first feature is 1
+    to the `favoured` experts (and leaves the others without a token)."""
+    router = np.asarray(lp["router"]).copy()
+    router[0, :] = -strength
+    router[0, list(favoured)] = strength
+    return {**lp, "router": jnp.asarray(router)}
+
+
+def block(h, lp, cfg=CFG):
+    return jax.jit(lambda h, lp: moe_block(h, lp, cfg))(h, lp)
+
+
+@pytest.mark.parametrize("case", ["even", "uneven", "empty experts",
+                                  "renormalised"])
+def test_block_equals_a_per_token_loop_over_its_experts(case):
+    cfg = replace(CFG, norm_topk_prob=case == "renormalised")
+    lp = layer_of(seeded_params())
+    h = jax.random.normal(jax.random.PRNGKey(3), (24, cfg.d_model))
+    if case != "even":
+        h = h.at[:, 0].set(1.0)
+    if case == "uneven":        # expert 3 in every token's choice
+        lp = routed(lp, [3], strength=4.0)
+    if case == "empty experts":  # experts 5, 6, 7 never chosen
+        lp = routed(lp, range(5), strength=4.0)
+    y, stats = block(h, lp, cfg)
+    want, counts = per_token_loop(h, lp, cfg)
+    assert np.array_equal(np.asarray(stats["counts"]), counts)
+    assert counts.sum() == 24 * cfg.experts_per_token
+    if case == "uneven":
+        assert counts[3] == 24
+    if case == "empty experts":
+        assert (counts[5:] == 0).all() and (counts[:5] > 0).all()
+    scale = np.sqrt(np.mean(want ** 2))
+    assert np.abs(np.asarray(y) - want).max() / scale < TOLERANCE
+
+
+def test_block_is_the_same_under_a_permutation_of_the_tokens():
+    lp = layer_of(seeded_params())
+    h = jax.random.normal(jax.random.PRNGKey(4), (32, CFG.d_model))
+    perm = jax.random.permutation(jax.random.PRNGKey(5), 32)
+    y, stats = block(h, lp)
+    y_perm, stats_perm = block(h[perm], lp)
+    # A token's row meets the same matrices in the same order wherever it
+    # sorts to, so only the tiles' edges can differ: float32 rounding.
+    np.testing.assert_allclose(np.asarray(y_perm), np.asarray(y)[perm],
+                               rtol=1e-5, atol=1e-6)
+    assert np.array_equal(stats["counts"], stats_perm["counts"])
+
+
+def test_no_assignment_is_lost_when_two_experts_take_every_token():
+    """40 tokens, all on experts 0 and 1 of 8: the capacity the old dispatch
+    gave an expert was 1.25 * 40 * 2 / 8 = 12 rows, and it dropped the
+    other 28 of each."""
+    lp = routed(layer_of(seeded_params()), [0, 1])
+    h = jax.random.normal(jax.random.PRNGKey(6), (40, CFG.d_model))
+    h = h.at[:, 0].set(1.0)
+    y, stats = block(h, lp)
+    assert np.asarray(stats["counts"]).tolist() == [40, 40, 0, 0, 0, 0, 0, 0]
+    assert set(np.asarray(stats["experts"]).reshape(-1).tolist()) == {0, 1}
+    want, _ = per_token_loop(h, lp, CFG)
+    scale = np.sqrt(np.mean(want ** 2))
+    assert np.abs(np.asarray(y) - want).max() / scale < TOLERANCE
+    assert (np.abs(np.asarray(y)).sum(-1) > 0).all()  # every token served
+
+
+def test_block_reads_a_layer_in_place_from_the_whole_stack():
+    params = seeded_params()
+    h = jax.random.normal(jax.random.PRNGKey(7), (16, CFG.d_model))
+    for i in range(CFG.n_layers):
+        lp = layer_of(params, i)
+        y, stats = block(h, lp)
+        whole = {**params["layers"], "router": lp["router"]}
+        y_at, stats_at = jax.jit(
+            lambda h, lp, i: moe_block(h, lp, CFG, i))(h, whole, jnp.int32(i))
+        np.testing.assert_allclose(np.asarray(y_at), np.asarray(y),
+                                   rtol=1e-6, atol=1e-7)
+        assert np.array_equal(stats["counts"], stats_at["counts"])
+
+
+def rel_rms(got, want):
+    got, want = np.asarray(got, np.float64), np.asarray(want, np.float64)
+    return float(np.sqrt(np.mean((got - want) ** 2))
+                 / np.sqrt(np.mean(want ** 2)))
+
+
+def reference_logits(params, tokens):
+    tokens = jnp.asarray(tokens, jnp.int32)
+    hidden = reference.hidden_layerwise(params, tokens, DIMS)
+    return np.asarray(reference.logits_rows(params, hidden, DIMS))
+
+
+def engine_for(params, cfg, **kw):
+    return ContinuousBatchingEngine(params, cfg, num_slots=2, max_len=64,
+                                    prefill_chunk=8, kv_mode="paged",
+                                    page_size=8, **kw)
+
+
+PROMPT = [int(t) for t in np.random.default_rng(11).integers(0, 256, 21)]
+
+
+def test_engine_prefill_then_decode_through_pages_against_the_reference():
+    """Prefill in three chunks into pages, then eight greedy decode steps
+    through the block table, against the reference's one full forward pass
+    over prompt + tokens: the prefill's logits outright, and every served
+    token by how far the reference's logit for it lies under the
+    reference's largest (token equality would hang on float32 near-ties)."""
+    params = seeded_params()
+    eng = engine_for(params, CFG)
+    try:
+        first = eng.prefill_logits(PROMPT)
+        served = eng.submit(PROMPT, max_new_tokens=8).result(timeout=120)
+    finally:
+        eng.shutdown()
+    ref = reference_logits(params, PROMPT + served[:-1])
+    assert rel_rms(first, ref[len(PROMPT) - 1]) < TOLERANCE
+    for i, token in enumerate(served):
+        row = ref[len(PROMPT) - 1 + i]
+        margin = (row.max() - row[token]) / np.sqrt(np.mean(row ** 2))
+        assert margin < TOLERANCE, (i, token, int(row.argmax()), margin)
+
+
+@pytest.mark.parametrize("mistake", ["QK-norm per head",
+                                     "top-k weights renormalised"])
+def test_the_reference_comparison_catches_a_wrong_layer(mistake):
+    """The same comparison, with the program's model wrong in one of the
+    two ways OLMoE differs from the models beside it, reads a hundred
+    times over the limit."""
+    params = seeded_params()
+    if mistake == "QK-norm per head":
+        cfg = replace(CFG, qk_norm_extent="head")
+        wrong = {**params, "layers": {
+            **params["layers"],
+            "q_norm": params["layers"]["q_norm"][:, :CFG.head_dim],
+            "k_norm": params["layers"]["k_norm"][:, :CFG.head_dim]}}
+    else:
+        cfg, wrong = replace(CFG, norm_topk_prob=True), params
+    eng = engine_for(wrong, cfg)
+    try:
+        first = eng.prefill_logits(PROMPT)
+    finally:
+        eng.shutdown()
+    ref = reference_logits(params, PROMPT)
+    assert rel_rms(first, ref[-1]) > 100 * TOLERANCE
+
+
+def test_loss_and_gradients_against_the_reference():
+    params = seeded_params()
+    tokens = jax.random.randint(jax.random.PRNGKey(8), (33,), 0,
+                                CFG.vocab_size)
+    loss, grads = jax.jit(jax.value_and_grad(
+        lambda p: loss_fn(p, tokens[None], CFG)))(params)
+    ref_loss, ref_grads = reference.loss_and_grads(params, tokens, DIMS)
+    assert abs(float(loss) - float(ref_loss)) < TOLERANCE * float(ref_loss)
+    flat, _ = jax.tree_util.tree_flatten_with_path(grads)
+    ref_flat = dict(jax.tree_util.tree_flatten_with_path(ref_grads)[0])
+    assert len(flat) == len(ref_flat)
+    for path, g in flat:
+        # 1e-3 of a leaf's own size: the gradients pass through every sum
+        # of the forward pass twice, and the router's are differences of
+        # nearly equal terms.
+        assert rel_rms(g, ref_flat[path]) < 1e-3, jax.tree_util.keystr(path)
+    assert float(jnp.abs(grads["layers"]["router"]).sum()) > 0
+
+
+def test_load_balancing_term_alone_against_its_formula():
+    """E * sum_e P_e * sum_j f_{j,e} over all layers' tokens together, by
+    the program (from its per-layer means and counts), by the reference
+    (from one-hot masks) and by hand from the program's own choices."""
+    params = seeded_params()
+    tokens = jax.random.randint(jax.random.PRNGKey(9), (1, 40), 0,
+                                CFG.vocab_size)
+    _, aux = forward(params, tokens, CFG)
+    x = params["embed"][tokens[0]]
+    probs, chosen = [], []
+    for i in range(CFG.n_layers):
+        x, p, c = reference.layer(x, layer_of(params, i), DIMS)
+        probs.append(p)
+        chosen.append(c)
+    want = reference.load_balancing(jnp.concatenate(probs),
+                                    jnp.concatenate(chosen), DIMS)
+    assert abs(float(aux) - float(want)) < TOLERANCE * float(want)
+    n, k, e = 40 * CFG.n_layers, CFG.experts_per_token, CFG.num_experts
+    f = np.zeros((k, e))
+    for row in np.asarray(jnp.concatenate(chosen)):
+        for j, expert in enumerate(row):
+            f[j, expert] += 1.0 / n
+    by_hand = e * float((np.asarray(jnp.concatenate(probs)).mean(0)
+                         * f.sum(0)).sum())
+    assert abs(float(aux) - by_hand) < TOLERANCE * by_hand
+    # Even routing gives k: all probabilities 1/E, all shares k/E.
+    even = load_balancing_loss(jnp.full((3, e), 1.0 / e),
+                               jnp.full((3, e), 16 * k // e), 16)
+    assert abs(float(even) - k) < 1e-6
+
+
+def test_generate_on_the_expert_model_follows_the_reference():
+    params = seeded_params()
+    prompt = jnp.asarray([PROMPT[:9]], jnp.int32)
+    out = np.asarray(generate(params, prompt, CFG, max_new_tokens=6))[0]
+    ref = reference_logits(params, PROMPT[:9] + out[:-1].tolist())
+    for i, token in enumerate(out.tolist()):
+        row = ref[8 + i]
+        assert (row.max() - row[token]) / np.sqrt(np.mean(row ** 2)) \
+            < TOLERANCE
+
+
+def test_engine_counts_every_assignment_and_fetches_them_in_stats_only():
+    params = seeded_params()
+    eng = engine_for(params, CFG)
+    try:
+        assert eng.stats()["moe"]["assignments"] == 0  # warm-up not counted
+        eng.submit(PROMPT, max_new_tokens=6).result(timeout=120)
+        eng.submit(PROMPT[:5], max_new_tokens=3,
+                   temperature=0.7).result(timeout=120)
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    moe, phases = stats["moe"], stats["timing"]["phases"]
+    chunks, steps = stats["timing"]["prefill_chunks"], \
+        phases["decode_dispatch"]["n"]
+    assert chunks == 3 + 1 and steps >= 5 + 2
+    # Every row a step program computed: a chunk's 8 (padding too), a
+    # decode step's 2 slots (idle ones too), k experts each, in every layer.
+    rows = chunks * 8 + steps * 2
+    k, layers = CFG.experts_per_token, CFG.n_layers
+    assert moe["calls"] == chunks + steps
+    assert moe["assignments"] == rows * k * layers
+    assert sum(moe["per_expert"]) == moe["assignments"]
+    assert len(moe["per_expert"]) == CFG.num_experts
+    calls = moe["calls"] * layers
+    assert k <= moe["experts_hit_sum"] / calls <= CFG.num_experts
+    assert moe["max_load_sum"] / calls >= rows * k / moe["calls"] \
+        / CFG.num_experts
+    # The phase ledger's keys are what they were: no new host phase.
+    assert "moe" not in " ".join(phases)
+    dense = ContinuousBatchingEngine(
+        init_params(jax.random.PRNGKey(0), configs.tiny), configs.tiny,
+        num_slots=2, max_len=32)
+    try:
+        assert "moe" not in dense.stats()
+    finally:
+        dense.shutdown()

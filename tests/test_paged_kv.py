@@ -138,7 +138,7 @@ def _layerwise(params, cfg, x, k_pool, v_pool, write_kv, positions, valid,
 
     def layer(x, inputs):
         lp, k_layer, v_layer = inputs
-        x, k_layer, v_layer = _layer_body(
+        x, k_layer, v_layer, _ = _layer_body(
             x, lp, k_layer, v_layer, cfg, cos, sin, positions, write_kv,
             valid)
         return x, (k_layer, v_layer)

@@ -14,11 +14,10 @@ from ray_tpu.parallel import (
     MeshConfig,
     build_mesh,
     logical_to_physical,
-    moe_layer,
+    moe_block,
     pipeline_stages,
     ring_attention,
     shard_params,
-    top_k_routing,
     ulysses_attention,
 )
 from ray_tpu.parallel.ring_attention import reference_attention
@@ -119,52 +118,50 @@ def test_pipeline_matches_sequential():
                                rtol=1e-5, atol=1e-5)
 
 
-def test_top_k_routing_capacity():
-    logits = jnp.array([[10.0, 0.0], [10.0, 0.0], [10.0, 0.0], [0.0, 10.0]])
-    dispatch, combine, aux = top_k_routing(logits, k=1, capacity=2)
-    # Expert 0 over-subscribed (3 tokens, capacity 2): one token dropped.
-    assert float(dispatch[:, 0].sum()) == 2.0
-    assert float(dispatch[:, 1].sum()) == 1.0
-    assert float(aux) > 0
+def _moe_setup(tokens=32, d=16, ff=16, experts=4):
+    from ray_tpu.models.transformer import TransformerConfig
+
+    cfg = TransformerConfig(d_model=d, d_ff=ff, num_experts=experts,
+                            experts_per_token=2, dtype=jnp.float32)
+    keys = jax.random.split(jax.random.PRNGKey(5), 5)
+    x = jax.random.normal(keys[0], (tokens, d))
+    lp = {
+        "router": jax.random.normal(keys[1], (d, experts)) * 0.1,
+        "w_gate": jax.random.normal(keys[2], (experts, d, ff)) * 0.1,
+        "w_up": jax.random.normal(keys[3], (experts, d, ff)) * 0.1,
+        "w_down": jax.random.normal(keys[4], (experts, ff, d)) * 0.1,
+    }
+    return cfg, x, lp
 
 
 def test_moe_layer_runs_and_balances():
-    key = jax.random.PRNGKey(5)
-    tokens, d, experts = 32, 16, 4
-    x = jax.random.normal(key, (tokens, d))
-    router_w = jax.random.normal(jax.random.PRNGKey(6), (d, experts)) * 0.1
-    w_experts = jax.random.normal(jax.random.PRNGKey(7), (experts, d, d)) * 0.1
-
-    def expert_fn(w, xin):  # xin: [E, C, D]
-        return jnp.einsum("ecd,edf->ecf", xin, w)
-
-    out, aux = moe_layer(x, router_w, expert_fn, w_experts, k=2)
-    assert out.shape == (tokens, d)
+    cfg, x, lp = _moe_setup()
+    out, stats = moe_block(x, lp, cfg)
+    assert out.shape == x.shape
     assert bool(jnp.isfinite(out).all())
+    # Dropless: every token's two choices were computed.
+    assert int(stats["counts"].sum()) == x.shape[0] * 2
 
 
 def test_moe_layer_sharded_over_ep():
     mesh = build_mesh(MeshConfig(ep=4))
     from jax.sharding import NamedSharding, PartitionSpec as P
 
-    tokens, d, experts = 32, 16, 4
-    x = jax.random.normal(jax.random.PRNGKey(8), (tokens, d))
-    router_w = jax.random.normal(jax.random.PRNGKey(9), (d, experts)) * 0.1
-    w_experts = jax.device_put(
-        jax.random.normal(jax.random.PRNGKey(10), (experts, d, d)) * 0.1,
-        NamedSharding(mesh, P("ep")),
-    )
-
-    def expert_fn(w, xin):
-        return jnp.einsum("ecd,edf->ecf", xin, w)
+    cfg, x, lp = _moe_setup()
+    want, _ = moe_block(x, lp, cfg)
+    sharded = {name: jax.device_put(w, NamedSharding(mesh, P("ep")))
+               if name != "router" else w for name, w in lp.items()}
 
     @jax.jit
-    def run(x, router_w, w_experts):
-        out, aux = moe_layer(x, router_w, expert_fn, w_experts, k=2)
-        return out, aux
+    def run(x, lp):
+        return moe_block(x, lp, cfg)
 
-    out, aux = run(x, router_w, w_experts)
-    assert out.shape == (tokens, d)
+    out, stats = run(x, sharded)
+    assert out.shape == x.shape
+    # The partitioner places the block; the result is the unsharded one.
+    np.testing.assert_allclose(np.asarray(out), np.asarray(want),
+                               rtol=1e-5, atol=1e-6)
+    assert int(stats["counts"].sum()) == x.shape[0] * 2
 
 @pytest.mark.slow
 def test_pipeline_transformer_trains_and_matches_single_device():
