@@ -9,7 +9,7 @@ LINT_JOBS ?= 4
 .PHONY: lint rtlint lint-stats lint-changed lint-fix sanitizers test \
   fast-test \
   bench-data bench-obs bench-scale bench-serve-obs bench-serve-ft \
-  bench-collective bench-multitenant bench-paged-kv bench-serve-macro \
+  bench-collective bench-multitenant bench-serve-macro \
   bench-rollup
 
 lint: rtlint sanitizers
@@ -80,13 +80,6 @@ bench-multitenant:
 # MIGRATION.md pins these numbers.
 bench-collective:
 	JAX_PLATFORMS=cpu $(PY) bench_collective.py
-
-# Regenerates BENCH_PAGED_KV.json (paged KV engine: mixed-length
-# concurrency at equal HBM, shared-prefix TTFT, HOL, autoscaler ramp,
-# page-leak gate); the bench asserts its own gates. Run
-# tools/check_claims.py afterwards — MIGRATION.md pins these numbers.
-bench-paged-kv:
-	JAX_PLATFORMS=cpu $(PY) bench_paged_kv.py
 
 # Regenerates BENCH_SERVE_MACRO.json (the cluster witness: trace
 # record/replay byte identity, sustained-QPS client<->server latency

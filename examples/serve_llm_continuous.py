@@ -1,7 +1,7 @@
 """Continuous-batching LLM serving: requests join a running decode loop.
 
 The upgrade over examples/serve_llm.py's static batcher (the reference's
-serve.batching model): a slotted KV cache lets requests enter at any
+serve.batching model): a paged KV cache over decode slots lets requests enter at any
 decode-step boundary and leave when they finish, so mixed arrival times
 keep the chip busy — measured 4.4x static batch=1 tokens/s on a v5e chip
 (BENCH_INFER.json). Per-request sampling (temperature/top_k/top_p)

@@ -320,16 +320,11 @@ class Config:
     # the original execution instead of running it twice).
     serve_idem_cache_size: int = 1024
     # -- serve paged KV (engine memory plane, ray_tpu/serve/paged_kv) -----
-    # KV layout: "paged" (page pool + block tables + prefix cache, the
-    # default) or "slotted" (the original one-row-per-request cache,
-    # kept for bit-exactness baselines). RT_SERVE_KV=slotted flips it.
-    serve_kv: str = "paged"
-    # Tokens per KV page (clamped to max_len; bit-exactness with the
-    # slotted path needs max_len % page_size == 0).
+    # Tokens per KV page (clamped to max_len).
     serve_kv_page_size: int = 16
     # Total pages in the pool, INCLUDING the reserved NULL page. 0 =
-    # auto: num_slots * ceil(max_len / page_size) + 1, i.e. the same
-    # HBM as the slotted cache it replaces.
+    # auto: num_slots * ceil(max_len / page_size) + 1, i.e. max_len rows
+    # for every slot.
     serve_kv_pages: int = 0
     # Prefix cache over full prompt pages (shared prefixes skip their
     # prefill and share pages copy-on-write). Disable to force every

@@ -45,6 +45,10 @@ class ServeController:
         self.apps: Dict[str, Dict] = {}
         self._health_fails: Dict[bytes, int] = {}
         self._lock = threading.Lock()
+        # One reconcile pass at a time: deploy() (an actor-call thread)
+        # and _reconcile_loop both run _reconcile_once, which reads the
+        # replica count, starts what is missing and only then records it.
+        self._reconcile_lock = threading.Lock()
         # Event, not a bare bool: shutdown() runs on an actor-call thread
         # while _reconcile_loop reads it — Event gives the cross-thread
         # visibility guarantee without taking self._lock (RT006).
@@ -364,10 +368,22 @@ class ServeController:
 
     # -- reconciliation ---------------------------------------------------
     def _reconcile_once(self, name: str):
+        """Start or retire replicas until the app has its target count.
+        Serialized: two passes that both read a count of 0 would each
+        start a replica, and where one chip serves the app the second
+        one stays PENDING for ever, with serve.run waiting for it."""
+        with self._reconcile_lock:
+            excess = self._reconcile_locked(name)
+        # Outside the lock: a drain may take serve_drain_timeout_s.
+        self._drain_then_kill(excess, name)
+
+    def _reconcile_locked(self, name: str) -> List:
+        """One pass, under _reconcile_lock; returns the replicas to
+        drain and kill (already out of the route table)."""
         with self._lock:
             app = self.apps.get(name)
             if app is None:
-                return
+                return []
             dep: Deployment = app["deployment"]
             current = len(app["replicas"])
             target = app["target"]
@@ -416,7 +432,8 @@ class ServeController:
             # finish, and only then does the process die.
             self._publish_routes(name)
             self._checkpoint()
-            self._drain_then_kill(excess, name)
+            return excess
+        return []
 
     def _drain_then_kill(self, replicas: List, name: str = "",
                          timeout_s: Optional[float] = None):

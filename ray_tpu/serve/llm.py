@@ -4,10 +4,12 @@ The reference's dynamic batcher (python/ray/serve/batching.py) coalesces
 requests that ARRIVE together; a static batch then decodes in lockstep
 until every member finishes, so at mixed arrival times most of the chip
 sits idle (a 1-token straggler pins the whole batch). This module goes
-past it: a decode loop over a SLOTTED kv-cache where requests join at
-any step boundary (prefill interleaved between decode steps), emit
-tokens as they are produced, and free their slot the moment they finish
-— the vLLM-style iteration-level scheduling, built TPU-first:
+past it: a decode loop over a fixed number of SLOTS, backed by the paged
+KV cache (`ray_tpu.serve.paged_kv`, which also holds the step programs
+this module jits), where requests join at any step boundary (prefill
+interleaved between decode steps), emit tokens as they are produced, and
+free their slot the moment they finish — the vLLM-style iteration-level
+scheduling, built TPU-first:
 
   * Static shapes everywhere: the decode step is jitted ONCE for the
     slot count and prompts prefill in fixed-size CHUNKS (one chunk
@@ -18,11 +20,11 @@ tokens as they are produced, and free their slot the moment they finish
     each slot's own length, so one batched decode serves slots whose
     sequences started at different times.
   * Cache buffers are donated to the step and ride whole in the layer
-    scan's carry (`_scan_layers`), each layer writing and reading them
-    at its own index, so decode and prefill update the KV cache in
-    place. Donation alone does not do that: scanned over as the scan's
-    inputs and stacked back as its outputs, a cache is two buffers and
-    every step copies all of it twice.
+    scan's carry (`paged_kv._scan_layers`), each layer writing and
+    reading them at its own index, so decode and prefill update the KV
+    cache in place. Donation alone does not do that: scanned over as
+    the scan's inputs and stacked back as its outputs, a cache is two
+    buffers and every step copies all of it twice.
   * The steady-state hot loop does ZERO avoidable host<->device traffic
     per step: sampling params and the active mask are device-resident
     (re-uploaded only on slot admission/eviction), step outputs come
@@ -41,7 +43,6 @@ BASELINE.json configs[4] (the serving north-star).
 
 from __future__ import annotations
 
-import functools
 import threading
 import time
 from collections import deque
@@ -61,19 +62,9 @@ from ray_tpu.exceptions import (
 from ray_tpu.serve import context as request_context
 from ray_tpu.serve import observatory
 from ray_tpu.serve import paged_kv
-from ray_tpu.models.transformer import (
-    TransformerConfig,
-    _act,
-    _embed_tokens,
-    project_logits,
-    project_qkv,
-)
-from ray_tpu.ops import apply_rope, rmsnorm, rope_frequencies
-from ray_tpu.parallel.moe import EXPERT_LEAVES, moe_block
+from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.util.compile_cache import compile_events
 from ray_tpu.util.device_peaks import device_report
-
-NEG_INF = -1e30
 
 _metrics_lock = threading.Lock()
 _metrics: Optional[Dict] = None
@@ -156,266 +147,6 @@ def _engine_metrics() -> Dict:
                 ),
             }
         return _metrics
-
-
-def init_slotted_cache(cfg: TransformerConfig, slots: int, max_len: int) -> Dict:
-    """[layers, slots, max_len, kv_heads, head_dim] cache with PER-SLOT
-    lengths — the structural difference from generate.init_kv_cache's
-    single shared scalar, and what lets sequences of different ages
-    share one decode batch."""
-    shape = (cfg.n_layers, slots, max_len, cfg.n_kv_heads, cfg.head_dim)
-    return {
-        "k": jnp.zeros(shape, dtype=cfg.dtype),
-        "v": jnp.zeros(shape, dtype=cfg.dtype),
-        "lengths": jnp.zeros((slots,), dtype=jnp.int32),
-    }
-
-
-def _grouped_attention(q, kf, vf, valid):
-    """q [S, Lq, H, D] vs caches [S, Lk, KVH, D]; valid [S, Lq, Lk]."""
-    s_, lq, h, d = q.shape
-    kvh = kf.shape[2]
-    group = h // kvh
-    scale = d ** -0.5
-    qg = q.reshape(s_, lq, kvh, group, d).astype(jnp.float32)
-    scores = jnp.einsum("sqhgd,skhd->shgqk", qg, kf) * scale
-    scores = jnp.where(valid[:, None, None], scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("shgqk,skhd->sqhgd", p, vf).reshape(s_, lq, h, d)
-    return out.astype(q.dtype)
-
-
-def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
-                write_kv, valid, mesh=None, layer=None):
-    """One transformer layer shared by slotted decode and prefill.
-
-    The two callers differ only in how K/V land in the cache and what
-    the attention source/mask is: `write_kv(kc, vc, k, v) -> (kc, vc,
-    k_att, v_att)` encapsulates that, `valid` is the caller's mask over
-    (B, Lq, Lk_att). `mesh` is the engine's: activations are replicated
-    over it, so the norm kernel runs whole on every device. Returns the
-    layer's output, its caches and, for a model with experts, the
-    assignments each expert received `[E]` (else None); `layer` is
-    `moe_block`'s: the index at which `lp`'s expert stacks, then the
-    whole model's, are read in place."""
-    b, l = x.shape[:2]
-    h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh)
-    q, k, v = project_qkv(h, lp, cfg)
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
-    k_cache_l, v_cache_l, k_att, v_att = write_kv(k_cache_l, v_cache_l, k, v)
-    attn = _grouped_attention(
-        q, k_att.astype(jnp.float32), v_att.astype(jnp.float32), valid
-    )
-    x = x + (attn.reshape(b, l, -1) @ lp["wo"]).astype(x.dtype)
-    h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh)
-    if cfg.num_experts:
-        y, routing = moe_block(h.reshape(b * l, -1), lp, cfg, layer)
-        return (x + y.reshape(b, l, -1), k_cache_l, v_cache_l,
-                routing["counts"])
-    gate = _act(cfg)((h @ lp["w_gate"]).astype(jnp.float32))
-    up = (h @ lp["w_up"]).astype(jnp.float32)
-    x = x + (((gate * up).astype(x.dtype)) @ lp["w_down"])
-    return x, k_cache_l, v_cache_l, None
-
-
-def _scan_layers(params, x, k_cache, v_cache, write_kv, cfg, cos, sin,
-                 positions, valid, mesh=None):
-    """Every layer in turn, the whole KV cache `[layers, ...]` riding in
-    the scan's carry: the one way a step threads its cache through the
-    layers, slotted or paged. `write_kv(i, kc, vc, k, v)` is
-    `_layer_body`'s with the layer index in front; it writes and reads
-    the whole cache at `[i, ...]`.
-
-    A carry is one buffer from the first layer to the last, so with the
-    caches donated each layer's rows are scattered into the caller's own
-    buffer. Scanned over as `xs` and stacked back as `ys` a cache is two
-    buffers: every layer is sliced out of one and written into the
-    other, and the result copied back over the donated argument.
-
-    The expert stacks of a model that has them stay out of the scan for
-    the same reason: every layer reads them whole at its own index
-    (`moe_block`), where a layer sliced out for a grouped matmul would be
-    copied first. Also returns the assignments each layer's experts
-    received in this call `[layers, E]`, None for a dense model."""
-    layers, experts = params["layers"], {}
-    if cfg.num_experts:
-        experts = {n: layers[n] for n in EXPERT_LEAVES}
-        layers = {n: w for n, w in layers.items() if n not in experts}
-
-    def layer(carry, inputs):
-        x, kc, vc = carry
-        lp, i = inputs
-        x, kc, vc, counts = _layer_body(
-            x, {**lp, **experts}, kc, vc, cfg, cos, sin, positions,
-            functools.partial(write_kv, i), valid, mesh,
-            i if experts else None,
-        )
-        return (x, kc, vc), counts
-
-    index = jnp.arange(k_cache.shape[0], dtype=jnp.int32)
-    (x, k_cache, v_cache), counts = jax.lax.scan(
-        layer, (x, k_cache, v_cache), (layers, index)
-    )
-    return x, k_cache, v_cache, counts
-
-
-def init_routing_counters(cfg: TransformerConfig) -> Dict:
-    """The device-resident accumulator of a model with experts: what its
-    step programs add to at every call and `engine.stats()["moe"]` fetches,
-    so that nothing about routing leaves the device inside the loop."""
-    per_layer = jnp.zeros((cfg.n_layers,), jnp.int32)
-    return {
-        "assignments": jnp.zeros((cfg.n_layers, cfg.num_experts), jnp.int32),
-        "calls": jnp.zeros((), jnp.int32),
-        "experts_hit_sum": per_layer,
-        "max_load_sum": per_layer,
-    }
-
-
-def _count_routing(out, moe, counts):
-    """A step program's results with the routing accumulator `moe`
-    advanced by this call's `counts [layers, E]` appended; a caller that
-    passed no accumulator gets `out` as it is."""
-    if moe is None:
-        return out
-    return (*out, {
-        "assignments": moe["assignments"] + counts,
-        "calls": moe["calls"] + 1,
-        "experts_hit_sum": moe["experts_hit_sum"] + (counts > 0).sum(-1),
-        "max_load_sum": moe["max_load_sum"] + counts.max(-1),
-    })
-
-
-MAX_TOP_K = 64  # per-slot top-k cap (static shape for lax.top_k)
-
-
-def _pick_tokens(logits, temps, top_ks, top_ps, key):
-    """Per-slot next-token selection on device: greedy where temp == 0,
-    else temperature-scaled sampling with optional per-slot top-k
-    (0 = off, capped at MAX_TOP_K) and top-p (1.0 = off) filtering —
-    generate.py's sampling semantics, vectorized over slots so mixed
-    greedy/sampled requests share one decode batch."""
-    logits = logits.astype(jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    # top-k: threshold each row at its k-th largest value. The static k
-    # clamps to the vocab so models with vocab_size < MAX_TOP_K don't
-    # crash the jitted step (lax.top_k requires k <= last dim).
-    k = min(MAX_TOP_K, logits.shape[-1])
-    topv = jax.lax.top_k(scaled, k)[0]  # [S, K] sorted desc
-    idx = jnp.clip(top_ks - 1, 0, k - 1)
-    kth = jnp.take_along_axis(topv, idx[:, None], axis=-1)
-    scaled = jnp.where((top_ks > 0)[:, None] & (scaled < kth),
-                       -jnp.inf, scaled)
-    # top-p: smallest prefix of the sorted distribution reaching p.
-    sorted_l = jnp.sort(scaled, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(sorted_l, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (cum - probs) < top_ps[:, None]
-    thr = jnp.min(jnp.where(keep, sorted_l, jnp.inf), axis=-1,
-                  keepdims=True)
-    scaled = jnp.where(scaled < thr, -jnp.inf, scaled)
-    sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
-    return jnp.where(temps > 0, sampled, greedy)
-
-
-def _decode_slots(params, tokens, k_cache, v_cache, lengths, active,
-                  temps, top_ks, top_ps, key,
-                  cfg: TransformerConfig, mesh=None, moe=None):
-    """One decode step for every slot at once.
-
-    tokens [S] int32 (last emitted per slot; 0 for inactive), lengths
-    [S] (current valid cache rows per slot), active [S] bool. Returns
-    (next_tokens [S], k_cache, v_cache, new_lengths): caches updated
-    in place at each ACTIVE slot's own position; inactive slots write
-    into their top spare row (masked out forever) and keep their length.
-    With `moe`, a model with experts' routing accumulator, the advanced
-    accumulator comes back as a fifth result (`_count_routing`).
-    """
-    s_ = tokens.shape[0]
-    lmax = k_cache.shape[2]
-    x = _embed_tokens(params, tokens[:, None], cfg)  # [S, 1, d]
-    cos, sin = rope_frequencies(cfg.head_dim, lmax, cfg.rope_theta)
-    positions = lengths[:, None]
-    # Inactive slots park their write in the slot's own last row; it is
-    # never unmasked (their length does not advance).
-    write_at = jnp.where(active, jnp.minimum(lengths, lmax - 1), lmax - 1)
-    slot_idx = jnp.arange(s_)
-    # Keys valid up to and including the token just written.
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (s_, 1, lmax), 2)
-    valid = k_pos <= positions[:, :, None]
-
-    def write_kv(i, kc, vc, k, v):
-        kc = kc.at[i, slot_idx, write_at].set(k[:, 0].astype(kc.dtype))
-        vc = vc.at[i, slot_idx, write_at].set(v[:, 0].astype(vc.dtype))
-        return kc, vc, kc[i], vc[i]  # attend against the layer's cache
-
-    x, k_new, v_new, counts = _scan_layers(
-        params, x, k_cache, v_cache, write_kv, cfg, cos, sin, positions,
-        valid, mesh,
-    )
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
-    logits = project_logits(x[:, -1], params, cfg)
-    new_lengths = jnp.where(active, lengths + 1, lengths)
-    # Next token computed ON DEVICE so the engine can feed it straight
-    # into the next dispatched step without a host round trip (the
-    # pipelining that hides host/RTT latency behind decode). temps=None
-    # compiles the greedy-only program: no top-k/sort/softmax work on
-    # the latency-critical all-greedy path.
-    if temps is None:
-        next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    else:
-        next_tokens = _pick_tokens(logits, temps, top_ks, top_ps, key)
-    return _count_routing((next_tokens, k_new, v_new, new_lengths), moe,
-                          counts)
-
-
-def _prefill_chunk(params, tokens, n_valid, slot, offset, k_cache, v_cache,
-                   lengths, cfg: TransformerConfig, mesh=None, moe=None):
-    """CHUNKED prefill: process one fixed-size chunk of a prompt into
-    slot `slot` at row `offset` — the scheme that lets a long prompt's
-    prefill interleave with other slots' decode steps instead of
-    stalling them for the whole prompt.
-
-    tokens [1, C] int32 (first n_valid real), writes K/V rows
-    [slot, offset:offset+C]; queries attend causally to the slot's
-    whole cache prefix (earlier chunks included). Sets lengths[slot] =
-    offset + n_valid and returns the logits of the chunk's last REAL
-    position [1, vocab] (meaningful on the final chunk).
-    """
-    _, c = tokens.shape
-    lmax = k_cache.shape[2]
-    x = _embed_tokens(params, tokens, cfg)
-    cos, sin = rope_frequencies(cfg.head_dim, lmax, cfg.rope_theta)
-    positions = offset + jnp.arange(c, dtype=jnp.int32)[None, :]
-    # Causal against the slot's full cache: key row j is visible to
-    # chunk query i when j <= offset + i and j is a real row.
-    q_pos = positions[:, :, None]                              # [1, C, 1]
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, c, lmax), 2)
-    valid = (k_pos <= q_pos) & (k_pos < offset + n_valid)
-    # Row-indexed scatter with mode="drop": a final chunk whose PADDING
-    # would run past the cache end simply drops those rows.
-    # (dynamic_update_slice would CLAMP the start instead, silently
-    # overwriting earlier chunks' rows.) Real rows always fit: prompts
-    # are bounded by max_len - 2 at submit.
-    rows = offset + jnp.arange(c, dtype=jnp.int32)
-
-    def write_kv(i, kc, vc, k, v):
-        kc = kc.at[i, slot, rows].set(k[0].astype(kc.dtype), mode="drop")
-        vc = vc.at[i, slot, rows].set(v[0].astype(vc.dtype), mode="drop")
-        # Attend against the slot's whole cache row range (masked).
-        return kc, vc, kc[i, slot][None], vc[i, slot][None]
-
-    x, k_new, v_new, counts = _scan_layers(
-        params, x, k_cache, v_cache, write_kv, cfg, cos, sin, positions,
-        valid, mesh,
-    )
-    x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
-    last = jax.lax.dynamic_slice(x, (0, n_valid - 1, 0), (1, 1, x.shape[-1]))
-    logits = project_logits(last[:, 0], params, cfg)
-    new_lengths = lengths.at[slot].set(offset + n_valid)
-    return _count_routing((logits, k_new, v_new, new_lengths), moe, counts)
 
 
 class GenerationHandle:
@@ -629,7 +360,8 @@ def _timing_of(ledger: Dict) -> Dict:
 
 
 class ContinuousBatchingEngine:
-    """Iteration-level scheduler over the slotted cache.
+    """Iteration-level scheduler over a fixed set of decode slots whose
+    K/V rows live in the paged pool (`paged_kv`).
 
     One background thread runs the decode loop; submit() enqueues a
     request which joins at the next step boundary when a slot frees.
@@ -637,10 +369,8 @@ class ContinuousBatchingEngine:
 
     def __init__(self, params, cfg: TransformerConfig, num_slots: int = 4,
                  max_len: int = 256, eos_id: Optional[int] = None,
-                 default_max_new_tokens: int = 32,
-                 prefill_buckets=None, seed: int = 0,
+                 default_max_new_tokens: int = 32, seed: int = 0,
                  mesh=None, prefill_chunk: int = 64,
-                 kv_mode: Optional[str] = None,
                  page_size: Optional[int] = None,
                  kv_pages: Optional[int] = None):
         """mesh: a jax.sharding.Mesh with a "tp" axis for tensor-
@@ -652,16 +382,13 @@ class ContinuousBatchingEngine:
         prefill_chunk: prompts prefill in fixed chunks of this many
         tokens, ONE chunk between decode steps — a long prompt never
         stalls other slots' decoding for more than a chunk (chunked
-        prefill), and prefill compiles exactly once. prefill_buckets is
-        a deprecated no-op (chunking bounds compilation by itself).
+        prefill), and prefill compiles exactly once.
 
-        kv_mode / page_size / kv_pages: the KV memory plane. "paged"
-        (default; ray_tpu/serve/paged_kv) backs slots with a shared
-        page pool + block tables and a prefix cache; "slotted" is the
-        original one-[max_len]-row-per-slot cache kept for bit-exact
-        baselines. None defers to config (RT_SERVE_KV,
-        RT_SERVE_KV_PAGE_SIZE, RT_SERVE_KV_PAGES; kv_pages 0/None =
-        slotted-HBM parity)."""
+        page_size / kv_pages: the KV memory plane (ray_tpu/serve/
+        paged_kv): a shared page pool + block tables and a prefix cache
+        back the slots. None defers to config (serve_kv_page_size,
+        serve_kv_pages; kv_pages 0/None = num_slots x max_len rows and
+        the NULL page)."""
         self.params = params
         self.cfg = cfg
         self.num_slots = num_slots
@@ -682,43 +409,33 @@ class ContinuousBatchingEngine:
                     f"n_kv_heads={cfg.n_kv_heads}"
                 )
         rcfg = get_config()
-        mode = (kv_mode or rcfg.serve_kv or "paged").lower()
-        if mode not in ("paged", "slotted"):
-            raise ValueError(
-                f"kv_mode must be 'paged' or 'slotted', got {mode!r}"
-            )
-        self.kv_mode = mode
-        self._paged = mode == "paged"
-        self._cow = None
-        if self._paged:
-            self.page_size = max(
-                1, min(int(page_size or rcfg.serve_kv_page_size), max_len)
-            )
-            self._pages_per_slot = -(-max_len // self.page_size)
-            self.kv_pages = int(kv_pages or rcfg.serve_kv_pages or 0)
-            if self.kv_pages <= 0:
-                # HBM parity with the slotted cache it replaces (+ the
-                # reserved NULL page).
-                self.kv_pages = num_slots * self._pages_per_slot + 1
-            self._pool = paged_kv.PagePool(self.kv_pages, self.page_size)
-            self._prefix_cache = (
-                paged_kv.PrefixCache(self._pool)
-                if rcfg.serve_prefix_cache else None
-            )
-            # Host mirror of the device block table; uploaded as ONE
-            # array only when admission/eviction changed it (same
-            # discipline — and the same test pins — as the sampling
-            # params: the steady-state decode step uploads nothing).
-            self._bt_host = np.zeros(
-                (num_slots, self._pages_per_slot), dtype=np.int32
-            )
-            self._bt_dirty = False
-            self._bt_uploads = 0
-            self._slot_pages: Dict[int, list] = {}
-            self._prefix_hits = 0
-            self._prefix_misses = 0
-            self._prefill_tok_skipped = 0
-            self._chaos_held: list = []
+        self.page_size = max(
+            1, min(int(page_size or rcfg.serve_kv_page_size), max_len)
+        )
+        self._pages_per_slot = -(-max_len // self.page_size)
+        self.kv_pages = int(kv_pages or rcfg.serve_kv_pages or 0)
+        if self.kv_pages <= 0:
+            # Every slot can hold max_len rows (+ the reserved NULL page).
+            self.kv_pages = num_slots * self._pages_per_slot + 1
+        self._pool = paged_kv.PagePool(self.kv_pages, self.page_size)
+        self._prefix_cache = (
+            paged_kv.PrefixCache(self._pool)
+            if rcfg.serve_prefix_cache else None
+        )
+        # Host mirror of the device block table; uploaded as ONE array
+        # only when admission/eviction changed it (same discipline — and
+        # the same test pins — as the sampling params: the steady-state
+        # decode step uploads nothing).
+        self._bt_host = np.zeros(
+            (num_slots, self._pages_per_slot), dtype=np.int32
+        )
+        self._bt_dirty = False
+        self._bt_uploads = 0
+        self._slot_pages: Dict[int, list] = {}
+        self._prefix_hits = 0
+        self._prefix_misses = 0
+        self._prefill_tok_skipped = 0
+        self._chaos_held: list = []
         cache = self._fresh_cache()
         self._k, self._v = cache["k"], cache["v"]
         self._lengths = cache["lengths"]
@@ -729,57 +446,33 @@ class ContinuousBatchingEngine:
         # thread, and an array no program consumes can be fetched at any
         # time. Written by the loop thread only.
         self._moe = ([jax.tree.map(self._replicated,
-                                   init_routing_counters(cfg))]
+                                   paged_kv.init_routing_counters(cfg))]
                      if cfg.num_experts else [])
-        if self._paged:
-            self._bt_dev = cache["block_tables"]
-            self._decode_sampled = jax.jit(
-                lambda p, t, k, v, ln, a, bt, tp, tk, tpp, key, *moe:
-                paged_kv.decode_paged(
-                    p, t, k, v, ln, a, bt, tp, tk, tpp, key, cfg, max_len,
-                    mesh, *moe,
-                ),
-                donate_argnums=(2, 3),
-            )
-            self._decode_greedy = jax.jit(
-                lambda p, t, k, v, ln, a, bt, *moe: paged_kv.decode_paged(
-                    p, t, k, v, ln, a, bt, None, None, None, None, cfg,
-                    max_len, mesh, *moe,
-                ),
-                donate_argnums=(2, 3),
-            )
-            self._prefill = jax.jit(
-                lambda p, t, n, s, o, k, v, ln, bt, *moe:
-                paged_kv.prefill_chunk_paged(
-                    p, t, n, s, o, k, v, ln, bt, cfg, max_len, mesh, *moe
-                ),
-                donate_argnums=(5, 6),
-            )
-            self._cow = jax.jit(
-                paged_kv.cow_copy_page, donate_argnums=(0, 1)
-            )
-        else:
-            self._decode_sampled = jax.jit(
-                lambda p, t, k, v, ln, a, tp, tk, tpp, key, *moe:
-                _decode_slots(
-                    p, t, k, v, ln, a, tp, tk, tpp, key, cfg, mesh, *moe
-                ),
-                donate_argnums=(2, 3),
-            )
-            self._decode_greedy = jax.jit(
-                lambda p, t, k, v, ln, a, *moe: _decode_slots(
-                    p, t, k, v, ln, a, None, None, None, None, cfg, mesh,
-                    *moe
-                ),
-                donate_argnums=(2, 3),
-            )
-            self._prefill = jax.jit(
-                lambda p, t, n, s, o, k, v, ln, *moe: _prefill_chunk(
-                    p, t, n, s, o, k, v, ln, cfg, mesh, *moe
-                ),
-                donate_argnums=(5, 6),
-            )
-        self._pick = jax.jit(_pick_tokens)
+        self._bt_dev = cache["block_tables"]
+        self._decode_sampled = jax.jit(
+            lambda p, t, k, v, ln, a, bt, tp, tk, tpp, key, *moe:
+            paged_kv.decode_paged(
+                p, t, k, v, ln, a, bt, tp, tk, tpp, key, cfg, max_len,
+                mesh, *moe,
+            ),
+            donate_argnums=(2, 3),
+        )
+        self._decode_greedy = jax.jit(
+            lambda p, t, k, v, ln, a, bt, *moe: paged_kv.decode_paged(
+                p, t, k, v, ln, a, bt, None, None, None, None, cfg,
+                max_len, mesh, *moe,
+            ),
+            donate_argnums=(2, 3),
+        )
+        self._prefill = jax.jit(
+            lambda p, t, n, s, o, k, v, ln, bt, *moe:
+            paged_kv.prefill_chunk_paged(
+                p, t, n, s, o, k, v, ln, bt, cfg, max_len, mesh, *moe
+            ),
+            donate_argnums=(5, 6),
+        )
+        self._cow = jax.jit(paged_kv.cow_copy_page, donate_argnums=(0, 1))
+        self._pick = jax.jit(paged_kv._pick_tokens)
         self._lock = threading.Lock()
         self._work = threading.Event()
         # BOUNDED admission queue with per-tenant weighted-fair service:
@@ -875,53 +568,34 @@ class ContinuousBatchingEngine:
         first-token path with its small eager programs (key split, pick
         or argmax, the token buffer's update) — so traffic flipping
         between greedy and sampled never compiles mid-serving. All
-        warmup calls run with `active` all-False: decode writes land in
-        each slot's parking row (lmax - 1, never unmasked) and the
-        prefill rows it touches are re-written by any real occupant
-        before its length exposes them, so cache contents stay
-        semantically untouched."""
+        warmup calls run with `active` all-False and an all-NULL block
+        table: decode and prefill writes land in the NULL page, which is
+        never gathered unmasked, so cache contents stay semantically
+        untouched."""
         # The loop's own two-way split (and the unpacking's unstack).
         self._rng, k1 = jax.random.split(self._rng)
         pad = np.zeros((1, self.prefill_chunk), dtype=np.int32)
         one, zero = np.int32(1), np.int32(0)
-        if self._paged:
-            (_, self._k, self._v, self._lengths,
-             *self._moe) = self._decode_greedy(
-                self.params, self._tokens_dev, self._k, self._v,
-                self._lengths, self._active_dev, self._bt_dev, *self._moe,
-            )
-            (_, self._k, self._v, self._lengths,
-             *self._moe) = self._decode_sampled(
-                self.params, self._tokens_dev, self._k, self._v,
-                self._lengths, self._active_dev, self._bt_dev,
-                self._temps_dev, self._top_ks_dev, self._top_ps_dev, k1,
-                *self._moe,
-            )
-            (logits, self._k, self._v, self._lengths,
-             *self._moe) = self._prefill(
-                self.params, pad, one, zero, zero,
-                self._k, self._v, self._lengths, self._bt_dev, *self._moe,
-            )
-            # Warm the copy-on-write page fork too (NULL page onto
-            # itself: contents never observable).
-            self._k, self._v = self._cow(self._k, self._v, zero, zero)
-        else:
-            (_, self._k, self._v, self._lengths,
-             *self._moe) = self._decode_greedy(
-                self.params, self._tokens_dev, self._k, self._v,
-                self._lengths, self._active_dev, *self._moe,
-            )
-            (_, self._k, self._v, self._lengths,
-             *self._moe) = self._decode_sampled(
-                self.params, self._tokens_dev, self._k, self._v,
-                self._lengths, self._active_dev, self._temps_dev,
-                self._top_ks_dev, self._top_ps_dev, k1, *self._moe,
-            )
-            (logits, self._k, self._v, self._lengths,
-             *self._moe) = self._prefill(
-                self.params, pad, one, zero, zero,
-                self._k, self._v, self._lengths, *self._moe,
-            )
+        (_, self._k, self._v, self._lengths,
+         *self._moe) = self._decode_greedy(
+            self.params, self._tokens_dev, self._k, self._v,
+            self._lengths, self._active_dev, self._bt_dev, *self._moe,
+        )
+        (_, self._k, self._v, self._lengths,
+         *self._moe) = self._decode_sampled(
+            self.params, self._tokens_dev, self._k, self._v,
+            self._lengths, self._active_dev, self._bt_dev,
+            self._temps_dev, self._top_ks_dev, self._top_ps_dev, k1,
+            *self._moe,
+        )
+        (logits, self._k, self._v, self._lengths,
+         *self._moe) = self._prefill(
+            self.params, pad, one, zero, zero,
+            self._k, self._v, self._lengths, self._bt_dev, *self._moe,
+        )
+        # Warm the copy-on-write page fork too (NULL page onto itself:
+        # contents never observable).
+        self._k, self._v = self._cow(self._k, self._v, zero, zero)
         # Both first-token variants, then the token buffer as it was.
         tokens = self._tokens_dev
         self._first_token(logits, 0, 0.5, 1, 1.0)
@@ -978,7 +652,7 @@ class ContinuousBatchingEngine:
     def _upload_block_table(self):  # rtlint: disable=RT006,RT010 — loop-thread-only; the lock is for submit()-side visibility
         """ONE host->device refresh of the block table. Admission-
         reserved paging means the table only changes when slot
-        membership does — never per decode step (the paged analog of
+        membership does — never per decode step (the block table's
         _upload_sampling_state, with its own counter so tests can pin
         the steady state)."""
         with self._phase("upload"):
@@ -1021,26 +695,10 @@ class ContinuousBatchingEngine:
                     self._chaos_held.extend(self._pool.alloc(grab))
 
     def _fresh_cache(self) -> Dict:
-        if self._paged:
-            return paged_kv.init_paged_cache(
-                self.cfg, self.num_slots, self.kv_pages, self.page_size,
-                self._pages_per_slot, mesh=self.mesh,
-            )
-        cache = init_slotted_cache(self.cfg, self.num_slots, self.max_len)
-        if self.mesh is not None:
-            from jax.sharding import NamedSharding, PartitionSpec as P
-
-            kv_sharding = NamedSharding(
-                self.mesh, P(None, None, None, "tp")
-            )
-            cache = {
-                "k": jax.device_put(cache["k"], kv_sharding),
-                "v": jax.device_put(cache["v"], kv_sharding),
-                "lengths": jax.device_put(
-                    cache["lengths"], NamedSharding(self.mesh, P())
-                ),
-            }
-        return cache
+        return paged_kv.init_paged_cache(
+            self.cfg, self.num_slots, self.kv_pages, self.page_size,
+            self._pages_per_slot, mesh=self.mesh,
+        )
 
     # -- public API ------------------------------------------------------
     def submit(self, prompt, max_new_tokens: Optional[int] = None,
@@ -1049,8 +707,8 @@ class ContinuousBatchingEngine:
         """temperature=0 decodes greedily (the default); >0 samples,
         optionally filtered by per-request top_k (<= MAX_TOP_K) and
         top_p — mixed greedy/sampled requests share one decode batch."""
-        if top_k is not None and not 0 < top_k <= MAX_TOP_K:
-            raise ValueError(f"top_k must be in (0, {MAX_TOP_K}]")
+        if top_k is not None and not 0 < top_k <= paged_kv.MAX_TOP_K:
+            raise ValueError(f"top_k must be in (0, {paged_kv.MAX_TOP_K}]")
         if top_p is not None and not 0.0 < top_p <= 1.0:
             raise ValueError("top_p must be in (0, 1]")
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
@@ -1058,16 +716,15 @@ class ContinuousBatchingEngine:
             raise ValueError("empty prompt")
         limit = self.max_len - 2
         detail = f"max_len - 2 = {self.max_len - 2} positions"
-        if self._paged:
-            # The pool must hold the whole prompt plus one generated
-            # token (+1 margin row for the pipelined in-flight step).
-            pool_limit = self._pool.usable * self.page_size - 2
-            if pool_limit < limit:
-                limit = pool_limit
-                detail = (
-                    f"page pool = {self._pool.usable} pages x "
-                    f"{self.page_size} tokens - 2 = {pool_limit}"
-                )
+        # The pool must hold the whole prompt plus one generated token
+        # (+1 margin row for the pipelined in-flight step).
+        pool_limit = self._pool.usable * self.page_size - 2
+        if pool_limit < limit:
+            limit = pool_limit
+            detail = (
+                f"page pool = {self._pool.usable} pages x "
+                f"{self.page_size} tokens - 2 = {pool_limit}"
+            )
         if len(prompt) > limit:
             raise PromptTooLongError(
                 f"prompt length {len(prompt)} exceeds this engine's "
@@ -1148,8 +805,6 @@ class ContinuousBatchingEngine:
         """The KV memory plane's health (stats()["kv"]): pool occupancy,
         prefix-cache effectiveness, and — for affinity routing — the
         cache's advertised root keys."""
-        if not self._paged:
-            return {"mode": "slotted", "page_size": 0}
         lookups = self._prefix_hits + self._prefix_misses
         cache_pages = (self._prefix_cache.pages_held
                        if self._prefix_cache is not None else 0)
@@ -1186,13 +841,11 @@ class ContinuousBatchingEngine:
             )
         cache = self._fresh_cache()
         k, v, lengths = cache["k"], cache["v"], cache["lengths"]
-        table = ()
-        if self._paged:
-            # Slot 0 over the scratch pool's first pages.
-            bt = np.zeros_like(self._bt_host)
-            n = min(self._pages_per_slot, self._pool.usable)
-            bt[0, :n] = np.arange(1, n + 1)
-            table = (self._replicated(bt),)
+        # Slot 0 over the scratch pool's first pages.
+        bt = np.zeros_like(self._bt_host)
+        n = min(self._pages_per_slot, self._pool.usable)
+        bt[0, :n] = np.arange(1, n + 1)
+        table = self._replicated(bt)
         c = self.prefill_chunk
         for off in range(0, len(prompt), c):
             chunk = prompt[off:off + c]
@@ -1200,7 +853,7 @@ class ContinuousBatchingEngine:
             padded[0, :len(chunk)] = chunk
             logits, k, v, lengths, *_ = self._prefill(
                 self.params, padded, np.int32(len(chunk)),
-                np.int32(0), np.int32(off), k, v, lengths, *table,
+                np.int32(0), np.int32(off), k, v, lengths, table,
                 *self._moe,
             )
         return np.asarray(logits, dtype=np.float32)[0]
@@ -1374,24 +1027,21 @@ class ContinuousBatchingEngine:
             h.max_new_tokens = min(
                 h.max_new_tokens, self.max_len - 1 - len(h.prompt)
             )
-            res = None
-            if self._paged:
-                # Reserve EVERY page the request can ever touch now:
-                # decode then never allocates, so the block table (like
-                # the sampling params) uploads only on slot membership
-                # changes and pool exhaustion can never strand a
-                # mid-decode sequence.
-                res = self._reserve_paged_locked(h)
-                if res is None:
-                    # Pool pressure: back to the FRONT of its tenant
-                    # queue; retried as decoding slots release pages.
-                    q = self._waiting.get(h.tenant)
-                    if q is None:
-                        q = self._waiting[h.tenant] = deque()
-                        self._wfq_rr.append(h.tenant)
-                    q.appendleft(h)
-                    self._waiting_n += 1
-                    break
+            # Reserve EVERY page the request can ever touch now: decode
+            # then never allocates, so the block table (like the sampling
+            # params) uploads only on slot membership changes and pool
+            # exhaustion can never strand a mid-decode sequence.
+            res = self._reserve_paged_locked(h)
+            if res is None:
+                # Pool pressure: back to the FRONT of its tenant queue;
+                # retried as decoding slots release pages.
+                q = self._waiting.get(h.tenant)
+                if q is None:
+                    q = self._waiting[h.tenant] = deque()
+                    self._wfq_rr.append(h.tenant)
+                q.appendleft(h)
+                self._waiting_n += 1
+                break
             grant_t = time.perf_counter()
             if h.submitted_at is not None:
                 _engine_metrics()["admission_wait_s"].observe(
@@ -1400,16 +1050,14 @@ class ContinuousBatchingEngine:
             if h.obs is not None:
                 h.obs.marks["slot_grant"] = grant_t
             slot = self._free.popleft()
-            entry = {"h": h, "offset": 0}
-            if self._paged:
-                entry["offset"] = res["skip"]
-                entry["pages"] = res["pages"]
-                entry["hashes"] = res["hashes"]
-                row = self._bt_host[slot]
-                row[:] = 0
-                row[:len(res["pages"])] = res["pages"]
-                self._bt_dirty = True
-            self._prefilling[slot] = entry
+            row = self._bt_host[slot]
+            row[:] = 0
+            row[:len(res["pages"])] = res["pages"]
+            self._bt_dirty = True
+            self._prefilling[slot] = {
+                "h": h, "offset": res["skip"], "pages": res["pages"],
+                "hashes": res["hashes"],
+            }
         if admitted:
             _engine_metrics()["waiting"].set(float(self._waiting_n))
 
@@ -1505,7 +1153,7 @@ class ContinuousBatchingEngine:
         injected = chaos.take_prefill_delay()
         if injected:
             time.sleep(injected)
-        if self._paged and self._bt_dirty:
+        if self._bt_dirty:
             self._upload_block_table()
         self._last_prefill_work = [
             {
@@ -1533,20 +1181,19 @@ class ContinuousBatchingEngine:
                     self._deadline_expired += int(not h.cancelled)
                     del self._prefilling[slot]
                     self._free.append(slot)
-                    if self._paged:
-                        self._pool.release(entry["pages"])
+                    self._pool.release(entry["pages"])
                 continue
             with self._phase("prefill_dispatch"):
                 chunk = h.prompt[off:off + c]
                 n = len(chunk)
                 padded = np.zeros((1, c), dtype=np.int32)
                 padded[0, :n] = chunk
-                table = (self._bt_dev,) if self._paged else ()
                 (logits, self._k, self._v, self._lengths,
                  *self._moe) = self._prefill(
                     self.params, padded,
                     np.int32(n), np.int32(slot), np.int32(off),
-                    self._k, self._v, self._lengths, *table, *self._moe,
+                    self._k, self._v, self._lengths, self._bt_dev,
+                    *self._moe,
                 )
                 entry["offset"] = off + n
                 if entry["offset"] < len(h.prompt):
@@ -1580,7 +1227,7 @@ class ContinuousBatchingEngine:
                     else False) or h.produced >= h.max_new_tokens
             h._push(tok, done)
             with self._lock:
-                if self._paged and self._prefix_cache is not None:
+                if self._prefix_cache is not None:
                     # Publish the prompt's full pages NOW (not at
                     # request completion): a concurrent same-prefix
                     # request admitted next tick already shares them.
@@ -1592,11 +1239,9 @@ class ContinuousBatchingEngine:
                 del self._prefilling[slot]
                 if done:
                     self._free.append(slot)
-                    if self._paged:
-                        self._pool.release(entry["pages"])
+                    self._pool.release(entry["pages"])
                 else:
-                    if self._paged:
-                        self._slot_pages[slot] = entry["pages"]
+                    self._slot_pages[slot] = entry["pages"]
                     self._slots[slot] = h
                     self._gen[slot] += 1
                     self._temps[slot] = h.temperature
@@ -1644,8 +1289,7 @@ class ContinuousBatchingEngine:
         a decode step, its (dispatch, fetch) seconds."""
         phase = self._phase
         with phase("admit"):
-            if self._paged:
-                self._apply_kv_chaos()
+            self._apply_kv_chaos()
             with self._lock:
                 self._admit_locked()
                 n_active = len(self._slots)
@@ -1667,17 +1311,16 @@ class ContinuousBatchingEngine:
         if snapshot:
             if self._params_dirty:
                 self._upload_sampling_state()
-            if self._paged and self._bt_dirty:
+            if self._bt_dirty:
                 self._upload_block_table()
             with phase("decode_dispatch") as dispatch:
-                table = (self._bt_dev,) if self._paged else ()
                 if self._sampled_active:
                     self._rng, step_key = jax.random.split(self._rng)
                     (next_dev, self._k, self._v, self._lengths,
                      *self._moe) = self._decode_sampled(
                         self.params, self._tokens_dev,
                         self._k, self._v, self._lengths,
-                        self._active_dev, *table,
+                        self._active_dev, self._bt_dev,
                         self._temps_dev, self._top_ks_dev,
                         self._top_ps_dev, step_key, *self._moe,
                     )
@@ -1686,7 +1329,7 @@ class ContinuousBatchingEngine:
                      *self._moe) = self._decode_greedy(
                         self.params, self._tokens_dev,
                         self._k, self._v, self._lengths,
-                        self._active_dev, *table, *self._moe,
+                        self._active_dev, self._bt_dev, *self._moe,
                     )
                 self._tokens_dev = next_dev
                 # Start the D2H copy NOW: it lands while this thread
@@ -1726,8 +1369,7 @@ class ContinuousBatchingEngine:
             m = _engine_metrics()
             m["occupancy"].set(len(snapshot) / self.num_slots)
             m["waiting"].set(float(self._waiting_n))  # gauge snapshot: a stale int is fine
-            if self._paged:
-                m["kv_pages"].set(float(self._pool.in_use))
+            m["kv_pages"].set(float(self._pool.in_use))
             return dispatch_s, fetch_s
         return None
 
@@ -1743,8 +1385,7 @@ class ContinuousBatchingEngine:
         self._top_ks[s] = 0
         self._top_ps[s] = 1.0
         self._params_dirty = True
-        if self._paged:
-            self._release_slot_pages_locked(s)
+        self._release_slot_pages_locked(s)
 
     def _distribute(self, prev_snapshot, toks, lengths_np):
         """Push a drained step's tokens to their handles; evict what
@@ -1816,8 +1457,7 @@ class ContinuousBatchingEngine:
                         # miss here is caught by the next wait.
                         idle = not self._waiting_n
                     if idle:
-                        if self._paged:
-                            self._apply_kv_chaos()
+                        self._apply_kv_chaos()
                         continue
                 with phase("turn") as turn:
                     timed = self._turn()
@@ -1847,19 +1487,18 @@ class ContinuousBatchingEngine:
                     cache = self._fresh_cache()
                     self._k, self._v = cache["k"], cache["v"]
                     self._lengths = cache["lengths"]
-                    if self._paged:
-                        # Every outstanding page reference pointed into
-                        # the dead cache: reset the allocator, drop the
-                        # prefix cache WITHOUT releasing (the refs are
-                        # void), zero the table.
-                        self._bt_dev = cache["block_tables"]
-                        self._pool.reset()
-                        if self._prefix_cache is not None:
-                            self._prefix_cache.reset()
-                        self._slot_pages.clear()
-                        self._chaos_held = []
-                        self._bt_host[:] = 0
-                        self._bt_dirty = False
+                    # Every outstanding page reference pointed into the
+                    # dead cache: reset the allocator, drop the prefix
+                    # cache WITHOUT releasing (the refs are void), zero
+                    # the table.
+                    self._bt_dev = cache["block_tables"]
+                    self._pool.reset()
+                    if self._prefix_cache is not None:
+                        self._prefix_cache.reset()
+                    self._slot_pages.clear()
+                    self._chaos_held = []
+                    self._bt_host[:] = 0
+                    self._bt_dirty = False
                     self._tokens_dev = self._replicated(
                         np.zeros(self.num_slots, dtype=np.int32))
                     self._gen += 1  # orphan any in-flight snapshot
@@ -1880,7 +1519,7 @@ class LLMReplica:
     def __init__(self, model_loader, num_slots: int = 4, max_len: int = 256,
                  eos_id: Optional[int] = None,
                  default_max_new_tokens: int = 32,
-                 prefill_chunk: int = 64, kv_mode: Optional[str] = None,
+                 prefill_chunk: int = 64,
                  page_size: Optional[int] = None,
                  kv_pages: Optional[int] = None):
         # The loader runs IN the replica process and may return
@@ -1896,7 +1535,7 @@ class LLMReplica:
         self.engine = ContinuousBatchingEngine(
             params, cfg, num_slots=num_slots, max_len=max_len,
             eos_id=eos_id, default_max_new_tokens=default_max_new_tokens,
-            mesh=mesh, prefill_chunk=prefill_chunk, kv_mode=kv_mode,
+            mesh=mesh, prefill_chunk=prefill_chunk,
             page_size=page_size, kv_pages=kv_pages,
         )
 
@@ -1951,7 +1590,7 @@ def llm_deployment(model_loader, *, num_slots: int = 4, max_len: int = 256,
                    default_max_new_tokens: int = 32, num_replicas: int = 1,
                    max_ongoing_requests: int = 64,
                    ray_actor_options: Optional[dict] = None,
-                   prefill_chunk: int = 64, kv_mode: Optional[str] = None,
+                   prefill_chunk: int = 64,
                    page_size: Optional[int] = None,
                    kv_pages: Optional[int] = None):
     """A ready-to-run continuous-batching LLM application.
@@ -1976,6 +1615,6 @@ def llm_deployment(model_loader, *, num_slots: int = 4, max_len: int = 256,
     return dep.bind(
         model_loader, num_slots=num_slots, max_len=max_len, eos_id=eos_id,
         default_max_new_tokens=default_max_new_tokens,
-        prefill_chunk=prefill_chunk, kv_mode=kv_mode,
+        prefill_chunk=prefill_chunk,
         page_size=page_size, kv_pages=kv_pages,
     )

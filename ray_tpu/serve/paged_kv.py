@@ -1,10 +1,16 @@
-"""Paged KV cache: the engine's memory plane as a first-class subsystem.
+"""Paged KV cache: the serving engine's memory plane and its step programs.
 
-The slotted cache (llm.init_slotted_cache) pins one whole `[max_len]`
-row per request: a 30-token chat holds the same HBM as a 4k-token
-document, concurrency is fixed at `num_slots` no matter the workload,
-and two requests sharing a prompt prefix each recompute and store it.
-This module replaces the row with PAGES — the vLLM PagedAttention idea
+The host half (`PagePool`, `PrefixCache`, the page hashes) decides which
+pages a request owns; the device half (`init_paged_cache`, `decode_paged`,
+`prefill_chunk_paged`, `cow_copy_page`, and the layer body, layer scan,
+sampler and routing counters they share) is everything the engine jits.
+`ray_tpu.serve.llm`, the host scheduler, imports this module; nothing
+here imports the scheduler.
+
+A request's K/V rows live in PAGES, not in one `[max_len]` row of its
+own, so a 30-token chat holds a 30-token footprint, concurrency follows
+the workload and not a fixed slot count, and two requests sharing a
+prompt prefix store it once: the vLLM PagedAttention idea
 (arXiv:2309.06180), built for the engine's TPU discipline of static
 shapes and zero steady-state host traffic:
 
@@ -13,7 +19,7 @@ shapes and zero steady-state host traffic:
     device. Decode gathers K/V *through* the block table (one gather
     per layer inside the jitted step); prefill scatters rows into the
     pages the table names. Program shapes depend only on the pool and
-    table geometry, so compilation stays bounded exactly as before.
+    table geometry, so compilation stays bounded.
     The pool is donated to the step and carried whole through the scan
     over layers; layer i scatters into `pool[i, pages, rows]` and
     gathers `pool[i, block_tables]`, so a step touches the rows it
@@ -40,16 +46,15 @@ default to it, inactive-slot decode writes park in it, and prefill
 padding rows drop into it — it is never gathered unmasked, so its
 contents are never observable.
 
-Bit-exactness with the slotted path: when `max_len % page_size == 0`
-the gathered attention width equals `max_len`, gathered row i of a slot
-is absolute position i (pages are table-ordered), and masked lanes
-underflow to exact 0.0 in the f32 softmax — the decode outputs are
-bit-identical, which tests/test_paged_kv.py pins against
-`RT_SERVE_KV=slotted`.
+Gathered row i of a slot is absolute position i (pages are table-
+ordered) and masked lanes underflow to exact 0.0 in the f32 softmax, so
+greedy decoding gives `models.generate`'s tokens, whose one-length cache
+is the plain reference (tests/test_paged_kv.py, tests/test_serve_llm.py).
 """
 
 from __future__ import annotations
 
+import functools
 import hashlib
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
@@ -60,13 +65,17 @@ import numpy as np
 
 from ray_tpu.models.transformer import (
     TransformerConfig,
+    _act,
     _embed_tokens,
     project_logits,
+    project_qkv,
 )
-from ray_tpu.ops import rmsnorm, rope_frequencies
+from ray_tpu.ops import apply_rope, rmsnorm, rope_frequencies
+from ray_tpu.parallel.moe import EXPERT_LEAVES, moe_block
 
 # The reserved NULL/scratch page (see module docstring).
 NULL_PAGE = 0
+NEG_INF = -1e30
 
 
 class OutOfPages(RuntimeError):
@@ -277,8 +286,8 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
                      page_size: int, pages_per_slot: int,
                      mesh=None) -> Dict:
     """Device state of the paged cache: the page pool, per-slot lengths,
-    and the block table (all entries NULL_PAGE). Sharding matches the
-    slotted cache: KV heads over "tp", everything else replicated."""
+    and the block table (all entries NULL_PAGE). KV heads shard over
+    "tp"; everything else is replicated."""
     shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads,
              cfg.head_dim)
     cache = {
@@ -304,23 +313,177 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
     return cache
 
 
+def _grouped_attention(q, kf, vf, valid):
+    """q [S, Lq, H, D] vs caches [S, Lk, KVH, D]; valid [S, Lq, Lk]."""
+    s_, lq, h, d = q.shape
+    kvh = kf.shape[2]
+    group = h // kvh
+    scale = d ** -0.5
+    qg = q.reshape(s_, lq, kvh, group, d).astype(jnp.float32)
+    scores = jnp.einsum("sqhgd,skhd->shgqk", qg, kf) * scale
+    scores = jnp.where(valid[:, None, None], scores, NEG_INF)
+    p = jax.nn.softmax(scores, axis=-1)
+    out = jnp.einsum("shgqk,skhd->sqhgd", p, vf).reshape(s_, lq, h, d)
+    return out.astype(q.dtype)
+
+
+def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
+                write_kv, valid, mesh=None, layer=None):
+    """One transformer layer, shared by the decode and prefill programs.
+
+    The two callers differ only in how K/V land in the cache and what
+    the attention source/mask is: `write_kv(kc, vc, k, v) -> (kc, vc,
+    k_att, v_att)` encapsulates that, `valid` is the caller's mask over
+    (B, Lq, Lk_att). `mesh` is the engine's: activations are replicated
+    over it, so the norm kernel runs whole on every device. Returns the
+    layer's output, its caches and, for a model with experts, the
+    assignments each expert received `[E]` (else None); `layer` is
+    `moe_block`'s: the index at which `lp`'s expert stacks, then the
+    whole model's, are read in place."""
+    b, l = x.shape[:2]
+    h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh)
+    q, k, v = project_qkv(h, lp, cfg)
+    q = apply_rope(q, cos, sin, positions)
+    k = apply_rope(k, cos, sin, positions)
+    k_cache_l, v_cache_l, k_att, v_att = write_kv(k_cache_l, v_cache_l, k, v)
+    attn = _grouped_attention(
+        q, k_att.astype(jnp.float32), v_att.astype(jnp.float32), valid
+    )
+    x = x + (attn.reshape(b, l, -1) @ lp["wo"]).astype(x.dtype)
+    h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh)
+    if cfg.num_experts:
+        y, routing = moe_block(h.reshape(b * l, -1), lp, cfg, layer)
+        return (x + y.reshape(b, l, -1), k_cache_l, v_cache_l,
+                routing["counts"])
+    gate = _act(cfg)((h @ lp["w_gate"]).astype(jnp.float32))
+    up = (h @ lp["w_up"]).astype(jnp.float32)
+    x = x + (((gate * up).astype(x.dtype)) @ lp["w_down"])
+    return x, k_cache_l, v_cache_l, None
+
+
+def _scan_layers(params, x, k_cache, v_cache, write_kv, cfg, cos, sin,
+                 positions, valid, mesh=None):
+    """Every layer in turn, the whole KV cache `[layers, ...]` riding in
+    the scan's carry: the one way a step threads its cache through the
+    layers. `write_kv(i, kc, vc, k, v)` is
+    `_layer_body`'s with the layer index in front; it writes and reads
+    the whole cache at `[i, ...]`.
+
+    A carry is one buffer from the first layer to the last, so with the
+    caches donated each layer's rows are scattered into the caller's own
+    buffer. Scanned over as `xs` and stacked back as `ys` a cache is two
+    buffers: every layer is sliced out of one and written into the
+    other, and the result copied back over the donated argument.
+
+    The expert stacks of a model that has them stay out of the scan for
+    the same reason: every layer reads them whole at its own index
+    (`moe_block`), where a layer sliced out for a grouped matmul would be
+    copied first. Also returns the assignments each layer's experts
+    received in this call `[layers, E]`, None for a dense model."""
+    layers, experts = params["layers"], {}
+    if cfg.num_experts:
+        experts = {n: layers[n] for n in EXPERT_LEAVES}
+        layers = {n: w for n, w in layers.items() if n not in experts}
+
+    def layer(carry, inputs):
+        x, kc, vc = carry
+        lp, i = inputs
+        x, kc, vc, counts = _layer_body(
+            x, {**lp, **experts}, kc, vc, cfg, cos, sin, positions,
+            functools.partial(write_kv, i), valid, mesh,
+            i if experts else None,
+        )
+        return (x, kc, vc), counts
+
+    index = jnp.arange(k_cache.shape[0], dtype=jnp.int32)
+    (x, k_cache, v_cache), counts = jax.lax.scan(
+        layer, (x, k_cache, v_cache), (layers, index)
+    )
+    return x, k_cache, v_cache, counts
+
+
+def init_routing_counters(cfg: TransformerConfig) -> Dict:
+    """The device-resident accumulator of a model with experts: what its
+    step programs add to at every call and `engine.stats()["moe"]` fetches,
+    so that nothing about routing leaves the device inside the loop."""
+    per_layer = jnp.zeros((cfg.n_layers,), jnp.int32)
+    return {
+        "assignments": jnp.zeros((cfg.n_layers, cfg.num_experts), jnp.int32),
+        "calls": jnp.zeros((), jnp.int32),
+        "experts_hit_sum": per_layer,
+        "max_load_sum": per_layer,
+    }
+
+
+def _count_routing(out, moe, counts):
+    """A step program's results with the routing accumulator `moe`
+    advanced by this call's `counts [layers, E]` appended; a caller that
+    passed no accumulator gets `out` as it is."""
+    if moe is None:
+        return out
+    return (*out, {
+        "assignments": moe["assignments"] + counts,
+        "calls": moe["calls"] + 1,
+        "experts_hit_sum": moe["experts_hit_sum"] + (counts > 0).sum(-1),
+        "max_load_sum": moe["max_load_sum"] + counts.max(-1),
+    })
+
+
+MAX_TOP_K = 64  # per-slot top-k cap (static shape for lax.top_k)
+
+
+def _pick_tokens(logits, temps, top_ks, top_ps, key):
+    """Per-slot next-token selection on device: greedy where temp == 0,
+    else temperature-scaled sampling with optional per-slot top-k
+    (0 = off, capped at MAX_TOP_K) and top-p (1.0 = off) filtering —
+    generate.py's sampling semantics, vectorized over slots so mixed
+    greedy/sampled requests share one decode batch."""
+    logits = logits.astype(jnp.float32)
+    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
+    # top-k: threshold each row at its k-th largest value. The static k
+    # clamps to the vocab so models with vocab_size < MAX_TOP_K don't
+    # crash the jitted step (lax.top_k requires k <= last dim).
+    k = min(MAX_TOP_K, logits.shape[-1])
+    topv = jax.lax.top_k(scaled, k)[0]  # [S, K] sorted desc
+    idx = jnp.clip(top_ks - 1, 0, k - 1)
+    kth = jnp.take_along_axis(topv, idx[:, None], axis=-1)
+    scaled = jnp.where((top_ks > 0)[:, None] & (scaled < kth),
+                       -jnp.inf, scaled)
+    # top-p: smallest prefix of the sorted distribution reaching p.
+    sorted_l = jnp.sort(scaled, axis=-1)[:, ::-1]
+    probs = jax.nn.softmax(sorted_l, axis=-1)
+    cum = jnp.cumsum(probs, axis=-1)
+    keep = (cum - probs) < top_ps[:, None]
+    thr = jnp.min(jnp.where(keep, sorted_l, jnp.inf), axis=-1,
+                  keepdims=True)
+    scaled = jnp.where(scaled < thr, -jnp.inf, scaled)
+    sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
+    return jnp.where(temps > 0, sampled, greedy)
+
+
 def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
                  block_tables, temps, top_ks, top_ps, key,
                  cfg: TransformerConfig, max_len: int, mesh=None, moe=None):
-    """One decode step for every slot, K/V gathered through the block
-    table — the paged twin of llm._decode_slots (same contract: same
-    inputs plus the table, same outputs).
+    """One decode step for every slot at once, K/V gathered through the
+    block table.
+
+    tokens [S] int32 (last emitted per slot; 0 for inactive), lengths
+    [S] (current valid cache rows per slot), active [S] bool. Returns
+    (next_tokens [S], k_pages, v_pages, new_lengths), the pool updated
+    in place. With `moe`, a model with experts' routing accumulator, the
+    advanced accumulator comes back as a fifth result (`_count_routing`).
 
     Each active slot writes its new K/V row into page
     `block_tables[slot, lengths[slot] // page_size]` at row
     `lengths[slot] % page_size`; inactive slots park the write in the
-    NULL page. Attention gathers the slot's whole table (width =
-    pages_per_slot * page_size) and masks by length, exactly like the
-    slotted step masks its `max_len` row."""
-    from ray_tpu.serve.llm import (  # local import: llm imports us too
-        _count_routing, _pick_tokens, _scan_layers,
-    )
+    NULL page and keep their length. Attention gathers the slot's whole
+    table (width = pages_per_slot * page_size) and masks by length.
 
+    The next token is computed ON DEVICE so the engine can feed it
+    straight into the next dispatched step without a host round trip.
+    temps=None compiles the greedy-only program: no top-k/sort/softmax
+    work on the all-greedy path."""
     s_ = tokens.shape[0]
     ps = k_pages.shape[2]
     mp = block_tables.shape[1]
@@ -368,19 +531,21 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
                         v_pages, lengths, block_tables,
                         cfg: TransformerConfig, max_len: int, mesh=None,
                         moe=None):
-    """Chunked prefill into pages — the paged twin of
-    llm._prefill_chunk. Chunk rows scatter into the pages the slot's
-    block-table row names (padding rows and anything past `max_len`
-    drop into the NULL page, the paged equivalent of mode="drop");
-    queries attend causally against the slot's gathered page run.
+    """CHUNKED prefill: one fixed-size chunk of a prompt into slot
+    `slot` at row `offset`, so that a long prompt's prefill interleaves
+    with other slots' decode steps instead of stalling them.
+
+    tokens [1, C] int32 (first n_valid real). Chunk rows scatter into
+    the pages the slot's block-table row names (padding rows and
+    anything past `max_len` drop into the NULL page); queries attend
+    causally against the slot's gathered page run, earlier chunks
+    included. Sets lengths[slot] = offset + n_valid and returns the
+    logits of the chunk's last REAL position [1, vocab] (meaningful on
+    the final chunk), the pool and the lengths (and the advanced `moe`).
 
     Prefix-cache resumption needs nothing special here: the engine
     starts `offset` at the shared-prefix boundary and the gathered
     pages already hold the donor's K/V rows below it."""
-    from ray_tpu.serve.llm import (  # local import (cycle)
-        _count_routing, _scan_layers,
-    )
-
     _, c = tokens.shape
     ps = k_pages.shape[2]
     mp = block_tables.shape[1]

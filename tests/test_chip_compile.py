@@ -147,16 +147,15 @@ def test_kernels_compile_under_a_sharded_jit(v5e):
 # of 1024 positions, pages of 16 rows and the NULL page, chunks of 64. Depth
 # is cut to 4: what is asserted does not depend on it.
 SLOTS, MAX_LEN, PAGE, CHUNK, DEPTH = 24, 1024, 16, 64, 4
-ENGINE_PROGRAMS = ["decode_paged", "prefill_chunk_paged",
-                   "decode_slots", "prefill_chunk"]
+ENGINE_PROGRAMS = ["decode_paged", "prefill_chunk_paged"]
 
 
 def _engine_program(name, cfg, one):
     """(function, donated arguments, argument shapes, the cache's shape)
-    of one of the engine's four step programs, as `ContinuousBatchingEngine`
+    of one of the engine's step programs, as `ContinuousBatchingEngine`
     jits it on one chip; decode is the sampled variant."""
     from ray_tpu.models.transformer import init_params
-    from ray_tpu.serve import llm, paged_kv
+    from ray_tpu.serve import paged_kv
 
     def struct(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -165,34 +164,24 @@ def _engine_program(name, cfg, one):
         lambda a: struct(a.shape, a.dtype),
         jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
     per_slot = MAX_LEN // PAGE
-    kv = (cfg.n_kv_heads, cfg.head_dim)
-    paged = name.endswith("_paged")
-    shape = ((cfg.n_layers, SLOTS * per_slot + 1, PAGE, *kv) if paged
-             else (cfg.n_layers, SLOTS, MAX_LEN, *kv))
+    shape = (cfg.n_layers, SLOTS * per_slot + 1, PAGE, cfg.n_kv_heads,
+             cfg.head_dim)
     cache = struct(shape, cfg.dtype)
     lengths = struct((SLOTS,))
-    table = (struct((SLOTS, per_slot)),) if paged else ()
-    if name.startswith("decode"):
+    table = struct((SLOTS, per_slot))
+    if name == "decode_paged":
         sampling = (struct((SLOTS,), jnp.float32), struct((SLOTS,)),
                     struct((SLOTS,), jnp.float32), struct((2,), jnp.uint32))
         args = (params, struct((SLOTS,)), cache, cache, lengths,
-                struct((SLOTS,), jnp.bool_), *table, *sampling)
-        if paged:
-            fn = lambda p, t, k, v, ln, a, bt, *s: paged_kv.decode_paged(  # noqa: E731
-                p, t, k, v, ln, a, bt, *s, cfg, MAX_LEN)
-        else:
-            fn = lambda p, t, k, v, ln, a, *s: llm._decode_slots(  # noqa: E731
-                p, t, k, v, ln, a, *s, cfg)
+                struct((SLOTS,), jnp.bool_), table, *sampling)
+        fn = lambda p, t, k, v, ln, a, bt, *s: paged_kv.decode_paged(  # noqa: E731
+            p, t, k, v, ln, a, bt, *s, cfg, MAX_LEN)
         return fn, (2, 3), args, shape
     scalar = struct(())
     args = (params, struct((1, CHUNK)), scalar, scalar, scalar, cache, cache,
-            lengths, *table)
-    if paged:
-        fn = lambda p, t, n, s, o, k, v, ln, bt: paged_kv.prefill_chunk_paged(  # noqa: E731
-            p, t, n, s, o, k, v, ln, bt, cfg, MAX_LEN)
-    else:
-        fn = lambda p, t, n, s, o, k, v, ln: llm._prefill_chunk(  # noqa: E731
-            p, t, n, s, o, k, v, ln, cfg)
+            lengths, table)
+    fn = lambda p, t, n, s, o, k, v, ln, bt: paged_kv.prefill_chunk_paged(  # noqa: E731
+        p, t, n, s, o, k, v, ln, bt, cfg, MAX_LEN)
     return fn, (5, 6), args, shape
 
 

@@ -175,8 +175,7 @@ def reference_logits(params, tokens):
 
 def engine_for(params, cfg, **kw):
     return ContinuousBatchingEngine(params, cfg, num_slots=2, max_len=64,
-                                    prefill_chunk=8, kv_mode="paged",
-                                    page_size=8, **kw)
+                                    prefill_chunk=8, page_size=8, **kw)
 
 
 PROMPT = [int(t) for t in np.random.default_rng(11).integers(0, 256, 21)]

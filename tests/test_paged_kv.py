@@ -1,8 +1,9 @@
 """Paged KV engine: block-table allocator, prefix cache, affinity
 routing pieces, and the ServeSignals-driven autoscaler.
 
-Covers the PR's acceptance list: bit-exact paged-vs-slotted decode on
-mixed-length batches, zero page leak over 1k admit/evict cycles,
+Covers: paged decode token for token against `generate()` on a concurrent
+mixed-length batch, the one-way import (this module never loads the
+scheduler), zero page leak over 1k admit/evict cycles,
 prefix-share correctness when the donor's cache entries are evicted
 mid-share, typed prompt rejection (+ proxy 413 mapping), chaos KV
 hooks, autoscaler hysteresis with a fake clock, and the schema-v2
@@ -98,27 +99,109 @@ def test_prefix_cache_match_insert_evict():
     assert cache.pages_held == 0 and pool.in_use == 0
 
 
-# -- engine: bit-exactness ------------------------------------------------
-def test_paged_vs_slotted_bit_exact_mixed_lengths():
-    """The paged decode must produce token-for-token identical output to
-    the slotted baseline for a concurrent mixed-length batch."""
+# -- engine: token parity with the plain reference ------------------------
+def test_paged_engine_matches_generate_on_mixed_lengths():
+    """The paged engine, decoding a concurrent mixed-length batch greedily,
+    gives token for token what `generate()` gives each prompt alone over
+    its simple one-length cache (the plain reference). The longest prompt
+    crosses a page boundary while it decodes."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import generate
     from ray_tpu.serve.llm import ContinuousBatchingEngine
 
     params, cfg = _tiny_model()
     prompts = [[1, 2, 3], [5, 6, 7, 8, 9, 10, 11], [4],
                [9, 9, 2, 1, 3, 3, 7, 7, 1, 2, 3, 4, 5, 6, 7, 8, 9, 10]]
-    outs = {}
-    for mode in ("slotted", "paged"):
-        eng = ContinuousBatchingEngine(
-            params, cfg, num_slots=4, max_len=64, kv_mode=mode,
-            page_size=16,
-        )
-        try:
-            handles = [eng.submit(p, max_new_tokens=8) for p in prompts]
-            outs[mode] = [h.result(timeout=180) for h in handles]
-        finally:
-            eng.shutdown()
-    assert outs["paged"] == outs["slotted"]
+    refs = [
+        np.asarray(generate(params, jnp.asarray([p], dtype=jnp.int32), cfg,
+                            max_new_tokens=8))[0].tolist()
+        for p in prompts
+    ]
+    eng = ContinuousBatchingEngine(
+        params, cfg, num_slots=4, max_len=64, page_size=16,
+    )
+    try:
+        handles = [eng.submit(p, max_new_tokens=8) for p in prompts]
+        outs = [h.result(timeout=180) for h in handles]
+    finally:
+        eng.shutdown()
+    assert outs == refs
+
+
+# -- one arrow: the scheduler imports the programs, never the reverse -----
+_TRACE_WITHOUT_THE_SCHEDULER = """
+import sys
+import jax, jax.numpy as jnp
+from ray_tpu.models import configs, init_params
+from ray_tpu.serve import paged_kv
+
+cfg = configs.tiny_qwen
+slots, ps, mp, max_len, chunk = 2, 4, 4, 16, 8
+params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
+cache = jax.eval_shape(
+    lambda: paged_kv.init_paged_cache(cfg, slots, slots * mp + 1, ps, mp))
+k, v, ln, bt = (cache[n] for n in ("k", "v", "lengths", "block_tables"))
+i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+f32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.float32)
+out = jax.eval_shape(
+    lambda p, t, k, v, ln, a, bt, tp, tk, tpp, key: paged_kv.decode_paged(
+        p, t, k, v, ln, a, bt, tp, tk, tpp, key, cfg, max_len),
+    params, i32(slots), k, v, ln, jax.ShapeDtypeStruct((slots,), jnp.bool_),
+    bt, f32(slots), i32(slots), f32(slots),
+    jax.ShapeDtypeStruct((2,), jnp.uint32))
+assert out[0].shape == (slots,) and out[1].shape == k.shape
+out = jax.eval_shape(
+    lambda p, t, n, s, o, k, v, ln, bt: paged_kv.prefill_chunk_paged(
+        p, t, n, s, o, k, v, ln, bt, cfg, max_len),
+    params, i32(1, chunk), i32(), i32(), i32(), k, v, ln, bt)
+assert out[0].shape == (1, cfg.vocab_size) and out[1].shape == k.shape
+loaded = sorted(m for m in sys.modules if m.startswith("ray_tpu.serve.llm"))
+assert not loaded, loaded
+print("TRACED-WITHOUT-LLM")
+"""
+
+
+def test_step_programs_trace_without_importing_the_scheduler():
+    """In a fresh interpreter `ray_tpu.serve.paged_kv` traces both step
+    programs (sampled decode, a prefill chunk) at `configs.tiny_qwen`
+    and `ray_tpu.serve.llm` is never loaded: the device code has one home
+    and the host scheduler imports it, not the other way round."""
+    import os
+    import subprocess
+    import sys
+
+    env = dict(os.environ, JAX_PLATFORMS="cpu")
+    done = subprocess.run(
+        [sys.executable, "-c", _TRACE_WITHOUT_THE_SCHEDULER], env=env,
+        capture_output=True, text=True, timeout=300,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert done.returncode == 0, done.stderr[-3000:]
+    assert "TRACED-WITHOUT-LLM" in done.stdout
+
+
+def test_the_deleted_kv_options_are_gone():
+    """One KV cache: the engine, the replica and the deployment name no
+    `kv_mode` and no `prefill_buckets` and swallow no `**kwargs`, so a
+    caller that passes one gets Python's TypeError, not an accepted-and-
+    ignored keyword; the config has no `serve_kv` to choose with."""
+    import dataclasses
+    import inspect
+
+    from ray_tpu._private.config import Config
+    from ray_tpu.serve.llm import (
+        ContinuousBatchingEngine,
+        LLMReplica,
+        llm_deployment,
+    )
+
+    for fn in (ContinuousBatchingEngine.__init__, LLMReplica.__init__,
+               llm_deployment):
+        names = inspect.signature(fn).parameters
+        assert "kv_mode" not in names and "prefill_buckets" not in names
+        assert not any(p.kind is p.VAR_KEYWORD for p in names.values())
+    assert "serve_kv" not in {f.name for f in dataclasses.fields(Config)}
 
 
 def _layerwise(params, cfg, x, k_pool, v_pool, write_kv, positions, valid,
@@ -132,7 +215,7 @@ def _layerwise(params, cfg, x, k_pool, v_pool, write_kv, positions, valid,
     import jax
 
     from ray_tpu.ops import rmsnorm, rope_frequencies
-    from ray_tpu.serve.llm import _layer_body
+    from ray_tpu.serve.paged_kv import _layer_body
 
     cos, sin = rope_frequencies(cfg.head_dim, max_len, cfg.rope_theta)
 
@@ -250,7 +333,7 @@ def test_zero_page_leak_over_1k_admit_evict_cycles():
 
     params, cfg = _tiny_model()
     eng = ContinuousBatchingEngine(
-        params, cfg, num_slots=8, max_len=16, kv_mode="paged", page_size=4,
+        params, cfg, num_slots=8, max_len=16, page_size=4,
     )
     try:
         done = 0
@@ -275,30 +358,36 @@ def test_zero_page_leak_over_1k_admit_evict_cycles():
         eng.shutdown()
 
 
-def test_prefix_cache_skips_prefill_for_shared_prompt():
-    """A repeat prompt hits the prefix cache, skips resident prefill
-    pages (the skipped-token counter says so) and still decodes the
-    same greedy tokens."""
+@pytest.mark.parametrize("prompt_len, full_pages, skipped", [
+    (20, 2, 16),   # two full pages and a tail: the tail is recomputed
+    (24, 3, 23),   # all full pages: everything but the last token, whose
+                   # row lands in a shared page (forked copy-on-write)
+])
+def test_prefix_cache_skips_prefill_for_shared_prompt(prompt_len, full_pages,
+                                                      skipped):
+    """A repeat prompt hits the prefix cache, skips exactly its resident
+    full pages' tokens (at most all but the last; the skipped-token
+    counter says how many) and still decodes the same greedy tokens."""
     from ray_tpu.serve.llm import ContinuousBatchingEngine
 
     params, cfg = _tiny_model()
     eng = ContinuousBatchingEngine(
-        params, cfg, num_slots=2, max_len=64, kv_mode="paged", page_size=8,
+        params, cfg, num_slots=2, max_len=64, page_size=8,
     )
     try:
-        prompt = [(3 * i + 1) % 50 for i in range(20)]  # 2 full pages
+        prompt = [(3 * i + 1) % 50 for i in range(prompt_len)]
         cold = eng.submit(prompt, max_new_tokens=6).result(timeout=180)
         # Wait for completion-side bookkeeping (insert happens at
         # prefill end; release at eviction).
         deadline = time.monotonic() + 10
-        while eng.stats()["kv"]["prefix_cache_pages"] < 2:
+        while eng.stats()["kv"]["prefix_cache_pages"] < full_pages:
             assert time.monotonic() < deadline
             time.sleep(0.02)
         warm = eng.submit(prompt, max_new_tokens=6).result(timeout=180)
         assert warm == cold
         kv = eng.stats()["kv"]
-        assert kv["prefix_hits"] >= 1  # one hit event per request
-        assert kv["prefill_tokens_skipped"] >= 8
+        assert kv["prefix_hits"] == 1  # one hit event per request
+        assert kv["prefill_tokens_skipped"] == skipped
         assert kv["prefix_hit_rate"] > 0
     finally:
         eng.shutdown()
@@ -314,7 +403,7 @@ def test_prefix_share_survives_donor_eviction_mid_share():
 
     params, cfg = _tiny_model()
     eng = ContinuousBatchingEngine(
-        params, cfg, num_slots=2, max_len=96, kv_mode="paged", page_size=8,
+        params, cfg, num_slots=2, max_len=96, page_size=8,
     )
     chaos.enable()
     try:
@@ -348,7 +437,7 @@ def test_chaos_exhaust_kv_pages_blocks_then_releases_admission():
 
     params, cfg = _tiny_model()
     eng = ContinuousBatchingEngine(
-        params, cfg, num_slots=2, max_len=32, kv_mode="paged", page_size=8,
+        params, cfg, num_slots=2, max_len=32, page_size=8,
     )
     chaos.enable()
     try:
@@ -377,7 +466,7 @@ def test_prompt_too_long_is_typed_and_bounded_by_pool():
     params, cfg = _tiny_model()
     # Pool smaller than max_len: 3 usable pages x 8 = 24 positions.
     eng = ContinuousBatchingEngine(
-        params, cfg, num_slots=1, max_len=64, kv_mode="paged", page_size=8,
+        params, cfg, num_slots=1, max_len=64, page_size=8,
         kv_pages=4,
     )
     try:
@@ -548,27 +637,19 @@ def test_engine_stats_expose_kv_plane_for_signals():
 
     params, cfg = _tiny_model()
     eng = ContinuousBatchingEngine(
-        params, cfg, num_slots=2, max_len=32, kv_mode="paged", page_size=8,
+        params, cfg, num_slots=2, max_len=32, page_size=8,
     )
     try:
         eng.submit([(11 * i + 1) % 40 for i in range(16)],
                    max_new_tokens=2).result(timeout=180)
         kv = eng.stats()["kv"]
         assert kv["mode"] == "paged" and kv["page_size"] == 8
-        assert kv["pages_total"] == 2 * 4  # slotted-HBM parity
+        assert kv["pages_total"] == 2 * 4  # max_len rows for every slot
         assert kv["prefix_cache_pages"] == 2  # the prompt's full pages
         assert kv["roots"]  # advertised for affinity routing
         assert 0.0 <= kv["util"] <= 1.0
     finally:
         eng.shutdown()
-
-    slotted = ContinuousBatchingEngine(
-        params, cfg, num_slots=2, max_len=32, kv_mode="slotted",
-    )
-    try:
-        assert slotted.stats()["kv"] == {"mode": "slotted", "page_size": 0}
-    finally:
-        slotted.shutdown()
 
 
 def test_handle_affinity_prefers_covering_replica():

@@ -323,3 +323,59 @@ def test_proxy_maps_typed_errors_to_status_codes(serve_session):
     code, _, body = _post(addr, "napper", {"s": 0.0})
     assert code == 200
     assert body["result"] == 0.0
+
+
+def test_concurrent_reconciles_start_each_replica_once(monkeypatch):
+    """deploy() reconciles on an actor-call thread while the controller's
+    loop reconciles on its own: two passes that both read "0 of 1
+    replicas" each started one, and on one chip the second stayed PENDING
+    for ever with serve.run waiting for it (about one deploy in 16 on the
+    chip). The passes are serialized: one app, target 1, two passes at
+    once, one replica started and recorded."""
+    import threading
+    from types import SimpleNamespace
+
+    from ray_tpu.serve import controller as controller_mod
+
+    started = []
+
+    class SlowReplicaActor:
+        """`ReplicaActor.options(...).remote(...)`, slow enough that the
+        second pass reads the count before the first records its own."""
+
+        @classmethod
+        def options(cls, **_):
+            return cls
+
+        @classmethod
+        def remote(cls, *_):
+            time.sleep(0.2)
+            started.append(object())
+            return started[-1]
+
+    monkeypatch.setattr(controller_mod, "ReplicaActor", SlowReplicaActor)
+    ctl = object.__new__(controller_mod.ServeController._cls)
+    ctl._lock = threading.Lock()
+    ctl._reconcile_lock = threading.Lock()
+    ctl.apps = {"app": {
+        "deployment": SimpleNamespace(
+            ray_actor_options={}, max_ongoing_requests=8,
+            func_or_class=object, user_config=None),
+        "init_args": (), "init_kwargs": {}, "replicas": [], "version": 0,
+        "target": 1,
+    }}
+    for name in ("_publish_routes", "_checkpoint", "_drain_then_kill"):
+        monkeypatch.setattr(ctl, name, lambda *a, **k: None, raising=False)
+    barrier = threading.Barrier(2)
+
+    def one_pass():
+        barrier.wait()
+        ctl._reconcile_once("app")
+
+    threads = [threading.Thread(target=one_pass) for _ in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=30)
+    assert len(started) == 1
+    assert ctl.apps["app"]["replicas"] == started

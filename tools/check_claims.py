@@ -170,15 +170,6 @@ def _bench_collective(metric_sub: str, field: str):
     return get
 
 
-def _bench_paged_kv(metric_sub: str, field: str):
-    def get():
-        for e in _load("BENCH_PAGED_KV.json"):
-            if metric_sub in e.get("metric", ""):
-                return e[field]
-        raise KeyError(f"no BENCH_PAGED_KV entry matching {metric_sub!r}")
-    return get
-
-
 def _bench_serve_macro(metric_sub: str, field: str):
     def get():
         for e in _load("BENCH_SERVE_MACRO.json"):
@@ -539,45 +530,6 @@ CLAIMS = [
     Claim("MIGRATION.md", r"recursive doubling beats it (\d+\.\d+)×",
           _bench_collective("rd vs ring latency", "speedup"),
           rel_tol=0.5, note="wall-clock ratio under injected latency"),
-    # Paged KV engine <- BENCH_PAGED_KV.json (bench_paged_kv.py).
-    # Peak concurrency, skipped-token and page counts are deterministic
-    # (tight pins); TTFT and the scale-up time are wall clock (loose).
-    Claim("MIGRATION.md", r"peaks at (\d+) concurrent requests paged",
-          _bench_paged_kv("mixed-length peak", "paged_peak_concurrent"),
-          rel_tol=0.0),
-    Claim("MIGRATION.md", r"vs (\d+) slotted \(gate",
-          _bench_paged_kv("mixed-length peak", "slotted_peak_concurrent"),
-          rel_tol=0.0),
-    Claim("MIGRATION.md", r"first token (\d+\.\d+)× faster",
-          _bench_paged_kv("shared-prefix TTFT", "speedup"),
-          rel_tol=0.5, note="wall-clock ratio on a shared box"),
-    Claim("MIGRATION.md", r"\((\d+\.\d+) ms warm",
-          _bench_paged_kv("shared-prefix TTFT", "warm_ttft_ms"),
-          rel_tol=1.0, note="ms-scale wall clock on a shared box"),
-    Claim("MIGRATION.md", r"(\d+\.\d+) ms cold",
-          _bench_paged_kv("shared-prefix TTFT", "cold_ttft_ms"),
-          rel_tol=1.0, note="ms-scale wall clock on a shared box"),
-    Claim("MIGRATION.md", r"counter reading exactly (\d+) tokens",
-          _bench_paged_kv("shared-prefix TTFT", "prefill_tokens_skipped"),
-          rel_tol=0.0),
-    Claim("MIGRATION.md", r"is (0\.\d+) blocked slot-seconds",
-          _bench_paged_kv("head-of-line", "hol_blocked_s"),
-          rel_tol=0.0),
-    Claim("MIGRATION.md", r"app to (\d+) replicas in",
-          _bench_paged_kv("autoscaler ramp", "peak_replicas"),
-          rel_tol=0.4, note="peak depends on ramp timing; gate is >= 2"),
-    Claim("MIGRATION.md", r"replicas in (\d+\.\d+) s under",
-          _bench_paged_kv("autoscaler ramp", "scale_up_s"),
-          rel_tol=1.5, note="wall clock against a 0.5 s signals tick"),
-    Claim("MIGRATION.md", r"(\d+) lost non-shed requests; and",
-          _bench_paged_kv("autoscaler ramp", "lost_non_shed"),
-          rel_tol=0.0),
-    Claim("MIGRATION.md", r"(\d+) resident cache pages",
-          _bench_paged_kv("page-leak", "cache_pages_flushed"),
-          rel_tol=0.0),
-    Claim("MIGRATION.md", r"exactly (\d+) pages in use",
-          _bench_paged_kv("page-leak", "pages_in_use_after"),
-          rel_tol=0.0),
     # -- serve macro (cluster witness) claims -> BENCH_SERVE_MACRO.json
     Claim("MIGRATION.md", r"sustains (\d+\.\d+) QPS achieved",
           _bench_serve_macro("sustained macro", "achieved_qps"),
