@@ -430,6 +430,59 @@ def _count_routing(out, moe, counts):
 
 
 MAX_TOP_K = 64  # per-slot top-k cap (static shape for lax.top_k)
+_SIGN_BIT, _ALL_BITS = np.uint32(1 << 31), np.uint32(0xFFFFFFFF)
+
+
+def _ordered_bits(x):
+    """float32 -> uint32 whose unsigned order is the floats' order: the
+    sign bit set on a positive, every bit flipped on a negative.
+    `_from_ordered_bits` is its inverse. -0.0 is folded into +0.0, so
+    that equal floats have equal images."""
+    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.uint32)
+    return bits ^ jnp.where(bits >= _SIGN_BIT, _ALL_BITS, _SIGN_BIT)
+
+
+def _from_ordered_bits(u):
+    bits = u ^ jnp.where(u >= _SIGN_BIT, _SIGN_BIT, _ALL_BITS)
+    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+
+
+def _top_p_threshold(scaled, top_ps):
+    """Top-p's cut for every row of `scaled [S, V]` float32 (logits over
+    the temperature, `-inf` where top-k masked): `thr [S, 1]`, the
+    smallest of the row's values `v` such that the softmax mass of the
+    entries strictly above `v` is under `top_ps [S]`. Keeping
+    `scaled >= thr` keeps the token that crosses `top_p` and every tie at
+    the cut, which is what sorting the row, a `cumsum` over the sorted
+    softmax and `min(where(cum - probs < top_p, sorted, inf))` give.
+
+    No sort: the mass above `v` only falls as `v` rises, so the cut is
+    found by bisection over the ordered integer image of float32, from
+    `-inf` to the row's maximum. Each pass is one masked sum over the row,
+    and 32 of them (float32's width, nothing to set) end on one
+    representable value, which is a value of the row: between two
+    neighbouring values of the row the mass above does not change, so the
+    smallest passing cut sits on one (or on `-inf`, which keeps the row).
+    Values are compared as integers, so nothing depends on how the
+    device treats denormals. Agrees with the sort but where a partial sum
+    lands within float32 rounding of `top_p`, which the sort's `cumsum`
+    decides by its summation order too."""
+    top = jnp.max(scaled, axis=-1, keepdims=True)
+    weights = jnp.exp(scaled - top)
+    budget = top_ps[:, None] * jnp.sum(weights, axis=-1, keepdims=True)
+    image = _ordered_bits(scaled)
+
+    def halve(_, bounds):
+        lo, hi = bounds  # the cut is in [lo, hi]; `hi` passes
+        mid = lo + (hi - lo) // 2
+        above = jnp.sum(jnp.where(image > mid, weights, 0.0), axis=-1,
+                        keepdims=True)
+        passes = above < budget
+        return jnp.where(passes, lo, mid + 1), jnp.where(passes, mid, hi)
+
+    bounds = _ordered_bits(jnp.full_like(top, -jnp.inf)), _ordered_bits(top)
+    _, cut = jax.lax.fori_loop(0, 32, halve, bounds)
+    return _from_ordered_bits(cut)
 
 
 def _pick_tokens(logits, temps, top_ks, top_ps, key):
@@ -437,7 +490,9 @@ def _pick_tokens(logits, temps, top_ks, top_ps, key):
     else temperature-scaled sampling with optional per-slot top-k
     (0 = off, capped at MAX_TOP_K) and top-p (1.0 = off) filtering —
     generate.py's sampling semantics, vectorized over slots so mixed
-    greedy/sampled requests share one decode batch."""
+    greedy/sampled requests share one decode batch. Top-k masks first,
+    top-p cuts the softmax of what is left; the cut is found without
+    sorting the vocabulary and equals the sort's (`_top_p_threshold`)."""
     logits = logits.astype(jnp.float32)
     greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
@@ -450,13 +505,7 @@ def _pick_tokens(logits, temps, top_ks, top_ps, key):
     kth = jnp.take_along_axis(topv, idx[:, None], axis=-1)
     scaled = jnp.where((top_ks > 0)[:, None] & (scaled < kth),
                        -jnp.inf, scaled)
-    # top-p: smallest prefix of the sorted distribution reaching p.
-    sorted_l = jnp.sort(scaled, axis=-1)[:, ::-1]
-    probs = jax.nn.softmax(sorted_l, axis=-1)
-    cum = jnp.cumsum(probs, axis=-1)
-    keep = (cum - probs) < top_ps[:, None]
-    thr = jnp.min(jnp.where(keep, sorted_l, jnp.inf), axis=-1,
-                  keepdims=True)
+    thr = _top_p_threshold(scaled, top_ps)
     scaled = jnp.where(scaled < thr, -jnp.inf, scaled)
     sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
     return jnp.where(temps > 0, sampled, greedy)

@@ -150,7 +150,7 @@ SLOTS, MAX_LEN, PAGE, CHUNK, DEPTH = 24, 1024, 16, 64, 4
 ENGINE_PROGRAMS = ["decode_paged", "prefill_chunk_paged"]
 
 
-def _engine_program(name, cfg, one):
+def _engine_program(name, cfg, one, slots=SLOTS):
     """(function, donated arguments, argument shapes, the cache's shape)
     of one of the engine's step programs, as `ContinuousBatchingEngine`
     jits it on one chip; decode is the sampled variant."""
@@ -164,16 +164,16 @@ def _engine_program(name, cfg, one):
         lambda a: struct(a.shape, a.dtype),
         jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
     per_slot = MAX_LEN // PAGE
-    shape = (cfg.n_layers, SLOTS * per_slot + 1, PAGE, cfg.n_kv_heads,
+    shape = (cfg.n_layers, slots * per_slot + 1, PAGE, cfg.n_kv_heads,
              cfg.head_dim)
     cache = struct(shape, cfg.dtype)
-    lengths = struct((SLOTS,))
-    table = struct((SLOTS, per_slot))
+    lengths = struct((slots,))
+    table = struct((slots, per_slot))
     if name == "decode_paged":
-        sampling = (struct((SLOTS,), jnp.float32), struct((SLOTS,)),
-                    struct((SLOTS,), jnp.float32), struct((2,), jnp.uint32))
-        args = (params, struct((SLOTS,)), cache, cache, lengths,
-                struct((SLOTS,), jnp.bool_), table, *sampling)
+        sampling = (struct((slots,), jnp.float32), struct((slots,)),
+                    struct((slots,), jnp.float32), struct((2,), jnp.uint32))
+        args = (params, struct((slots,)), cache, cache, lengths,
+                struct((slots,), jnp.bool_), table, *sampling)
         fn = lambda p, t, k, v, ln, a, bt, *s: paged_kv.decode_paged(  # noqa: E731
             p, t, k, v, ln, a, bt, *s, cfg, MAX_LEN)
         return fn, (2, 3), args, shape
@@ -205,6 +205,34 @@ def test_engine_step_updates_its_cache_in_place(v5e, program):
     whole_cache_copies = re.findall(
         rf"= bf16\[{dims}\]\S* copy\(", compiled.as_text())
     assert not whole_cache_copies
+
+
+@pytest.mark.parametrize("model,slots,depth", [
+    ("qwen3-4b", 24, DEPTH), ("olmoe-1b-7b", 16, 2)])
+def test_sampled_decode_sorts_no_vocabulary(v5e, model, slots, depth,
+                                            monkeypatch):
+    """Top-p's threshold is found by bisection (`_top_p_threshold`), so
+    the sampled decode program at a serving cell's slots and vocabulary
+    has no sort over the vocabulary: with one (`sort.6 f32[24,151936]`)
+    it was the largest single name on the device in both Qwen3 cells,
+    5.7 ms of a 28 ms step. What `lax.top_k` compiles to is its own
+    custom fusion over `[slots, MAX_TOP_K]` and stays."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    cfg = dataclasses.replace(configs.get_config(model), n_layers=depth)
+    fn, donated, args, _ = _engine_program(
+        "decode_paged", cfg, SingleDeviceSharding(v5e[0]), slots)
+    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
+    vocabulary_wide = re.compile(rf"[\[,]{cfg.vocab_size}\]")
+    sorts = [line for line in compiled.as_text().splitlines()
+             if " sort(" in line and vocabulary_wide.search(line)]
+    assert not sorts
+    if not cfg.num_experts:
+        # The sorted float32 copy and its cumsum went with it: what is
+        # left is under one float32 `[slots, vocabulary]` (0.76 MB where
+        # the sort's program had 15.26 MB). OLMoE's temporaries are
+        # attention's gathered cache in float32 (136 MB) either way.
+        one_copy = 4 * slots * cfg.vocab_size
+        assert compiled.memory_analysis().temp_size_in_bytes < one_copy
 
 
 @pytest.mark.parametrize("program", ["decode_paged", "prefill_chunk_paged"])
