@@ -57,9 +57,33 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from reference.draws import normal, ones
+
 F32 = jnp.float32
 AUX_COEFFICIENT = 0.01
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
+
+
+# The model's initialisation as the program's `init_params` has it: the
+# input projections, the router and the untied head at d**-0.5, the two
+# projections that write the residual stream at d**-0.5 * (2L)**-0.5, the
+# embedding table at 1, the norm scales ones.
+_PROJECTIONS = ("wq", "wk", "wv", "router", "w_gate", "w_up", "lm_head")
+_RESIDUAL_WRITERS = ("wo", "w_down")
+
+
+def leaf_init(path, m: Dict):
+    """The rule (reference/draws.py) by which bench/weights.py draws the
+    leaf at `path`, the tuple of keys from the root of the program's
+    parameter tree; `m` is `dims`."""
+    name, base = path[-1], m["d_model"] ** -0.5
+    if name in _PROJECTIONS:
+        return (normal, base)
+    if name in _RESIDUAL_WRITERS:
+        return (normal, base * (2 * m["n_layers"]) ** -0.5)
+    if name == "embed":
+        return (normal, 1.0)
+    return (ones,)
 
 
 def _rmsnorm(x, w, eps):

@@ -39,7 +39,32 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from reference.draws import normal, ones
+
 F32 = jnp.float32
+
+
+# The model's initialisation as the program's `init_params` has it: the
+# input projections and the head at d**-0.5, the two projections that write
+# the residual stream at d**-0.5 * (2L)**-0.5, a tied embedding table at
+# the head's scale (PERF.md finding 7) and an untied one at 1, the norm
+# scales ones.
+_PROJECTIONS = ("wq", "wk", "wv", "w_gate", "w_up", "lm_head")
+_RESIDUAL_WRITERS = ("wo", "w_down")
+
+
+def leaf_init(path, m: Dict):
+    """The rule (reference/draws.py) by which bench/weights.py draws the
+    leaf at `path`, the tuple of keys from the root of the program's
+    parameter tree; `m` is `dims` and `tie_embeddings`."""
+    name, base = path[-1], m["d_model"] ** -0.5
+    if name in _PROJECTIONS:
+        return (normal, base)
+    if name in _RESIDUAL_WRITERS:
+        return (normal, base * (2 * m["n_layers"]) ** -0.5)
+    if name == "embed":
+        return (normal, base if m["tie_embeddings"] else 1.0)
+    return (ones,)
 
 
 def _rmsnorm(x, w, eps):

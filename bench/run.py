@@ -184,7 +184,8 @@ def main(argv=None) -> int:
     redirect_children_output(log_path)
     say(f"{cell['name']}: config {cell['config_name']} (reference "
         f"{cell['config']['reference']}, operations "
-        f"{cell['config']['operations']}), traffic "
+        f"{cell['config']['operations']}, probe "
+        f"{cell['config']['probe']}), traffic "
         f"{cell['traffic']['name']}, {cell['chips']} chip(s), seed "
         f"{args.seed}, {args.seconds:g}s, trace {args.trace}, platform "
         f"{args.platform}; detail in {os.path.relpath(run_dir, REPO)}")

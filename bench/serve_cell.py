@@ -44,17 +44,19 @@ class BenchReplica(LLMReplica):
         cfg = spec.program_config(config, platform)
         self._dims = spec.dims_of(cfg, config)
         self._reference = spec.named_module(config, "reference")
+        self._probe = spec.named_module(config, "probe")
+        leaf_rule = spec.leaf_rules(cfg, config)
         t0 = time.monotonic()
 
         def loader():
             from dataclasses import replace
 
-            return weights.make_params(cfg, seed), replace(cfg, remat=False)
+            return (weights.make_params(cfg, seed, leaf_rule),
+                    replace(cfg, remat=False))
 
         super().__init__(loader, **config["engine"])
         self._ready_s = time.monotonic() - t0
         self._trace = None
-        self._probe = None
 
     def about(self) -> Dict:
         import jax
@@ -121,41 +123,10 @@ class BenchReplica(LLMReplica):
         return xr.reduce_dir(trace_dir, window_s)
 
     def prefill_logits(self, prompt: List[int]):
-        """Next-token logits [vocab] of `prompt` from the program's own
-        chunked prefill into pages (`paged_kv.prefill_chunk_paged`, the
-        function the engine jits), run on a scratch page pool of one slot.
-        The engine's `prefill_logits` probe builds a scratch pool as large
-        as the serving one, which a chip filled by a real cache has no
-        room for; the served path through the real pool is held to the
-        reference by the served tokens below."""
-        import jax
-        import jax.numpy as jnp
-        import numpy as np
-
-        from ray_tpu.serve import paged_kv
-
-        eng = self.engine
-        if self._probe is None:
-            self._probe = jax.jit(
-                lambda p, t, n, o, k, v, ln, bt: paged_kv.prefill_chunk_paged(
-                    p, t, n, jnp.int32(0), o, k, v, ln, bt, eng.cfg,
-                    eng.max_len, eng.mesh),
-                donate_argnums=(4, 5))
-        ps, c = eng.page_size, eng.prefill_chunk
-        pages = -(-eng.max_len // ps)
-        cache = paged_kv.init_paged_cache(eng.cfg, 1, pages + 1, ps, pages,
-                                          mesh=eng.mesh)
-        k, v, lengths = cache["k"], cache["v"], cache["lengths"]
-        table = jnp.asarray(np.arange(1, pages + 1, dtype=np.int32)[None])
-        prompt = np.asarray(prompt, dtype=np.int32)
-        for off in range(0, len(prompt), c):
-            chunk = prompt[off:off + c]
-            padded = np.zeros((1, c), dtype=np.int32)
-            padded[0, :len(chunk)] = chunk
-            logits, k, v, lengths = self._probe(
-                eng.params, jnp.asarray(padded), jnp.int32(len(chunk)),
-                jnp.int32(off), k, v, lengths, table)
-        return np.asarray(logits[0], dtype=np.float32)
+        """Next-token logits, float32 [vocab], of `prompt` from the
+        program's own prefill, through the probe the configuration names
+        (bench/probes/): what the cache is made of is that file's to know."""
+        return self._probe.prefill_logits(self.engine, prompt)
 
     def reference_check(self, prompts: List[List[int]],
                         served: List[List[int]]) -> Dict:

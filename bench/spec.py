@@ -1,8 +1,8 @@
 """What a cell is made of, found by name: BENCHMARK.json's entry, the
-configuration's file with the reference and the operations arithmetic it
-names, the traffic mix's file and the per-layer metrics' files. Nothing
-here knows a cell, a configuration, an architecture or a metric by name, so
-a later PR adds one as files and one entry, and edits nothing."""
+configuration's file with the reference, the operations arithmetic and the
+probe it names, the traffic mix's file and the per-layer metrics' files.
+Nothing here knows a cell, a configuration, an architecture or a metric by
+name, so a later PR adds one as files and one entry, and edits nothing."""
 
 from __future__ import annotations
 
@@ -34,13 +34,15 @@ PUBLISHED_KEYS = {
     "tie_word_embeddings": "tie_embeddings",
 }
 
-# The two modules a configuration file names: the key in the file -> the
+# The three modules a configuration file names: the key in the file -> the
 # package under bench/ the module lies in ("" is bench/ itself) and the
-# functions it has to define. There is no default for either.
+# functions it has to define. There is no default for any.
 MODULES = {
     "reference": ("reference", ("hidden_layerwise", "logits_rows",
-                                "loss_and_grads", "loss_layerwise")),
+                                "loss_and_grads", "loss_layerwise",
+                                "leaf_init")),
     "operations": ("", ("train_flops_per_token",)),
+    "probe": ("probes", ("prefill_logits",)),
 }
 
 
@@ -113,9 +115,10 @@ def _defined_names(path: str) -> Set[str]:
 
 def check_config(doc: Dict, path: str) -> None:
     """Refuse, in one line that names the file and the fault, a
-    configuration file that does not name its reference and its operations
-    arithmetic, names a module that is missing or lacks a function the
-    harness calls, or maps a size to a field the program's config lacks.
+    configuration file that does not name its reference, its operations
+    arithmetic and its probe, names a module that is missing or lacks a
+    function the harness calls, or maps a size to a field the program's
+    config lacks.
     Called before the runtime starts and before any chip is waited for."""
     for key, (package, needs) in MODULES.items():
         name = doc.get(key)
@@ -150,8 +153,8 @@ def check_config(doc: Dict, path: str) -> None:
 
 
 def named_module(doc: Dict, key: str):
-    """The module a configuration file names under `key` ("reference" or
-    "operations"), imported; `doc` is the file, or anything that carries
+    """The module a configuration file names under `key` (a key of
+    `MODULES`), imported; `doc` is the file, or anything that carries
     the key on from it. `check_config` has seen that it is there."""
     package = MODULES[key][0]
     return importlib.import_module(
@@ -162,6 +165,15 @@ def _published(doc: Dict) -> Dict[str, str]:
     """Every published size of a configuration file, the public config's
     key -> the program's field: the ten and the file's own."""
     return {**PUBLISHED_KEYS, **(doc.get("published_extra") or {})}
+
+
+def _same(published, held) -> bool:
+    """A published size against the program's: a sequence in the file (a
+    list) equals one in a frozen config (a tuple) when their items do."""
+    if isinstance(published, list) and isinstance(held, (list, tuple)):
+        return (len(published) == len(held)
+                and all(map(_same, published, held)))
+    return published == held
 
 
 def program_config(doc: Dict, platform: str):
@@ -180,7 +192,7 @@ def program_config(doc: Dict, platform: str):
                 continue
             if not hasattr(cfg, field):
                 wrong.append(f"{key}: the program's config has no {field!r}")
-            elif getattr(cfg, field) != doc[key]:
+            elif not _same(doc[key], getattr(cfg, field)):
                 wrong.append(f"{key}: file {doc[key]!r}, program "
                              f"{getattr(cfg, field)!r}")
         if wrong:
@@ -194,6 +206,17 @@ def program_config(doc: Dict, platform: str):
 def dims_of(cfg, doc: Dict) -> Dict:
     """The sizes the reference and the operations arithmetic take, as
     plain numbers under the program's field names: the ten, and whatever
-    further sizes the configuration file `doc` states."""
+    further sizes the configuration file `doc` states (a sequence as the
+    program's config holds it, a tuple, which a static argument can be)."""
     return {field: getattr(cfg, field) for field in _published(doc).values()
             if field != "tie_embeddings"}
+
+
+def leaf_rules(cfg, doc: Dict):
+    """`path -> rule` for `weights.make_params`: the `leaf_init` of the
+    reference the file names, over `dims_of` and whether the table is tied
+    (the reference's other functions read that off the tree; a leaf's rule
+    has no tree to read)."""
+    leaf_init = named_module(doc, "reference").leaf_init
+    m = dict(dims_of(cfg, doc), tie_embeddings=cfg.tie_embeddings)
+    return lambda path: leaf_init(path, m)

@@ -1,5 +1,6 @@
 """A later PR adds another architecture (its configuration, its plain
-reference, its operations arithmetic), a traffic mix, cells, a reader and
+reference with its leaf table, its operations arithmetic, its probe), a
+traffic mix, cells, a reader and
 per-layer metrics as NEW files and one entry each in BENCHMARK.json, and
 edits no file that is there. Shown in a temporary copy, on the CPU preset,
 with the second architecture the program runs at toy size through both the
@@ -67,6 +68,7 @@ def test_add_another_architecture_as_files(tmp_path, monkeypatch):
     there = root / "bench"
     shutil.copy(os.path.join(THROWAWAY, "gemma.py"), there / "reference")
     shutil.copy(os.path.join(THROWAWAY, "opcount.py"), there)
+    shutil.copy(os.path.join(THROWAWAY, "engine_probe.py"), there / "probes")
     shutil.copy(os.path.join(THROWAWAY, "ops_per_token.py"), there / "readers")
     shutil.copy(os.path.join(THROWAWAY, "gemma.json"),
                 there / "configs" / "throwaway.json")
@@ -118,7 +120,8 @@ def test_add_another_architecture_as_files(tmp_path, monkeypatch):
     for trace in (0, 1):
         line, out = result_line("throwaway-serve", trace, root)
         assert line["correct"] is True and line["failed"] == 0
-        assert "(reference gemma, operations opcount)" in out
+        assert ("(reference gemma, operations opcount, probe engine_probe)"
+                in out)
     assert set(line["metrics"]) == {"throwaway.decode_ms"}
     assert line["metrics"]["throwaway.decode_ms"]["value"] > 0
     for trace in (0, 1):
@@ -147,7 +150,7 @@ def test_add_another_architecture_as_files(tmp_path, monkeypatch):
     assert not cmp.diff_files and not cmp.left_only
     assert sorted(cmp.right_only) == ["opcount.py"]
     for sub, new in (("configs", 2), ("traffic", 1), ("layer_metrics", 3),
-                     ("reference", 2), ("readers", 1)):
+                     ("reference", 2), ("readers", 1), ("probes", 1)):
         sc = cmp.subdirs[sub]
         assert not sc.diff_files and not sc.left_only, sub
         assert len(sc.right_only) == new, (sub, sc.right_only)

@@ -1,8 +1,9 @@
-"""A configuration file names its reference, its further published sizes
-and its operations arithmetic. Every file BENCHMARK.json lists is held to
-that here, a case a configuration, and a file that does not is refused
+"""A configuration file names its reference, its further published sizes,
+its operations arithmetic and its probe. Every file BENCHMARK.json lists is
+held to that here, a case a configuration, and a file that does not is refused
 before the runtime starts, in one line that names the file and the fault."""
 
+import dataclasses
 import json
 import os
 import shutil
@@ -47,8 +48,8 @@ def test_a_listed_configuration_names_what_the_harness_calls(entry):
 
 def test_further_published_sizes_are_held_and_handed_on():
     doc = load(os.path.join(THROWAWAY, "gemma.json"))
-    spec.check_config(dict(doc, reference="qwen3", operations="flops"),
-                      "gemma.json")
+    spec.check_config(dict(doc, reference="qwen3", operations="flops",
+                           probe="paged_kv"), "gemma.json")
     dims = spec.dims_of(spec.program_config(doc, "tpu"), doc)
     assert set(dims) == DENSE_DIMS | {"final_logit_softcap"}
     assert dims["final_logit_softcap"] == 30.0
@@ -58,6 +59,39 @@ def test_further_published_sizes_are_held_and_handed_on():
         spec.program_config(dict(doc, hidden_size=128), "tpu")
 
 
+def test_a_published_size_may_be_a_sequence(monkeypatch):
+    """A layer pattern is a list in the file and a tuple in the program's
+    frozen config: equal when their items are, refused when not, and
+    handed on in `dims` as a value a static argument can be."""
+    from ray_tpu.models import configs
+
+    @dataclasses.dataclass(frozen=True)
+    class Patterned(type(configs.get_config("tiny_gemma"))):
+        layer_types: tuple = ("mamba", "attention", "mamba")
+
+    fields = {f.name: getattr(configs.get_config("tiny_gemma"), f.name)
+              for f in dataclasses.fields(configs.get_config("tiny_gemma"))}
+    monkeypatch.setitem(configs.NAMED_CONFIGS, "tiny_patterned",
+                        Patterned(**fields))
+    doc = load(os.path.join(THROWAWAY, "gemma.json"))
+    doc.update(model="tiny_patterned",
+               layer_types=["mamba", "attention", "mamba"])
+    doc["published_extra"]["layer_types"] = "layer_types"
+    spec.check_config(dict(doc, reference="qwen3", operations="flops",
+                           probe="paged_kv"), "patterned.json")
+    dims = spec.dims_of(spec.program_config(doc, "tpu"), doc)
+    assert dims["layer_types"] == ("mamba", "attention", "mamba")
+    hash(tuple(sorted(dims.items())))  # as the references' jits take it
+    with pytest.raises(SystemExit, match=(
+            r"layer_types: file \['mamba', 'mamba', 'attention'\], program "
+            r"\('mamba', 'attention', 'mamba'\)")):
+        spec.program_config(
+            dict(doc, layer_types=["mamba", "mamba", "attention"]), "tpu")
+    with pytest.raises(SystemExit, match="layer_types: file"):
+        spec.program_config(dict(doc, layer_types=["mamba", "attention"]),
+                            "tpu")
+
+
 def _no_key(key):
     return lambda d: d.pop(key)
 
@@ -65,6 +99,12 @@ def _no_key(key):
 FAULTS = {
     "no reference": (_no_key("reference"), "names no 'reference' module"),
     "no operations": (_no_key("operations"), "names no 'operations' module"),
+    "no probe": (_no_key("probe"), "names no 'probe' module"),
+    "probe missing": (lambda d: d.update(probe="nowhere"),
+                      "there is no bench/probes/nowhere.py"),
+    "reference without leaf_init": (
+        lambda d: d.update(reference="no_leaf_init"),
+        "bench/reference/no_leaf_init.py does not define leaf_init"),
     "reference missing": (lambda d: d.update(reference="nowhere"),
                           "there is no bench/reference/nowhere.py"),
     "reference lacks a function": (
@@ -92,10 +132,15 @@ def test_a_faulty_configuration_file_is_refused_before_the_runtime(
     there = root / "bench"
     shutil.copy(os.path.join(THROWAWAY, "gemma.py"), there / "reference")
     shutil.copy(os.path.join(THROWAWAY, "opcount.py"), there)
+    shutil.copy(os.path.join(THROWAWAY, "engine_probe.py"), there / "probes")
     with open(os.path.join(THROWAWAY, "gemma.py")) as f:
         whole = f.read()
-    with open(there / "reference" / "partial.py", "w") as f:
-        f.write(whole.replace("loss_layerwise = ", "_unused = "))
+    for name, had, has in (
+            ("partial", "loss_layerwise = ", "_unused = "),
+            ("no_leaf_init", "def leaf_init(", "def _unused(")):
+        assert whole.count(had) == 1
+        with open(there / "reference" / f"{name}.py", "w") as f:
+            f.write(whole.replace(had, has))
     with open(there / "peaks_only.py", "w") as f:
         f.write("def peaks(kind):\n    return {}\n")
     derive(os.path.join(THROWAWAY, "gemma.json"),

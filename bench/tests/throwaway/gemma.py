@@ -20,7 +20,21 @@ from typing import Dict
 import jax
 import jax.numpy as jnp
 
+from reference.draws import normal, ones
+
 F32 = jnp.float32
+
+
+def leaf_init(path, m: Dict):
+    """The program's `init_params` table for this family: projections at
+    d**-0.5, the residual stream's two writers at d**-0.5 * (2L)**-0.5,
+    the tied table at the head's scale, norm scales ones."""
+    name, base = path[-1], m["d_model"] ** -0.5
+    if name in ("wq", "wk", "wv", "w_gate", "w_up", "embed"):
+        return (normal, base)
+    if name in ("wo", "w_down"):
+        return (normal, base * (2 * m["n_layers"]) ** -0.5)
+    return (ones,)
 
 
 def _rmsnorm(x, w, eps):

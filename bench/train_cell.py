@@ -117,7 +117,8 @@ def train_loop(config: Dict):
     mesh = build_mesh(MeshConfig(**doc["deployment"]["mesh"]), jax.devices())
     replicated = NamedSharding(mesh, P())
     params = weights.make_params(
-        cfg, seed, logical_shardings(param_logical_axes(cfg), mesh))
+        cfg, seed, spec.leaf_rules(cfg, doc),
+        logical_shardings(param_logical_axes(cfg), mesh))
     jax.block_until_ready(params)
     took("mesh_and_weights_s")
     check = _reference_check(spec.named_module(doc, "reference"), params,
