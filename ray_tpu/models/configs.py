@@ -219,6 +219,67 @@ olmoe_1b_7b = TransformerConfig(
     qk_norm_extent="projection",
 )
 
+# Both kinds of layer, two Mamba layers in a row, and blocks of 8 rows so
+# that a test prompt spans several.
+tiny_granite_h = TransformerConfig(
+    vocab_size=256,
+    d_model=64,
+    n_layers=4,
+    n_heads=4,
+    n_kv_heads=2,
+    d_ff=128,
+    max_seq=128,
+    norm_eps=1e-5,
+    dtype=jnp.float32,
+    remat=False,
+    tie_embeddings=True,
+    layer_pattern=("mamba", "mamba", "attention", "mamba"),
+    mamba_n_heads=8,
+    mamba_d_head=16,
+    mamba_d_state=16,
+    mamba_chunk_size=8,
+    embedding_multiplier=12.0,
+    attention_multiplier=0.0625,
+    residual_multiplier=0.22,
+    logits_scaling=8.0,
+    position_embedding_type="nope",
+)
+
+# granite-4.0-h-micro (the model's public config.json, `model_type`
+# granitemoehybrid; Mamba-2 is arXiv:2405.21060): 36 Mamba-2 layers and 4
+# attention layers (5, 15, 25, 35) without a position embedding, every
+# layer followed by a dense SwiGLU MLP of width 8192 (no routed experts),
+# Granite's four multipliers, a tied 100,352-row vocabulary. 3.19 B
+# parameters.
+granite_4_0_h_micro = TransformerConfig(
+    vocab_size=100352,
+    d_model=2048,
+    n_layers=40,
+    n_heads=32,
+    n_kv_heads=8,
+    d_ff=8192,
+    max_seq=131072,
+    rope_theta=10000.0,  # published, and unused: no position embedding
+    norm_eps=1e-5,
+    tie_embeddings=True,
+    layer_pattern=tuple("attention" if i in (5, 15, 25, 35) else "mamba"
+                      for i in range(40)),
+    mamba_n_heads=64,
+    mamba_d_head=64,
+    mamba_d_state=128,
+    mamba_d_conv=4,
+    mamba_n_groups=1,
+    mamba_expand=2,
+    mamba_chunk_size=256,
+    mamba_conv_bias=True,
+    mamba_proj_bias=False,
+    embedding_multiplier=12.0,
+    attention_multiplier=0.015625,
+    residual_multiplier=0.22,
+    logits_scaling=8.0,
+    position_embedding_type="nope",
+)
+
 NAMED_CONFIGS = {
     "tiny": tiny,
     "tiny_gqa": tiny_gqa,
@@ -235,6 +296,8 @@ NAMED_CONFIGS = {
     "qwen3-4b": qwen3_4b,
     "tiny_olmoe": tiny_olmoe,
     "olmoe-1b-7b": olmoe_1b_7b,
+    "tiny_granite_h": tiny_granite_h,
+    "granite-4.0-h-micro": granite_4_0_h_micro,
 }
 
 

@@ -21,8 +21,8 @@ import jax.numpy as jnp
 
 from ray_tpu.models.transformer import (
     TransformerConfig,
-    _act,
     _embed_tokens,
+    dense_mlp,
     project_logits,
     project_qkv,
 )
@@ -34,6 +34,11 @@ NEG_INF = -1e30
 
 def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int) -> Dict:
     """Preallocated [layers, batch, max_len, kv_heads, head_dim] cache."""
+    if cfg.layer_pattern:
+        raise ValueError(
+            "generate's cache is keys and values for every layer: a model "
+            "with recurrent layers decodes through ContinuousBatchingEngine "
+            "(serve/paged_kv.py carries its recurrent pool)")
     shape = (cfg.n_layers, batch, max_len, cfg.n_kv_heads, cfg.head_dim)
     return {
         "k": jnp.zeros(shape, dtype=cfg.dtype),
@@ -103,10 +108,7 @@ def _forward_with_cache(params, tokens, cache, cfg: TransformerConfig):
         if cfg.num_experts:
             y, _ = moe_block(h.reshape(b * lq, -1), lp, cfg)
             return x + y.reshape(b, lq, -1), (k_cache_l, v_cache_l)
-        gate = _act(cfg)((h @ lp["w_gate"]).astype(jnp.float32))
-        up = (h @ lp["w_up"]).astype(jnp.float32)
-        x = x + (((gate * up).astype(x.dtype)) @ lp["w_down"])
-        return x, (k_cache_l, v_cache_l)
+        return x + dense_mlp(h, lp, cfg), (k_cache_l, v_cache_l)
 
     x, (k_new, v_new) = jax.lax.scan(
         layer, x, (params["layers"], cache["k"], cache["v"])

@@ -24,9 +24,11 @@ from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import apply_rope, flash_attention, rmsnorm, rope_frequencies, softmax_cross_entropy
+from ray_tpu.models import mamba2
 from ray_tpu.parallel.moe import load_balancing_loss, moe_block
 
 
@@ -93,10 +95,41 @@ class TransformerConfig:
     # Explicit head dim when it differs from d_model/n_heads (Qwen3
     # uses 128-wide heads at every scale). 0 = derive from d_model.
     custom_head_dim: int = 0
+    # A hybrid decoder's published sizes (granitemoehybrid's config keys):
+    # the kind of every layer, "mamba" or "attention" (the public config's
+    # `layer_types`; empty: every layer is attention), and the Mamba-2
+    # mixer's shape (models/mamba2.py).
+    layer_pattern: Tuple[str, ...] = ()
+    mamba_n_heads: int = 0
+    mamba_d_head: int = 0
+    mamba_d_state: int = 0
+    mamba_d_conv: int = 4
+    mamba_n_groups: int = 1
+    mamba_expand: int = 2
+    mamba_chunk_size: int = 256
+    mamba_conv_bias: bool = True
+    mamba_proj_bias: bool = False
+    # Granite's four multipliers: on the embeddings, on the attention
+    # scores in place of head_dim ** -0.5 (0 = that), on what every mixer
+    # and MLP adds to the residual stream, and under the logits.
+    embedding_multiplier: float = 1.0
+    attention_multiplier: float = 0.0
+    residual_multiplier: float = 1.0
+    logits_scaling: float = 1.0
+    # "rope", or "nope": attention without a position embedding.
+    position_embedding_type: str = "rope"
 
     @property
     def head_dim(self) -> int:
         return self.custom_head_dim or self.d_model // self.n_heads
+
+    @property
+    def attention_scale(self) -> float:
+        return self.attention_multiplier or self.head_dim ** -0.5
+
+    def layers_of(self, kind: str) -> int:
+        """How many of a hybrid's layers are of `kind`."""
+        return sum(t == kind for t in self.layer_pattern)
 
 
 # Where parallel.mesh.DEFAULT_RULES put activations, for the kernels that
@@ -109,8 +142,86 @@ def _dense_init(key, shape, scale, dtype):
     return (jax.random.normal(key, shape, dtype=jnp.float32) * scale).astype(dtype)
 
 
+def _check_hybrid(cfg: TransformerConfig) -> None:
+    kinds = set(cfg.layer_pattern)
+    if not kinds <= {"mamba", "attention"}:
+        raise ValueError(f"unknown layer kinds {sorted(kinds)} in "
+                         "layer_pattern: expected 'mamba' or 'attention'")
+    if len(cfg.layer_pattern) != cfg.n_layers:
+        raise ValueError(f"layer_pattern names {len(cfg.layer_pattern)} layers, "
+                         f"n_layers is {cfg.n_layers}")
+    if cfg.num_experts or cfg.qk_norm:
+        raise ValueError("a hybrid's MLP is dense and its attention has no "
+                         "QK-norm here: routed experts or a QK-norm beside "
+                         "state-space layers are not written")
+    if len(kinds) != 2:
+        raise ValueError("a hybrid has layers of both kinds; layer_pattern "
+                         f"has only {sorted(kinds)}")
+    if cfg.mamba_expand * cfg.d_model != mamba2.d_inner(cfg):
+        raise ValueError("mamba_n_heads * mamba_d_head must be "
+                         "mamba_expand * d_model")
+
+
+def _init_hybrid_layers(key, cfg: TransformerConfig) -> Dict:
+    """A hybrid's layers: one stack a kind of mixer (`ssm`, `attn`, each
+    with its input norm) and the MLPs of all layers (`mlp`), so three
+    stacks of unlike length. `A_log`, `dt_bias` and the convolution are
+    drawn by Mamba-2's published rule (arXiv:2405.21060)."""
+    _check_hybrid(cfg)
+    d, h, kvh, hd, ff = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
+                         cfg.head_dim, cfg.d_ff)
+    n_ssm, n_attn, n = (cfg.layers_of("mamba"), cfg.layers_of("attention"),
+                        cfg.n_layers)
+    scale, out_scale = d ** -0.5, d ** -0.5 * (2 * n) ** -0.5
+    heads, inner, conv = (cfg.mamba_n_heads, mamba2.d_inner(cfg),
+                          mamba2.conv_dim(cfg))
+    keys = iter(jax.random.split(key, 16))
+
+    def normal(count, shape, scale):
+        return _dense_init(next(keys), (count, *shape), scale, cfg.dtype)
+
+    def uniform(shape, lo, hi):
+        return jax.random.uniform(next(keys), shape, jnp.float32, lo, hi)
+
+    dt = jnp.exp(uniform((n_ssm, heads), jnp.log(0.001), jnp.log(0.1)))
+    bound = cfg.mamba_d_conv ** -0.5
+    ssm = {
+        "norm": jnp.ones((n_ssm, d), cfg.dtype),
+        "w_in": normal(n_ssm, (d, mamba2.in_proj_dim(cfg)), scale),
+        "w_dt": normal(n_ssm, (d, heads), scale),
+        "conv_w": uniform((n_ssm, conv, cfg.mamba_d_conv), -bound,
+                          bound).astype(cfg.dtype),
+        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(cfg.dtype),
+        "a_log": jnp.log(uniform((n_ssm, heads), 1.0, 16.0)).astype(cfg.dtype),
+        "d_skip": jnp.ones((n_ssm, heads), cfg.dtype),
+        "gate_norm": jnp.ones((n_ssm, inner), cfg.dtype),
+        "w_out": normal(n_ssm, (inner, d), out_scale),
+    }
+    if cfg.mamba_conv_bias:
+        ssm["conv_b"] = uniform((n_ssm, conv), -bound, bound).astype(cfg.dtype)
+    if cfg.mamba_proj_bias:
+        ssm["b_in"] = jnp.zeros((n_ssm, mamba2.in_proj_dim(cfg)), cfg.dtype)
+        ssm["b_dt"] = jnp.zeros((n_ssm, heads), cfg.dtype)
+        ssm["b_out"] = jnp.zeros((n_ssm, d), cfg.dtype)
+    attn = {
+        "attn_norm": jnp.ones((n_attn, d), cfg.dtype),
+        "wq": normal(n_attn, (d, h * hd), scale),
+        "wk": normal(n_attn, (d, kvh * hd), scale),
+        "wv": normal(n_attn, (d, kvh * hd), scale),
+        "wo": normal(n_attn, (h * hd, d), out_scale),
+    }
+    mlp = {
+        "mlp_norm": jnp.ones((n, d), cfg.dtype),
+        "w_gate": normal(n, (d, ff), scale),
+        "w_up": normal(n, (d, ff), scale),
+        "w_down": normal(n, (ff, d), out_scale),
+    }
+    return {"ssm": ssm, "attn": attn, "mlp": mlp}
+
+
 def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
-    """Initialize the full parameter pytree (layers stacked on axis 0)."""
+    """Initialize the full parameter pytree (layers stacked on axis 0; a
+    hybrid's as `_init_hybrid_layers` says)."""
     keys = jax.random.split(key, 10)
     d, h, kvh, hd, ff = (
         cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff
@@ -122,6 +233,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
         ks = jax.random.split(k, L)
         return jnp.stack([_dense_init(ks[i], shape, scale, cfg.dtype) for i in range(L)])
 
+    if cfg.layer_pattern:
+        return _with_tables(_init_hybrid_layers(keys[0], cfg), keys, cfg)
     layer = {
         "attn_norm": jnp.ones((L, d), dtype=cfg.dtype),
         "wq": stack(keys[0], (d, h * hd), scale),
@@ -154,6 +267,13 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
                 "w_down": stack(sub[2], (E, ff, d), scale * (2 * L) ** -0.5),
             }
         )
+    return _with_tables(layer, keys, cfg)
+
+
+def _with_tables(layer: Dict, keys, cfg: TransformerConfig) -> Dict:
+    """The parameter tree around its `layers`: the embedding table, the
+    last norm and, where it is not the table, the output head."""
+    d, scale = cfg.d_model, cfg.d_model ** -0.5
     # A tied table is also the output head, so it takes the head's scale:
     # at unit scale every token predicts itself with a logit of about d
     # (a first loss of 1,887 at Qwen3-4B widths, where ln(vocab) is 11.9).
@@ -175,7 +295,30 @@ def param_logical_axes(cfg: TransformerConfig) -> Dict:
 
     Mapped through parallel.mesh.DEFAULT_RULES: "embed"->fsdp, "mlp"/
     "heads"/"vocab"->tp, "expert"->ep, layer-stack axis -> "stage" (pp).
+    A hybrid's Mamba stack is replicated but for its two projections'
+    model axis: heads, groups and the convolution's channels do not split
+    without a partitioned mixer.
     """
+    if cfg.layer_pattern:
+        shapes = jax.eval_shape(lambda k: init_params(k, cfg),
+                                jax.random.PRNGKey(0))
+        split = {"wq": ("stage", "embed", "heads"),
+                 "wk": ("stage", "embed", "heads"),
+                 "wv": ("stage", "embed", "heads"),
+                 "wo": ("stage", "heads", "embed"),
+                 "w_gate": ("stage", "embed", "mlp"),
+                 "w_up": ("stage", "embed", "mlp"),
+                 "w_down": ("stage", "mlp", "embed"),
+                 "w_in": ("stage", "embed", None),
+                 "w_dt": ("stage", "embed", None),
+                 "w_out": ("stage", None, "embed"),
+                 "embed": ("vocab", "embed"), "lm_head": ("embed", "vocab")}
+        return jax.tree_util.tree_map_with_path(
+            lambda path, leaf: split.get(
+                path[-1].key,
+                (("stage",) if path[0].key == "layers" else ())
+                + (None,) * (leaf.ndim - (path[0].key == "layers"))),
+            shapes)
     layer = {
         "attn_norm": ("stage", None),
         "wq": ("stage", "embed", "heads"),
@@ -226,6 +369,8 @@ def _embed_tokens(params, tokens, cfg: TransformerConfig):
     x = params["embed"][tokens].astype(cfg.dtype)
     if cfg.scale_embeddings:  # Gemma normalizes the embedding scale
         x = x * jnp.asarray(cfg.d_model ** 0.5, dtype=cfg.dtype)
+    if cfg.embedding_multiplier != 1.0:
+        x = x * jnp.asarray(cfg.embedding_multiplier, dtype=cfg.dtype)
     return x
 
 
@@ -238,6 +383,8 @@ def lm_head_weight(params, cfg: TransformerConfig):
 
 def project_logits(x, params, cfg: TransformerConfig):
     logits = x @ lm_head_weight(params, cfg)
+    if cfg.logits_scaling != 1.0:
+        logits = logits / cfg.logits_scaling
     if cfg.final_logit_softcap:
         cap = cfg.final_logit_softcap
         logits = cap * jnp.tanh(logits.astype(jnp.float32) / cap)
@@ -288,6 +435,83 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh, positions):
                            mesh=mesh, spec=_HEADS_SPEC)
 
 
+def dense_mlp(h, lp, cfg: TransformerConfig):
+    """(act(h W_gate) * (h W_up)) W_down, gate and product in float32."""
+    gate = _act(cfg)((h @ lp["w_gate"]).astype(jnp.float32))
+    up = (h @ lp["w_up"]).astype(jnp.float32)
+    return ((gate * up).astype(h.dtype)) @ lp["w_down"]
+
+
+def residual(x, y, cfg: TransformerConfig):
+    """x + residual_multiplier * y (a model without one adds y as it is)."""
+    if cfg.residual_multiplier == 1.0:
+        return x + y
+    return x + cfg.residual_multiplier * y
+
+
+def layer_kinds(cfg: TransformerConfig):
+    """For every layer of a hybrid: whether it is a Mamba layer `[n] bool`
+    and its index within its own kind's stack `[n] int32`."""
+    is_mamba = np.array([t == "mamba" for t in cfg.layer_pattern])
+    within = np.where(is_mamba, np.cumsum(is_mamba), np.cumsum(~is_mamba)) - 1
+    return is_mamba, within.astype(np.int32)
+
+
+def at_layer(stack: Dict, i):
+    """Layer `i` of a stack of leaves, read where it lies: a consumer
+    fuses the index, as it does a scan's own slice of its inputs."""
+    return jax.tree.map(lambda w: w[i], stack)
+
+
+def _hybrid_layers(params, x, cfg: TransformerConfig, mesh, positions):
+    """A hybrid's layers over whole sequences `x [B, L, D]`, every Mamba
+    layer from a zero state: ONE scan over all layers, each taking its
+    kind's mixer under a `lax.cond` and reading its weights from its
+    kind's stack at its own index (the MLPs, one a layer, are the scan's
+    inputs). Differentiable; nothing is carried but `x`."""
+    _check_hybrid(cfg)
+    layers = params["layers"]
+    b, l, _ = x.shape
+    fresh = mamba2.init_state(cfg, 1, b)
+    every_row = jnp.full((b,), l, jnp.int32)
+    rope = cfg.position_embedding_type == "rope"
+    if rope:
+        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
+
+    def norm(x, w):
+        return rmsnorm(x, w, cfg.norm_eps, mesh=mesh, spec=_ACT_SPEC)
+
+    def ssm_mixer(x, j):
+        lp = at_layer(layers["ssm"], j)
+        out, _, _ = mamba2.mixer(norm(x, lp["norm"]), lp, cfg,
+                                 fresh["state"][0], fresh["conv"][0],
+                                 every_row)
+        return out
+
+    def attn_mixer(x, j):
+        lp = at_layer(layers["attn"], j)
+        q, k, v = project_qkv(norm(x, lp["attn_norm"]), lp, cfg)
+        if rope:
+            q = apply_rope(q, cos, sin, positions)
+            k = apply_rope(k, cos, sin, positions)
+        # The kernels scale scores by head_dim ** -0.5: q carries the rest.
+        q = q * jnp.asarray(cfg.attention_scale * cfg.head_dim ** 0.5, q.dtype)
+        attn = _attention(cfg, q, k, v, mesh, positions)
+        return attn.reshape(b, l, -1) @ lp["wo"]
+
+    def body(x, inputs):
+        mlp, is_mamba, j = inputs
+        x = residual(x, jax.lax.cond(
+            is_mamba, ssm_mixer, attn_mixer, x, j).astype(x.dtype), cfg)
+        return residual(x, dense_mlp(norm(x, mlp["mlp_norm"]), mlp, cfg),
+                        cfg), None
+
+    if cfg.remat:
+        body = jax.checkpoint(body)
+    x, _ = jax.lax.scan(body, x, (layers["mlp"], *layer_kinds(cfg)))
+    return x
+
+
 def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
     """Build the per-layer body used by lax.scan."""
 
@@ -305,9 +529,7 @@ def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
         h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh,
                     spec=_ACT_SPEC)
         if cfg.num_experts == 0:
-            gate = _act(cfg)((h @ lp["w_gate"]).astype(jnp.float32))
-            up = (h @ lp["w_up"]).astype(jnp.float32)
-            mlp_out = ((gate * up).astype(x.dtype)) @ lp["w_down"]
+            mlp_out = dense_mlp(h, lp, cfg)
             routing = None
         else:
             mlp_flat, routing = moe_block(h.reshape(b * l, d), lp, cfg)
@@ -356,9 +578,12 @@ def forward(
     return_hidden, the pre-lm_head hidden states [B, L, D] instead of
     logits (the chunked-CE loss applies lm_head itself)."""
     x = _embed_tokens(params, tokens, cfg)
-    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
-    body = _layer_fn(cfg, mesh, cos, sin, positions)
-    x, routing = jax.lax.scan(body, x, params["layers"])
+    if cfg.layer_pattern:
+        x, routing = _hybrid_layers(params, x, cfg, mesh, positions), None
+    else:
+        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
+        body = _layer_fn(cfg, mesh, cos, sin, positions)
+        x, routing = jax.lax.scan(body, x, params["layers"])
     aux = _aux_loss(routing, tokens.size)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh,
                 spec=_ACT_SPEC)
@@ -414,6 +639,10 @@ def forward_pipelined(
             "pipeline parallelism currently supports dense layers only "
             "(the MoE aux loss does not thread through the pp schedule)"
         )
+    if cfg.layer_pattern:
+        raise ValueError(
+            "pipeline parallelism needs stages of like layers: a hybrid's "
+            "stacks (one a kind of mixer) do not split into pp stages")
 
     x = _embed_tokens(params, tokens, cfg)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)

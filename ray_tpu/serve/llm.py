@@ -11,6 +11,13 @@ interleaved between decode steps), emit tokens as they are produced, and
 free their slot the moment they finish — the vLLM-style iteration-level
 scheduling, built TPU-first:
 
+  * A model with recurrent layers (a hybrid: Mamba-2 among attention
+    layers) gives every slot a row of fixed-size state beside its pages,
+    in a second pool that the same step programs carry and update in
+    place. What follows from the model and is no option: such a model
+    serves without a prefix cache (a prompt cannot resume below a shared
+    prefix without the state at that boundary, and no snapshots are
+    kept) and without tensor parallelism (the mixer is not partitioned).
   * Static shapes everywhere: the decode step is jitted ONCE for the
     slot count and prompts prefill in fixed-size CHUNKS (one chunk
     between decode steps — chunked prefill: a long prompt never stalls
@@ -408,6 +415,12 @@ class ContinuousBatchingEngine:
                     f"the mesh's tp={mesh.shape['tp']} must divide "
                     f"n_kv_heads={cfg.n_kv_heads}"
                 )
+            if cfg.layer_pattern and mesh.shape["tp"] > 1:
+                raise ValueError(
+                    "a model with recurrent layers serves on one chip: its "
+                    "recurrent pool (Mamba-2 state and convolution inputs) "
+                    f"is not sharded over tp={mesh.shape['tp']}"
+                )
         rcfg = get_config()
         self.page_size = max(
             1, min(int(page_size or rcfg.serve_kv_page_size), max_len)
@@ -418,10 +431,16 @@ class ContinuousBatchingEngine:
             # Every slot can hold max_len rows (+ the reserved NULL page).
             self.kv_pages = num_slots * self._pages_per_slot + 1
         self._pool = paged_kv.PagePool(self.kv_pages, self.page_size)
+        # A model with recurrent layers builds no prefix cache: pages
+        # below a shared prefix are of no use without the recurrent state
+        # at that boundary, and no one keeps snapshots of it.
         self._prefix_cache = (
             paged_kv.PrefixCache(self._pool)
-            if rcfg.serve_prefix_cache else None
+            if rcfg.serve_prefix_cache and not cfg.layer_pattern else None
         )
+        self._prefix_wanted = bool(rcfg.serve_prefix_cache)
+        self._prefix_reuse_skipped = 0
+        self._state_resets = 0
         # Host mirror of the device block table; uploaded as ONE array
         # only when admission/eviction changed it (same discipline — and
         # the same test pins — as the sampling params: the steady-state
@@ -439,37 +458,47 @@ class ContinuousBatchingEngine:
         cache = self._fresh_cache()
         self._k, self._v = cache["k"], cache["v"]
         self._lengths = cache["lengths"]
-        # A model with experts: its routing accumulator, which every step
-        # program takes last and returns last (`*moe` below; nothing for a
-        # dense model, whose programs are then what they were). Carried
-        # like the cache but not donated: stats() reads it from another
-        # thread, and an array no program consumes can be fetched at any
-        # time. Written by the loop thread only.
-        self._moe = ([jax.tree.map(self._replicated,
-                                   paged_kv.init_routing_counters(cfg))]
-                     if cfg.num_experts else [])
+        # What every step program takes after the cache and returns after
+        # it, by the program's own argument names and in its order; a
+        # dense attention model has none, and its programs are then what
+        # they were. A call's results after the cache are the tail again,
+        # in its order. `moe`: a model with experts' routing accumulator.
+        # `rec`, `rec_count`: a model with recurrent layers' second pool,
+        # part of the cache and donated with it, and its accumulator. The
+        # accumulators are carried like the cache but not donated: stats()
+        # reads them from another thread, and an array no program
+        # consumes can be fetched at any time. Written by the loop thread
+        # only.
+        self._tail: Dict = {}
+        if cfg.num_experts:
+            self._tail["moe"] = jax.tree.map(
+                self._replicated, paged_kv.init_routing_counters(cfg))
+        if cfg.layer_pattern:
+            self._tail["rec"] = cache["rec"]
+            self._tail["rec_count"] = paged_kv.init_ssm_counters()
+            self._rec_bytes = sum(a.nbytes for a in cache["rec"].values())
         self._bt_dev = cache["block_tables"]
         self._decode_sampled = jax.jit(
-            lambda p, t, k, v, ln, a, bt, tp, tk, tpp, key, *moe:
+            lambda p, t, k, v, ln, a, bt, tp, tk, tpp, key, **tail:
             paged_kv.decode_paged(
                 p, t, k, v, ln, a, bt, tp, tk, tpp, key, cfg, max_len,
-                mesh, *moe,
+                mesh, **tail,
             ),
-            donate_argnums=(2, 3),
+            donate_argnums=(2, 3), donate_argnames=("rec",),
         )
         self._decode_greedy = jax.jit(
-            lambda p, t, k, v, ln, a, bt, *moe: paged_kv.decode_paged(
+            lambda p, t, k, v, ln, a, bt, **tail: paged_kv.decode_paged(
                 p, t, k, v, ln, a, bt, None, None, None, None, cfg,
-                max_len, mesh, *moe,
+                max_len, mesh, **tail,
             ),
-            donate_argnums=(2, 3),
+            donate_argnums=(2, 3), donate_argnames=("rec",),
         )
         self._prefill = jax.jit(
-            lambda p, t, n, s, o, k, v, ln, bt, *moe:
+            lambda p, t, n, s, o, k, v, ln, bt, **tail:
             paged_kv.prefill_chunk_paged(
-                p, t, n, s, o, k, v, ln, bt, cfg, max_len, mesh, *moe
+                p, t, n, s, o, k, v, ln, bt, cfg, max_len, mesh, **tail
             ),
-            donate_argnums=(5, 6),
+            donate_argnums=(5, 6), donate_argnames=("rec",),
         )
         self._cow = jax.jit(paged_kv.cow_copy_page, donate_argnums=(0, 1))
         self._pick = jax.jit(paged_kv._pick_tokens)
@@ -577,22 +606,25 @@ class ContinuousBatchingEngine:
         pad = np.zeros((1, self.prefill_chunk), dtype=np.int32)
         one, zero = np.int32(1), np.int32(0)
         (_, self._k, self._v, self._lengths,
-         *self._moe) = self._decode_greedy(
+         *tail) = self._decode_greedy(
             self.params, self._tokens_dev, self._k, self._v,
-            self._lengths, self._active_dev, self._bt_dev, *self._moe,
+            self._lengths, self._active_dev, self._bt_dev, **self._tail,
         )
+        self._tail = dict(zip(self._tail, tail))
         (_, self._k, self._v, self._lengths,
-         *self._moe) = self._decode_sampled(
+         *tail) = self._decode_sampled(
             self.params, self._tokens_dev, self._k, self._v,
             self._lengths, self._active_dev, self._bt_dev,
             self._temps_dev, self._top_ks_dev, self._top_ps_dev, k1,
-            *self._moe,
+            **self._tail,
         )
+        self._tail = dict(zip(self._tail, tail))
         (logits, self._k, self._v, self._lengths,
-         *self._moe) = self._prefill(
+         *tail) = self._prefill(
             self.params, pad, one, zero, zero,
-            self._k, self._v, self._lengths, self._bt_dev, *self._moe,
+            self._k, self._v, self._lengths, self._bt_dev, **self._tail,
         )
+        self._tail = dict(zip(self._tail, tail))
         # Warm the copy-on-write page fork too (NULL page onto itself:
         # contents never observable).
         self._k, self._v = self._cow(self._k, self._v, zero, zero)
@@ -602,10 +634,14 @@ class ContinuousBatchingEngine:
         self._first_token(logits, 0, 0.0, 0, 1.0)
         self._tokens_dev = tokens
         # Undo the warmup prefill's lengths[0] = 1 and what warm-up
-        # counted of routing (device-side, keeps the mesh sharding of
-        # the arrays).
+        # counted (device-side, keeps the mesh sharding of the arrays).
+        # Slot 0's recurrent row stays as warm-up left it: a slot's first
+        # chunk starts from zeros whatever its row holds.
         self._lengths = self._lengths * 0
-        self._moe = jax.tree.map(lambda a: a * 0, self._moe)
+        for name in ("moe", "rec_count"):
+            if name in self._tail:
+                self._tail[name] = jax.tree.map(lambda a: a * 0,
+                                                self._tail[name])
         jax.block_until_ready(self._lengths)
 
     # Single-writer: rng and token buffer are engine-thread-owned.
@@ -699,6 +735,23 @@ class ContinuousBatchingEngine:
             self.cfg, self.num_slots, self.kv_pages, self.page_size,
             self._pages_per_slot, mesh=self.mesh,
         )
+
+    def _ssm_stats(self) -> Dict:
+        """stats()["ssm"]: the recurrent pool's size and what the step
+        programs counted since warm-up (`paged_kv.init_ssm_counters`,
+        fetched now), with the two counts the host keeps: admissions that
+        started a slot's state from zero, and admissions whose prompt
+        filled a page and so would have been looked up in a prefix cache,
+        had this model one."""
+        acc = jax.device_get(self._tail["rec_count"])
+        with self._lock:
+            return {
+                "pool_bytes": self._rec_bytes,
+                "bytes_per_slot": self._rec_bytes // self.num_slots,
+                **{name: int(n) for name, n in acc.items()},
+                "state_resets": self._state_resets,
+                "prefix_reuse_skipped": self._prefix_reuse_skipped,
+            }
 
     # -- public API ------------------------------------------------------
     def submit(self, prompt, max_new_tokens: Optional[int] = None,
@@ -829,33 +882,36 @@ class ContinuousBatchingEngine:
 
     def prefill_logits(self, prompt) -> np.ndarray:
         """Next-token logits [vocab], float32, for `prompt`: the engine's
-        own prefill program run on a scratch cache, i.e. what the first
-        decode step picks from. For comparing two engines (one chip
-        against a tensor-parallel mesh), where token equality is hostage
-        to bf16 reduction order. Shares nothing with the serving loop
-        and, its shapes being the loop's, compiles nothing."""
+        own prefill program run on a scratch cache of ONE slot, made of
+        whatever the engine's cache is made of (a slot's pages and the
+        NULL page; a model with recurrent layers' one row of state), i.e.
+        what the first decode step picks from. For comparing two engines
+        (one chip against a tensor-parallel mesh), where token equality is
+        hostage to bf16 reduction order, and a served model against its
+        reference. Shares nothing with the serving loop; the one-slot
+        shapes compile on the first call."""
         prompt = np.asarray(prompt, dtype=np.int32).reshape(-1)
         if not 0 < len(prompt) <= self.max_len - 2:
             raise ValueError(
                 f"prompt length {len(prompt)} not in [1, {self.max_len - 2}]"
             )
-        cache = self._fresh_cache()
+        pages = self._pages_per_slot
+        cache = paged_kv.init_paged_cache(
+            self.cfg, 1, pages + 1, self.page_size, pages, mesh=self.mesh)
         k, v, lengths = cache["k"], cache["v"], cache["lengths"]
-        # Slot 0 over the scratch pool's first pages.
-        bt = np.zeros_like(self._bt_host)
-        n = min(self._pages_per_slot, self._pool.usable)
-        bt[0, :n] = np.arange(1, n + 1)
-        table = self._replicated(bt)
+        tail = {name: cache["rec"] if name == "rec" else acc
+                for name, acc in self._tail.items()}
+        table = self._replicated(np.arange(1, pages + 1, dtype=np.int32)[None])
         c = self.prefill_chunk
         for off in range(0, len(prompt), c):
             chunk = prompt[off:off + c]
             padded = np.zeros((1, c), dtype=np.int32)
             padded[0, :len(chunk)] = chunk
-            logits, k, v, lengths, *_ = self._prefill(
+            logits, k, v, lengths, *out = self._prefill(
                 self.params, padded, np.int32(len(chunk)),
-                np.int32(0), np.int32(off), k, v, lengths, table,
-                *self._moe,
+                np.int32(0), np.int32(off), k, v, lengths, table, **tail,
             )
+            tail = dict(zip(tail, out))
         return np.asarray(logits, dtype=np.float32)[0]
 
     def _moe_stats(self) -> Dict:
@@ -868,7 +924,7 @@ class ContinuousBatchingEngine:
         (over calls x layers: a layer's mean experts hit and its largest
         expert's mean load); `per_expert [E]` assignments summed over
         layers."""
-        acc = jax.device_get(self._moe[0])
+        acc = jax.device_get(self._tail["moe"])
         per_layer_expert = acc["assignments"].astype(np.int64)
         return {
             "assignments": int(per_layer_expert.sum()),
@@ -881,11 +937,13 @@ class ContinuousBatchingEngine:
     def stats(self) -> Dict:
         compiles = compile_events() - self._compiles_base
         # Before the lock: the fetch waits for the step in flight.
-        moe = {"moe": self._moe_stats()} if self._moe else {}
+        tail = {"moe": self._moe_stats()} if "moe" in self._tail else {}
+        if "rec_count" in self._tail:
+            tail["ssm"] = self._ssm_stats()
         with self._lock:
             ts = max(self._timed_steps, 1)
             return {
-                **moe,
+                **tail,
                 # The device this engine's programs run on, as JAX
                 # reports it in this process, and what warm-up cost.
                 "device": self._device,
@@ -1050,6 +1108,8 @@ class ContinuousBatchingEngine:
             if h.obs is not None:
                 h.obs.marks["slot_grant"] = grant_t
             slot = self._free.popleft()
+            # Its first chunk starts every recurrent layer from zeros.
+            self._state_resets += bool(self.cfg.layer_pattern)
             row = self._bt_host[slot]
             row[:] = 0
             row[:len(res["pages"])] = res["pages"]
@@ -1073,6 +1133,8 @@ class ContinuousBatchingEngine:
         p_len = len(h.prompt)
         hashes = (paged_kv.page_hashes(h.prompt, ps)
                   if self._prefix_cache is not None else [])
+        if self.cfg.layer_pattern and self._prefix_wanted and p_len >= ps:
+            self._prefix_reuse_skipped += 1
         shared = self._prefix_cache.match(hashes) if hashes else []
         # Footprint: prompt + generated tokens + one margin row for the
         # pipelined in-flight step, capped by addressable positions.
@@ -1189,12 +1251,13 @@ class ContinuousBatchingEngine:
                 padded = np.zeros((1, c), dtype=np.int32)
                 padded[0, :n] = chunk
                 (logits, self._k, self._v, self._lengths,
-                 *self._moe) = self._prefill(
+                 *tail) = self._prefill(
                     self.params, padded,
                     np.int32(n), np.int32(slot), np.int32(off),
                     self._k, self._v, self._lengths, self._bt_dev,
-                    *self._moe,
+                    **self._tail,
                 )
+                self._tail = dict(zip(self._tail, tail))
                 entry["offset"] = off + n
                 if entry["offset"] < len(h.prompt):
                     continue
@@ -1317,20 +1380,21 @@ class ContinuousBatchingEngine:
                 if self._sampled_active:
                     self._rng, step_key = jax.random.split(self._rng)
                     (next_dev, self._k, self._v, self._lengths,
-                     *self._moe) = self._decode_sampled(
+                     *tail) = self._decode_sampled(
                         self.params, self._tokens_dev,
                         self._k, self._v, self._lengths,
                         self._active_dev, self._bt_dev,
                         self._temps_dev, self._top_ks_dev,
-                        self._top_ps_dev, step_key, *self._moe,
+                        self._top_ps_dev, step_key, **self._tail,
                     )
                 else:
                     (next_dev, self._k, self._v, self._lengths,
-                     *self._moe) = self._decode_greedy(
+                     *tail) = self._decode_greedy(
                         self.params, self._tokens_dev,
                         self._k, self._v, self._lengths,
-                        self._active_dev, self._bt_dev, *self._moe,
+                        self._active_dev, self._bt_dev, **self._tail,
                     )
+                self._tail = dict(zip(self._tail, tail))
                 self._tokens_dev = next_dev
                 # Start the D2H copy NOW: it lands while this thread
                 # distributes the previous step's tokens and the next
@@ -1487,6 +1551,8 @@ class ContinuousBatchingEngine:
                     cache = self._fresh_cache()
                     self._k, self._v = cache["k"], cache["v"]
                     self._lengths = cache["lengths"]
+                    if "rec" in cache:
+                        self._tail["rec"] = cache["rec"]
                     # Every outstanding page reference pointed into the
                     # dead cache: reset the allocator, drop the prefix
                     # cache WITHOUT releasing (the refs are void), zero

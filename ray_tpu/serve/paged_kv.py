@@ -14,7 +14,8 @@ prompt prefix store it once: the vLLM PagedAttention idea
 (arXiv:2309.06180), built for the engine's TPU discipline of static
 shapes and zero steady-state host traffic:
 
-  * One page pool `[layers, pages, page_size, kv_heads, head_dim]` and
+  * One page pool `[layers, pages, page_size, kv_heads, head_dim]` (the
+    last two axes one where a head is narrower than 128 lanes) and
     a per-slot block table `[slots, pages_per_slot]` resident on
     device. Decode gathers K/V *through* the block table (one gather
     per layer inside the jitted step); prefill scatters rows into the
@@ -63,12 +64,16 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ray_tpu.models import mamba2
 from ray_tpu.models.transformer import (
     TransformerConfig,
-    _act,
     _embed_tokens,
+    at_layer,
+    dense_mlp,
+    layer_kinds,
     project_logits,
     project_qkv,
+    residual,
 )
 from ray_tpu.ops import apply_rope, rmsnorm, rope_frequencies
 from ray_tpu.parallel.moe import EXPERT_LEAVES, moe_block
@@ -287,9 +292,18 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
                      mesh=None) -> Dict:
     """Device state of the paged cache: the page pool, per-slot lengths,
     and the block table (all entries NULL_PAGE). KV heads shard over
-    "tp"; everything else is replicated."""
-    shape = (cfg.n_layers, num_pages, page_size, cfg.n_kv_heads,
-             cfg.head_dim)
+    "tp"; everything else is replicated. A hybrid's pool has its
+    attention layers only, indexed by their own count, and the cache
+    gains `rec`, the recurrent pool (`init_recurrent_pool`)."""
+    kv_layers = (cfg.layers_of("attention") if cfg.layer_pattern
+                 else cfg.n_layers)
+    # A row's heads side by side where a head does not fill a tile's 128
+    # lanes: under `[.., kv_heads, head_dim]` at head_dim 64 the compiler
+    # turns the whole pool into another tiling between a layer's scatter
+    # and its gather (seen in the program compiled for the v5e).
+    row = ((cfg.n_kv_heads, cfg.head_dim) if cfg.head_dim % 128 == 0
+           else (cfg.n_kv_heads * cfg.head_dim,))
+    shape = (kv_layers, num_pages, page_size, *row)
     cache = {
         "k": jnp.zeros(shape, dtype=cfg.dtype),
         "v": jnp.zeros(shape, dtype=cfg.dtype),
@@ -310,15 +324,25 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
             "lengths": jax.device_put(cache["lengths"], rep),
             "block_tables": jax.device_put(cache["block_tables"], rep),
         }
+    if cfg.layer_pattern:
+        cache["rec"] = init_recurrent_pool(cfg, slots)
     return cache
 
 
-def _grouped_attention(q, kf, vf, valid):
+def init_recurrent_pool(cfg: TransformerConfig, slots: int) -> Dict:
+    """The second pool of a model with recurrent layers: a row of fixed
+    size a slot and a Mamba layer, `state [ssm layers, slots, heads,
+    d_head, d_state]` float32 and the convolution's last inputs `conv [ssm
+    layers, slots, d_conv - 1, conv_dim]` in the weights' dtype. It rides
+    in the layer walk's carry beside the pages and is donated with them."""
+    return mamba2.init_state(cfg, cfg.layers_of("mamba"), slots)
+
+
+def _grouped_attention(q, kf, vf, valid, scale):
     """q [S, Lq, H, D] vs caches [S, Lk, KVH, D]; valid [S, Lq, Lk]."""
     s_, lq, h, d = q.shape
     kvh = kf.shape[2]
     group = h // kvh
-    scale = d ** -0.5
     qg = q.reshape(s_, lq, kvh, group, d).astype(jnp.float32)
     scores = jnp.einsum("sqhgd,skhd->shgqk", qg, kf) * scale
     scores = jnp.where(valid[:, None, None], scores, NEG_INF)
@@ -339,26 +363,131 @@ def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
     layer's output, its caches and, for a model with experts, the
     assignments each expert received `[E]` (else None); `layer` is
     `moe_block`'s: the index at which `lp`'s expert stacks, then the
-    whole model's, are read in place."""
+    whole model's, are read in place. `cos` is None for a model without
+    a position embedding."""
     b, l = x.shape[:2]
     h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh)
     q, k, v = project_qkv(h, lp, cfg)
-    q = apply_rope(q, cos, sin, positions)
-    k = apply_rope(k, cos, sin, positions)
+    if cos is not None:
+        q = apply_rope(q, cos, sin, positions)
+        k = apply_rope(k, cos, sin, positions)
     k_cache_l, v_cache_l, k_att, v_att = write_kv(k_cache_l, v_cache_l, k, v)
     attn = _grouped_attention(
-        q, k_att.astype(jnp.float32), v_att.astype(jnp.float32), valid
+        q, k_att.astype(jnp.float32), v_att.astype(jnp.float32), valid,
+        cfg.attention_scale,
     )
-    x = x + (attn.reshape(b, l, -1) @ lp["wo"]).astype(x.dtype)
+    x = residual(x, (attn.reshape(b, l, -1) @ lp["wo"]).astype(x.dtype), cfg)
     h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh)
     if cfg.num_experts:
         y, routing = moe_block(h.reshape(b * l, -1), lp, cfg, layer)
         return (x + y.reshape(b, l, -1), k_cache_l, v_cache_l,
                 routing["counts"])
-    gate = _act(cfg)((h @ lp["w_gate"]).astype(jnp.float32))
-    up = (h @ lp["w_up"]).astype(jnp.float32)
-    x = x + (((gate * up).astype(x.dtype)) @ lp["w_down"])
-    return x, k_cache_l, v_cache_l, None
+    return residual(x, dense_mlp(h, lp, cfg), cfg), k_cache_l, v_cache_l, None
+
+
+def _rows(new, pool):
+    """Rows `new [R, kv_heads, head_dim]` as `pool` holds a row."""
+    return new.astype(pool.dtype).reshape(new.shape[:1] + pool.shape[3:])
+
+
+def _rope_tables(cfg, max_len):
+    """(cos, sin), or (None, None) for a model without a position
+    embedding."""
+    if cfg.position_embedding_type != "rope":
+        return None, None
+    return rope_frequencies(cfg.head_dim, max_len, cfg.rope_theta)
+
+
+def _walk_hybrid(params, x, k_cache, v_cache, rec, write_kv, rec_io,
+                 n_valid, cfg, cos, sin, positions, valid, mesh=None):
+    """A hybrid's layers in turn, `_scan_layers` for layers of two kinds:
+    the KV pool (attention layers only, indexed by their own count) and
+    the recurrent pool `rec` both ride in the carry and are updated in
+    place. ONE scan over the attention layers; each of its steps first
+    walks the run of Mamba layers that stands before its attention layer
+    (a `fori_loop` whose bounds are the scan's inputs: 5, 9, 9, 9 for
+    granite-4.0-h-micro), and the Mamba layers after the last attention
+    layer follow in a loop of their own. So a Mamba layer is compiled
+    twice and an attention layer once, whatever the depth and whatever
+    the pattern, and every buffer is a loop carry from the first layer to
+    the last. (One scan over all layers with a `lax.cond` on the kind
+    would hand each pool through the branch that does not touch it, and a
+    conditional's result that is its own argument is copied: 3.7 GB four
+    times a step here.) Every layer reads its weights from its kind's
+    stack at its own index.
+
+    `rec_io = (read, write)`: `read(rec, j) -> (state [B, H, P, N], conv
+    [B, K-1, C])` of the rows this call advances in Mamba layer `j`, and
+    `write(rec, j, state, conv) -> rec`; `n_valid [B]` real rows of each
+    (`mamba2.mixer`). Returns x, the caches and `rec`."""
+    layers = params["layers"]
+    read_rec, write_rec = rec_io
+    is_mamba, _ = layer_kinds(cfg)
+    attn_at = np.flatnonzero(~is_mamba)          # each attention layer's place
+    run_from = np.concatenate([[0], attn_at[:-1] + 1])
+    n_attn = len(attn_at)
+
+    def mamba_run(x, rec, first, ssm_first, count):
+        """`count` Mamba layers from layer `first`, the `ssm_first`-th of
+        their kind."""
+        def one(t, carry):
+            x, rec = carry
+            lp = at_layer(layers["ssm"], ssm_first + t)
+            mlp = at_layer(layers["mlp"], first + t)
+            state, conv = read_rec(rec, ssm_first + t)
+            out, state, conv = mamba2.mixer(
+                rmsnorm(x, lp["norm"], cfg.norm_eps, mesh=mesh), lp, cfg,
+                state, conv, n_valid)
+            rec = write_rec(rec, ssm_first + t, state, conv)
+            x = residual(x, out, cfg)
+            h = rmsnorm(x, mlp["mlp_norm"], cfg.norm_eps, mesh=mesh)
+            return residual(x, dense_mlp(h, mlp, cfg), cfg), rec
+
+        return jax.lax.fori_loop(0, count, one, (x, rec))
+
+    def period(carry, inputs):
+        x, kc, vc, rec = carry
+        attn, a, at, first = inputs
+        x, rec = mamba_run(x, rec, first, first - a, at - first)
+        x, kc, vc, _ = _layer_body(
+            x, {**attn, **at_layer(layers["mlp"], at)}, kc, vc, cfg, cos, sin,
+            positions, functools.partial(write_kv, a), valid, mesh)
+        return (x, kc, vc, rec), None
+
+    (x, k_cache, v_cache, rec), _ = jax.lax.scan(
+        period, (x, k_cache, v_cache, rec),
+        (layers["attn"], jnp.arange(n_attn, dtype=jnp.int32),
+         jnp.asarray(attn_at, jnp.int32), jnp.asarray(run_from, jnp.int32)))
+    tail = int(attn_at[-1]) + 1
+    if tail < cfg.n_layers:
+        x, rec = mamba_run(x, rec, tail, tail - n_attn, cfg.n_layers - tail)
+    return x, k_cache, v_cache, rec
+
+
+def init_ssm_counters() -> Dict:
+    """The device-resident accumulator of a model with recurrent layers
+    (`init_routing_counters`' pattern: last argument and result of the
+    step programs, not donated, fetched by `engine.stats()["ssm"]` alone):
+    slot rows a decode step computed and those of them that belonged to a
+    live sequence (the others' state is computed and left as it was),
+    a prefill chunk's tokens computed and those that were real, and the
+    calls of a step program."""
+    return {name: jnp.zeros((), jnp.int32) for name in (
+        "decode_rows_live", "decode_rows_computed", "prefill_tokens_valid",
+        "prefill_tokens_computed", "calls")}
+
+
+def _with_recurrent(out, rec, count, **added):
+    """A step program's results with the recurrent pool and, where the
+    caller passed one, the accumulator advanced by `added` appended; a
+    model without recurrent layers gets `out` as it is."""
+    if rec is None:
+        return out
+    if count is None:
+        return (*out, rec)
+    return (*out, rec, {
+        name: total + added.get(name, 0) + (name == "calls")
+        for name, total in count.items()})
 
 
 def _scan_layers(params, x, k_cache, v_cache, write_kv, cfg, cos, sin,
@@ -513,7 +642,8 @@ def _pick_tokens(logits, temps, top_ks, top_ps, key):
 
 def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
                  block_tables, temps, top_ks, top_ps, key,
-                 cfg: TransformerConfig, max_len: int, mesh=None, moe=None):
+                 cfg: TransformerConfig, max_len: int, mesh=None, moe=None,
+                 rec=None, rec_count=None):
     """One decode step for every slot at once, K/V gathered through the
     block table.
 
@@ -522,6 +652,9 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
     (next_tokens [S], k_pages, v_pages, new_lengths), the pool updated
     in place. With `moe`, a model with experts' routing accumulator, the
     advanced accumulator comes back as a fifth result (`_count_routing`).
+    With `rec`, a hybrid's recurrent pool (donate it), the pool and then
+    the advanced `rec_count` (`init_ssm_counters`) come back last: a slot
+    that is not active keeps its state and its convolution inputs.
 
     Each active slot writes its new K/V row into page
     `block_tables[slot, lengths[slot] // page_size]` at row
@@ -537,9 +670,9 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
     ps = k_pages.shape[2]
     mp = block_tables.shape[1]
     width = mp * ps
-    kvh, hd = k_pages.shape[3], k_pages.shape[4]
+    kvh, hd = cfg.n_kv_heads, cfg.head_dim
     x = _embed_tokens(params, tokens[:, None], cfg)  # [S, 1, d]
-    cos, sin = rope_frequencies(cfg.head_dim, max_len, cfg.rope_theta)
+    cos, sin = _rope_tables(cfg, max_len)
     positions = lengths[:, None]
     pos_w = jnp.where(active, jnp.minimum(lengths, max_len - 1), 0)
     page_of = jnp.minimum(pos_w // ps, mp - 1)
@@ -555,16 +688,28 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
         # as a contiguous [width] view, both through the layer index
         # (slicing kc[i] out first would copy the layer). Inactive slots
         # all target (NULL_PAGE, 0); whichever lands is never unmasked.
-        kc = kc.at[i, pages_w, rows_w].set(k[:, 0].astype(kc.dtype))
-        vc = vc.at[i, pages_w, rows_w].set(v[:, 0].astype(vc.dtype))
+        kc = kc.at[i, pages_w, rows_w].set(_rows(k[:, 0], kc))
+        vc = vc.at[i, pages_w, rows_w].set(_rows(v[:, 0], vc))
         k_att = kc[i, block_tables].reshape(s_, width, kvh, hd)
         v_att = vc[i, block_tables].reshape(s_, width, kvh, hd)
         return kc, vc, k_att, v_att
 
-    x, k_new, v_new, counts = _scan_layers(
-        params, x, k_pages, v_pages, write_kv, cfg, cos, sin, positions,
-        valid, mesh,
-    )
+    if rec is None:
+        x, k_new, v_new, counts = _scan_layers(
+            params, x, k_pages, v_pages, write_kv, cfg, cos, sin, positions,
+            valid, mesh,
+        )
+    else:
+        # Every slot's row of a layer at once, the whole layer written
+        # back at its index: an idle slot's row passes through unchanged.
+        rec_io = (lambda rec, j: (rec["state"][j], rec["conv"][j]),
+                  lambda rec, j, state, conv: {
+                      "state": rec["state"].at[j].set(state),
+                      "conv": rec["conv"].at[j].set(conv)})
+        x, k_new, v_new, rec = _walk_hybrid(
+            params, x, k_pages, v_pages, rec, write_kv, rec_io,
+            active.astype(jnp.int32), cfg, cos, sin, positions, valid, mesh)
+        counts = None
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
     logits = project_logits(x[:, -1], params, cfg)
     new_lengths = jnp.where(active, lengths + 1, lengths)
@@ -572,14 +717,17 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
         next_tokens = jnp.argmax(logits, axis=-1).astype(jnp.int32)
     else:
         next_tokens = _pick_tokens(logits, temps, top_ks, top_ps, key)
-    return _count_routing((next_tokens, k_new, v_new, new_lengths), moe,
-                          counts)
+    out = _count_routing((next_tokens, k_new, v_new, new_lengths), moe,
+                         counts)
+    return _with_recurrent(out, rec, rec_count,
+                           decode_rows_live=active.sum(dtype=jnp.int32),
+                           decode_rows_computed=s_)
 
 
 def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
                         v_pages, lengths, block_tables,
                         cfg: TransformerConfig, max_len: int, mesh=None,
-                        moe=None):
+                        moe=None, rec=None, rec_count=None):
     """CHUNKED prefill: one fixed-size chunk of a prompt into slot
     `slot` at row `offset`, so that a long prompt's prefill interleaves
     with other slots' decode steps instead of stalling them.
@@ -594,14 +742,22 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
 
     Prefix-cache resumption needs nothing special here: the engine
     starts `offset` at the shared-prefix boundary and the gathered
-    pages already hold the donor's K/V rows below it."""
+    pages already hold the donor's K/V rows below it.
+
+    With `rec`, a hybrid's recurrent pool (donate it): the chunk starts
+    from the slot's row of every Mamba layer, or from zeros where `offset`
+    is 0 (a slot's first chunk, whatever the last tenant left), and
+    leaves there the state and the convolution inputs after its last REAL
+    token; padding rows advance nothing. A prompt cannot resume below a
+    shared prefix without the state at that boundary, which no one keeps.
+    The pool and the advanced `rec_count` come back last."""
     _, c = tokens.shape
     ps = k_pages.shape[2]
     mp = block_tables.shape[1]
     width = mp * ps
-    kvh, hd = k_pages.shape[3], k_pages.shape[4]
+    kvh, hd = cfg.n_kv_heads, cfg.head_dim
     x = _embed_tokens(params, tokens, cfg)
-    cos, sin = rope_frequencies(cfg.head_dim, max_len, cfg.rope_theta)
+    cos, sin = _rope_tables(cfg, max_len)
     positions = offset + jnp.arange(c, dtype=jnp.int32)[None, :]
     q_pos = positions[:, :, None]                               # [1, C, 1]
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, c, width), 2)
@@ -614,21 +770,39 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     rows_w = pos % ps
 
     def write_kv(i, kc, vc, k, v):
-        kc = kc.at[i, pages_w, rows_w].set(k[0].astype(kc.dtype))
-        vc = vc.at[i, pages_w, rows_w].set(v[0].astype(vc.dtype))
+        kc = kc.at[i, pages_w, rows_w].set(_rows(k[0], kc))
+        vc = vc.at[i, pages_w, rows_w].set(_rows(v[0], vc))
         k_att = kc[i, bt_row].reshape(1, width, kvh, hd)
         v_att = vc[i, bt_row].reshape(1, width, kvh, hd)
         return kc, vc, k_att, v_att
 
-    x, k_new, v_new, counts = _scan_layers(
-        params, x, k_pages, v_pages, write_kv, cfg, cos, sin, positions,
-        valid, mesh,
-    )
+    if rec is None:
+        x, k_new, v_new, counts = _scan_layers(
+            params, x, k_pages, v_pages, write_kv, cfg, cos, sin, positions,
+            valid, mesh,
+        )
+    else:
+        carried = offset > 0
+
+        def read_rec(rec, j):
+            return (jnp.where(carried, rec["state"][j, slot], 0.0)[None],
+                    jnp.where(carried, rec["conv"][j, slot], 0)[None])
+
+        def write_rec(rec, j, state, conv):
+            return {"state": rec["state"].at[j, slot].set(state[0]),
+                    "conv": rec["conv"].at[j, slot].set(conv[0])}
+
+        x, k_new, v_new, rec = _walk_hybrid(
+            params, x, k_pages, v_pages, rec, write_kv, (read_rec, write_rec),
+            jnp.reshape(n_valid, (1,)), cfg, cos, sin, positions, valid, mesh)
+        counts = None
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
     last = jax.lax.dynamic_slice(x, (0, n_valid - 1, 0), (1, 1, x.shape[-1]))
     logits = project_logits(last[:, 0], params, cfg)
     new_lengths = lengths.at[slot].set(offset + n_valid)
-    return _count_routing((logits, k_new, v_new, new_lengths), moe, counts)
+    out = _count_routing((logits, k_new, v_new, new_lengths), moe, counts)
+    return _with_recurrent(out, rec, rec_count, prefill_tokens_valid=n_valid,
+                           prefill_tokens_computed=c)
 
 
 def cow_copy_page(k_pages, v_pages, src, dst):

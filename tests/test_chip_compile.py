@@ -264,3 +264,75 @@ def test_expert_model_step_reads_its_expert_stacks_in_place(
     # cache in float32, under one expert matrix's 268 MB at these slots.
     one_matrix = 2 * cfg.num_experts * cfg.d_model * cfg.d_ff
     assert compiled.memory_analysis().temp_size_in_bytes < one_matrix
+
+
+@pytest.mark.parametrize("program", ENGINE_PROGRAMS)
+def test_hybrid_step_updates_both_pools_in_place(v5e, program):
+    """granite-4.0-h-micro's step programs at its published sizes, all 40
+    layers, at the serving cell's 48 slots x 1024 (chunks of 256): the
+    pages and the recurrent pool ride in the layer walk's carry and come
+    back in the buffers they came in, no stack of weights and no pool is
+    copied, and what is left among the temporaries is a step's own (the
+    gathered pages of one attention layer in float32, a chunk's decay
+    matrix). Seen here before the cell ran: `in_proj` held whole
+    `[36, 2048, 8512]` was copied into another tiling at every call (1.25
+    GB of temporaries), and pages laid `[.., 8, 64]` were turned over whole
+    between a layer's scatter and its gather."""
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.serve import paged_kv
+
+    cfg = configs.get_config("granite-4.0-h-micro")
+    one = SingleDeviceSharding(v5e[0])
+    slots, chunk, per_slot = 48, 256, MAX_LEN // PAGE
+
+    def described(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    params = described(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = described(jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, slots, slots * per_slot + 1, PAGE, per_slot)))
+    count = described(jax.eval_shape(paged_kv.init_ssm_counters))
+    assert cache["k"].shape == (4, slots * per_slot + 1, PAGE, 8 * 64)
+    assert cache["rec"]["state"].shape == (36, slots, 64, 64, 128)
+    assert cache["rec"]["conv"].shape == (36, slots, 3, 4352)
+
+    def struct(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    pool = (cache["k"], cache["v"], cache["lengths"])
+    if program == "decode_paged":
+        fn = lambda p, t, k, v, ln, a, bt, tp, tk, tpp, key, rec, c: (  # noqa: E731
+            paged_kv.decode_paged(p, t, k, v, ln, a, bt, tp, tk, tpp, key,
+                                  cfg, MAX_LEN, None, None, rec, c))
+        args = (params, struct((slots,)), *pool, struct((slots,), jnp.bool_),
+                cache["block_tables"], struct((slots,), jnp.float32),
+                struct((slots,)), struct((slots,), jnp.float32),
+                struct((2,), jnp.uint32), cache["rec"], count)
+        donated = (2, 3, 11)
+    else:
+        fn = lambda p, t, n, s, o, k, v, ln, bt, rec, c: (  # noqa: E731
+            paged_kv.prefill_chunk_paged(p, t, n, s, o, k, v, ln, bt, cfg,
+                                         MAX_LEN, None, None, rec, c))
+        scalar = struct(())
+        args = (params, struct((1, chunk)), scalar, scalar, scalar, *pool,
+                cache["block_tables"], cache["rec"], count)
+        donated = (5, 6, 9)
+    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    pools = sum(a.size * a.dtype.itemsize for a in (
+        cache["k"], cache["v"], *cache["rec"].values()))
+    assert memory.alias_size_in_bytes >= pools
+    # 71 MB in the decode program and 63 MB in a chunk when written.
+    assert memory.temp_size_in_bytes < 128 * 2**20
+    text = compiled.as_text()
+    # (The convolution's saved inputs are 45 MB of the 3.7 GB pool, and a
+    # chunk does turn those over: the decode program wants the 3 inputs of
+    # all slots side by side, a chunk one slot's, whichever way they lie.)
+    whole = [",".join(map(str, a.shape)) for a in (
+        cache["k"], cache["rec"]["state"],
+        *params["layers"]["ssm"].values(), *params["layers"]["mlp"].values())
+        if a.size > 2**24]  # not the 9 MB of dt columns, prefetched whole
+    for dims in whole:
+        assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims

@@ -264,7 +264,8 @@ def test_llm_deployment_serving(rt_serve):
 def test_prefill_logits_are_the_first_step_of_the_served_path():
     """engine.prefill_logits: the logits the first served token is the
     argmax of, equal to the plain forward pass, over several chunks,
-    without touching the serving loop's cache or compiling anything."""
+    without touching the serving loop's cache; its one-slot scratch cache
+    compiles the prefill's shapes once, on the first call."""
     import jax.numpy as jnp
 
     from ray_tpu.models import forward
@@ -280,6 +281,7 @@ def test_prefill_logits_are_the_first_step_of_the_served_path():
         # events): run the eager reference before the snapshot.
         reference, _ = forward(params, jnp.asarray([prompt]), cfg)
         reference = np.asarray(reference[0, -1])
+        eng.prefill_logits(prompt)
         compiles = eng.stats()["compiles"]
         logits = eng.prefill_logits(prompt)
         assert eng.stats()["compiles"] == compiles
