@@ -553,6 +553,13 @@ class ContinuousBatchingEngine:
         self._params_dirty = False
         self._sampled_active = False
         self._param_uploads = 0  # refresh events (tests pin steady state)
+        # stats()["attention"]: how much of the cache decode attention is
+        # given to read. `_rows_host` mirrors a decoding slot's device
+        # length (the device's own reaches the host a step late): the
+        # prompt's at the slot's first step, one more each step after.
+        self._rows_host = np.zeros(num_slots, dtype=np.int64)
+        self._attn_rows_read = 0
+        self._attn_rows_held = 0
         # The loop's phase ledger (loop-thread-only) and the copy the
         # loop publishes under the lock after each turn for stats().
         self._phase = _PhaseLedger()
@@ -599,7 +606,7 @@ class ContinuousBatchingEngine:
         between greedy and sampled never compiles mid-serving. All
         warmup calls run with `active` all-False and an all-NULL block
         table: decode and prefill writes land in the NULL page, which is
-        never gathered unmasked, so cache contents stay semantically
+        never read unmasked, so cache contents stay semantically
         untouched."""
         # The loop's own two-way split (and the unpacking's unstack).
         self._rng, k1 = jax.random.split(self._rng)
@@ -964,6 +971,15 @@ class ContinuousBatchingEngine:
                 "warm_compiles": self._warm_compiles,
                 "recompiles_post_warm": compiles - self._warm_compiles,
                 "param_uploads": self._param_uploads,
+                # Cumulative over dispatched decode steps: rows in the
+                # pages decode attention was given to read (each decoding
+                # slot's length in whole pages) and rows the pool holds
+                # (slots x max_len a step), from host mirrors: nothing is
+                # fetched for it.
+                "attention": {
+                    "decode_rows_read": self._attn_rows_read,
+                    "decode_rows_held": self._attn_rows_held,
+                },
                 # Where the loop's time goes (EQuARX discipline — you
                 # cannot shrink a step you cannot decompose). _total
                 # fields are cumulative: probes delta two stats()
@@ -1306,6 +1322,7 @@ class ContinuousBatchingEngine:
                 else:
                     self._slot_pages[slot] = entry["pages"]
                     self._slots[slot] = h
+                    self._rows_host[slot] = len(h.prompt)
                     self._gen[slot] += 1
                     self._temps[slot] = h.temperature
                     self._top_ks[slot] = h.top_k
@@ -1370,6 +1387,15 @@ class ContinuousBatchingEngine:
             snapshot = [
                 (s, int(self._gen[s]), h) for s, h in self._slots.items()
             ]
+            if snapshot:
+                # The step about to be dispatched: each decoding slot
+                # attends to its rows and the one it writes, in whole
+                # pages; the pool holds max_len rows for every slot.
+                live = list(self._slots)
+                self._rows_host[live] += 1
+                pages = -(-self._rows_host[live] // self.page_size)
+                self._attn_rows_read += int(pages.sum()) * self.page_size
+                self._attn_rows_held += self.num_slots * self.max_len
         new_inflight, dispatch_s, fetch_s = None, 0.0, 0.0
         if snapshot:
             if self._params_dirty:

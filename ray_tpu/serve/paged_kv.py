@@ -14,17 +14,18 @@ prompt prefix store it once: the vLLM PagedAttention idea
 (arXiv:2309.06180), built for the engine's TPU discipline of static
 shapes and zero steady-state host traffic:
 
-  * One page pool `[layers, pages, page_size, kv_heads, head_dim]` (the
-    last two axes one where a head is narrower than 128 lanes) and
-    a per-slot block table `[slots, pages_per_slot]` resident on
-    device. Decode gathers K/V *through* the block table (one gather
-    per layer inside the jitted step); prefill scatters rows into the
-    pages the table names. Program shapes depend only on the pool and
-    table geometry, so compilation stays bounded.
+  * One page pool `[layers, pages, page_size, kv_heads * head_dim]` (a
+    row's heads side by side) and a per-slot block table `[slots,
+    pages_per_slot]` resident on device. Decode attends *through* the
+    block table, reading each slot's live pages where they lie
+    (`ops.paged_attention`: a kernel on the chip, a gather of the whole
+    table elsewhere); prefill scatters rows into the pages the table
+    names and gathers its one slot's. Program shapes depend only on the
+    pool and table geometry, so compilation stays bounded.
     The pool is donated to the step and carried whole through the scan
-    over layers; layer i scatters into `pool[i, pages, rows]` and
-    gathers `pool[i, block_tables]`, so a step touches the rows it
-    writes and the pages it attends to, and never copies the pool.
+    over layers; layer i scatters into `pool[i, pages, rows]` and reads
+    `pool[i]` through the table, so a step touches the rows it writes
+    and the pages it attends to, and never copies the pool.
   * A host-side free-list allocator with REFCOUNTED pages. Admission
     reserves every page a request can ever touch up front
     (ceil((prompt + max_new + 1) / page_size)); decode then never
@@ -44,10 +45,10 @@ shapes and zero steady-state host traffic:
 
 Page 0 is reserved as the NULL/scratch page: block-table entries
 default to it, inactive-slot decode writes park in it, and prefill
-padding rows drop into it — it is never gathered unmasked, so its
+padding rows drop into it — it is never read unmasked, so its
 contents are never observable.
 
-Gathered row i of a slot is absolute position i (pages are table-
+Row i of a slot's table is absolute position i (pages are table-
 ordered) and masked lanes underflow to exact 0.0 in the f32 softmax, so
 greedy decoding gives `models.generate`'s tokens, whose one-length cache
 is the plain reference (tests/test_paged_kv.py, tests/test_serve_llm.py).
@@ -76,11 +77,14 @@ from ray_tpu.models.transformer import (
     residual,
 )
 from ray_tpu.ops import apply_rope, rmsnorm, rope_frequencies
+from ray_tpu.ops.paged_attention import (
+    grouped_attention,
+    paged_decode_attention,
+)
 from ray_tpu.parallel.moe import EXPERT_LEAVES, moe_block
 
 # The reserved NULL/scratch page (see module docstring).
 NULL_PAGE = 0
-NEG_INF = -1e30
 
 
 class OutOfPages(RuntimeError):
@@ -297,13 +301,13 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
     gains `rec`, the recurrent pool (`init_recurrent_pool`)."""
     kv_layers = (cfg.layers_of("attention") if cfg.layer_pattern
                  else cfg.n_layers)
-    # A row's heads side by side where a head does not fill a tile's 128
-    # lanes: under `[.., kv_heads, head_dim]` at head_dim 64 the compiler
-    # turns the whole pool into another tiling between a layer's scatter
-    # and its gather (seen in the program compiled for the v5e).
-    row = ((cfg.n_kv_heads, cfg.head_dim) if cfg.head_dim % 128 == 0
-           else (cfg.n_kv_heads * cfg.head_dim,))
-    shape = (kv_layers, num_pages, page_size, *row)
+    # A row's heads side by side, one layout for every program that
+    # touches the pool: a page is then whole tiles whatever a head's
+    # width, which is how the decode kernel fetches it, and a head is a
+    # slice of the row's lanes. (Under `[.., kv_heads, head_dim]` at
+    # head_dim 64 the compiler turned the whole pool into another tiling
+    # between a layer's scatter and its gather.)
+    shape = (kv_layers, num_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
     cache = {
         "k": jnp.zeros(shape, dtype=cfg.dtype),
         "v": jnp.zeros(shape, dtype=cfg.dtype),
@@ -338,28 +342,15 @@ def init_recurrent_pool(cfg: TransformerConfig, slots: int) -> Dict:
     return mamba2.init_state(cfg, cfg.layers_of("mamba"), slots)
 
 
-def _grouped_attention(q, kf, vf, valid, scale):
-    """q [S, Lq, H, D] vs caches [S, Lk, KVH, D]; valid [S, Lq, Lk]."""
-    s_, lq, h, d = q.shape
-    kvh = kf.shape[2]
-    group = h // kvh
-    qg = q.reshape(s_, lq, kvh, group, d).astype(jnp.float32)
-    scores = jnp.einsum("sqhgd,skhd->shgqk", qg, kf) * scale
-    scores = jnp.where(valid[:, None, None], scores, NEG_INF)
-    p = jax.nn.softmax(scores, axis=-1)
-    out = jnp.einsum("shgqk,skhd->sqhgd", p, vf).reshape(s_, lq, h, d)
-    return out.astype(q.dtype)
-
-
 def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
-                write_kv, valid, mesh=None, layer=None):
+                attend, mesh=None, layer=None):
     """One transformer layer, shared by the decode and prefill programs.
 
-    The two callers differ only in how K/V land in the cache and what
-    the attention source/mask is: `write_kv(kc, vc, k, v) -> (kc, vc,
-    k_att, v_att)` encapsulates that, `valid` is the caller's mask over
-    (B, Lq, Lk_att). `mesh` is the engine's: activations are replicated
-    over it, so the norm kernel runs whole on every device. Returns the
+    The two callers differ only in how K/V land in the cache and how the
+    queries meet it: `attend(kc, vc, q, k, v) -> (kc, vc, attention
+    [B, L, H, D])` encapsulates that. `mesh` is the engine's: activations
+    are replicated over it, so the norm kernel runs whole on every
+    device, and KV heads lie over its "tp". Returns the
     layer's output, its caches and, for a model with experts, the
     assignments each expert received `[E]` (else None); `layer` is
     `moe_block`'s: the index at which `lp`'s expert stacks, then the
@@ -371,11 +362,7 @@ def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
     if cos is not None:
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
-    k_cache_l, v_cache_l, k_att, v_att = write_kv(k_cache_l, v_cache_l, k, v)
-    attn = _grouped_attention(
-        q, k_att.astype(jnp.float32), v_att.astype(jnp.float32), valid,
-        cfg.attention_scale,
-    )
+    k_cache_l, v_cache_l, attn = attend(k_cache_l, v_cache_l, q, k, v)
     x = residual(x, (attn.reshape(b, l, -1) @ lp["wo"]).astype(x.dtype), cfg)
     h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh)
     if cfg.num_experts:
@@ -398,8 +385,8 @@ def _rope_tables(cfg, max_len):
     return rope_frequencies(cfg.head_dim, max_len, cfg.rope_theta)
 
 
-def _walk_hybrid(params, x, k_cache, v_cache, rec, write_kv, rec_io,
-                 n_valid, cfg, cos, sin, positions, valid, mesh=None):
+def _walk_hybrid(params, x, k_cache, v_cache, rec, attend, rec_io,
+                 n_valid, cfg, cos, sin, positions, mesh=None):
     """A hybrid's layers in turn, `_scan_layers` for layers of two kinds:
     the KV pool (attention layers only, indexed by their own count) and
     the recurrent pool `rec` both ride in the carry and are updated in
@@ -451,7 +438,7 @@ def _walk_hybrid(params, x, k_cache, v_cache, rec, write_kv, rec_io,
         x, rec = mamba_run(x, rec, first, first - a, at - first)
         x, kc, vc, _ = _layer_body(
             x, {**attn, **at_layer(layers["mlp"], at)}, kc, vc, cfg, cos, sin,
-            positions, functools.partial(write_kv, a), valid, mesh)
+            positions, functools.partial(attend, a), mesh)
         return (x, kc, vc, rec), None
 
     (x, k_cache, v_cache, rec), _ = jax.lax.scan(
@@ -490,13 +477,13 @@ def _with_recurrent(out, rec, count, **added):
         for name, total in count.items()})
 
 
-def _scan_layers(params, x, k_cache, v_cache, write_kv, cfg, cos, sin,
-                 positions, valid, mesh=None):
+def _scan_layers(params, x, k_cache, v_cache, attend, cfg, cos, sin,
+                 positions, mesh=None):
     """Every layer in turn, the whole KV cache `[layers, ...]` riding in
     the scan's carry: the one way a step threads its cache through the
-    layers. `write_kv(i, kc, vc, k, v)` is
-    `_layer_body`'s with the layer index in front; it writes and reads
-    the whole cache at `[i, ...]`.
+    layers. `attend(i, kc, vc, q, k, v)` is `_layer_body`'s with the
+    layer index in front; it writes and reads the whole cache at
+    `[i, ...]`.
 
     A carry is one buffer from the first layer to the last, so with the
     caches donated each layer's rows are scattered into the caller's own
@@ -519,8 +506,7 @@ def _scan_layers(params, x, k_cache, v_cache, write_kv, cfg, cos, sin,
         lp, i = inputs
         x, kc, vc, counts = _layer_body(
             x, {**lp, **experts}, kc, vc, cfg, cos, sin, positions,
-            functools.partial(write_kv, i), valid, mesh,
-            i if experts else None,
+            functools.partial(attend, i), mesh, i if experts else None,
         )
         return (x, kc, vc), counts
 
@@ -644,7 +630,7 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
                  block_tables, temps, top_ks, top_ps, key,
                  cfg: TransformerConfig, max_len: int, mesh=None, moe=None,
                  rec=None, rec_count=None):
-    """One decode step for every slot at once, K/V gathered through the
+    """One decode step for every slot at once, K/V read through the
     block table.
 
     tokens [S] int32 (last emitted per slot; 0 for inactive), lengths
@@ -659,8 +645,12 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
     Each active slot writes its new K/V row into page
     `block_tables[slot, lengths[slot] // page_size]` at row
     `lengths[slot] % page_size`; inactive slots park the write in the
-    NULL page and keep their length. Attention gathers the slot's whole
-    table (width = pages_per_slot * page_size) and masks by length.
+    NULL page and keep their length. An active slot then attends to its
+    `lengths + 1` rows where they lie in the pool, the row just written
+    among them (`ops.paged_decode_attention`): on the chip a kernel
+    fetches the pages that hold them and no others, elsewhere the slot's
+    whole table is gathered and masked by that count. A slot that is not
+    active attends to nothing; its result is finite and unused.
 
     The next token is computed ON DEVICE so the engine can feed it
     straight into the next dispatched step without a host round trip.
@@ -669,8 +659,6 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
     s_ = tokens.shape[0]
     ps = k_pages.shape[2]
     mp = block_tables.shape[1]
-    width = mp * ps
-    kvh, hd = cfg.n_kv_heads, cfg.head_dim
     x = _embed_tokens(params, tokens[:, None], cfg)  # [S, 1, d]
     cos, sin = _rope_tables(cfg, max_len)
     positions = lengths[:, None]
@@ -679,25 +667,25 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
     rows_w = pos_w % ps
     slot_idx = jnp.arange(s_)
     pages_w = jnp.where(active, block_tables[slot_idx, page_of], NULL_PAGE)
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (s_, 1, width), 2)
-    valid = k_pos <= positions[:, :, None]
+    rows_att = jnp.where(active, pos_w + 1, 0)
 
-    def write_kv(i, kc, vc, k, v):
-        # kc is the whole pool [layers, pages, ps, kvh, hd]: scatter one
-        # row per slot into layer i, then gather each slot's pages back
-        # as a contiguous [width] view, both through the layer index
-        # (slicing kc[i] out first would copy the layer). Inactive slots
-        # all target (NULL_PAGE, 0); whichever lands is never unmasked.
+    def attend(i, kc, vc, q, k, v):
+        # kc is the whole pool [layers, pages, ps, kvh * hd]: scatter one
+        # row per slot into layer i, then read layer i through the table,
+        # both through the layer index (slicing kc[i] out first would
+        # copy the layer). Inactive slots all target (NULL_PAGE, 0);
+        # whichever lands is never read.
         kc = kc.at[i, pages_w, rows_w].set(_rows(k[:, 0], kc))
         vc = vc.at[i, pages_w, rows_w].set(_rows(v[:, 0], vc))
-        k_att = kc[i, block_tables].reshape(s_, width, kvh, hd)
-        v_att = vc[i, block_tables].reshape(s_, width, kvh, hd)
-        return kc, vc, k_att, v_att
+        attn = paged_decode_attention(
+            q[:, 0], kc, vc, i, block_tables, rows_att, cfg.attention_scale,
+            mesh=mesh)
+        return kc, vc, attn[:, None]
 
     if rec is None:
         x, k_new, v_new, counts = _scan_layers(
-            params, x, k_pages, v_pages, write_kv, cfg, cos, sin, positions,
-            valid, mesh,
+            params, x, k_pages, v_pages, attend, cfg, cos, sin, positions,
+            mesh,
         )
     else:
         # Every slot's row of a layer at once, the whole layer written
@@ -707,8 +695,8 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
                       "state": rec["state"].at[j].set(state),
                       "conv": rec["conv"].at[j].set(conv)})
         x, k_new, v_new, rec = _walk_hybrid(
-            params, x, k_pages, v_pages, rec, write_kv, rec_io,
-            active.astype(jnp.int32), cfg, cos, sin, positions, valid, mesh)
+            params, x, k_pages, v_pages, rec, attend, rec_io,
+            active.astype(jnp.int32), cfg, cos, sin, positions, mesh)
         counts = None
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
     logits = project_logits(x[:, -1], params, cfg)
@@ -769,17 +757,19 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     pages_w = jnp.where(in_range, bt_row[page_of], NULL_PAGE)
     rows_w = pos % ps
 
-    def write_kv(i, kc, vc, k, v):
+    def attend(i, kc, vc, q, k, v):
         kc = kc.at[i, pages_w, rows_w].set(_rows(k[0], kc))
         vc = vc.at[i, pages_w, rows_w].set(_rows(v[0], vc))
         k_att = kc[i, bt_row].reshape(1, width, kvh, hd)
         v_att = vc[i, bt_row].reshape(1, width, kvh, hd)
-        return kc, vc, k_att, v_att
+        return kc, vc, grouped_attention(
+            q, k_att.astype(jnp.float32), v_att.astype(jnp.float32), valid,
+            cfg.attention_scale)
 
     if rec is None:
         x, k_new, v_new, counts = _scan_layers(
-            params, x, k_pages, v_pages, write_kv, cfg, cos, sin, positions,
-            valid, mesh,
+            params, x, k_pages, v_pages, attend, cfg, cos, sin, positions,
+            mesh,
         )
     else:
         carried = offset > 0
@@ -793,8 +783,8 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
                     "conv": rec["conv"].at[j, slot].set(conv[0])}
 
         x, k_new, v_new, rec = _walk_hybrid(
-            params, x, k_pages, v_pages, rec, write_kv, (read_rec, write_rec),
-            jnp.reshape(n_valid, (1,)), cfg, cos, sin, positions, valid, mesh)
+            params, x, k_pages, v_pages, rec, attend, (read_rec, write_rec),
+            jnp.reshape(n_valid, (1,)), cfg, cos, sin, positions, mesh)
         counts = None
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
     last = jax.lax.dynamic_slice(x, (0, n_valid - 1, 0), (1, 1, x.shape[-1]))

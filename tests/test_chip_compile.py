@@ -142,6 +142,27 @@ def test_kernels_compile_under_a_sharded_jit(v5e):
     with pytest.raises(NotImplementedError, match="shard_map"):
         _compile(unwrapped, x, w)
 
+    # The decode-attention kernel under the engine's mesh: KV heads (the
+    # pool's last axis, the queries' heads) over "tp", the rest whole.
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+
+    def whole(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype,
+                                    sharding=NamedSharding(mesh, P()))
+
+    pool = struct((DEPTH, SLOTS * (MAX_LEN // PAGE) + 1, PAGE,
+                   QWEN.n_kv_heads * QWEN.head_dim), P(None, None, None, "tp"))
+    queries = struct((SLOTS, QWEN.n_heads, QWEN.head_dim), P(None, "tp"))
+
+    def attend(q, k, v, layer, tables, rows):
+        return paged_decode_attention(q, k, v, layer, tables, rows,
+                                      QWEN.attention_scale, use_pallas=True,
+                                      mesh=mesh)
+
+    compiled = _compile(attend, queries, pool, pool, whole(()),
+                        whole((SLOTS, MAX_LEN // PAGE)), whole((SLOTS,)))
+    assert "paged_decode_attention" in compiled.as_text()
+
 
 # The serving cell's geometry (bench/configs/qwen3-4b-serve.json): 24 slots
 # of 1024 positions, pages of 16 rows and the NULL page, chunks of 64. Depth
@@ -164,8 +185,8 @@ def _engine_program(name, cfg, one, slots=SLOTS):
         lambda a: struct(a.shape, a.dtype),
         jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)))
     per_slot = MAX_LEN // PAGE
-    shape = (cfg.n_layers, slots * per_slot + 1, PAGE, cfg.n_kv_heads,
-             cfg.head_dim)
+    shape = jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, slots, slots * per_slot + 1, PAGE, per_slot))["k"].shape
     cache = struct(shape, cfg.dtype)
     lengths = struct((slots,))
     table = struct((slots, per_slot))
@@ -186,12 +207,14 @@ def _engine_program(name, cfg, one, slots=SLOTS):
 
 
 @pytest.mark.parametrize("program", ENGINE_PROGRAMS)
-def test_engine_step_updates_its_cache_in_place(v5e, program):
+def test_engine_step_updates_its_cache_in_place(v5e, program, monkeypatch):
     """The KV cache rides in the layer scan's carry, so a step with its
     caches donated scatters into the caller's buffers: no second cache
     among the temporaries (scanned over and stacked back a cache is two
     buffers, 3.73 GB of temporaries at 36 layers) and no copy of a whole
-    one."""
+    one, the decode-attention kernel reading the carried pool at the
+    layer's index included (a layer sliced out for it would be copied)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = dataclasses.replace(QWEN, n_layers=DEPTH)
     fn, donated, args, shape = _engine_program(
         program, cfg, SingleDeviceSharding(v5e[0]))
@@ -226,13 +249,65 @@ def test_sampled_decode_sorts_no_vocabulary(v5e, model, slots, depth,
     sorts = [line for line in compiled.as_text().splitlines()
              if " sort(" in line and vocabulary_wide.search(line)]
     assert not sorts
-    if not cfg.num_experts:
-        # The sorted float32 copy and its cumsum went with it: what is
-        # left is under one float32 `[slots, vocabulary]` (0.76 MB where
-        # the sort's program had 15.26 MB). OLMoE's temporaries are
-        # attention's gathered cache in float32 (136 MB) either way.
-        one_copy = 4 * slots * cfg.vocab_size
-        assert compiled.memory_analysis().temp_size_in_bytes < one_copy
+    # The sorted float32 copy and its cumsum went with it: what is left
+    # is under one float32 `[slots, vocabulary]` (0.76 MB where the
+    # sort's program had 15.26 MB; 2.0 MB at OLMoE's, which held its
+    # gathered cache in float32, 136 MB, until attention read the pool
+    # in place).
+    one_copy = 4 * slots * cfg.vocab_size
+    assert compiled.memory_analysis().temp_size_in_bytes < one_copy
+
+
+def _gathered_results(text, slots, row_elements):
+    """Lines of a compiled decode program whose result is a slot's whole
+    table brought together: `[slots x pages a slot, page, ...]` or
+    `[slots, pages a slot, page, ...]` in any type, or a float32 result
+    of the whole table's size (the cast cache, whichever way it lies)."""
+    per_slot = MAX_LEN // PAGE
+    by_page = re.compile(
+        rf"= \w+\[(?:{slots * per_slot}|{slots},{per_slot}),{PAGE},[\d,]+\]")
+    whole_f32 = re.compile(r"= f32\[([\d,]+)\]")
+
+    def elements(dims):
+        n = 1
+        for d in dims.split(","):
+            n *= int(d)
+        return n
+
+    return [line.strip()[:160] for line in text.splitlines()
+            if by_page.search(line) or any(
+                elements(m) >= slots * MAX_LEN * row_elements
+                for m in whole_f32.findall(line.split(" = ", 1)[-1][:80]))]
+
+
+@pytest.mark.parametrize("model,slots,depth", [
+    ("qwen3-4b", 24, DEPTH), ("olmoe-1b-7b", 16, 2),
+    ("granite-4.0-h-micro", 48, None)])
+def test_sampled_decode_reads_its_live_pages_in_place(v5e, model, slots, depth,
+                                                      monkeypatch):
+    """The sampled decode program at each serving cell's model and slots
+    holds the decode-attention kernel's custom call and nothing of the
+    gather it replaced: no result of the gathered shapes
+    (`bf16[1536,16,8,128]` and its float32 cast were 8.5 ms of Qwen3's
+    22 ms step, `f32[1024,16,16,128]` twice and the scores over them 11.8
+    of OLMoE's 32.6), and temporaries under one slot-major copy of a
+    layer's keys. The hybrid's narrow row `[8 x 64]` goes through the same
+    kernel, its 40 layers whole."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e[0])
+    cfg = configs.get_config(model)
+    if cfg.layer_pattern:
+        fn, donated, args, _ = _hybrid_program("decode_paged", one)
+    else:
+        cfg = dataclasses.replace(cfg, n_layers=depth)
+        fn, donated, args, _ = _engine_program("decode_paged", cfg, one, slots)
+    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%paged_decode_attention[.\d]* = f32\[", text)) == 1
+    row = cfg.n_kv_heads * cfg.head_dim
+    assert not _gathered_results(text, slots, row)
+    one_gather = 2 * slots * MAX_LEN * row
+    assert compiled.memory_analysis().temp_size_in_bytes < one_gather
 
 
 @pytest.mark.parametrize("program", ["decode_paged", "prefill_chunk_paged"])
@@ -260,29 +335,20 @@ def test_expert_model_step_reads_its_expert_stacks_in_place(
         stack = f"{cfg.num_experts},{inner}"
         assert not re.findall(
             rf"= bf16\[(?:1,)?{stack}\]\S* (?:copy|fusion)\(", text)
-    # What is left among the temporaries is attention's: the gathered
-    # cache in float32, under one expert matrix's 268 MB at these slots.
+    # What is left among the temporaries is a chunk's one slot of gathered
+    # pages and a step's activations, far under one expert matrix's 268 MB.
     one_matrix = 2 * cfg.num_experts * cfg.d_model * cfg.d_ff
     assert compiled.memory_analysis().temp_size_in_bytes < one_matrix
 
 
-@pytest.mark.parametrize("program", ENGINE_PROGRAMS)
-def test_hybrid_step_updates_both_pools_in_place(v5e, program):
-    """granite-4.0-h-micro's step programs at its published sizes, all 40
-    layers, at the serving cell's 48 slots x 1024 (chunks of 256): the
-    pages and the recurrent pool ride in the layer walk's carry and come
-    back in the buffers they came in, no stack of weights and no pool is
-    copied, and what is left among the temporaries is a step's own (the
-    gathered pages of one attention layer in float32, a chunk's decay
-    matrix). Seen here before the cell ran: `in_proj` held whole
-    `[36, 2048, 8512]` was copied into another tiling at every call (1.25
-    GB of temporaries), and pages laid `[.., 8, 64]` were turned over whole
-    between a layer's scatter and its gather."""
+def _hybrid_program(program, one):
+    """`_engine_program` for granite-4.0-h-micro at its published sizes,
+    all 40 layers, at the serving cell's 48 slots x 1024 (chunks of 256):
+    (function, donated arguments, argument shapes, the cache's shapes)."""
     from ray_tpu.models.transformer import init_params
     from ray_tpu.serve import paged_kv
 
     cfg = configs.get_config("granite-4.0-h-micro")
-    one = SingleDeviceSharding(v5e[0])
     slots, chunk, per_slot = 48, 256, MAX_LEN // PAGE
 
     def described(tree):
@@ -294,9 +360,6 @@ def test_hybrid_step_updates_both_pools_in_place(v5e, program):
     cache = described(jax.eval_shape(lambda: paged_kv.init_paged_cache(
         cfg, slots, slots * per_slot + 1, PAGE, per_slot)))
     count = described(jax.eval_shape(paged_kv.init_ssm_counters))
-    assert cache["k"].shape == (4, slots * per_slot + 1, PAGE, 8 * 64)
-    assert cache["rec"]["state"].shape == (36, slots, 64, 64, 128)
-    assert cache["rec"]["conv"].shape == (36, slots, 3, 4352)
 
     def struct(shape, dtype=jnp.int32):
         return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
@@ -310,21 +373,42 @@ def test_hybrid_step_updates_both_pools_in_place(v5e, program):
                 cache["block_tables"], struct((slots,), jnp.float32),
                 struct((slots,)), struct((slots,), jnp.float32),
                 struct((2,), jnp.uint32), cache["rec"], count)
-        donated = (2, 3, 11)
-    else:
-        fn = lambda p, t, n, s, o, k, v, ln, bt, rec, c: (  # noqa: E731
-            paged_kv.prefill_chunk_paged(p, t, n, s, o, k, v, ln, bt, cfg,
-                                         MAX_LEN, None, None, rec, c))
-        scalar = struct(())
-        args = (params, struct((1, chunk)), scalar, scalar, scalar, *pool,
-                cache["block_tables"], cache["rec"], count)
-        donated = (5, 6, 9)
+        return fn, (2, 3, 11), args, cache
+    fn = lambda p, t, n, s, o, k, v, ln, bt, rec, c: (  # noqa: E731
+        paged_kv.prefill_chunk_paged(p, t, n, s, o, k, v, ln, bt, cfg,
+                                     MAX_LEN, None, None, rec, c))
+    scalar = struct(())
+    args = (params, struct((1, chunk)), scalar, scalar, scalar, *pool,
+            cache["block_tables"], cache["rec"], count)
+    return fn, (5, 6, 9), args, cache
+
+
+@pytest.mark.parametrize("program", ENGINE_PROGRAMS)
+def test_hybrid_step_updates_both_pools_in_place(v5e, program, monkeypatch):
+    """granite-4.0-h-micro's step programs at its published sizes, all 40
+    layers, at the serving cell's 48 slots x 1024 (chunks of 256): the
+    pages and the recurrent pool ride in the layer walk's carry and come
+    back in the buffers they came in, no stack of weights and no pool is
+    copied, and what is left among the temporaries is a step's own (a
+    chunk's decay matrix and its one slot's gathered pages). Seen here
+    before the cell ran: `in_proj` held whole
+    `[36, 2048, 8512]` was copied into another tiling at every call (1.25
+    GB of temporaries), and pages laid `[.., 8, 64]` were turned over whole
+    between a layer's scatter and its gather."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, donated, args, cache = _hybrid_program(
+        program, SingleDeviceSharding(v5e[0]))
+    params = args[0]
+    assert cache["k"].shape == (4, 48 * (MAX_LEN // PAGE) + 1, PAGE, 8 * 64)
+    assert cache["rec"]["state"].shape == (36, 48, 64, 64, 128)
+    assert cache["rec"]["conv"].shape == (36, 48, 3, 4352)
     compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
     memory = compiled.memory_analysis()
     pools = sum(a.size * a.dtype.itemsize for a in (
         cache["k"], cache["v"], *cache["rec"].values()))
     assert memory.alias_size_in_bytes >= pools
-    # 71 MB in the decode program and 63 MB in a chunk when written.
+    # 63 MB in a chunk; 1.7 MB in the decode program, 71 MB while it
+    # gathered an attention layer's pages and cast them.
     assert memory.temp_size_in_bytes < 128 * 2**20
     text = compiled.as_text()
     # (The convolution's saved inputs are 45 MB of the 3.7 GB pool, and a

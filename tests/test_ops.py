@@ -283,6 +283,120 @@ def test_chunked_lm_head_ce_parity():
         )
 
 
+# The three served pools' rows: (KV heads, head width, queries a KV head,
+# scale). The last is the hybrid's: a head is half of a tile's 128 lanes,
+# no rope, scores scaled by 1/64.
+PAGED_POOLS = {"qwen3": (8, 128, 4, 128 ** -0.5),
+               "olmoe": (16, 128, 1, 128 ** -0.5),
+               "granite-h": (8, 64, 4, 1 / 64)}
+
+
+def _paged_case(kv_heads, head_dim, group, page=16, per_slot=8, layers=3):
+    """A pool, a table and lengths that meet every edge at once: slot 0
+    is inactive (length 0, parked on the NULL page), then 1 row, a page's
+    last row, a page's first row, max_len - 1, and two slots that share
+    their first two pages (a copy-on-write prefix). Every table is out of
+    order. Whole pages no live row lies in hold NaN, the NULL page too:
+    a kernel that touched one would show it."""
+    max_len = page * per_slot
+    rows = np.asarray([0, 1, page, page + 1, max_len - 1, 40, 37], np.int32)
+    slots = len(rows)
+    pages = slots * per_slot + 1
+    rng = np.random.default_rng(3)
+    tables = rng.permutation(np.arange(1, pages))[:slots * per_slot].reshape(
+        slots, per_slot).astype(np.int32)
+    tables[0] = 0
+    tables[6, :2] = tables[5, :2]
+    ks = jax.random.split(jax.random.PRNGKey(5), 3)
+    shape = (layers, pages, page, kv_heads * head_dim)
+    k_pool = jax.random.normal(ks[0], shape, jnp.float32)
+    v_pool = jax.random.normal(ks[1], shape, jnp.float32)
+    q = jax.random.normal(ks[2], (slots, kv_heads * group, head_dim))
+    live = np.zeros(pages, bool)
+    for s in range(slots):
+        live[tables[s, :-(-rows[s] // page)]] = True
+    poison = jnp.where(jnp.asarray(live)[None, :, None, None], 0.0, jnp.nan)
+    return q, k_pool, v_pool, poison, jnp.asarray(tables), jnp.asarray(rows)
+
+
+@pytest.mark.parametrize("block_rows", [32, 512], ids=["4blocks", "1block"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("pool", PAGED_POOLS)
+def test_paged_decode_attention_kernel_matches_the_plain_form(
+        pool, dtype, block_rows, monkeypatch):
+    """The decode-attention kernel (interpret mode) against the plain
+    form (the whole table gathered, cast and masked), at every layer of
+    the pool, in blocks shorter than a sequence and longer than any."""
+    from ray_tpu.ops import paged_attention
+
+    kv_heads, head_dim, group, scale = PAGED_POOLS[pool]
+    monkeypatch.setattr(paged_attention, "_MAX_BLOCK_ROWS", block_rows)
+    q, k_pool, v_pool, poison, tables, rows = _paged_case(
+        kv_heads, head_dim, group)
+    q, k_pool, v_pool = (a.astype(dtype) for a in (q, k_pool, v_pool))
+    assert paged_attention.kernel_takes(k_pool, head_dim)
+    live = np.asarray(rows) > 0
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    for layer in range(k_pool.shape[0]):
+        want = paged_attention.paged_decode_attention(
+            q, k_pool, v_pool, jnp.int32(layer), tables, rows, scale,
+            use_pallas=False)
+        got = paged_attention.paged_decode_attention(
+            q, k_pool + poison.astype(dtype), v_pool + poison.astype(dtype),
+            jnp.int32(layer), tables, rows, scale, interpret=True)
+        assert got.shape == q.shape and got.dtype == q.dtype
+        got, want = (np.asarray(a, np.float32) for a in (got, want))
+        assert np.isfinite(got).all()  # the inactive slot's row too
+        np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+
+
+def test_paged_decode_attention_takes_the_plain_form_for_other_pools():
+    """A page that is not whole tiles, or a head that does not divide a
+    tile's lanes, is read by the plain form whatever the backend: the
+    pool's shape chooses, nothing else does."""
+    from ray_tpu.ops.paged_attention import kernel_takes, pages_per_block
+
+    pool = lambda page, width, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        (2, 9, page, width), dtype)
+    assert kernel_takes(pool(16, 1024), 128)
+    assert kernel_takes(pool(16, 512), 64)
+    assert kernel_takes(pool(8, 256, jnp.float32), 256)
+    assert not kernel_takes(pool(8, 1024), 128)    # half a bf16 tile a page
+    assert not kernel_takes(pool(16, 64), 32)      # a row of half a tile
+    assert not kernel_takes(pool(16, 768), 96)     # 96 divides no 128
+    # Blocks from the page's shape: 8 MB for K and V twice, 512 rows at
+    # most: the three served rows (1, 2 and 4 KB) get 512, a row of 16 KB
+    # 128, and a page that is not a divisor of 128 rows whole pages.
+    for width in (512, 1024, 2048):
+        assert pages_per_block(16, width, jnp.bfloat16) == 32
+    assert pages_per_block(16, 8192, jnp.bfloat16) == 8
+    assert pages_per_block(48, 4096, jnp.bfloat16) == 5
+
+
+def test_paged_decode_attention_per_shard_matches_whole():
+    """Under the engine's mesh the kernel runs on each device's KV heads
+    (the pools' last axis and the queries' heads over "tp"), table, rows
+    and layer whole on every device."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from ray_tpu.ops.paged_attention import paged_decode_attention
+    from ray_tpu.parallel import MeshConfig, build_mesh
+
+    mesh = build_mesh(MeshConfig(tp=2), jax.devices()[:2])
+    q, k_pool, v_pool, _, tables, rows = _paged_case(4, 64, 2)
+    whole = paged_decode_attention(q, k_pool, v_pool, jnp.int32(1), tables,
+                                   rows, 0.125, interpret=True)
+    put = lambda a, *spec: jax.device_put(a, NamedSharding(mesh, P(*spec)))  # noqa: E731
+    sharded = jax.jit(lambda q, k, v, t, r: paged_decode_attention(
+        q, k, v, jnp.int32(1), t, r, 0.125, interpret=True, mesh=mesh))(
+        put(q, None, "tp"), put(k_pool, None, None, None, "tp"),
+        put(v_pool, None, None, None, "tp"), put(tables), put(rows))
+    live = np.asarray(rows) > 0
+    np.testing.assert_allclose(np.asarray(sharded)[live],
+                               np.asarray(whole)[live], rtol=1e-5, atol=1e-5)
+
+
 def test_kernels_per_shard_match_whole():
     """Under a mesh the ops run each kernel on a device's block
     (ops.per_shard). Values and gradients equal the unsharded call,

@@ -214,16 +214,24 @@ def _layerwise(params, cfg, x, k_pool, v_pool, write_kv, positions, valid,
     results differ from a scan's in the last bit (1e-6 on the logits)."""
     import jax
 
+    import jax.numpy as jnp
+
     from ray_tpu.ops import rmsnorm, rope_frequencies
+    from ray_tpu.ops.paged_attention import grouped_attention
     from ray_tpu.serve.paged_kv import _layer_body
 
     cos, sin = rope_frequencies(cfg.head_dim, max_len, cfg.rope_theta)
 
+    def attend(kc, vc, q, k, v):
+        kc, vc, k_att, v_att = write_kv(kc, vc, k, v)
+        return kc, vc, grouped_attention(
+            q, k_att.astype(jnp.float32), v_att.astype(jnp.float32), valid,
+            cfg.attention_scale)
+
     def layer(x, inputs):
         lp, k_layer, v_layer = inputs
         x, k_layer, v_layer, _ = _layer_body(
-            x, lp, k_layer, v_layer, cfg, cos, sin, positions, write_kv,
-            valid)
+            x, lp, k_layer, v_layer, cfg, cos, sin, positions, attend)
         return x, (k_layer, v_layer)
 
     x, (k_pool, v_pool) = jax.lax.scan(
@@ -235,10 +243,12 @@ def _layerwise(params, cfg, x, k_pool, v_pool, write_kv, positions, valid,
 def test_carried_pool_matches_a_layer_by_layer_reference(program):
     """`decode_paged` and `prefill_chunk_paged` carry the whole pool
     through the layer scan and index it by layer; the arithmetic is that
-    of a loop over per-layer pools. Slots 0 and 1 share their first page
-    (a prefix-cache hit), slot 2 is inactive, and the prefill chunk is a
-    final one: 5 real rows and 3 of padding, starting mid-page. Each side
-    is one jitted program, as the engine runs it."""
+    of a loop over per-layer pools `[pages, page_size, kv_heads,
+    head_dim]`, each gathered whole through the table and masked. Slots 0
+    and 1 share their first page (a prefix-cache hit), slot 2 is inactive
+    (it attends to nothing), and the prefill chunk is a final one: 5 real
+    rows and 3 of padding, starting mid-page. Each side is one jitted
+    program, as the engine runs it."""
     import jax
     import jax.numpy as jnp
 
@@ -280,7 +290,8 @@ def test_carried_pool_matches_a_layer_by_layer_reference(program):
             positions = lengths[:, None]
             x, k, v = _layerwise(
                 params, cfg, _embed_tokens(params, tokens[:, None], cfg),
-                k, v, write_kv, positions, k_pos <= positions[:, :, None],
+                k, v, write_kv, positions,
+                (k_pos <= positions[:, :, None]) & active[:, None, None],
                 max_len)
             logits = project_logits(x[:, -1], params, cfg)
             return (jnp.argmax(logits, axis=-1).astype(jnp.int32), k, v,
@@ -315,13 +326,92 @@ def test_carried_pool_matches_a_layer_by_layer_reference(program):
             return (project_logits(x[:, n_valid - 1], params, cfg), k, v,
                     lengths.at[slot].set(offset + n_valid))
 
-    got = jax.jit(step)(k_pool, v_pool)
+    flat = shape[:3] + (kvh * hd,)  # as the programs hold a row
+    got = jax.jit(step)(k_pool.reshape(flat), v_pool.reshape(flat))
     want = jax.jit(reference)(k_pool, v_pool)
     for name, g, w in zip(("out", "k", "v", "lengths"), got, want):
-        np.testing.assert_array_equal(np.asarray(g), np.asarray(w),
-                                      err_msg=name)
+        np.testing.assert_array_equal(
+            np.asarray(g), np.asarray(w).reshape(g.shape), err_msg=name)
     # Both wrote: the comparison above is not of two untouched pools.
-    assert not np.array_equal(np.asarray(got[1]), np.asarray(k_pool))
+    assert not np.array_equal(np.asarray(got[1]),
+                              np.asarray(k_pool).reshape(flat))
+
+
+# Toy models whose page row is whole 128-lane tiles, so that the decode
+# kernel takes their pool: two KV heads of 64 with two queries each (a
+# head is half a tile), four of 32 with one each, and the hybrid's two.
+KERNEL_MODELS = {"dense": ("tiny_qwen", 64), "expert": ("tiny_olmoe", 32),
+                 "hybrid": ("tiny_granite_h", 64)}
+
+
+@pytest.mark.parametrize("sampled", [False, True], ids=["greedy", "sampled"])
+@pytest.mark.parametrize("model", KERNEL_MODELS)
+def test_decode_through_the_kernel_gives_the_plain_forms_tokens(
+        model, sampled, monkeypatch):
+    """`decode_paged` with attention through the Pallas kernel (interpret
+    mode) against the same program through the plain form, which is what
+    every other test here runs: after a prompt's prefill, eight steps of
+    three slots (one inactive, two sharing their first page) give the same
+    tokens, greedy and sampled, and leave the same pages."""
+    import dataclasses
+    import functools
+
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import configs, init_params
+    from ray_tpu.ops import paged_attention
+
+    name, head_dim = KERNEL_MODELS[model]
+    cfg = dataclasses.replace(configs.get_config(name), dtype=jnp.float32,
+                              custom_head_dim=head_dim)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    slots, ps, mp, max_len = 3, 8, 4, 32
+    cache = paged_kv.init_paged_cache(cfg, slots, 1 + slots * mp, ps, mp)
+    assert paged_attention.kernel_takes(cache["k"], cfg.head_dim)
+    tables = jnp.asarray([[1, 2, 3, 4], [1, 5, 6, 7], [0, 0, 0, 0]], jnp.int32)
+    active = jnp.asarray([True, True, False])
+    tail = {}
+    if cfg.num_experts:
+        tail["moe"] = paged_kv.init_routing_counters(cfg)
+    if cfg.layer_pattern:
+        tail["rec"], tail["rec_count"] = (cache["rec"],
+                                          paged_kv.init_ssm_counters())
+    # Slot 0's prompt fills the shared page and two rows of its own.
+    prompt = jnp.asarray([[3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 0, 0]], jnp.int32)
+    _, k, v, lengths, *out = paged_kv.prefill_chunk_paged(
+        params, prompt, jnp.int32(10), jnp.int32(0), jnp.int32(0),
+        cache["k"], cache["v"], cache["lengths"], tables, cfg, max_len,
+        **tail)
+    tail = dict(zip(tail, out))
+    lengths = lengths.at[1].set(ps)  # slot 1 holds the shared page alone
+    sampling = ((jnp.asarray([0.8, 0.0, 0.7]), jnp.asarray([0, 0, 5]),
+                 jnp.asarray([0.9, 1.0, 1.0])) if sampled else (None,) * 3)
+
+    def run(attention):
+        monkeypatch.setattr(paged_kv, "paged_decode_attention", attention)
+        step = jax.jit(lambda t, k, v, ln, key, tail: paged_kv.decode_paged(
+            params, t, k, v, ln, active, tables, *sampling,
+            key if sampled else None, cfg, max_len, **tail))
+        tokens, state = [], (jnp.asarray([7, 11, 0], jnp.int32), k, v, lengths)
+        carried = tail
+        for i in range(8):
+            t, kk, vv, ln, *out = step(*state, jax.random.PRNGKey(i), carried)
+            carried = dict(zip(carried, out))
+            state = (t, kk, vv, ln)
+            tokens.append(np.asarray(t)[:2])
+        return np.stack(tokens), state[1], state[2]
+
+    want, k_want, v_want = run(functools.partial(
+        paged_attention.paged_decode_attention, use_pallas=False))
+    got, k_got, v_got = run(functools.partial(
+        paged_attention.paged_decode_attention, interpret=True))
+    np.testing.assert_array_equal(got, want)
+    # But for the NULL page, where the inactive slot's row is parked: its
+    # attention is over nothing, which the two forms need not agree on.
+    for a, b in ((k_got, k_want), (v_got, v_want)):
+        np.testing.assert_allclose(np.asarray(a)[:, 1:], np.asarray(b)[:, 1:],
+                                   rtol=1e-4, atol=1e-4)
 
 
 # -- engine: page accounting ----------------------------------------------

@@ -421,6 +421,38 @@ def test_continuous_batching_step_timing_breakdown():
         eng.shutdown()
 
 
+def test_attention_counters_follow_the_decoding_slots():
+    """stats()['attention']: rows in the pages decode attention is given
+    to read against rows the pool holds, cumulative over dispatched decode
+    steps, from the host's mirror of each decoding slot's length. Nothing
+    before the first request (warm-up's steps are not counted); a step of
+    one slot at length n reads ceil((n + 1) / page) pages of 4 rows and
+    holds slots x max_len."""
+    from ray_tpu.serve.llm import ContinuousBatchingEngine
+
+    params, cfg = _tiny_model()
+    eng = ContinuousBatchingEngine(params, cfg, num_slots=2, max_len=64,
+                                   page_size=4)
+    try:
+        assert eng.stats()["attention"] == {
+            "decode_rows_read": 0, "decode_rows_held": 0}
+        eng.submit([3, 7, 11], max_new_tokens=6).result(timeout=180)
+        first = eng.stats()["attention"]
+        # Lengths 3..7 at the five steps that gave tokens 2..6, each with
+        # the row it writes: 4, 8, 8, 8, 8 rows in whole pages; the loop
+        # may have dispatched one step more before it saw the last token.
+        steps, extra = divmod(first["decode_rows_held"], 2 * 64)
+        assert extra == 0 and steps in (5, 6)
+        assert first["decode_rows_read"] == 36 + 12 * (steps - 5)
+        eng.submit(list(range(1, 30)), max_new_tokens=4).result(timeout=180)
+        second = eng.stats()["attention"]
+        assert second["decode_rows_read"] > first["decode_rows_read"]
+        assert second["decode_rows_held"] > first["decode_rows_held"]
+        assert second["decode_rows_read"] <= second["decode_rows_held"]
+    finally:
+        eng.shutdown()
+
+
 # -- the loop's phase ledger --------------------------------------------
 
 _LEDGER_CHILDREN = ("admit", "prefill_dispatch", "prefill_first_token_wait",
