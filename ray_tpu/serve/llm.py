@@ -19,10 +19,14 @@ scheduling, built TPU-first:
     prefix without the state at that boundary, and no snapshots are
     kept) and without tensor parallelism (the mixer is not partitioned).
   * Static shapes everywhere: the decode step is jitted ONCE for the
-    slot count and prompts prefill in fixed-size CHUNKS (one chunk
-    between decode steps — chunked prefill: a long prompt never stalls
-    other slots' decoding for more than a chunk), so compilation count
-    is bounded and none happens mid-traffic after warmup.
+    slot count and prompts prefill in fixed-size CHUNKS, one PASS of
+    them between decode steps: a pass is one program over one row of a
+    chunk or over up to `PASS_ROWS` of them (at most `PASS_TOKENS`
+    tokens), of several slots or of one long prompt, so that they share
+    one read of the weights, and a long prompt never stalls other slots'
+    decoding for more than a pass.
+    Compilation count is bounded and none happens mid-traffic after
+    warmup.
   * Per-slot sequence lengths live in device memory; attention masks by
     each slot's own length, so one batched decode serves slots whose
     sequences started at different times.
@@ -72,6 +76,24 @@ from ray_tpu.serve import paged_kv
 from ray_tpu.models.transformer import TransformerConfig
 from ray_tpu.util.compile_cache import compile_events
 from ray_tpu.util.device_peaks import device_report
+
+# The most rows a prefill pass holds. A pass is compiled at two widths,
+# one row and the widest, and takes the smaller that holds the rows it
+# owes, padded with inert rows (`n_valid` 0): a lone short prompt pays for
+# one row, not for the widest pass. No width between them: every program
+# is a second of every start of the engine (read from the compile cache
+# and loaded), and at 64 tokens a row two rows in a four-row pass cost a
+# quarter more than in a pass of their own (20.9 ms against 16.4 on a
+# v5e at Qwen3-4B's widths).
+PASS_ROWS = 4
+# The most tokens a pass may hold, whatever the chunk: a pass stands
+# between two decode steps, and rows share a read of the weights only
+# while the products wait for it. On a v5e a dense model's products catch
+# up with the read at about 250 tokens (four rows of 64 cost 1.46 of one,
+# two of 256 cost 2.04 of one at the hybrid's widths) and a sparse
+# model's later (two rows of 256 cost 1.35 of one at OLMoE's, four 1.99),
+# while an inert row of a pass past that point costs what a real one does.
+PASS_TOKENS = 512
 
 _metrics_lock = threading.Lock()
 _metrics: Optional[Dict] = None
@@ -307,6 +329,7 @@ class _PhaseLedger:
         self.n = dict.fromkeys(keys, 0)
         self.s = dict.fromkeys(keys, 0.0)
         self.prefill_passes = 0  # turns in which _advance_prefills ran
+        self.prefill_rows = 0    # real rows (chunks) its passes dispatched
         self.t = 0.0             # the newest stamp any phase read
 
     def __call__(self, key: str) -> "_Phase":
@@ -314,7 +337,8 @@ class _PhaseLedger:
 
     def snapshot(self) -> Dict:
         return {"n": dict(self.n), "s": dict(self.s),
-                "prefill_passes": self.prefill_passes}
+                "prefill_passes": self.prefill_passes,
+                "prefill_rows": self.prefill_rows}
 
 
 class _Phase:
@@ -356,7 +380,11 @@ def _timing_of(ledger: Dict) -> Dict:
         "turns": n["turn"],
         "turn_ms_total": s["turn"] * 1e3,
         "prefill_passes": ledger["prefill_passes"],
+        # Dispatches of the prefill program, one a pass, and the real
+        # rows (chunks of a prompt) they held: rows over chunks is how
+        # many chunks shared a read of the weights.
         "prefill_chunks": n["prefill_dispatch"],
+        "prefill_rows": ledger["prefill_rows"],
         "work_ms_total": by_class["work"] * 1e3,
         "wait_ms_total": by_class["wait"] * 1e3,
         "other_ms_total": (s["turn"] - by_class["work"]
@@ -364,6 +392,15 @@ def _timing_of(ledger: Dict) -> Dict:
         "phases": {k: {"n": n[k], "ms_total": s[k] * 1e3}
                    for k in (*_TURN_PHASES, "wait_for_work")},
     }
+
+
+def _pick_first_tokens(logits, tokens, slots, temps, top_ks, top_ps, key):
+    """A pass's first tokens, picked over all its rows' logits `[P, vocab]`
+    (`paged_kv._pick_tokens`: greedy where a row's temperature is 0), and
+    the decode loop's token buffer `tokens [slots]` with row `r`'s at
+    `slots[r]`; a row whose slot is past the last puts nothing."""
+    picked = paged_kv._pick_tokens(logits, temps, top_ks, top_ps, key)
+    return picked, tokens.at[slots].set(picked, mode="drop")
 
 
 class ContinuousBatchingEngine:
@@ -387,9 +424,10 @@ class ContinuousBatchingEngine:
         inside the compiled step (GSPMD inserts them).
 
         prefill_chunk: prompts prefill in fixed chunks of this many
-        tokens, ONE chunk between decode steps — a long prompt never
-        stalls other slots' decoding for more than a chunk (chunked
-        prefill), and prefill compiles exactly once.
+        tokens, one pass of up to `PASS_ROWS` chunks and
+        `PASS_TOKENS` tokens between decode steps — a long prompt never
+        stalls other slots' decoding for more than a pass (chunked
+        prefill), and prefill compiles once a row count, at warm-up.
 
         page_size / kv_pages: the KV memory plane (ray_tpu/serve/
         paged_kv): a shared page pool + block tables and a prefix cache
@@ -404,6 +442,9 @@ class ContinuousBatchingEngine:
         self.default_max_new_tokens = default_max_new_tokens
         self.mesh = mesh
         self.prefill_chunk = max(1, min(int(prefill_chunk), max_len))
+        # The widths of a prefill pass, in rows of one chunk each.
+        widest = max(1, min(PASS_ROWS, PASS_TOKENS // self.prefill_chunk))
+        self._pass_rows = (1, widest) if widest > 1 else (1,)
         if mesh is not None:
             if "tp" not in mesh.shape:
                 raise ValueError(
@@ -501,7 +542,7 @@ class ContinuousBatchingEngine:
             donate_argnums=(5, 6), donate_argnames=("rec",),
         )
         self._cow = jax.jit(paged_kv.cow_copy_page, donate_argnums=(0, 1))
-        self._pick = jax.jit(paged_kv._pick_tokens)
+        self._pick_first = jax.jit(_pick_first_tokens)
         self._lock = threading.Lock()
         self._work = threading.Event()
         # BOUNDED admission queue with per-tenant weighted-fair service:
@@ -520,7 +561,8 @@ class ContinuousBatchingEngine:
         self._deadline_expired = 0
         self._slots: Dict[int, GenerationHandle] = {}
         # Mid-prefill requests: slot -> {"h": handle, "offset": rows
-        # already prefilled}. One chunk advances per loop iteration.
+        # already prefilled}, oldest admission first. A loop iteration
+        # advances them by one pass (_advance_prefills).
         self._prefilling: Dict[int, Dict] = {}
         self._free = deque(range(num_slots))
         # Next input token per slot, ON DEVICE: the decode loop feeds
@@ -600,18 +642,17 @@ class ContinuousBatchingEngine:
 
     def _warmup(self):  # rtlint: disable=RT010 — runs before the loop thread starts; Thread.start() is the happens-before
         """Compile every steady-state program up front — BOTH decode
-        variants (greedy and sampled), the prefill chunk, and the
-        first-token path with its small eager programs (key split, pick
-        or argmax, the token buffer's update) — so traffic flipping
-        between greedy and sampled never compiles mid-serving. All
-        warmup calls run with `active` all-False and an all-NULL block
-        table: decode and prefill writes land in the NULL page, which is
-        never read unmasked, so cache contents stay semantically
+        variants (greedy and sampled), the prefill pass at each of its
+        widths, and the first-token path of each (key split, pick into the
+        token buffer) — so traffic flipping between greedy and sampled,
+        or between one prompt and several, never compiles mid-serving.
+        All warmup calls run with `active` all-False and an all-NULL
+        block table: decode and prefill writes land in the NULL page,
+        which is never read unmasked, so cache contents stay semantically
         untouched."""
         # The loop's own two-way split (and the unpacking's unstack).
         self._rng, k1 = jax.random.split(self._rng)
-        pad = np.zeros((1, self.prefill_chunk), dtype=np.int32)
-        one, zero = np.int32(1), np.int32(0)
+        zero = np.int32(0)
         (_, self._k, self._v, self._lengths,
          *tail) = self._decode_greedy(
             self.params, self._tokens_dev, self._k, self._v,
@@ -626,20 +667,23 @@ class ContinuousBatchingEngine:
             **self._tail,
         )
         self._tail = dict(zip(self._tail, tail))
-        (logits, self._k, self._v, self._lengths,
-         *tail) = self._prefill(
-            self.params, pad, one, zero, zero,
-            self._k, self._v, self._lengths, self._bt_dev, **self._tail,
-        )
-        self._tail = dict(zip(self._tail, tail))
+        # Each pass with one real row (slot 0) and the rest inert, then
+        # the first-token pick over its logits (and the key's split), and
+        # the token buffer as it was.
+        tokens = self._tokens_dev
+        for rows in self._pass_rows:
+            row = np.zeros(rows, dtype=np.int32)
+            n_valid = np.arange(rows, dtype=np.int32) == 0
+            logits = self._dispatch_prefill(
+                np.zeros((rows, self.prefill_chunk), dtype=np.int32),
+                n_valid.astype(np.int32), row, row)
+            self._first_tokens(logits, row, np.full(rows, 0.5, np.float32),
+                               np.ones(rows, np.int32),
+                               np.ones(rows, np.float32))
+        self._tokens_dev = tokens
         # Warm the copy-on-write page fork too (NULL page onto itself:
         # contents never observable).
         self._k, self._v = self._cow(self._k, self._v, zero, zero)
-        # Both first-token variants, then the token buffer as it was.
-        tokens = self._tokens_dev
-        self._first_token(logits, 0, 0.5, 1, 1.0)
-        self._first_token(logits, 0, 0.0, 0, 1.0)
-        self._tokens_dev = tokens
         # Undo the warmup prefill's lengths[0] = 1 and what warm-up
         # counted (device-side, keeps the mesh sharding of the arrays).
         # Slot 0's recurrent row stays as warm-up left it: a slot's first
@@ -651,27 +695,38 @@ class ContinuousBatchingEngine:
                                                 self._tail[name])
         jax.block_until_ready(self._lengths)
 
+    # Single-writer: KV cache and the step programs' tail are engine-
+    # thread-owned device state.
+    def _dispatch_prefill(self, tokens, n_valid, slots, offsets):  # rtlint: disable=RT006 — loop-thread-only (and warm-up, before the thread starts)
+        """One prefill pass over `tokens [P, C]`, a row a `(n_valid, slot,
+        offset)`, into the engine's cache. Returns the rows' logits
+        `[P, vocab]`, on the device."""
+        (logits, self._k, self._v, self._lengths, *tail) = self._prefill(
+            self.params, tokens, n_valid, slots, offsets,
+            self._k, self._v, self._lengths, self._bt_dev, **self._tail,
+        )
+        self._tail = dict(zip(self._tail, tail))
+        return logits
+
     # Single-writer: rng and token buffer are engine-thread-owned.
-    def _first_token(self, logits, slot, temperature, top_k, top_p):  # rtlint: disable=RT006 — loop-thread-only (and warm-up, before the thread starts)
-        """A request's first token under its sampling, from its final
-        prefill chunk's logits, ON DEVICE: it feeds the decode loop's
-        token buffer device-to-device and starts the non-blocking copy
-        the handle push drains. Returns the token's device array [1]."""
-        if temperature > 0:
+    def _first_tokens(self, logits, slots, temps, top_ks, top_ps):  # rtlint: disable=RT006 — loop-thread-only (and warm-up, before the thread starts)
+        """The first tokens of the requests a pass finished, each under
+        its own sampling, from the pass's logits `[P, vocab]`, ON DEVICE
+        and in one call: row `r`'s token feeds the decode loop's token
+        buffer at `slots[r]` device-to-device (a row that ends no prompt
+        names `num_slots` and feeds nothing), and the non-blocking copy
+        the handle push drains is started. Returns the tokens' device
+        array [P]."""
+        key = self._rng  # a pass of greedy rows draws nothing from it
+        if (temps > 0).any():
             self._rng, key = jax.random.split(self._rng)
-            tok_dev = self._pick(
-                logits, np.full(1, temperature, np.float32),
-                np.full(1, top_k, np.int32), np.full(1, top_p, np.float32),
-                key,
-            )
-        else:
-            tok_dev = jnp.argmax(logits, -1).astype(jnp.int32)
-        self._tokens_dev = self._tokens_dev.at[slot].set(tok_dev[0])
+        toks_dev, self._tokens_dev = self._pick_first(
+            logits, self._tokens_dev, slots, temps, top_ks, top_ps, key)
         try:
-            tok_dev.copy_to_host_async()
+            toks_dev.copy_to_host_async()
         except Exception:  # rtlint: disable=RT007 — optional prefetch; sharded layouts fetch at the drain
             pass
-        return tok_dev
+        return toks_dev
 
     # Single-writer: every *_dev array is owned by the engine thread
     # (this runs on it); submit() only flips _params_dirty under
@@ -1072,9 +1127,9 @@ class ContinuousBatchingEngine:
 
     def _admit_locked(self):
         """Assign free slots to waiting requests; their prompts then
-        prefill ONE chunk per loop iteration (_advance_prefills), so a
-        long prompt never stalls other slots' decode for more than a
-        chunk. Requests whose deadline expired while queued (or that the
+        prefill one pass of chunks per loop iteration (_advance_prefills),
+        so a long prompt never stalls other slots' decode for more than a
+        pass. Requests whose deadline expired while queued (or that the
         caller cancelled) are dropped here instead of burning a slot."""
         admitted = bool(self._waiting_n and self._free)
         now = time.time()
@@ -1215,15 +1270,24 @@ class ContinuousBatchingEngine:
     # Single-writer: KV cache, rng, and token buffers are engine-thread-
     # owned device state; no other thread touches them after __init__.
     def _advance_prefills(self):  # rtlint: disable=RT006
-        """One prefill chunk for every mid-prefill slot (interleaved
-        between decode dispatches). A request whose final chunk lands
-        emits its first token and joins the decode set.
+        """One prefill PASS (interleaved between decode dispatches): one
+        program over the chunks the mid-prefill slots owe, a row a chunk:
+        one for every slot, oldest admission first, then the rows left to
+        the oldest again. A prompt whose cache is pages alone may so take
+        several rows, consecutive chunks in the rows' order (a later row
+        reads the earlier one's keys from the pages); a model with
+        recurrent layers gets one row a slot, since a chunk starts from
+        the state the chunk before it left. The pass runs the smaller of
+        the engine's two widths (one row, or `PASS_ROWS` within
+        `PASS_TOKENS`) that holds what is owed, the rest inert; what does
+        not fit waits a turn. A request whose final chunk lands emits its
+        first token and joins the decode set.
 
-        First tokens stay ON DEVICE through admission: each finishing
-        slot's pick feeds _tokens_dev device-to-device, and ONE batched
-        fetch (async copy started at dispatch, drained once) delivers
-        all of this round's first tokens to their handles — not one
-        blocking scalar device_get per request."""
+        First tokens stay ON DEVICE through admission: one pick over the
+        pass's logits feeds _tokens_dev device-to-device, and ONE fetch
+        (async copy started at dispatch, drained once) delivers all of
+        this pass's first tokens to their handles — not one blocking
+        scalar device_get per request."""
         c = self.prefill_chunk
         # Chaos hook: a deterministic stretch stands in for a genuinely
         # huge prompt so HOL-attribution tests don't need one. Inside
@@ -1241,10 +1305,11 @@ class ContinuousBatchingEngine:
             }
             for e in self._prefilling.values()
         ]
-        finished = []  # (slot, handle, first-token device array [1])
+        owing = []  # (slot, entry, the offsets of the chunks it owes)
+        per_slot = 1 if self.cfg.layer_pattern else self._pass_rows[-1]
         now_wall = time.time()
         for slot, entry in list(self._prefilling.items()):
-            h, off = entry["h"], entry["offset"]
+            h = entry["h"]
             if h.cancelled or (h.deadline_ts and now_wall > h.deadline_ts):
                 # Abandon the partial prefill: remaining chunks would be
                 # work for a request nobody is waiting on.
@@ -1261,32 +1326,54 @@ class ContinuousBatchingEngine:
                     self._free.append(slot)
                     self._pool.release(entry["pages"])
                 continue
-            with self._phase("prefill_dispatch"):
-                chunk = h.prompt[off:off + c]
-                n = len(chunk)
-                padded = np.zeros((1, c), dtype=np.int32)
-                padded[0, :n] = chunk
-                (logits, self._k, self._v, self._lengths,
-                 *tail) = self._prefill(
-                    self.params, padded,
-                    np.int32(n), np.int32(slot), np.int32(off),
-                    self._k, self._v, self._lengths, self._bt_dev,
-                    **self._tail,
-                )
-                self._tail = dict(zip(self._tail, tail))
-                entry["offset"] = off + n
-                if entry["offset"] < len(h.prompt):
-                    continue
-                # Final chunk: the first token, fed to the decode loop
-                # device-side (no host round trip), its copy started for
-                # the handle push below.
-                tok_dev = self._first_token(
-                    logits, slot, h.temperature, h.top_k, h.top_p)
-                finished.append((slot, h, tok_dev, entry))
-        if not finished:
+            owing.append((slot, entry, range(
+                entry["offset"], len(h.prompt), c)[:per_slot]))
+        # A row for every slot first, so that a short prompt never waits
+        # for a long one's chunks, then the rows left to the oldest.
+        room = self._pass_rows[-1]
+        take = [0] * len(owing)
+        for share in (1, per_slot):
+            for i, (_, _, owed) in enumerate(owing):
+                given = min(min(share, len(owed)) - take[i], room)
+                take[i] += given
+                room -= given
+        rows = [(slot, entry, off)
+                for (slot, entry, owed), n in zip(owing, take)
+                for off in owed[:n]]
+        if not rows:
             return
+        with self._phase("prefill_dispatch"):
+            width = next(p for p in self._pass_rows if p >= len(rows))
+            tokens = np.zeros((width, c), dtype=np.int32)
+            n_valid, slots, offsets = np.zeros((3, width), dtype=np.int32)
+            # Rows that end a prompt: where their token goes, and how it
+            # is picked (a row that ends none: nowhere, greedily).
+            ends = np.full(width, self.num_slots, dtype=np.int32)
+            temps = np.zeros(width, dtype=np.float32)
+            top_ks = np.zeros(width, dtype=np.int32)
+            top_ps = np.ones(width, dtype=np.float32)
+            finished = []  # (row, slot, handle, entry)
+            for r, (slot, entry, off) in enumerate(rows):
+                h = entry["h"]
+                chunk = h.prompt[off:off + c]
+                tokens[r, :len(chunk)] = chunk
+                n_valid[r], slots[r], offsets[r] = len(chunk), slot, off
+                entry["offset"] = off + len(chunk)
+                if entry["offset"] == len(h.prompt):
+                    ends[r] = slot
+                    temps[r], top_ks[r], top_ps[r] = (
+                        h.temperature, h.top_k, h.top_p)
+                    finished.append((r, slot, h, entry))
+            logits = self._dispatch_prefill(tokens, n_valid, slots, offsets)
+            self._phase.prefill_rows += len(rows)
+            if not finished:
+                return
+            # Final chunks: their first tokens, fed to the decode loop
+            # device-side (no host round trip), the copy started for the
+            # handle push below.
+            toks_dev = self._first_tokens(logits, ends, temps, top_ks, top_ps)
         with self._phase("prefill_first_token_wait"):
-            toks_np = jax.device_get([t for _, _, t, _ in finished])
+            toks_np = jax.device_get(toks_dev)
         with self._phase("prefill_publish"):
             self._publish_first_tokens(finished, toks_np)
 
@@ -1294,8 +1381,8 @@ class ContinuousBatchingEngine:
         """Push this pass's first tokens to their handles and move each
         request from the prefilling set to the decode set (or free its
         slot if that token finished it)."""
-        for (slot, h, _, entry), tok_arr in zip(finished, toks_np):
-            tok = int(tok_arr[0])
+        for row, slot, h, entry in finished:
+            tok = int(toks_np[row])
             h.produced = 1
             # admitted_at_step must be visible before the push wakes a
             # consumer (a request finishing on its prefill token would
@@ -1333,8 +1420,8 @@ class ContinuousBatchingEngine:
     def _note_hol(self, prefill_s: float, n_active: int):
         """Attribute a slow prefill pass to the decode slots it stalled.
 
-        Chunked prefill bounds the stall at one chunk per pass, but a
-        pass can still cross the threshold (huge chunk, slow host, chaos
+        Chunked prefill bounds the stall at one pass of a few chunks, but
+        a pass can still cross the threshold (huge chunk, slow host, chaos
         injection). Cost: one get_config() + comparison per PREFILL
         pass; the steady-state decode loop never reaches here."""
         if n_active <= 0 or prefill_s < get_config().serve_hol_threshold_s:
@@ -1364,7 +1451,7 @@ class ContinuousBatchingEngine:
     # the ledger; shared host state is touched under self._lock.
     def _turn(self):  # rtlint: disable=RT006,RT010
         """One loop iteration with work, inside the ledger's `turn` span:
-        admit, advance prefills by a chunk, dispatch decode step k+1,
+        admit, advance prefills by a pass, dispatch decode step k+1,
         drain and distribute step k. Returns, for a turn that dispatched
         a decode step, its (dispatch, fetch) seconds."""
         phase = self._phase

@@ -716,52 +716,70 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
                         v_pages, lengths, block_tables,
                         cfg: TransformerConfig, max_len: int, mesh=None,
                         moe=None, rec=None, rec_count=None):
-    """CHUNKED prefill: one fixed-size chunk of a prompt into slot
-    `slot` at row `offset`, so that a long prompt's prefill interleaves
-    with other slots' decode steps instead of stalling them.
+    """CHUNKED prefill, one pass of it: `P` rows of one fixed-size chunk
+    each, row `r` being `n_valid[r]` tokens of a prompt into slot
+    `slot[r]` at row `offset[r]`. The rows share one read of the weights,
+    and a long prompt's prefill interleaves with other slots' decode
+    steps instead of stalling them.
 
-    tokens [1, C] int32 (first n_valid real). Chunk rows scatter into
-    the pages the slot's block-table row names (padding rows and
-    anything past `max_len` drop into the NULL page); queries attend
-    causally against the slot's gathered page run, earlier chunks
-    included. Sets lengths[slot] = offset + n_valid and returns the
-    logits of the chunk's last REAL position [1, vocab] (meaningful on
-    the final chunk), the pool and the lengths (and the advanced `moe`).
+    tokens [P, C] int32 (a row's first n_valid real); n_valid, slot and
+    offset [P] int32, or scalars for a pass of one row. Every row's keys
+    and values scatter into the pages its slot's block-table row names
+    (padding and anything past `max_len` drop into the NULL page) before
+    any row reads; then each row's queries attend causally, by their own
+    positions, against their own slot's gathered page run, earlier chunks
+    included. So two consecutive chunks of ONE prompt may be two rows of a
+    pass (the earlier chunk the earlier row): the later one finds the
+    earlier one's keys in the pages. Sets lengths[slot] = offset + n_valid
+    for every row, a later row over an earlier one, and returns the
+    logits of each row's last REAL position [P, vocab] (meaningful on a
+    prompt's final chunk), the pool and the lengths (and the advanced
+    `moe`).
+
+    A row with `n_valid` 0 is INERT: it writes no page, no length and no
+    recurrent state, whatever its slot and offset; a pass that owes three
+    rows runs the four-row program with one such row.
 
     Prefix-cache resumption needs nothing special here: the engine
     starts `offset` at the shared-prefix boundary and the gathered
     pages already hold the donor's K/V rows below it.
 
-    With `rec`, a hybrid's recurrent pool (donate it): the chunk starts
-    from the slot's row of every Mamba layer, or from zeros where `offset`
+    With `rec`, a hybrid's recurrent pool (donate it): a row starts from
+    its slot's row of every Mamba layer, or from zeros where its `offset`
     is 0 (a slot's first chunk, whatever the last tenant left), and
     leaves there the state and the convolution inputs after its last REAL
-    token; padding rows advance nothing. A prompt cannot resume below a
-    shared prefix without the state at that boundary, which no one keeps.
-    The pool and the advanced `rec_count` come back last."""
-    _, c = tokens.shape
+    token; padding advances nothing. A chunk starts from the state the
+    chunk before it left, so the real rows of a pass are of different
+    slots. A prompt cannot resume below a shared prefix without the state
+    at that boundary, which no one keeps. The pool and the advanced
+    `rec_count` come back last."""
+    p_, c = tokens.shape
+    n_valid, slot, offset = (
+        jnp.reshape(a, (-1,)).astype(jnp.int32)
+        for a in (n_valid, slot, offset))
     ps = k_pages.shape[2]
     mp = block_tables.shape[1]
     width = mp * ps
     kvh, hd = cfg.n_kv_heads, cfg.head_dim
     x = _embed_tokens(params, tokens, cfg)
     cos, sin = _rope_tables(cfg, max_len)
-    positions = offset + jnp.arange(c, dtype=jnp.int32)[None, :]
-    q_pos = positions[:, :, None]                               # [1, C, 1]
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (1, c, width), 2)
-    valid = (k_pos <= q_pos) & (k_pos < offset + n_valid)
-    bt_row = jax.lax.dynamic_slice_in_dim(block_tables, slot, 1, axis=0)[0]
-    pos = offset + jnp.arange(c, dtype=jnp.int32)
-    in_range = (pos < offset + n_valid) & (pos < max_len)
-    page_of = jnp.minimum(pos // ps, mp - 1)
-    pages_w = jnp.where(in_range, bt_row[page_of], NULL_PAGE)
-    rows_w = pos % ps
+    positions = offset[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
+    end = (offset + n_valid)[:, None]                           # [P, 1]
+    k_pos = jax.lax.broadcasted_iota(jnp.int32, (p_, c, width), 2)
+    valid = (k_pos <= positions[:, :, None]) & (k_pos < end[:, :, None])
+    bt_rows = block_tables[slot]                                # [P, mp]
+    in_range = (positions < end) & (positions < max_len)
+    page_of = jnp.minimum(positions // ps, mp - 1)
+    pages_w = jnp.where(in_range, jnp.take_along_axis(bt_rows, page_of, 1),
+                        NULL_PAGE).reshape(-1)
+    rows_w = (positions % ps).reshape(-1)
+    real = n_valid > 0
 
     def attend(i, kc, vc, q, k, v):
-        kc = kc.at[i, pages_w, rows_w].set(_rows(k[0], kc))
-        vc = vc.at[i, pages_w, rows_w].set(_rows(v[0], vc))
-        k_att = kc[i, bt_row].reshape(1, width, kvh, hd)
-        v_att = vc[i, bt_row].reshape(1, width, kvh, hd)
+        kc = kc.at[i, pages_w, rows_w].set(_rows(k.reshape(p_ * c, kvh, hd), kc))
+        vc = vc.at[i, pages_w, rows_w].set(_rows(v.reshape(p_ * c, kvh, hd), vc))
+        k_att = kc[i, bt_rows].reshape(p_, width, kvh, hd)
+        v_att = vc[i, bt_rows].reshape(p_, width, kvh, hd)
         return kc, vc, grouped_attention(
             q, k_att.astype(jnp.float32), v_att.astype(jnp.float32), valid,
             cfg.attention_scale)
@@ -774,25 +792,39 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     else:
         carried = offset > 0
 
+        # A row's slot at a time: a slice of the pool at `[j, slot]` read,
+        # and written in place where it lies, as a pass of one row does.
         def read_rec(rec, j):
-            return (jnp.where(carried, rec["state"][j, slot], 0.0)[None],
-                    jnp.where(carried, rec["conv"][j, slot], 0)[None])
+            state = jnp.stack([rec["state"][j, slot[r]] for r in range(p_)])
+            conv = jnp.stack([rec["conv"][j, slot[r]] for r in range(p_)])
+            return (jnp.where(carried[:, None, None, None], state, 0.0),
+                    jnp.where(carried[:, None, None], conv, 0))
 
         def write_rec(rec, j, state, conv):
-            return {"state": rec["state"].at[j, slot].set(state[0]),
-                    "conv": rec["conv"].at[j, slot].set(conv[0])}
+            out = dict(rec)
+            for name, new in (("state", state), ("conv", conv)):
+                for r in range(p_):  # an inert row puts back what it found
+                    row = jnp.where(real[r], new[r], out[name][j, slot[r]])
+                    out[name] = out[name].at[j, slot[r]].set(row)
+            return out
 
         x, k_new, v_new, rec = _walk_hybrid(
             params, x, k_pages, v_pages, rec, attend, (read_rec, write_rec),
-            jnp.reshape(n_valid, (1,)), cfg, cos, sin, positions, mesh)
+            n_valid, cfg, cos, sin, positions, mesh)
         counts = None
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
-    last = jax.lax.dynamic_slice(x, (0, n_valid - 1, 0), (1, 1, x.shape[-1]))
+    last = jnp.take_along_axis(
+        x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)
     logits = project_logits(last[:, 0], params, cfg)
-    new_lengths = lengths.at[slot].set(offset + n_valid)
+    new_lengths = lengths
+    for r in range(p_):  # in the rows' order: a prompt's later chunk last
+        new_lengths = jnp.where(
+            real[r], new_lengths.at[slot[r]].set(offset[r] + n_valid[r]),
+            new_lengths)
     out = _count_routing((logits, k_new, v_new, new_lengths), moe, counts)
-    return _with_recurrent(out, rec, rec_count, prefill_tokens_valid=n_valid,
-                           prefill_tokens_computed=c)
+    return _with_recurrent(out, rec, rec_count,
+                           prefill_tokens_valid=n_valid.sum(),
+                           prefill_tokens_computed=p_ * c)
 
 
 def cow_copy_page(k_pages, v_pages, src, dst):

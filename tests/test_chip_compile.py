@@ -22,6 +22,7 @@ from jax.sharding import NamedSharding, PartitionSpec as P, SingleDeviceSharding
 from ray_tpu.models import configs
 from ray_tpu.ops.flash_attention import flash_attention
 from ray_tpu.ops.rmsnorm import rmsnorm
+from ray_tpu.serve import llm
 
 QWEN = configs.get_config("qwen3-4b")
 # Every width a production-scale configuration trains at.
@@ -169,12 +170,33 @@ def test_kernels_compile_under_a_sharded_jit(v5e):
 # is cut to 4: what is asserted does not depend on it.
 SLOTS, MAX_LEN, PAGE, CHUNK, DEPTH = 24, 1024, 16, 64, 4
 ENGINE_PROGRAMS = ["decode_paged", "prefill_chunk_paged"]
+# A step program and, for a prefill pass, its rows: one, and every count
+# up to the engine's widest (it compiles one row and four at a chunk of
+# 64, one and two at a chunk of 256).
+ENGINE_STEPS = [("decode_paged", None), *(
+    ("prefill_chunk_paged", rows) for rows in (1, 2, llm.PASS_ROWS))]
+STEP_IDS = [f"{name}-{rows}" if rows else name for name, rows in ENGINE_STEPS]
+USABLE_BYTES = 16.9e9  # of a v5e's memory, PERF.md section 3
 
 
-def _engine_program(name, cfg, one, slots=SLOTS):
+def _whole_model_fits(compiled, args, cfg, depth):
+    """The program's arguments at all of `cfg`'s layers (it was compiled
+    at `depth` of them: a leaf with the layers in front grows by their
+    ratio, and the temporaries of a scan over layers do not) and its
+    temporaries lie inside the chip's memory."""
+    grown = sum(
+        a.size * a.dtype.itemsize * (
+            cfg.n_layers / depth if a.ndim > 1 and a.shape[0] == depth else 1)
+        for a in jax.tree.leaves(args))
+    temporaries = compiled.memory_analysis().temp_size_in_bytes
+    return grown + temporaries < USABLE_BYTES
+
+
+def _engine_program(name, cfg, one, slots=SLOTS, rows=1, chunk=CHUNK):
     """(function, donated arguments, argument shapes, the cache's shape)
     of one of the engine's step programs, as `ContinuousBatchingEngine`
-    jits it on one chip; decode is the sampled variant."""
+    jits it on one chip; decode is the sampled variant, a prefill pass is
+    `rows` rows of `chunk` tokens."""
     from ray_tpu.models.transformer import init_params
     from ray_tpu.serve import paged_kv
 
@@ -198,16 +220,17 @@ def _engine_program(name, cfg, one, slots=SLOTS):
         fn = lambda p, t, k, v, ln, a, bt, *s: paged_kv.decode_paged(  # noqa: E731
             p, t, k, v, ln, a, bt, *s, cfg, MAX_LEN)
         return fn, (2, 3), args, shape
-    scalar = struct(())
-    args = (params, struct((1, CHUNK)), scalar, scalar, scalar, cache, cache,
+    row = struct((rows,))
+    args = (params, struct((rows, chunk)), row, row, row, cache, cache,
             lengths, table)
     fn = lambda p, t, n, s, o, k, v, ln, bt: paged_kv.prefill_chunk_paged(  # noqa: E731
         p, t, n, s, o, k, v, ln, bt, cfg, MAX_LEN)
     return fn, (5, 6), args, shape
 
 
-@pytest.mark.parametrize("program", ENGINE_PROGRAMS)
-def test_engine_step_updates_its_cache_in_place(v5e, program, monkeypatch):
+@pytest.mark.parametrize("program,rows", ENGINE_STEPS, ids=STEP_IDS)
+def test_engine_step_updates_its_cache_in_place(v5e, program, rows,
+                                                monkeypatch):
     """The KV cache rides in the layer scan's carry, so a step with its
     caches donated scatters into the caller's buffers: no second cache
     among the temporaries (scanned over and stacked back a cache is two
@@ -217,11 +240,12 @@ def test_engine_step_updates_its_cache_in_place(v5e, program, monkeypatch):
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = dataclasses.replace(QWEN, n_layers=DEPTH)
     fn, donated, args, shape = _engine_program(
-        program, cfg, SingleDeviceSharding(v5e[0]))
+        program, cfg, SingleDeviceSharding(v5e[0]), rows=rows)
     compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
     one_cache = 2 * DEPTH * SLOTS * MAX_LEN * cfg.n_kv_heads * cfg.head_dim
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < one_cache // 2
+    assert _whole_model_fits(compiled, args, QWEN, DEPTH)
     # Both caches come back in the buffers they came in.
     assert memory.alias_size_in_bytes >= 2 * one_cache
     dims = ",".join(map(str, shape))
@@ -310,9 +334,9 @@ def test_sampled_decode_reads_its_live_pages_in_place(v5e, model, slots, depth,
     assert compiled.memory_analysis().temp_size_in_bytes < one_gather
 
 
-@pytest.mark.parametrize("program", ["decode_paged", "prefill_chunk_paged"])
+@pytest.mark.parametrize("program,rows", ENGINE_STEPS, ids=STEP_IDS)
 def test_expert_model_step_reads_its_expert_stacks_in_place(
-        v5e, program, monkeypatch):
+        v5e, program, rows, monkeypatch):
     """OLMoE's step programs at its published widths (depth cut to 2): the
     three grouped products of a layer are the Mosaic kernel, and no layer's
     experts are copied out of the stack for it. A kernel takes its operands
@@ -323,12 +347,17 @@ def test_expert_model_step_reads_its_expert_stacks_in_place(
     # The program asks the backend which branch to take; the chip's is
     # the kernel outside Pallas's interpreter.
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = dataclasses.replace(configs.get_config("olmoe-1b-7b"), n_layers=2)
-    fn, donated, args, _ = _engine_program(
-        program, cfg, SingleDeviceSharding(v5e[0]))
+    olmoe = configs.get_config("olmoe-1b-7b")
+    cfg = dataclasses.replace(olmoe, n_layers=2)
+    # The serving cell's geometry: 16 slots x 1024, chunks of 256.
+    fn, donated, args, shape = _engine_program(
+        program, cfg, SingleDeviceSharding(v5e[0]), 16, rows, 256)
     compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
     text = compiled.as_text()
     assert len(re.findall(r"%gmm[.\d]* = f32\[", text)) == 3
+    assert _whole_model_fits(compiled, args, olmoe, 2)
+    dims = ",".join(map(str, shape))
+    assert not re.findall(rf"= bf16\[{dims}\]\S* copy\(", text)
     # Sliced out for a kernel, a layer's matrix is the result of a fusion
     # (`dynamic-slice_bitcast_fusion`) or a copy, `bf16[E, d, ff]`.
     for inner in (f"{cfg.d_model},{cfg.d_ff}", f"{cfg.d_ff},{cfg.d_model}"):
@@ -341,10 +370,11 @@ def test_expert_model_step_reads_its_expert_stacks_in_place(
     assert compiled.memory_analysis().temp_size_in_bytes < one_matrix
 
 
-def _hybrid_program(program, one):
+def _hybrid_program(program, one, rows=1):
     """`_engine_program` for granite-4.0-h-micro at its published sizes,
-    all 40 layers, at the serving cell's 48 slots x 1024 (chunks of 256):
-    (function, donated arguments, argument shapes, the cache's shapes)."""
+    all 40 layers, at the serving cell's 48 slots x 1024 (`rows` chunks of
+    256 a prefill pass): (function, donated arguments, argument shapes,
+    the cache's shapes)."""
     from ray_tpu.models.transformer import init_params
     from ray_tpu.serve import paged_kv
 
@@ -377,14 +407,15 @@ def _hybrid_program(program, one):
     fn = lambda p, t, n, s, o, k, v, ln, bt, rec, c: (  # noqa: E731
         paged_kv.prefill_chunk_paged(p, t, n, s, o, k, v, ln, bt, cfg,
                                      MAX_LEN, None, None, rec, c))
-    scalar = struct(())
-    args = (params, struct((1, chunk)), scalar, scalar, scalar, *pool,
+    row = struct((rows,))
+    args = (params, struct((rows, chunk)), row, row, row, *pool,
             cache["block_tables"], cache["rec"], count)
     return fn, (5, 6, 9), args, cache
 
 
-@pytest.mark.parametrize("program", ENGINE_PROGRAMS)
-def test_hybrid_step_updates_both_pools_in_place(v5e, program, monkeypatch):
+@pytest.mark.parametrize("program,rows", ENGINE_STEPS, ids=STEP_IDS)
+def test_hybrid_step_updates_both_pools_in_place(v5e, program, rows,
+                                                 monkeypatch):
     """granite-4.0-h-micro's step programs at its published sizes, all 40
     layers, at the serving cell's 48 slots x 1024 (chunks of 256): the
     pages and the recurrent pool ride in the layer walk's carry and come
@@ -397,7 +428,7 @@ def test_hybrid_step_updates_both_pools_in_place(v5e, program, monkeypatch):
     between a layer's scatter and its gather."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     fn, donated, args, cache = _hybrid_program(
-        program, SingleDeviceSharding(v5e[0]))
+        program, SingleDeviceSharding(v5e[0]), rows)
     params = args[0]
     assert cache["k"].shape == (4, 48 * (MAX_LEN // PAGE) + 1, PAGE, 8 * 64)
     assert cache["rec"]["state"].shape == (36, 48, 64, 64, 128)
@@ -407,9 +438,11 @@ def test_hybrid_step_updates_both_pools_in_place(v5e, program, monkeypatch):
     pools = sum(a.size * a.dtype.itemsize for a in (
         cache["k"], cache["v"], *cache["rec"].values()))
     assert memory.alias_size_in_bytes >= pools
-    # 63 MB in a chunk; 1.7 MB in the decode program, 71 MB while it
-    # gathered an attention layer's pages and cast them.
-    assert memory.temp_size_in_bytes < 128 * 2**20
+    # 63 MB a row of a prefill pass; 1.7 MB in the decode program, 71 MB
+    # while it gathered an attention layer's pages and cast them.
+    assert memory.temp_size_in_bytes < 128 * 2**20 * (rows or 1)
+    assert (memory.argument_size_in_bytes + memory.temp_size_in_bytes
+            < USABLE_BYTES)
     text = compiled.as_text()
     # (The convolution's saved inputs are 45 MB of the 3.7 GB pool, and a
     # chunk does turn those over: the decode program wants the 3 inputs of

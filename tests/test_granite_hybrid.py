@@ -360,6 +360,53 @@ def test_decode_leaves_idle_and_prefilling_slots_as_they_were(params,
     assert rel_rms(np.asarray(logits[0]), ref[-1]) < TOLERANCE
 
 
+@pytest.mark.parametrize("width", [2, 4])
+def test_a_pass_advances_each_rows_slot_from_its_own_state(params, programs,
+                                                           width):
+    """Two slots' chunks as rows of one pass, slot 2's first chunk (from
+    zeros, whatever its row held) and slot 0's second (from what its first
+    left), the rest of the pass inert rows that name slot 1: each slot's
+    state, convolution inputs and logits are what a call a chunk leaves,
+    slot 1's poisoned row is not touched, and the counters sum over rows."""
+    prefill, _ = programs
+    long_prompt, short = prompt_of(CHUNK + 7, 4), prompt_of(9, 4)
+    _, start, _ = prefill_prompt(prefill, params, fresh_cache(poison=3.0), 0,
+                                 long_prompt[:CHUNK])
+    # One call a chunk.
+    want_short, cache, _ = prefill_prompt(prefill, params, start, 2, short)
+    k, v, lengths, rec = (cache[n] for n in ("k", "v", "lengths", "rec"))
+    padded = np.zeros((1, CHUNK), np.int32)
+    padded[0, :7] = long_prompt[CHUNK:]
+    want_long, k, v, lengths, rec, _ = prefill(
+        params, padded, np.int32(7), np.int32(0), np.int32(CHUNK), k, v,
+        lengths, cache["block_tables"], rec, paged_kv.init_ssm_counters())
+    want = dict(cache, k=k, v=v, lengths=lengths, rec=rec)
+    # The same two chunks as rows of one pass.
+    tokens = np.zeros((width, CHUNK), np.int32)
+    tokens[0, :9], tokens[1, :7] = short, long_prompt[CHUNK:]
+    n_valid, slot, offset = np.zeros((3, width), np.int32)
+    n_valid[:2], slot[:2], offset[:2] = (9, 7), (2, 0), (0, CHUNK)
+    slot[2:], offset[2:] = 1, 5
+    logits, k, v, lengths, rec, count = prefill(
+        params, tokens, n_valid, slot, offset, start["k"], start["v"],
+        start["lengths"], start["block_tables"], start["rec"],
+        paged_kv.init_ssm_counters())
+    assert logits.shape == (width, CFG.vocab_size)
+    assert rel_rms(np.asarray(logits[0]), np.asarray(want_short[0])) < TOLERANCE
+    assert rel_rms(np.asarray(logits[1]), np.asarray(want_long[0])) < TOLERANCE
+    assert list(np.asarray(lengths)) == list(np.asarray(want["lengths"]))
+    for name in ("state", "conv"):
+        for s in (0, 2):
+            got, ref = rows({"rec": rec}, name, s), rows(want, name, s)
+            assert float(jnp.abs(got - ref).max()) < TOLERANCE * float(
+                jnp.abs(ref).max()), (name, s)
+        assert (np.asarray(rows({"rec": rec}, name, 1)) == 3.0).all(), name
+    counted = jax.device_get(count)
+    assert counted["calls"] == 1
+    assert counted["prefill_tokens_valid"] == 9 + 7
+    assert counted["prefill_tokens_computed"] == width * CHUNK
+
+
 # -- the engine --------------------------------------------------------------
 
 @pytest.fixture(scope="module")

@@ -202,6 +202,45 @@ def test_engine_prefill_then_decode_through_pages_against_the_reference():
         assert margin < TOLERANCE, (i, token, int(row.argmax()), margin)
 
 
+@pytest.mark.parametrize("rows", [2, 4])
+def test_a_prompt_prefilled_as_rows_of_one_pass_against_the_reference(rows):
+    """The prompt's three chunks of 8 as rows of ONE call of the prefill
+    program (`rows` 4: all three and an inert row; `rows` 2: two, then the
+    third beside an inert row): the last real row's logits against the
+    reference's forward pass over the whole prompt, every expert product
+    over the rows' tokens at once, and the accumulator counting programs
+    and every row they routed."""
+    from ray_tpu.serve import paged_kv
+
+    params = seeded_params()
+    chunk, page, pages, max_len = 8, 8, 8, 64
+    cache = paged_kv.init_paged_cache(CFG, 2, 2 * pages + 1, page, pages)
+    table = jnp.asarray(1 + np.arange(2 * pages, dtype=np.int32).reshape(2, -1))
+    k, v, lengths = cache["k"], cache["v"], cache["lengths"]
+    moe = paged_kv.init_routing_counters(CFG)
+    offsets = list(range(0, len(PROMPT), chunk))
+    calls = 0
+    while offsets:
+        now, offsets = offsets[:rows], offsets[rows:]
+        tokens = np.zeros((rows, chunk), np.int32)
+        n_valid, slot, offset = np.zeros((3, rows), np.int32)
+        for r, off in enumerate(now):
+            piece = PROMPT[off:off + chunk]
+            tokens[r, :len(piece)] = piece
+            n_valid[r], slot[r], offset[r] = len(piece), 1, off
+        logits, k, v, lengths, moe = paged_kv.prefill_chunk_paged(
+            params, jnp.asarray(tokens), n_valid, slot, offset, k, v, lengths,
+            table, CFG, max_len, None, moe)
+        calls += 1
+        last = len(now) - 1
+    ref = reference_logits(params, PROMPT)
+    assert rel_rms(np.asarray(logits[last]), ref[-1]) < TOLERANCE
+    assert np.asarray(lengths).tolist() == [0, len(PROMPT)]
+    assert int(moe["calls"]) == calls == -(-3 // rows)
+    assert int(moe["assignments"].sum()) == (
+        calls * rows * chunk * CFG.experts_per_token * CFG.n_layers)
+
+
 @pytest.mark.parametrize("mistake", ["QK-norm per head",
                                      "top-k weights renormalised"])
 def test_the_reference_comparison_catches_a_wrong_layer(mistake):
@@ -299,14 +338,18 @@ def test_engine_counts_every_assignment_and_fetches_them_in_stats_only():
     finally:
         eng.shutdown()
     moe, phases = stats["moe"], stats["timing"]["phases"]
-    chunks, steps = stats["timing"]["prefill_chunks"], \
+    passes, steps = stats["timing"]["prefill_chunks"], \
         phases["decode_dispatch"]["n"]
-    assert chunks == 3 + 1 and steps >= 5 + 2
-    # Every row a step program computed: a chunk's 8 (padding too), a
-    # decode step's 2 slots (idle ones too), k experts each, in every layer.
-    rows = chunks * 8 + steps * 2
+    # The first prompt's three chunks are one pass of four rows (one
+    # inert), the second prompt's one chunk a pass of one.
+    assert stats["timing"]["prefill_rows"] == 3 + 1
+    assert passes == 1 + 1 and steps >= 5 + 2
+    # Every row a step program computed: a chunk's 8 in each row of a pass
+    # (padding and an inert row too), a decode step's 2 slots (idle ones
+    # too), k experts each, in every layer.
+    rows = (4 + 1) * 8 + steps * 2
     k, layers = CFG.experts_per_token, CFG.n_layers
-    assert moe["calls"] == chunks + steps
+    assert moe["calls"] == passes + steps
     assert moe["assignments"] == rows * k * layers
     assert sum(moe["per_expert"]) == moe["assignments"]
     assert len(moe["per_expert"]) == CFG.num_experts

@@ -414,6 +414,204 @@ def test_decode_through_the_kernel_gives_the_plain_forms_tokens(
                                    rtol=1e-4, atol=1e-4)
 
 
+# -- a prefill pass is one program over rows ------------------------------
+PASS_MODELS = {"dense": "tiny_qwen", "expert": "tiny_olmoe",
+               "hybrid": "tiny_granite_h"}
+PASS_SLOTS, PASS_PAGE, PASS_PAGES, PASS_LEN, PASS_CHUNK = 3, 4, 8, 32, 8
+
+
+class _PassBench:
+    """A model of one kind, a cache of three slots with a table of their
+    own pages, and `paged_kv.prefill_chunk_paged` jitted as the engine
+    jits it; `run(cache, rows)` dispatches `rows`, each a `(slot, offset,
+    tokens)`, as ONE pass (padded to `width` rows with inert ones), and
+    `one_at_a_time` as a call a row with scalars."""
+
+    def __init__(self, model):
+        import jax
+        import jax.numpy as jnp
+
+        from ray_tpu.models import configs, init_params
+
+        self.cfg = cfg = replace(configs.get_config(PASS_MODELS[model]),
+                                 dtype=jnp.float32)
+        self.params = init_params(jax.random.PRNGKey(0), cfg)
+        self.program = jax.jit(
+            lambda t, n, s, o, k, v, ln, bt, **tail:
+            paged_kv.prefill_chunk_paged(
+                self.params, t, n, s, o, k, v, ln, bt, cfg, PASS_LEN,
+                **tail))
+
+    def cache(self, poison=0.0):
+        import jax
+        import jax.numpy as jnp
+
+        cache = paged_kv.init_paged_cache(
+            self.cfg, PASS_SLOTS, PASS_SLOTS * PASS_PAGES + 1, PASS_PAGE,
+            PASS_PAGES)
+        cache["block_tables"] = jnp.asarray(
+            1 + np.arange(PASS_SLOTS * PASS_PAGES, dtype=np.int32).reshape(
+                PASS_SLOTS, PASS_PAGES))
+        if self.cfg.num_experts:
+            cache["moe"] = paged_kv.init_routing_counters(self.cfg)
+        if self.cfg.layer_pattern:
+            cache["rec"] = jax.tree.map(
+                lambda a: a + jnp.asarray(poison, a.dtype), cache["rec"])
+            cache["rec_count"] = paged_kv.init_ssm_counters()
+        return cache
+
+    def _call(self, cache, tokens, n_valid, slot, offset):
+        tail = {n: cache[n] for n in ("moe", "rec", "rec_count") if n in cache}
+        logits, k, v, lengths, *out = self.program(
+            tokens, n_valid, slot, offset, cache["k"], cache["v"],
+            cache["lengths"], cache["block_tables"], **tail)
+        return logits, dict(cache, k=k, v=v, lengths=lengths,
+                            **dict(zip(tail, out)))
+
+    def run(self, cache, rows, width):
+        tokens = np.zeros((width, PASS_CHUNK), np.int32)
+        n_valid, slots, offsets = np.zeros((3, width), np.int32)
+        slots[len(rows):] = 1      # an inert row may name any slot
+        offsets[len(rows):] = 5
+        for r, (slot, offset, chunk) in enumerate(rows):
+            tokens[r, :len(chunk)] = chunk
+            n_valid[r], slots[r], offsets[r] = len(chunk), slot, offset
+        logits, cache = self._call(cache, tokens, n_valid, slots, offsets)
+        return np.asarray(logits)[:len(rows)], cache
+
+    def one_at_a_time(self, cache, rows):
+        logits = []
+        for slot, offset, chunk in rows:
+            tokens = np.zeros((1, PASS_CHUNK), np.int32)
+            tokens[0, :len(chunk)] = chunk
+            out, cache = self._call(cache, tokens, np.int32(len(chunk)),
+                                    np.int32(slot), np.int32(offset))
+            assert out.shape == (1, self.cfg.vocab_size)
+            logits.append(np.asarray(out)[0])
+        return np.stack(logits), cache
+
+
+@pytest.fixture(scope="module")
+def pass_benches():
+    return {}
+
+
+@pytest.fixture(params=list(PASS_MODELS))
+def pass_bench(request, pass_benches):
+    if request.param not in pass_benches:
+        pass_benches[request.param] = _PassBench(request.param)
+    return pass_benches[request.param]
+
+
+# The models whose cache is pages of keys and values alone.
+paged_alone = pytest.mark.parametrize("pass_bench", ["dense", "expert"],
+                                      indirect=True)
+
+
+def _prompt(n, seed):
+    return ((np.arange(n) * (7 + 2 * seed) + seed) % 250 + 1).tolist()
+
+
+def _same_cache(got, want, exact=False):
+    """The pool but for the NULL page (padding and inert rows park their
+    writes there), the lengths and, for a hybrid, the recurrent pool."""
+    close = (np.testing.assert_array_equal if exact else
+             lambda a, b, err_msg: np.testing.assert_allclose(
+                 a, b, rtol=2e-4, atol=2e-5, err_msg=err_msg))
+    for name in ("k", "v"):
+        close(np.asarray(got[name])[:, 1:], np.asarray(want[name])[:, 1:],
+              err_msg=name)
+    np.testing.assert_array_equal(np.asarray(got["lengths"]),
+                                  np.asarray(want["lengths"]))
+    for name, leaf in got.get("rec", {}).items():
+        close(np.asarray(leaf), np.asarray(want["rec"][name]), err_msg=name)
+
+
+def test_a_pass_of_rows_is_the_same_chunks_one_call_at_a_time(pass_bench):
+    """Three slots' chunks as three rows of one four-row pass (a first
+    chunk, a chunk behind an earlier one, a short one; the fourth row
+    inert) leave the pages, the lengths, the recurrent state and each
+    row's logits as three calls of the one-row program do, whatever the
+    order of the slots among the rows."""
+    bench = pass_bench
+    earlier = [(2, 0, _prompt(PASS_CHUNK, 1))]
+    _, start = bench.one_at_a_time(bench.cache(poison=2.0), earlier)
+    rows = [(2, PASS_CHUNK, _prompt(5, 2)), (0, 0, _prompt(PASS_CHUNK, 3)),
+            (1, 0, _prompt(3, 4))]
+    want_logits, want = bench.one_at_a_time(start, rows)
+    got_logits, got = bench.run(start, rows, 4)
+    np.testing.assert_allclose(got_logits, want_logits, rtol=2e-4, atol=2e-4)
+    _same_cache(got, want)
+    assert np.asarray(got["lengths"]).tolist() == [8, 3, 13]
+    if "moe" in got:
+        # Programs are counted, and every row they computed is routed.
+        assert int(got["moe"]["calls"]) == 2 and int(want["moe"]["calls"]) == 4
+        per_row = (PASS_CHUNK * bench.cfg.experts_per_token
+                   * bench.cfg.n_layers)
+        assert int(got["moe"]["assignments"].sum()) == (1 + 4) * per_row
+    if "rec_count" in got:
+        counted = {n: int(c) for n, c in got["rec_count"].items()}
+        assert counted["calls"] == 2
+        assert counted["prefill_tokens_valid"] == PASS_CHUNK + 5 + 8 + 3
+        assert counted["prefill_tokens_computed"] == (1 + 4) * PASS_CHUNK
+
+
+@paged_alone
+def test_two_rows_of_a_pass_are_two_chunks_of_one_prompt(pass_bench):
+    """A model whose cache is pages alone: two consecutive chunks of one
+    prompt as two rows of one pass against two calls. Every layer
+    scatters both rows' keys before either row reads, so the later row
+    finds the earlier one's. (A model with recurrent layers cannot: its
+    second chunk starts from the state its first one leaves.)"""
+    bench = pass_bench
+    prompt = _prompt(PASS_CHUNK + 6, 5)
+    rows = [(1, 0, prompt[:PASS_CHUNK]), (1, PASS_CHUNK, prompt[PASS_CHUNK:])]
+    want_logits, want = bench.one_at_a_time(bench.cache(), rows)
+    got_logits, got = bench.run(bench.cache(), rows, 2)
+    np.testing.assert_allclose(got_logits, want_logits, rtol=2e-4, atol=2e-4)
+    _same_cache(got, want)
+    assert np.asarray(got["lengths"]).tolist() == [0, PASS_CHUNK + 6, 0]
+
+
+@pytest.mark.parametrize("width", [1, 2, 4])
+def test_a_row_without_tokens_changes_nothing(pass_bench, width):
+    """A pass of inert rows alone (`n_valid` 0, whatever slot and offset
+    they name) writes no page, no length and no recurrent state, over a
+    cache that holds a prompt and a poisoned recurrent pool."""
+    bench = pass_bench
+    _, start = bench.one_at_a_time(bench.cache(poison=3.0),
+                                   [(1, 0, _prompt(6, 6))])
+    _, after = bench.run(start, [], width)
+    _same_cache(after, start, exact=True)
+
+
+@paged_alone
+def test_the_probes_call_is_a_pass_of_one_row(pass_bench):
+    """`bench/probes/paged_kv.py` calls the program with `tokens [1, C]`
+    and scalars for the count, the slot and the offset, and reads
+    `logits[0]`: four results, the logits `[1, vocab]`, and what a
+    one-row pass with vectors gives. (The hybrid is probed through
+    `engine.prefill_logits`, tests/test_granite_hybrid.py.)"""
+    import jax.numpy as jnp
+
+    bench = pass_bench
+    cache = bench.cache()
+    tokens = np.zeros((1, PASS_CHUNK), np.int32)
+    tokens[0, :5] = _prompt(5, 7)
+    out = paged_kv.prefill_chunk_paged(
+        bench.params, jnp.asarray(tokens), jnp.int32(5), jnp.int32(0),
+        jnp.int32(0), cache["k"], cache["v"], cache["lengths"],
+        cache["block_tables"], bench.cfg, PASS_LEN, None)
+    assert len(out) == 4
+    logits, k, v, lengths = out
+    assert logits.shape == (1, bench.cfg.vocab_size)
+    assert k.shape == cache["k"].shape and v.shape == cache["v"].shape
+    assert np.asarray(lengths).tolist() == [5, 0, 0]
+    rows, _ = bench.run(cache, [(0, 0, _prompt(5, 7))], 1)
+    np.testing.assert_allclose(np.asarray(logits[0]), rows[0], rtol=1e-5,
+                               atol=1e-5)
+
+
 # -- engine: page accounting ----------------------------------------------
 def test_zero_page_leak_over_1k_admit_evict_cycles():
     """1000 admissions/evictions leave the pool exactly empty. Prompts

@@ -461,7 +461,7 @@ _LEDGER_CHILDREN = ("admit", "prefill_dispatch", "prefill_first_token_wait",
 _LEDGER_CHUNK = 8
 # Distinct prompts under a page in common: the prefix cache skips nothing.
 _LEDGER_PROMPTS = [[(7 * i + j) % 250 + 1 for j in range(n)]
-                   for i, n in enumerate((20, 3, 29, 10, 17, 8))]
+                   for i, n in enumerate((44, 3, 29, 10, 17, 8))]
 
 
 @pytest.fixture(scope="module")
@@ -533,24 +533,30 @@ def _ledger_sums_to_the_turn_total(run):
 
 
 def _ledger_counts_prefill_chunks_and_passes(run):
+    """`prefill_chunks` counts dispatches of the prefill program, one a
+    pass, and `prefill_rows` the chunks of prompts they held."""
     t0, t1 = run["before"]["timing"], run["after"]["timing"]
-    chunks = sum(-(-len(p) // _LEDGER_CHUNK) for p in _LEDGER_PROMPTS)
-    assert t1["prefill_chunks"] - t0["prefill_chunks"] == chunks
+    rows = sum(-(-len(p) // _LEDGER_CHUNK) for p in _LEDGER_PROMPTS)
+    assert t1["prefill_rows"] - t0["prefill_rows"] == rows
     assert t1["phases"]["prefill_dispatch"]["n"] == t1["prefill_chunks"]
+    # No request was cancelled: every pass dispatched, at most four rows.
+    assert t1["prefill_passes"] == t1["prefill_chunks"]
     assert 0 < t1["prefill_passes"] <= t1["turns"]
-    assert t1["prefill_passes"] <= t1["prefill_chunks"]
+    assert rows / 4 <= t1["prefill_chunks"] < rows
     # Six requests finished prefill in at most six passes' drains.
     assert 0 < t1["phases"]["prefill_first_token_wait"]["n"] <= 6
 
 
 def _ledger_counts_turns_without_a_decoding_slot(run):
-    """A 20-token prompt alone prefills in three turns; only the last
-    leaves a slot decoding, and the old clock counted none of them."""
+    """A 44-token prompt alone, six chunks, prefills in two passes of a
+    turn each; only the last leaves a slot decoding, and the old clock
+    did not count the first."""
     t0, t1 = run["before"]["timing"], run["alone"]["timing"]
     turns = t1["turns"] - t0["turns"]
     timed = t1["steps_timed"] - t0["steps_timed"]
-    assert t1["prefill_passes"] - t0["prefill_passes"] == 3
-    assert turns - timed >= 2
+    assert t1["prefill_passes"] - t0["prefill_passes"] == 2
+    assert t1["prefill_rows"] - t0["prefill_rows"] == 6
+    assert turns - timed >= 1
     # The old keys keep their meaning: turns that dispatched a decode step.
     assert timed == t1["phases"]["decode_dispatch"]["n"]
     assert t1["dispatch_ms_total"] == pytest.approx(
@@ -687,8 +693,8 @@ def test_chunked_prefill_parity_and_interleaving():
     from ray_tpu.serve.llm import ContinuousBatchingEngine
 
     params, cfg = _tiny_model()
-    long_prompt = [(7 * i) % 250 + 1 for i in range(30)]  # 4 chunks @ 8
-    eng = ContinuousBatchingEngine(params, cfg, num_slots=3, max_len=96,
+    long_prompt = [(7 * i) % 250 + 1 for i in range(90)]  # 12 chunks @ 8
+    eng = ContinuousBatchingEngine(params, cfg, num_slots=3, max_len=128,
                                    prefill_chunk=8)
     try:
         long_h = eng.submit(long_prompt, max_new_tokens=6)
@@ -708,10 +714,149 @@ def test_chunked_prefill_parity_and_interleaving():
         # The short request's single chunk completed while the long
         # prompt was still chunking — STRICTLY earlier admission is the
         # interleaving property (whole-prompt prefill would admit both
-        # in the same iteration).
+        # in the same iteration): a pass gives every mid-prefill slot a
+        # row before it gives the long prompt the rows left, and twelve
+        # chunks are three passes at least.
         assert short_h.admitted_at_step < long_h.admitted_at_step
     finally:
         eng.shutdown()
+
+
+# -- a prefill pass is one program over rows ------------------------------
+
+_ROWS_CHUNK = 8
+_ROWS_PROMPTS = [[(5 * i + 3 * j) % 250 + 1 for j in range(n)]
+                 for i, n in enumerate((19, 3, 12, 30, 8))]
+
+
+@pytest.fixture(scope="module")
+def rows_engine():
+    from ray_tpu.serve.llm import ContinuousBatchingEngine
+
+    params, cfg = _tiny_model()
+    eng = ContinuousBatchingEngine(params, cfg, num_slots=5, max_len=128,
+                                   prefill_chunk=_ROWS_CHUNK)
+    yield eng, params, cfg
+    eng.shutdown()
+
+
+@pytest.mark.parametrize("together", [1, 2, 3, 5])
+def test_prompts_sent_together_share_prefill_passes(rows_engine, together):
+    """Prompts of mixed lengths submitted together, their chunks rows of
+    shared passes (more than one row a dispatch), give, greedy, the
+    tokens `generate` gives one at a time, and nothing compiles after
+    warm-up at either width."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import generate
+
+    eng, params, cfg = rows_engine
+    # A first token of its own a case: the prefix cache skips nothing.
+    prompts = [[100 + together] + p for p in _ROWS_PROMPTS[:together]]
+    before = eng.stats()
+    handles = [eng.submit(p, max_new_tokens=6) for p in prompts]
+    outs = [h.result(timeout=180) for h in handles]
+    stats = eng.stats()
+    refs = [np.asarray(generate(params, jnp.asarray([p], dtype=jnp.int32),
+                                cfg, max_new_tokens=6))[0].tolist()
+            for p in prompts]
+    assert outs == refs
+    timing, was = stats["timing"], before["timing"]
+    rows = timing["prefill_rows"] - was["prefill_rows"]
+    dispatches = timing["prefill_chunks"] - was["prefill_chunks"]
+    assert rows == sum(-(-len(p) // _ROWS_CHUNK) for p in prompts)
+    assert rows / dispatches > 1
+    # (The counter is the process's: `generate` above compiles after it
+    # was read.)
+    assert stats["recompiles_post_warm"] == before["recompiles_post_warm"]
+
+
+def test_short_prompts_sent_alone_are_passes_of_one_row(rows_engine):
+    """A prompt under a chunk, sent when nothing else prefills, is a
+    pass of one row: the one-row program, which costs what a chunk cost
+    before a pass had rows."""
+    eng, _, _ = rows_engine
+    before = eng.stats()
+    for prompt in ([4, 2], [9, 9, 1, 7], [250]):
+        assert len(eng.submit(prompt, max_new_tokens=3).result(
+            timeout=180)) == 3
+    after = eng.stats()
+    rows = after["timing"]["prefill_rows"] - before["timing"]["prefill_rows"]
+    dispatches = (after["timing"]["prefill_chunks"]
+                  - before["timing"]["prefill_chunks"])
+    assert rows == dispatches == 3
+    assert after["recompiles_post_warm"] == before["recompiles_post_warm"]
+
+
+def test_a_pass_holds_at_most_pass_tokens(rows_engine):
+    """The two widths a pass is compiled at follow the chunk: one row and
+    `PASS_ROWS` at a chunk of 8, one and two rows at a chunk of 256
+    (`PASS_TOKENS` 512), where a prompt of three chunks then prefills in
+    two passes and decodes as `generate` does."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import generate
+    from ray_tpu.serve import llm
+
+    eng, params, cfg = rows_engine
+    assert eng._pass_rows == (1, llm.PASS_ROWS) == (1, 4)
+    assert llm.PASS_TOKENS == 512
+    prompt = [(11 * i) % 250 + 1 for i in range(2 * 256 + 40)]
+    ref = np.asarray(generate(params, jnp.asarray([prompt], dtype=jnp.int32),
+                              cfg, max_new_tokens=4))[0].tolist()
+    wide = llm.ContinuousBatchingEngine(params, cfg, num_slots=2,
+                                        max_len=640, prefill_chunk=256)
+    try:
+        assert wide._pass_rows == (1, 2)
+        assert wide.submit(prompt, max_new_tokens=4).result(
+            timeout=180) == ref
+        timing = wide.stats()["timing"]
+    finally:
+        wide.shutdown()
+    assert timing["prefill_rows"] == 3 and timing["prefill_chunks"] == 2
+
+
+def test_a_hybrid_gets_one_row_a_slot_a_pass():
+    """A model with recurrent layers: a chunk starts from the state the
+    chunk before it left, so a pass never holds two rows of one prompt;
+    several prompts' chunks still share passes, and each request's greedy
+    tokens are what it gets sent alone."""
+    import jax
+
+    from ray_tpu.models import configs, init_params
+    from ray_tpu.serve.llm import ContinuousBatchingEngine
+
+    cfg = replace(configs.get_config("tiny_granite_h"), dtype=np.float32)
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    prompts = [p[:n] for p, n in zip(_ROWS_PROMPTS, (19, 3, 12, 30))]
+    eng = ContinuousBatchingEngine(params, cfg, num_slots=4, max_len=128,
+                                   prefill_chunk=_ROWS_CHUNK)
+    passes = []
+    dispatch = eng._dispatch_prefill
+
+    def recorded(tokens, n_valid, slots, offsets):
+        passes.append([int(s) for s, n in zip(slots, n_valid) if n])
+        return dispatch(tokens, n_valid, slots, offsets)
+
+    eng._dispatch_prefill = recorded
+    try:
+        alone = [eng.submit(p, max_new_tokens=5).result(timeout=180)
+                 for p in prompts]
+        assert all(len(slots) == 1 for slots in passes)
+        assert len(passes) == sum(-(-len(p) // _ROWS_CHUNK) for p in prompts)
+        del passes[:]
+        handles = [eng.submit(p, max_new_tokens=5) for p in prompts]
+        assert [h.result(timeout=180) for h in handles] == alone
+        stats = eng.stats()
+    finally:
+        eng.shutdown()
+    assert all(len(set(slots)) == len(slots) for slots in passes)
+    assert max(len(slots) for slots in passes) > 1
+    # The recurrent counters sum over a pass's rows: real tokens, and
+    # every row the program computed, the inert ones too.
+    assert stats["ssm"]["prefill_tokens_valid"] == 2 * sum(map(len, prompts))
+    assert stats["ssm"]["prefill_tokens_computed"] % _ROWS_CHUNK == 0
+    assert stats["recompiles_post_warm"] == 0
 
 
 def test_chunked_prefill_non_multiple_max_len():
