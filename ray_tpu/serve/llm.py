@@ -54,6 +54,7 @@ BASELINE.json configs[4] (the serving north-star).
 
 from __future__ import annotations
 
+import logging
 import threading
 import time
 from collections import deque
@@ -94,6 +95,8 @@ PASS_ROWS = 4
 # model's later (two rows of 256 cost 1.35 of one at OLMoE's, four 1.99),
 # while an inert row of a pass past that point costs what a real one does.
 PASS_TOKENS = 512
+
+logger = logging.getLogger("ray_tpu.serve.llm")
 
 _metrics_lock = threading.Lock()
 _metrics: Optional[Dict] = None
@@ -311,6 +314,16 @@ _TURN_PHASES = {
 }
 
 
+# What a late dry stretch or a stall is told apart by: the phase it was
+# seen in, `other` between two phases.
+_BY_PHASE = (*_TURN_PHASES, "other")
+_DISPATCHES = ("prefill_dispatch", "decode_dispatch")
+# A span of a phase past this is a stall: five times the longest healthy
+# phase in any cell (56 ms on a v5e: the hybrid's decode step and a
+# two-row pass behind one first-token fetch).
+STALL_S = 0.25
+
+
 class _PhaseLedger:
     """Where the engine loop's time goes, on two clocks at once:
     `with ledger(key):` opens `jax.profiler.TraceAnnotation(
@@ -320,17 +333,55 @@ class _PhaseLedger:
     is one `turn` whose children are `_TURN_PHASES`; what a turn spends
     outside them is `other` = turn - children, so work + wait + other is
     the turn total by construction. `wait_for_work` (idle) lies beside
-    the turns and in no total. Loop-thread-only: stats() reads the copy
-    the loop publishes after each turn."""
+    the turns and in no total.
+
+    The time the loop KNOWS the device dry, by cause. The loop dispatches
+    every program in order and hands the ledger a result of the newest
+    (`newest`: one no later program takes as a donated argument); the
+    device is known drained from the moment the loop sees that result
+    complete until its next dispatch returns, inside turns only. Seen
+    complete by a blocking fetch that returned (the exit of a `wait`
+    phase finds `newest` ready: the loop was made to wait and nothing is
+    queued behind what it waited for) the stretch is `drained_fetch`; seen
+    by `is_ready()` at any other phase boundary (polled at every entry and
+    exit while something is in flight) it is `drained_late`: the host was
+    at work when the device finished, somewhere inside the phase at whose
+    exit it was seen (`late@<phase>`; `late@other` when seen at an entry,
+    between two phases). Both are lower bounds: completion is seen after
+    it happened. A dispatch at whose entry the device was drained `late` is
+    a `late_dispatch`. In a trace the fetch's stretch is `engine.pass_drain`,
+    opened at `prefill_first_token_wait`'s entry (that fetch always drains:
+    the pass and its pick are the last things queued) and closed with the
+    next dispatch or the turn, and a late one `engine.drained_late` from
+    where it is seen to the next dispatch. A span of a phase, or a turn's
+    `other`, past `STALL_S` is a stall (`stalls`, `stall@<phase>`) and one
+    line of the log.
+
+    Loop-thread-only: stats() reads the copy the loop publishes after
+    each turn."""
 
     def __init__(self):
         keys = ("turn", "wait_for_work", *_TURN_PHASES)
         self.names = {k: f"engine.{k}" for k in keys}
-        self.n = dict.fromkeys(keys, 0)
-        self.s = dict.fromkeys(keys, 0.0)
+        counted = (*keys, "pass_drain", "drained_fetch", "drained_late",
+                   "stalls", *(f"{kind}@{k}" for kind in ("late", "stall")
+                               for k in _BY_PHASE))
+        self.n = dict.fromkeys(counted, 0)
+        self.s = dict.fromkeys(counted, 0.0)
         self.prefill_passes = 0  # turns in which _advance_prefills ran
         self.prefill_rows = 0    # real rows (chunks) its passes dispatched
+        self.dispatches = 0      # decode steps and prefill passes
+        self.late_dispatches = 0
         self.t = 0.0             # the newest stamp any phase read
+        # A result of the newest program dispatched (the loop sets it
+        # inside the dispatch's phase), None once it was seen complete.
+        self.newest = None
+        self._dry = ()           # the totals the open dry stretch adds to
+        self._dry_t = 0.0        # ... and the stamp it has added up to
+        self._children = 0.0     # this turn's children, for its `other`
+        self._pass_span = None   # the open `engine.pass_drain`
+        self._pass_t0 = 0.0
+        self._late_span = None   # the open `engine.drained_late`
 
     def __call__(self, key: str) -> "_Phase":
         return _Phase(self, key)
@@ -338,7 +389,94 @@ class _PhaseLedger:
     def snapshot(self) -> Dict:
         return {"n": dict(self.n), "s": dict(self.s),
                 "prefill_passes": self.prefill_passes,
-                "prefill_rows": self.prefill_rows}
+                "prefill_rows": self.prefill_rows,
+                "dispatches": self.dispatches,
+                "late_dispatches": self.late_dispatches}
+
+    def _entered(self, key: str, now: float):
+        if key == "prefill_first_token_wait":
+            self._pass_t0 = now  # `_Phase` opened the span around its own
+            return
+        if key == "wait_for_work":
+            # Dry for want of requests from here on: in neither cause
+            # (the turn's exit added the stretch up; what lies between
+            # two turns is in no total).
+            self._dry = ()
+            self._dispatched(now)
+            self.newest = None
+            return
+        if key == "turn":
+            self._children = 0.0
+            self._dry_t = now
+        if self.newest is not None and self.newest.is_ready():
+            self._seen(now, "late", "other")
+
+    def _exited(self, key: str, now: float, elapsed: float, failed: bool):
+        self.s[key] += elapsed
+        self.n[key] += 1
+        if key == "wait_for_work":
+            return
+        if key == "turn":
+            by, over = "other", elapsed - self._children
+            self._accrue(now)
+            self._close_pass(now)
+        else:
+            by, over = key, elapsed
+            self._children += elapsed
+        if over > STALL_S:
+            self.n["stalls"] += 1
+            self.s["stalls"] += over
+            self.n[f"stall@{by}"] += 1
+            self.s[f"stall@{by}"] += over
+            logger.warning("engine loop stalled %.0f ms in %s (a healthy "
+                           "phase is under %.0f ms)", over * 1e3, by,
+                           STALL_S * 1e3)
+        if key in _DISPATCHES:
+            # Late if entered so: nothing polls inside a dispatch.
+            self.dispatches += 1
+            self.late_dispatches += "drained_late" in self._dry
+            self._dispatched(now)
+        elif (self.newest is not None and not failed
+              and self.newest.is_ready()):
+            if _TURN_PHASES.get(key) == "wait":
+                self._seen(now, "fetch", key)
+            else:
+                self._seen(now, "late", by)
+
+    def _seen(self, now: float, cause: str, by: str):
+        """`newest` was found complete: a dry stretch opens."""
+        self.newest = None
+        self._dry_t = now
+        self.n[f"drained_{cause}"] += 1
+        if cause == "fetch":
+            self._dry = ("drained_fetch",)
+            return
+        self._dry = ("drained_late", f"late@{by}")
+        self.n[f"late@{by}"] += 1
+        self._late_span = jax.profiler.TraceAnnotation("engine.drained_late")
+        self._late_span.__enter__()
+
+    def _accrue(self, now: float):
+        for key in self._dry:
+            self.s[key] += now - self._dry_t
+        self._dry_t = now
+
+    def _close_pass(self, now: float):
+        if self._pass_span is not None:
+            self._pass_span.__exit__(None, None, None)
+            self._pass_span = None
+            self.s["pass_drain"] += now - self._pass_t0
+            self.n["pass_drain"] += 1
+
+    def _dispatched(self, now: float):
+        """A dispatch returned (or the loop goes idle): the dry stretch
+        and its spans end."""
+        self._accrue(now)
+        self._dry = ()
+        self._close_pass(now)
+        if self._late_span is not None:
+            self._late_span.__exit__(None, None, None)
+            self._late_span = None
 
 
 class _Phase:
@@ -353,10 +491,16 @@ class _Phase:
         self.elapsed = 0.0
 
     def __enter__(self):
-        self._span = jax.profiler.TraceAnnotation(
-            self._ledger.names[self._key])
+        ledger, key = self._ledger, self._key
+        if key == "prefill_first_token_wait":
+            # Around the fetch's own span, not inside it.
+            ledger._pass_span = jax.profiler.TraceAnnotation(
+                "engine.pass_drain")
+            ledger._pass_span.__enter__()
+        self._span = jax.profiler.TraceAnnotation(ledger.names[key])
         self._span.__enter__()
-        self._t0 = self._ledger.t = time.perf_counter()
+        self._t0 = ledger.t = time.perf_counter()
+        ledger._entered(key, self._t0)
         return self
 
     def __exit__(self, *exc):
@@ -364,8 +508,7 @@ class _Phase:
         ledger.t = now = time.perf_counter()
         self._span.__exit__(*exc)
         self.elapsed = now - self._t0
-        ledger.s[self._key] += self.elapsed
-        ledger.n[self._key] += 1
+        ledger._exited(self._key, now, self.elapsed, exc[0] is not None)
         return False
 
 
@@ -376,6 +519,10 @@ def _timing_of(ledger: Dict) -> Dict:
     by_class = {"work": 0.0, "wait": 0.0}
     for key, cls in _TURN_PHASES.items():
         by_class[cls] += s[key]
+
+    def of(key):
+        return {"n": n[key], "ms_total": s[key] * 1e3}
+
     return {
         "turns": n["turn"],
         "turn_ms_total": s["turn"] * 1e3,
@@ -389,8 +536,22 @@ def _timing_of(ledger: Dict) -> Dict:
         "wait_ms_total": by_class["wait"] * 1e3,
         "other_ms_total": (s["turn"] - by_class["work"]
                            - by_class["wait"]) * 1e3,
-        "phases": {k: {"n": n[k], "ms_total": s[k] * 1e3}
-                   for k in (*_TURN_PHASES, "wait_for_work")},
+        "phases": {k: of(k) for k in (*_TURN_PHASES, "wait_for_work")},
+        # The time the loop knew the device dry inside its turns, by
+        # cause (a lower bound of the device's idle time beside
+        # `wait_for_work`, see `_PhaseLedger`), and the span that names
+        # the fetch's stretch in a trace (no child of the turn: it
+        # overlaps four of them).
+        "drained": {
+            "fetch": of("drained_fetch"),
+            "late": of("drained_late"),
+            "late_by_phase": {k: of(f"late@{k}") for k in _BY_PHASE},
+        },
+        "pass_drain": of("pass_drain"),
+        "dispatches": ledger["dispatches"],
+        "late_dispatches": ledger["late_dispatches"],
+        "stalls": {**of("stalls"),
+                   "by_phase": {k: of(f"stall@{k}") for k in _BY_PHASE}},
     }
 
 
@@ -1003,7 +1164,6 @@ class ContinuousBatchingEngine:
         if "rec_count" in self._tail:
             tail["ssm"] = self._ssm_stats()
         with self._lock:
-            ts = max(self._timed_steps, 1)
             return {
                 **tail,
                 # The device this engine's programs run on, as JAX
@@ -1039,15 +1199,12 @@ class ContinuousBatchingEngine:
                 # cannot shrink a step you cannot decompose). _total
                 # fields are cumulative: probes delta two stats()
                 # snapshots for a clean steady-state window. The first
-                # seven keys cover only turns that dispatched a decode
+                # four keys cover only turns that dispatched a decode
                 # step, `host` being such a turn less its dispatch and
                 # its fetch (prefill and its device waits included); the
                 # ledger's keys cover every turn.
                 "timing": {
                     "steps_timed": self._timed_steps,
-                    "dispatch_ms_avg": self._t_dispatch / ts * 1e3,
-                    "fetch_ms_avg": self._t_fetch / ts * 1e3,
-                    "host_ms_avg": self._t_host / ts * 1e3,
                     "dispatch_ms_total": self._t_dispatch * 1e3,
                     "fetch_ms_total": self._t_fetch * 1e3,
                     "host_ms_total": self._t_host * 1e3,
@@ -1366,12 +1523,14 @@ class ContinuousBatchingEngine:
                     finished.append((r, slot, h, entry))
             logits = self._dispatch_prefill(tokens, n_valid, slots, offsets)
             self._phase.prefill_rows += len(rows)
+            self._phase.newest = logits
             if not finished:
                 return
             # Final chunks: their first tokens, fed to the decode loop
             # device-side (no host round trip), the copy started for the
             # handle push below.
             toks_dev = self._first_tokens(logits, ends, temps, top_ks, top_ps)
+            self._phase.newest = toks_dev
         with self._phase("prefill_first_token_wait"):
             toks_np = jax.device_get(toks_dev)
         with self._phase("prefill_publish"):
@@ -1508,7 +1667,7 @@ class ContinuousBatchingEngine:
                         self._active_dev, self._bt_dev, **self._tail,
                     )
                 self._tail = dict(zip(self._tail, tail))
-                self._tokens_dev = next_dev
+                self._tokens_dev = phase.newest = next_dev
                 # Start the D2H copy NOW: it lands while this thread
                 # distributes the previous step's tokens and the next
                 # turn dispatches — the drain below then finds a
@@ -1686,7 +1845,7 @@ class ContinuousBatchingEngine:
                     self._top_ks[:] = 0
                     self._top_ps[:] = 1.0
                     self._params_dirty = True
-                self._inflight = None
+                self._inflight = phase.newest = None
                 time.sleep(0.1)
 
 

@@ -396,8 +396,8 @@ def test_continuous_batching_steady_state_zero_host_traffic():
 
 def test_continuous_batching_step_timing_breakdown():
     """stats()['timing'] decomposes engine steps into dispatch/fetch/
-    host wall-time; totals are cumulative (probes delta two snapshots)
-    and consistent with the averages."""
+    host wall-time; totals are cumulative (probes delta two snapshots:
+    a mean is a total over `steps_timed`)."""
     from ray_tpu.serve.llm import ContinuousBatchingEngine
 
     params, cfg = _tiny_model()
@@ -413,10 +413,10 @@ def test_continuous_batching_step_timing_breakdown():
         t = eng.stats()["timing"]
         assert t["steps_timed"] >= 12
         for part in ("dispatch", "fetch", "host"):
-            total = t[f"{part}_ms_total"]
-            avg = t[f"{part}_ms_avg"]
-            assert total >= 0.0
-            assert avg == pytest.approx(total / t["steps_timed"])
+            assert t[f"{part}_ms_total"] >= 0.0
+        # A decode turn is its dispatch, its fetch and the rest.
+        assert (t["dispatch_ms_total"] + t["fetch_ms_total"]
+                + t["host_ms_total"]) <= t["turn_ms_total"] * (1 + 1e-9)
     finally:
         eng.shutdown()
 
@@ -469,7 +469,7 @@ def ledger_run(tmp_path_factory):
     """Mixed traffic through one engine under a CPU profiler trace
     (python tracer off): a request alone, so that its prefill turns have
     no decoding slot, then greedy and sampled requests together, then an
-    idle stretch. Returns stats() at each point and the host plane's
+    idle stretch and another. Returns stats() at each point and the host plane's
     `engine.*` spans per thread as (name, start, end)."""
     import glob
 
@@ -497,6 +497,8 @@ def ledger_run(tmp_path_factory):
             assert len(h.result(timeout=180)) == 12
         time.sleep(0.7)  # the loop goes idle: a wait_for_work closes
         after = eng.stats()
+        time.sleep(0.6)  # ... and another, with nothing on the device
+        idle = eng.stats()
     finally:
         jax.profiler.stop_trace()
         eng.shutdown()
@@ -510,7 +512,7 @@ def ledger_run(tmp_path_factory):
                      for ev in line.events if ev.name.startswith("engine.")]
             if spans:
                 threads.append(spans)
-    return {"before": before, "alone": alone, "after": after,
+    return {"before": before, "alone": alone, "after": after, "idle": idle,
             "threads": threads}
 
 
@@ -563,13 +565,21 @@ def _ledger_counts_turns_without_a_decoding_slot(run):
         t1["phases"]["decode_dispatch"]["ms_total"])
 
 
-def _ledger_spans_nest_in_the_profilers_trace(run):
-    """On the profiler's clock, in the loop's thread: every child lies
-    inside an `engine.turn`, and `engine.wait_for_work` inside none."""
+def _loop_spans(run):
     loop = [spans for spans in run["threads"]
             if any(n == "engine.turn" for n, _s, _e in spans)]
     assert len(loop) == 1, "engine.turn spans on one thread, the loop's"
-    spans = loop[0]
+    return loop[0]
+
+
+def _ledger_spans_nest_in_the_profilers_trace(run):
+    """On the profiler's clock, in the loop's thread: every child and every
+    `engine.pass_drain` lies inside an `engine.turn`, and
+    `engine.wait_for_work` inside none. `engine.drained_late` runs from
+    where the loop saw the device dry to its next dispatch, across a
+    turn's end where that dispatch is the next turn's: it nests in
+    nothing."""
+    spans = _loop_spans(run)
     turns = [(s, e) for n, s, e in spans if n == "engine.turn"]
     assert len(turns) == (run["after"]["timing"]["turns"]
                           - run["before"]["timing"]["turns"])
@@ -578,10 +588,11 @@ def _ledger_spans_nest_in_the_profilers_trace(run):
         return any(ts <= s and e <= te for ts, te in turns)
 
     names = {n for n, _s, _e in spans}
-    assert names == ({f"engine.{k}" for k in _LEDGER_CHILDREN}
-                     | {"engine.turn", "engine.wait_for_work"})
+    assert names - {"engine.drained_late"} == (
+        {f"engine.{k}" for k in _LEDGER_CHILDREN}
+        | {"engine.turn", "engine.wait_for_work", "engine.pass_drain"})
     for n, s, e in spans:
-        if n == "engine.turn":
+        if n in ("engine.turn", "engine.drained_late"):
             continue
         assert inside_a_turn(s, e) == (n != "engine.wait_for_work"), n
     # The spans are the ledger's: as many of each as it counted.
@@ -592,14 +603,281 @@ def _ledger_spans_nest_in_the_profilers_trace(run):
                             - t0["phases"][key]["n"]), key
 
 
+def _delta(t0, t1, path):
+    def dig(doc):
+        for key in path.split("."):
+            doc = doc[key]
+        return doc
+    return dig(t1) - dig(t0)
+
+
+def _ledger_a_first_token_fetch_drains_the_device(run):
+    """A request alone: the one pass that ends its prompt is one
+    `drained.fetch` stretch (the fetch returned with nothing queued) and
+    one `pass_drain` span, which holds the wait and the dry stretch after
+    it; the last step's drain, in a turn that dispatched nothing, may be
+    one more."""
+    t0, t1 = run["before"]["timing"], run["alone"]["timing"]
+    assert _delta(t0, t1, "phases.prefill_first_token_wait.n") == 1
+    idle_turns = _delta(t0, t1, "turns") - _delta(t0, t1, "steps_timed")
+    assert 1 <= _delta(t0, t1, "drained.fetch.n") <= 1 + idle_turns
+    assert (0 < _delta(t0, t1, "drained.fetch.ms_total")
+            <= _delta(t0, t1, "turn_ms_total"))
+    assert _delta(t0, t1, "pass_drain.n") == 1
+    assert (_delta(t0, t1, "pass_drain.ms_total")
+            >= _delta(t0, t1, "phases.prefill_first_token_wait.ms_total"))
+    assert (_delta(t0, t1, "pass_drain.ms_total")
+            <= _delta(t0, t1, "turn_ms_total"))
+
+
+def _ledger_dry_time_lies_inside_the_turns(run):
+    """Fetch-drained and late together are part of the turns' time, the
+    late stretches are told apart by phase, a dispatch is late at most
+    once, and an idle loop adds to `wait_for_work` and to neither cause."""
+    t = run["after"]["timing"]
+    dry = t["drained"]
+    for cause in ("fetch", "late"):
+        assert dry[cause]["n"] >= 0 and dry[cause]["ms_total"] >= 0
+    assert (dry["fetch"]["ms_total"] + dry["late"]["ms_total"]
+            <= t["turn_ms_total"])
+    by_phase = dry["late_by_phase"]
+    assert set(by_phase) == set(_LEDGER_CHILDREN) | {"other"}
+    assert sum(v["n"] for v in by_phase.values()) == dry["late"]["n"]
+    assert sum(v["ms_total"] for v in by_phase.values()) == pytest.approx(
+        dry["late"]["ms_total"])
+    # A fetch's own phase is never where the host was late.
+    assert by_phase["prefill_first_token_wait"]["n"] == 0
+    assert t["dispatches"] == (t["phases"]["decode_dispatch"]["n"]
+                               + t["phases"]["prefill_dispatch"]["n"])
+    assert 0 <= t["late_dispatches"] <= min(t["dispatches"],
+                                            dry["late"]["n"])
+    later = run["idle"]["timing"]
+    assert later["drained"] == dry and later["turns"] == t["turns"]
+    assert (later["phases"]["wait_for_work"]["ms_total"]
+            >= t["phases"]["wait_for_work"]["ms_total"] + 400.0)
+    assert t["stalls"]["n"] == 0 and not any(
+        v["n"] for v in t["stalls"]["by_phase"].values())
+
+
+def _ledger_pass_drain_spans_end_with_a_dispatch(run):
+    """In the trace: as many `engine.pass_drain` spans as the ledger
+    counted, each opened around a first-token fetch and closed just after
+    the next dispatch's span (here the decode step that takes the new
+    slot in), so it covers the whole gap the fetch leaves on the device."""
+    spans = _loop_spans(run)
+    drains = [(s, e) for n, s, e in spans if n == "engine.pass_drain"]
+    t0, t1 = run["before"]["timing"], run["after"]["timing"]
+    assert len(drains) == _delta(t0, t1, "pass_drain.n") > 0
+    assert len(drains) == _delta(
+        t0, t1, "phases.prefill_first_token_wait.n")
+    fetches = [(s, e) for n, s, e in spans
+               if n == "engine.prefill_first_token_wait"]
+    dispatch_ends = [e for n, _s, e in spans
+                     if n in ("engine.decode_dispatch",
+                              "engine.prefill_dispatch")]
+    for s, e in drains:
+        assert sum(1 for fs, fe in fetches if s <= fs and fe <= e) == 1
+        assert sum(1 for end in dispatch_ends if s < end <= e) == 1
+    late = sum(1 for n, _s, _e in spans if n == "engine.drained_late")
+    assert late == _delta(t0, t1, "drained.late.n")
+
+
+def _ledger_keeps_the_totals_and_no_averages(run):
+    """`timing` is cumulative: the seven totals stay, the three means
+    nobody read are gone (a mean is a total over `steps_timed`)."""
+    t = run["after"]["timing"]
+    for key in ("dispatch", "fetch", "host", "turn", "work", "wait",
+                "other"):
+        assert t[f"{key}_ms_total"] >= 0.0, key
+    assert not [k for k in t if k.endswith("_avg")]
+    # Readers walk `a.b.c`: no key carries a dot.
+    def keys(doc):
+        for k, v in doc.items():
+            yield k
+            if isinstance(v, dict):
+                yield from keys(v)
+    assert not [k for k in keys(t) if "." in k]
+
+
 @pytest.mark.parametrize("check", [
     _ledger_sums_to_the_turn_total,
     _ledger_counts_prefill_chunks_and_passes,
     _ledger_counts_turns_without_a_decoding_slot,
     _ledger_spans_nest_in_the_profilers_trace,
+    _ledger_a_first_token_fetch_drains_the_device,
+    _ledger_dry_time_lies_inside_the_turns,
+    _ledger_pass_drain_spans_end_with_a_dispatch,
+    _ledger_keeps_the_totals_and_no_averages,
 ], ids=lambda f: f.__name__.lstrip("_"))
 def test_phase_ledger(ledger_run, check):
     check(ledger_run)
+
+
+def _decoding(eng, steps, timeout=120):
+    """Wait until one request decodes, none prefills and `steps` decode
+    steps were distributed."""
+    deadline = time.monotonic() + timeout
+    while time.monotonic() < deadline:
+        s = eng.stats()
+        if (s["active"] == 1 and s["prefilling"] == 0
+                and s["steps"] >= steps):
+            return
+        time.sleep(0.01)
+    pytest.fail(f"request never reached decode: {eng.stats()}")
+
+
+def test_phase_ledger_names_the_phase_the_host_was_late_in():
+    """Host work stretched past the step in flight: `_distribute` made to
+    outlast it is seen at that phase's exit (`late_by_phase.distribute`)
+    and the dispatch that follows is a late one; a chaos stretch between
+    two phases, a step in flight, is late time in no phase (this model's
+    step is over before `distribute` is, so the stretch it lengthens is
+    one first seen there; the test below holds the device's side still
+    and sees `other`)."""
+    from ray_tpu._private import chaos
+    from ray_tpu.serve.llm import ContinuousBatchingEngine
+
+    params, cfg = _tiny_model()
+    eng = ContinuousBatchingEngine(params, cfg, num_slots=2, max_len=128)
+    inner = eng._distribute
+
+    def slow_distribute(*a, **kw):
+        time.sleep(0.02)
+        return inner(*a, **kw)
+
+    chaos.enable()
+    try:
+        t0 = eng.stats()["timing"]
+        eng._distribute = slow_distribute
+        long_h = eng.submit([3, 7, 11, 2], max_new_tokens=24)
+        _decoding(eng, steps=4)
+        eng._distribute = inner
+        t1 = eng.stats()["timing"]
+        # The next pass sleeps before its first phase, a step in flight.
+        chaos.delay_prefills(0.05, count=1)
+        eng.submit([5, 1, 8, 2, 9, 4], max_new_tokens=4).result(timeout=120)
+        long_h.result(timeout=120)
+        t2 = eng.stats()["timing"]
+    finally:
+        chaos.disable()
+        chaos.clear()
+        eng.shutdown()
+    assert _delta(t0, t1, "drained.late_by_phase.distribute.n") >= 1
+    assert _delta(t0, t1, "drained.late_by_phase.distribute.ms_total") > 0
+    assert _delta(t0, t1, "drained.late.n") >= 1
+    assert _delta(t0, t1, "late_dispatches") >= 1
+    assert _delta(t1, t2, "drained.late.ms_total") >= 45.0
+    assert _delta(t1, t2, "other_ms_total") >= 45.0
+    assert _delta(t1, t2, "late_dispatches") >= 1
+    assert t2["late_dispatches"] <= t2["dispatches"]
+    assert (t2["drained"]["fetch"]["ms_total"]
+            + t2["drained"]["late"]["ms_total"]) <= t2["turn_ms_total"]
+
+
+def test_phase_ledger_tells_late_from_fetched_and_between_from_inside():
+    """The ledger alone, the device's side played by a result that is
+    ready when told: seen complete at a work phase's exit it is late in
+    that phase, at an entry late in `other`, at a wait phase's exit
+    drained by the fetch; only a dispatch entered late is a late one, and
+    an idle loop ends every stretch."""
+    from ray_tpu.serve.llm import _PhaseLedger, _timing_of
+
+    class Result:
+        ready = False
+
+        def is_ready(self):
+            return self.ready
+
+    ledger = _PhaseLedger()
+
+    def dispatch(key="decode_dispatch"):
+        with ledger(key):
+            ledger.newest = result = Result()
+        return result
+
+    with ledger("turn"):
+        step = dispatch()
+        with ledger("distribute"):
+            step.ready = True           # done while the host distributes
+        assert ledger.newest is None    # seen: nothing more to poll
+        with ledger("admit"):
+            pass
+        step = dispatch()               # entered late
+        step.ready = True               # done between two phases
+        with ledger("upload"):
+            pass
+        step = dispatch()               # entered late again
+        with ledger("decode_fetch_wait"):
+            step.ready = True           # the loop was made to wait
+        with ledger("prefill_publish"):
+            pass
+        step = dispatch("prefill_dispatch")  # ends a fetch's stretch
+        with ledger("prefill_first_token_wait"):
+            step.ready = True
+    with ledger("wait_for_work"):
+        pass
+    t = _timing_of(ledger.snapshot())
+    dry = t["drained"]
+    assert {k: v["n"] for k, v in dry["late_by_phase"].items() if v["n"]} \
+        == {"distribute": 1, "other": 1}
+    assert dry["late"]["n"] == 2 and dry["fetch"]["n"] == 2
+    assert t["dispatches"] == 4 and t["late_dispatches"] == 2
+    assert t["pass_drain"]["n"] == 1
+    assert all(v["ms_total"] > 0 for v in (dry["late"], dry["fetch"],
+                                            t["pass_drain"]))
+    assert (dry["late"]["ms_total"] + dry["fetch"]["ms_total"]
+            <= t["turn_ms_total"])
+    # Idle: nothing in flight, nothing open, and no stretch grows.
+    assert ledger.newest is None and not ledger._dry
+    assert ledger._pass_span is None and ledger._late_span is None
+    before = dict(ledger.s)
+    with ledger("wait_for_work"):
+        pass
+    assert {k: v for k, v in ledger.s.items() if v != before[k]}.keys() \
+        == {"wait_for_work"}
+
+
+def test_phase_ledger_counts_and_logs_a_stall(monkeypatch, caplog):
+    """One span of a phase past `STALL_S` (brought down here: nobody
+    sleeps a quarter of a second) is one stall under its phase and one
+    line of the module's log; `wait_for_work`, however long, is none."""
+    import logging
+
+    from ray_tpu.serve import llm
+
+    params, cfg = _tiny_model()
+    eng = llm.ContinuousBatchingEngine(params, cfg, num_slots=2, max_len=64)
+    inner = eng._distribute
+    calls = []
+
+    def stalling_distribute(*a, **kw):
+        calls.append(1)
+        if len(calls) == 3:
+            time.sleep(0.16)
+        return inner(*a, **kw)
+
+    monkeypatch.setattr(llm, "STALL_S", 0.1)
+    eng._distribute = stalling_distribute
+    try:
+        with caplog.at_level(logging.WARNING, logger="ray_tpu.serve.llm"):
+            eng.submit([3, 7, 11], max_new_tokens=8).result(timeout=120)
+            time.sleep(0.7)  # an idle stretch of 0.5 s: no stall
+            t = eng.stats()["timing"]
+    finally:
+        eng.shutdown()
+    stalls = t["stalls"]
+    assert stalls["by_phase"]["distribute"]["n"] == 1
+    assert 160.0 <= stalls["by_phase"]["distribute"]["ms_total"]
+    assert stalls["n"] == sum(v["n"] for v in stalls["by_phase"].values())
+    assert stalls["ms_total"] == pytest.approx(
+        sum(v["ms_total"] for v in stalls["by_phase"].values()))
+    assert t["phases"]["wait_for_work"]["ms_total"] >= 400.0
+    lines = [r.getMessage() for r in caplog.records
+             if r.name == "ray_tpu.serve.llm" and "stalled" in r.getMessage()]
+    assert len([m for m in lines if m.endswith("in distribute "
+                                                "(a healthy phase is under "
+                                                "100 ms)")]) == 1, lines
+    assert len(lines) == stalls["n"]
 
 
 def test_continuous_batching_tp_sharded():
