@@ -17,25 +17,53 @@ keeps the account:
     start time and command line, so that the next run can signal what is
     left and leave a reused pid alone;
   * a chip is free when no process holds a TPU device file open
-    (`/proc/*/fd` against `/dev/accel*`, `/dev/vfio/*`). libtpu's lock
-    file is never touched.
+    (`/proc/*/fd` against `/dev/accel*`, `/dev/vfio/*`) AND every such
+    file opens. The second half is the kernel's: a VFIO group opens for
+    one holder at a time, and a worker that held chips goes on giving
+    them back, one after the other, after it has left `/proc`: 2-5 s for
+    one chip, 14-22 s for four, in which an open of a group answers
+    `EBUSY` or sleeps until the group is back (PERF.md section 6, PR 49).
+    libtpu's lock file is never touched.
 """
 
 from __future__ import annotations
 
+import errno
 import glob
 import json
 import os
 import signal
 import time
-from typing import Dict, Iterable, List, Optional, Sequence
+from typing import Callable, Dict, Iterable, List, Optional, Sequence
 
 TOKEN_ENV = "RT_BENCH_RUN_TOKEN"
 DEVICE_GLOBS = ("/dev/accel*", "/dev/vfio/*")
 
 
 class ChipBusy(RuntimeError):
-    """Processes still hold the node's TPU device files."""
+    """Processes still hold the node's TPU device files, or the kernel
+    still refuses to open them."""
+
+
+class Waited(float):
+    """The seconds `wait_chip_free` waited, in its two parts: `unheld_s`
+    until /proc showed no holder, `opened_s` more until every device file
+    opened. `probe_ms` is what the last look at the files itself took."""
+
+    unheld_s: float
+    opened_s: float
+    probe_ms: float
+
+    def __new__(cls, unheld_s: float, opened_s: float, probe_ms: float):
+        self = super().__new__(cls, unheld_s + opened_s)
+        self.unheld_s, self.opened_s, self.probe_ms = (
+            unheld_s, opened_s, probe_ms)
+        return self
+
+    def parts(self) -> str:
+        return (f"{float(self):.2f}s (no holder after {self.unheld_s:.2f}s, "
+                f"the device files opened {self.opened_s:.2f}s later; the "
+                f"last look at them took {self.probe_ms:.2f} ms)")
 
 
 def _pids() -> List[int]:
@@ -203,22 +231,54 @@ def holders(globs: Sequence[str] = DEVICE_GLOBS) -> Dict[int, List[str]]:
     return out
 
 
+def busy_files(globs: Sequence[str] = DEVICE_GLOBS,
+               opener: Callable[[str, int], int] = os.open) -> List[str]:
+    """The device files the kernel will not open yet: `EBUSY` on an open
+    for reading and writing, closed again at once. Any other error
+    (`EACCES`, `ENOENT`, `EISDIR`, a file that is no group) says nothing
+    about a release in progress: for such a file /proc's answer stands."""
+    busy = []
+    for path in sorted({p for g in globs for p in glob.glob(g)}):
+        try:
+            fd = opener(path, os.O_RDWR)
+        except OSError as e:
+            if e.errno == errno.EBUSY:
+                busy.append(path)
+            continue
+        os.close(fd)
+    return busy
+
+
 def wait_chip_free(limit_s: float, globs: Sequence[str] = DEVICE_GLOBS,
-                   poll_s: float = 0.1) -> float:
-    """Return, as soon as no process holds a TPU device file, the seconds
-    waited; past `limit_s` raise ChipBusy naming the holders."""
+                   poll_s: float = 0.1,
+                   opener: Callable[[str, int], int] = os.open) -> Waited:
+    """Return, as soon as no process holds a TPU device file and every
+    one of them opens, the seconds waited; past `limit_s` raise ChipBusy
+    naming the holders, or the files that would not open."""
     t0 = time.monotonic()
+    unheld_at = None
     while True:
         held = holders(globs)
-        if not held:
-            return time.monotonic() - t0
-        if time.monotonic() - t0 >= limit_s:
-            who = "; ".join(
-                f"pid {pid} ({_cmdline(pid)[:80]}) holds "
-                f"{', '.join(sorted(set(paths)))}"
-                for pid, paths in sorted(held.items()))
+        looked_at = time.monotonic()
+        busy = [] if held else busy_files(globs, opener)
+        now = time.monotonic()
+        if not held and unheld_at is None:
+            unheld_at = looked_at
+        if not held and not busy:
+            return Waited(unheld_at - t0, now - unheld_at,
+                          (now - looked_at) * 1e3)
+        if now - t0 >= limit_s:
+            if held:
+                why = "; ".join(
+                    f"pid {pid} ({_cmdline(pid)[:80]}) holds "
+                    f"{', '.join(sorted(set(paths)))}"
+                    for pid, paths in sorted(held.items()))
+            else:
+                why = (f"no process holds a device file (since "
+                       f"{unheld_at - t0:.2f}s), yet {', '.join(busy)} "
+                       f"would not open: EBUSY, {os.strerror(errno.EBUSY)}")
             raise ChipBusy(
-                f"the chip was not free within {limit_s:.0f}s: {who}")
+                f"the chip was not free within {limit_s:g}s: {why}")
         time.sleep(poll_s)
 
 

@@ -32,9 +32,12 @@ BENCH = os.path.dirname(os.path.abspath(__file__))
 REPO = os.path.dirname(BENCH)
 sys.path[:0] = [p for p in (BENCH, REPO) if p not in sys.path]
 
-# How long a run waits for the last run's processes to let go of the chip
-# (counted into setup_s). A replica holding 13 GB exits in a second or two
-# once signalled; past this something is wrong and the run says what.
+# How long a run waits, at its start and at its end, for a free chip: no
+# process holding a device file and every device file opening. A replica
+# holding 13 GB exits in a second or two once signalled, and the kernel
+# took 2-5 s to give back the chip of a run that had gone and 14-22 s to
+# give back four (PERF.md section 6, PR 49); past this something is wrong
+# and the run says what.
 CHIP_FREE_LIMIT_S = 60.0
 # serve.run's own deploy limit is 300 s. A cold 36-layer deploy with a
 # 24 x 1024 cache took 87 s and a warm one 23 s (my chip runs, PR 23); the
@@ -92,6 +95,14 @@ def driver_touched_jax() -> bool:
     return xla_bridge.backends_are_initialized()
 
 
+def setup_seconds(window_t: float, t_start: float, start_wait: float) -> float:
+    """`setup_s`: from this process's start to the window's, less the wait
+    before `rt.init()` for a chip to come free. That wait is an earlier
+    run's teardown finishing in the kernel (the other tree's as often as
+    this one's), no set-up of this run; everything else counts."""
+    return window_t - t_start - start_wait
+
+
 def metrics_of(cell, result, setup_s: float, traced: bool):
     """The line's metrics: the cell's end-to-end metrics, or with
     --trace 1 its per-layer metrics, each through its own reader."""
@@ -136,8 +147,9 @@ def prepare_environment(token: str, platform: str, chips: int) -> None:
 def teardown(rt, token: str, pid_file: str):
     """The runtime's own shutdown, then every process the run started
     waited for (killed after a grace period, and waited for again), then
-    the chip seen free. Returns what was done and, if the chip stayed
-    held, that failure."""
+    the chip seen free: the run ends only when the next one could take
+    its chips. Returns what was done and, if the chip stayed held or its
+    device files would not open, that failure."""
     import procs
 
     try:
@@ -160,10 +172,10 @@ def teardown(rt, token: str, pid_file: str):
     ended = procs.end_run(token, pid_file)
     busy = None
     try:
-        freed = f"{procs.wait_chip_free(CHIP_FREE_LIMIT_S):.2f}"
+        freed = procs.wait_chip_free(CHIP_FREE_LIMIT_S).parts()
     except procs.ChipBusy as e:
         freed, busy = "-", (e, str(e))
-    say(f"after shutdown: {ended}; chip free after {freed}s; driver "
+    say(f"after shutdown: {ended}; chip free after {freed}; driver "
         f"initialised a JAX backend: {driver_touched_jax()}")
     return ended, busy
 
@@ -197,7 +209,7 @@ def main(argv=None) -> int:
         reaped = procs.reap_previous(pid_file)
         waited = procs.wait_chip_free(CHIP_FREE_LIMIT_S)
         say(f"before init: earlier runs' processes {reaped}; the chip was "
-            f"free after {waited:.2f}s")
+            f"free after {waited.parts()}, left out of setup_s")
         subprocess.run(["make", "-s", "-C",
                         os.path.join(REPO, "ray_tpu", "native")],
                        check=True, timeout=300, stdout=subprocess.DEVNULL)
@@ -240,7 +252,7 @@ def main(argv=None) -> int:
         say(f"no result: the workers ran on {device}, the cell needs "
             f"{cell['chips']} {args.platform} device(s)")
         return 1
-    setup_s = window["t"] - T_START
+    setup_s = setup_seconds(window["t"], T_START, waited)
     line = {
         "correct": result["correct"], "attempted": result["attempted"],
         "failed": result["failed"],
@@ -260,9 +272,16 @@ def main(argv=None) -> int:
             with open(os.path.join(run_dir, "trace_shape.txt"), "w") as f:
                 f.write(trace["describe"])
     say(f"setup_s {setup_s:.2f}")
+    # What decided `correct`, each number beside its limit: last in the
+    # line, and the last lines of standard error.
+    line["compared"] = result["compared"]
     with open(os.path.join(run_dir, "result.json"), "w") as f:
         json.dump(line, f)
     print(json.dumps(line), flush=True)
+    for name, c in line["compared"].items():
+        print(f"compared {name}: {c['value']} against the limit {c['limit']}"
+              f": {'holds' if c['holds'] else 'DOES NOT HOLD'}",
+              file=sys.stderr, flush=True)
     return 0
 
 

@@ -326,6 +326,13 @@ def run(ctx: Dict) -> Dict:
     }
     return {
         "correct": bool(check["logits_ok"] and check["margin_ok"]),
+        "compared": {
+            "logits_rel_rms_max": {
+                "value": max(s["logits_rel_rms"] for s in check["samples"]),
+                "limit": LOGITS_TOLERANCE, "holds": check["logits_ok"]},
+            "greedy_margin_rms_max": {
+                "value": max(s["max_margin_rms"] for s in check["samples"]),
+                "limit": MARGIN_TOLERANCE, "holds": check["margin_ok"]}},
         "attempted": summary["attempted"], "failed": summary["failed"],
         "values": {
             "ttft_p95_ms": summary["ttft_ms"]["p95"],

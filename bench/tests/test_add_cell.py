@@ -100,7 +100,7 @@ def test_add_another_architecture_as_files(tmp_path, monkeypatch):
             "file": f"bench/configs/{name}.json", "reduced": [],
             "why": "shows that another architecture is files"})
         add_cell(bench, f"{name}-serve", name, "throwaway-bursty",
-                 ("ttft_p95_ms", "tpot_p95_ms"))
+                 ("tpot_p95_ms",))
         add_cell(bench, f"{name}-train", name, "tokens-8x1024",
                  ("train_tokens_per_s_chip",))
     serve_cells = ["throwaway-serve", "throwaway-wrong-serve"]

@@ -12,7 +12,8 @@ import pytest
 import procs
 import spec
 
-LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device"}
+LINE_KEYS = {"correct", "attempted", "failed", "metrics", "device",
+             "compared"}
 
 
 def cells():
@@ -33,6 +34,14 @@ def check_line(done, bench, name, trace):
     assert done.returncode == 0, done.stdout[-3000:] + done.stderr[-3000:]
     line = json.loads(done.stdout.strip().splitlines()[-1])
     assert set(line) == LINE_KEYS | ({"breakdown"} if trace else set())
+    # What decided `correct`, each number beside its limit: last in the
+    # line and the last lines of standard error.
+    assert list(line)[-1] == "compared" and line["compared"]
+    assert all(set(c) == {"value", "limit", "holds"} and c["holds"]
+               for c in line["compared"].values())
+    last = done.stderr.strip().splitlines()[-len(line["compared"]):]
+    assert [row.split(":")[0] for row in last] == [
+        f"compared {name}" for name in line["compared"]]
     assert line["device"]["platform"] == "cpu"
     assert line["correct"] is True and line["failed"] == 0
     assert line["attempted"] > 0

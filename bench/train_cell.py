@@ -268,8 +268,24 @@ def run(ctx: Dict) -> Dict:
     finite = all(math.isfinite(x) for x in losses)
     near = bool(losses) and abs(losses[0] - out["ln_vocab"]) < FIRST_LOSS_DISTANCE
     device = out["device"]
+    # Each number beside its limit; `correct` is that every one holds.
+    loss_apart = abs(check["loss_system"] - check["loss_reference"])
+    compared = {"loss_apart": {"value": loss_apart, "limit": LOSS_TOLERANCE,
+                               "holds": loss_apart <= LOSS_TOLERANCE}}
+    if "grad_norm_rel" in check:
+        compared["grad_norm_rel"] = {
+            "value": check["grad_norm_rel"], "limit": GRAD_NORM_TOLERANCE,
+            "holds": check["grad_norm_rel"] <= GRAD_NORM_TOLERANCE}
+        compared["grad_cosine_min"] = {  # a floor
+            "value": check["grad_cosine_min"], "limit": GRAD_COSINE_FLOOR,
+            "holds": check["grad_cosine_min"] >= GRAD_COSINE_FLOOR}
+    compared["first_loss_from_ln_vocab"] = {
+        "value": abs(losses[0] - out["ln_vocab"]) if losses else None,
+        "limit": FIRST_LOSS_DISTANCE, "holds": near}
     return {
-        "correct": bool(check["ok"] and finite and near),
+        "correct": bool(check["ok"] and finite
+                        and all(c["holds"] for c in compared.values())),
+        "compared": compared,
         "attempted": out["steps"],
         "failed": sum(1 for x in losses if not math.isfinite(x)),
         "values": {"train_tokens_per_s_chip": out["tokens_per_s_chip"]},
