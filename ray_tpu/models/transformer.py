@@ -406,7 +406,18 @@ def project_qkv(h, lp, cfg: TransformerConfig):
         return (rmsnorm(q, lp["q_norm"], cfg.norm_eps, use_pallas=False),
                 rmsnorm(k, lp["k_norm"], cfg.norm_eps, use_pallas=False))
 
-    q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+    # The products are held back from the norm. Fused into a product, a
+    # per-head sum of squares has the chip's compiler slice `wq` and `wk`
+    # out of the layer stack and copy each heads-major, every layer of an
+    # engine step (26 MB a layer at Qwen3-4B's widths, a millisecond of a
+    # 15 ms decode step); held back, they are read where they lie, as `wv`
+    # is, and a train step of 8 x 1024 tokens is 0.6% shorter too. The
+    # arithmetic is the same, yet results equal the fused ones bit for bit
+    # on the CPU only: on the chip the norm reads the product as rounded
+    # to the model's dtype (what the program states and the reference
+    # computes), where fused it read the f32 accumulator.
+    q, k, v = jax.lax.optimization_barrier(
+        (h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]))
     if extent == "projection":
         q, k = normed(q, k)
     q = q.reshape(b, l, cfg.n_heads, cfg.head_dim)

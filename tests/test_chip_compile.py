@@ -228,8 +228,28 @@ def _engine_program(name, cfg, one, slots=SLOTS, rows=1, chunk=CHUNK):
     return fn, (5, 6), args, shape
 
 
+@pytest.fixture(scope="module")
+def qwen_step(v5e):
+    """Qwen3-4B's step programs at depth 4 as the engine jits them on one
+    described chip: (compiled, argument shapes, the cache's shape), each
+    compiled once for the tests that read it."""
+    compiled = {}
+
+    def step(program, rows):
+        if (program, rows) not in compiled:
+            fn, donated, args, shape = _engine_program(
+                program, dataclasses.replace(QWEN, n_layers=DEPTH),
+                SingleDeviceSharding(v5e[0]), rows=rows)
+            compiled[program, rows] = (
+                jax.jit(fn, donate_argnums=donated).lower(*args).compile(),
+                args, shape)
+        return compiled[program, rows]
+
+    return step
+
+
 @pytest.mark.parametrize("program,rows", ENGINE_STEPS, ids=STEP_IDS)
-def test_engine_step_updates_its_cache_in_place(v5e, program, rows,
+def test_engine_step_updates_its_cache_in_place(qwen_step, program, rows,
                                                 monkeypatch):
     """The KV cache rides in the layer scan's carry, so a step with its
     caches donated scatters into the caller's buffers: no second cache
@@ -238,11 +258,8 @@ def test_engine_step_updates_its_cache_in_place(v5e, program, rows,
     one, the decode-attention kernel reading the carried pool at the
     layer's index included (a layer sliced out for it would be copied)."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    cfg = dataclasses.replace(QWEN, n_layers=DEPTH)
-    fn, donated, args, shape = _engine_program(
-        program, cfg, SingleDeviceSharding(v5e[0]), rows=rows)
-    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
-    one_cache = 2 * DEPTH * SLOTS * MAX_LEN * cfg.n_kv_heads * cfg.head_dim
+    compiled, args, shape = qwen_step(program, rows)
+    one_cache = 2 * DEPTH * SLOTS * MAX_LEN * QWEN.n_kv_heads * QWEN.head_dim
     memory = compiled.memory_analysis()
     assert memory.temp_size_in_bytes < one_cache // 2
     assert _whole_model_fits(compiled, args, QWEN, DEPTH)
@@ -252,6 +269,27 @@ def test_engine_step_updates_its_cache_in_place(v5e, program, rows,
     whole_cache_copies = re.findall(
         rf"= bf16\[{dims}\]\S* copy\(", compiled.as_text())
     assert not whole_cache_copies
+
+
+@pytest.mark.parametrize("program,rows", ENGINE_STEPS, ids=STEP_IDS)
+def test_engine_step_reads_wq_and_wk_in_place(qwen_step, program, rows,
+                                              monkeypatch):
+    """Qwen3-4B's step programs read a layer's `wq` and `wk` where they lie
+    in the stack, as they read `wv`: the slice is a nested computation of
+    the product. With the per-head norm's sum of squares fused into the
+    product the compiler wants the weight heads-major, so every layer of
+    every step sliced `bf16[1,2560,4096]` and `bf16[1,2560,1024]` out of
+    the stack (`constant_dynamic-slice_fusion`) and copied each into the
+    other order: 0.31 s of 2.6 s busy in `serve-chat-steady`.
+    `project_qkv` holds the products back from the norm."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    text = qwen_step(program, rows)[0].as_text()
+    for heads in (QWEN.n_heads, QWEN.n_kv_heads):
+        # Top level: a fusion's or a copy's result. Inside a fused
+        # computation the slice is a `dynamic-slice(` and costs nothing.
+        layer = f"{QWEN.d_model},{heads * QWEN.head_dim}"
+        assert not re.findall(
+            rf"= bf16\[1,{layer}\]\S* (?:copy|fusion)\(", text)
 
 
 @pytest.mark.parametrize("model,slots,depth", [

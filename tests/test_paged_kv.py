@@ -239,6 +239,82 @@ def _layerwise(params, cfg, x, k_pool, v_pool, write_kv, positions, valid,
     return rmsnorm(x, params["final_norm"], cfg.norm_eps), k_pool, v_pool
 
 
+@pytest.mark.parametrize("extent", ["head", "projection", None])
+def test_held_back_products_change_no_head_and_no_gradient(extent):
+    """`project_qkv` holds its three products back from the QK-norm
+    (`optimization_barrier`: fused into a product, a per-head sum of
+    squares has the chip's compiler copy `wq` and `wk` out of the stack
+    every layer). The hold is no arithmetic: the heads `_layer_body` hands
+    to `attend`, and the gradients a train step takes through
+    `project_qkv`, equal the same lines written without it bit for bit,
+    at every extent of the norm, in bfloat16 as both run. That is the
+    CPU's word: on the chip the norm now reads the product as rounded to
+    bfloat16, where fused it read the f32 accumulator."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import configs, init_params
+    from ray_tpu.models.transformer import at_layer, project_qkv
+    from ray_tpu.ops import rmsnorm
+    from ray_tpu.serve.paged_kv import _layer_body
+
+    cfg = replace(configs.tiny_qwen, dtype=jnp.bfloat16,
+                  qk_norm=extent is not None, qk_norm_extent=extent or "head")
+    lp = at_layer(init_params(jax.random.PRNGKey(0), cfg)["layers"], 1)
+    if extent:  # a scale of ones would hide a norm at the wrong extent
+        lp = {**lp, **{n: jax.random.normal(jax.random.PRNGKey(i),
+                                            lp[n].shape, cfg.dtype)
+                       for i, n in enumerate(("q_norm", "k_norm"))}}
+    x = jax.random.normal(jax.random.PRNGKey(2), (3, 5, cfg.d_model),
+                          cfg.dtype)
+
+    def through_the_layer_body(x, lp):
+        def attend(kc, vc, q, k, v):
+            return (q, k), v, jnp.zeros_like(q)
+
+        # No position embedding: `attend` is handed the heads as projected.
+        _, (q, k), v, _ = _layer_body(x, lp, None, None, cfg, None, None,
+                                      None, attend)
+        return q, k, v
+
+    def plain(x, lp):
+        h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
+        q, k, v = h @ lp["wq"], h @ lp["wk"], h @ lp["wv"]
+
+        def norm(q, k):
+            return (rmsnorm(q, lp["q_norm"], cfg.norm_eps),
+                    rmsnorm(k, lp["k_norm"], cfg.norm_eps))
+
+        if extent == "projection":
+            q, k = norm(q, k)
+        q = q.reshape(*x.shape[:2], cfg.n_heads, cfg.head_dim)
+        k = k.reshape(*x.shape[:2], cfg.n_kv_heads, cfg.head_dim)
+        v = v.reshape(*x.shape[:2], cfg.n_kv_heads, cfg.head_dim)
+        return (*(norm(q, k) if extent == "head" else (q, k)), v)
+
+    def held(x, lp):
+        return project_qkv(rmsnorm(x, lp["attn_norm"], cfg.norm_eps), lp, cfg)
+
+    def same(ours, theirs):
+        for a, b in zip(jax.tree.leaves(ours), jax.tree.leaves(theirs),
+                        strict=True):
+            assert a.dtype == b.dtype == cfg.dtype
+            np.testing.assert_array_equal(np.asarray(a, np.float32),
+                                          np.asarray(b, np.float32))
+
+    same(jax.jit(through_the_layer_body)(x, lp), jax.jit(plain)(x, lp))
+
+    def grads(project):
+        def loss(x, lp):
+            return sum(jnp.sum(jnp.sin(t.astype(jnp.float32)))
+                       for t in project(x, lp))
+        return jax.jit(jax.grad(loss, argnums=(0, 1)))(x, lp)
+
+    same(grads(held), grads(plain))
+    assert "optimization_barrier" in str(jax.make_jaxpr(held)(x, lp))
+    assert "optimization_barrier" not in str(jax.make_jaxpr(plain)(x, lp))
+
+
 @pytest.mark.parametrize("program", ["decode", "prefill_final_chunk"])
 def test_carried_pool_matches_a_layer_by_layer_reference(program):
     """`decode_paged` and `prefill_chunk_paged` carry the whole pool
