@@ -7,6 +7,8 @@ scale for CPU tests.
 
 from __future__ import annotations
 
+from dataclasses import replace
+
 import jax.numpy as jnp
 
 from ray_tpu.models.transformer import TransformerConfig
@@ -280,6 +282,95 @@ granite_4_0_h_micro = TransformerConfig(
     position_embedding_type="nope",
 )
 
+# Every mechanism of the model below at toy widths: latent attention with a
+# query bottleneck, one leading dense layer, sigmoid scores chosen within 2
+# of 4 groups, a shared expert, YaRN, and blocks of 16 cached rows so that a
+# test prompt spans several.
+tiny_dots = TransformerConfig(
+    vocab_size=256,
+    d_model=64,
+    n_layers=3,
+    n_heads=4,
+    n_kv_heads=4,
+    d_ff=128,
+    max_seq=128,
+    dtype=jnp.float32,
+    remat=False,
+    q_lora_rank=32,
+    kv_lora_rank=32,
+    qk_nope_head_dim=16,
+    qk_rope_head_dim=8,
+    v_head_dim=16,
+    first_k_dense_replace=1,
+    moe_intermediate_size=32,
+    num_experts=16,
+    experts_per_token=4,
+    n_shared_experts=1,
+    scoring_func="sigmoid",
+    n_group=4,
+    topk_group=2,
+    norm_topk_prob=True,
+    routed_scaling_factor=2.5,
+    rope_factor=4.0,
+    rope_original_max_position=32,
+    rope_beta_fast=4.0,
+    rope_mscale=1.0,
+    rope_mscale_all_dim=1.0,
+)
+
+# dots.vlm1.inst's language model (the model's public config.json,
+# `model_type` dots_vlm; the block is DeepSeek-V3's, arXiv:2412.19437):
+# 61 layers of latent attention (128 heads of 128 + 64, a 512-wide latent
+# and one 64-wide rope key a token, queries through 1536), the first 3
+# with a dense MLP of 18432, the others 256 routed experts of 2048, 8 a
+# token chosen by sigmoid scores within 4 of 8 groups and weighted 2.5
+# times their normalised scores, beside 1 shared expert; YaRN by 40 over
+# 4096; an untied 129,280-row vocabulary. 672 B parameters, 37 B used by a
+# token. Its image tower and its multi-token-prediction module are not
+# here (bench/configs/dots-vlm1-ep16-serve.json says why).
+dots_vlm1 = TransformerConfig(
+    vocab_size=129280,
+    d_model=7168,
+    n_layers=61,
+    n_heads=128,
+    n_kv_heads=128,
+    d_ff=18432,
+    max_seq=4096,
+    rope_theta=10000.0,
+    norm_eps=1e-6,
+    q_lora_rank=1536,
+    kv_lora_rank=512,
+    qk_nope_head_dim=128,
+    qk_rope_head_dim=64,
+    v_head_dim=128,
+    first_k_dense_replace=3,
+    moe_intermediate_size=2048,
+    num_experts=256,
+    experts_per_token=8,
+    n_shared_experts=1,
+    scoring_func="sigmoid",
+    n_group=8,
+    topk_group=4,
+    norm_topk_prob=True,
+    routed_scaling_factor=2.5,
+    rope_factor=40.0,
+    rope_original_max_position=4096,
+    rope_beta_fast=32.0,
+    rope_beta_slow=1.0,
+    rope_mscale=1.0,
+    rope_mscale_all_dim=1.0,
+)
+
+# One chip's share of it, as one of 16 chips that share each layer: 16 of
+# the 256 routed experts (share 0: experts 0-15; the router's width stays
+# 256), attention, the shared expert, the router and the dense layer whole,
+# an eighth of the vocabulary, and ONE leading dense layer (leading dense
+# layers count once in a cut of depth; the benchmark's file gives the depth
+# it runs, 6: the others would lie on further chips, as pipeline stages).
+dots_vlm1_ep16 = replace(
+    dots_vlm1, vocab_size=16160, first_k_dense_replace=1,
+    experts_held=16, expert_share=0)
+
 NAMED_CONFIGS = {
     "tiny": tiny,
     "tiny_gqa": tiny_gqa,
@@ -298,6 +389,9 @@ NAMED_CONFIGS = {
     "olmoe-1b-7b": olmoe_1b_7b,
     "tiny_granite_h": tiny_granite_h,
     "granite-4.0-h-micro": granite_4_0_h_micro,
+    "tiny_dots": tiny_dots,
+    "dots-vlm1": dots_vlm1,
+    "dots-vlm1-ep16": dots_vlm1_ep16,
 }
 
 

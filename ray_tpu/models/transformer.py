@@ -28,6 +28,7 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import apply_rope, flash_attention, rmsnorm, rope_frequencies, softmax_cross_entropy
+from ray_tpu.ops.rope import yarn_inv_freq, yarn_mscale
 from ray_tpu.models import mamba2
 from ray_tpu.parallel.moe import load_balancing_loss, moe_block
 
@@ -118,14 +119,77 @@ class TransformerConfig:
     logits_scaling: float = 1.0
     # "rope", or "nope": attention without a position embedding.
     position_embedding_type: str = "rope"
+    # Multi-head latent attention (DeepSeek-V2/V3, arXiv:2405.04434), under
+    # the public config's keys: queries through a `q_lora_rank` bottleneck
+    # (0: projected directly), keys and values through one `kv_lora_rank`
+    # latent a token (0: no latent attention), a head's query and key made
+    # of a `qk_nope_head_dim` part without a position and a
+    # `qk_rope_head_dim` part with one, the key's shared by all heads, and
+    # values `v_head_dim` wide. What is cached is the latent and the rope
+    # key (`project_latent`).
+    q_lora_rank: int = 0
+    kv_lora_rank: int = 0
+    qk_nope_head_dim: int = 0
+    qk_rope_head_dim: int = 0
+    v_head_dim: int = 0
+    # A decoder of two kinds of layer (DeepSeek-V3's keys): the first
+    # `first_k_dense_replace` layers have a dense MLP of width `d_ff`, the
+    # others `num_experts` routed experts of width `moe_intermediate_size`
+    # (0: `d_ff`) beside `n_shared_experts` that every token takes. Router
+    # scores are a "softmax" or a "sigmoid" (`scoring_func`); with
+    # `n_group` > 1 a token chooses within its `topk_group` best groups of
+    # experts; chosen weights are multiplied by `routed_scaling_factor`.
+    first_k_dense_replace: int = 0
+    moe_intermediate_size: int = 0
+    n_shared_experts: int = 0
+    scoring_func: str = "softmax"
+    n_group: int = 1
+    topk_group: int = 1
+    routed_scaling_factor: float = 1.0
+    # One chip's share of a layer's routed experts: `experts_held` of the
+    # `num_experts` the router chooses among (0: all), the `expert_share`-th
+    # such run, experts [held * share, held * (share + 1)). The layer then
+    # computes what its own experts add and nothing else (parallel/moe.py).
+    experts_held: int = 0
+    expert_share: int = 0
+    # YaRN (arXiv:2309.00071, the public config's `rope_scaling`): factor
+    # (1: plain rope), the length the model was first trained at, the two
+    # rotation counts between which frequencies blend, and the two
+    # attention-temperature coefficients.
+    rope_factor: float = 1.0
+    rope_original_max_position: int = 0
+    rope_beta_fast: float = 32.0
+    rope_beta_slow: float = 1.0
+    rope_mscale: float = 1.0
+    rope_mscale_all_dim: float = 0.0
 
     @property
     def head_dim(self) -> int:
+        if self.kv_lora_rank and not self.custom_head_dim:
+            return self.qk_nope_head_dim + self.qk_rope_head_dim
         return self.custom_head_dim or self.d_model // self.n_heads
 
     @property
     def attention_scale(self) -> float:
+        if self.kv_lora_rank:  # YaRN's temperature, on both q and k
+            return self.head_dim ** -0.5 * yarn_mscale(
+                self.rope_factor, self.rope_mscale_all_dim) ** 2
         return self.attention_multiplier or self.head_dim ** -0.5
+
+    @property
+    def expert_ff(self) -> int:
+        """One routed expert's width."""
+        return self.moe_intermediate_size or self.d_ff
+
+    @property
+    def held(self) -> int:
+        """Routed experts of a layer whose weights are here."""
+        return self.experts_held or self.num_experts
+
+    @property
+    def expert_layers(self) -> int:
+        return (self.n_layers - self.first_k_dense_replace
+                if self.num_experts else 0)
 
     def layers_of(self, kind: str) -> int:
         """How many of a hybrid's layers are of `kind`."""
@@ -219,9 +283,105 @@ def _init_hybrid_layers(key, cfg: TransformerConfig) -> Dict:
     return {"ssm": ssm, "attn": attn, "mlp": mlp}
 
 
+def _check_latent(cfg: TransformerConfig) -> None:
+    if not (cfg.qk_nope_head_dim and cfg.qk_rope_head_dim and cfg.v_head_dim):
+        raise ValueError("latent attention needs qk_nope_head_dim, "
+                         "qk_rope_head_dim and v_head_dim")
+    if cfg.layer_pattern or cfg.qk_norm:
+        raise ValueError("latent attention beside state-space layers, or "
+                         "under a QK-norm, is not written")
+    if not 0 <= cfg.first_k_dense_replace <= cfg.n_layers:
+        raise ValueError("first_k_dense_replace must lie in [0, n_layers]")
+    if cfg.num_experts:
+        if cfg.num_experts % cfg.held or not (
+                0 <= cfg.expert_share < cfg.num_experts // cfg.held):
+            raise ValueError(
+                f"experts_held {cfg.held} must divide num_experts "
+                f"{cfg.num_experts}, and expert_share {cfg.expert_share} "
+                "name one of the shares")
+        if cfg.num_experts % cfg.n_group or cfg.topk_group > cfg.n_group:
+            raise ValueError("n_group must divide num_experts and hold "
+                             "topk_group")
+
+
+def _init_latent_layers(key, cfg: TransformerConfig) -> Dict:
+    """The layers of a decoder with latent attention, a stack a kind:
+    `dense` (the first `first_k_dense_replace` layers, or all of a model
+    without experts) and `moe` (the others), each a whole layer's leaves.
+    The published `q_b_proj`, `kv_a_proj_with_mqa` and `kv_b_proj` are held
+    as the column blocks the program multiplies by: `wq_n | wq_r` (every
+    head's part without and with a position), `wkv_a | wk_r` (the latent
+    and the shared rope key) and `w_uk | w_uv` (every head's keys and
+    values out of the latent). Every matrix at its fan-in ** -0.5, the
+    writers of the residual stream at (2 n_layers) ** -0.5 of that."""
+    _check_latent(cfg)
+    d, h, n = cfg.d_model, cfg.n_heads, cfg.n_layers
+    dn, dr, dv = cfg.qk_nope_head_dim, cfg.qk_rope_head_dim, cfg.v_head_dim
+    rank, qr = cfg.kv_lora_rank, cfg.q_lora_rank
+    keys = iter(jax.random.split(key, 40))
+    out = (2 * n) ** -0.5
+
+    def normal(count, shape, fan_in, scale=1.0):
+        return _dense_init(next(keys), (count, *shape),
+                           fan_in ** -0.5 * scale, cfg.dtype)
+
+    def attention(count):
+        lp = {"attn_norm": jnp.ones((count, d), cfg.dtype),
+              "mlp_norm": jnp.ones((count, d), cfg.dtype)}
+        q_in = d
+        if qr:
+            lp["wq_a"] = normal(count, (d, qr), d)
+            lp["q_a_norm"] = jnp.ones((count, qr), cfg.dtype)
+            q_in = qr
+        lp.update({
+            "wq_n": normal(count, (q_in, h * dn), q_in),
+            "wq_r": normal(count, (q_in, h * dr), q_in),
+            "wkv_a": normal(count, (d, rank), d),
+            "kv_a_norm": jnp.ones((count, rank), cfg.dtype),
+            "wk_r": normal(count, (d, dr), d),
+            "w_uk": normal(count, (rank, h * dn), rank),
+            "w_uv": normal(count, (rank, h * dv), rank),
+            "wo": normal(count, (h * dv, d), h * dv, out),
+        })
+        return lp
+
+    n_moe = cfg.expert_layers
+    layers = {}
+    if n - n_moe:
+        ff = cfg.d_ff
+        layers["dense"] = {
+            **attention(n - n_moe),
+            "w_gate": normal(n - n_moe, (d, ff), d),
+            "w_up": normal(n - n_moe, (d, ff), d),
+            "w_down": normal(n - n_moe, (ff, d), ff, out),
+        }
+    if n_moe:
+        e, held, ff = cfg.num_experts, cfg.held, cfg.expert_ff
+        layers["moe"] = {
+            **attention(n_moe),
+            "router": normal(n_moe, (d, e), d),
+            # `e_score_correction_bias`: added to the scores to choose
+            # with, never to weigh with; zero as published checkpoints
+            # start it, float32 as they keep it.
+            "router_bias": jnp.zeros((n_moe, e), jnp.float32),
+            "w_gate": normal(n_moe, (held, d, ff), d),
+            "w_up": normal(n_moe, (held, d, ff), d),
+            "w_down": normal(n_moe, (held, ff, d), ff, out),
+        }
+        if cfg.n_shared_experts:
+            sff = cfg.n_shared_experts * ff
+            layers["moe"].update({
+                "shared_gate": normal(n_moe, (d, sff), d),
+                "shared_up": normal(n_moe, (d, sff), d),
+                "shared_down": normal(n_moe, (sff, d), sff, out),
+            })
+    return layers
+
+
 def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
     """Initialize the full parameter pytree (layers stacked on axis 0; a
-    hybrid's as `_init_hybrid_layers` says)."""
+    hybrid's as `_init_hybrid_layers` says, a latent-attention model's as
+    `_init_latent_layers`)."""
     keys = jax.random.split(key, 10)
     d, h, kvh, hd, ff = (
         cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim, cfg.d_ff
@@ -235,6 +395,8 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
 
     if cfg.layer_pattern:
         return _with_tables(_init_hybrid_layers(keys[0], cfg), keys, cfg)
+    if cfg.kv_lora_rank:
+        return _with_tables(_init_latent_layers(keys[0], cfg), keys, cfg)
     layer = {
         "attn_norm": jnp.ones((L, d), dtype=cfg.dtype),
         "wq": stack(keys[0], (d, h * hd), scale),
@@ -299,6 +461,14 @@ def param_logical_axes(cfg: TransformerConfig) -> Dict:
     model axis: heads, groups and the convolution's channels do not split
     without a partitioned mixer.
     """
+    if cfg.kv_lora_rank:
+        # Replicated but for the stack axis: a latent pool and a held share
+        # of experts are one chip's (serve/llm.py refuses them under tp).
+        shapes = jax.eval_shape(lambda k: init_params(k, cfg),
+                                jax.random.PRNGKey(0))
+        return jax.tree_util.tree_map_with_path(
+            lambda path, leaf: (("stage",) if path[0].key == "layers" else ())
+            + (None,) * (leaf.ndim - (path[0].key == "layers")), shapes)
     if cfg.layer_pattern:
         shapes = jax.eval_shape(lambda k: init_params(k, cfg),
                                 jax.random.PRNGKey(0))
@@ -523,6 +693,125 @@ def _hybrid_layers(params, x, cfg: TransformerConfig, mesh, positions):
     return x
 
 
+def rope_tables(cfg: TransformerConfig, length: int):
+    """(cos, sin) `[length, rotated width // 2]` of the model's position
+    embedding, or (None, None) for a model without one. Latent attention
+    rotates its `qk_rope_head_dim` alone, at YaRN's frequencies where the
+    config has a factor."""
+    if cfg.position_embedding_type != "rope":
+        return None, None
+    if not cfg.kv_lora_rank:
+        return rope_frequencies(cfg.head_dim, length, cfg.rope_theta)
+    inv_freq, magnitude = None, 1.0
+    if cfg.rope_factor > 1.0:
+        inv_freq = yarn_inv_freq(
+            cfg.qk_rope_head_dim, cfg.rope_theta, cfg.rope_factor,
+            cfg.rope_original_max_position, cfg.rope_beta_fast,
+            cfg.rope_beta_slow)
+        magnitude = (yarn_mscale(cfg.rope_factor, cfg.rope_mscale)
+                     / yarn_mscale(cfg.rope_factor, cfg.rope_mscale_all_dim))
+    return rope_frequencies(cfg.qk_rope_head_dim, length, cfg.rope_theta,
+                            inv_freq=inv_freq, magnitude=magnitude)
+
+
+def project_latent(h, lp, cfg: TransformerConfig, cos, sin, positions):
+    """Latent attention's four products of normed activations `h [B, L,
+    D]`: every head's query without a position `q_n [B, L, H, dn]` and
+    with one `q_r [B, L, H, dr]`, the token's normed latent `c [B, L,
+    rank]` and its one rope key `k_r [B, L, dr]`, both rotated. `c` and
+    `k_r` side by side are what a cache holds of the token."""
+    b, l, _ = h.shape
+    with jax.named_scope("mla.project"):
+        cq = h
+        if cfg.q_lora_rank:
+            cq = rmsnorm(h @ lp["wq_a"], lp["q_a_norm"], cfg.norm_eps,
+                         use_pallas=False)
+        q_n = (cq @ lp["wq_n"]).reshape(b, l, cfg.n_heads, -1)
+        q_r = (cq @ lp["wq_r"]).reshape(b, l, cfg.n_heads, -1)
+        c = rmsnorm(h @ lp["wkv_a"], lp["kv_a_norm"], cfg.norm_eps,
+                    use_pallas=False)
+        k_r = (h @ lp["wk_r"])[:, :, None, :]
+        if cos is not None:
+            q_r = apply_rope(q_r, cos, sin, positions)
+            k_r = apply_rope(k_r, cos, sin, positions)
+        return q_n, q_r, c, k_r[:, :, 0]
+
+
+def expand_latent(c, lp, cfg: TransformerConfig):
+    """Every head's keys without a position `[B, K, H, dn]` and values
+    `[B, K, H, dv]` out of latents `c [B, K, rank]`."""
+    b, k, _ = c.shape
+    return ((c @ lp["w_uk"]).reshape(b, k, cfg.n_heads, -1),
+            (c @ lp["w_uv"]).reshape(b, k, cfg.n_heads, -1))
+
+
+def _latent_attention_full(q_n, q_r, c, k_r, lp, cfg: TransformerConfig):
+    """Causal latent attention of whole sequences from position 0, in the
+    expanded form and plain `jax.numpy` (differentiable): `[B, L, H * dv]`."""
+    b, l = c.shape[:2]
+    with jax.named_scope("mla.attend"):
+        k_n, v = expand_latent(c, lp, cfg)
+        scores = (jnp.einsum("bqhn,bkhn->bhqk", q_n, k_n,
+                             preferred_element_type=jnp.float32)
+                  + jnp.einsum("bqhr,bkr->bhqk", q_r, k_r,
+                               preferred_element_type=jnp.float32))
+        causal = jnp.tril(jnp.ones((l, l), dtype=bool))
+        scores = jnp.where(causal, scores * cfg.attention_scale, -jnp.inf)
+        probs = jax.nn.softmax(scores, axis=-1).astype(v.dtype)
+        return jnp.einsum("bhqk,bkhv->bqhv", probs, v).reshape(b, l, -1)
+
+
+def latent_layer(x, lp, cfg: TransformerConfig, cos, sin, positions, attend,
+                 mesh=None, layer=None):
+    """One layer of a latent-attention decoder on `x [B, L, D]`, for the
+    training forward and the cached walks alike. `attend(q_n, q_r, c, k_r)
+    -> (attention [B, L, H * dv], cache)` is how the queries meet the keys:
+    over the sequence itself (no cache: None), or through a cache it also
+    writes. The MLP is dense where `lp` has no router; `layer` is
+    `moe_block`'s. Returns the layer's output, its routing statistics
+    (None for a dense layer) and `attend`'s cache."""
+    b, l, d = x.shape
+    h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh, spec=_ACT_SPEC)
+    attn, cache = attend(*project_latent(h, lp, cfg, cos, sin, positions))
+    x = x + (attn @ lp["wo"]).astype(x.dtype)
+    h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh, spec=_ACT_SPEC)
+    if "router" not in lp:
+        return x + dense_mlp(h, lp, cfg), None, cache
+    y, routing = moe_block(h.reshape(b * l, d), lp, cfg, layer)
+    return x + y.reshape(b, l, d), routing, cache
+
+
+def latent_stacks(params):
+    """A latent-attention decoder's stacks in the order its layers run,
+    `(kind, stack, first layer)`: the dense layers lead."""
+    layers, first, out = params["layers"], 0, []
+    for kind in ("dense", "moe"):
+        if kind in layers:
+            out.append((kind, layers[kind], first))
+            first += layers[kind]["attn_norm"].shape[0]
+    return out
+
+
+def _latent_layers(params, x, cfg: TransformerConfig, mesh, positions):
+    """Whole sequences through a latent-attention decoder's layers: a scan
+    a kind. Returns x and the expert layers' stacked routing statistics."""
+    _check_latent(cfg)
+    cos, sin = rope_tables(cfg, cfg.max_seq)
+    routing = None
+    for _kind, stack, _first in latent_stacks(params):
+        def body(x, lp):
+            return latent_layer(
+                x, lp, cfg, cos, sin, positions,
+                lambda *qck: (_latent_attention_full(*qck, lp, cfg), None),
+                mesh)[:2]
+
+        if cfg.remat:
+            body = jax.checkpoint(body)
+        x, stats = jax.lax.scan(body, x, stack)
+        routing = stats if stats is not None else routing
+    return x, routing
+
+
 def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
     """Build the per-layer body used by lax.scan."""
 
@@ -591,6 +880,8 @@ def forward(
     x = _embed_tokens(params, tokens, cfg)
     if cfg.layer_pattern:
         x, routing = _hybrid_layers(params, x, cfg, mesh, positions), None
+    elif cfg.kv_lora_rank:
+        x, routing = _latent_layers(params, x, cfg, mesh, positions)
     else:
         cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
         body = _layer_fn(cfg, mesh, cos, sin, positions)
@@ -650,10 +941,11 @@ def forward_pipelined(
             "pipeline parallelism currently supports dense layers only "
             "(the MoE aux loss does not thread through the pp schedule)"
         )
-    if cfg.layer_pattern:
+    if cfg.layer_pattern or cfg.kv_lora_rank:
         raise ValueError(
-            "pipeline parallelism needs stages of like layers: a hybrid's "
-            "stacks (one a kind of mixer) do not split into pp stages")
+            "pipeline parallelism needs stages of like layers: stacks of "
+            "unlike kinds (a hybrid's mixers, dense layers before expert "
+            "layers) do not split into pp stages")
 
     x = _embed_tokens(params, tokens, cfg)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)

@@ -75,6 +75,44 @@ def load_balancing_loss(prob_mean: jax.Array, counts: jax.Array,
     return num_experts * jnp.sum(p * f)
 
 
+def route(logits: jax.Array, cfg, bias: Optional[jax.Array] = None):
+    """A token's choice of experts from its router logits `[tokens, E]`
+    float32: `(scores [tokens, E], weights [tokens, k], chosen [tokens,
+    k])`. "softmax": the k largest probabilities, renormalised where
+    `norm_topk_prob` says so (Mixtral, OLMoE). "sigmoid" (DeepSeek-V3's
+    `noaux_tc`): every expert's sigmoid score; the choice is made on score +
+    `bias [E]` and, with `n_group` > 1, within the `topk_group` groups
+    whose two best sum highest; the weights are the chosen experts' scores
+    without the bias, over their sum where `norm_topk_prob`, times
+    `routed_scaling_factor`."""
+    k = cfg.experts_per_token
+    if cfg.scoring_func == "softmax":
+        probs = jax.nn.softmax(logits, axis=-1)
+        weights, chosen = jax.lax.top_k(probs, k)            # [tokens, k]
+        if cfg.norm_topk_prob:
+            weights = weights / weights.sum(-1, keepdims=True)
+        return probs, weights, chosen
+    if cfg.scoring_func != "sigmoid":
+        raise ValueError(f"unknown scoring_func {cfg.scoring_func!r}: "
+                         "expected 'softmax' or 'sigmoid'")
+    tokens, num_experts = logits.shape
+    scores = jax.nn.sigmoid(logits)
+    choose = scores if bias is None else scores + bias.astype(jnp.float32)
+    if cfg.n_group > 1:
+        groups = choose.reshape(tokens, cfg.n_group, -1)
+        best = jax.lax.top_k(groups, 2)[0].sum(-1)           # [tokens, G]
+        _, kept = jax.lax.top_k(best, cfg.topk_group)
+        keep = jnp.zeros(best.shape, bool).at[
+            jnp.arange(tokens)[:, None], kept].set(True)
+        choose = jnp.where(keep[:, :, None], groups, -jnp.inf).reshape(
+            tokens, num_experts)
+    _, chosen = jax.lax.top_k(choose, k)
+    weights = jnp.take_along_axis(scores, chosen, axis=-1)
+    if cfg.norm_topk_prob:
+        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+    return scores, weights * cfg.routed_scaling_factor, chosen
+
+
 def moe_block(h: jax.Array, lp: Dict, cfg,
               layer: Optional[jax.Array] = None) -> Tuple[jax.Array, Dict]:
     """The expert half of a layer on normed activations `h [tokens, d]`.
@@ -87,42 +125,65 @@ def moe_block(h: jax.Array, lp: Dict, cfg,
     sliced out of the stack inside a loop is first copied: 0.8 GB a layer
     at OLMoE's sizes, 3.3 against 0.96 ms (compiler and chip, PR 27).
 
+    One chip's share of the experts (`cfg.experts_held` of `num_experts`,
+    the `cfg.expert_share`-th run of them): the router still chooses among
+    ALL experts, the stacks hold the held ones alone, and the result is the
+    part the held experts add. An assignment to an expert that is not here
+    sorts behind every held expert's rows, belongs to no group, is not
+    multiplied and adds nothing; nothing stands in for the chips that hold
+    the others or for the exchange with them. Shared experts (`shared_gate`,
+    `shared_up`, `shared_down` in `lp`) are every chip's alike and are
+    added whole.
+
     Returns `(y [tokens, d] in h's dtype, stats)`; `stats["counts"]` is the
-    assignments each expert received `[E] int32`, `stats["prob_mean"]` its
-    mean router probability `[E] float32` (what the load-balancing term is
-    made of) and `stats["experts"]` each token's choices `[tokens, k]`."""
-    from ray_tpu.models.transformer import _act
+    assignments each expert received `[E] int32`, held here or not,
+    `stats["prob_mean"]` its mean router score `[E] float32` (what the
+    load-balancing term is made of) and `stats["experts"]` each token's
+    choices `[tokens, k]`."""
+    from ray_tpu.models.transformer import _act, dense_mlp
 
     tokens = h.shape[0]
     k, num_experts = cfg.experts_per_token, cfg.num_experts
+    held = cfg.experts_held or num_experts
     with jax.named_scope("moe.route"):
         logits = jnp.dot(h, lp["router"], preferred_element_type=jnp.float32)
-        probs = jax.nn.softmax(logits, axis=-1)
-        weights, chosen = jax.lax.top_k(probs, k)            # [tokens, k]
-        if cfg.norm_topk_prob:
-            weights = weights / weights.sum(-1, keepdims=True)
+        probs, weights, chosen = route(logits, cfg, lp.get("router_bias"))
         flat = chosen.reshape(-1)
+        counts = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+        group_sizes = counts
+        if held < num_experts:
+            first = cfg.expert_share * held
+            flat = jnp.where((flat >= first) & (flat < first + held),
+                             flat - first, held)  # not here: sorts last
+            group_sizes = counts[first:first + held]
         # Assignments in expert order; ties keep token order (stable).
         order = jnp.argsort(flat, stable=True)
-        counts = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
         rows = h[order // k]                                 # [tokens*k, d]
     with jax.named_scope("moe.experts"):
         stacks = [lp[name] for name in EXPERT_LEAVES]
-        group_sizes = counts
         if layer is not None:
             depth = stacks[0].shape[0]
-            stacks = [w.reshape((depth * num_experts,) + w.shape[2:])
+            stacks = [w.reshape((depth * held,) + w.shape[2:])
                       for w in stacks]
             group_sizes = jax.lax.dynamic_update_slice(
-                jnp.zeros((depth * num_experts,), jnp.int32), counts,
-                (layer * num_experts,))
+                jnp.zeros((depth * held,), jnp.int32), group_sizes,
+                (layer * held,))
         w_gate, w_up, w_down = stacks
         inner = (_act(cfg)(grouped_matmul(rows, w_gate, group_sizes))
                  * grouped_matmul(rows, w_up, group_sizes))
         out = grouped_matmul(inner.astype(h.dtype), w_down, group_sizes)
+        if held < num_experts:
+            # Rows behind the last group are whatever the buffer held.
+            here = jnp.arange(tokens * k) < group_sizes.sum()
+            out = jnp.where(here[:, None], out, 0.0)
     with jax.named_scope("moe.combine"):
         # Back to (token, choice) order, weighted, summed over the choices.
         out = out[jnp.argsort(order)].reshape(tokens, k, -1)
         y = jnp.einsum("tk,tkd->td", weights, out).astype(h.dtype)
+    if "shared_gate" in lp:
+        with jax.named_scope("moe.shared"):
+            y = y + dense_mlp(h, {"w_gate": lp["shared_gate"],
+                                  "w_up": lp["shared_up"],
+                                  "w_down": lp["shared_down"]}, cfg)
     return y, {"counts": counts, "prob_mean": probs.mean(0),
                "experts": chosen}

@@ -577,7 +577,8 @@ class ContinuousBatchingEngine:
                  default_max_new_tokens: int = 32, seed: int = 0,
                  mesh=None, prefill_chunk: int = 64,
                  page_size: Optional[int] = None,
-                 kv_pages: Optional[int] = None):
+                 kv_pages: Optional[int] = None,
+                 max_queued: Optional[int] = None):
         """mesh: a jax.sharding.Mesh with a "tp" axis for tensor-
         parallel serving (the pods layout): pass params already sharded
         via parallel.shard_params and the engine lays the KV cache out
@@ -594,10 +595,15 @@ class ContinuousBatchingEngine:
         paged_kv): a shared page pool + block tables and a prefix cache
         back the slots. None defers to config (serve_kv_page_size,
         serve_kv_pages; kv_pages 0/None = num_slots x max_len rows and
-        the NULL page)."""
+        the NULL page).
+
+        max_queued: how many requests may wait for a slot before
+        submit() sheds; None defers to config
+        (serve_max_queued_per_engine), read at each submit."""
         self.params = params
         self.cfg = cfg
         self.num_slots = num_slots
+        self.max_queued = max_queued
         self.max_len = max_len
         self.eos_id = eos_id
         self.default_max_new_tokens = default_max_new_tokens
@@ -622,6 +628,17 @@ class ContinuousBatchingEngine:
                     "a model with recurrent layers serves on one chip: its "
                     "recurrent pool (Mamba-2 state and convolution inputs) "
                     f"is not sharded over tp={mesh.shape['tp']}"
+                )
+            if cfg.kv_lora_rank and mesh.shape["tp"] > 1:
+                raise ValueError(
+                    "a model with latent attention serves on one chip: a "
+                    "row of its pool is nothing a head, and is not sharded "
+                    f"over tp={mesh.shape['tp']}"
+                )
+            if cfg.kv_lora_rank and mesh.shape.get("pp", 1) > 1:
+                raise ValueError(
+                    "stacks of unlike layers (dense before expert layers) "
+                    f"do not split into pp={mesh.shape['pp']} stages"
                 )
         rcfg = get_config()
         self.page_size = max(
@@ -763,6 +780,7 @@ class ContinuousBatchingEngine:
         self._rows_host = np.zeros(num_slots, dtype=np.int64)
         self._attn_rows_read = 0
         self._attn_rows_held = 0
+        self._attn_rows_live = 0
         # The loop's phase ledger (loop-thread-only) and the copy the
         # loop publishes under the lock after each turn for stats().
         self._phase = _PhaseLedger()
@@ -977,6 +995,12 @@ class ContinuousBatchingEngine:
             }
 
     # -- public API ------------------------------------------------------
+    def queue_bound(self) -> int:
+        """How many requests may wait for a slot before submit() sheds."""
+        if self.max_queued is None:
+            return get_config().serve_max_queued_per_engine
+        return int(self.max_queued)
+
     def submit(self, prompt, max_new_tokens: Optional[int] = None,
                temperature: float = 0.0, top_k: Optional[int] = None,
                top_p: Optional[float] = None) -> GenerationHandle:
@@ -1026,8 +1050,9 @@ class ContinuousBatchingEngine:
                 "deadline expired before engine admission",
                 reason="deadline", rid=meta.rid if meta else "",
             )
+        max_queued = self.queue_bound()
         with self._lock:
-            if self._waiting_n >= cfg.serve_max_queued_per_engine:
+            if self._waiting_n >= max_queued:
                 # Fast shed: reject BEFORE allocating anything. The
                 # retry hint is a coarse backlog-drain estimate (queue
                 # depth over slot count, capped) — good enough to spread
@@ -1039,8 +1064,7 @@ class ContinuousBatchingEngine:
                 observatory.record_shed("", tenant, "queue_full")
                 raise ServeOverloadedError(
                     f"engine admission queue full "
-                    f"({self._waiting_n} waiting >= "
-                    f"{cfg.serve_max_queued_per_engine})",
+                    f"({self._waiting_n} waiting >= {max_queued})",
                     tenant=tenant, reason="queue_full", retry_after_s=retry,
                 )
             h = GenerationHandle(self._next_id)
@@ -1145,11 +1169,20 @@ class ContinuousBatchingEngine:
         are read too: `assignments` rows x k x layers; `calls`;
         `experts_hit_sum` and `max_load_sum` summed over calls and layers
         (over calls x layers: a layer's mean experts hit and its largest
-        expert's mean load); `per_expert [E]` assignments summed over
-        layers."""
+        expert's mean load), both of the experts held here; `per_expert
+        [E]` assignments summed over layers. For a chip's share of the
+        experts, `assignments` counts every expert the router chose and
+        `held_assignments` those to the `experts_held` of `num_experts`
+        whose weights are here, in each of `expert_layers` layers."""
         acc = jax.device_get(self._tail["moe"])
         per_layer_expert = acc["assignments"].astype(np.int64)
+        cfg = self.cfg
         return {
+            "held_assignments": int(
+                per_layer_expert[:, paged_kv.held_experts(cfg)].sum()),
+            "experts_held": cfg.held,
+            "num_experts": cfg.num_experts,
+            "expert_layers": cfg.expert_layers,
             "assignments": int(per_layer_expert.sum()),
             "calls": int(acc["calls"]),
             "experts_hit_sum": int(acc["experts_hit_sum"].sum()),
@@ -1188,12 +1221,15 @@ class ContinuousBatchingEngine:
                 "param_uploads": self._param_uploads,
                 # Cumulative over dispatched decode steps: rows in the
                 # pages decode attention was given to read (each decoding
-                # slot's length in whole pages) and rows the pool holds
-                # (slots x max_len a step), from host mirrors: nothing is
-                # fetched for it.
+                # slot's length in whole pages; for a latent pool, which no
+                # kernel reads, the rows its loop gathers:
+                # `paged_kv.latent_rows_gathered`), rows the pool holds
+                # (slots x max_len a step) and rows the live slots hold,
+                # from host mirrors: nothing is fetched for it.
                 "attention": {
                     "decode_rows_read": self._attn_rows_read,
                     "decode_rows_held": self._attn_rows_held,
+                    "decode_rows_live": self._attn_rows_live,
                 },
                 # Where the loop's time goes (EQuARX discipline — you
                 # cannot shrink a step you cannot decompose). _total
@@ -1639,8 +1675,14 @@ class ContinuousBatchingEngine:
                 # pages; the pool holds max_len rows for every slot.
                 live = list(self._slots)
                 self._rows_host[live] += 1
-                pages = -(-self._rows_host[live] // self.page_size)
-                self._attn_rows_read += int(pages.sum()) * self.page_size
+                self._attn_rows_live += int(self._rows_host[live].sum())
+                if self.cfg.kv_lora_rank:  # no kernel: what the loop gathers
+                    self._attn_rows_read += paged_kv.latent_rows_gathered(
+                        self._rows_host[live], self.num_slots,
+                        self.page_size, self._pages_per_slot)
+                else:
+                    pages = -(-self._rows_host[live] // self.page_size)
+                    self._attn_rows_read += int(pages.sum()) * self.page_size
                 self._attn_rows_held += self.num_slots * self.max_len
         new_inflight, dispatch_s, fetch_s = None, 0.0, 0.0
         if snapshot:
@@ -1859,7 +1901,8 @@ class LLMReplica:
                  default_max_new_tokens: int = 32,
                  prefill_chunk: int = 64,
                  page_size: Optional[int] = None,
-                 kv_pages: Optional[int] = None):
+                 kv_pages: Optional[int] = None,
+                 max_queued: Optional[int] = None):
         # The loader runs IN the replica process and may return
         # (params, cfg) or (params, cfg, mesh) — a Mesh cannot cross
         # the actor boundary as an argument, so tensor-parallel serving
@@ -1874,8 +1917,16 @@ class LLMReplica:
             params, cfg, num_slots=num_slots, max_len=max_len,
             eos_id=eos_id, default_max_new_tokens=default_max_new_tokens,
             mesh=mesh, prefill_chunk=prefill_chunk,
-            page_size=page_size, kv_pages=kv_pages,
+            page_size=page_size, kv_pages=kv_pages, max_queued=max_queued,
         )
+
+    @property
+    def admission_bound(self) -> int:
+        """Requests the engine itself holds before its submit() sheds:
+        every slot and its whole waiting queue. The replica around this
+        object admits up to here even where the deployment's own bound is
+        lower, since admission lives in the engine."""
+        return self.engine.num_slots + self.engine.queue_bound()
 
     def __call__(self, prompt, max_new_tokens: Optional[int] = None,
                  temperature: float = 0.0, top_k: Optional[int] = None,
@@ -1930,7 +1981,8 @@ def llm_deployment(model_loader, *, num_slots: int = 4, max_len: int = 256,
                    ray_actor_options: Optional[dict] = None,
                    prefill_chunk: int = 64,
                    page_size: Optional[int] = None,
-                   kv_pages: Optional[int] = None):
+                   kv_pages: Optional[int] = None,
+                   max_queued: Optional[int] = None):
     """A ready-to-run continuous-batching LLM application.
 
         app = llm_deployment(lambda: (params, cfg), num_slots=8)
@@ -1954,5 +2006,5 @@ def llm_deployment(model_loader, *, num_slots: int = 4, max_len: int = 256,
         model_loader, num_slots=num_slots, max_len=max_len, eos_id=eos_id,
         default_max_new_tokens=default_max_new_tokens,
         prefill_chunk=prefill_chunk,
-        page_size=page_size, kv_pages=kv_pages,
+        page_size=page_size, kv_pages=kv_pages, max_queued=max_queued,
     )

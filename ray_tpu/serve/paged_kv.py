@@ -43,6 +43,12 @@ shapes and zero steady-state host traffic:
     never invalidates the sharers; under pool pressure the cache LRU-
     releases entries back to the free list.
 
+A model with latent attention (DeepSeek-V2/V3's) keeps ONE pool: a row is
+a token's latent and its one rope key, nothing a head, and every layer
+reads it through `_latent_attention`, a loop over blocks of a slot's pages
+with a running softmax (no kernel reads such a row yet). The host half
+counts pages, not what a page holds, and is the same.
+
 Page 0 is reserved as the NULL/scratch page: block-table entries
 default to it, inactive-slot decode writes park in it, and prefill
 padding rows drop into it — it is never read unmasked, so its
@@ -71,12 +77,16 @@ from ray_tpu.models.transformer import (
     _embed_tokens,
     at_layer,
     dense_mlp,
+    expand_latent,
+    latent_layer,
+    latent_stacks,
     layer_kinds,
     project_logits,
     project_qkv,
     residual,
+    rope_tables,
 )
-from ray_tpu.ops import apply_rope, rmsnorm, rope_frequencies
+from ray_tpu.ops import apply_rope, rmsnorm
 from ray_tpu.ops.paged_attention import (
     grouped_attention,
     paged_decode_attention,
@@ -298,7 +308,11 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
     and the block table (all entries NULL_PAGE). KV heads shard over
     "tp"; everything else is replicated. A hybrid's pool has its
     attention layers only, indexed by their own count, and the cache
-    gains `rec`, the recurrent pool (`init_recurrent_pool`)."""
+    gains `rec`, the recurrent pool (`init_recurrent_pool`). A model with
+    latent attention has ONE pool, under `k`: a row is a token's latent and
+    its rope key side by side and zeros up to whole lanes
+    (`latent_row_width`), nothing a head, and `v` is None, an argument
+    without a buffer (the engine refuses such a pool under `tp` > 1)."""
     kv_layers = (cfg.layers_of("attention") if cfg.layer_pattern
                  else cfg.n_layers)
     # A row's heads side by side, one layout for every program that
@@ -307,10 +321,13 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
     # slice of the row's lanes. (Under `[.., kv_heads, head_dim]` at
     # head_dim 64 the compiler turned the whole pool into another tiling
     # between a layer's scatter and its gather.)
-    shape = (kv_layers, num_pages, page_size, cfg.n_kv_heads * cfg.head_dim)
+    latent = bool(cfg.kv_lora_rank)
+    shape = (kv_layers, num_pages, page_size,
+             latent_row_width(cfg) if latent
+             else cfg.n_kv_heads * cfg.head_dim)
     cache = {
         "k": jnp.zeros(shape, dtype=cfg.dtype),
-        "v": jnp.zeros(shape, dtype=cfg.dtype),
+        "v": None if latent else jnp.zeros(shape, dtype=cfg.dtype),
         "lengths": jnp.zeros((slots,), dtype=jnp.int32),
         "block_tables": jnp.zeros((slots, pages_per_slot),
                                   dtype=jnp.int32),
@@ -324,7 +341,8 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
         rep = NamedSharding(mesh, P())
         cache = {
             "k": jax.device_put(cache["k"], kv_sharding),
-            "v": jax.device_put(cache["v"], kv_sharding),
+            "v": (None if latent
+                  else jax.device_put(cache["v"], kv_sharding)),
             "lengths": jax.device_put(cache["lengths"], rep),
             "block_tables": jax.device_put(cache["block_tables"], rep),
         }
@@ -377,12 +395,177 @@ def _rows(new, pool):
     return new.astype(pool.dtype).reshape(new.shape[:1] + pool.shape[3:])
 
 
-def _rope_tables(cfg, max_len):
-    """(cos, sin), or (None, None) for a model without a position
-    embedding."""
-    if cfg.position_embedding_type != "rope":
-        return None, None
-    return rope_frequencies(cfg.head_dim, max_len, cfg.rope_theta)
+def latent_row_width(cfg) -> int:
+    """Values a latent pool keeps of a token in a layer: the latent and the
+    rope key side by side, and zeros up to whole lanes of 128."""
+    return -(-(cfg.kv_lora_rank + cfg.qk_rope_head_dim) // 128) * 128
+
+
+def _latent_rows(latent, k_r, pool):
+    """Rows `[R, row width]` as `pool` holds them, of latents `[.., rank]`
+    and rope keys `[.., dr]`."""
+    rows = jnp.concatenate([latent, k_r], -1)
+    rows = rows.reshape(-1, rows.shape[-1]).astype(pool.dtype)
+    return jnp.pad(rows, ((0, 0), (0, pool.shape[-1] - rows.shape[-1])))
+
+
+# Cached rows one step of `_latent_attention`'s loop gathers of each batch
+# row, at most (the size the chip's readings were taken at).
+LATENT_BLOCK_ROWS = 512
+
+
+def latent_block_pages(page_size: int, pages_per_slot: int) -> int:
+    """Pages of a slot's table that one step of `_latent_attention`'s loop
+    gathers: an eighth of the table, so that the loop overshoots the longest
+    live slot by an eighth of a slot's width at most, and no more than
+    `LATENT_BLOCK_ROWS` rows' worth, which bounds a block's scores."""
+    return max(1, min(pages_per_slot // 8, LATENT_BLOCK_ROWS // page_size))
+
+
+def latent_rows_gathered(live_rows, slots: int, page_size: int,
+                         pages_per_slot: int) -> int:
+    """Cached rows a decode step of a latent-attention model gathers in one
+    layer, from the live slots' lengths `live_rows` (each with the row the
+    step writes): every slot's rows, live or not, up to the longest live
+    slot's last block. What `engine.stats()["attention"]` counts."""
+    block = latent_block_pages(page_size, pages_per_slot) * page_size
+    width = pages_per_slot * page_size
+    return slots * min(-(-int(max(live_rows)) // block) * block,
+                       -(-width // block) * block)
+
+
+def _latent_attention(q_n, q_r, lp, pool, layer, tables, q_pos, end, cfg,
+                      absorbed: bool):
+    """Latent attention of `Q` queries a batch row against the rows its
+    table names in `pool[layer]`, where they lie: a loop over blocks of
+    cached rows (`latent_block_pages`) with a running softmax, as many steps
+    as the longest row of the batch needs and no more, so that neither the
+    rows read nor the scores held grow with the pool's width.
+
+    `q_n [B, Q, H, dn]`, `q_r [B, Q, H, dr]` (rotated); `tables [B, pages a
+    slot]`; query `q` of row `b` sees cached row `k` where `k <= q_pos[b,
+    q]` and `k < end[b]`. `absorbed`: the queries are first multiplied into
+    the latent's space (`q_n W_uk^T`, `[H, rank]` a query), set beside
+    their rope part as one vector as wide as a cached row, and scores and
+    the weighted sum are taken against the rows as cached, the values'
+    projection coming last; else each block's latents are first expanded
+    into every head's keys (the rope key set beside each) and values. The
+    same numbers either way. One product gives a block's scores in either
+    form, in the order its operands give them (`[B, Q, H, K]` absorbed,
+    where the heads are the queries' own axis; `[B, H, Q, K]` expanded,
+    where they are the keys' too), so that no score is transposed. A row
+    that sees nothing (an idle slot, an inert row) gives zeros. Returns
+    `[B, Q, H * dv]` in the queries' dtype."""
+    b, n_q, h, dn = q_n.shape
+    ps, mp, row = pool.shape[2], tables.shape[1], pool.shape[3]
+    rank, dv = cfg.kv_lora_rank, cfg.v_head_dim
+    dtype, f32 = q_n.dtype, jnp.float32
+    pages = latent_block_pages(ps, mp)
+    block, n_max = pages * ps, -(-mp // pages)
+    tables = jnp.pad(tables, ((0, 0), (0, n_max * pages - mp)))  # NULL_PAGE
+    w_uk = lp["w_uk"].reshape(rank, h, dn)
+    w_uv = lp["w_uv"].reshape(rank, h, dv)
+    scale = jnp.asarray(cfg.attention_scale, dtype)
+    if absorbed:
+        q = jnp.concatenate(
+            [jnp.einsum("bqhn,rhn->bqhr", q_n, w_uk), q_r], -1) * scale
+        q = jnp.pad(q, ((0, 0),) * 3 + ((0, row - q.shape[-1]),))
+        stat, width = (b, n_q, h), rank
+    else:
+        q = jnp.concatenate([q_n, q_r], -1) * scale
+        stat, width = (b, h, n_q), dv
+    low = jnp.asarray(-1e30, f32)
+
+    def step(j, carry):
+        top, total, acc = carry                 # stat, stat, stat + [width]
+        at = jax.lax.dynamic_slice_in_dim(tables, j * pages, pages, axis=1)
+        rows = pool[layer, at].reshape(b, block, row)
+        k_pos = j * block + jnp.arange(block, dtype=jnp.int32)
+        seen = ((k_pos <= q_pos[:, :, None])
+                & (k_pos < end[:, None, None]))              # [B, Q, K]
+        if absorbed:
+            values, seen = rows[..., :rank], seen[:, :, None]
+            scores = jnp.einsum("bqhr,bkr->bqhk", q, rows,
+                                preferred_element_type=f32)
+        else:
+            k_n, values = expand_latent(rows[..., :rank], lp, cfg)
+            k_r = rows[:, :, None, rank:rank + q_r.shape[-1]]
+            keys = jnp.concatenate(
+                [k_n, jnp.broadcast_to(k_r, k_n.shape[:3] + k_r.shape[3:])],
+                -1)
+            seen = seen[:, None]
+            scores = jnp.einsum("bqhd,bkhd->bhqk", q, keys,
+                                preferred_element_type=f32)
+        scores = jnp.where(seen, scores, low)
+        new_top = jnp.maximum(top, scores.max(-1))
+        weights = jnp.where(seen, jnp.exp(scores - new_top[..., None]), 0.0)
+        keep = jnp.exp(top - new_top)
+        added = jnp.einsum(
+            "bqhk,bkr->bqhr" if absorbed else "bhqk,bkhr->bhqr",
+            weights.astype(dtype), values, preferred_element_type=f32)
+        return (new_top, total * keep + weights.sum(-1),
+                acc * keep[..., None] + added)
+
+    with jax.named_scope("mla.attend"):
+        n_blocks = jnp.minimum(-(-jnp.max(end) // block), n_max)
+        _, total, acc = jax.lax.fori_loop(
+            0, n_blocks, step,
+            (jnp.full(stat, low), jnp.zeros(stat, f32),
+             jnp.zeros(stat + (width,), f32)))
+        out = (acc / jnp.maximum(total, 1e-30)[..., None]).astype(dtype)
+        if absorbed:
+            out = jnp.einsum("bqhr,rhv->bqhv", out, w_uv)
+        else:
+            out = out.transpose(0, 2, 1, 3)
+        return out.reshape(b, n_q, h * dv)
+
+
+def _latent_through_pool(pages_w, rows_w, tables, q_pos, end, cfg,
+                         absorbed: bool):
+    """`_walk_latent`'s `attend` of a step program: every row's latent and
+    rope key scattered into layer `i` of the one pool at `(pages_w,
+    rows_w)`, then the queries against the rows `tables` names
+    (`_latent_attention`)."""
+    def attend(i, pool, lp, q_n, q_r, latent, k_r):
+        pool = pool.at[i, pages_w, rows_w].set(_latent_rows(latent, k_r, pool))
+        return _latent_attention(q_n, q_r, lp, pool, i, tables, q_pos, end,
+                                 cfg, absorbed), pool
+
+    return attend
+
+
+def _walk_latent(params, x, pool, attend, cfg, cos, sin, positions,
+                 mesh=None):
+    """A latent-attention decoder's layers in turn, `_scan_layers` for a
+    stack a kind: the dense layers' scan, then the expert layers', the one
+    pool `[layers, ...]` riding in both carries. `attend(i, pool, lp, q_n,
+    q_r, c, k_r) -> (attention [B, L, H * dv], pool)` writes and reads the
+    pool at layer `i`. The expert stacks stay out of the scan and are read
+    whole at the layer's index within its kind (`moe_block`). Returns x,
+    the pool and the assignments each expert layer's experts received
+    `[expert layers, E]` (None without experts)."""
+    counts = None
+    for kind, stack, first in latent_stacks(params):
+        experts = ({n: stack[n] for n in EXPERT_LEAVES}
+                   if kind == "moe" else {})
+        scanned = {n: w for n, w in stack.items() if n not in experts}
+
+        def layer(carry, inputs, experts=experts, first=first):
+            x, pool = carry
+            lp, j = inputs
+            lp = {**lp, **experts}
+            x, routing, pool = latent_layer(
+                x, lp, cfg, cos, sin, positions,
+                functools.partial(attend, first + j, pool, lp), mesh,
+                j if experts else None)
+            return (x, pool), (None if routing is None
+                               else routing["counts"])
+
+        depth = scanned["attn_norm"].shape[0]
+        (x, pool), got = jax.lax.scan(
+            layer, (x, pool), (scanned, jnp.arange(depth, dtype=jnp.int32)))
+        counts = got if got is not None else counts
+    return x, pool, counts
 
 
 def _walk_hybrid(params, x, k_cache, v_cache, rec, attend, rec_io,
@@ -521,26 +704,36 @@ def init_routing_counters(cfg: TransformerConfig) -> Dict:
     """The device-resident accumulator of a model with experts: what its
     step programs add to at every call and `engine.stats()["moe"]` fetches,
     so that nothing about routing leaves the device inside the loop."""
-    per_layer = jnp.zeros((cfg.n_layers,), jnp.int32)
+    per_layer = jnp.zeros((cfg.expert_layers,), jnp.int32)
     return {
-        "assignments": jnp.zeros((cfg.n_layers, cfg.num_experts), jnp.int32),
+        "assignments": jnp.zeros((cfg.expert_layers, cfg.num_experts),
+                                 jnp.int32),
         "calls": jnp.zeros((), jnp.int32),
         "experts_hit_sum": per_layer,
         "max_load_sum": per_layer,
     }
 
 
-def _count_routing(out, moe, counts):
+def held_experts(cfg: TransformerConfig) -> slice:
+    """The run of a layer's routed experts whose weights are here."""
+    return slice(cfg.expert_share * cfg.held,
+                 (cfg.expert_share + 1) * cfg.held)
+
+
+def _count_routing(out, moe, counts, cfg):
     """A step program's results with the routing accumulator `moe`
-    advanced by this call's `counts [layers, E]` appended; a caller that
-    passed no accumulator gets `out` as it is."""
+    advanced by this call's `counts [expert layers, E]` appended; a caller
+    that passed no accumulator gets `out` as it is. Assignments are counted
+    for every expert the router can choose; experts hit and the largest
+    load are of the experts held here, whose weights the call read."""
     if moe is None:
         return out
+    here = counts[:, held_experts(cfg)]
     return (*out, {
         "assignments": moe["assignments"] + counts,
         "calls": moe["calls"] + 1,
-        "experts_hit_sum": moe["experts_hit_sum"] + (counts > 0).sum(-1),
-        "max_load_sum": moe["max_load_sum"] + counts.max(-1),
+        "experts_hit_sum": moe["experts_hit_sum"] + (here > 0).sum(-1),
+        "max_load_sum": moe["max_load_sum"] + here.max(-1),
     })
 
 
@@ -660,7 +853,7 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
     ps = k_pages.shape[2]
     mp = block_tables.shape[1]
     x = _embed_tokens(params, tokens[:, None], cfg)  # [S, 1, d]
-    cos, sin = _rope_tables(cfg, max_len)
+    cos, sin = rope_tables(cfg, max_len)
     positions = lengths[:, None]
     pos_w = jnp.where(active, jnp.minimum(lengths, max_len - 1), 0)
     page_of = jnp.minimum(pos_w // ps, mp - 1)
@@ -682,7 +875,15 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
             mesh=mesh)
         return kc, vc, attn[:, None]
 
-    if rec is None:
+    if cfg.kv_lora_rank:
+        # One row a slot into the one pool, then the absorbed form against
+        # the slot's live blocks.
+        x, k_new, counts = _walk_latent(
+            params, x, k_pages, _latent_through_pool(
+                pages_w, rows_w, block_tables, positions, rows_att, cfg,
+                absorbed=True), cfg, cos, sin, positions, mesh)
+        v_new = None
+    elif rec is None:
         x, k_new, v_new, counts = _scan_layers(
             params, x, k_pages, v_pages, attend, cfg, cos, sin, positions,
             mesh,
@@ -706,7 +907,7 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
     else:
         next_tokens = _pick_tokens(logits, temps, top_ks, top_ps, key)
     out = _count_routing((next_tokens, k_new, v_new, new_lengths), moe,
-                         counts)
+                         counts, cfg)
     return _with_recurrent(out, rec, rec_count,
                            decode_rows_live=active.sum(dtype=jnp.int32),
                            decode_rows_computed=s_)
@@ -762,7 +963,7 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     width = mp * ps
     kvh, hd = cfg.n_kv_heads, cfg.head_dim
     x = _embed_tokens(params, tokens, cfg)
-    cos, sin = _rope_tables(cfg, max_len)
+    cos, sin = rope_tables(cfg, max_len)
     positions = offset[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
     end = (offset + n_valid)[:, None]                           # [P, 1]
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (p_, c, width), 2)
@@ -784,7 +985,17 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
             q, k_att.astype(jnp.float32), v_att.astype(jnp.float32), valid,
             cfg.attention_scale)
 
-    if rec is None:
+    if cfg.kv_lora_rank:
+        # The expanded form: a chunk's scores and weighted sum are as wide
+        # as a head, not as a cached row, and that outweighs expanding each
+        # block (on the chip a pass took 38.9 ms against 49.0 absorbed).
+        x, k_new, counts = _walk_latent(
+            params, x, k_pages, _latent_through_pool(
+                pages_w, rows_w, bt_rows, positions, end[:, 0], cfg,
+                absorbed=False),
+            cfg, cos, sin, positions, mesh)
+        v_new = None
+    elif rec is None:
         x, k_new, v_new, counts = _scan_layers(
             params, x, k_pages, v_pages, attend, cfg, cos, sin, positions,
             mesh,
@@ -821,7 +1032,8 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
         new_lengths = jnp.where(
             real[r], new_lengths.at[slot[r]].set(offset[r] + n_valid[r]),
             new_lengths)
-    out = _count_routing((logits, k_new, v_new, new_lengths), moe, counts)
+    out = _count_routing((logits, k_new, v_new, new_lengths), moe, counts,
+                         cfg)
     return _with_recurrent(out, rec, rec_count,
                            prefill_tokens_valid=n_valid.sum(),
                            prefill_tokens_computed=p_ * c)
@@ -831,5 +1043,6 @@ def cow_copy_page(k_pages, v_pages, src, dst):
     """Copy one page's rows across all layers (the copy-on-write fork).
     Jitted by the engine with donated buffers so it runs in place."""
     k_pages = k_pages.at[:, dst].set(k_pages[:, src])
-    v_pages = v_pages.at[:, dst].set(v_pages[:, src])
+    if v_pages is not None:  # a latent pool is one
+        v_pages = v_pages.at[:, dst].set(v_pages[:, src])
     return k_pages, v_pages

@@ -109,8 +109,12 @@ class ReplicaActor:
                 reason="deadline", app=self._app_name, rid=meta.rid,
             )
         if self._max_ongoing > 0:
-            bound = (self._max_ongoing
-                     + get_config().serve_max_queued_per_replica)
+            # A callable with bounded admission of its own (an LLM
+            # replica's engine: slots and a waiting queue, shed at
+            # submit()) is not cut off below what it would take itself.
+            bound = max(self._max_ongoing
+                        + get_config().serve_max_queued_per_replica,
+                        int(getattr(self.callable, "admission_bound", 0)))
             with self._lock:
                 cur = self.ongoing
             if cur >= bound:

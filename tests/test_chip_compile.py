@@ -491,3 +491,66 @@ def test_hybrid_step_updates_both_pools_in_place(v5e, program, rows,
         if a.size > 2**24]  # not the 9 MB of dt columns, prefetched whole
     for dims in whole:
         assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
+
+
+@pytest.mark.parametrize("program", ["decode_paged", "prefill_chunk_paged"])
+def test_latent_pool_steps_fit_and_leave_the_pool_where_it_lies(
+        v5e, program, monkeypatch):
+    """The cell `serve-mla-docqa-closed`'s step programs at its own sizes
+    (one chip's share of dots.vlm1.inst's language model, 1 dense + 5
+    expert layers, 64 slots x 4096, a pass of one row of 512): they fit
+    the chip beside 13.02 GB of arguments, the one pool of 640-wide rows
+    is updated where it lies (at the 576 values a row needs, the compiler
+    re-laid the whole pool at the step's start and end: 2 GB of
+    temporaries and two copies of 1.8 GB a step), and the held experts'
+    stacks are read in place by the three grouped products."""
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.serve import paged_kv
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e[0])
+    cfg = dataclasses.replace(configs.get_config("dots-vlm1-ep16"),
+                              n_layers=6, remat=False)
+    slots, max_len, page, chunk = 64, 4096, 16, 512
+    per_slot = max_len // page
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    def struct(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, slots, slots * per_slot + 1, page, per_slot)))
+    assert cache["v"] is None and cache["k"].shape[-1] == 640
+    moe = on_chip(jax.eval_shape(
+        lambda: paged_kv.init_routing_counters(cfg)))
+    if program == "decode_paged":
+        fn = lambda p, t, k, ln, a, bt, tp, tk, tpp, key, moe: (  # noqa: E731
+            paged_kv.decode_paged(p, t, k, None, ln, a, bt, tp, tk, tpp, key,
+                                  cfg, max_len, moe=moe))
+        args = (params, struct((slots,)), cache["k"], cache["lengths"],
+                struct((slots,), jnp.bool_), cache["block_tables"],
+                struct((slots,), jnp.float32), struct((slots,)),
+                struct((slots,), jnp.float32), struct((2,), jnp.uint32), moe)
+        donated = (2,)
+    else:
+        fn = lambda p, t, n, s, o, k, ln, bt, moe: (  # noqa: E731
+            paged_kv.prefill_chunk_paged(p, t, n, s, o, k, None, ln, bt, cfg,
+                                         max_len, moe=moe))
+        row = struct((1,))
+        args = (params, struct((1, chunk)), row, row, row, cache["k"],
+                cache["lengths"], cache["block_tables"], moe)
+        donated = (5,)
+    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    assert 12.9e9 < memory.argument_size_in_bytes < 13.1e9
+    assert memory.temp_size_in_bytes < 0.5e9  # the pool is 2.01 GB
+    text = compiled.as_text()
+    pool = ",".join(map(str, cache["k"].shape))
+    assert not re.findall(rf"= bf16\[{pool}\]\S* copy\(", text)
+    assert len(re.findall(r"%gmm[.\d]* = f32\[", text)) == 3
+    assert not re.findall(r"= bf16\[(?:80|5,16),7168,2048\]\S* copy\(", text)

@@ -366,3 +366,60 @@ def test_engine_counts_every_assignment_and_fetches_them_in_stats_only():
         assert "moe" not in dense.stats()
     finally:
         dense.shutdown()
+
+
+def _block_as_it_was(h, lp, cfg, layer=None):
+    """`moe_block` as PR 51 left it (softmax scores, every expert held,
+    none shared), kept here word for word as the yardstick of `unchanged`."""
+    from ray_tpu.models.transformer import _act
+    from ray_tpu.parallel.moe import EXPERT_LEAVES, grouped_matmul
+
+    tokens = h.shape[0]
+    k, num_experts = cfg.experts_per_token, cfg.num_experts
+    logits = jnp.dot(h, lp["router"], preferred_element_type=jnp.float32)
+    probs = jax.nn.softmax(logits, axis=-1)
+    weights, chosen = jax.lax.top_k(probs, k)
+    if cfg.norm_topk_prob:
+        weights = weights / weights.sum(-1, keepdims=True)
+    flat = chosen.reshape(-1)
+    order = jnp.argsort(flat, stable=True)
+    counts = jnp.bincount(flat, length=num_experts).astype(jnp.int32)
+    rows = h[order // k]
+    stacks = [lp[name] for name in EXPERT_LEAVES]
+    group_sizes = counts
+    if layer is not None:
+        depth = stacks[0].shape[0]
+        stacks = [w.reshape((depth * num_experts,) + w.shape[2:])
+                  for w in stacks]
+        group_sizes = jax.lax.dynamic_update_slice(
+            jnp.zeros((depth * num_experts,), jnp.int32), counts,
+            (layer * num_experts,))
+    w_gate, w_up, w_down = stacks
+    inner = (_act(cfg)(grouped_matmul(rows, w_gate, group_sizes))
+             * grouped_matmul(rows, w_up, group_sizes))
+    out = grouped_matmul(inner.astype(h.dtype), w_down, group_sizes)
+    out = out[jnp.argsort(order)].reshape(tokens, k, -1)
+    return jnp.einsum("tk,tkd->td", weights, out).astype(h.dtype), counts
+
+
+@pytest.mark.parametrize("renormalised", [False, True])
+@pytest.mark.parametrize("in_place", [False, True])
+def test_the_softmax_block_of_a_whole_model_is_unchanged_bit_for_bit(
+        renormalised, in_place):
+    """What a held share, sigmoid scores and a shared expert added to the
+    block changes no bit of a model that has none of them."""
+    cfg = replace(CFG, norm_topk_prob=renormalised)
+    params = seeded_params()
+    h = jax.random.normal(jax.random.PRNGKey(11), (24, cfg.d_model))
+    if in_place:
+        lp = dict(layer_of(params, 1),
+                  **{n: params["layers"][n] for n in
+                     ("w_gate", "w_up", "w_down")})
+        args = (h, lp, cfg, jnp.int32(1))
+    else:
+        args = (h, layer_of(params, 1), cfg)
+    want, want_counts = jax.jit(_block_as_it_was, static_argnums=2)(*args)
+    got, stats = jax.jit(moe_block, static_argnums=2)(*args)
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    np.testing.assert_array_equal(np.asarray(stats["counts"]),
+                                  np.asarray(want_counts))

@@ -492,7 +492,7 @@ def test_decode_through_the_kernel_gives_the_plain_forms_tokens(
 
 # -- a prefill pass is one program over rows ------------------------------
 PASS_MODELS = {"dense": "tiny_qwen", "expert": "tiny_olmoe",
-               "hybrid": "tiny_granite_h"}
+               "hybrid": "tiny_granite_h", "latent": "tiny_dots"}
 PASS_SLOTS, PASS_PAGE, PASS_PAGES, PASS_LEN, PASS_CHUNK = 3, 4, 8, 32, 8
 
 
@@ -579,9 +579,12 @@ def pass_bench(request, pass_benches):
     return pass_benches[request.param]
 
 
-# The models whose cache is pages of keys and values alone.
+# The models whose cache is pages of keys and values alone, and those
+# whose cache is pages alone (a latent pool is one pool of rows).
 paged_alone = pytest.mark.parametrize("pass_bench", ["dense", "expert"],
                                       indirect=True)
+pages_alone = pytest.mark.parametrize(
+    "pass_bench", ["dense", "expert", "latent"], indirect=True)
 
 
 def _prompt(n, seed):
@@ -595,6 +598,9 @@ def _same_cache(got, want, exact=False):
              lambda a, b, err_msg: np.testing.assert_allclose(
                  a, b, rtol=2e-4, atol=2e-5, err_msg=err_msg))
     for name in ("k", "v"):
+        if want[name] is None:  # a latent pool is one
+            assert got[name] is None
+            continue
         close(np.asarray(got[name])[:, 1:], np.asarray(want[name])[:, 1:],
               err_msg=name)
     np.testing.assert_array_equal(np.asarray(got["lengths"]),
@@ -623,7 +629,7 @@ def test_a_pass_of_rows_is_the_same_chunks_one_call_at_a_time(pass_bench):
         # Programs are counted, and every row they computed is routed.
         assert int(got["moe"]["calls"]) == 2 and int(want["moe"]["calls"]) == 4
         per_row = (PASS_CHUNK * bench.cfg.experts_per_token
-                   * bench.cfg.n_layers)
+                   * bench.cfg.expert_layers)
         assert int(got["moe"]["assignments"].sum()) == (1 + 4) * per_row
     if "rec_count" in got:
         counted = {n: int(c) for n, c in got["rec_count"].items()}
@@ -632,7 +638,7 @@ def test_a_pass_of_rows_is_the_same_chunks_one_call_at_a_time(pass_bench):
         assert counted["prefill_tokens_computed"] == (1 + 4) * PASS_CHUNK
 
 
-@paged_alone
+@pages_alone
 def test_two_rows_of_a_pass_are_two_chunks_of_one_prompt(pass_bench):
     """A model whose cache is pages alone: two consecutive chunks of one
     prompt as two rows of one pass against two calls. Every layer
@@ -1057,3 +1063,23 @@ def test_handle_affinity_prefers_covering_replica():
     with s["lock"]:
         s["prefix"] = {}
     assert h._route_key((list(range(16)),)) is None
+
+
+def test_a_latent_pools_page_forks_without_a_second_pool():
+    """Copy-on-write over ONE pool: the page's rows in every layer, and
+    None where a second pool would be."""
+    import jax.numpy as jnp
+
+    from ray_tpu.models import configs
+
+    cfg = configs.get_config("tiny_dots")
+    cache = paged_kv.init_paged_cache(cfg, 2, 5, 4, 2)
+    width = paged_kv.latent_row_width(cfg)
+    pool = jnp.arange(cfg.n_layers * 5 * 4 * width, dtype=jnp.float32
+                      ).reshape(cache["k"].shape)
+    forked, none = paged_kv.cow_copy_page(pool, cache["v"], 3, 1)
+    assert none is None
+    np.testing.assert_array_equal(np.asarray(forked[:, 1]),
+                                  np.asarray(pool[:, 3]))
+    np.testing.assert_array_equal(np.asarray(forked[:, 2:]),
+                                  np.asarray(pool[:, 2:]))

@@ -135,6 +135,50 @@ def test_engine_admission_shed_wfq_and_deadline(cfg_override, monkeypatch):
         eng.shutdown()
 
 
+def test_engine_queue_bound_of_its_own_and_the_replica_admits_up_to_it():
+    """An engine built with `max_queued` sheds at ITS bound, whatever the
+    process-wide default says, and the replica around an LLM replica
+    admits up to what the engine takes (slots + its queue) where the
+    deployment's own bound is lower: 128 callers on 64 slots wait in the
+    engine's queue, and are not shed by the router at 64 + 32."""
+    from ray_tpu.serve.llm import LLMReplica
+    from ray_tpu.serve.replica import ReplicaActor
+
+    params, cfg = _tiny_model()
+    rep = ReplicaActor._cls(
+        LLMReplica, (lambda: (params, cfg),),
+        {"num_slots": 1, "max_len": 512, "max_queued": 2},
+        app_name="bound", max_ongoing=1)
+    eng, handles = rep.callable.engine, []
+    try:
+        assert get_config().serve_max_queued_per_engine > 2
+        assert rep.callable.admission_bound == 3
+        handles.append(eng.submit([3, 7, 11], max_new_tokens=256))
+        deadline = time.time() + 60
+        while eng.stats()["active"] < 1:
+            assert time.time() < deadline, "slot was never granted"
+            time.sleep(0.01)
+        handles += [eng.submit([1, 2], max_new_tokens=1) for _ in range(2)]
+        with pytest.raises(ServeOverloadedError, match="2 waiting >= 2"):
+            eng.submit([1, 2], max_new_tokens=1)
+        # The replica's gate: max_ongoing 1 + the default 32 queued, as it
+        # was, since the engine's own 3 is lower; with a longer queue the
+        # engine's bound is the gate.
+        meta = request_context.RequestMeta()
+        rep.ongoing = 1 + get_config().serve_max_queued_per_replica
+        with pytest.raises(ServeOverloadedError):
+            rep._admit(meta)
+        eng.max_queued = 64
+        rep._admit(meta)
+        rep.ongoing = 1 + 64
+        with pytest.raises(ServeOverloadedError):
+            rep._admit(meta)
+    finally:
+        for h in handles:
+            h.cancel()
+        eng.shutdown()
+
+
 # -- admission control at the handle ------------------------------------
 
 def test_handle_shed_is_synchronous_and_typed(serve_session, cfg_override):

@@ -435,7 +435,8 @@ def test_attention_counters_follow_the_decoding_slots():
                                    page_size=4)
     try:
         assert eng.stats()["attention"] == {
-            "decode_rows_read": 0, "decode_rows_held": 0}
+            "decode_rows_read": 0, "decode_rows_held": 0,
+            "decode_rows_live": 0}
         eng.submit([3, 7, 11], max_new_tokens=6).result(timeout=180)
         first = eng.stats()["attention"]
         # Lengths 3..7 at the five steps that gave tokens 2..6, each with
@@ -444,6 +445,8 @@ def test_attention_counters_follow_the_decoding_slots():
         steps, extra = divmod(first["decode_rows_held"], 2 * 64)
         assert extra == 0 and steps in (5, 6)
         assert first["decode_rows_read"] == 36 + 12 * (steps - 5)
+        # The rows themselves, each step's with the one it writes.
+        assert first["decode_rows_live"] == 30 + 9 * (steps - 5)
         eng.submit(list(range(1, 30)), max_new_tokens=4).result(timeout=180)
         second = eng.stats()["attention"]
         assert second["decode_rows_read"] > first["decode_rows_read"]
