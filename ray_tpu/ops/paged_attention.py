@@ -23,6 +23,16 @@ block is computed.
 On other backends the plain form runs: the slot's whole table gathered,
 cast and scored, masked by the count (`grouped_attention`, which the
 prefill program uses too).
+
+A LATENT pool `[layers, pages, page_size, row]` (DeepSeek-V2/V3's: a row
+is a token's latent and its one rope key, nothing a head, and there is
+no pool of values) has a kernel of its own, `latent_decode_attention`,
+that shares this one's walk and nothing of its body: a slot's heads are
+the rows of both products (128 of them where a group of queries is 8),
+a block of pages lands in VMEM once and is keys in all its lanes and
+values in its first `rank`, and queries and results pass a slot at a
+time (all slots' would not fit VMEM). Which kernel runs is what the
+caller holds: a pool of keys and one of values a head, or the one pool.
 """
 
 from __future__ import annotations
@@ -325,3 +335,233 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     heads, pool = P(None, "tp", None), P(None, None, None, "tp")
     return per_shard(kernel, mesh, (heads, pool, pool, P(), P(), P()),
                      heads)(q, k_pool, v_pool, layer, block_tables, rows)
+
+
+def latent_kernel_takes(pool, heads: int, rank: int) -> bool:
+    """Whether the latent kernel can read this pool `[layers, pages, page,
+    row]`: a page is whole tiles of the pool's dtype, a row whole lanes,
+    the latent (a row's first `rank` values, which are the values too)
+    whole lanes of it, and a slot's heads whole sublanes of its queries."""
+    _, _, page_size, row = pool.shape
+    sublanes = 8 * 4 // jnp.dtype(pool.dtype).itemsize
+    return (page_size % sublanes == 0 and heads % sublanes == 0
+            and row % _LANES == 0 and rank % _LANES == 0 and 0 < rank <= row)
+
+
+# Rows a block of the latent kernel holds at most (two buffers of 1024
+# rows of 640 bf16 lanes are 2.6 MB, a block's float32 scores 0.5 MB), and
+# the widths its scores are worked out at, in quarters of a block. From
+# the chip at the cell's sizes (PERF.md section 6, PR 53): six layers over
+# 67,566 live rows took 1.80 ms in blocks of 512 rows, 1.59 in 1024 and
+# 1.53 in 2048 (a block's fixed costs are paid as often as there are
+# blocks, and a wider quarter computes more rows past a sequence's end);
+# eight widths read what four did.
+_LATENT_BLOCK_ROWS = 1024
+_LATENT_TILES = 4
+
+
+def _latent_kernel(layer_ref, rows_ref, tables_ref, q_ref, pool_hbm, o_ref,
+                   buf, sems, held_ref, m_ref, l_ref, acc_ref, *, rank: int,
+                   ppb: int):
+    """One slot a grid step, its blocks of `ppb` pages inside that.
+
+    layer_ref [1], rows_ref [S] (rows a slot attends to) and tables_ref
+    [S * pages_per_slot] are in SMEM. q_ref [H, row]: the slot's absorbed
+    queries, scaled, as wide as a row of the pool, in the pool's dtype;
+    o_ref [H, rank]. pool_hbm: the pool where it lies. buf [2, T, row]: a
+    block's rows, keys in all their lanes and values in the first `rank`,
+    one buffer filled while the other is computed on, the next live
+    slot's first block while this slot's last is. held_ref [1] in SMEM:
+    the buffer this slot's first block is in. m_ref, l_ref [H, 128] and
+    acc_ref [H, rank]: the slot's running softmax."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s, slots = pl.program_id(0), pl.num_programs(0)
+    page_size = pool_hbm.shape[2]
+    per_slot = tables_ref.shape[0] // rows_ref.shape[0]
+    block_rows = ppb * page_size
+    layer = layer_ref[0]
+    rows = rows_ref[s]
+    # Pages started, and waited for, at a stretch: 128 rows' worth. The
+    # scalar unit issues a page's DMA in about 25 ns where it has several
+    # to schedule together and 45 ns one at a time, wait included, and
+    # issuing is not hidden behind the products (PERF.md section 6, PR 53).
+    group = _LANES // page_size if _LANES % page_size == 0 else 1
+
+    def next_live(t):
+        """The first slot from `t` on that has rows, or `slots`."""
+        return jax.lax.while_loop(
+            lambda t: jnp.logical_and(
+                t < slots, rows_ref[jnp.minimum(t, slots - 1)] == 0),
+            lambda t: t + 1, t)
+
+    def block_copies(t, b, at, start: bool):
+        """The DMAs of every live page of block `b` of slot `t` into
+        buffer `at`, started or waited for: whole groups of pages first,
+        a group's DMAs issued as straight-line code and waited for as the
+        bytes they bring (a buffer's DMAs share one semaphore, which
+        counts bytes), then the pages left, one at a time."""
+        first = b * ppb
+
+        def page_copy(j):
+            page = tables_ref[t * per_slot + first + j]
+            dst = pl.ds(pl.multiple_of(j * page_size, page_size), page_size)
+            return pltpu.make_async_copy(
+                pool_hbm.at[layer, page], buf.at[at, dst], sems.at[at])
+
+        def a_group(i, _):
+            if start:
+                for k in range(group):
+                    page_copy(i * group + k).start()
+            else:
+                span = group * page_size
+                dst = pl.ds(pl.multiple_of(i * span, span), span)
+                pltpu.make_async_copy(buf.at[1 - at, dst], buf.at[at, dst],
+                                      sems.at[at]).wait()
+
+        def a_page(j, _):
+            if start:
+                page_copy(j).start()
+            else:
+                page_copy(j).wait()
+
+        live = jnp.minimum(pl.cdiv(rows_ref[t], page_size) - first, ppb)
+        jax.lax.fori_loop(0, live // group, a_group, None)
+        jax.lax.fori_loop(live // group * group, live, a_page, None)
+
+    def start_if_any(t, b, at):
+        @pl.when(t < slots)
+        def _():
+            block_copies(jnp.minimum(t, slots - 1), b, at, start=True)
+
+    @pl.when(s == 0)
+    def _():
+        # A row no DMA has written meets a probability of exactly 0, and
+        # what VMEM held before may be a NaN's bits.
+        buf[...] = jnp.zeros_like(buf)
+        held_ref[0] = 0
+        start_if_any(next_live(0), 0, 0)
+
+    @pl.when(rows == 0)
+    def _():
+        o_ref[...] = jnp.zeros_like(o_ref)
+
+    # As in `_kernel`: the scores over as many whole tiles of keys as a
+    # block's live rows reach, each count a straight-line program; a
+    # quarter of a block a tile, so that there are four whatever the
+    # block.
+    tile = block_rows // _LATENT_TILES
+    tile = tile if tile % _LANES == 0 else block_rows
+
+    def scores_over(b, at, width):
+        """The first `width` rows of the block into the running softmax:
+        the heads are the rows of both products."""
+        k_pos = b * block_rows + jax.lax.broadcasted_iota(
+            jnp.int32, (q_ref.shape[0], width), 1)
+        live = k_pos < rows
+        scores = jax.lax.dot_general(
+            q_ref[...], buf[at, pl.ds(0, width), :], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+        scores = jnp.where(live, scores, NEG_INF)
+        m_old = m_ref[:, :1]
+        m_new = jnp.maximum(m_old, scores.max(axis=-1, keepdims=True))
+        p = jnp.where(live, jnp.exp(scores - m_new), 0.0)
+        fade = jnp.exp(m_old - m_new)
+        l_new = fade * l_ref[:, :1] + p.sum(axis=-1, keepdims=True)
+        acc_ref[...] = fade * acc_ref[...] + jnp.dot(
+            p.astype(buf.dtype), buf[at, pl.ds(0, width), pl.ds(0, rank)],
+            preferred_element_type=jnp.float32)
+        m_ref[...] = jnp.broadcast_to(m_new, m_ref.shape)
+        l_ref[...] = jnp.broadcast_to(l_new, l_ref.shape)
+
+    @pl.when(rows > 0)
+    def _():
+        n_blocks = pl.cdiv(rows, block_rows)
+        m_ref[...] = jnp.full_like(m_ref, NEG_INF)
+        l_ref[...] = jnp.zeros_like(l_ref)
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+        def block(b, at):
+            """Block `b` from buffer `at`, the one after it (the slot's
+            next, else the next live slot's first) started into the
+            other."""
+            last = b + 1 >= n_blocks
+            start_if_any(
+                jax.lax.cond(last, lambda: next_live(s + 1), lambda: s),
+                jnp.where(last, 0, b + 1), 1 - at)
+            block_copies(s, b, at, start=False)
+            in_block = jnp.minimum(rows - b * block_rows, block_rows)
+            for tiles in range(1, block_rows // tile + 1):
+                pl.when(pl.cdiv(in_block, tile) == tiles)(functools.partial(
+                    scores_over, b, at, tiles * tile))
+            return 1 - at
+
+        held_ref[0] = jax.lax.fori_loop(0, n_blocks, block, held_ref[0])
+        o_ref[...] = (acc_ref[...] / l_ref[:, :1]).astype(o_ref.dtype)
+
+
+def latent_pages_per_block(page_size: int, pages_per_slot: int) -> int:
+    """Pages a block of the latent kernel holds: `_LATENT_BLOCK_ROWS` rows'
+    worth, and no more than a slot has."""
+    return max(1, min(_LATENT_BLOCK_ROWS // page_size, pages_per_slot))
+
+
+def _latent_pallas(q, pool, layer, block_tables, rows, *, rank: int,
+                   interpret: bool = False):
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    s_, h, row = q.shape
+    page_size = pool.shape[2]
+    ppb = latent_pages_per_block(page_size, block_tables.shape[1])
+    # The result as the loop's weighted sum is shaped, `[S, 1, H, rank]`:
+    # in a trace an operation goes by its name and its first result's
+    # shape, and the benchmark's readers find the attention by that.
+    return pl.pallas_call(
+        functools.partial(_latent_kernel, rank=rank, ppb=ppb),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=3,
+            grid=(s_,),
+            in_specs=[
+                pl.BlockSpec((None, h, row), lambda s, *_: (s, 0, 0)),
+                pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((None, None, h, rank),
+                                   lambda s, *_: (s, 0, 0, 0)),
+            scratch_shapes=[
+                pltpu.VMEM((2, ppb * page_size, row), pool.dtype),
+                pltpu.SemaphoreType.DMA((2,)),
+                pltpu.SMEM((1,), jnp.int32),
+                pltpu.VMEM((h, _LANES), jnp.float32),
+                pltpu.VMEM((h, _LANES), jnp.float32),
+                pltpu.VMEM((h, rank), jnp.float32),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((s_, 1, h, rank), q.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=pltpu.InterpretParams() if interpret else False,
+        name="latent_decode_attention",
+    )(jnp.reshape(layer, (1,)).astype(jnp.int32), rows.astype(jnp.int32),
+      block_tables.reshape(-1).astype(jnp.int32), q.astype(pool.dtype), pool)
+
+
+def latent_decode_attention(q: jax.Array, pool: jax.Array, layer,
+                            block_tables: jax.Array, rows: jax.Array,
+                            rank: int, interpret: bool = False,
+                            mesh=None) -> jax.Array:
+    """Every slot's absorbed queries against its own rows of one layer of
+    a latent pool, the kernel: q [S, H, row] (a head's query multiplied
+    into the latent's space beside its rope part, scaled, zeros up to the
+    pool's row); pool [layers, pages, page_size, row], a row's first
+    `rank` values the latent, which is key and value both; `layer` a
+    scalar; block_tables [S, pages_per_slot]; rows [S], how many rows from
+    the table's start a slot attends to (0: none, and the slot's result
+    is zeros). Returns [S, 1, H, rank] in q's dtype: the weighted sum of
+    the latents, the values' projection left to the caller.
+
+    For a pool `latent_kernel_takes`. mesh: under a sharded jit, the
+    engine's: every operand is whole on every device."""
+    kernel = functools.partial(_latent_pallas, rank=rank, interpret=interpret)
+    return per_shard(kernel, mesh, (P(),) * 5, P())(
+        q, pool, layer, block_tables, rows)

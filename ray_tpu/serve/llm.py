@@ -1221,11 +1221,10 @@ class ContinuousBatchingEngine:
                 "param_uploads": self._param_uploads,
                 # Cumulative over dispatched decode steps: rows in the
                 # pages decode attention was given to read (each decoding
-                # slot's length in whole pages; for a latent pool, which no
-                # kernel reads, the rows its loop gathers:
-                # `paged_kv.latent_rows_gathered`), rows the pool holds
-                # (slots x max_len a step) and rows the live slots hold,
-                # from host mirrors: nothing is fetched for it.
+                # slot's length in whole pages, a latent pool's too), rows
+                # the pool holds (slots x max_len a step) and rows the
+                # live slots hold, from host mirrors: nothing is fetched
+                # for it.
                 "attention": {
                     "decode_rows_read": self._attn_rows_read,
                     "decode_rows_held": self._attn_rows_held,
@@ -1676,13 +1675,8 @@ class ContinuousBatchingEngine:
                 live = list(self._slots)
                 self._rows_host[live] += 1
                 self._attn_rows_live += int(self._rows_host[live].sum())
-                if self.cfg.kv_lora_rank:  # no kernel: what the loop gathers
-                    self._attn_rows_read += paged_kv.latent_rows_gathered(
-                        self._rows_host[live], self.num_slots,
-                        self.page_size, self._pages_per_slot)
-                else:
-                    pages = -(-self._rows_host[live] // self.page_size)
-                    self._attn_rows_read += int(pages.sum()) * self.page_size
+                pages = -(-self._rows_host[live] // self.page_size)
+                self._attn_rows_read += int(pages.sum()) * self.page_size
                 self._attn_rows_held += self.num_slots * self.max_len
         new_inflight, dispatch_s, fetch_s = None, 0.0, 0.0
         if snapshot:

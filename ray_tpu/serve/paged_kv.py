@@ -45,9 +45,13 @@ shapes and zero steady-state host traffic:
 
 A model with latent attention (DeepSeek-V2/V3's) keeps ONE pool: a row is
 a token's latent and its one rope key, nothing a head, and every layer
-reads it through `_latent_attention`, a loop over blocks of a slot's pages
-with a running softmax (no kernel reads such a row yet). The host half
-counts pages, not what a page holds, and is the same.
+reads it through `_latent_attention`. A decode step's one query a slot
+meets the rows as cached (the absorbed form): on the chip a kernel
+fetches the pages that hold a slot's live rows and no others
+(`ops.paged_attention.latent_decode_attention`), elsewhere a loop over
+blocks of every slot's pages with a running softmax; a prefill pass
+keeps that loop, each block expanded into every head's keys and values.
+The host half counts pages, not what a page holds, and is the same.
 
 Page 0 is reserved as the NULL/scratch page: block-table entries
 default to it, inactive-slot decode writes park in it, and prefill
@@ -89,6 +93,8 @@ from ray_tpu.models.transformer import (
 from ray_tpu.ops import apply_rope, rmsnorm
 from ray_tpu.ops.paged_attention import (
     grouped_attention,
+    latent_decode_attention,
+    latent_kernel_takes,
     paged_decode_attention,
 )
 from ray_tpu.parallel.moe import EXPERT_LEAVES, moe_block
@@ -422,20 +428,21 @@ def latent_block_pages(page_size: int, pages_per_slot: int) -> int:
     return max(1, min(pages_per_slot // 8, LATENT_BLOCK_ROWS // page_size))
 
 
-def latent_rows_gathered(live_rows, slots: int, page_size: int,
-                         pages_per_slot: int) -> int:
-    """Cached rows a decode step of a latent-attention model gathers in one
-    layer, from the live slots' lengths `live_rows` (each with the row the
-    step writes): every slot's rows, live or not, up to the longest live
-    slot's last block. What `engine.stats()["attention"]` counts."""
-    block = latent_block_pages(page_size, pages_per_slot) * page_size
-    width = pages_per_slot * page_size
-    return slots * min(-(-int(max(live_rows)) // block) * block,
-                       -(-width // block) * block)
+def absorbed_queries(q_n, q_r, lp, cfg, row: int):
+    """The queries of latent attention's absorbed form, `[B, Q, H, row]`:
+    each head's `q_n [B, Q, H, dn]` multiplied into the latent's space
+    (`q_n W_uk^T`, `[rank]` a head), its rope part `q_r [B, Q, H, dr]`
+    beside it, scaled, and zeros up to `row`, a cached row's width: one
+    product with a cached row is the query's score against it."""
+    h, dn = q_n.shape[2:]
+    w_uk = lp["w_uk"].reshape(cfg.kv_lora_rank, h, dn)
+    q = jnp.concatenate([jnp.einsum("bqhn,rhn->bqhr", q_n, w_uk), q_r], -1)
+    q = q * jnp.asarray(cfg.attention_scale, q_n.dtype)
+    return jnp.pad(q, ((0, 0),) * 3 + ((0, row - q.shape[-1]),))
 
 
 def _latent_attention(q_n, q_r, lp, pool, layer, tables, q_pos, end, cfg,
-                      absorbed: bool):
+                      absorbed: bool, mesh=None):
     """Latent attention of `Q` queries a batch row against the rows its
     table names in `pool[layer]`, where they lie: a loop over blocks of
     cached rows (`latent_block_pages`) with a running softmax, as many steps
@@ -444,7 +451,11 @@ def _latent_attention(q_n, q_r, lp, pool, layer, tables, q_pos, end, cfg,
 
     `q_n [B, Q, H, dn]`, `q_r [B, Q, H, dr]` (rotated); `tables [B, pages a
     slot]`; query `q` of row `b` sees cached row `k` where `k <= q_pos[b,
-    q]` and `k < end[b]`. `absorbed`: the queries are first multiplied into
+    q]` and `k < end[b]`; `q_pos` None: every row below `end[b]`, a decode
+    step's one query a row, and on the chip the absorbed form of that is a
+    kernel that fetches the pages under `end[b]` and no others
+    (`ops.paged_attention.latent_decode_attention`, where the shapes allow:
+    `latent_kernel_takes`). `absorbed`: the queries are first multiplied into
     the latent's space (`q_n W_uk^T`, `[H, rank]` a query), set beside
     their rope part as one vector as wide as a cached row, and scores and
     the weighted sum are taken against the rows as cached, the values'
@@ -456,33 +467,33 @@ def _latent_attention(q_n, q_r, lp, pool, layer, tables, q_pos, end, cfg,
     where they are the keys' too), so that no score is transposed. A row
     that sees nothing (an idle slot, an inert row) gives zeros. Returns
     `[B, Q, H * dv]` in the queries' dtype."""
-    b, n_q, h, dn = q_n.shape
+    b, n_q, h, _ = q_n.shape
     ps, mp, row = pool.shape[2], tables.shape[1], pool.shape[3]
     rank, dv = cfg.kv_lora_rank, cfg.v_head_dim
     dtype, f32 = q_n.dtype, jnp.float32
     pages = latent_block_pages(ps, mp)
     block, n_max = pages * ps, -(-mp // pages)
-    tables = jnp.pad(tables, ((0, 0), (0, n_max * pages - mp)))  # NULL_PAGE
-    w_uk = lp["w_uk"].reshape(rank, h, dn)
     w_uv = lp["w_uv"].reshape(rank, h, dv)
-    scale = jnp.asarray(cfg.attention_scale, dtype)
     if absorbed:
-        q = jnp.concatenate(
-            [jnp.einsum("bqhn,rhn->bqhr", q_n, w_uk), q_r], -1) * scale
-        q = jnp.pad(q, ((0, 0),) * 3 + ((0, row - q.shape[-1]),))
+        q = absorbed_queries(q_n, q_r, lp, cfg, row)
         stat, width = (b, n_q, h), rank
     else:
-        q = jnp.concatenate([q_n, q_r], -1) * scale
+        q = jnp.concatenate([q_n, q_r], -1) * jnp.asarray(
+            cfg.attention_scale, dtype)
         stat, width = (b, h, n_q), dv
     low = jnp.asarray(-1e30, f32)
+    padded = jnp.pad(tables, ((0, 0), (0, n_max * pages - mp)))  # NULL_PAGE
 
     def step(j, carry):
         top, total, acc = carry                 # stat, stat, stat + [width]
-        at = jax.lax.dynamic_slice_in_dim(tables, j * pages, pages, axis=1)
+        at = jax.lax.dynamic_slice_in_dim(padded, j * pages, pages, axis=1)
         rows = pool[layer, at].reshape(b, block, row)
         k_pos = j * block + jnp.arange(block, dtype=jnp.int32)
-        seen = ((k_pos <= q_pos[:, :, None])
-                & (k_pos < end[:, None, None]))              # [B, Q, K]
+        if q_pos is None:
+            seen = k_pos < end[:, None, None]                # [B, 1, K]
+        else:
+            seen = ((k_pos <= q_pos[:, :, None])
+                    & (k_pos < end[:, None, None]))          # [B, Q, K]
         if absorbed:
             values, seen = rows[..., :rank], seen[:, :, None]
             scores = jnp.einsum("bqhr,bkr->bqhk", q, rows,
@@ -506,13 +517,20 @@ def _latent_attention(q_n, q_r, lp, pool, layer, tables, q_pos, end, cfg,
         return (new_top, total * keep + weights.sum(-1),
                 acc * keep[..., None] + added)
 
+    in_place = (absorbed and q_pos is None and n_q == 1
+                and jax.default_backend() == "tpu"
+                and latent_kernel_takes(pool, h, rank))
     with jax.named_scope("mla.attend"):
-        n_blocks = jnp.minimum(-(-jnp.max(end) // block), n_max)
-        _, total, acc = jax.lax.fori_loop(
-            0, n_blocks, step,
-            (jnp.full(stat, low), jnp.zeros(stat, f32),
-             jnp.zeros(stat + (width,), f32)))
-        out = (acc / jnp.maximum(total, 1e-30)[..., None]).astype(dtype)
+        if in_place:
+            out = latent_decode_attention(q[:, 0], pool, layer, tables, end,
+                                          rank, mesh=mesh)
+        else:
+            n_blocks = jnp.minimum(-(-jnp.max(end) // block), n_max)
+            _, total, acc = jax.lax.fori_loop(
+                0, n_blocks, step,
+                (jnp.full(stat, low), jnp.zeros(stat, f32),
+                 jnp.zeros(stat + (width,), f32)))
+            out = (acc / jnp.maximum(total, 1e-30)[..., None]).astype(dtype)
         if absorbed:
             out = jnp.einsum("bqhr,rhv->bqhv", out, w_uv)
         else:
@@ -521,7 +539,7 @@ def _latent_attention(q_n, q_r, lp, pool, layer, tables, q_pos, end, cfg,
 
 
 def _latent_through_pool(pages_w, rows_w, tables, q_pos, end, cfg,
-                         absorbed: bool):
+                         absorbed: bool, mesh=None):
     """`_walk_latent`'s `attend` of a step program: every row's latent and
     rope key scattered into layer `i` of the one pool at `(pages_w,
     rows_w)`, then the queries against the rows `tables` names
@@ -529,7 +547,7 @@ def _latent_through_pool(pages_w, rows_w, tables, q_pos, end, cfg,
     def attend(i, pool, lp, q_n, q_r, latent, k_r):
         pool = pool.at[i, pages_w, rows_w].set(_latent_rows(latent, k_r, pool))
         return _latent_attention(q_n, q_r, lp, pool, i, tables, q_pos, end,
-                                 cfg, absorbed), pool
+                                 cfg, absorbed, mesh), pool
 
     return attend
 
@@ -877,11 +895,11 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
 
     if cfg.kv_lora_rank:
         # One row a slot into the one pool, then the absorbed form against
-        # the slot's live blocks.
+        # the rows the slot holds, the one just written the last of them.
         x, k_new, counts = _walk_latent(
             params, x, k_pages, _latent_through_pool(
-                pages_w, rows_w, block_tables, positions, rows_att, cfg,
-                absorbed=True), cfg, cos, sin, positions, mesh)
+                pages_w, rows_w, block_tables, None, rows_att, cfg,
+                absorbed=True, mesh=mesh), cfg, cos, sin, positions, mesh)
         v_new = None
     elif rec is None:
         x, k_new, v_new, counts = _scan_layers(

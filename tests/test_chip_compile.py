@@ -493,26 +493,22 @@ def test_hybrid_step_updates_both_pools_in_place(v5e, program, rows,
         assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
 
 
-@pytest.mark.parametrize("program", ["decode_paged", "prefill_chunk_paged"])
-def test_latent_pool_steps_fit_and_leave_the_pool_where_it_lies(
-        v5e, program, monkeypatch):
-    """The cell `serve-mla-docqa-closed`'s step programs at its own sizes
-    (one chip's share of dots.vlm1.inst's language model, 1 dense + 5
-    expert layers, 64 slots x 4096, a pass of one row of 512): they fit
-    the chip beside 13.02 GB of arguments, the one pool of 640-wide rows
-    is updated where it lies (at the 576 values a row needs, the compiler
-    re-laid the whole pool at the step's start and end: 2 GB of
-    temporaries and two copies of 1.8 GB a step), and the held experts'
-    stacks are read in place by the three grouped products."""
+LATENT_SLOTS, LATENT_MAX_LEN, LATENT_CHUNK = 64, 4096, 512
+
+
+def _latent_program(program, one):
+    """`decode_paged` or `prefill_chunk_paged` at the sizes of the cell
+    `serve-mla-docqa-closed` (one chip's share of dots.vlm1.inst's
+    language model, 1 dense + 5 expert layers, 64 slots x 4096, pages of
+    16, a pass of one row of 512), on shapes: (fn, donated, args, the
+    cache's shapes, cfg)."""
     from ray_tpu.models.transformer import init_params
     from ray_tpu.serve import paged_kv
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
-    one = SingleDeviceSharding(v5e[0])
     cfg = dataclasses.replace(configs.get_config("dots-vlm1-ep16"),
                               n_layers=6, remat=False)
-    slots, max_len, page, chunk = 64, 4096, 16, 512
-    per_slot = max_len // page
+    slots, max_len = LATENT_SLOTS, LATENT_MAX_LEN
+    per_slot = max_len // PAGE
 
     def on_chip(tree):
         return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
@@ -524,8 +520,7 @@ def test_latent_pool_steps_fit_and_leave_the_pool_where_it_lies(
     params = on_chip(jax.eval_shape(
         lambda: init_params(jax.random.PRNGKey(0), cfg)))
     cache = on_chip(jax.eval_shape(lambda: paged_kv.init_paged_cache(
-        cfg, slots, slots * per_slot + 1, page, per_slot)))
-    assert cache["v"] is None and cache["k"].shape[-1] == 640
+        cfg, slots, slots * per_slot + 1, PAGE, per_slot)))
     moe = on_chip(jax.eval_shape(
         lambda: paged_kv.init_routing_counters(cfg)))
     if program == "decode_paged":
@@ -542,9 +537,26 @@ def test_latent_pool_steps_fit_and_leave_the_pool_where_it_lies(
             paged_kv.prefill_chunk_paged(p, t, n, s, o, k, None, ln, bt, cfg,
                                          max_len, moe=moe))
         row = struct((1,))
-        args = (params, struct((1, chunk)), row, row, row, cache["k"],
+        args = (params, struct((1, LATENT_CHUNK)), row, row, row, cache["k"],
                 cache["lengths"], cache["block_tables"], moe)
         donated = (5,)
+    return fn, donated, args, cache, cfg
+
+
+@pytest.mark.parametrize("program", ["decode_paged", "prefill_chunk_paged"])
+def test_latent_pool_steps_fit_and_leave_the_pool_where_it_lies(
+        v5e, program, monkeypatch):
+    """The cell `serve-mla-docqa-closed`'s step programs at its own sizes
+    (`_latent_program`): they fit the chip beside 13.02 GB of arguments,
+    the one pool of 640-wide rows is updated where it lies (at the 576
+    values a row needs, the compiler re-laid the whole pool at the step's
+    start and end: 2 GB of temporaries and two copies of 1.8 GB a step),
+    and the held experts' stacks are read in place by the three grouped
+    products."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, donated, args, cache, _ = _latent_program(
+        program, SingleDeviceSharding(v5e[0]))
+    assert cache["v"] is None and cache["k"].shape[-1] == 640
     compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
     memory = compiled.memory_analysis()
     assert 12.9e9 < memory.argument_size_in_bytes < 13.1e9
@@ -554,3 +566,52 @@ def test_latent_pool_steps_fit_and_leave_the_pool_where_it_lies(
     assert not re.findall(rf"= bf16\[{pool}\]\S* copy\(", text)
     assert len(re.findall(r"%gmm[.\d]* = f32\[", text)) == 3
     assert not re.findall(r"= bf16\[(?:80|5,16),7168,2048\]\S* copy\(", text)
+
+
+def test_latent_decode_reads_its_live_pages_in_place(v5e, monkeypatch):
+    """The sampled decode program of `serve-mla-docqa-closed` at its own
+    sizes holds the latent kernel's custom call once a stack of layers
+    (the dense layers' scan and the expert layers') and nothing of the
+    loop it replaced: no block of every slot's pages gathered
+    (`bf16[2048,16,640]` a trip, read twice, was a third of a 24.5 ms
+    step), temporaries under that one block's copy. The kernel's line is
+    one that `mla.decode_attn_time_share` and `_roofline_share` match, the
+    pattern filled as `bench/readers/mla.py` fills it: in a trace an
+    operation goes by its own name and its first result's shape
+    (`bench/xplane/reduce.py`), so a result of another shape would leave
+    the share of the roofline to the queries' pad alone."""
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    import spec
+    from readers import mla
+    from xplane import reduce
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, donated, args, cache, cfg = _latent_program(
+        "decode_paged", SingleDeviceSharding(v5e[0]))
+    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
+    text = compiled.as_text()
+    calls = [line.strip() for line in text.splitlines()
+             if re.search(r"%latent_decode_attention[.\d]* = ", line)]
+    assert len(calls) == 2 and all("tpu_custom_call" in c for c in calls)
+    row = cache["k"].shape[-1]
+    assert not re.findall(rf"= bf16\[\d+,{PAGE},{row}\]", text)
+    one_block = 2 * LATENT_SLOTS * 512 * row
+    assert compiled.memory_analysis().temp_size_in_bytes < one_block
+    how = spec.layer_metric_spec("mla.decode_attn_time_share")
+    model = {"num_slots": LATENT_SLOTS, "dims": {
+        "n_heads": cfg.n_heads, "d_model": cfg.d_model, **{
+            name: getattr(cfg, name) for name in (
+                "kv_lora_rank", "qk_rope_head_dim", "qk_nope_head_dim",
+                "v_head_dim")}}}
+    match, program = (re.compile(mla._sized(how[k], model))
+                      for k in ("match", "contains_op"))
+    for call in calls:
+        assert match.search(reduce._short(call)), call[:120]
+    # And the decode program is one the reader picks.
+    assert any(program.search(reduce._short(line.strip()))
+               for line in text.splitlines() if " = " in line)

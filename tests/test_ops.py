@@ -397,6 +397,99 @@ def test_paged_decode_attention_per_shard_matches_whole():
                                np.asarray(whole)[live], rtol=1e-5, atol=1e-5)
 
 
+def _latent_case(page, per_slot=40, layers=2):
+    """A small latent pool (16 heads, a 128-wide latent and a 64-wide rope
+    key in rows of 256), tables and lengths at every edge: no rows (twice:
+    the first slot and one between live ones), one row, a page's last row,
+    two lengths that end mid-page (one under a tile of 128 rows, one over
+    it), a full table. A table's entries past its slot's rows are the NULL
+    page; every page no live row lies in holds NaN, the NULL page too."""
+    from dataclasses import replace
+
+    from ray_tpu.models import configs
+
+    cfg = replace(configs.get_config("tiny_dots"), n_heads=16,
+                  kv_lora_rank=128, qk_rope_head_dim=64, qk_nope_head_dim=32,
+                  v_head_dim=32, dtype=jnp.float32)
+    max_len = page * per_slot
+    end = np.asarray([0, 1, page, 2 * page + 5, 0, 128 + page + 3, max_len],
+                     np.int32)
+    slots, pages = len(end), len(end) * per_slot + 1
+    tables = np.random.default_rng(3).permutation(np.arange(1, pages))[
+        :slots * per_slot].reshape(slots, per_slot).astype(np.int32)
+    live = np.zeros(pages, bool)
+    for s in range(slots):
+        tables[s, -(-end[s] // page):] = 0
+        live[tables[s, :-(-end[s] // page)]] = True
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    rank, h = cfg.kv_lora_rank, cfg.n_heads
+    lp = {"w_uk": jax.random.normal(
+              ks[0], (rank, h * cfg.qk_nope_head_dim)) * rank ** -0.5,
+          "w_uv": jax.random.normal(
+              ks[1], (rank, h * cfg.v_head_dim)) * rank ** -0.5}
+    q_n = jax.random.normal(ks[2], (slots, 1, h, cfg.qk_nope_head_dim))
+    q_r = jax.random.normal(ks[3], (slots, 1, h, cfg.qk_rope_head_dim))
+    pool = jax.random.normal(ks[4], (layers, pages, page, 256))
+    poison = jnp.where(jnp.asarray(live)[None, :, None, None], 0.0, jnp.nan)
+    return cfg, lp, q_n, q_r, pool, poison, jnp.asarray(tables), jnp.asarray(end)
+
+
+@pytest.mark.parametrize("block_rows", [128, 512], ids=["1tile", "4tiles"])
+@pytest.mark.parametrize("dtype", [jnp.bfloat16, jnp.float32],
+                         ids=["bf16", "f32"])
+@pytest.mark.parametrize("page", [16, 32])
+def test_latent_decode_attention_kernel_matches_the_loop(
+        page, dtype, block_rows, monkeypatch):
+    """The latent pool's decode kernel (interpret mode) against the loop
+    that runs off the chip (`_latent_attention`, absorbed), at every layer
+    of the pool: the same queries (`absorbed_queries`), the values'
+    projection after. A slot of 640 rows (1280 at pages of 32) is five
+    (ten) blocks of one tile, or a block of four tiles and one of one
+    (two and a half blocks); a block's pages come as whole groups of a
+    tile's pages and the pages left."""
+    from ray_tpu.ops import paged_attention
+    from ray_tpu.serve import paged_kv
+
+    monkeypatch.setattr(paged_attention, "_LATENT_BLOCK_ROWS", block_rows)
+    cfg, lp, q_n, q_r, pool, poison, tables, end = _latent_case(page)
+    lp, q_n, q_r, pool = jax.tree.map(
+        lambda a: a.astype(dtype), (lp, q_n, q_r, pool))
+    rank, h = cfg.kv_lora_rank, cfg.n_heads
+    assert paged_attention.latent_kernel_takes(pool, h, rank)
+    live = np.asarray(end) > 0
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    q = paged_kv.absorbed_queries(q_n, q_r, lp, cfg, pool.shape[-1])
+    for layer in range(pool.shape[0]):
+        want = paged_kv._latent_attention(
+            q_n, q_r, lp, pool, layer, tables, None, end, cfg, absorbed=True)
+        got = paged_attention.latent_decode_attention(
+            q[:, 0], pool + poison.astype(dtype), jnp.int32(layer), tables,
+            end, rank, interpret=True)
+        assert got.shape == (len(live), 1, h, rank) and got.dtype == dtype
+        assert not np.asarray(got, np.float32)[~live].any()
+        got = jnp.einsum("bqhr,rhv->bqhv", got,
+                         lp["w_uv"].reshape(rank, h, -1)).reshape(want.shape)
+        got, want = (np.asarray(a, np.float32) for a in (got, want))
+        assert np.abs(want[live]).max() > 0.1
+        np.testing.assert_allclose(got[live], want[live], rtol=tol, atol=tol)
+
+
+def test_latent_decode_attention_takes_the_loop_for_other_pools():
+    """A page that is not whole tiles, a row or a latent that is not whole
+    lanes, or heads that are not whole sublanes: the loop, whatever the
+    backend."""
+    from ray_tpu.ops.paged_attention import latent_kernel_takes
+
+    pool = lambda page, row, dtype=jnp.bfloat16: jax.ShapeDtypeStruct(  # noqa: E731
+        (2, 9, page, row), dtype)
+    assert latent_kernel_takes(pool(16, 640), 128, 512)
+    assert latent_kernel_takes(pool(8, 256, jnp.float32), 8, 128)
+    assert not latent_kernel_takes(pool(8, 640), 128, 512)   # half a tile
+    assert not latent_kernel_takes(pool(16, 576), 128, 512)  # 4.5 lanes' tiles
+    assert not latent_kernel_takes(pool(16, 128), 4, 32)     # tiny_dots' rows
+    assert not latent_kernel_takes(pool(16, 640), 8, 512)    # 8 bf16 heads
+
+
 def test_kernels_per_shard_match_whole():
     """Under a mesh the ops run each kernel on a device's block
     (ops.per_shard). Values and gradients equal the unsharded call,
