@@ -20,6 +20,17 @@ block to block, an initial state taken and the final one returned) and
 entry point of training, generation, the prefill chunk and the decode
 step; it takes the form by the static length of what it is given.
 
+The one-token form has two homes, and who takes which goes by what the
+caller holds. A caller with a bare state array `[B, H, P, N]` (the cached
+forward, a test, the kernel's reference) gets `ssd_step` here, the plain
+form: the compiler makes two fusions of it, one that writes the state and
+one that reads it again for `y`. A caller with the whole recurrent pool
+`[layers, B, H, P, N]` and a layer's index (the engine's decode program,
+`paged_kv.decode_paged` through `_walk_hybrid`) passes both to `mixer`
+and gets `ops.ssm_update`: on the chip a kernel that passes over that
+layer's rows once, where they lie in the pool; elsewhere `ssd_step` on
+the layer sliced out and set back.
+
 What lives from call to call is a row of fixed size a sequence: the state
 `[heads, d_head, d_state]` in float32 (it accumulates over the sequence's
 whole life) and the convolution's last `d_conv - 1` inputs `[d_conv - 1,
@@ -35,6 +46,8 @@ from typing import Dict, Tuple
 
 import jax
 import jax.numpy as jnp
+
+from ray_tpu.ops.ssm_update import ssm_update
 
 F32 = jnp.float32
 
@@ -168,13 +181,17 @@ def gate_norm(y, z, w, cfg):
         return grouped.reshape(v.shape) * w.astype(F32)
 
 
-def mixer(h, lp: Dict, cfg, state, conv, n_valid):
+def mixer(h, lp: Dict, cfg, state, conv, n_valid, layer=None):
     """The whole mixer on normed activations `h [B, L, d]` from `state [B,
     H, P, N]` float32 and the saved convolution inputs `conv [B, K-1, C]`;
     `n_valid [B]` rows of each sequence are real. Returns the mixer's
     output `[B, L, d]` in h's dtype, the state and the convolution inputs
     after the last real row. One token (L == 1) takes the one-token form,
-    anything longer the chunked one."""
+    anything longer the chunked one.
+
+    With `layer`, a scalar, `state` is the whole recurrent pool `[layers,
+    B, H, P, N]` and the pool comes back, that layer's rows advanced where
+    they lie (`ops.ssm_update`): one token only."""
     bsz, length, _ = h.shape
     heads, p, n, g = (cfg.mamba_n_heads, cfg.mamba_d_head, cfg.mamba_d_state,
                       cfg.mamba_n_groups)
@@ -193,7 +210,12 @@ def mixer(h, lp: Dict, cfg, state, conv, n_valid):
                    jax.nn.softplus(dt.astype(F32) + lp["dt_bias"].astype(F32)),
                    0.0)
     a = -jnp.exp(lp["a_log"].astype(F32))
-    if length == 1:
+    if layer is not None:
+        assert length == 1, "a pool and a layer: the one-token form"
+        y, state = ssm_update(x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0], state,
+                              layer)
+        y = y[:, None]
+    elif length == 1:
         y, state = ssd_step(x[:, 0], dt[:, 0], a, b[:, 0], c[:, 0], state)
         y = y[:, None]
     else:

@@ -607,9 +607,11 @@ def _walk_hybrid(params, x, k_cache, v_cache, rec, attend, rec_io,
     `rec_io = (read, write)`: `read(rec, j) -> (state [B, H, P, N], conv
     [B, K-1, C])` of the rows this call advances in Mamba layer `j`, and
     `write(rec, j, state, conv) -> rec`; `n_valid [B]` real rows of each
-    (`mamba2.mixer`). Returns x, the caches and `rec`."""
+    (`mamba2.mixer`). `rec_io` None: the call advances every slot's row
+    by one token, and the mixer takes the whole pool of states and `j`
+    (`ops.ssm_update`: layer `j` is passed over once, where it lies).
+    Returns x, the caches and `rec`."""
     layers = params["layers"]
-    read_rec, write_rec = rec_io
     is_mamba, _ = layer_kinds(cfg)
     attn_at = np.flatnonzero(~is_mamba)          # each attention layer's place
     run_from = np.concatenate([[0], attn_at[:-1] + 1])
@@ -620,13 +622,20 @@ def _walk_hybrid(params, x, k_cache, v_cache, rec, attend, rec_io,
         their kind."""
         def one(t, carry):
             x, rec = carry
-            lp = at_layer(layers["ssm"], ssm_first + t)
+            j = ssm_first + t
+            lp = at_layer(layers["ssm"], j)
             mlp = at_layer(layers["mlp"], first + t)
-            state, conv = read_rec(rec, ssm_first + t)
-            out, state, conv = mamba2.mixer(
-                rmsnorm(x, lp["norm"], cfg.norm_eps, mesh=mesh), lp, cfg,
-                state, conv, n_valid)
-            rec = write_rec(rec, ssm_first + t, state, conv)
+            h = rmsnorm(x, lp["norm"], cfg.norm_eps, mesh=mesh)
+            if rec_io is None:
+                out, state, conv = mamba2.mixer(
+                    h, lp, cfg, rec["state"], rec["conv"][j], n_valid, layer=j)
+                rec = {"state": state, "conv": rec["conv"].at[j].set(conv)}
+            else:
+                read_rec, write_rec = rec_io
+                state, conv = read_rec(rec, j)
+                out, state, conv = mamba2.mixer(h, lp, cfg, state, conv,
+                                                n_valid)
+                rec = write_rec(rec, j, state, conv)
             x = residual(x, out, cfg)
             h = rmsnorm(x, mlp["mlp_norm"], cfg.norm_eps, mesh=mesh)
             return residual(x, dense_mlp(h, mlp, cfg), cfg), rec
@@ -907,14 +916,10 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
             mesh,
         )
     else:
-        # Every slot's row of a layer at once, the whole layer written
-        # back at its index: an idle slot's row passes through unchanged.
-        rec_io = (lambda rec, j: (rec["state"][j], rec["conv"][j]),
-                  lambda rec, j, state, conv: {
-                      "state": rec["state"].at[j].set(state),
-                      "conv": rec["conv"].at[j].set(conv)})
+        # Every slot's row of a layer at once, advanced where it lies in
+        # the pool: an idle slot's row passes through unchanged.
         x, k_new, v_new, rec = _walk_hybrid(
-            params, x, k_pages, v_pages, rec, attend, rec_io,
+            params, x, k_pages, v_pages, rec, attend, None,
             active.astype(jnp.int32), cfg, cos, sin, positions, mesh)
         counts = None
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)

@@ -493,6 +493,83 @@ def test_hybrid_step_updates_both_pools_in_place(v5e, program, rows,
         assert not re.findall(rf"= \w+\[{dims}\]\S* copy\(", text), dims
 
 
+def _bench_on_path():
+    """The benchmark's modules (`spec`, `readers`, `xplane`) importable, for
+    the tests that hold a kernel's line to a metric's pattern."""
+    import sys
+
+    bench = os.path.join(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__))), "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+
+
+def _takes_the_state_pool(text):
+    """The fusions and custom calls of a compiled program that have the
+    recurrent pool `f32[36,48,64,64,128]` among their operands, as (own
+    name, whole line): what passes over the state."""
+    pool, shapes, found = "f32[36,48,64,64,128]", {}, []
+    lines = [line.strip() for line in text.splitlines() if " = " in line]
+    for line in lines:
+        own, rest = line.removeprefix("ROOT ").split(" = ", 1)
+        shapes[own] = re.match(r"\(?([a-z0-9]+\[[0-9,]*\])?", rest).group(1)
+    for line in lines:
+        m = re.search(r" (?:fusion|custom-call)\((.*?)\)", line)
+        if m and any(shapes.get(o) == pool
+                     for o in re.findall(r"%[\w.\-]+", m.group(1))):
+            found.append((line.removeprefix("ROOT ").split(" = ")[0], line))
+    return found
+
+
+def test_hybrid_decode_passes_over_its_state_once(v5e, monkeypatch):
+    """The hybrid's decode program compiled for the described v5e holds
+    `ops.ssm_update`'s custom call once a compiled Mamba layer (twice: the
+    scan's run and the tail's) and nothing else that takes the state pool:
+    no fusion `f32[48,64,64]` that reads a layer's rows again for `y`
+    beside one that writes them (the parent's two: 15.7 ms of a 27.6 ms
+    step, 10.9 GB moved where 7.25 must). The pool is still aliased and
+    not copied. Each call's line is one that `ssm.update_time_share` and
+    `ssm.update_roofline_share` match, in a program their `contains_op`
+    picks: in a trace an operation goes by its own name and its FIRST
+    result's shape (`bench/xplane/reduce.py`), so the pool is the
+    kernel's first result and `y` its second. The prefill pass does not
+    reach the kernel: what takes the pool there is the parent's count (a
+    slot's row read by two fusions and written by one, a compiled Mamba
+    layer)."""
+    _bench_on_path()
+    import spec
+    from xplane import reduce
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e[0])
+    fn, donated, args, cache = _hybrid_program("decode_paged", one)
+    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
+    text = compiled.as_text()
+    passes = _takes_the_state_pool(text)
+    assert len(passes) == 2, [own for own, _ in passes]
+    for own, line in passes:
+        assert re.fullmatch(r"%ssm_update[.\d]*", own), own
+        assert "tpu_custom_call" in line
+    pools = sum(a.size * a.dtype.itemsize for a in (
+        cache["k"], cache["v"], *cache["rec"].values()))
+    assert compiled.memory_analysis().alias_size_in_bytes >= pools
+    assert not re.findall(r"= f32\[36,48,64,64,128\]\S* copy\(", text)
+    short = [reduce._short(line.strip().removeprefix("ROOT "))
+             for line in text.splitlines() if " = " in line]
+    for metric in ("ssm.update_time_share", "ssm.update_roofline_share"):
+        how = spec.layer_metric_spec(metric)
+        match = re.compile(how["match"])
+        for _, line in passes:
+            assert match.search(reduce._short(line)), (metric, line[:120])
+        if "contains_op" in how:  # the decode program is one the reader picks
+            assert any(re.search(how["contains_op"], op) for op in short)
+    fn, donated, args, _ = _hybrid_program("prefill_chunk_paged", one)
+    text = jax.jit(fn, donate_argnums=donated).lower(*args).compile().as_text()
+    passes = _takes_the_state_pool(text)
+    assert len(passes) == 6 and not any("ssm_update" in own
+                                        for own, _ in passes)
+
+
 LATENT_SLOTS, LATENT_MAX_LEN, LATENT_CHUNK = 64, 4096, 512
 
 
@@ -580,12 +657,7 @@ def test_latent_decode_reads_its_live_pages_in_place(v5e, monkeypatch):
     operation goes by its own name and its first result's shape
     (`bench/xplane/reduce.py`), so a result of another shape would leave
     the share of the roofline to the queries' pad alone."""
-    import sys
-
-    bench = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "bench")
-    if bench not in sys.path:
-        sys.path.insert(0, bench)
+    _bench_on_path()
     import spec
     from readers import mla
     from xplane import reduce
