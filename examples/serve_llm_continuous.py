@@ -3,8 +3,7 @@
 The upgrade over examples/serve_llm.py's static batcher (the reference's
 serve.batching model): a paged KV cache over decode slots lets requests enter at any
 decode-step boundary and leave when they finish, so mixed arrival times
-keep the chip busy — measured 4.4x static batch=1 tokens/s on a v5e chip
-(BENCH_INFER.json). Per-request sampling (temperature/top_k/top_p)
+keep the chip busy. Per-request sampling (temperature/top_k/top_p)
 shares the same decode batch as greedy requests.
 
 Run: python examples/serve_llm_continuous.py

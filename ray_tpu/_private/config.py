@@ -52,16 +52,9 @@ class Config:
     spill_dir: str = ""
 
     # -- scheduling -----------------------------------------------------
-    # Prefer the local node until its critical resource utilization crosses
-    # this threshold (reference: scheduler_spread_threshold,
-    # ray_config_def.h:196).
-    scheduler_spread_threshold: float = 0.5
     # Max worker processes per node per job (reference sizes the pool from
     # num_cpus; we keep an explicit cap for tests).
     max_workers_per_node: int = 16
-    # Seconds an idle worker lives before the pool reaps it (reference:
-    # idle_worker_killing_time_threshold_ms).
-    idle_worker_timeout_s: float = 300.0
     # How long a spawned worker may take to register (runtime-env download
     # and extraction happen before registration; reference:
     # worker_register_timeout_seconds).
@@ -88,7 +81,6 @@ class Config:
 
     # -- rpc ------------------------------------------------------------
     rpc_connect_timeout_s: float = 10.0
-    rpc_max_message_size: int = 512 * 1024 * 1024
     # Dial timeout for raylet->raylet peer connections (short: waiters
     # queue behind the per-peer lock, so a blackholed peer must fail fast).
     peer_dial_timeout_s: float = 2.0

@@ -17,8 +17,7 @@ own latency attribution against what clients actually observed.
   * runner    — the fleet driver (also replays chaos schedules
                 anchored to the trace origin)
 
-Entry points: ``rt loadgen`` (CLI) and bench_serve_macro.py (the
-pinned headline trajectory).
+Entry point: ``rt loadgen`` (CLI).
 """
 
 from ray_tpu.loadgen.arrival import (

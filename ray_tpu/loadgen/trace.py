@@ -9,8 +9,8 @@ Determinism contract: ``generate(spec)`` is a pure function of the
 spec (seed included), and serialization is canonical (sorted keys,
 fixed separators, no whitespace variance) — so generating the same
 spec twice, or replaying a recorded file through ``generate`` of its
-own header, produces byte-identical files. bench_serve_macro gates on
-exactly that.
+own header, produces byte-identical files (tests/test_loadgen.py
+holds exactly that).
 """
 
 from __future__ import annotations
@@ -150,7 +150,7 @@ def read(path: str) -> Tuple[Dict, List[Dict]]:
 def regenerate_bytes(path: str) -> bytes:
     """Re-derive the trace from its own header and return the canonical
     bytes — equal to the file's bytes iff generation is deterministic
-    (the replay gate in bench_serve_macro and tests/test_loadgen)."""
+    (the replay gate in tests/test_loadgen)."""
     header, _ = read(path)
     spec = TraceSpec.from_header(header)
     new_header, records = generate(spec)

@@ -163,9 +163,7 @@ def _compiled_generate(cfg: TransformerConfig, max_new_tokens: int,
     per (config, sampling signature); jax.jit's own cache handles
     distinct prompt shapes underneath. Without this, generate() ran
     eagerly — every layer op a separate dispatch, every decode step a
-    host round trip — which is why the warmed static serving probe
-    measured ~27x slower than raw batched decode (BENCH_INFER r5:
-    11.5 tok/s vs 308.9 raw at batch 1)."""
+    host round trip."""
 
     def run(params, prompt, rng):
         b, lp = prompt.shape

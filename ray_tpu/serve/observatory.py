@@ -13,8 +13,9 @@ item; the request-path mirror of the train-side flight recorder):
           prefill, decode, stream}
 
      that sums to the e2e wall BY CONSTRUCTION (telescoping over the
-     stamp chain — the fraction gate in bench_serve_obs.py catches any
-     stamp-wiring regression, not float drift). Finished vectors ride a
+     stamp chain — tests/test_serve_observatory.py::
+     test_engine_phase_vector_sums_to_e2e catches any stamp-wiring
+     regression, not float drift). Finished vectors ride a
      per-replica ring (same design as the StepProfiler ring) and feed
      process-wide labeled metrics. Non-engine deployments collapse the
      engine phases into one ``exec`` phase. A streamed request's record
@@ -48,8 +49,7 @@ serve requests stitch into `rt profile tasks` / `rt timeline
 --lifecycle` next to control-plane phases.
 
 The unsampled steady-state cost is a handful of perf_counter stamps and
-dict writes per REQUEST (never per decode step); bench_serve_obs.py
-gates the paired-median per-request overhead at < 2%.
+dict writes per REQUEST (never per decode step).
 """
 
 from __future__ import annotations

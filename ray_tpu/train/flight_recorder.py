@@ -8,7 +8,7 @@ per-step wall-time breakdown (data-wait, compute, collective, checkpoint,
 other), compile/retrace counts, throughput, and an MFU estimate, into a
 fixed-size ring buffer. Recording is a handful of `perf_counter` reads
 and dict writes per step — cheap enough to leave on in production
-(bench_obs.py pins the overhead; BENCH_OBS.json).
+(`train.step_ms` of the ledger's train cells is read through it).
 
 Per-rank records ride the existing report/poll stream back to the
 trainer, which computes CROSS-RANK SKEW and names the slowest rank

@@ -37,7 +37,7 @@ RT_JOURNAL_AUTODUMP, RT_JOURNAL_COOLDOWN_S.
 
 Steady-state cost is one short lock hold + a deque append per event
 (emitters send one event per *step/request/transition*, never per
-task), gated <2% on a 5 ms train step by bench_obs.py.
+task).
 """
 
 from __future__ import annotations

@@ -20,8 +20,9 @@ the rows on the shared chrome-trace axis.
 
 The unsampled fast path must stay ~free: the only per-task cost with
 sampling off is the module-attribute ``enabled`` check on the submit
-side and ``spec.get("sampled")`` dict misses on the hops (benched in
-bench_scale.py, gated < 2 µs/task).
+side and ``spec.get("sampled")`` dict misses on the hops
+(tests/test_control_plane_profiler.py::test_rate_zero_emits_no_lifecycle_events
+holds that nothing is emitted).
 """
 
 from __future__ import annotations

@@ -190,6 +190,20 @@ def test_files_that_never_open_end_the_run_in_exit_1_naming_them(tmp_path):
     assert procs.tagged() == []
 
 
+def test_fewer_chips_than_the_cell_needs_is_no_result():
+    """No number under a per-chip unit where the node shows no chip: the
+    case of `bench/tests/test_rehearsal.py`, under the gate since PR 55."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu", RT_TPU_CHIPS="0")
+    done = subprocess.run(
+        [sys.executable, "bench/run.py", "--workload", "train-d4-8x1024",
+         "--seed", "1", "--seconds", "1", "--trace", "0"],
+        cwd=spec.REPO, env=env, capture_output=True, text=True, timeout=120)
+    assert done.returncode != 0
+    assert "nothing was run" in done.stdout
+    assert not done.stdout.strip().splitlines()[-1].startswith("{")
+    assert procs.tagged() == []
+
+
 def test_a_tagged_process_is_found_and_ended(tmp_path):
     env = dict(os.environ, **{procs.TOKEN_ENV: "test-token"})
     child = subprocess.Popen([sys.executable, "-c", "import time; time.sleep(60)"],

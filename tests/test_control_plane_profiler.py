@@ -113,7 +113,7 @@ def test_phases_cover_e2e_wall(rt_start):
     lifecycle.set_sample_rate(1.0)
     # Serial round-trips: burst submissions complete batch-granular (an
     # early task's e2e spans its successors' exec), so the coverage
-    # contract holds per round-trip, matching how bench_scale measures.
+    # contract holds per round-trip.
     for i in range(6):
         assert rt.get(work.remote(i), timeout=120) == i
     lifecycle.set_sample_rate(0.0)

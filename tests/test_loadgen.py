@@ -1,8 +1,7 @@
 """Loadgen harness tests: trace determinism / byte-identical replay,
 open- vs closed-loop runner semantics (stub call_fn — no cluster),
 client<->server reconciliation math, the gap gate, and schedule-
-anchored chaos replay. The cluster-backed end of the same machinery is
-exercised by bench_serve_macro.py.
+anchored chaos replay.
 """
 
 import threading

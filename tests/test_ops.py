@@ -188,7 +188,8 @@ def test_rmsnorm_pallas_grads(rows):
 
 def test_model_grads_through_pallas_interpret():
     """End-to-end: the flagship forward+backward with the Pallas kernels
-    forced on (interpret mode) — the exact path bench.py takes on TPU."""
+    forced on (interpret mode) — the path the train cells take on the
+    chip."""
     from dataclasses import replace
     from unittest import mock
 

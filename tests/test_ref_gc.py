@@ -61,8 +61,8 @@ def test_del_task_return_frees_store(rt_start):
 
 
 def test_repeated_big_puts_never_fill_store(rt_start):
-    """The bench_core regression: 20 x 64MB puts through a 256MB store must
-    recycle freed space, not spill or die with ObjectStoreFullError."""
+    """20 x 64MB puts through a 256MB store must recycle freed space, not
+    spill or die with ObjectStoreFullError."""
     for i in range(20):
         ref = rt.put(np.full(8_000_000, i, dtype=np.float64))  # 64 MB
         out = rt.get(ref)

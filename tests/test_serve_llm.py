@@ -332,8 +332,7 @@ def test_continuous_batching_steady_state_zero_host_traffic():
     sampling-param uploads. Any per-step jnp.asarray of temps/top_k/
     top_p/active, or a shape/dtype flip that retraces a jitted step,
     reintroduces the per-step host round trips this engine was rebuilt
-    to eliminate (ISSUE r6 tentpole; BENCH_INFER r5 showed a ~20x
-    engine-vs-raw throughput hole from exactly this traffic)."""
+    to eliminate."""
     import time
 
     from ray_tpu.serve.llm import ContinuousBatchingEngine

@@ -13,9 +13,9 @@
     python -m tools.rtlint --list-rules           one-line rule catalog
     python -m tools.rtlint --explain RT003        full rule rationale
 
-With no paths, the default target set is linted: ray_tpu/, tools/, and
-the root bench_*.py harnesses, resolved against the repo root (the
-directory holding tools/rtlint/). Exit codes: 0 clean, 1 new findings
+With no paths, the default target set is linted: ray_tpu/ and tools/,
+resolved against the repo root (the directory holding tools/rtlint/).
+Exit codes: 0 clean, 1 new findings
 (or stale baseline with --strict-baseline), 2 usage error.
 """
 

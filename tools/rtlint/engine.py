@@ -71,8 +71,8 @@ def _rulepack_digest() -> str:
     return _RULEPACK_DIGEST
 
 # The repo-wide default target set (relative to the lint root): the
-# runtime, the tooling (rtlint lints itself), and the root benches.
-DEFAULT_TARGETS = ("ray_tpu", "tools", "bench_*.py")
+# runtime and the tooling (rtlint lints itself).
+DEFAULT_TARGETS = ("ray_tpu", "tools")
 
 
 @dataclass

@@ -1,7 +1,7 @@
 """Output renderers for rtlint: text (default), json, sarif.
 
-``json`` is the machine interface for bots and the bench harness;
-``sarif`` (2.1.0) is what code-review UIs ingest. Both render the same
+``json`` is the machine interface for bots; ``sarif`` (2.1.0) is what
+code-review UIs ingest. Both render the same
 post-baseline view the text output shows: the findings that would fail
 the gate, plus run metadata. Renderers are pure — they return a string
 and never exit — so the CLI owns all exit-code policy.

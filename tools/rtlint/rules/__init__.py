@@ -4,7 +4,7 @@ One module per concern; every rule subclasses :class:`Rule` from
 ``rules.base`` and is instantiated exactly once here, in id order.
 ``ALL_RULES`` is the engine's default rule set and the catalog printed
 by ``--list-rules``; adding a rule means adding its instance here and a
-section to RULES.md (check_claims.py pins the count).
+section to RULES.md.
 """
 
 from __future__ import annotations
