@@ -2,7 +2,11 @@
 
 Llama-2 family dimensions follow the published architecture (Touvron et
 al., arXiv:2307.09288); tiny/test configs keep the same structure at toy
-scale for CPU tests.
+scale for CPU tests. The hybrids: `granite-4.0-h-micro` (Mamba-2 among
+attention layers, dense MLPs; `tiny_granite_h`) and `lfm2-24b-a2b` (gated
+short convolutions among attention layers, routed experts after two dense
+layers; `lfm2-24b-a2b-l10`, its first ten layers, is what one chip serves;
+`tiny_lfm2_moe`).
 """
 
 from __future__ import annotations
@@ -371,6 +375,80 @@ dots_vlm1_ep16 = replace(
     dots_vlm1, vocab_size=16160, first_k_dense_replace=1,
     experts_held=16, expert_share=0)
 
+# Every mechanism of the model below at toy widths: gated short
+# convolutions and attention layers (a QK-norm by head, a rope), two conv
+# layers in a row, ONE leading dense layer, 8 routed experts of which a
+# token takes 2 by sigmoid scores and a choice bias (zero here: a test draws
+# it), blocks of 8 cached rows so that a test prompt spans several.
+tiny_lfm2_moe = TransformerConfig(
+    vocab_size=256,
+    d_model=64,
+    n_layers=5,
+    n_heads=4,
+    n_kv_heads=2,
+    d_ff=128,
+    max_seq=128,
+    rope_theta=1e6,
+    norm_eps=1e-5,
+    dtype=jnp.float32,
+    remat=False,
+    qk_norm=True,
+    layer_pattern=("conv", "conv", "full_attention", "conv",
+                   "full_attention"),
+    conv_L_cache=3,
+    first_k_dense_replace=1,
+    moe_intermediate_size=32,
+    num_experts=8,
+    experts_per_token=2,
+    scoring_func="sigmoid",
+    norm_topk_prob=True,
+    use_expert_bias=True,
+    norm_topk_eps=1e-6,
+)
+
+# LFM2-24B-A2B (the model's public config.json, `model_type` lfm2_moe;
+# Liquid AI's LFM2 technical report): 40 layers, 30 gated short
+# convolutions (kernel 3, no bias) and 10 attention layers at 2, 6, ..., 38
+# (32 query and 8 KV heads of 64, an RMSNorm over each head of q and k, a
+# rope at theta 1e6); the first 2 layers have a dense SwiGLU MLP of 11776,
+# the other 38 have 64 routed experts of 1536, 4 a token chosen by sigmoid
+# score + a learned bias and weighted by their scores over the sum + 1e-6;
+# a 65,536-row vocabulary, the head held apart from the table (the public
+# config read here has no key for tying; apart, 23.98 B parameters, tied
+# 23.84 B; bench/configs/lfm2-24b-a2b-serve.json says why apart). 2.3 B
+# used by a token.
+lfm2_24b_a2b = TransformerConfig(
+    vocab_size=65536,
+    d_model=2048,
+    n_layers=40,
+    n_heads=32,
+    n_kv_heads=8,
+    d_ff=11776,
+    max_seq=128000,
+    rope_theta=1e6,
+    norm_eps=1e-5,
+    qk_norm=True,
+    layer_pattern=tuple("full_attention" if i % 4 == 2 else "conv"
+                        for i in range(40)),
+    conv_L_cache=3,
+    first_k_dense_replace=2,
+    moe_intermediate_size=1536,
+    num_experts=64,
+    experts_per_token=4,
+    scoring_func="sigmoid",
+    norm_topk_prob=True,
+    routed_scaling_factor=1.0,
+    use_expert_bias=True,
+    norm_topk_eps=1e-6,
+)
+
+# Its first 10 layers exactly as published, what one chip holds whole
+# (conv, conv, attention, conv, conv, conv, attention, conv, conv, conv:
+# both dense layers and eight expert layers, two periods of the pattern);
+# the other 30 would lie on four further chips, as pipeline stages.
+lfm2_24b_a2b_l10 = replace(
+    lfm2_24b_a2b, n_layers=10, layer_pattern=lfm2_24b_a2b.layer_pattern[:10])
+
 NAMED_CONFIGS = {
     "tiny": tiny,
     "tiny_gqa": tiny_gqa,
@@ -392,6 +470,9 @@ NAMED_CONFIGS = {
     "tiny_dots": tiny_dots,
     "dots-vlm1": dots_vlm1,
     "dots-vlm1-ep16": dots_vlm1_ep16,
+    "tiny_lfm2_moe": tiny_lfm2_moe,
+    "lfm2-24b-a2b": lfm2_24b_a2b,
+    "lfm2-24b-a2b-l10": lfm2_24b_a2b_l10,
 }
 
 

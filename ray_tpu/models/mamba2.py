@@ -81,24 +81,32 @@ def init_state(cfg, layers: int, rows: int) -> Dict:
     }
 
 
+def depthwise_conv(x, prev, lp, n_valid) -> Tuple[jax.Array, jax.Array]:
+    """The depthwise causal convolution of `x [B, L, C]` behind the
+    sequence's last inputs `prev [B, K-1, C]`, float32 (with `lp["conv_b"]`
+    where there is one, and no activation), and the inputs to keep for the
+    next call: the K-1 rows before row `n_valid [B]` of the joined stream
+    (the old ones where `n_valid` is 0). The gated short convolution
+    (models/shortconv.py) is this as it stands."""
+    k1 = prev.shape[1]
+    length = x.shape[1]
+    stream = jnp.concatenate([prev.astype(x.dtype), x], axis=1)
+    w = lp["conv_w"].astype(F32)                           # [C, K]
+    out = sum(stream[:, k:k + length].astype(F32) * w[:, k]
+              for k in range(k1 + 1))
+    if "conv_b" in lp:
+        out = out + lp["conv_b"].astype(F32)
+    keep = jax.vmap(
+        lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, k1, axis=0)
+    )(stream, n_valid.astype(jnp.int32))
+    return out, keep.astype(prev.dtype)
+
+
 def causal_conv(xbc, prev, lp, n_valid) -> Tuple[jax.Array, jax.Array]:
-    """silu(depthwise causal convolution + bias) of `xbc [B, L, C]` behind
-    the sequence's last inputs `prev [B, K-1, C]`, and the inputs to keep
-    for the next call: the K-1 rows before row `n_valid [B]` of the joined
-    stream (the old ones where `n_valid` is 0)."""
+    """silu(`depthwise_conv`) of `xbc [B, L, C]`, and the inputs to keep."""
     with jax.named_scope("ssm.conv"):
-        k1 = prev.shape[1]
-        length = xbc.shape[1]
-        stream = jnp.concatenate([prev.astype(xbc.dtype), xbc], axis=1)
-        w = lp["conv_w"].astype(F32)                       # [C, K]
-        out = sum(stream[:, k:k + length].astype(F32) * w[:, k]
-                  for k in range(k1 + 1))
-        if "conv_b" in lp:
-            out = out + lp["conv_b"].astype(F32)
-        keep = jax.vmap(
-            lambda s, n: jax.lax.dynamic_slice_in_dim(s, n, k1, axis=0)
-        )(stream, n_valid.astype(jnp.int32))
-        return jax.nn.silu(out), keep.astype(prev.dtype)
+        out, keep = depthwise_conv(xbc, prev, lp, n_valid)
+        return jax.nn.silu(out), keep
 
 
 def ssd_chunked(x, dt, a, b, c, state, chunk: int):

@@ -29,7 +29,7 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import apply_rope, flash_attention, rmsnorm, rope_frequencies, softmax_cross_entropy
 from ray_tpu.ops.rope import yarn_inv_freq, yarn_mscale
-from ray_tpu.models import mamba2
+from ray_tpu.models import mamba2, shortconv
 from ray_tpu.parallel.moe import load_balancing_loss, moe_block
 
 
@@ -96,11 +96,17 @@ class TransformerConfig:
     # Explicit head dim when it differs from d_model/n_heads (Qwen3
     # uses 128-wide heads at every scale). 0 = derive from d_model.
     custom_head_dim: int = 0
-    # A hybrid decoder's published sizes (granitemoehybrid's config keys):
-    # the kind of every layer, "mamba" or "attention" (the public config's
-    # `layer_types`; empty: every layer is attention), and the Mamba-2
-    # mixer's shape (models/mamba2.py).
+    # A hybrid decoder's published sizes (granitemoehybrid's and lfm2_moe's
+    # config keys): the kind of every layer under the public config's own
+    # strings (`layer_types`; empty: every layer is attention), attention
+    # ("attention", "full_attention") among recurrent layers of ONE kind,
+    # "mamba" (the Mamba-2 mixer, models/mamba2.py, shaped by `mamba_*`) or
+    # "conv" (the gated short convolution, models/shortconv.py, whose
+    # kernel is `conv_L_cache` long). A hybrid's MLP is dense in its first
+    # `first_k_dense_replace` layers and routed in the others where
+    # `num_experts` is set, dense in every layer where it is not.
     layer_pattern: Tuple[str, ...] = ()
+    conv_L_cache: int = 3
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
     mamba_d_state: int = 0
@@ -146,6 +152,13 @@ class TransformerConfig:
     n_group: int = 1
     topk_group: int = 1
     routed_scaling_factor: float = 1.0
+    # Sigmoid routing's two further published settings: whether a learned
+    # bias a layer and expert is added to the scores to CHOOSE with (the
+    # leaf `router_bias`; lfm2_moe's `use_expert_bias`, always there under
+    # latent attention), and what is added to the chosen scores' sum before
+    # it divides them (DeepSeek-V3's code adds 1e-20, lfm2_moe's 1e-6).
+    use_expert_bias: bool = False
+    norm_topk_eps: float = 1e-20
     # One chip's share of a layer's routed experts: `experts_held` of the
     # `num_experts` the router chooses among (0: all), the `expert_share`-th
     # such run, experts [held * share, held * (share + 1)). The layer then
@@ -195,6 +208,26 @@ class TransformerConfig:
         """How many of a hybrid's layers are of `kind`."""
         return sum(t == kind for t in self.layer_pattern)
 
+    @property
+    def attention_layers(self) -> int:
+        """A hybrid's attention layers, under either published string."""
+        return sum(t in ATTENTION_KINDS for t in self.layer_pattern)
+
+    @property
+    def recurrent_kind(self) -> str:
+        """The kind of a hybrid's recurrent layers, "mamba" or "conv"."""
+        return next(t for t in self.layer_pattern if t not in ATTENTION_KINDS)
+
+    @property
+    def recurrent_layers(self) -> int:
+        return len(self.layer_pattern) - self.attention_layers
+
+
+# The published strings of a hybrid's attention layers (granitemoehybrid's,
+# lfm2_moe's) and, for each kind of recurrent layer, its stack's name under
+# `params["layers"]` and the module whose `mixer` and `init_state` it takes.
+ATTENTION_KINDS = ("attention", "full_attention")
+RECURRENT_KINDS = {"mamba": ("ssm", mamba2), "conv": ("conv", shortconv)}
 
 # Where parallel.mesh.DEFAULT_RULES put activations, for the kernels that
 # run on each device's block (ops.per_shard).
@@ -207,39 +240,63 @@ def _dense_init(key, shape, scale, dtype):
 
 
 def _check_hybrid(cfg: TransformerConfig) -> None:
+    """What a hybrid may be: attention layers among recurrent layers of one
+    kind, a QK-norm and a rope or neither in the attention layers, and an
+    MLP that is dense in every layer or dense in the leading layers and
+    whole routed experts (softmax or sigmoid scores, a choice bias) in the
+    rest. Refused by name: an unknown kind, Mamba and conv layers in one
+    model, latent attention, a held share of the experts or shared experts
+    beside recurrent layers."""
     kinds = set(cfg.layer_pattern)
-    if not kinds <= {"mamba", "attention"}:
+    if not kinds <= {*RECURRENT_KINDS, *ATTENTION_KINDS}:
         raise ValueError(f"unknown layer kinds {sorted(kinds)} in "
-                         "layer_pattern: expected 'mamba' or 'attention'")
+                         "layer_pattern: expected 'mamba' or 'conv', and "
+                         "'attention' or 'full_attention'")
     if len(cfg.layer_pattern) != cfg.n_layers:
         raise ValueError(f"layer_pattern names {len(cfg.layer_pattern)} layers, "
                          f"n_layers is {cfg.n_layers}")
-    if cfg.num_experts or cfg.qk_norm:
-        raise ValueError("a hybrid's MLP is dense and its attention has no "
-                         "QK-norm here: routed experts or a QK-norm beside "
-                         "state-space layers are not written")
-    if len(kinds) != 2:
-        raise ValueError("a hybrid has layers of both kinds; layer_pattern "
-                         f"has only {sorted(kinds)}")
-    if cfg.mamba_expand * cfg.d_model != mamba2.d_inner(cfg):
+    recurrent = kinds - set(ATTENTION_KINDS)
+    if not recurrent or recurrent == kinds:
+        raise ValueError("a hybrid has layers of both kinds, recurrent and "
+                         f"attention; layer_pattern has only {sorted(kinds)}")
+    if len(recurrent) > 1:
+        raise ValueError("a hybrid's recurrent layers are of one kind: Mamba "
+                         "and conv layers in one model (two recurrent pools "
+                         "in one walk) are not written")
+    if cfg.kv_lora_rank:
+        raise ValueError("latent attention beside recurrent layers is not "
+                         "written")
+    if cfg.num_experts and (cfg.experts_held or cfg.n_shared_experts):
+        raise ValueError("a hybrid's expert layers hold all their routed "
+                         "experts and nothing beside them: a held share "
+                         "(experts_held) or shared experts beside recurrent "
+                         "layers are not written")
+    if cfg.num_experts and not 0 <= cfg.first_k_dense_replace < cfg.n_layers:
+        raise ValueError("first_k_dense_replace must lie in [0, n_layers)")
+    if "mamba" in kinds and (
+            cfg.mamba_expand * cfg.d_model != mamba2.d_inner(cfg)):
         raise ValueError("mamba_n_heads * mamba_d_head must be "
                          "mamba_expand * d_model")
 
 
 def _init_hybrid_layers(key, cfg: TransformerConfig) -> Dict:
-    """A hybrid's layers: one stack a kind of mixer (`ssm`, `attn`, each
-    with its input norm) and the MLPs of all layers (`mlp`), so three
-    stacks of unlike length. `A_log`, `dt_bias` and the convolution are
-    drawn by Mamba-2's published rule (arXiv:2405.21060)."""
+    """A hybrid's layers: one stack a kind of mixer (`ssm` or `conv`, and
+    `attn`, each with its input norm), the dense MLPs of the leading layers
+    (`mlp`: of all layers in a model without experts) and the routed MLPs
+    of the others (`moe`, the experts `[expert layers, E, ...]`), so three
+    or four stacks of unlike length. `A_log`, `dt_bias` and the convolution
+    are drawn by Mamba-2's published rule (arXiv:2405.21060); a short
+    convolution's taps are uniform in +-K ** -0.5."""
     _check_hybrid(cfg)
     d, h, kvh, hd, ff = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                          cfg.head_dim, cfg.d_ff)
-    n_ssm, n_attn, n = (cfg.layers_of("mamba"), cfg.layers_of("attention"),
-                        cfg.n_layers)
+    n_rec, n_attn, n = cfg.recurrent_layers, cfg.attention_layers, cfg.n_layers
+    n_moe = cfg.expert_layers
     scale, out_scale = d ** -0.5, d ** -0.5 * (2 * n) ** -0.5
-    heads, inner, conv = (cfg.mamba_n_heads, mamba2.d_inner(cfg),
-                          mamba2.conv_dim(cfg))
-    keys = iter(jax.random.split(key, 16))
+    # A second run of keys behind the first sixteen, so that a model that
+    # needed no more draws what it drew before there were expert stacks.
+    keys = iter([*jax.random.split(key, 16),
+                 *jax.random.split(jax.random.fold_in(key, 1), 8)])
 
     def normal(count, shape, scale):
         return _dense_init(next(keys), (count, *shape), scale, cfg.dtype)
@@ -247,6 +304,59 @@ def _init_hybrid_layers(key, cfg: TransformerConfig) -> Dict:
     def uniform(shape, lo, hi):
         return jax.random.uniform(next(keys), shape, jnp.float32, lo, hi)
 
+    layers = {}
+    if cfg.recurrent_kind == "conv":
+        bound = cfg.conv_L_cache ** -0.5
+        layers["conv"] = {
+            "norm": jnp.ones((n_rec, d), cfg.dtype),
+            "w_in": normal(n_rec, (d, 3 * d), scale),
+            "conv_w": uniform((n_rec, d, cfg.conv_L_cache), -bound,
+                              bound).astype(cfg.dtype),
+            "w_out": normal(n_rec, (d, d), out_scale),
+        }
+    else:
+        layers["ssm"] = _init_mamba_stack(cfg, n_rec, normal, uniform,
+                                          scale, out_scale)
+    attn = {
+        "attn_norm": jnp.ones((n_attn, d), cfg.dtype),
+        "wq": normal(n_attn, (d, h * hd), scale),
+        "wk": normal(n_attn, (d, kvh * hd), scale),
+        "wv": normal(n_attn, (d, kvh * hd), scale),
+        "wo": normal(n_attn, (h * hd, d), out_scale),
+    }
+    if cfg.qk_norm:
+        full = cfg.qk_norm_extent == "projection"
+        attn["q_norm"] = jnp.ones((n_attn, h * hd if full else hd), cfg.dtype)
+        attn["k_norm"] = jnp.ones((n_attn, kvh * hd if full else hd),
+                                  cfg.dtype)
+    layers["attn"] = attn
+    layers["mlp"] = {
+        "mlp_norm": jnp.ones((n - n_moe, d), cfg.dtype),
+        "w_gate": normal(n - n_moe, (d, ff), scale),
+        "w_up": normal(n - n_moe, (d, ff), scale),
+        "w_down": normal(n - n_moe, (ff, d), out_scale),
+    }
+    if n_moe:
+        e, eff = cfg.num_experts, cfg.expert_ff
+        layers["moe"] = {
+            "mlp_norm": jnp.ones((n_moe, d), cfg.dtype),
+            "router": normal(n_moe, (d, e), scale),
+            "w_gate": normal(n_moe, (e, d, eff), scale),
+            "w_up": normal(n_moe, (e, d, eff), scale),
+            "w_down": normal(n_moe, (e, eff, d),
+                             eff ** -0.5 * (2 * n) ** -0.5),
+        }
+        if cfg.use_expert_bias:
+            # Added to the scores to choose with, never to weigh with; zero
+            # as a checkpoint starts it, float32 as it is kept.
+            layers["moe"]["router_bias"] = jnp.zeros((n_moe, e), jnp.float32)
+    return layers
+
+
+def _init_mamba_stack(cfg, n_ssm, normal, uniform, scale, out_scale) -> Dict:
+    d = cfg.d_model
+    heads, inner, conv = (cfg.mamba_n_heads, mamba2.d_inner(cfg),
+                          mamba2.conv_dim(cfg))
     dt = jnp.exp(uniform((n_ssm, heads), jnp.log(0.001), jnp.log(0.1)))
     bound = cfg.mamba_d_conv ** -0.5
     ssm = {
@@ -267,20 +377,7 @@ def _init_hybrid_layers(key, cfg: TransformerConfig) -> Dict:
         ssm["b_in"] = jnp.zeros((n_ssm, mamba2.in_proj_dim(cfg)), cfg.dtype)
         ssm["b_dt"] = jnp.zeros((n_ssm, heads), cfg.dtype)
         ssm["b_out"] = jnp.zeros((n_ssm, d), cfg.dtype)
-    attn = {
-        "attn_norm": jnp.ones((n_attn, d), cfg.dtype),
-        "wq": normal(n_attn, (d, h * hd), scale),
-        "wk": normal(n_attn, (d, kvh * hd), scale),
-        "wv": normal(n_attn, (d, kvh * hd), scale),
-        "wo": normal(n_attn, (h * hd, d), out_scale),
-    }
-    mlp = {
-        "mlp_norm": jnp.ones((n, d), cfg.dtype),
-        "w_gate": normal(n, (d, ff), scale),
-        "w_up": normal(n, (d, ff), scale),
-        "w_down": normal(n, (ff, d), out_scale),
-    }
-    return {"ssm": ssm, "attn": attn, "mlp": mlp}
+    return ssm
 
 
 def _check_latent(cfg: TransformerConfig) -> None:
@@ -483,12 +580,19 @@ def param_logical_axes(cfg: TransformerConfig) -> Dict:
                  "w_dt": ("stage", "embed", None),
                  "w_out": ("stage", None, "embed"),
                  "embed": ("vocab", "embed"), "lm_head": ("embed", "vocab")}
-        return jax.tree_util.tree_map_with_path(
-            lambda path, leaf: split.get(
+        experts = {"w_gate": ("stage", "expert", "embed", "mlp"),
+                   "w_up": ("stage", "expert", "embed", "mlp"),
+                   "w_down": ("stage", "expert", "mlp", "embed")}
+
+        def axes_of(path, leaf):
+            table = (experts if len(path) > 1 and path[1].key == "moe"
+                     else split)
+            return table.get(
                 path[-1].key,
                 (("stage",) if path[0].key == "layers" else ())
-                + (None,) * (leaf.ndim - (path[0].key == "layers"))),
-            shapes)
+                + (None,) * (leaf.ndim - (path[0].key == "layers")))
+
+        return jax.tree_util.tree_map_with_path(axes_of, shapes)
     layer = {
         "attn_norm": ("stage", None),
         "wq": ("stage", "embed", "heads"),
@@ -631,11 +735,26 @@ def residual(x, y, cfg: TransformerConfig):
 
 
 def layer_kinds(cfg: TransformerConfig):
-    """For every layer of a hybrid: whether it is a Mamba layer `[n] bool`
-    and its index within its own kind's stack `[n] int32`."""
-    is_mamba = np.array([t == "mamba" for t in cfg.layer_pattern])
-    within = np.where(is_mamba, np.cumsum(is_mamba), np.cumsum(~is_mamba)) - 1
-    return is_mamba, within.astype(np.int32)
+    """For every layer of a hybrid: whether it is a recurrent layer `[n]
+    bool` and its index within its own kind's stack `[n] int32`."""
+    is_recurrent = np.array([t not in ATTENTION_KINDS
+                             for t in cfg.layer_pattern])
+    within = np.where(is_recurrent, np.cumsum(is_recurrent),
+                      np.cumsum(~is_recurrent)) - 1
+    return is_recurrent, within.astype(np.int32)
+
+
+def mix_recurrent(h, lp: Dict, cfg: TransformerConfig, rows: Dict, n_valid):
+    """A recurrent layer's mixer on normed activations `h [B, L, D]` from
+    its pool's rows by their names (`state` and `conv` of a Mamba layer,
+    `conv` alone of a gated short convolution), `n_valid [B]` of the rows
+    real: the mixer's output and the rows after the last real one."""
+    if "state" in rows:
+        out, state, conv = mamba2.mixer(h, lp, cfg, rows["state"],
+                                        rows["conv"], n_valid)
+        return out, {"state": state, "conv": conv}
+    out, conv = shortconv.mixer(h, lp, cfg, rows["conv"], n_valid)
+    return out, {"conv": conv}
 
 
 def at_layer(stack: Dict, i):
@@ -645,34 +764,34 @@ def at_layer(stack: Dict, i):
 
 
 def _hybrid_layers(params, x, cfg: TransformerConfig, mesh, positions):
-    """A hybrid's layers over whole sequences `x [B, L, D]`, every Mamba
-    layer from a zero state: ONE scan over all layers, each taking its
-    kind's mixer under a `lax.cond` and reading its weights from its
-    kind's stack at its own index (the MLPs, one a layer, are the scan's
-    inputs). Differentiable; nothing is carried but `x`."""
+    """A hybrid's layers over whole sequences `x [B, L, D]`, every
+    recurrent layer from a zero state: a scan a kind of MLP (ONE over all
+    layers for a model without experts; the leading dense layers', then
+    the expert layers'), each layer taking its kind's mixer under a
+    `lax.cond` and reading its weights from its kind's stack at its own
+    index (the MLPs, one a layer, are the scan's inputs). Differentiable;
+    nothing is carried but `x`. Returns x and the expert layers' stacked
+    routing statistics (None without experts)."""
     _check_hybrid(cfg)
     layers = params["layers"]
-    b, l, _ = x.shape
-    fresh = mamba2.init_state(cfg, 1, b)
+    b, l, d = x.shape
+    stack_name, recurrent = RECURRENT_KINDS[cfg.recurrent_kind]
+    fresh = at_layer(recurrent.init_state(cfg, 1, b), 0)
     every_row = jnp.full((b,), l, jnp.int32)
-    rope = cfg.position_embedding_type == "rope"
-    if rope:
-        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
+    cos, sin = rope_tables(cfg, cfg.max_seq)
 
     def norm(x, w):
         return rmsnorm(x, w, cfg.norm_eps, mesh=mesh, spec=_ACT_SPEC)
 
-    def ssm_mixer(x, j):
-        lp = at_layer(layers["ssm"], j)
-        out, _, _ = mamba2.mixer(norm(x, lp["norm"]), lp, cfg,
-                                 fresh["state"][0], fresh["conv"][0],
-                                 every_row)
-        return out
+    def recurrent_mixer(x, j):
+        lp = at_layer(layers[stack_name], j)
+        return mix_recurrent(norm(x, lp["norm"]), lp, cfg, fresh,
+                             every_row)[0]
 
     def attn_mixer(x, j):
         lp = at_layer(layers["attn"], j)
         q, k, v = project_qkv(norm(x, lp["attn_norm"]), lp, cfg)
-        if rope:
+        if cos is not None:
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
         # The kernels scale scores by head_dim ** -0.5: q carries the rest.
@@ -681,16 +800,28 @@ def _hybrid_layers(params, x, cfg: TransformerConfig, mesh, positions):
         return attn.reshape(b, l, -1) @ lp["wo"]
 
     def body(x, inputs):
-        mlp, is_mamba, j = inputs
+        mlp, is_recurrent, j = inputs
         x = residual(x, jax.lax.cond(
-            is_mamba, ssm_mixer, attn_mixer, x, j).astype(x.dtype), cfg)
-        return residual(x, dense_mlp(norm(x, mlp["mlp_norm"]), mlp, cfg),
-                        cfg), None
+            is_recurrent, recurrent_mixer, attn_mixer, x, j).astype(x.dtype),
+            cfg)
+        h = norm(x, mlp["mlp_norm"])
+        if "router" not in mlp:
+            return residual(x, dense_mlp(h, mlp, cfg), cfg), None
+        y, routing = moe_block(h.reshape(b * l, d), mlp, cfg)
+        return residual(x, y.reshape(b, l, d), cfg), routing
 
     if cfg.remat:
         body = jax.checkpoint(body)
-    x, _ = jax.lax.scan(body, x, (layers["mlp"], *layer_kinds(cfg)))
-    return x
+    is_recurrent, within = layer_kinds(cfg)
+    n_dense = cfg.n_layers - cfg.expert_layers
+    routing = None
+    for name, span in (("mlp", slice(0, n_dense)),
+                       ("moe", slice(n_dense, cfg.n_layers))):
+        if span.stop > span.start:
+            x, stats = jax.lax.scan(
+                body, x, (layers[name], is_recurrent[span], within[span]))
+            routing = stats if stats is not None else routing
+    return x, routing
 
 
 def rope_tables(cfg: TransformerConfig, length: int):
@@ -879,7 +1010,7 @@ def forward(
     logits (the chunked-CE loss applies lm_head itself)."""
     x = _embed_tokens(params, tokens, cfg)
     if cfg.layer_pattern:
-        x, routing = _hybrid_layers(params, x, cfg, mesh, positions), None
+        x, routing = _hybrid_layers(params, x, cfg, mesh, positions)
     elif cfg.kv_lora_rank:
         x, routing = _latent_layers(params, x, cfg, mesh, positions)
     else:

@@ -80,11 +80,13 @@ def route(logits: jax.Array, cfg, bias: Optional[jax.Array] = None):
     float32: `(scores [tokens, E], weights [tokens, k], chosen [tokens,
     k])`. "softmax": the k largest probabilities, renormalised where
     `norm_topk_prob` says so (Mixtral, OLMoE). "sigmoid" (DeepSeek-V3's
-    `noaux_tc`): every expert's sigmoid score; the choice is made on score +
-    `bias [E]` and, with `n_group` > 1, within the `topk_group` groups
-    whose two best sum highest; the weights are the chosen experts' scores
-    without the bias, over their sum where `norm_topk_prob`, times
-    `routed_scaling_factor`."""
+    `noaux_tc`, lfm2_moe's router): every expert's sigmoid score; the
+    choice is made on score + `bias [E]` (the layer's `router_bias`, where
+    the model has one) and, with `n_group` > 1, within the `topk_group`
+    groups whose two best sum highest; the weights are the chosen experts'
+    scores without the bias, over their sum + `norm_topk_eps` where
+    `norm_topk_prob` (1e-20 as DeepSeek-V3's code has it, 1e-6 as
+    lfm2_moe's), times `routed_scaling_factor`."""
     k = cfg.experts_per_token
     if cfg.scoring_func == "softmax":
         probs = jax.nn.softmax(logits, axis=-1)
@@ -109,7 +111,8 @@ def route(logits: jax.Array, cfg, bias: Optional[jax.Array] = None):
     _, chosen = jax.lax.top_k(choose, k)
     weights = jnp.take_along_axis(scores, chosen, axis=-1)
     if cfg.norm_topk_prob:
-        weights = weights / (weights.sum(-1, keepdims=True) + 1e-20)
+        weights = weights / (weights.sum(-1, keepdims=True)
+                             + cfg.norm_topk_eps)
     return scores, weights * cfg.routed_scaling_factor, chosen
 
 

@@ -11,13 +11,16 @@ interleaved between decode steps), emit tokens as they are produced, and
 free their slot the moment they finish — the vLLM-style iteration-level
 scheduling, built TPU-first:
 
-  * A model with recurrent layers (a hybrid: Mamba-2 among attention
-    layers) gives every slot a row of fixed-size state beside its pages,
-    in a second pool that the same step programs carry and update in
-    place. What follows from the model and is no option: such a model
-    serves without a prefix cache (a prompt cannot resume below a shared
-    prefix without the state at that boundary, and no snapshots are
-    kept) and without tensor parallelism (the mixer is not partitioned).
+  * A model with recurrent layers (a hybrid: Mamba-2 layers or gated
+    short convolutions among attention layers, its MLPs dense or routed)
+    gives every slot a row of fixed-size state beside its pages, in a
+    second pool that the same step programs carry and update in place;
+    where it has experts, `stats()` reports their routing counters
+    (`"moe"`) beside the recurrent ones (`"ssm"`). What follows from the
+    model and is no option: such a model serves without a prefix cache
+    (a prompt cannot resume below a shared prefix without the state at
+    that boundary, and no snapshots are kept) and without tensor
+    parallelism (the mixer is not partitioned).
   * Static shapes everywhere: the decode step is jitted ONCE for the
     slot count and prompts prefill in fixed-size CHUNKS, one PASS of
     them between decode steps: a pass is one program over one row of a
@@ -626,7 +629,8 @@ class ContinuousBatchingEngine:
             if cfg.layer_pattern and mesh.shape["tp"] > 1:
                 raise ValueError(
                     "a model with recurrent layers serves on one chip: its "
-                    "recurrent pool (Mamba-2 state and convolution inputs) "
+                    "recurrent pool (a recurrent state or a convolution's "
+                    "inputs a slot and layer) "
                     f"is not sharded over tp={mesh.shape['tp']}"
                 )
             if cfg.kv_lora_rank and mesh.shape["tp"] > 1:

@@ -77,6 +77,7 @@ import numpy as np
 
 from ray_tpu.models import mamba2
 from ray_tpu.models.transformer import (
+    RECURRENT_KINDS,
     TransformerConfig,
     _embed_tokens,
     at_layer,
@@ -85,6 +86,7 @@ from ray_tpu.models.transformer import (
     latent_layer,
     latent_stacks,
     layer_kinds,
+    mix_recurrent,
     project_logits,
     project_qkv,
     residual,
@@ -319,8 +321,7 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
     its rope key side by side and zeros up to whole lanes
     (`latent_row_width`), nothing a head, and `v` is None, an argument
     without a buffer (the engine refuses such a pool under `tp` > 1)."""
-    kv_layers = (cfg.layers_of("attention") if cfg.layer_pattern
-                 else cfg.n_layers)
+    kv_layers = cfg.attention_layers if cfg.layer_pattern else cfg.n_layers
     # A row's heads side by side, one layout for every program that
     # touches the pool: a page is then whole tiles whatever a head's
     # width, which is how the decode kernel fetches it, and a head is a
@@ -359,11 +360,15 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
 
 def init_recurrent_pool(cfg: TransformerConfig, slots: int) -> Dict:
     """The second pool of a model with recurrent layers: a row of fixed
-    size a slot and a Mamba layer, `state [ssm layers, slots, heads,
-    d_head, d_state]` float32 and the convolution's last inputs `conv [ssm
-    layers, slots, d_conv - 1, conv_dim]` in the weights' dtype. It rides
-    in the layer walk's carry beside the pages and is donated with them."""
-    return mamba2.init_state(cfg, cfg.layers_of("mamba"), slots)
+    size a slot and a recurrent layer. Mamba layers: `state [ssm layers,
+    slots, heads, d_head, d_state]` float32 and the convolution's last
+    inputs `conv [ssm layers, slots, d_conv - 1, conv_dim]` in the weights'
+    dtype. Gated short convolutions: their last gated inputs `conv [conv
+    layers, slots, conv_L_cache - 1, d_model]` in the weights' dtype and
+    nothing else. It rides in the layer walk's carry beside the pages and
+    is donated with them."""
+    return RECURRENT_KINDS[cfg.recurrent_kind][1].init_state(
+        cfg, cfg.recurrent_layers, slots)
 
 
 def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
@@ -375,8 +380,8 @@ def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
     [B, L, H, D])` encapsulates that. `mesh` is the engine's: activations
     are replicated over it, so the norm kernel runs whole on every
     device, and KV heads lie over its "tp". Returns the
-    layer's output, its caches and, for a model with experts, the
-    assignments each expert received `[E]` (else None); `layer` is
+    layer's output, its caches and, for a layer with a router among its
+    leaves, the assignments each expert received `[E]` (else None); `layer` is
     `moe_block`'s: the index at which `lp`'s expert stacks, then the
     whole model's, are read in place. `cos` is None for a model without
     a position embedding."""
@@ -389,7 +394,7 @@ def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
     k_cache_l, v_cache_l, attn = attend(k_cache_l, v_cache_l, q, k, v)
     x = residual(x, (attn.reshape(b, l, -1) @ lp["wo"]).astype(x.dtype), cfg)
     h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh)
-    if cfg.num_experts:
+    if "router" in lp:
         y, routing = moe_block(h.reshape(b * l, -1), lp, cfg, layer)
         return (x + y.reshape(b, l, -1), k_cache_l, v_cache_l,
                 routing["counts"])
@@ -592,73 +597,126 @@ def _walk_hybrid(params, x, k_cache, v_cache, rec, attend, rec_io,
     the KV pool (attention layers only, indexed by their own count) and
     the recurrent pool `rec` both ride in the carry and are updated in
     place. ONE scan over the attention layers; each of its steps first
-    walks the run of Mamba layers that stands before its attention layer
-    (a `fori_loop` whose bounds are the scan's inputs: 5, 9, 9, 9 for
-    granite-4.0-h-micro), and the Mamba layers after the last attention
-    layer follow in a loop of their own. So a Mamba layer is compiled
-    twice and an attention layer once, whatever the depth and whatever
-    the pattern, and every buffer is a loop carry from the first layer to
-    the last. (One scan over all layers with a `lax.cond` on the kind
-    would hand each pool through the branch that does not touch it, and a
-    conditional's result that is its own argument is copied: 3.7 GB four
-    times a step here.) Every layer reads its weights from its kind's
+    walks the run of recurrent layers that stands before its attention
+    layer (a `fori_loop` whose bounds are the scan's inputs: 5, 9, 9, 9 for
+    granite-4.0-h-micro), and the recurrent layers after the last
+    attention layer follow in a loop of their own. So a recurrent layer is
+    compiled twice and an attention layer once, whatever the depth and
+    whatever the pattern, and every buffer is a loop carry from the first
+    layer to the last. (One scan over all layers with a `lax.cond` on the
+    kind would hand each pool through the branch that does not touch it,
+    and a conditional's result that is its own argument is copied: 3.7 GB
+    four times a step here.) Every layer reads its weights from its kind's
     stack at its own index.
 
-    `rec_io = (read, write)`: `read(rec, j) -> (state [B, H, P, N], conv
-    [B, K-1, C])` of the rows this call advances in Mamba layer `j`, and
-    `write(rec, j, state, conv) -> rec`; `n_valid [B]` real rows of each
-    (`mamba2.mixer`). `rec_io` None: the call advances every slot's row
-    by one token, and the mixer takes the whole pool of states and `j`
-    (`ops.ssm_update`: layer `j` is passed over once, where it lies).
-    Returns x, the caches and `rec`."""
+    A model with experts is walked as two such spans, split where the MLP
+    changes kind and not branched there: the leading layers, whose MLP is
+    dense (`layers["mlp"]`), then the expert layers, each reading its
+    router from `layers["moe"]` and the expert stacks whole at its own
+    index among them (`moe_block(..., layer=)`, as `_scan_layers` does).
+    The assignments each expert layer's experts received ride in the carry
+    too, `[expert layers, E]`.
+
+    `rec_io = (read, write)`: `read(rec, j) -> {name: rows [B, ...]}` of
+    the rows this call advances in recurrent layer `j`, by the pool's own
+    names, and `write(rec, j, rows) -> rec`; `n_valid [B]` real rows of
+    each (`transformer.mix_recurrent`). `rec_io` None: the call
+    advances every slot's row by one token where it lies in the pool, and
+    a Mamba mixer takes the whole pool of states and `j` (`ops.ssm_update`:
+    layer `j` is passed over once). Returns x, the caches, `rec` and the
+    assignments (None without experts)."""
     layers = params["layers"]
-    is_mamba, _ = layer_kinds(cfg)
-    attn_at = np.flatnonzero(~is_mamba)          # each attention layer's place
-    run_from = np.concatenate([[0], attn_at[:-1] + 1])
-    n_attn = len(attn_at)
+    kind = cfg.recurrent_kind
+    stack = layers[RECURRENT_KINDS[kind][0]]
+    is_recurrent, within = layer_kinds(cfg)
+    n_attn, n_dense = cfg.attention_layers, cfg.n_layers - cfg.expert_layers
+    experts, routers, counts = {}, None, None
+    if cfg.expert_layers:
+        experts = {n: layers["moe"][n] for n in EXPERT_LEAVES}
+        routers = {n: w for n, w in layers["moe"].items() if n not in experts}
+        counts = jnp.zeros((cfg.expert_layers, cfg.num_experts), jnp.int32)
 
-    def mamba_run(x, rec, first, ssm_first, count):
-        """`count` Mamba layers from layer `first`, the `ssm_first`-th of
-        their kind."""
+    def mlp_at(i, dense):
+        """Layer `i`'s MLP leaves, and `moe_block`'s `layer` for them."""
+        if dense:
+            return at_layer(layers["mlp"], i), None
+        return {**at_layer(routers, i - n_dense), **experts}, i - n_dense
+
+    def mix(h, lp, rec, j):
+        if kind == "mamba" and rec_io is None:
+            out, state, conv = mamba2.mixer(
+                h, lp, cfg, rec["state"], rec["conv"][j], n_valid, layer=j)
+            return out, {"state": state, "conv": rec["conv"].at[j].set(conv)}
+        rows = at_layer(rec, j) if rec_io is None else rec_io[0](rec, j)
+        out, rows = mix_recurrent(h, lp, cfg, rows, n_valid)
+        if rec_io is None:
+            return out, {name: rec[name].at[j].set(new)
+                         for name, new in rows.items()}
+        return out, rec_io[1](rec, j, rows)
+
+    def recurrent_run(x, rec, counts, first, rec_first, count, dense):
+        """`count` recurrent layers from layer `first`, the `rec_first`-th
+        of their kind."""
         def one(t, carry):
-            x, rec = carry
-            j = ssm_first + t
-            lp = at_layer(layers["ssm"], j)
-            mlp = at_layer(layers["mlp"], first + t)
+            x, rec, counts = carry
+            lp = at_layer(stack, rec_first + t)
             h = rmsnorm(x, lp["norm"], cfg.norm_eps, mesh=mesh)
-            if rec_io is None:
-                out, state, conv = mamba2.mixer(
-                    h, lp, cfg, rec["state"], rec["conv"][j], n_valid, layer=j)
-                rec = {"state": state, "conv": rec["conv"].at[j].set(conv)}
-            else:
-                read_rec, write_rec = rec_io
-                state, conv = read_rec(rec, j)
-                out, state, conv = mamba2.mixer(h, lp, cfg, state, conv,
-                                                n_valid)
-                rec = write_rec(rec, j, state, conv)
+            out, rec = mix(h, lp, rec, rec_first + t)
             x = residual(x, out, cfg)
+            mlp, j = mlp_at(first + t, dense)
             h = rmsnorm(x, mlp["mlp_norm"], cfg.norm_eps, mesh=mesh)
-            return residual(x, dense_mlp(h, mlp, cfg), cfg), rec
+            if dense:
+                return residual(x, dense_mlp(h, mlp, cfg), cfg), rec, counts
+            b, l, d = x.shape
+            y, routing = moe_block(h.reshape(b * l, d), mlp, cfg, j)
+            return (x + y.reshape(b, l, d), rec,
+                    counts.at[j].set(routing["counts"]))
 
-        return jax.lax.fori_loop(0, count, one, (x, rec))
+        return jax.lax.fori_loop(0, count, one, (x, rec, counts))
 
-    def period(carry, inputs):
-        x, kc, vc, rec = carry
-        attn, a, at, first = inputs
-        x, rec = mamba_run(x, rec, first, first - a, at - first)
-        x, kc, vc, _ = _layer_body(
-            x, {**attn, **at_layer(layers["mlp"], at)}, kc, vc, cfg, cos, sin,
-            positions, functools.partial(attend, a), mesh)
-        return (x, kc, vc, rec), None
+    def span(carry, lo, hi, dense):
+        """Layers `[lo, hi)`, whose MLPs are all `dense` or all routed."""
+        attn_at = lo + np.flatnonzero(~is_recurrent[lo:hi])
+        tail = lo
+        if len(attn_at):
+            run_from = np.concatenate([[lo], attn_at[:-1] + 1])
+            a0 = int(within[attn_at[0]])
+            attn = layers["attn"]
+            if len(attn_at) < n_attn:
+                attn = jax.tree.map(lambda w: w[a0:a0 + len(attn_at)], attn)
 
-    (x, k_cache, v_cache, rec), _ = jax.lax.scan(
-        period, (x, k_cache, v_cache, rec),
-        (layers["attn"], jnp.arange(n_attn, dtype=jnp.int32),
-         jnp.asarray(attn_at, jnp.int32), jnp.asarray(run_from, jnp.int32)))
-    tail = int(attn_at[-1]) + 1
-    if tail < cfg.n_layers:
-        x, rec = mamba_run(x, rec, tail, tail - n_attn, cfg.n_layers - tail)
-    return x, k_cache, v_cache, rec
+            def period(carry, inputs):
+                x, kc, vc, rec, counts = carry
+                attn, a, at, first = inputs
+                x, rec, counts = recurrent_run(
+                    x, rec, counts, first, first - a, at - first, dense)
+                mlp, j = mlp_at(at, dense)
+                x, kc, vc, got = _layer_body(
+                    x, {**attn, **mlp}, kc, vc, cfg, cos, sin, positions,
+                    functools.partial(attend, a), mesh, j)
+                if got is not None:
+                    counts = counts.at[j].set(got)
+                return (x, kc, vc, rec, counts), None
+
+            carry, _ = jax.lax.scan(
+                period, carry,
+                (attn, a0 + jnp.arange(len(attn_at), dtype=jnp.int32),
+                 jnp.asarray(attn_at, jnp.int32),
+                 jnp.asarray(run_from, jnp.int32)))
+            tail = int(attn_at[-1]) + 1
+        if tail < hi:
+            x, kc, vc, rec, counts = carry
+            x, rec, counts = recurrent_run(
+                x, rec, counts, tail, int(within[tail]), hi - tail, dense)
+            carry = (x, kc, vc, rec, counts)
+        return carry
+
+    carry = (x, k_cache, v_cache, rec, counts)
+    if n_dense:
+        carry = span(carry, 0, n_dense, True)
+    if cfg.expert_layers:
+        carry = span(carry, n_dense, cfg.n_layers, False)
+    return carry
 
 
 def init_ssm_counters() -> Dict:
@@ -918,10 +976,9 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
     else:
         # Every slot's row of a layer at once, advanced where it lies in
         # the pool: an idle slot's row passes through unchanged.
-        x, k_new, v_new, rec = _walk_hybrid(
+        x, k_new, v_new, rec, counts = _walk_hybrid(
             params, x, k_pages, v_pages, rec, attend, None,
             active.astype(jnp.int32), cfg, cos, sin, positions, mesh)
-        counts = None
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
     logits = project_logits(x[:, -1], params, cfg)
     new_lengths = jnp.where(active, lengths + 1, lengths)
@@ -1029,23 +1086,24 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
         # A row's slot at a time: a slice of the pool at `[j, slot]` read,
         # and written in place where it lies, as a pass of one row does.
         def read_rec(rec, j):
-            state = jnp.stack([rec["state"][j, slot[r]] for r in range(p_)])
-            conv = jnp.stack([rec["conv"][j, slot[r]] for r in range(p_)])
-            return (jnp.where(carried[:, None, None, None], state, 0.0),
-                    jnp.where(carried[:, None, None], conv, 0))
+            def rows(pool):
+                got = jnp.stack([pool[j, slot[r]] for r in range(p_)])
+                return jnp.where(
+                    carried.reshape((-1,) + (1,) * (got.ndim - 1)), got, 0)
 
-        def write_rec(rec, j, state, conv):
+            return {name: rows(pool) for name, pool in rec.items()}
+
+        def write_rec(rec, j, rows):
             out = dict(rec)
-            for name, new in (("state", state), ("conv", conv)):
+            for name, new in rows.items():
                 for r in range(p_):  # an inert row puts back what it found
                     row = jnp.where(real[r], new[r], out[name][j, slot[r]])
                     out[name] = out[name].at[j, slot[r]].set(row)
             return out
 
-        x, k_new, v_new, rec = _walk_hybrid(
+        x, k_new, v_new, rec, counts = _walk_hybrid(
             params, x, k_pages, v_pages, rec, attend, (read_rec, write_rec),
             n_valid, cfg, cos, sin, positions, mesh)
-        counts = None
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh)
     last = jnp.take_along_axis(
         x, jnp.maximum(n_valid - 1, 0)[:, None, None], axis=1)

@@ -44,7 +44,11 @@ def test_a_listed_configuration_is_held_to_its_three_keys(entry):
     assert cfg.n_layers == doc["num_hidden_layers"]
     from ray_tpu.models import configs
 
-    full_depth = configs.get_config(doc["model"]).n_layers
+    # The published depth is the named model's, or, where the program
+    # names the cut itself (a hybrid's layer pattern is cut with its
+    # depth), what the file states under `published`.
+    full_depth = (doc.get("published") or {}).get(
+        "num_hidden_layers", configs.get_config(doc["model"]).n_layers)
     assert ("num_hidden_layers" in entry["reduced"]) == (
         cfg.n_layers != full_depth)
     extra = doc.get("published_extra") or {}

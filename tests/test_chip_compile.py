@@ -687,3 +687,128 @@ def test_latent_decode_reads_its_live_pages_in_place(v5e, monkeypatch):
     # And the decode program is one the reader picks.
     assert any(program.search(reduce._short(line.strip()))
                for line in text.splitlines() if " = " in line)
+
+
+LFM2_SLOTS, LFM2_MAX_LEN, LFM2_CHUNK = 96, 2560, 256
+LFM2_FILE = "lfm2-24b-a2b-serve.json"
+
+
+def _lfm2_program(program, one, rows=1):
+    """`decode_paged` or `prefill_chunk_paged` at the sizes of the cell
+    `serve-lfm2-gen-closed` (LFM2-24B-A2B's first ten layers, 96 slots x
+    2560, pages of 16, a pass of `rows` rows of 256), on shapes, with both
+    accumulators in the tail as the engine passes them: (fn, donated, args,
+    the cache's shapes, cfg)."""
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.serve import paged_kv
+
+    cfg = dataclasses.replace(configs.get_config("lfm2-24b-a2b-l10"),
+                              remat=False)
+    slots, per_slot = LFM2_SLOTS, LFM2_MAX_LEN // PAGE
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    def struct(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, slots, slots * per_slot + 1, PAGE, per_slot)))
+    moe = on_chip(jax.eval_shape(
+        lambda: paged_kv.init_routing_counters(cfg)))
+    count = on_chip(jax.eval_shape(paged_kv.init_ssm_counters))
+    pool = (cache["k"], cache["v"], cache["lengths"])
+    if program == "decode_paged":
+        fn = lambda p, t, k, v, ln, a, bt, tp, tk, tpp, key, moe, rec, c: (  # noqa: E731
+            paged_kv.decode_paged(p, t, k, v, ln, a, bt, tp, tk, tpp, key,
+                                  cfg, LFM2_MAX_LEN, None, moe, rec, c))
+        args = (params, struct((slots,)), *pool, struct((slots,), jnp.bool_),
+                cache["block_tables"], struct((slots,), jnp.float32),
+                struct((slots,)), struct((slots,), jnp.float32),
+                struct((2,), jnp.uint32), moe, cache["rec"], count)
+        return fn, (2, 3, 12), args, cache, cfg
+    fn = lambda p, t, n, s, o, k, v, ln, bt, moe, rec, c: (  # noqa: E731
+        paged_kv.prefill_chunk_paged(p, t, n, s, o, k, v, ln, bt, cfg,
+                                     LFM2_MAX_LEN, None, moe, rec, c))
+    row = struct((rows,))
+    args = (params, struct((rows, LFM2_CHUNK)), row, row, row, *pool,
+            cache["block_tables"], moe, cache["rec"], count)
+    return fn, (5, 6, 10), args, cache, cfg
+
+
+# The cell's per-layer metrics that read a trace by an operation's name,
+# and the step program in which each has to find one.
+LFM2_TRACE_METRICS = {
+    "decode_paged": ("moe.expert_time_share.lfm2",
+                     "moe.dispatch_time_share.lfm2",
+                     "moe.expert_roofline_share.lfm2",
+                     "conv.mixer_time_share.lfm2",
+                     "attn.decode_time_share.lfm2",
+                     "sampler.time_share.lfm2"),
+    "prefill_chunk_paged": ("moe.expert_time_share.lfm2",
+                            "moe.dispatch_time_share.lfm2",
+                            "conv.mixer_time_share.lfm2"),
+}
+
+
+@pytest.mark.parametrize("program,rows", [
+    ("decode_paged", 1), ("prefill_chunk_paged", 1),
+    ("prefill_chunk_paged", 2)], ids=["decode", "prefill-1", "prefill-2"])
+def test_conv_expert_hybrid_steps_fit_and_read_their_stacks_in_place(
+        v5e, program, rows, monkeypatch):
+    """The cell `serve-lfm2-gen-closed`'s step programs at its own sizes
+    (`_lfm2_program`): the arguments are the bytes its configuration file
+    states, the pages and the conv pool come back in the buffers they came
+    in, the expert stacks `[8, 64, ...]` are read in place by the grouped
+    products of the three compiled expert layers (a conv layer in the
+    scan's run, the attention layer, a conv layer in the tail) and by none
+    in the two leading dense layers, and every trace metric the cell adds
+    finds an operation of its pattern among the names the compiler
+    prints."""
+    import json
+
+    _bench_on_path()
+    import spec
+    from xplane import reduce
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, donated, args, cache, cfg = _lfm2_program(
+        program, SingleDeviceSharding(v5e[0]), rows)
+    assert sorted(cache["rec"]) == ["conv"]
+    assert cache["rec"]["conv"].shape == (8, 96, 2, 2048)
+    assert cache["k"].shape == (2, 96 * 160 + 1, PAGE, 512)
+    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    with open(os.path.join(spec.BENCH, "configs", LFM2_FILE)) as f:
+        stated = json.load(f)["compiled"]
+    key = "decode" if program == "decode_paged" else f"prefill_{rows}"
+    assert memory.argument_size_in_bytes == stated[key]["arguments_bytes"]
+    assert memory.temp_size_in_bytes <= stated[key]["temporaries_bytes_at_most"]
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12.1e9
+    pools = sum(a.size * a.dtype.itemsize for a in (
+        cache["k"], cache["v"], cache["rec"]["conv"]))
+    assert memory.alias_size_in_bytes >= pools
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = f32\[", text)) == 9
+    assert len(re.findall(r"%paged_decode_attention[.\d]* = f32\[", text)) == (
+        program == "decode_paged")
+    for inner in ("2048,1536", "1536,2048"):
+        assert not re.findall(
+            rf"= bf16\[(?:1,|8,)?64,{inner}\]\S* (?:copy|fusion)\(", text)
+        assert not re.findall(rf"= bf16\[512,{inner}\]\S* copy\(", text)
+    short = [reduce._short(line.strip().removeprefix("ROOT "))
+             for line in text.splitlines() if " = " in line]
+    for metric in LFM2_TRACE_METRICS[program]:
+        how = spec.layer_metric_spec(metric)
+        assert any(re.search(how["match"], op) for op in short), metric
+    step = ("decode.device_ms_per_step.lfm2" if program == "decode_paged"
+            else "prefill.device_ms_per_chunk.lfm2")
+    other = ("prefill.device_ms_per_chunk.lfm2" if program == "decode_paged"
+             else "decode.device_ms_per_step.lfm2")
+    assert any(re.search(spec.layer_metric_spec(step)["contains_op"], op)
+               for op in short)
+    assert not any(re.search(spec.layer_metric_spec(other)["contains_op"], op)
+                   for op in short)
