@@ -14,7 +14,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import PartitionSpec as P
 
-from ray_tpu.ops.per_shard import per_shard
+from ray_tpu.ops.per_shard import is_per_device, per_shard
 
 
 def _rmsnorm_ref(x, w, eps):
@@ -139,23 +139,6 @@ def _rmsnorm_pallas_bwd2(x2, w, g2, eps, block_rows, interpret):
     return dx[:rows], dw_acc.sum(axis=0).astype(w.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4))
-def _rmsnorm_pallas_core(x2, w, eps, block_rows, interpret):
-    return _rmsnorm_pallas_fwd2(x2, w, eps, block_rows, interpret)
-
-
-def _pallas_core_fwd(x2, w, eps, block_rows, interpret):
-    return _rmsnorm_pallas_fwd2(x2, w, eps, block_rows, interpret), (x2, w)
-
-
-def _pallas_core_bwd(eps, block_rows, interpret, res, g):
-    x2, w = res
-    return _rmsnorm_pallas_bwd2(x2, w, g, eps, block_rows, interpret)
-
-
-_rmsnorm_pallas_core.defvjp(_pallas_core_fwd, _pallas_core_bwd)
-
-
 # A TPU kernel's blocks and temporaries live in scoped VMEM, 16 MiB by
 # default on v5e ("Scoped allocation ... limit 16.00M" is the compiler's
 # refusal). Half of it is budgeted for what _block_rows_for counts; the
@@ -176,22 +159,49 @@ def _block_rows_for(d: int, dtype) -> int:
     return max(sublane, min(_MAX_BLOCK_ROWS, rows))
 
 
-def _rmsnorm_pallas(x, w, eps, block_rows: Optional[int] = None,
-                    interpret: bool = False):
-    orig_shape = x.shape
+@functools.partial(jax.custom_vjp, nondiff_argnums=(2, 3, 4, 5, 6))
+def _rmsnorm_pallas(x, w, eps, block_rows: int, interpret: bool = False,
+                    mesh=None, spec: P = P()):
+    """The kernels on each device's rows (every axis of x but the last),
+    differentiated on whole arrays.
+
+    The rule lies outside the per-device region so that JAX transposes no
+    shard_map: `spec` may leave a mesh axis unnamed ("tp", for the
+    activations), and the transpose of such a region divides the
+    cotangent and all-reduces dx over that axis (ops.per_shard): two
+    passes over the activation that compute the identity."""
     d = x.shape[-1]
-    rows = int(np_prod(orig_shape[:-1]))  # rtlint: disable=RT001 — static shape math: fine at trace time
-    if block_rows is None:
-        block_rows = _block_rows_for(d, x.dtype)
-    out = _rmsnorm_pallas_core(x.reshape(rows, d), w, eps, block_rows, interpret)
-    return out.reshape(orig_shape)
+
+    def forward(x, w):
+        return _rmsnorm_pallas_fwd2(x.reshape(-1, d), w, eps, block_rows,
+                                    interpret).reshape(x.shape)
+
+    return per_shard(forward, mesh, (spec, P()), spec)(x, w)
 
 
-def np_prod(shape):
-    out = 1
-    for s in shape:
-        out *= s
-    return out
+def _pallas_fwd(x, w, eps, block_rows, interpret, mesh, spec):
+    return _rmsnorm_pallas(x, w, eps, block_rows, interpret, mesh, spec), (x, w)
+
+
+def _pallas_bwd(eps, block_rows, interpret, mesh, spec, res, g):
+    x, w = res
+    d = x.shape[-1]
+    # The rows are split over the mesh axes `spec` names and over nothing
+    # else, so the scale's gradient sums over those; where the code
+    # already runs on one device's rows, whoever made that region sums.
+    sum_over = () if is_per_device(mesh) else tuple(
+        axis for entry in spec if entry is not None
+        for axis in (entry if isinstance(entry, tuple) else (entry,)))
+
+    def backward(x, w, g):
+        dx, dw = _rmsnorm_pallas_bwd2(x.reshape(-1, d), w, g.reshape(-1, d),
+                                      eps, block_rows, interpret)
+        return dx.reshape(x.shape), jax.lax.psum(dw, sum_over)
+
+    return per_shard(backward, mesh, (spec, P(), spec), (spec, P()))(x, w, g)
+
+
+_rmsnorm_pallas.defvjp(_pallas_fwd, _pallas_bwd)
 
 
 def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-6,
@@ -205,5 +215,5 @@ def rmsnorm(x: jax.Array, w: jax.Array, eps: float = 1e-6,
         use_pallas = jax.default_backend() == "tpu"
     if not (use_pallas or interpret):
         return _rmsnorm_xla(x, w, eps)
-    kernel = functools.partial(_rmsnorm_pallas, eps=eps, interpret=interpret)
-    return per_shard(kernel, mesh, (spec, P()), spec)(x, w)
+    return _rmsnorm_pallas(x, w, eps, _block_rows_for(x.shape[-1], x.dtype),
+                           interpret, mesh, spec)

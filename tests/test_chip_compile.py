@@ -108,14 +108,20 @@ def test_rmsnorm_compiles_at_engine_rows(v5e, rows):
     assert _kernel_count(_compile(fn, x, w)) == 1
 
 
-def test_kernels_compile_under_a_sharded_jit(v5e):
+@pytest.fixture(scope="module")
+def sharded_mesh(v5e):
+    from ray_tpu.parallel import MeshConfig, build_mesh
+
+    return build_mesh(MeshConfig(fsdp=2, tp=2), v5e)
+
+
+def test_kernels_compile_under_a_sharded_jit(sharded_mesh):
     """GSPMD cannot partition a Mosaic kernel; given the mesh, the ops
     run it on each device's block, and the gradient of the replicated
     scale comes back through a collective."""
     from ray_tpu.models.transformer import _ACT_SPEC, _HEADS_SPEC
-    from ray_tpu.parallel import MeshConfig, build_mesh
 
-    mesh = build_mesh(MeshConfig(fsdp=2, tp=2), v5e)
+    mesh = sharded_mesh
 
     def struct(shape, spec):
         return jax.ShapeDtypeStruct(shape, jnp.bfloat16,
@@ -163,6 +169,111 @@ def test_kernels_compile_under_a_sharded_jit(v5e):
     compiled = _compile(attend, queries, pool, pool, whole(()),
                         whole((SLOTS, MAX_LEN // PAGE)), whole((SLOTS,)))
     assert "paged_decode_attention" in compiled.as_text()
+
+
+COLLECTIVE = r"(?:all-reduce|all-gather|reduce-scatter|all-to-all|collective-permute)"
+
+
+def _results_of(text, op, shape):
+    """(name, op_name) of every instruction of a compiled text whose
+    result is `shape` (as the compiler prints it, layout apart) and whose
+    operation matches `op`, the asynchronous form's start included."""
+    line = re.compile(rf"^\s*(?:ROOT )?(\S+) = \(?{re.escape(shape)}[^=]*? "
+                      rf"{op}(?:-start)?\(.*$", re.MULTILINE)
+    found = []
+    for m in line.finditer(text):
+        op_name = re.search(r'op_name="([^"]*)"', m.group(0))
+        found.append((m.group(1), op_name.group(1) if op_name else ""))
+    return found
+
+
+def test_sharded_train_step_reduces_an_activation_four_times_a_layer(
+        sharded_mesh, monkeypatch):
+    """`train-fsdp2tp2-8x1024`'s step (bench/train_cell.py: AdamW over
+    `value_and_grad(loss_fn)`, `dots_nobatch`, a loss chunk of 256, 8 x
+    1025 tokens) at depth 2, the layers being one scan: the all-reduces
+    of a device's activation `bf16[4,1024,2560]` are the two a layer that
+    `tp=2` requires forward (after `wo` and `w_down`) and the two backward
+    (the cotangents of the norms' outputs, from the column-parallel
+    products), all the partitioner's. While the norm's rule lay inside
+    its per-device region, JAX transposed a `shard_map` whose operand was
+    replicated over "tp": the backward body halved each cotangent
+    (`checkpoint/div`) and all-reduced each dx over the same pairs again
+    (`shard_map/psum`), 21 MB each that computed the identity, 64 + 1
+    times a step at depth 32."""
+    import optax
+
+    from ray_tpu.models import loss_fn, param_logical_axes
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.parallel import logical_shardings
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    mesh = sharded_mesh
+    cfg = dataclasses.replace(QWEN, n_layers=2, max_seq=1024, remat=True,
+                              remat_policy="dots_nobatch", ce_chunk=256)
+    replicated = NamedSharding(mesh, P())
+    params = jax.tree.map(
+        lambda a, sharding: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                 sharding=sharding),
+        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)),
+        logical_shardings(param_logical_axes(cfg), mesh))
+    optimizer = optax.adamw(3e-4)
+    # The moments lie as their parameters do, the count is whole.
+    moments, *rest = jax.eval_shape(optimizer.init, params)
+    opt_state = (moments._replace(
+        count=jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated),
+        mu=params, nu=params), *rest)
+    layouts = jax.tree.map(lambda x: x.sharding, (params, opt_state))
+    tokens = jax.ShapeDtypeStruct(
+        (8, 1025), jnp.int32,
+        sharding=NamedSharding(mesh, P(("dp", "fsdp"), None)))
+
+    def step(params, opt_state, tokens):
+        loss, grads = jax.value_and_grad(loss_fn)(params, tokens, cfg, mesh)
+        updates, opt_state = optimizer.update(grads, opt_state, params)
+        return optax.apply_updates(params, updates), opt_state, loss
+
+    text = jax.jit(
+        step, donate_argnums=(0, 1), out_shardings=(*layouts, replicated),
+    ).lower(params, opt_state, tokens).compile().as_text()
+    assert text.count("tpu_custom_call") >= 8  # the kernels are in it
+    activation = f"bf16[4,1024,{QWEN.d_model}]"
+    reduced = _results_of(text, "all-reduce", activation)
+    in_body = [op for _, op in reduced if "/while/body/" in op]
+    assert len(reduced) == len(in_body) == 4, reduced
+    assert sum("transpose(" in op for op in in_body) == 2, reduced
+    assert all(op.endswith("/dot_general") for op in in_body), reduced
+    assert "shard_map/psum" not in " ".join(
+        op for _, op in _results_of(text, COLLECTIVE, activation))
+    # Nothing passes over an activation to divide it by the replicas.
+    assert not [op for _, op in _results_of(text, "fusion", activation)
+                if op.endswith("/div")]
+
+
+def test_flash_attention_backward_under_a_mesh_moves_no_operand(sharded_mesh):
+    """`_HEADS_SPEC` names every axis of this mesh that is wider than
+    one, so the transpose of the attention's per-device region neither
+    divides nor sums: no collective carries anything of q's, k's or v's
+    shape, a device's block or the whole."""
+    from ray_tpu.models.transformer import _HEADS_SPEC
+
+    def struct(heads):
+        return jax.ShapeDtypeStruct(
+            (8, 1024, heads, QWEN.head_dim), jnp.bfloat16,
+            sharding=NamedSharding(sharded_mesh, _HEADS_SPEC))
+
+    def loss(q, k, v):
+        return flash_attention(q, k, v, use_pallas=True, mesh=sharded_mesh,
+                               spec=_HEADS_SPEC).astype(jnp.float32).sum()
+
+    q, kv = struct(QWEN.n_heads), struct(QWEN.n_kv_heads)
+    text = _compile(jax.grad(loss, argnums=(0, 1, 2)), q, kv, kv).as_text()
+    assert text.count("tpu_custom_call") >= 3
+    for batch in (8, 4):
+        for heads in {QWEN.n_heads, QWEN.n_heads // 2,
+                      QWEN.n_kv_heads, QWEN.n_kv_heads // 2}:
+            shape = f"bf16[{batch},1024,{heads},{QWEN.head_dim}]"
+            assert not _results_of(text, COLLECTIVE, shape), shape
 
 
 # The serving cell's geometry (bench/configs/qwen3-4b-serve.json): 24 slots
