@@ -19,6 +19,7 @@ the framework so JaxTrainer/Serve/RL all share it.
 
 from __future__ import annotations
 
+import functools
 from dataclasses import dataclass, field, replace
 from typing import Any, Dict, Optional, Tuple
 
@@ -28,8 +29,10 @@ import numpy as np
 from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import apply_rope, flash_attention, rmsnorm, rope_frequencies, softmax_cross_entropy
+from ray_tpu.ops.cross_entropy import chunked_lm_head_ce
 from ray_tpu.ops.rope import yarn_inv_freq, yarn_mscale
 from ray_tpu.models import mamba2, shortconv
+from ray_tpu.parallel.mesh import DEFAULT_RULES, with_sharding_constraint
 from ray_tpu.parallel.moe import load_balancing_loss, moe_block
 
 
@@ -1104,6 +1107,70 @@ def forward_pipelined(
     return project_logits(x, params, cfg), jnp.zeros((), dtype=jnp.float32)
 
 
+def _head_axes(cfg: TransformerConfig):
+    """`lm_head_weight`'s logical axes `[D, V]`, from the parameter's own."""
+    axes = param_logical_axes(cfg)
+    return axes["embed"][::-1] if cfg.tie_embeddings else axes["lm_head"]
+
+
+def _table_split_on_model_axis(cfg: TransformerConfig, mesh) -> bool:
+    """Whether `mesh` splits the head's table on its model axis."""
+    return (mesh is not None
+            and any(mesh.shape.get(a, 1) > 1 for a in DEFAULT_RULES["embed"])
+            and "embed" in _head_axes(cfg))
+
+
+def _chunked_loss(hidden, table, labels, cfg: TransformerConfig):
+    return chunked_lm_head_ce(hidden, table, labels, cfg.ce_chunk,
+                              softcap=cfg.final_logit_softcap)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _chunked_loss_table_whole(hidden, table, labels, cfg, mesh, axes):
+    """`_chunked_loss` of a table that `mesh` splits over "fsdp" on its
+    model axis (`axes`, its logical axes): gathered once for both of the
+    loss's scans (the forward, and the backward's, which recomputes each
+    chunk's logits), its gradient accumulated whole over the chunks and
+    reduce-scattered once after them. The layouts are constraints; the
+    collectives are the partitioner's.
+
+    The rule is written out because the hint alone costs memory where the
+    step is deepest. Nothing but the returned loss reads the forward scan,
+    so the scheduler puts it last and keeps the gathered table through the
+    layers' backward; it puts the reduce-scatter after the layers'
+    backward too and keeps the gathered gradient as long. The two barriers
+    say what the data does not: the backward scan follows the forward one,
+    and the layers' backward follows the reduce-scatter."""
+    return _table_whole_fwd(hidden, table, labels, cfg, mesh, axes)[0]
+
+
+def _whole_on_model_axis(x, mesh, axes):
+    return with_sharding_constraint(
+        x, tuple(None if a == "embed" else a for a in axes), mesh)
+
+
+def _table_whole_fwd(hidden, table, labels, cfg, mesh, axes):
+    table = _whole_on_model_axis(table, mesh, axes)
+    loss = _chunked_loss(hidden, table, labels, cfg)
+    return loss, (hidden, table, labels, loss)
+
+
+def _table_whole_bwd(cfg, mesh, axes, residuals, g):
+    hidden, table, labels, loss = residuals
+    hidden, table, _ = jax.lax.optimization_barrier((hidden, table, loss))
+    _, vjp = jax.vjp(lambda h, t: _chunked_loss(h, t, labels, cfg),
+                     hidden, table)
+    d_hidden, d_table = vjp(g)
+    # Whole as the table was, so that the backward scan's carry is; then
+    # as the parameter lies: the one reduce-scatter.
+    d_table = _whole_on_model_axis(d_table, mesh, axes)
+    d_table = with_sharding_constraint(d_table, axes, mesh)
+    return (*jax.lax.optimization_barrier((d_hidden, d_table)), None)
+
+
+_chunked_loss_table_whole.defvjp(_table_whole_fwd, _table_whole_bwd)
+
+
 def loss_fn(params, tokens, cfg: TransformerConfig, mesh=None,
             aux_weight: float = 0.01):
     """Next-token LM loss. tokens: [B, L]; predicts tokens[:, 1:].
@@ -1111,19 +1178,27 @@ def loss_fn(params, tokens, cfg: TransformerConfig, mesh=None,
     With a pp>1 mesh the forward runs the GPipe microbatch pipeline; the
     backward differentiates straight through it (static-bound scan), which
     is what makes MeshConfig(pp=...) a real training capability.
+
+    The chunked loss is handed the head's table whole over "fsdp" (its
+    own logical axes with "embed" left whole, so still split over "tp"
+    on the vocabulary): the table does not change during the step, so it
+    crosses "fsdp" once each way, one all-gather before the loss's scans
+    and one reduce-scatter of its gradient after them. Left as the
+    parameter lies, the partitioner gathers it inside every chunk's body,
+    forward and backward, and reduce-scatters its gradient once a chunk.
     """
     labels = tokens[:, 1:]
     if mesh is not None and mesh.shape.get("pp", 1) > 1:
         logits, aux = forward_pipelined(params, tokens[:, :-1], cfg, mesh)
     elif cfg.ce_chunk:
-        from ray_tpu.ops.cross_entropy import chunked_lm_head_ce
-
         hidden, aux = forward(params, tokens[:, :-1], cfg, mesh,
                               return_hidden=True)
-        loss = chunked_lm_head_ce(
-            hidden, lm_head_weight(params, cfg), labels, cfg.ce_chunk,
-            softcap=cfg.final_logit_softcap,
-        )
+        table = lm_head_weight(params, cfg)
+        if _table_split_on_model_axis(cfg, mesh):
+            loss = _chunked_loss_table_whole(hidden, table, labels, cfg,
+                                             mesh, _head_axes(cfg))
+        else:
+            loss = _chunked_loss(hidden, table, labels, cfg)
         return loss + aux_weight * aux
     else:
         logits, aux = forward(params, tokens[:, :-1], cfg, mesh)

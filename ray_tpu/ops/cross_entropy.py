@@ -40,6 +40,12 @@ def chunked_lm_head_ce(hidden: jax.Array, lm_head: jax.Array,
     backward recomputes that chunk's logits (one extra lm_head forward,
     ~3% of step FLOPs at Llama shapes) instead of keeping them alive.
     The scan over chunks keeps peak logits memory at B*chunk*V.
+
+    Both scans (this one, and the backward's) close over `lm_head` as it
+    is handed in, and the backward's carry holds its gradient in the same
+    layout: under a mesh the caller hands it whole over the axes that
+    would otherwise be gathered in every chunk's body (`loss_fn` does,
+    over "fsdp"); `ce_chunk` bounds the logits, never the table.
     """
     b, s, d = hidden.shape
     if s % chunk != 0:

@@ -187,56 +187,77 @@ def _results_of(text, op, shape):
     return found
 
 
-def test_sharded_train_step_reduces_an_activation_four_times_a_layer(
-        sharded_mesh, monkeypatch):
+@pytest.fixture(scope="module")
+def sharded_step(sharded_mesh):
     """`train-fsdp2tp2-8x1024`'s step (bench/train_cell.py: AdamW over
     `value_and_grad(loss_fn)`, `dots_nobatch`, a loss chunk of 256, 8 x
-    1025 tokens) at depth 2, the layers being one scan: the all-reduces
-    of a device's activation `bf16[4,1024,2560]` are the two a layer that
-    `tp=2` requires forward (after `wo` and `w_down`) and the two backward
-    (the cotangents of the norms' outputs, from the column-parallel
-    products), all the partitioner's. While the norm's rule lay inside
-    its per-device region, JAX transposed a `shard_map` whose operand was
-    replicated over "tp": the backward body halved each cotangent
-    (`checkpoint/div`) and all-reduced each dx over the same pairs again
-    (`shard_map/psum`), 21 MB each that computed the identity, 64 + 1
-    times a step at depth 32."""
+    1025 tokens), the layers being one scan, compiled once for each
+    (tied, depth) asked for: its text and its `memory_analysis()`."""
     import optax
 
     from ray_tpu.models import loss_fn, param_logical_axes
     from ray_tpu.models.transformer import init_params
     from ray_tpu.parallel import logical_shardings
 
-    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     mesh = sharded_mesh
-    cfg = dataclasses.replace(QWEN, n_layers=2, max_seq=1024, remat=True,
-                              remat_policy="dots_nobatch", ce_chunk=256)
     replicated = NamedSharding(mesh, P())
-    params = jax.tree.map(
-        lambda a, sharding: jax.ShapeDtypeStruct(a.shape, a.dtype,
-                                                 sharding=sharding),
-        jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)),
-        logical_shardings(param_logical_axes(cfg), mesh))
-    optimizer = optax.adamw(3e-4)
-    # The moments lie as their parameters do, the count is whole.
-    moments, *rest = jax.eval_shape(optimizer.init, params)
-    opt_state = (moments._replace(
-        count=jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated),
-        mu=params, nu=params), *rest)
-    layouts = jax.tree.map(lambda x: x.sharding, (params, opt_state))
-    tokens = jax.ShapeDtypeStruct(
-        (8, 1025), jnp.int32,
-        sharding=NamedSharding(mesh, P(("dp", "fsdp"), None)))
+    compiled = {}
 
-    def step(params, opt_state, tokens):
-        loss, grads = jax.value_and_grad(loss_fn)(params, tokens, cfg, mesh)
-        updates, opt_state = optimizer.update(grads, opt_state, params)
-        return optax.apply_updates(params, updates), opt_state, loss
+    def compile_step(tied=True, depth=2):
+        if (tied, depth) in compiled:
+            return compiled[tied, depth]
+        cfg = dataclasses.replace(
+            QWEN, n_layers=depth, max_seq=1024, remat=True,
+            remat_policy="dots_nobatch", ce_chunk=256, tie_embeddings=tied)
+        params = jax.tree.map(
+            lambda a, sharding: jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                                     sharding=sharding),
+            jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg)),
+            logical_shardings(param_logical_axes(cfg), mesh))
+        optimizer = optax.adamw(3e-4)
+        # The moments lie as their parameters do, the count is whole.
+        moments, *rest = jax.eval_shape(optimizer.init, params)
+        opt_state = (moments._replace(
+            count=jax.ShapeDtypeStruct((), jnp.int32, sharding=replicated),
+            mu=params, nu=params), *rest)
+        layouts = jax.tree.map(lambda x: x.sharding, (params, opt_state))
+        tokens = jax.ShapeDtypeStruct(
+            (8, 1025), jnp.int32,
+            sharding=NamedSharding(mesh, P(("dp", "fsdp"), None)))
 
-    text = jax.jit(
-        step, donate_argnums=(0, 1), out_shardings=(*layouts, replicated),
-    ).lower(params, opt_state, tokens).compile().as_text()
-    assert text.count("tpu_custom_call") >= 8  # the kernels are in it
+        def step(params, opt_state, tokens):
+            loss, grads = jax.value_and_grad(loss_fn)(params, tokens, cfg,
+                                                      mesh)
+            updates, opt_state = optimizer.update(grads, opt_state, params)
+            return optax.apply_updates(params, updates), opt_state, loss
+
+        with pytest.MonkeyPatch.context() as patch:
+            patch.setattr(jax, "default_backend", lambda: "tpu")
+            exe = jax.jit(
+                step, donate_argnums=(0, 1),
+                out_shardings=(*layouts, replicated),
+            ).lower(params, opt_state, tokens).compile()
+        text = exe.as_text()
+        assert text.count("tpu_custom_call") >= 8  # the kernels are in it
+        compiled[tied, depth] = text, exe.memory_analysis()
+        return compiled[tied, depth]
+
+    return compile_step
+
+
+def test_sharded_train_step_reduces_an_activation_four_times_a_layer(
+        sharded_step):
+    """At depth 2 the all-reduces of a device's activation
+    `bf16[4,1024,2560]` are the two a layer that `tp=2` requires forward
+    (after `wo` and `w_down`) and the two backward (the cotangents of the
+    norms' outputs, from the column-parallel products), all the
+    partitioner's. While the norm's rule lay inside its per-device
+    region, JAX transposed a `shard_map` whose operand was replicated
+    over "tp": the backward body halved each cotangent (`checkpoint/div`)
+    and all-reduced each dx over the same pairs again (`shard_map/psum`),
+    21 MB each that computed the identity, 64 + 1 times a step at depth
+    32."""
+    text, _ = sharded_step()
     activation = f"bf16[4,1024,{QWEN.d_model}]"
     reduced = _results_of(text, "all-reduce", activation)
     in_body = [op for _, op in reduced if "/while/body/" in op]
@@ -248,6 +269,56 @@ def test_sharded_train_step_reduces_an_activation_four_times_a_layer(
     # Nothing passes over an activation to divide it by the replicas.
     assert not [op for _, op in _results_of(text, "fusion", activation)
                 if op.endswith("/div")]
+
+
+def _moves_of_width(text, width):
+    """(kind, op_name) of every instruction of a compiled text that moves
+    an array with a dimension of `width` between devices: a collective, or
+    the fusion the compiler makes of a reduce-scatter."""
+    line = re.compile(
+        rf"^\s*(?:ROOT )?\S+ = \(?\w+\[(?:\d+,)*{width}(?:,\d+)*\][^=]*? "
+        rf"(?:({COLLECTIVE})(?:-start)?\(|fusion\(.*calls=%(all-reduce-scatter))"
+        rf".*$", re.MULTILINE)
+    found = []
+    for m in line.finditer(text):
+        op_name = re.search(r'op_name="([^"]*)"', m.group(0))
+        found.append((m.group(1) or m.group(2),
+                      op_name.group(1) if op_name else ""))
+    return found
+
+
+@pytest.mark.parametrize("head", ["tied", "untied"])
+def test_sharded_train_step_moves_the_head_table_over_fsdp_once(
+        sharded_step, head):
+    """The chunked loss closes over the head's table in two scans (forward,
+    and the backward that recomputes each chunk's logits). Left as the
+    parameter lies, split over "fsdp" on its model axis, the partitioner
+    gathered it inside both bodies and reduce-scattered its gradient
+    inside the backward's: eight gathers and four reduce-scatters of 389
+    MB a step at 8 x 1024 tokens. `loss_fn` hands the loss the table
+    whole over "fsdp": one gather before the scans, one reduce-scatter
+    after, none of that width in a `while` body, for `embed.T` and for a
+    head of its own. The ties that keep the gathered table and gradient
+    out of the layers' backward show as memory: no more temporaries than
+    the step had before (2,008 MB; the hint alone 2,262)."""
+    text, memory = sharded_step(tied=head == "tied")
+    moves = _moves_of_width(text, QWEN.vocab_size // 2)
+    named = [(kind, op) for kind, op in moves if op]  # a fusion's insides
+    assert not [m for m in named if "/while/body/" in m[1]], named
+    assert sorted(kind for kind, _ in named) == [
+        "all-gather", "all-reduce-scatter"], named
+    assert memory.temp_size_in_bytes <= 2.0e9, memory.temp_size_in_bytes
+
+
+def test_sharded_train_step_fits_its_chip_at_the_cell_s_depth(sharded_step):
+    """At the cell's 32 layers the step's arguments and temporaries stay
+    under the 15.2 GB a chip by which its configuration chose the depth
+    (`bench/configs/qwen3-4b-train-fsdp2tp2.json`, `depth_note`: 14.59
+    then). A gathered table or gradient that outlives the loss lands on
+    the step's deepest point: the hint alone compiled at 15.92."""
+    _, memory = sharded_step(depth=32)
+    assert (memory.argument_size_in_bytes
+            + memory.temp_size_in_bytes) <= 15.2e9
 
 
 def test_flash_attention_backward_under_a_mesh_moves_no_operand(sharded_mesh):
