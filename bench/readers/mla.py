@@ -1,7 +1,9 @@
 """A model with latent attention: the reduced trace and, for the roofline
-share, `engine.stats()["attention"]`. No kernel reads a latent pool yet, so
-its attention is a loop of plain operations, found in a trace by their
-result shapes. spec["quantity"]:
+share, `engine.stats()["attention"]`. The decode program's attention over
+a latent pool is a kernel (`%latent_decode_attention`, PR 53) and the
+prefill program's a loop of plain operations; a trace names an operation
+by its own name and its first result's shape, and both are found by those
+shapes. spec["quantity"]:
 
   attn_time_share        device time of the operations matching
                          spec["match"] in the programs that run an operation
@@ -17,9 +19,9 @@ result shapes. spec["quantity"]:
                          module, bench/peaks.json), at the window's mean
                          LIVE cached rows a step (`decode_rows_live` over
                          `steps`: what the algorithm needs, which is fewer
-                         than the rows the loop gathers,
-                         `decode_rows_read`), times the traced launches,
-                         over the matched operations' time
+                         than the rows in the whole pages the kernel
+                         fetches, `decode_rows_read`), times the traced
+                         launches, over the matched operations' time
 
 spec["match"] and spec["contains_op"] name the run's own sizes as <slots>,
 <heads>, <rank>, <rope>, <nope>, <v>, <d_model>, <latent> (rank + rope) and

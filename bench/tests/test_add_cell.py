@@ -45,12 +45,15 @@ def derive(src, dst, change):
         json.dump(doc, f)
 
 
-def add_cell(bench, name, config, traffic, end_to_end):
+def add_cell(bench, name, config, traffic, metrics):
+    """A cell is an entry, and its name appended to the `workloads` of each
+    accepted metric it reports: an end-to-end one, or a per-layer one that
+    is read the same way in every cell of its kind."""
     bench["workloads"].append({
         "name": name, "config": config, "traffic": traffic, "chips": 1,
         "why": "shows that a cell is an entry"})
-    for m in bench["end_to_end"]:
-        if m["name"] in end_to_end:
+    for m in bench["end_to_end"] + bench["per_layer"]:
+        if m["name"] in metrics:
             m["workloads"].append(name)
 
 
@@ -99,8 +102,10 @@ def test_add_another_architecture_as_files(tmp_path, monkeypatch):
             "name": name, "source": "ray_tpu/models/configs.py tiny_gemma",
             "file": f"bench/configs/{name}.json", "reduced": [],
             "why": "shows that another architecture is files"})
+        # What a later `model_config` PR does: its serve cell joins a
+        # generic per-layer entry's list beside its end-to-end metric's.
         add_cell(bench, f"{name}-serve", name, "throwaway-bursty",
-                 ("tpot_p95_ms",))
+                 ("tpot_p95_ms", "compiles_in_window.steady"))
         add_cell(bench, f"{name}-train", name, "tokens-8x1024",
                  ("train_tokens_per_s_chip",))
     serve_cells = ["throwaway-serve", "throwaway-wrong-serve"]
@@ -122,8 +127,10 @@ def test_add_another_architecture_as_files(tmp_path, monkeypatch):
         assert line["correct"] is True and line["failed"] == 0
         assert ("(reference gemma, operations opcount, probe engine_probe)"
                 in out)
-    assert set(line["metrics"]) == {"throwaway.decode_ms"}
+    assert set(line["metrics"]) == {"throwaway.decode_ms",
+                                    "compiles_in_window.steady"}
     assert line["metrics"]["throwaway.decode_ms"]["value"] > 0
+    assert line["metrics"]["compiles_in_window.steady"]["value"] >= 0
     for trace in (0, 1):
         line, out = result_line("throwaway-train", trace, root)
         assert line["correct"] is True and line["failed"] == 0
