@@ -449,6 +449,94 @@ lfm2_24b_a2b = TransformerConfig(
 lfm2_24b_a2b_l10 = replace(
     lfm2_24b_a2b, n_layers=10, layer_pattern=lfm2_24b_a2b.layer_pattern[:10])
 
+# Every mechanism of the model below at toy widths: two periods of one
+# gated NoPE attention layer and three delta-rule layers (4 heads of 16 x
+# 16, a 4-tap convolution, beta up to 2), every layer's MLP 8 routed experts
+# (2 a token by sigmoid scores and a choice bias, zero here: a test draws
+# it) of which this share holds 4, beside a shared expert.
+tiny_solar_open2 = TransformerConfig(
+    vocab_size=256,
+    d_model=64,
+    n_layers=8,
+    n_heads=4,
+    n_kv_heads=2,
+    d_ff=128,
+    max_seq=128,
+    norm_eps=1e-5,
+    dtype=jnp.float32,
+    remat=False,
+    custom_head_dim=16,
+    position_embedding_type="nope",
+    attn_output_gate=True,
+    layer_pattern=("full_attention", "kda", "kda", "kda") * 2,
+    kda_num_heads=4,
+    kda_head_dim=16,
+    kda_short_conv_kernel_size=4,
+    kda_allow_neg_eigval=True,
+    moe_intermediate_size=32,
+    num_experts=8,
+    experts_per_token=2,
+    n_shared_experts=1,
+    scoring_func="sigmoid",
+    norm_topk_prob=True,
+    use_expert_bias=True,
+    experts_held=4,
+    expert_share=0,
+)
+
+# Solar-Open2-250B (the model's public config.json, `model_type`
+# solar_open2, upstage; the delta-rule layers are Kimi Linear's,
+# arXiv:2510.26692): 48 layers of hidden size 4096; layers 0, 4, ..., 44
+# (`gqa_layers`) are softmax attention with 64 query and 8 KV heads of 128,
+# no position embedding (`use_rope` false) and a sigmoid gate on the heads'
+# outputs (`use_gqa_gate`), the three between are channel-gated delta-rule
+# layers (64 heads of 128 x 128, a 4-tap convolution on q, k and v, beta up
+# to 2); every layer's MLP is 320 routed experts of 1280, 8 a token by
+# sigmoid scores and a choice bias, their weights over their sum, beside
+# one shared expert of 1280; an untied 196,608-row vocabulary. 250 B
+# parameters, 15 B used by a token. `d_ff` is the published
+# `intermediate_size`, which no layer uses (`first_k_dense_replace` 0).
+solar_open2_250b = TransformerConfig(
+    vocab_size=196608,
+    d_model=4096,
+    n_layers=48,
+    n_heads=64,
+    n_kv_heads=8,
+    d_ff=10240,
+    max_seq=1048576,
+    rope_theta=10000.0,  # published, and unused: no position embedding
+    norm_eps=1e-5,
+    custom_head_dim=128,
+    position_embedding_type="nope",
+    attn_output_gate=True,
+    layer_pattern=tuple("full_attention" if i % 4 == 0 else "kda"
+                        for i in range(48)),
+    kda_num_heads=64,
+    kda_head_dim=128,
+    kda_short_conv_kernel_size=4,
+    kda_allow_neg_eigval=True,
+    first_k_dense_replace=0,
+    moe_intermediate_size=1280,
+    num_experts=320,
+    experts_per_token=8,
+    n_shared_experts=1,
+    scoring_func="sigmoid",
+    norm_topk_prob=True,
+    routed_scaling_factor=1.0,
+    use_expert_bias=True,
+)
+
+# One chip's share of it, as one of 8 chips that share each layer: 40 of
+# the 320 routed experts (share 0: experts 0-39; the router's width stays
+# 320), both kinds of mixer, the shared expert and the router whole, an
+# eighth of the vocabulary, and its first period (layers 0-3: the attention
+# layer and three delta-rule layers); the other 44 layers would lie on
+# further groups of eight, as pipeline stages.
+solar_open2_250b_ep8_l4 = replace(
+    solar_open2_250b, n_layers=4,
+    layer_pattern=solar_open2_250b.layer_pattern[:4], vocab_size=24576,
+    experts_held=40, expert_share=0)
+
 NAMED_CONFIGS = {
     "tiny": tiny,
     "tiny_gqa": tiny_gqa,
@@ -473,6 +561,9 @@ NAMED_CONFIGS = {
     "tiny_lfm2_moe": tiny_lfm2_moe,
     "lfm2-24b-a2b": lfm2_24b_a2b,
     "lfm2-24b-a2b-l10": lfm2_24b_a2b_l10,
+    "tiny_solar_open2": tiny_solar_open2,
+    "solar-open2-250b": solar_open2_250b,
+    "solar-open2-250b-ep8-l4": solar_open2_250b_ep8_l4,
 }
 
 

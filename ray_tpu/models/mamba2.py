@@ -69,6 +69,10 @@ def in_proj_dim(cfg) -> int:
     return d_inner(cfg) + conv_dim(cfg)
 
 
+# A sequence's rows in the pool, in the order `mixer` takes and returns them.
+ROWS = ("state", "conv")
+
+
 def init_state(cfg, layers: int, rows: int) -> Dict:
     """The recurrent pool of `layers` Mamba layers and `rows` sequences,
     zeros: `state [layers, rows, H, P, N]` float32, `conv [layers, rows,

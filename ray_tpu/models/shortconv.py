@@ -31,6 +31,8 @@ import jax.numpy as jnp
 from ray_tpu.models import mamba2
 
 F32 = jnp.float32
+# A sequence's rows in the pool, in the order `mixer` takes and returns them.
+ROWS = ("conv",)
 
 
 def init_state(cfg, layers: int, rows: int) -> Dict:

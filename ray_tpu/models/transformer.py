@@ -31,7 +31,7 @@ from jax.sharding import PartitionSpec as P
 from ray_tpu.ops import apply_rope, flash_attention, rmsnorm, rope_frequencies, softmax_cross_entropy
 from ray_tpu.ops.cross_entropy import chunked_lm_head_ce
 from ray_tpu.ops.rope import yarn_inv_freq, yarn_mscale
-from ray_tpu.models import mamba2, shortconv
+from ray_tpu.models import kda, mamba2, shortconv
 from ray_tpu.parallel.mesh import DEFAULT_RULES, with_sharding_constraint
 from ray_tpu.parallel.moe import load_balancing_loss, moe_block
 
@@ -99,17 +99,28 @@ class TransformerConfig:
     # Explicit head dim when it differs from d_model/n_heads (Qwen3
     # uses 128-wide heads at every scale). 0 = derive from d_model.
     custom_head_dim: int = 0
-    # A hybrid decoder's published sizes (granitemoehybrid's and lfm2_moe's
-    # config keys): the kind of every layer under the public config's own
-    # strings (`layer_types`; empty: every layer is attention), attention
-    # ("attention", "full_attention") among recurrent layers of ONE kind,
-    # "mamba" (the Mamba-2 mixer, models/mamba2.py, shaped by `mamba_*`) or
-    # "conv" (the gated short convolution, models/shortconv.py, whose
-    # kernel is `conv_L_cache` long). A hybrid's MLP is dense in its first
-    # `first_k_dense_replace` layers and routed in the others where
-    # `num_experts` is set, dense in every layer where it is not.
+    # A hybrid decoder's published sizes (granitemoehybrid's, lfm2_moe's and
+    # solar_open2's config keys): the kind of every layer under the public
+    # config's own strings (`layer_types`; empty: every layer is attention),
+    # attention ("attention", "full_attention") among recurrent layers of
+    # ONE kind, "mamba" (the Mamba-2 mixer, models/mamba2.py, shaped by
+    # `mamba_*`), "conv" (the gated short convolution, models/shortconv.py,
+    # whose kernel is `conv_L_cache` long) or "kda" (the channel-gated delta
+    # rule, models/kda.py: `linear_attn_config`'s heads, their width and the
+    # short convolution's taps, and whether beta reaches 2). A hybrid's MLP
+    # is dense in its first `first_k_dense_replace` layers and routed in the
+    # others where `num_experts` is set, dense in every layer where it is
+    # not.
     layer_pattern: Tuple[str, ...] = ()
     conv_L_cache: int = 3
+    kda_num_heads: int = 0
+    kda_head_dim: int = 0
+    kda_short_conv_kernel_size: int = 4
+    kda_allow_neg_eigval: bool = False
+    # A sigmoid gate of the layer's input on the attention heads' outputs,
+    # before the output projection (solar_open2's `use_gqa_gate`; the leaf
+    # `wg [d, heads * head_dim]`).
+    attn_output_gate: bool = False
     mamba_n_heads: int = 0
     mamba_d_head: int = 0
     mamba_d_state: int = 0
@@ -218,7 +229,8 @@ class TransformerConfig:
 
     @property
     def recurrent_kind(self) -> str:
-        """The kind of a hybrid's recurrent layers, "mamba" or "conv"."""
+        """The kind of a hybrid's recurrent layers, a key of
+        `RECURRENT_KINDS`."""
         return next(t for t in self.layer_pattern if t not in ATTENTION_KINDS)
 
     @property
@@ -230,7 +242,8 @@ class TransformerConfig:
 # lfm2_moe's) and, for each kind of recurrent layer, its stack's name under
 # `params["layers"]` and the module whose `mixer` and `init_state` it takes.
 ATTENTION_KINDS = ("attention", "full_attention")
-RECURRENT_KINDS = {"mamba": ("ssm", mamba2), "conv": ("conv", shortconv)}
+RECURRENT_KINDS = {"mamba": ("ssm", mamba2), "conv": ("conv", shortconv),
+                   "kda": ("kda", kda)}
 
 # Where parallel.mesh.DEFAULT_RULES put activations, for the kernels that
 # run on each device's block (ops.per_shard).
@@ -242,19 +255,35 @@ def _dense_init(key, shape, scale, dtype):
     return (jax.random.normal(key, shape, dtype=jnp.float32) * scale).astype(dtype)
 
 
+def _check_experts(cfg: TransformerConfig) -> None:
+    """A layer's routed experts: the held share divides them and is one of
+    the shares, the groups divide them and hold `topk_group`."""
+    if cfg.num_experts % cfg.held or not (
+            0 <= cfg.expert_share < cfg.num_experts // cfg.held):
+        raise ValueError(
+            f"experts_held {cfg.held} must divide num_experts "
+            f"{cfg.num_experts}, and expert_share {cfg.expert_share} "
+            "name one of the shares")
+    if cfg.num_experts % cfg.n_group or cfg.topk_group > cfg.n_group:
+        raise ValueError("n_group must divide num_experts and hold "
+                         "topk_group")
+
+
 def _check_hybrid(cfg: TransformerConfig) -> None:
     """What a hybrid may be: attention layers among recurrent layers of one
-    kind, a QK-norm and a rope or neither in the attention layers, and an
-    MLP that is dense in every layer or dense in the leading layers and
-    whole routed experts (softmax or sigmoid scores, a choice bias) in the
-    rest. Refused by name: an unknown kind, Mamba and conv layers in one
-    model, latent attention, a held share of the experts or shared experts
-    beside recurrent layers."""
+    kind (Mamba-2, gated short convolutions or the channel-gated delta
+    rule), a QK-norm, a rope and an output gate or none of them in the
+    attention layers, and an MLP that is dense in every layer or dense in
+    the leading layers (there may be none) and routed in the rest: all of
+    a layer's experts or one chip's share of them (`experts_held`), with or
+    without shared experts beside them, softmax or sigmoid scores, a choice
+    bias. Refused by name: an unknown kind, recurrent layers of two kinds
+    in one model, latent attention beside recurrent layers."""
     kinds = set(cfg.layer_pattern)
     if not kinds <= {*RECURRENT_KINDS, *ATTENTION_KINDS}:
         raise ValueError(f"unknown layer kinds {sorted(kinds)} in "
-                         "layer_pattern: expected 'mamba' or 'conv', and "
-                         "'attention' or 'full_attention'")
+                         f"layer_pattern: expected one of {list(RECURRENT_KINDS)}"
+                         ", and 'attention' or 'full_attention'")
     if len(cfg.layer_pattern) != cfg.n_layers:
         raise ValueError(f"layer_pattern names {len(cfg.layer_pattern)} layers, "
                          f"n_layers is {cfg.n_layers}")
@@ -263,43 +292,47 @@ def _check_hybrid(cfg: TransformerConfig) -> None:
         raise ValueError("a hybrid has layers of both kinds, recurrent and "
                          f"attention; layer_pattern has only {sorted(kinds)}")
     if len(recurrent) > 1:
-        raise ValueError("a hybrid's recurrent layers are of one kind: Mamba "
-                         "and conv layers in one model (two recurrent pools "
-                         "in one walk) are not written")
+        raise ValueError("a hybrid's recurrent layers are of one kind: "
+                         f"{sorted(recurrent)} in one model (two recurrent "
+                         "pools in one walk) are not written")
     if cfg.kv_lora_rank:
         raise ValueError("latent attention beside recurrent layers is not "
                          "written")
-    if cfg.num_experts and (cfg.experts_held or cfg.n_shared_experts):
-        raise ValueError("a hybrid's expert layers hold all their routed "
-                         "experts and nothing beside them: a held share "
-                         "(experts_held) or shared experts beside recurrent "
-                         "layers are not written")
-    if cfg.num_experts and not 0 <= cfg.first_k_dense_replace < cfg.n_layers:
-        raise ValueError("first_k_dense_replace must lie in [0, n_layers)")
+    if cfg.num_experts:
+        if not 0 <= cfg.first_k_dense_replace < cfg.n_layers:
+            raise ValueError("first_k_dense_replace must lie in [0, n_layers)")
+        _check_experts(cfg)
     if "mamba" in kinds and (
             cfg.mamba_expand * cfg.d_model != mamba2.d_inner(cfg)):
         raise ValueError("mamba_n_heads * mamba_d_head must be "
                          "mamba_expand * d_model")
+    if "kda" in kinds and not (cfg.kda_num_heads and cfg.kda_head_dim):
+        raise ValueError("delta-rule layers need kda_num_heads and "
+                         "kda_head_dim")
 
 
 def _init_hybrid_layers(key, cfg: TransformerConfig) -> Dict:
-    """A hybrid's layers: one stack a kind of mixer (`ssm` or `conv`, and
-    `attn`, each with its input norm), the dense MLPs of the leading layers
-    (`mlp`: of all layers in a model without experts) and the routed MLPs
-    of the others (`moe`, the experts `[expert layers, E, ...]`), so three
-    or four stacks of unlike length. `A_log`, `dt_bias` and the convolution
-    are drawn by Mamba-2's published rule (arXiv:2405.21060); a short
-    convolution's taps are uniform in +-K ** -0.5."""
+    """A hybrid's layers: one stack a kind of mixer (`ssm`, `conv` or
+    `kda`, and `attn`, each with its input norm), the dense MLPs of the
+    leading layers (`mlp`: of all layers in a model without experts, absent
+    where every layer is routed) and the routed MLPs of the others (`moe`,
+    the experts `[expert layers, held, ...]`, shared experts as
+    `shared_*`), so three or four stacks of unlike length. `A_log`,
+    `dt_bias` and the convolution are drawn by Mamba-2's published rule
+    (arXiv:2405.21060), the delta rule's alike (a `dt_bias` a channel); a
+    short convolution's taps are uniform in +-K ** -0.5."""
     _check_hybrid(cfg)
     d, h, kvh, hd, ff = (cfg.d_model, cfg.n_heads, cfg.n_kv_heads,
                          cfg.head_dim, cfg.d_ff)
     n_rec, n_attn, n = cfg.recurrent_layers, cfg.attention_layers, cfg.n_layers
     n_moe = cfg.expert_layers
     scale, out_scale = d ** -0.5, d ** -0.5 * (2 * n) ** -0.5
-    # A second run of keys behind the first sixteen, so that a model that
-    # needed no more draws what it drew before there were expert stacks.
+    # Further runs of keys behind the first sixteen, so that a model that
+    # needed no more draws what it drew before there were expert stacks,
+    # and then shared experts, a gate and delta-rule layers.
     keys = iter([*jax.random.split(key, 16),
-                 *jax.random.split(jax.random.fold_in(key, 1), 8)])
+                 *jax.random.split(jax.random.fold_in(key, 1), 8),
+                 *jax.random.split(jax.random.fold_in(key, 2), 16)])
 
     def normal(count, shape, scale):
         return _dense_init(next(keys), (count, *shape), scale, cfg.dtype)
@@ -317,6 +350,8 @@ def _init_hybrid_layers(key, cfg: TransformerConfig) -> Dict:
                               bound).astype(cfg.dtype),
             "w_out": normal(n_rec, (d, d), out_scale),
         }
+    elif cfg.recurrent_kind == "kda":
+        layers["kda"] = _init_kda_stack(cfg, n_rec, normal, uniform)
     else:
         layers["ssm"] = _init_mamba_stack(cfg, n_rec, normal, uniform,
                                           scale, out_scale)
@@ -327,40 +362,86 @@ def _init_hybrid_layers(key, cfg: TransformerConfig) -> Dict:
         "wv": normal(n_attn, (d, kvh * hd), scale),
         "wo": normal(n_attn, (h * hd, d), out_scale),
     }
+    if cfg.attn_output_gate:
+        attn["wg"] = normal(n_attn, (d, h * hd), scale)
     if cfg.qk_norm:
         full = cfg.qk_norm_extent == "projection"
         attn["q_norm"] = jnp.ones((n_attn, h * hd if full else hd), cfg.dtype)
         attn["k_norm"] = jnp.ones((n_attn, kvh * hd if full else hd),
                                   cfg.dtype)
     layers["attn"] = attn
-    layers["mlp"] = {
-        "mlp_norm": jnp.ones((n - n_moe, d), cfg.dtype),
-        "w_gate": normal(n - n_moe, (d, ff), scale),
-        "w_up": normal(n - n_moe, (d, ff), scale),
-        "w_down": normal(n - n_moe, (ff, d), out_scale),
-    }
+    if n - n_moe:
+        layers["mlp"] = {
+            "mlp_norm": jnp.ones((n - n_moe, d), cfg.dtype),
+            "w_gate": normal(n - n_moe, (d, ff), scale),
+            "w_up": normal(n - n_moe, (d, ff), scale),
+            "w_down": normal(n - n_moe, (ff, d), out_scale),
+        }
     if n_moe:
-        e, eff = cfg.num_experts, cfg.expert_ff
+        e, held, eff = cfg.num_experts, cfg.held, cfg.expert_ff
+        down = eff ** -0.5 * (2 * n) ** -0.5
         layers["moe"] = {
             "mlp_norm": jnp.ones((n_moe, d), cfg.dtype),
             "router": normal(n_moe, (d, e), scale),
-            "w_gate": normal(n_moe, (e, d, eff), scale),
-            "w_up": normal(n_moe, (e, d, eff), scale),
-            "w_down": normal(n_moe, (e, eff, d),
-                             eff ** -0.5 * (2 * n) ** -0.5),
+            "w_gate": normal(n_moe, (held, d, eff), scale),
+            "w_up": normal(n_moe, (held, d, eff), scale),
+            "w_down": normal(n_moe, (held, eff, d), down),
         }
         if cfg.use_expert_bias:
             # Added to the scores to choose with, never to weigh with; zero
             # as a checkpoint starts it, float32 as it is kept.
             layers["moe"]["router_bias"] = jnp.zeros((n_moe, e), jnp.float32)
+        if cfg.n_shared_experts:
+            sff = cfg.n_shared_experts * eff
+            layers["moe"].update({
+                "shared_gate": normal(n_moe, (d, sff), scale),
+                "shared_up": normal(n_moe, (d, sff), scale),
+                "shared_down": normal(n_moe, (sff, d),
+                                      sff ** -0.5 * (2 * n) ** -0.5),
+            })
     return layers
+
+
+def _init_kda_stack(cfg, n_kda, normal, uniform) -> Dict:
+    """The delta-rule mixers' leaves: `w_qkv` the published q, k and v
+    projections side by side and `conv_w` their three convolutions', the
+    decay's and the output gate's low-rank pairs (`w_fa`, `w_fb`; `w_ga`,
+    `w_gb`) through `kda_head_dim`, `a_log` a head, `dt_bias` a channel."""
+    d, heads, dk, width = (cfg.d_model, cfg.kda_num_heads, cfg.kda_head_dim,
+                           kda.inner(cfg))
+    taps = cfg.kda_short_conv_kernel_size
+    bound = taps ** -0.5
+    dt_bias = _dt_bias(uniform, (n_kda, width))
+    return {
+        "norm": jnp.ones((n_kda, d), cfg.dtype),
+        "w_qkv": normal(n_kda, (d, 3 * width), d ** -0.5),
+        "conv_w": uniform((n_kda, 3 * width, taps), -bound,
+                          bound).astype(cfg.dtype),
+        "w_fa": normal(n_kda, (d, dk), d ** -0.5),
+        "w_fb": normal(n_kda, (dk, width), dk ** -0.5),
+        "dt_bias": dt_bias.astype(cfg.dtype),
+        "a_log": jnp.log(uniform((n_kda, heads), 1.0, 16.0)).astype(cfg.dtype),
+        "w_beta": normal(n_kda, (d, heads), d ** -0.5),
+        "w_ga": normal(n_kda, (d, dk), d ** -0.5),
+        "w_gb": normal(n_kda, (dk, width), dk ** -0.5),
+        "gate_norm": jnp.ones((n_kda, dk), cfg.dtype),
+        "w_out": normal(n_kda, (width, d),
+                        width ** -0.5 * (2 * cfg.n_layers) ** -0.5),
+    }
+
+
+def _dt_bias(uniform, shape):
+    """A rate log-uniform in [0.001, 0.1], kept as the value whose softplus
+    it is (Mamba-2's published rule), float32."""
+    dt = jnp.exp(uniform(shape, jnp.log(0.001), jnp.log(0.1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
 
 
 def _init_mamba_stack(cfg, n_ssm, normal, uniform, scale, out_scale) -> Dict:
     d = cfg.d_model
     heads, inner, conv = (cfg.mamba_n_heads, mamba2.d_inner(cfg),
                           mamba2.conv_dim(cfg))
-    dt = jnp.exp(uniform((n_ssm, heads), jnp.log(0.001), jnp.log(0.1)))
+    dt_bias = _dt_bias(uniform, (n_ssm, heads))
     bound = cfg.mamba_d_conv ** -0.5
     ssm = {
         "norm": jnp.ones((n_ssm, d), cfg.dtype),
@@ -368,7 +449,7 @@ def _init_mamba_stack(cfg, n_ssm, normal, uniform, scale, out_scale) -> Dict:
         "w_dt": normal(n_ssm, (d, heads), scale),
         "conv_w": uniform((n_ssm, conv, cfg.mamba_d_conv), -bound,
                           bound).astype(cfg.dtype),
-        "dt_bias": (dt + jnp.log(-jnp.expm1(-dt))).astype(cfg.dtype),
+        "dt_bias": dt_bias.astype(cfg.dtype),
         "a_log": jnp.log(uniform((n_ssm, heads), 1.0, 16.0)).astype(cfg.dtype),
         "d_skip": jnp.ones((n_ssm, heads), cfg.dtype),
         "gate_norm": jnp.ones((n_ssm, inner), cfg.dtype),
@@ -393,15 +474,7 @@ def _check_latent(cfg: TransformerConfig) -> None:
     if not 0 <= cfg.first_k_dense_replace <= cfg.n_layers:
         raise ValueError("first_k_dense_replace must lie in [0, n_layers]")
     if cfg.num_experts:
-        if cfg.num_experts % cfg.held or not (
-                0 <= cfg.expert_share < cfg.num_experts // cfg.held):
-            raise ValueError(
-                f"experts_held {cfg.held} must divide num_experts "
-                f"{cfg.num_experts}, and expert_share {cfg.expert_share} "
-                "name one of the shares")
-        if cfg.num_experts % cfg.n_group or cfg.topk_group > cfg.n_group:
-            raise ValueError("n_group must divide num_experts and hold "
-                             "topk_group")
+        _check_experts(cfg)
 
 
 def _init_latent_layers(key, cfg: TransformerConfig) -> Dict:
@@ -557,9 +630,9 @@ def param_logical_axes(cfg: TransformerConfig) -> Dict:
 
     Mapped through parallel.mesh.DEFAULT_RULES: "embed"->fsdp, "mlp"/
     "heads"/"vocab"->tp, "expert"->ep, layer-stack axis -> "stage" (pp).
-    A hybrid's Mamba stack is replicated but for its two projections'
+    A hybrid's recurrent stack is replicated but for its two projections'
     model axis: heads, groups and the convolution's channels do not split
-    without a partitioned mixer.
+    without a partitioned mixer. Shared experts are replicated.
     """
     if cfg.kv_lora_rank:
         # Replicated but for the stack axis: a latent pool and a held share
@@ -579,8 +652,10 @@ def param_logical_axes(cfg: TransformerConfig) -> Dict:
                  "w_gate": ("stage", "embed", "mlp"),
                  "w_up": ("stage", "embed", "mlp"),
                  "w_down": ("stage", "mlp", "embed"),
+                 "wg": ("stage", "embed", "heads"),
                  "w_in": ("stage", "embed", None),
                  "w_dt": ("stage", "embed", None),
+                 "w_qkv": ("stage", "embed", None),
                  "w_out": ("stage", None, "embed"),
                  "embed": ("vocab", "embed"), "lm_head": ("embed", "vocab")}
         experts = {"w_gate": ("stage", "expert", "embed", "mlp"),
@@ -705,6 +780,17 @@ def project_qkv(h, lp, cfg: TransformerConfig):
     return q, k, v
 
 
+def gate_attention(attn, h, lp):
+    """The heads' outputs `attn [B, L, H * head_dim]` times the sigmoid
+    gate of the layer's normed input `h`, for a layer that has the leaf
+    `wg` (`attn_output_gate`); any other layer's as they are."""
+    if "wg" not in lp:
+        return attn
+    with jax.named_scope("attn.gate"):
+        gate = jax.nn.sigmoid((h @ lp["wg"]).astype(jnp.float32))
+        return (attn.astype(jnp.float32) * gate).astype(attn.dtype)
+
+
 def _attention(cfg: TransformerConfig, q, k, v, mesh, positions):
     if cfg.attn_impl == "ring" and mesh is not None and mesh.shape.get("sp", 1) > 1:
         from ray_tpu.parallel.ring_attention import ring_attention
@@ -749,15 +835,15 @@ def layer_kinds(cfg: TransformerConfig):
 
 def mix_recurrent(h, lp: Dict, cfg: TransformerConfig, rows: Dict, n_valid):
     """A recurrent layer's mixer on normed activations `h [B, L, D]` from
-    its pool's rows by their names (`state` and `conv` of a Mamba layer,
-    `conv` alone of a gated short convolution), `n_valid [B]` of the rows
-    real: the mixer's output and the rows after the last real one."""
-    if "state" in rows:
-        out, state, conv = mamba2.mixer(h, lp, cfg, rows["state"],
-                                        rows["conv"], n_valid)
-        return out, {"state": state, "conv": conv}
-    out, conv = shortconv.mixer(h, lp, cfg, rows["conv"], n_valid)
-    return out, {"conv": conv}
+    its pool's rows by their names, `n_valid [B]` of the rows real: the
+    mixer's output and the rows after the last real one. The mixer is the
+    one of the kind's module (`RECURRENT_KINDS`), which names the rows it
+    takes and returns (`ROWS`): a gated short convolution keeps `conv`
+    alone, a Mamba-2 and a delta-rule layer a `state` and a `conv`."""
+    module = RECURRENT_KINDS[cfg.recurrent_kind][1]
+    out, *after = module.mixer(
+        h, lp, cfg, *(rows[name] for name in module.ROWS), n_valid)
+    return out, dict(zip(module.ROWS, after))
 
 
 def at_layer(stack: Dict, i):
@@ -793,14 +879,15 @@ def _hybrid_layers(params, x, cfg: TransformerConfig, mesh, positions):
 
     def attn_mixer(x, j):
         lp = at_layer(layers["attn"], j)
-        q, k, v = project_qkv(norm(x, lp["attn_norm"]), lp, cfg)
+        h = norm(x, lp["attn_norm"])
+        q, k, v = project_qkv(h, lp, cfg)
         if cos is not None:
             q = apply_rope(q, cos, sin, positions)
             k = apply_rope(k, cos, sin, positions)
         # The kernels scale scores by head_dim ** -0.5: q carries the rest.
         q = q * jnp.asarray(cfg.attention_scale * cfg.head_dim ** 0.5, q.dtype)
         attn = _attention(cfg, q, k, v, mesh, positions)
-        return attn.reshape(b, l, -1) @ lp["wo"]
+        return gate_attention(attn.reshape(b, l, -1), h, lp) @ lp["wo"]
 
     def body(x, inputs):
         mlp, is_recurrent, j = inputs
@@ -958,7 +1045,8 @@ def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
         attn = _attention(cfg, q, k, v, mesh, positions)
-        x = x + (attn.reshape(b, l, -1) @ lp["wo"]).astype(x.dtype)
+        x = x + (gate_attention(attn.reshape(b, l, -1), h, lp)
+                 @ lp["wo"]).astype(x.dtype)
 
         h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh,
                     spec=_ACT_SPEC)

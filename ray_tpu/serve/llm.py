@@ -11,10 +11,12 @@ interleaved between decode steps), emit tokens as they are produced, and
 free their slot the moment they finish — the vLLM-style iteration-level
 scheduling, built TPU-first:
 
-  * A model with recurrent layers (a hybrid: Mamba-2 layers or gated
-    short convolutions among attention layers, its MLPs dense or routed)
-    gives every slot a row of fixed-size state beside its pages, in a
-    second pool that the same step programs carry and update in place;
+  * A model with recurrent layers (a hybrid: Mamba-2 layers, gated short
+    convolutions or channel-gated delta-rule layers among attention
+    layers, its MLPs dense or routed, all of a layer's experts or one
+    chip's share of them) gives every slot a row of fixed-size state
+    beside its pages, in a second pool that the same step programs carry
+    and update in place;
     where it has experts, `stats()` reports their routing counters
     (`"moe"`) beside the recurrent ones (`"ssm"`). What follows from the
     model and is no option: such a model serves without a prefix cache
@@ -982,12 +984,13 @@ class ContinuousBatchingEngine:
         )
 
     def _ssm_stats(self) -> Dict:
-        """stats()["ssm"]: the recurrent pool's size and what the step
-        programs counted since warm-up (`paged_kv.init_ssm_counters`,
-        fetched now), with the two counts the host keeps: admissions that
-        started a slot's state from zero, and admissions whose prompt
-        filled a page and so would have been looked up in a prefix cache,
-        had this model one."""
+        """stats()["ssm"]: the recurrent pool's size (whatever its kind
+        keeps a slot: a Mamba state, a convolution's rows, a matrix a head)
+        and what the step programs counted since warm-up
+        (`paged_kv.init_ssm_counters`, fetched now), with the two counts
+        the host keeps: admissions that started a slot's state from zero,
+        and admissions whose prompt filled a page and so would have been
+        looked up in a prefix cache, had this model one."""
         acc = jax.device_get(self._tail["rec_count"])
         with self._lock:
             return {

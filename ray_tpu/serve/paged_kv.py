@@ -83,6 +83,7 @@ from ray_tpu.models.transformer import (
     at_layer,
     dense_mlp,
     expand_latent,
+    gate_attention,
     latent_layer,
     latent_stacks,
     layer_kinds,
@@ -365,8 +366,11 @@ def init_recurrent_pool(cfg: TransformerConfig, slots: int) -> Dict:
     inputs `conv [ssm layers, slots, d_conv - 1, conv_dim]` in the weights'
     dtype. Gated short convolutions: their last gated inputs `conv [conv
     layers, slots, conv_L_cache - 1, d_model]` in the weights' dtype and
-    nothing else. It rides in the layer walk's carry beside the pages and
-    is donated with them."""
+    nothing else. Delta-rule layers: a matrix a head, `state [kda layers,
+    slots, heads, head_dim, head_dim]` float32, and the last inputs of q's,
+    k's and v's convolutions `conv [kda layers, slots, kernel - 1, 3 *
+    heads * head_dim]`. It rides in the layer walk's carry beside the pages
+    and is donated with them."""
     return RECURRENT_KINDS[cfg.recurrent_kind][1].init_state(
         cfg, cfg.recurrent_layers, slots)
 
@@ -392,7 +396,8 @@ def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
         q = apply_rope(q, cos, sin, positions)
         k = apply_rope(k, cos, sin, positions)
     k_cache_l, v_cache_l, attn = attend(k_cache_l, v_cache_l, q, k, v)
-    x = residual(x, (attn.reshape(b, l, -1) @ lp["wo"]).astype(x.dtype), cfg)
+    attn = gate_attention(attn.reshape(b, l, -1), h, lp)
+    x = residual(x, (attn @ lp["wo"]).astype(x.dtype), cfg)
     h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh)
     if "router" in lp:
         y, routing = moe_block(h.reshape(b * l, -1), lp, cfg, layer)
@@ -611,11 +616,14 @@ def _walk_hybrid(params, x, k_cache, v_cache, rec, attend, rec_io,
 
     A model with experts is walked as two such spans, split where the MLP
     changes kind and not branched there: the leading layers, whose MLP is
-    dense (`layers["mlp"]`), then the expert layers, each reading its
-    router from `layers["moe"]` and the expert stacks whole at its own
-    index among them (`moe_block(..., layer=)`, as `_scan_layers` does).
-    The assignments each expert layer's experts received ride in the carry
-    too, `[expert layers, E]`.
+    dense (`layers["mlp"]`; a model may have none), then the expert layers,
+    each reading its router, its choice bias and its shared expert from
+    `layers["moe"]` and the expert stacks whole at its own index among them
+    (`moe_block(..., layer=)`, as `_scan_layers` does): all of a layer's
+    experts or the held share (`cfg.experts_held`). The assignments each
+    expert layer's experts received, held here or not, ride in the carry
+    too, `[expert layers, E]` (`_count_routing` counts the held ones' hits,
+    as for `_walk_latent`).
 
     `rec_io = (read, write)`: `read(rec, j) -> {name: rows [B, ...]}` of
     the rows this call advances in recurrent layer `j`, by the pool's own
@@ -623,8 +631,9 @@ def _walk_hybrid(params, x, k_cache, v_cache, rec, attend, rec_io,
     each (`transformer.mix_recurrent`). `rec_io` None: the call
     advances every slot's row by one token where it lies in the pool, and
     a Mamba mixer takes the whole pool of states and `j` (`ops.ssm_update`:
-    layer `j` is passed over once). Returns x, the caches, `rec` and the
-    assignments (None without experts)."""
+    layer `j` is passed over once; the other kinds' rows are sliced out at
+    `j` and set back, which the compiler does in place). Returns x, the
+    caches, `rec` and the assignments (None without experts)."""
     layers = params["layers"]
     kind = cfg.recurrent_kind
     stack = layers[RECURRENT_KINDS[kind][0]]

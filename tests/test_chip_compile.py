@@ -994,3 +994,154 @@ def test_conv_expert_hybrid_steps_fit_and_read_their_stacks_in_place(
                for op in short)
     assert not any(re.search(spec.layer_metric_spec(other)["contains_op"], op)
                    for op in short)
+
+
+SOLAR_SLOTS, SOLAR_MAX_LEN, SOLAR_CHUNK = 128, 2560, 256
+SOLAR_FILE = "solar-open2-250b-ep8-serve.json"
+
+
+def _solar_program(program, one, rows=1):
+    """`decode_paged` or `prefill_chunk_paged` at the sizes of the cell
+    `serve-kda-reason-closed` (Solar-Open2-250B's first period as one chip
+    of eight, 128 slots x 2560, pages of 16, a pass of `rows` rows of 256),
+    on shapes, with both accumulators in the tail as the engine passes
+    them: (fn, donated, args, the cache's shapes, cfg)."""
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.serve import paged_kv
+
+    cfg = dataclasses.replace(configs.get_config("solar-open2-250b-ep8-l4"),
+                              remat=False)
+    slots, per_slot = SOLAR_SLOTS, SOLAR_MAX_LEN // PAGE
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    def struct(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, slots, slots * per_slot + 1, PAGE, per_slot)))
+    moe = on_chip(jax.eval_shape(
+        lambda: paged_kv.init_routing_counters(cfg)))
+    count = on_chip(jax.eval_shape(paged_kv.init_ssm_counters))
+    pool = (cache["k"], cache["v"], cache["lengths"])
+    if program == "decode_paged":
+        fn = lambda p, t, k, v, ln, a, bt, tp, tk, tpp, key, moe, rec, c: (  # noqa: E731
+            paged_kv.decode_paged(p, t, k, v, ln, a, bt, tp, tk, tpp, key,
+                                  cfg, SOLAR_MAX_LEN, None, moe, rec, c))
+        args = (params, struct((slots,)), *pool, struct((slots,), jnp.bool_),
+                cache["block_tables"], struct((slots,), jnp.float32),
+                struct((slots,)), struct((slots,), jnp.float32),
+                struct((2,), jnp.uint32), moe, cache["rec"], count)
+        return fn, (2, 3, 12), args, cache, cfg
+    fn = lambda p, t, n, s, o, k, v, ln, bt, moe, rec, c: (  # noqa: E731
+        paged_kv.prefill_chunk_paged(p, t, n, s, o, k, v, ln, bt, cfg,
+                                     SOLAR_MAX_LEN, None, moe, rec, c))
+    row = struct((rows,))
+    args = (params, struct((rows, SOLAR_CHUNK)), row, row, row, *pool,
+            cache["block_tables"], moe, cache["rec"], count)
+    return fn, (5, 6, 10), args, cache, cfg
+
+
+# The cell's per-layer metrics that read a trace by an operation's name,
+# and the step program in which each has to find one.
+SOLAR_TRACE_METRICS = {
+    "decode_paged": ("moe.expert_time_share.solar",
+                     "moe.dispatch_time_share.solar",
+                     "moe.expert_roofline_share.solar",
+                     "kda.update_time_share",
+                     "kda.update_roofline_share",
+                     "kda.mixer_time_share",
+                     "attn.decode_time_share.solar",
+                     "sampler.time_share.solar"),
+    "prefill_chunk_paged": ("moe.expert_time_share.solar",
+                            "moe.dispatch_time_share.solar",
+                            "kda.scan_time_share",
+                            "kda.scan_roofline_share",
+                            "kda.mixer_time_share"),
+}
+
+
+@pytest.mark.parametrize("program,rows", [
+    ("decode_paged", 1), ("prefill_chunk_paged", 1),
+    ("prefill_chunk_paged", 2)], ids=["decode", "prefill-1", "prefill-2"])
+def test_delta_rule_hybrid_steps_fit_and_update_their_pools_in_place(
+        v5e, program, rows, monkeypatch):
+    """The cell `serve-kda-reason-closed`'s step programs at its own sizes
+    (`_solar_program`): the arguments are the bytes its configuration file
+    states (and what the file says the chip stands for, within 2%), the
+    pages, the matrix states and the convolution rows come back in the
+    buffers they came in, the held experts' stacks `[4, 40, ...]` are read
+    in place by the grouped products of the two compiled expert layers (the
+    attention layer in the scan, a delta-rule layer in the tail), the
+    decode step passes over a layer's states in two fusions and no more,
+    and every trace metric the cell adds finds an operation of its pattern
+    among the names the compiler prints."""
+    import json
+
+    _bench_on_path()
+    import solar_flops
+    import spec
+    from xplane import reduce
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, donated, args, cache, cfg = _solar_program(
+        program, SingleDeviceSharding(v5e[0]), rows)
+    assert sorted(cache["rec"]) == ["conv", "state"]
+    assert cache["rec"]["state"].shape == (3, 128, 64, 128, 128)
+    assert cache["rec"]["state"].dtype == jnp.float32
+    assert cache["rec"]["conv"].shape == (3, 128, 3, 24576)
+    assert cache["k"].shape == (1, 128 * 160 + 1, PAGE, 1024)
+    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    with open(os.path.join(spec.BENCH, "configs", SOLAR_FILE)) as f:
+        doc = json.load(f)
+    stated = doc["compiled"]
+    key = "decode" if program == "decode_paged" else f"prefill_{rows}"
+    assert memory.argument_size_in_bytes == stated[key]["arguments_bytes"]
+    assert memory.temp_size_in_bytes <= stated[key]["temporaries_bytes_at_most"]
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 10.2e9
+    # What the file's `stands_for` counts: the weights by the benchmark's
+    # arithmetic and the three pools, within 2% of the compiler's arguments.
+    dims = {field: doc[k] for k, field in spec._published(doc).items()
+            if field != "tie_embeddings"}
+    pools = sum(a.size * a.dtype.itemsize for a in (
+        cache["k"], cache["v"], cache["rec"]["state"], cache["rec"]["conv"]))
+    counted = 2 * solar_flops.params_held(
+        dict(dims, layer_pattern=tuple(dims["layer_pattern"])), 4) + pools
+    assert abs(counted / memory.argument_size_in_bytes - 1) < 0.02
+    assert memory.alias_size_in_bytes >= pools
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = f32\[", text)) == 6
+    assert len(re.findall(r"%paged_decode_attention[.\d]* = f32\[", text)) == (
+        program == "decode_paged")
+    for inner in ("4096,1280", "1280,4096"):
+        assert not re.findall(
+            rf"= bf16\[(?:1,|4,)?40,{inner}\]\S* (?:copy|fusion)\(", text)
+        assert not re.findall(rf"= bf16\[160,{inner}\]\S* copy\(", text)
+    # No copy of the pool of states, and in the decode step two fusions
+    # that take it: the sums over the decayed state, and the write.
+    assert not re.findall(r"= f32\[3,128,64,128,128\]\S* copy\(", text)
+    if program == "decode_paged":                          # the write
+        assert len(re.findall(
+            r"= f32\[3,128,64,128,128\]\S* fusion\(", text)) == 1
+    short = [reduce._short(line.strip().removeprefix("ROOT "))
+             for line in text.splitlines() if " = " in line]
+    for metric in SOLAR_TRACE_METRICS[program]:
+        how = spec.layer_metric_spec(metric)
+        assert any(re.search(how["match"], op) for op in short), metric
+    step = ("decode.device_ms_per_step.solar" if program == "decode_paged"
+            else "prefill.device_ms_per_chunk.solar")
+    other = ("prefill.device_ms_per_chunk.solar" if program == "decode_paged"
+             else "decode.device_ms_per_step.solar")
+    assert any(re.search(spec.layer_metric_spec(step)["contains_op"], op)
+               for op in short)
+    assert not any(re.search(spec.layer_metric_spec(other)["contains_op"], op)
+                   for op in short)
+    for metric in ("kda.update_roofline_share", "kda.scan_roofline_share"):
+        how = spec.layer_metric_spec(metric)
+        own = step.startswith("decode") == (metric == "kda.update_roofline_share")
+        assert any(re.search(how["contains_op"], op) for op in short) == own

@@ -714,12 +714,19 @@ REFUSED = {
                         "unknown layer kinds"),
     "Mamba and conv layers together": (
         dict(layer_pattern=("conv", "mamba", "full_attention", "conv",
-                            "attention")), "Mamba and conv layers in one"),
+                            "attention")),
+        r"\['conv', 'mamba'\] in one model"),
     "one kind alone": (dict(layer_pattern=("conv",) * 5), "both kinds"),
     "a pattern of another length": (dict(n_layers=4), "names 5 layers"),
     "latent attention": (dict(kv_lora_rank=16), "latent attention beside"),
-    "a held share": (dict(experts_held=4), "a held share"),
-    "shared experts": (dict(n_shared_experts=1), "a held share"),
+    # A held share of the experts and shared experts beside recurrent
+    # layers are served since PR 61 (tests/test_solar_open2.py); a share
+    # that is none of the experts' is refused as it is under latent
+    # attention.
+    "a held share that does not divide": (dict(experts_held=3),
+                                          "must divide num_experts"),
+    "a share past the last": (dict(experts_held=4, expert_share=2),
+                              "name one of the shares"),
     "no expert layer": (dict(first_k_dense_replace=5),
                         "first_k_dense_replace"),
 }
