@@ -29,6 +29,17 @@ state taken and the final one returned) and `kda_step` (one token).
 decode step; it takes the form by the static length of what it is given,
 as `mamba2.mixer` does.
 
+The one-token form has two homes, as Mamba-2's has, and who takes which
+goes by what the caller holds. A caller with a bare state array `[B, H, K,
+V]` (the cached forward, a test, the kernel's reference) gets `kda_step`
+here, the plain form: the compiler makes two fusions of it, one that reads
+the state for both sums over it and one that reads it again to write it. A
+caller with the whole recurrent pool `[layers, B, H, K, V]` and a layer's
+index (the engine's decode program, `paged_kv.decode_paged` through
+`_walk_hybrid`) passes both to `mixer` and gets `ops.kda_update`: on the
+chip a kernel that passes over that layer's rows once each way, where they
+lie in the pool; elsewhere `kda_step` on the layer sliced out and set back.
+
 Inside a block a decay is always `exp(G_i - G_j)` of log-decays summed
 from the block's start, with `i >= j`, so its exponent is never positive:
 taken a channel at a time inside the sums that make the block's two score
@@ -52,6 +63,7 @@ import jax
 import jax.numpy as jnp
 
 from ray_tpu.models import mamba2
+from ray_tpu.ops.kda_update import kda_update
 
 F32 = jnp.float32
 # Tokens of a block of the chunked form.
@@ -61,6 +73,9 @@ L2_EPS = 1e-6
 _EXACT = jax.lax.Precision.HIGHEST
 # A sequence's rows in the pool, in the order `mixer` takes and returns them.
 ROWS = ("state", "conv")
+# Those of them that `mixer` given `layer=` takes as the whole pool `[layers,
+# ...]` and advances where they lie.
+IN_POOL = ("state",)
 
 
 def inner(cfg) -> int:
@@ -197,13 +212,17 @@ def gate_norm(o, h, lp: Dict, cfg):
         return o * jax.nn.sigmoid(((h @ lp["w_ga"]) @ lp["w_gb"]).astype(F32))
 
 
-def mixer(h, lp: Dict, cfg, state, conv, n_valid):
+def mixer(h, lp: Dict, cfg, state, conv, n_valid, layer=None):
     """The whole mixer on normed activations `h [B, L, d]` from `state [B,
     H, K, V]` float32 and the saved convolution inputs `conv [B, kernel -
     1, 3 H K]`; `n_valid [B]` rows of each sequence are real. Returns the
     mixer's output `[B, L, d]` in h's dtype, the state and the convolution
     inputs after the last real row. One token (L == 1) takes the one-token
-    form, anything longer the chunked one."""
+    form, anything longer the chunked one.
+
+    With `layer`, a scalar, `state` is the whole recurrent pool `[layers,
+    B, H, K, V]` and the pool comes back, that layer's rows advanced where
+    they lie (`ops.kda_update`): one token only."""
     bsz, length, _ = h.shape
     heads, dk = cfg.kda_num_heads, cfg.kda_head_dim
     with jax.named_scope("kda.conv"):
@@ -221,7 +240,12 @@ def mixer(h, lp: Dict, cfg, state, conv, n_valid):
         beta = jnp.where(
             real, (2.0 if cfg.kda_allow_neg_eigval else 1.0)
             * jax.nn.sigmoid((h @ lp["w_beta"]).astype(F32)), 0.0)
-    if length == 1:
+    if layer is not None:
+        assert length == 1, "a pool and a layer: the one-token form"
+        o, state = kda_update(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
+                              state, layer)
+        o = o[:, None]
+    elif length == 1:
         o, state = kda_step(q[:, 0], k[:, 0], v[:, 0], g[:, 0], beta[:, 0],
                             state)
         o = o[:, None]

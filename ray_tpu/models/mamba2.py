@@ -71,6 +71,9 @@ def in_proj_dim(cfg) -> int:
 
 # A sequence's rows in the pool, in the order `mixer` takes and returns them.
 ROWS = ("state", "conv")
+# Those of them that `mixer` given `layer=` takes as the whole pool `[layers,
+# ...]` and advances where they lie.
+IN_POOL = ("state",)
 
 
 def init_state(cfg, layers: int, rows: int) -> Dict:

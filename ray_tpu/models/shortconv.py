@@ -33,6 +33,9 @@ from ray_tpu.models import mamba2
 F32 = jnp.float32
 # A sequence's rows in the pool, in the order `mixer` takes and returns them.
 ROWS = ("conv",)
+# None of them is advanced where it lies in the pool: `mixer` takes no
+# `layer=`, and a walk slices the layer's rows out and sets them back.
+IN_POOL = ()
 
 
 def init_state(cfg, layers: int, rows: int) -> Dict:

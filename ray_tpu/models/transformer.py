@@ -833,16 +833,22 @@ def layer_kinds(cfg: TransformerConfig):
     return is_recurrent, within.astype(np.int32)
 
 
-def mix_recurrent(h, lp: Dict, cfg: TransformerConfig, rows: Dict, n_valid):
+def mix_recurrent(h, lp: Dict, cfg: TransformerConfig, rows: Dict, n_valid,
+                  layer=None):
     """A recurrent layer's mixer on normed activations `h [B, L, D]` from
     its pool's rows by their names, `n_valid [B]` of the rows real: the
     mixer's output and the rows after the last real one. The mixer is the
     one of the kind's module (`RECURRENT_KINDS`), which names the rows it
     takes and returns (`ROWS`): a gated short convolution keeps `conv`
-    alone, a Mamba-2 and a delta-rule layer a `state` and a `conv`."""
+    alone, a Mamba-2 and a delta-rule layer a `state` and a `conv`. With
+    `layer`, a scalar, the rows the module names `IN_POOL` are the whole
+    pool `[layers, ...]` and come back whole, that layer's rows advanced
+    where they lie (one token only; a module that names none is not given
+    a `layer`)."""
     module = RECURRENT_KINDS[cfg.recurrent_kind][1]
     out, *after = module.mixer(
-        h, lp, cfg, *(rows[name] for name in module.ROWS), n_valid)
+        h, lp, cfg, *(rows[name] for name in module.ROWS), n_valid,
+        **({} if layer is None else {"layer": layer}))
     return out, dict(zip(module.ROWS, after))
 
 

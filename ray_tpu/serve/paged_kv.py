@@ -75,7 +75,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ray_tpu.models import mamba2
 from ray_tpu.models.transformer import (
     RECURRENT_KINDS,
     TransformerConfig,
@@ -630,13 +629,15 @@ def _walk_hybrid(params, x, k_cache, v_cache, rec, attend, rec_io,
     names, and `write(rec, j, rows) -> rec`; `n_valid [B]` real rows of
     each (`transformer.mix_recurrent`). `rec_io` None: the call
     advances every slot's row by one token where it lies in the pool, and
-    a Mamba mixer takes the whole pool of states and `j` (`ops.ssm_update`:
-    layer `j` is passed over once; the other kinds' rows are sliced out at
-    `j` and set back, which the compiler does in place). Returns x, the
-    caches, `rec` and the assignments (None without experts)."""
+    a kind whose module names rows `IN_POOL` (Mamba-2's and the delta
+    rule's `state`) has its mixer take those whole and `j`
+    (`ops.ssm_update`, `ops.kda_update`: layer `j` is passed over once each
+    way; every other row is sliced out at `j` and set back, which the
+    compiler does in place). Returns x, the caches, `rec` and the
+    assignments (None without experts)."""
     layers = params["layers"]
-    kind = cfg.recurrent_kind
-    stack = layers[RECURRENT_KINDS[kind][0]]
+    stack_name, recurrent = RECURRENT_KINDS[cfg.recurrent_kind]
+    stack = layers[stack_name]
     is_recurrent, within = layer_kinds(cfg)
     n_attn, n_dense = cfg.attention_layers, cfg.n_layers - cfg.expert_layers
     experts, routers, counts = {}, None, None
@@ -652,16 +653,16 @@ def _walk_hybrid(params, x, k_cache, v_cache, rec, attend, rec_io,
         return {**at_layer(routers, i - n_dense), **experts}, i - n_dense
 
     def mix(h, lp, rec, j):
-        if kind == "mamba" and rec_io is None:
-            out, state, conv = mamba2.mixer(
-                h, lp, cfg, rec["state"], rec["conv"][j], n_valid, layer=j)
-            return out, {"state": state, "conv": rec["conv"].at[j].set(conv)}
-        rows = at_layer(rec, j) if rec_io is None else rec_io[0](rec, j)
-        out, rows = mix_recurrent(h, lp, cfg, rows, n_valid)
-        if rec_io is None:
-            return out, {name: rec[name].at[j].set(new)
-                         for name, new in rows.items()}
-        return out, rec_io[1](rec, j, rows)
+        if rec_io is not None:
+            out, rows = mix_recurrent(h, lp, cfg, rec_io[0](rec, j), n_valid)
+            return out, rec_io[1](rec, j, rows)
+        whole = recurrent.IN_POOL
+        out, rows = mix_recurrent(
+            h, lp, cfg, {name: pool if name in whole else pool[j]
+                         for name, pool in rec.items()},
+            n_valid, layer=j if whole else None)
+        return out, {name: new if name in whole else rec[name].at[j].set(new)
+                     for name, new in rows.items()}
 
     def recurrent_run(x, rec, counts, first, rec_first, count, dense):
         """`count` recurrent layers from layer `first`, the `rec_first`-th

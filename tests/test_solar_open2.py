@@ -28,6 +28,7 @@ import numpy as np
 import pytest
 
 from ray_tpu.models import configs, forward, init_params, kda
+from ray_tpu.ops import kda_update as kda_update_op
 from ray_tpu.parallel.moe import moe_block
 from ray_tpu.serve import paged_kv
 from ray_tpu.serve.llm import ContinuousBatchingEngine
@@ -454,8 +455,8 @@ SLOTS, MAX_LEN, PAGE = 3, 64, 8
 PAGES_PER_SLOT = MAX_LEN // PAGE
 
 
-def fresh_cache(poison=0.0):
-    cache = paged_kv.init_paged_cache(CFG, SLOTS, SLOTS * PAGES_PER_SLOT + 1,
+def fresh_cache(poison=0.0, cfg=CFG):
+    cache = paged_kv.init_paged_cache(cfg, SLOTS, SLOTS * PAGES_PER_SLOT + 1,
                                       PAGE, PAGES_PER_SLOT)
     table = np.zeros((SLOTS, PAGES_PER_SLOT), np.int32)
     for s in range(SLOTS):
@@ -470,17 +471,61 @@ def counters():
     return paged_kv.init_routing_counters(CFG), paged_kv.init_ssm_counters()
 
 
-@pytest.fixture(scope="module")
-def programs():
+def programs_for(cfg):
     prefill = jax.jit(
         lambda p, t, n, s, o, k, v, ln, bt, moe, rec, count:
-        paged_kv.prefill_chunk_paged(p, t, n, s, o, k, v, ln, bt, CFG,
+        paged_kv.prefill_chunk_paged(p, t, n, s, o, k, v, ln, bt, cfg,
                                      MAX_LEN, None, moe, rec, count))
     decode = jax.jit(
         lambda p, t, k, v, ln, a, bt, moe, rec, count: paged_kv.decode_paged(
-            p, t, k, v, ln, a, bt, None, None, None, None, CFG, MAX_LEN,
+            p, t, k, v, ln, a, bt, None, None, None, None, cfg, MAX_LEN,
             None, moe, rec, count))
     return prefill, decode
+
+
+@pytest.fixture(scope="module")
+def programs():
+    return programs_for(CFG)
+
+
+# The model with delta-rule heads of a width `ops.kda_update`'s kernel takes
+# (values of whole lanes, heads in whole eights; the toy's four of 16 take
+# the plain form): the served-path cases that decode run on it too, the
+# kernel interpreted in the walk.
+WIDE = replace(CFG, kda_num_heads=8, kda_head_dim=128)
+
+
+@pytest.fixture(scope="module")
+def wide_params():
+    return seeded_params(WIDE)
+
+
+@pytest.fixture
+def kernel_in_the_walk(monkeypatch):
+    """`kda.mixer`'s `kda_update` run in Pallas's interpreter for the length
+    of a test (off the TPU it would take the plain form); afterwards, that
+    the walk did reach it."""
+    calls = []
+
+    def interpreted(*args):
+        calls.append(kda_update_op.kernel_takes(args[5]))
+        return kda_update_op.kda_update(*args, interpret=True)
+
+    monkeypatch.setattr(kda, "kda_update", interpreted)
+    yield
+    assert calls and all(calls)
+
+
+@pytest.fixture(params=["plain", "kernel"])
+def served(request, params, programs):
+    """What a step-program case runs on, as (cfg, params, the reference's
+    dims, prefill, decode): the toy model through the plain form of the
+    one-token update, and `WIDE` through the kernel."""
+    if request.param == "plain":
+        return CFG, params, DIMS, *programs
+    request.getfixturevalue("kernel_in_the_walk")
+    return (WIDE, request.getfixturevalue("wide_params"),
+            spec.dims_of(WIDE, FILE), *programs_for(WIDE))
 
 
 def rows(cache, slot):
@@ -583,16 +628,16 @@ def test_a_chunks_padding_advances_nothing(params, programs, length):
     assert close(rows(cache, 1), rows({"rec": rec}, 2))
 
 
-def test_decode_leaves_idle_and_prefilling_slots_as_they_were(params,
-                                                              programs):
+def test_decode_leaves_idle_and_prefilling_slots_as_they_were(served):
     """The decode program runs over every slot: one that is idle, or whose
     prompt is half way through its chunks, keeps its state and its
     convolution inputs bit for bit, and the half-way prompt then finishes
-    as if no step had run."""
-    prefill, decode = programs
+    as if no step had run. Through the plain one-token form and through
+    the kernel over the pool (`served`)."""
+    cfg, params, dims, prefill, decode = served
     long_prompt, short = prompt_of(2 * CHUNK + 9), prompt_of(7)
-    _, cache, _ = prefill_prompt(prefill, params, fresh_cache(poison=2.0), 0,
-                                 short)
+    _, cache, _ = prefill_prompt(prefill, params,
+                                 fresh_cache(poison=2.0, cfg=cfg), 0, short)
     # Slot 1: the first chunk of the long prompt only.
     _, cache, _ = prefill_prompt(prefill, params, cache, 1,
                                  long_prompt[:CHUNK])
@@ -627,7 +672,7 @@ def test_decode_leaves_idle_and_prefilling_slots_as_they_were(params,
             params, padded, np.int32(len(chunk)), np.int32(1),
             np.int32(CHUNK + off), k, v, lengths, cache["block_tables"], moe,
             rec, c)
-    ref = reference_logits(params, long_prompt)
+    ref = reference_logits(params, long_prompt, dims)
     assert rel_rms(np.asarray(logits[0]), ref[-1]) < TOLERANCE
 
 
@@ -675,19 +720,37 @@ def engine(params):
     eng.shutdown()
 
 
+@pytest.fixture(params=["plain", "kernel"])
+def served_engine(request):
+    """(engine, params, the reference's dims): the module's engine on the
+    toy model, and one on `WIDE` whose decode program is traced with the
+    kernel interpreted in the walk."""
+    if request.param == "plain":
+        yield (request.getfixturevalue("engine"),
+               request.getfixturevalue("params"), DIMS)
+        return
+    request.getfixturevalue("kernel_in_the_walk")
+    wide = request.getfixturevalue("wide_params")
+    eng = engine_for(wide, WIDE)
+    yield eng, wide, spec.dims_of(WIDE, FILE)
+    eng.shutdown()
+
+
 @pytest.mark.parametrize("length", PROMPT_LENS)
-def test_engine_prefill_then_decode_against_the_reference(params, engine,
+def test_engine_prefill_then_decode_against_the_reference(served_engine,
                                                           length):
     """Prefill (under a chunk, exactly one, several and a remainder) into
     pages and the delta-rule pool, then eight greedy decode steps through
     both pools, against the reference's one full forward pass over prompt +
     tokens: the prefill's logits outright, every served token by its
-    margin."""
+    margin. Through the plain one-token form and through the kernel over
+    the pool (`served_engine`)."""
+    engine, params, dims = served_engine
     prompt = prompt_of(length, seed=1)
     first = engine.prefill_logits(prompt)
     served = engine.submit(prompt, max_new_tokens=8).result(timeout=180)
     assert len(served) == 8
-    ref = reference_logits(params, prompt + served[:-1])
+    ref = reference_logits(params, prompt + served[:-1], dims)
     assert rel_rms(first, ref[len(prompt) - 1]) < TOLERANCE
     assert max(margins(ref[len(prompt) - 1:], served)) < TOLERANCE
 
