@@ -186,6 +186,18 @@ def _engine_metrics() -> Dict:
         return _metrics
 
 
+def _acquire_timed(lock, t0: Optional[float] = None) -> float:  # rtlint: disable=RT016 — returns holding `lock`, as acquire() does
+    """`lock.acquire()` that says what it waited, in seconds: 0.0, and no
+    clock read, where the lock was free; else from `t0` (a `perf_counter`
+    stamp the caller has taken already, or one taken here) to having it."""
+    if lock.acquire(False):
+        return 0.0
+    if t0 is None:
+        t0 = time.perf_counter()
+    lock.acquire()
+    return time.perf_counter() - t0
+
+
 class GenerationHandle:
     """Per-request stream: tokens arrive as the engine produces them."""
 
@@ -231,7 +243,10 @@ class GenerationHandle:
         ))
 
     # -- engine side --
-    def _push(self, token: int, done: bool):
+    def _push(self, token: int, done: bool) -> float:
+        """Hand the consumer a token. Returns the seconds the caller
+        waited for the condition (a consumer holds it while it takes a
+        token): 0.0, and no second stamp, where it was free."""
         now = time.perf_counter()
         first = self._first_token_t is None
         if first:
@@ -248,10 +263,13 @@ class GenerationHandle:
             if done:
                 obs.marks["engine_done"] = now
                 obs.tokens_out = self.produced
-        with self._cond:
+        waited = _acquire_timed(self._cond, now)
+        try:
             self._tokens.append(int(token))
             self._done = self._done or done
             self._cond.notify_all()
+        finally:
+            self._cond.release()
         # Observe outside the condition: a blocked consumer wakes without
         # waiting on the metrics registry lock.
         m = _engine_metrics()
@@ -261,14 +279,21 @@ class GenerationHandle:
             m["tpot_s"].observe(
                 (now - self._first_token_t) / (self.produced - 1)
             )
+        return waited
 
-    def _fail(self, err: BaseException):
-        with self._cond:
+    def _fail(self, err: BaseException) -> float:
+        """Fail the request. Returns the seconds waited for the condition,
+        as `_push` does."""
+        waited = _acquire_timed(self._cond)
+        try:
             if self._done and self._error is None:
-                return  # finished cleanly first; late cancel/fail is moot
+                return waited  # finished cleanly first; late cancel/fail is moot
             self._error = err
             self._done = True
             self._cond.notify_all()
+        finally:
+            self._cond.release()
+        return waited
 
     # -- caller side --
     def __iter__(self):
@@ -340,6 +365,18 @@ class _PhaseLedger:
     the turn total by construction. `wait_for_work` (idle) lies beside
     the turns and in no total.
 
+    What the loop's thread waits for a lock is counted, and only when it
+    waits: `_Held` takes the engine's lock for the loop and, when
+    somebody else holds it, stamps the wait into `lock_wait_engine` under
+    an `engine.lock_wait` span (so a gap the lock made is named so in a
+    trace and not by its phase); a handle says what its `_push` or
+    `_fail` waited for its condition and the loop adds it (`lock_waited`).
+    `between_turns` is a turn's exit to the next turn's entry with no
+    `wait_for_work` between: the publication of the snapshot, in no other
+    total. (No `time.thread_time()` beside the stamps: it is a system
+    call a read on a sandboxed host, and the loop stalled more often with
+    it; `PERF.md` section 6, PR 63, has what it found.)
+
     The time the loop KNOWS the device dry, by cause. The loop dispatches
     every program in order and hands the ledger a result of the newest
     (`newest`: one no later program takes as a donated argument); the
@@ -371,6 +408,7 @@ class _PhaseLedger:
         counted = (*keys, "pass_drain", "drained_fetch", "drained_late",
                    "stalls", *(f"{kind}@{k}" for kind in ("late", "stall")
                                for k in _BY_PHASE))
+        counted += ("lock_wait_engine", "lock_wait_handle", "between_turns")
         self.n = dict.fromkeys(counted, 0)
         self.s = dict.fromkeys(counted, 0.0)
         self.prefill_passes = 0  # turns in which _advance_prefills ran
@@ -387,9 +425,19 @@ class _PhaseLedger:
         self._pass_span = None   # the open `engine.pass_drain`
         self._pass_t0 = 0.0
         self._late_span = None   # the open `engine.drained_late`
+        self._turn_end = 0.0     # the last turn's exit, 0.0 once idle
 
     def __call__(self, key: str) -> "_Phase":
         return _Phase(self, key)
+
+    def lock_waited(self, kind: str, seconds: float):
+        """What the loop's thread waited for a lock (`_acquire_timed`):
+        one contended acquisition of `lock_wait_<kind>`, or 0.0 and
+        nothing to count."""
+        if seconds:
+            key = f"lock_wait_{kind}"
+            self.s[key] += seconds
+            self.n[key] += 1
 
     def snapshot(self) -> Dict:
         return {"n": dict(self.n), "s": dict(self.s),
@@ -409,10 +457,14 @@ class _PhaseLedger:
             self._dry = ()
             self._dispatched(now)
             self.newest = None
+            self._turn_end = 0.0
             return
         if key == "turn":
             self._children = 0.0
             self._dry_t = now
+            if self._turn_end:
+                self.s["between_turns"] += now - self._turn_end
+                self.n["between_turns"] += 1
         if self.newest is not None and self.newest.is_ready():
             self._seen(now, "late", "other")
 
@@ -422,6 +474,8 @@ class _PhaseLedger:
         if key == "wait_for_work":
             return
         if key == "turn":
+            # A failed turn is followed by the loop's recovery and a sleep.
+            self._turn_end = 0.0 if failed else now
             by, over = "other", elapsed - self._children
             self._accrue(now)
             self._close_pass(now)
@@ -486,14 +540,13 @@ class _PhaseLedger:
 
 class _Phase:
     """One span of the ledger (class-based: this runs a dozen times a
-    turn). `elapsed` is set on exit."""
+    turn)."""
 
-    __slots__ = ("_ledger", "_key", "_span", "_t0", "elapsed")
+    __slots__ = ("_ledger", "_key", "_span", "_t0")
 
     def __init__(self, ledger: _PhaseLedger, key: str):
         self._ledger = ledger
         self._key = key
-        self.elapsed = 0.0
 
     def __enter__(self):
         ledger, key = self._ledger, self._key
@@ -512,9 +565,30 @@ class _Phase:
         ledger = self._ledger
         ledger.t = now = time.perf_counter()
         self._span.__exit__(*exc)
-        self.elapsed = now - self._t0
-        ledger._exited(self._key, now, self.elapsed, exc[0] is not None)
-        return False
+        ledger._exited(self._key, now, now - self._t0, exc[0] is not None)
+
+
+class _Held:
+    """`with _Held(ledger, lock):` is `with lock:` for the loop's thread
+    and the engine's lock. Free, it costs the one failed branch; held by
+    another thread, the wait is stamped, counted (`lock_wait_engine`) and
+    spanned `engine.lock_wait`. Holds no state of its own: one is kept
+    and entered again."""
+
+    __slots__ = ("_ledger", "_lock")
+
+    def __init__(self, ledger: _PhaseLedger, lock):
+        self._ledger = ledger
+        self._lock = lock
+
+    def __enter__(self):  # rtlint: disable=RT016 — a context manager: __exit__ releases
+        if not self._lock.acquire(False):
+            with jax.profiler.TraceAnnotation("engine.lock_wait"):
+                waited = _acquire_timed(self._lock)
+            self._ledger.lock_waited("engine", waited)
+
+    def __exit__(self, *exc):
+        self._lock.release()
 
 
 def _timing_of(ledger: Dict) -> Dict:
@@ -542,6 +616,12 @@ def _timing_of(ledger: Dict) -> Dict:
         "other_ms_total": (s["turn"] - by_class["work"]
                            - by_class["wait"]) * 1e3,
         "phases": {k: of(k) for k in (*_TURN_PHASES, "wait_for_work")},
+        # Acquisitions by the loop's thread that found the lock taken
+        # (the engine's, a handle's condition), and what they waited.
+        "lock_wait": {"engine": of("lock_wait_engine"),
+                      "handle": of("lock_wait_handle")},
+        # A turn's exit to the next turn's entry, no idle wait between.
+        "between_turns": of("between_turns"),
         # The time the loop knew the device dry inside its turns, by
         # cause (a lower bound of the device's idle time beside
         # `wait_for_work`, see `_PhaseLedger`), and the span that names
@@ -791,13 +871,9 @@ class ContinuousBatchingEngine:
         # loop publishes under the lock after each turn for stats().
         self._phase = _PhaseLedger()
         self._ledger_pub = self._phase.snapshot()
-        # Decode-step breakdown over the turns that dispatched a decode
-        # step, from the ledger's spans (loop thread writes under the
-        # lock, stats() reads).
-        self._t_dispatch = 0.0
-        self._t_fetch = 0.0
-        self._t_host = 0.0
-        self._timed_steps = 0
+        # `self._lock` as the loop's thread takes it: a wait for it is
+        # the ledger's `lock_wait_engine`.
+        self._lock_loop = _Held(self._phase, self._lock)
         self._rng = jax.random.PRNGKey(seed)
         self._next_id = 0
         self._steps = 0  # decode-step counter (observability + tests)
@@ -960,13 +1036,13 @@ class ContinuousBatchingEngine:
         adjusting toward the target as pages free up, until the frac is
         set back to 0."""
         if self._prefix_cache is not None and chaos.take_flush_prefix_cache():
-            with self._lock:
+            with self._lock_loop:
                 self._prefix_cache.flush()
         frac = chaos.kv_exhaust_frac()
         if frac is None and not self._chaos_held:
             return
         target = int(round((frac or 0.0) * self._pool.usable))
-        with self._lock:
+        with self._lock_loop:
             if len(self._chaos_held) > target:
                 give_back = self._chaos_held[target:]
                 del self._chaos_held[target:]
@@ -1240,18 +1316,9 @@ class ContinuousBatchingEngine:
                 # Where the loop's time goes (EQuARX discipline — you
                 # cannot shrink a step you cannot decompose). _total
                 # fields are cumulative: probes delta two stats()
-                # snapshots for a clean steady-state window. The first
-                # four keys cover only turns that dispatched a decode
-                # step, `host` being such a turn less its dispatch and
-                # its fetch (prefill and its device waits included); the
-                # ledger's keys cover every turn.
-                "timing": {
-                    "steps_timed": self._timed_steps,
-                    "dispatch_ms_total": self._t_dispatch * 1e3,
-                    "fetch_ms_total": self._t_fetch * 1e3,
-                    "host_ms_total": self._t_host * 1e3,
-                    **_timing_of(self._ledger_pub),
-                },
+                # snapshots for a clean steady-state window. One ledger
+                # (`_PhaseLedger`), every turn.
+                "timing": _timing_of(self._ledger_pub),
                 # Request-level latency (flight recorder): process-wide
                 # lifetime summaries of the TTFT/TPOT histograms, plus
                 # the instantaneous batch occupancy.
@@ -1340,11 +1407,12 @@ class ContinuousBatchingEngine:
                 continue  # cancel() already failed the handle
             if h.deadline_ts and now > h.deadline_ts:
                 self._deadline_expired += 1
-                h._fail(RequestCancelledError(
-                    f"deadline expired in admission queue "
-                    f"(request {h.request_id})",
-                    reason="deadline", rid=str(h.request_id),
-                ))
+                self._phase.lock_waited("handle", h._fail(
+                    RequestCancelledError(
+                        f"deadline expired in admission queue "
+                        f"(request {h.request_id})",
+                        reason="deadline", rid=str(h.request_id),
+                    )))
                 observatory.record_deadline_expired("", "engine_admission")
                 continue
             # Deliverable budget: the loop cuts a sequence at lengths >=
@@ -1513,13 +1581,14 @@ class ContinuousBatchingEngine:
                 # Abandon the partial prefill: remaining chunks would be
                 # work for a request nobody is waiting on.
                 if not h.cancelled:
-                    h._fail(RequestCancelledError(
-                        f"deadline expired mid-prefill "
-                        f"(request {h.request_id})",
-                        reason="deadline", rid=str(h.request_id),
-                    ))
+                    self._phase.lock_waited("handle", h._fail(
+                        RequestCancelledError(
+                            f"deadline expired mid-prefill "
+                            f"(request {h.request_id})",
+                            reason="deadline", rid=str(h.request_id),
+                        )))
                     observatory.record_deadline_expired("", "engine_decode")
-                with self._lock:
+                with self._lock_loop:
                     self._deadline_expired += int(not h.cancelled)
                     del self._prefilling[slot]
                     self._free.append(slot)
@@ -1592,8 +1661,8 @@ class ContinuousBatchingEngine:
             h.admitted_at_step = self._steps  # rtlint: disable=RT010 — _steps is loop-thread-only (see comment)
             done = (tok == self.eos_id if self.eos_id is not None
                     else False) or h.produced >= h.max_new_tokens
-            h._push(tok, done)
-            with self._lock:
+            self._phase.lock_waited("handle", h._push(tok, done))
+            with self._lock_loop:
                 if self._prefix_cache is not None:
                     # Publish the prompt's full pages NOW (not at
                     # request completion): a concurrent same-prefix
@@ -1629,7 +1698,7 @@ class ContinuousBatchingEngine:
             return
         blocked = prefill_s * n_active  # slot-seconds of stalled decode
         culprits = self._last_prefill_work
-        with self._lock:
+        with self._lock_loop:
             self._hol_blocked_s += blocked
             self._hol_events.append({
                 "ts": time.time(),
@@ -1653,12 +1722,11 @@ class ContinuousBatchingEngine:
     def _turn(self):  # rtlint: disable=RT006,RT010
         """One loop iteration with work, inside the ledger's `turn` span:
         admit, advance prefills by a pass, dispatch decode step k+1,
-        drain and distribute step k. Returns, for a turn that dispatched
-        a decode step, its (dispatch, fetch) seconds."""
+        drain and distribute step k."""
         phase = self._phase
         with phase("admit"):
             self._apply_kv_chaos()
-            with self._lock:
+            with self._lock_loop:
                 self._admit_locked()
                 n_active = len(self._slots)
         # HOL watchdog: a prefill pass that stalls active decode slots
@@ -1671,7 +1739,7 @@ class ContinuousBatchingEngine:
             t_pass = phase.t
             self._advance_prefills()
             self._note_hol(phase.t - t_pass, n_active)
-        with self._lock:
+        with self._lock_loop:
             snapshot = [
                 (s, int(self._gen[s]), h) for s, h in self._slots.items()
             ]
@@ -1685,13 +1753,13 @@ class ContinuousBatchingEngine:
                 pages = -(-self._rows_host[live] // self.page_size)
                 self._attn_rows_read += int(pages.sum()) * self.page_size
                 self._attn_rows_held += self.num_slots * self.max_len
-        new_inflight, dispatch_s, fetch_s = None, 0.0, 0.0
+        new_inflight = None
         if snapshot:
             if self._params_dirty:
                 self._upload_sampling_state()
             if self._bt_dirty:
                 self._upload_block_table()
-            with phase("decode_dispatch") as dispatch:
+            with phase("decode_dispatch"):
                 if self._sampled_active:
                     self._rng, step_key = jax.random.split(self._rng)
                     (next_dev, self._k, self._v, self._lengths,
@@ -1724,17 +1792,15 @@ class ContinuousBatchingEngine:
                 # below (0.24 ms a turn on the v5e).
                 step_key = None
             new_inflight = (snapshot, next_dev, self._lengths)
-            dispatch_s = dispatch.elapsed
         if self._inflight is not None:
             prev_snapshot, prev_tokens, prev_lengths = self._inflight
-            with phase("decode_fetch_wait") as fetch:
+            with phase("decode_fetch_wait"):
                 # Intentional single drain: copy_to_host_async above
                 # started this transfer a full step ago, so this is the
                 # double-buffered collect, not a per-step sync.
                 toks, lengths_np = jax.device_get(  # rtlint: disable=RT001
                     (prev_tokens, prev_lengths)
                 )
-            fetch_s = fetch.elapsed
             with phase("distribute"):
                 self._distribute(prev_snapshot, toks, lengths_np)
                 # The drained step's device arrays die here, inside a
@@ -1749,8 +1815,6 @@ class ContinuousBatchingEngine:
             m["occupancy"].set(len(snapshot) / self.num_slots)
             m["waiting"].set(float(self._waiting_n))  # gauge snapshot: a stale int is fine
             m["kv_pages"].set(float(self._pool.in_use))
-            return dispatch_s, fetch_s
-        return None
 
     def _evict_locked(self, s: int):
         """Free decode slot `s` (finished, cancelled or expired): the
@@ -1770,7 +1834,10 @@ class ContinuousBatchingEngine:
         """Push a drained step's tokens to their handles; evict what
         finished, was cancelled or ran out of deadline."""
         now_wall = time.time()
-        with self._lock:
+        # A handle says what its push waited for a consumer that held its
+        # condition (it holds it while it takes a token).
+        phase = self._phase
+        with self._lock_loop:
             self._steps += 1
             for s, gen, h in prev_snapshot:
                 if self._gen[s] != gen or self._slots.get(s) is not h:
@@ -1783,12 +1850,13 @@ class ContinuousBatchingEngine:
                     # cancelled case).
                     if not h.cancelled:
                         self._deadline_expired += 1
-                        h._fail(RequestCancelledError(
-                            f"deadline expired mid-decode "
-                            f"(request {h.request_id}, "
-                            f"{h.produced} tokens produced)",
-                            reason="deadline", rid=str(h.request_id),
-                        ))
+                        phase.lock_waited("handle", h._fail(
+                            RequestCancelledError(
+                                f"deadline expired mid-decode "
+                                f"(request {h.request_id}, "
+                                f"{h.produced} tokens produced)",
+                                reason="deadline", rid=str(h.request_id),
+                            )))
                         observatory.record_deadline_expired(
                             "", "engine_decode"
                         )
@@ -1803,7 +1871,7 @@ class ContinuousBatchingEngine:
                     # margin.
                     or int(lengths_np[s]) >= self.max_len - 2
                 )
-                h._push(tok, done)
+                phase.lock_waited("handle", h._push(tok, done))
                 if done:
                     self._evict_locked(s)
 
@@ -1830,7 +1898,7 @@ class ContinuousBatchingEngine:
                     with phase("wait_for_work"):
                         self._work.wait(timeout=0.5)
                         self._work.clear()
-                    with self._lock:
+                    with self._lock_loop:
                         self._ledger_pub = phase.snapshot()
                         # submit() raises it before setting _work: a
                         # miss here is caught by the next wait.
@@ -1838,25 +1906,19 @@ class ContinuousBatchingEngine:
                     if idle:
                         self._apply_kv_chaos()
                         continue
-                with phase("turn") as turn:
-                    timed = self._turn()
-                with self._lock:
+                with phase("turn"):
+                    self._turn()
+                with self._lock_loop:
                     self._ledger_pub = phase.snapshot()
-                    if timed is not None:
-                        dispatch_s, fetch_s = timed
-                        self._t_dispatch += dispatch_s
-                        self._t_fetch += fetch_s
-                        self._t_host += turn.elapsed - dispatch_s - fetch_s
-                        self._timed_steps += 1
             except BaseException as e:  # noqa: BLE001 — fail all, keep serving
-                with self._lock:
+                with self._lock_loop:
                     pending = (
                         list(self._slots.values())
                         + self._drain_waiting_locked()
                         + [en["h"] for en in self._prefilling.values()]
                     )
                     for h in pending:
-                        h._fail(e)
+                        phase.lock_waited("handle", h._fail(e))
                     self._slots.clear()
                     self._prefilling.clear()
                     self._free = deque(range(self.num_slots))

@@ -394,9 +394,9 @@ def test_continuous_batching_steady_state_zero_host_traffic():
 
 
 def test_continuous_batching_step_timing_breakdown():
-    """stats()['timing'] decomposes engine steps into dispatch/fetch/
-    host wall-time; totals are cumulative (probes delta two snapshots:
-    a mean is a total over `steps_timed`)."""
+    """stats()['timing'] decomposes engine steps into the ledger's
+    phases; totals are cumulative (probes delta two snapshots: a mean is
+    a total over a phase's `n`)."""
     from ray_tpu.serve.llm import ContinuousBatchingEngine
 
     params, cfg = _tiny_model()
@@ -406,16 +406,22 @@ def test_continuous_batching_step_timing_breakdown():
         # The last token is pushed inside the turn that drains it, and
         # the turn's times are added when it ends: give it that moment.
         deadline = time.monotonic() + 10
-        while (eng.stats()["timing"]["steps_timed"] < 12
-               and time.monotonic() < deadline):
+        def fetched():
+            return eng.stats()["timing"]["phases"]["decode_fetch_wait"]["n"]
+
+        while fetched() < 11 and time.monotonic() < deadline:
             time.sleep(0.01)
         t = eng.stats()["timing"]
-        assert t["steps_timed"] >= 12
-        for part in ("dispatch", "fetch", "host"):
-            assert t[f"{part}_ms_total"] >= 0.0
+        dispatch, fetch = (t["phases"][k] for k in ("decode_dispatch",
+                                                    "decode_fetch_wait"))
+        # Eleven steps after the pass's own token; the loop may have
+        # dispatched one more before it saw the last.
+        assert dispatch["n"] >= fetch["n"] >= 11
+        for part in (dispatch, fetch):
+            assert part["ms_total"] >= 0.0
         # A decode turn is its dispatch, its fetch and the rest.
-        assert (t["dispatch_ms_total"] + t["fetch_ms_total"]
-                + t["host_ms_total"]) <= t["turn_ms_total"] * (1 + 1e-9)
+        assert (dispatch["ms_total"] + fetch["ms_total"]
+                <= t["turn_ms_total"] * (1 + 1e-9))
     finally:
         eng.shutdown()
 
@@ -483,6 +489,8 @@ def ledger_run(tmp_path_factory):
     params, cfg = _tiny_model()
     eng = ContinuousBatchingEngine(params, cfg, num_slots=3, max_len=128,
                                    prefill_chunk=_LEDGER_CHUNK)
+    turn, turn_returned = eng._turn, []
+    eng._turn = lambda: turn_returned.append(turn())
     trace_dir = str(tmp_path_factory.mktemp("ledger_trace"))
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -515,7 +523,7 @@ def ledger_run(tmp_path_factory):
             if spans:
                 threads.append(spans)
     return {"before": before, "alone": alone, "after": after, "idle": idle,
-            "threads": threads}
+            "threads": threads, "turn_returned": turn_returned}
 
 
 def _ledger_sums_to_the_turn_total(run):
@@ -553,18 +561,14 @@ def _ledger_counts_prefill_chunks_and_passes(run):
 
 def _ledger_counts_turns_without_a_decoding_slot(run):
     """A 44-token prompt alone, six chunks, prefills in two passes of a
-    turn each; only the last leaves a slot decoding, and the old clock
-    did not count the first."""
+    turn each; only the last leaves a slot decoding, and a clock over
+    the turns that dispatch a decode step would not count the first."""
     t0, t1 = run["before"]["timing"], run["alone"]["timing"]
     turns = t1["turns"] - t0["turns"]
-    timed = t1["steps_timed"] - t0["steps_timed"]
+    decoding = _delta(t0, t1, "phases.decode_dispatch.n")
     assert t1["prefill_passes"] - t0["prefill_passes"] == 2
     assert t1["prefill_rows"] - t0["prefill_rows"] == 6
-    assert turns - timed >= 1
-    # The old keys keep their meaning: turns that dispatched a decode step.
-    assert timed == t1["phases"]["decode_dispatch"]["n"]
-    assert t1["dispatch_ms_total"] == pytest.approx(
-        t1["phases"]["decode_dispatch"]["ms_total"])
+    assert decoding >= 5 and turns - decoding >= 1
 
 
 def _loop_spans(run):
@@ -590,11 +594,11 @@ def _ledger_spans_nest_in_the_profilers_trace(run):
         return any(ts <= s and e <= te for ts, te in turns)
 
     names = {n for n, _s, _e in spans}
-    assert names - {"engine.drained_late"} == (
+    assert names - {"engine.drained_late", "engine.lock_wait"} == (
         {f"engine.{k}" for k in _LEDGER_CHILDREN}
         | {"engine.turn", "engine.wait_for_work", "engine.pass_drain"})
     for n, s, e in spans:
-        if n in ("engine.turn", "engine.drained_late"):
+        if n in ("engine.turn", "engine.drained_late", "engine.lock_wait"):
             continue
         assert inside_a_turn(s, e) == (n != "engine.wait_for_work"), n
     # The spans are the ledger's: as many of each as it counted.
@@ -621,7 +625,8 @@ def _ledger_a_first_token_fetch_drains_the_device(run):
     one more."""
     t0, t1 = run["before"]["timing"], run["alone"]["timing"]
     assert _delta(t0, t1, "phases.prefill_first_token_wait.n") == 1
-    idle_turns = _delta(t0, t1, "turns") - _delta(t0, t1, "steps_timed")
+    idle_turns = (_delta(t0, t1, "turns")
+                  - _delta(t0, t1, "phases.decode_dispatch.n"))
     assert 1 <= _delta(t0, t1, "drained.fetch.n") <= 1 + idle_turns
     assert (0 < _delta(t0, t1, "drained.fetch.ms_total")
             <= _delta(t0, t1, "turn_ms_total"))
@@ -685,13 +690,18 @@ def _ledger_pass_drain_spans_end_with_a_dispatch(run):
 
 
 def _ledger_keeps_the_totals_and_no_averages(run):
-    """`timing` is cumulative: the seven totals stay, the three means
-    nobody read are gone (a mean is a total over `steps_timed`)."""
+    """`timing` is cumulative: the ledger's totals stay; the three means
+    nobody read are gone (a mean is a total over a count), and so are the
+    four totals of the decode turns alone, which `phases.decode_dispatch`,
+    `phases.decode_fetch_wait` and `turn_ms_total` hold for every turn:
+    `_turn` hands the loop nothing to add up."""
     t = run["after"]["timing"]
-    for key in ("dispatch", "fetch", "host", "turn", "work", "wait",
-                "other"):
+    for key in ("turn", "work", "wait", "other"):
         assert t[f"{key}_ms_total"] >= 0.0, key
     assert not [k for k in t if k.endswith("_avg")]
+    assert not {"steps_timed", "dispatch_ms_total", "fetch_ms_total",
+                "host_ms_total"} & set(t)
+    assert run["turn_returned"] and set(run["turn_returned"]) == {None}
     # Readers walk `a.b.c`: no key carries a dot.
     def keys(doc):
         for k, v in doc.items():
@@ -699,6 +709,33 @@ def _ledger_keeps_the_totals_and_no_averages(run):
             if isinstance(v, dict):
                 yield from keys(v)
     assert not [k for k in keys(t) if "." in k]
+
+
+def _ledger_counts_the_time_between_turns(run):
+    """A turn's exit to the next turn's entry, where no idle wait lay
+    between: at least once in a request's decode steps, at most once a
+    turn, in no other total, and never falling."""
+    shots = [run[k]["timing"] for k in ("before", "alone", "after", "idle")]
+    assert shots[0]["between_turns"] == {"n": 0, "ms_total": 0.0}
+    for a, b in zip(shots, shots[1:]):
+        assert a["between_turns"]["n"] <= b["between_turns"]["n"]
+        assert a["between_turns"]["ms_total"] <= b["between_turns"]["ms_total"]
+    for t in shots[1:]:
+        assert 1 <= t["between_turns"]["n"] < t["turns"]
+        assert t["between_turns"]["ms_total"] > 0.0
+    # An idle loop adds nothing: the wait ends the stretch.
+    assert shots[3]["between_turns"] == shots[2]["between_turns"]
+
+
+def _ledger_nobody_made_a_request_alone_wait_for_a_lock(run):
+    """Nothing is stamped when the lock is free: a request alone, whose
+    caller sleeps in `result()`, finds the engine's lock and its handle's
+    condition free at every acquisition."""
+    t0, t1 = run["before"]["timing"], run["alone"]["timing"]
+    assert set(t1["lock_wait"]) == {"engine", "handle"}
+    for kind in ("engine", "handle"):
+        assert _delta(t0, t1, f"lock_wait.{kind}.n") == 0
+        assert _delta(t0, t1, f"lock_wait.{kind}.ms_total") == 0.0
 
 
 @pytest.mark.parametrize("check", [
@@ -710,6 +747,8 @@ def _ledger_keeps_the_totals_and_no_averages(run):
     _ledger_dry_time_lies_inside_the_turns,
     _ledger_pass_drain_spans_end_with_a_dispatch,
     _ledger_keeps_the_totals_and_no_averages,
+    _ledger_counts_the_time_between_turns,
+    _ledger_nobody_made_a_request_alone_wait_for_a_lock,
 ], ids=lambda f: f.__name__.lstrip("_"))
 def test_phase_ledger(ledger_run, check):
     check(ledger_run)
@@ -880,6 +919,232 @@ def test_phase_ledger_counts_and_logs_a_stall(monkeypatch, caplog):
                                                 "(a healthy phase is under "
                                                 "100 ms)")]) == 1, lines
     assert len(lines) == stalls["n"]
+
+
+@pytest.mark.parametrize("how", ["engine_lock", "handle_push", "handle_fail"])
+def test_lock_wait_stamps_nothing_when_free_and_the_wait_when_not(
+        monkeypatch, how):
+    """The loop's three ways to a lock. `_Held(ledger, lock)` (the engine's):
+    free, the lock is taken with no clock read, no span and no count; held
+    by another thread, the wait is one contended acquisition with what it
+    waited, under one `engine.lock_wait` span. A handle's `_push` and
+    `_fail` take its condition themselves and return what they waited
+    (0.0 where it was free, with no stamp but `_push`'s own), which
+    `lock_waited` counts; they open no span (`cancel()` calls `_fail` from
+    a caller's thread). On a clock that ticks 50 ms a read, so that the
+    wait is the two stamps' and no thread's luck."""
+    import threading
+
+    from ray_tpu.serve import llm
+
+    spans, clock_reads = [], []
+
+    class Span:
+        def __init__(self, name):
+            spans.append(name)
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    def perf_counter():  # 50 ms a read
+        clock_reads.append(1)
+        return 0.05 * len(clock_reads)
+
+    ledger = llm._PhaseLedger()
+    handle = llm.GenerationHandle(0)
+    lock = threading.Lock() if how == "engine_lock" else handle._cond
+    kind = "engine" if how == "engine_lock" else "handle"
+    own_stamps = 1 if how == "handle_push" else 0
+
+    def take():
+        if how == "engine_lock":
+            with llm._Held(ledger, lock):
+                assert lock.locked()
+        elif how == "handle_push":
+            ledger.lock_waited("handle", handle._push(5, False))
+        else:
+            ledger.lock_waited("handle", handle._fail(RuntimeError("x")))
+
+    monkeypatch.setattr(llm.jax.profiler, "TraceAnnotation", Span)
+    monkeypatch.setattr(llm.time, "perf_counter", perf_counter)
+    free = {"n": 0, "ms_total": 0.0}
+    if how != "handle_fail":  # a failed handle stays failed: its one take is below
+        take()
+        assert not spans and len(clock_reads) == own_stamps
+        t = llm._timing_of(ledger.snapshot())
+        assert t["lock_wait"] == {"engine": free, "handle": free}
+
+    taken, release = threading.Event(), threading.Event()
+
+    def hold():
+        with lock:
+            taken.set()
+            release.wait(timeout=30)
+
+    holder = threading.Thread(target=hold)
+    holder.start()
+    assert taken.wait(timeout=30)
+    threading.Timer(0.05, release.set).start()
+    reads = len(clock_reads)
+    take()
+    holder.join(timeout=30)
+    # The wait's two stamps, the first of them `_push`'s own.
+    assert len(clock_reads) == reads + 2
+    t = llm._timing_of(ledger.snapshot())
+    other = "handle" if kind == "engine" else "engine"
+    assert t["lock_wait"][kind] == {"n": 1, "ms_total": pytest.approx(50.0)}
+    assert t["lock_wait"][other] == free  # each under its own kind
+    assert spans == ["engine.lock_wait"] * (how == "engine_lock")
+    if how == "handle_fail":
+        assert handle._error is not None and handle._done
+
+
+def test_push_stamps_the_token_before_it_waits_for_the_condition(monkeypatch):
+    """`_push` reads its stamp (first token, the observatory card's
+    `push_t`) before it takes the condition, so a consumer that holds the
+    condition delays the token's delivery and not its stamp, and the wait
+    `_push` returns starts at that stamp."""
+    import threading
+    from types import SimpleNamespace
+
+    from ray_tpu.serve import llm
+
+    ticks = []
+
+    def perf_counter():  # 50 ms a read
+        ticks.append(1)
+        return 0.05 * len(ticks)
+
+    handle = llm.GenerationHandle(0)
+    handle.obs = SimpleNamespace(push_t=[], marks={}, tokens_out=0)
+    taken, release = threading.Event(), threading.Event()
+
+    def hold():
+        with handle._cond:
+            taken.set()
+            release.wait(timeout=30)
+
+    holder = threading.Thread(target=hold)
+    holder.start()
+    assert taken.wait(timeout=30)
+    threading.Timer(0.05, release.set).start()
+    monkeypatch.setattr(llm.time, "perf_counter", perf_counter)
+    waited = handle._push(5, False)
+    holder.join(timeout=30)
+    assert handle.obs.push_t == [pytest.approx(0.05)]  # the first read
+    assert handle.obs.marks["first_token"] == handle._first_token_t
+    assert handle._first_token_t == pytest.approx(0.05)
+    assert waited == pytest.approx(0.05) and len(ticks) == 2
+    assert list(handle._tokens) == [5]
+
+
+def test_phase_reads_one_clock_twice_and_no_system_call(monkeypatch):
+    """A phase of the ledger costs two `perf_counter` reads (the vDSO's)
+    and never `time.thread_time()`, a system call a read on a sandboxed
+    host, with which the loop stalled more often (`PERF.md`, PR 63)."""
+    from ray_tpu.serve import llm
+
+    reads = {"perf_counter": 0, "thread_time": 0}
+
+    def clock(name, step):
+        def read():
+            reads[name] += 1
+            return step * reads[name]
+        return read
+
+    monkeypatch.setattr(llm.time, "perf_counter", clock("perf_counter", 0.002))
+    monkeypatch.setattr(llm.time, "thread_time", clock("thread_time", 0.001))
+    ledger = llm._PhaseLedger()
+    with ledger("wait_for_work"):
+        pass
+    with ledger("turn"):
+        for key in llm._TURN_PHASES:
+            with ledger(key):
+                pass
+    assert reads == {"perf_counter": 2 * (2 + len(llm._TURN_PHASES)),
+                     "thread_time": 0}
+    t = llm._timing_of(ledger.snapshot())
+    assert all(set(p) == {"n", "ms_total"} for p in t["phases"].values())
+    assert t["phases"]["distribute"]["ms_total"] == pytest.approx(2.0)
+    assert not [k for k in t if "cpu" in k]
+
+
+def _paced_engine():
+    """A tiny engine whose `_distribute` sleeps 2 ms first, so that a
+    request of two hundred tokens decodes for half a second and more."""
+    from ray_tpu.serve.llm import ContinuousBatchingEngine
+
+    params, cfg = _tiny_model()
+    eng = ContinuousBatchingEngine(params, cfg, num_slots=2, max_len=256)
+    inner = eng._distribute
+
+    def paced(*a, **kw):
+        time.sleep(0.002)
+        return inner(*a, **kw)
+
+    eng._distribute = paced
+    return eng
+
+
+def _published(eng, path, t0, timeout=30):
+    """stats()["timing"] once the loop has published a turn in which the
+    count at `path` grew past `t0`'s (the ledger's copy is a turn old),
+    or the last one read."""
+    deadline = time.monotonic() + timeout
+    while True:
+        t1 = eng.stats()["timing"]
+        if _delta(t0, t1, path) >= 1 or time.monotonic() > deadline:
+            return t1
+        time.sleep(0.01)
+
+
+def test_phase_ledger_counts_the_loops_wait_for_the_engines_lock():
+    """Another thread holds the engine's lock across a turn (0.25 s: a
+    few paced turns even on a loaded machine): the loop's next acquisition
+    is a contended one, and what it waited is in `lock_wait.engine` and,
+    wherever the loop stood, in a turn's wall time or between two."""
+    eng = _paced_engine()
+    try:
+        h = eng.submit([3, 7, 11, 2], max_new_tokens=200)
+        _decoding(eng, steps=4)
+        t0 = eng.stats()["timing"]
+        with eng._lock:
+            time.sleep(0.25)
+        t1 = _published(eng, "lock_wait.engine.n", t0)
+        h.cancel()
+    finally:
+        eng.shutdown()
+    assert _delta(t0, t1, "lock_wait.engine.n") >= 1
+    assert _delta(t0, t1, "lock_wait.engine.ms_total") >= 40.0
+    assert (_delta(t0, t1, "turn_ms_total")
+            + _delta(t0, t1, "between_turns.ms_total")
+            >= _delta(t0, t1, "lock_wait.engine.ms_total"))
+    assert _delta(t0, t1, "lock_wait.handle.n") == 0
+
+
+def test_phase_ledger_counts_the_loops_wait_for_a_handles_condition():
+    """`GenerationHandle.__iter__` yields holding its condition: a
+    consumer that takes 0.25 s over a token makes the loop's next `_push`
+    to it wait, which is `lock_wait.handle`."""
+    eng = _paced_engine()
+    try:
+        t0 = eng.stats()["timing"]
+        h = eng.submit([3, 7, 11, 2], max_new_tokens=24)
+        # stats() takes the engine's lock, which the loop holds while it
+        # waits to push: nothing reads it before the consumer is through
+        # (a suspended generator that called stats() would wait for the
+        # loop that waits for it).
+        for i, _ in enumerate(h):
+            if i == 0:
+                time.sleep(0.25)  # suspended inside `with self._cond`
+        t1 = _published(eng, "lock_wait.handle.n", t0)
+    finally:
+        eng.shutdown()
+    assert _delta(t0, t1, "lock_wait.handle.n") >= 1
+    assert _delta(t0, t1, "lock_wait.handle.ms_total") >= 40.0
 
 
 def test_continuous_batching_tp_sharded():
