@@ -33,12 +33,96 @@ import jax
 import jax.numpy as jnp
 
 EXPERT_LEAVES = ("w_gate", "w_up", "w_down")
-# The kernel's tiles: rows of one tile belong to at most a few experts, and
-# the weight tile is what a group with rows costs to read. Of (128, 1024,
-# 1024), (128, 2048, 512), (128, 512, 1024) and (128, 1024, 512) the first
-# was fastest at 128 and at 512 rows (chip, PR 27).
+# The kernel's tiles: `(tm, tk, tn)` over an `[m, k] x [G, k, n]` product.
+# Rows of one tile belong to at most a few experts, and the weight tile
+# `(tk, tn)` is what a group with rows costs to read. What a step moves
+# besides it is the rows' tile `(tm, tk)`, fetched again at every step
+# unless one tile spans `k` (the block's index then stays put from group to
+# group): `tm / tn` of the weights' bytes, an eighth at (128, 1024, 1024).
+# A width that no tile divides costs a step that is mostly past the edge
+# in `n`, and in `k` a mask over both operands on the last step. The kernel
+# alone on the chip (`tools/gmm_sweep.py`, PR 64; PERF.md section 6), share
+# of 819 GB/s a decode step's product reads its weights at: `k` whole
+# 88-92% at every width of the four serving cells (OLMoE 2048 x 1024 and
+# back, LFM2 2048 x 1536, Solar 4096 x 1280, dots' 2048 x 7168), `k` split
+# 81-82% at `tn` 1024, 83-84% at 1280, 86-87% at 2048, 74% at 512,
+# whatever `tk`; (128, 1024, 1024) over 1280 and 1536 59-81%; a row tile
+# under 128 loses at 5-6 rows a group (a group astride two row tiles is
+# read twice). The scoped VMEM a kernel compiles under is 16 MiB.
 _ROW_TILE = 128
-_WEIGHT_TILE = 1024
+_WEIGHT_TILE = 1024       # the plain tile's side: what no divisor falls back to
+_STEP_BYTES = 13 << 20    # a step's buffers; the rest is the compiler's
+
+
+def _row_tile(m: int) -> int:
+    return min(_ROW_TILE, -(-m // 8) * 8)
+
+
+def _sides(x: int):
+    """The tile sides that leave a dimension of `x` no remainder: `x` whole
+    up to the plain tile, beyond it the divisors that are multiples of 128
+    and at least half the plain tile."""
+    if x <= _WEIGHT_TILE:
+        return [x]
+    return [t for t in range(_WEIGHT_TILE // 2, x + 1, 128) if x % t == 0]
+
+
+def gmm_tiling(m: int, k: int, n: int, itemsize: int) -> Tuple[int, int, int]:
+    """The `(tm, tk, tn)` of an `[m, k] x [G, k, n]` grouped product whose
+    operands have `itemsize` bytes an element, from the shapes alone: of
+    the tiles that divide `k` and `n` and fit, the one that fetches the
+    rows' tile least often, then the largest."""
+    tm = _row_tile(m)
+
+    def fits(tk, tn):
+        # Two buffers of each operand and of the float32 result, and the
+        # float32 accumulator.
+        return (2 * (tk * tn + tm * tk) * itemsize + 3 * tm * tn * 4
+                <= _STEP_BYTES)
+
+    pairs = [(tk, tn) for tk in _sides(k) for tn in _sides(n) if fits(tk, tn)]
+    if not pairs:  # nothing divides and fits: the kernel's masks take the rest
+        return tm, min(k, _WEIGHT_TILE), min(n, _WEIGHT_TILE)
+    tk, tn = max(pairs, key=lambda t: (
+        0 if t[0] == k else -tm / t[1], t[0] * t[1]))
+    return tm, tk, tn
+
+
+def _interpret() -> bool:
+    return jax.default_backend() != "tpu"
+
+
+@jax.custom_vjp
+def _gmm(x: jax.Array, w: jax.Array, group_sizes: jax.Array) -> jax.Array:
+    """The kernel on `x` of whole row tiles. The two products of the
+    backward pass keep the plain tile: their shapes are other shapes, and
+    `tgmm` holds a float32 `(tk, tn)` where `gmm` holds `(tm, tn)`."""
+    return _gmm_fwd(x, w, group_sizes)[0]
+
+
+def _gmm_fwd(x, w, group_sizes):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm
+
+    (m, k), n = x.shape, w.shape[-1]
+    out = gmm(x, w, group_sizes, jnp.float32,
+              gmm_tiling(m, k, n, w.dtype.itemsize), interpret=_interpret())
+    return out, (x, w, group_sizes)
+
+
+def _gmm_bwd(residual, grad):
+    from jax.experimental.pallas.ops.tpu.megablox.gmm import gmm, tgmm
+
+    x, w, group_sizes = residual
+    (m, k), n = x.shape, w.shape[-1]
+    plain = (_row_tile(m), min(k, _WEIGHT_TILE), min(n, _WEIGHT_TILE))
+    grad_x = gmm(grad, w, group_sizes, x.dtype, plain, transpose_rhs=True,
+                 interpret=_interpret())
+    grad_w = tgmm(x.swapaxes(0, 1), grad, group_sizes, w.dtype, plain,
+                  num_actual_groups=w.shape[0], interpret=_interpret())
+    return grad_x, grad_w, None
+
+
+_gmm.defvjp(_gmm_fwd, _gmm_bwd)
 
 
 def grouped_matmul(x: jax.Array, w: jax.Array,
@@ -46,17 +130,10 @@ def grouped_matmul(x: jax.Array, w: jax.Array,
     """`x [m, k]` whose rows lie grouped, in the order of `w [G, k, n]`'s
     groups and `group_sizes [G]` long each, times each row's own group's
     matrix: `[m, n]` float32. Groups without rows are not read."""
-    from jax.experimental.pallas.ops.tpu.megablox import gmm
-
-    m, k = x.shape
-    tile_m = min(_ROW_TILE, -(-m // 8) * 8)
+    m = x.shape[0]
     # Rows past the groups' total belong to no group and come back zero.
-    x = jnp.pad(x, ((0, -m % tile_m), (0, 0)))
-    out = gmm(x, w, group_sizes, preferred_element_type=jnp.float32,
-              tiling=(tile_m, min(k, _WEIGHT_TILE),
-                      min(w.shape[-1], _WEIGHT_TILE)),
-              interpret=jax.default_backend() != "tpu")
-    return out[:m]
+    x = jnp.pad(x, ((0, -m % _row_tile(m)), (0, 0)))
+    return _gmm(x, w, group_sizes)[:m]
 
 
 def load_balancing_loss(prob_mean: jax.Array, counts: jax.Array,

@@ -590,6 +590,50 @@ def test_expert_model_step_reads_its_expert_stacks_in_place(
     assert compiled.memory_analysis().temp_size_in_bytes < one_matrix
 
 
+# The cells' grouped products: rows of a decode step and of the widest
+# prefill pass, `k`, `n`, groups in the stack the kernel is handed.
+GROUPED_PRODUCTS = {
+    "olmoe": ((128, 4096), 2048, 1024, 16 * 64),
+    "dots": ((512, 4096), 7168, 2048, 5 * 16),
+    "lfm2": ((384, 2048), 2048, 1536, 8 * 64),
+    "solar": ((1024, 4096), 4096, 1280, 4 * 40),
+}
+
+
+@pytest.mark.parametrize("backward", [False, True], ids=["fwd", "bwd"])
+@pytest.mark.parametrize("product", ["gate-up", "down"])
+@pytest.mark.parametrize("cell", GROUPED_PRODUCTS)
+def test_grouped_product_compiles_at_the_cells_shapes(
+        v5e, cell, product, backward, monkeypatch):
+    """The grouped matmul alone with the tiles `gmm_tiling` works out for
+    each serving cell's two products, inside the scoped VMEM a kernel
+    compiles under; and its gradient, whose two products keep the plain
+    tile (`tgmm` holds a float32 weight tile: with the forward product's
+    tiles an expert model's train step would not compile)."""
+    from ray_tpu.parallel.moe import gmm_tiling, grouped_matmul
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    one = SingleDeviceSharding(v5e[0])
+    rows, k, n, groups = GROUPED_PRODUCTS[cell]
+    if product == "down":
+        k, n = n, k
+    sizes = jax.ShapeDtypeStruct((groups,), jnp.int32, sharding=one)
+    w = jax.ShapeDtypeStruct((groups, k, n), jnp.bfloat16, sharding=one)
+    for m in rows[:1] if backward else rows:
+        x = jax.ShapeDtypeStruct((m, k), jnp.bfloat16, sharding=one)
+        _, tk, tn = gmm_tiling(m, k, n, 2)
+        assert (k % tk, n % tn) == (0, 0)
+        fn = grouped_matmul
+        if backward:
+            fn = jax.grad(lambda x, w, sizes: grouped_matmul(
+                x, w, sizes).sum(), argnums=(0, 1))
+        text = _compile(fn, x, w, sizes).as_text()
+        # The forward product under the name the cells' metrics match; of a
+        # sum's gradient the two cotangents' products alone are left.
+        assert text.count("tpu_custom_call") == (2 if backward else 1)
+        assert len(re.findall(r"%gmm[.\d]* = f32\[", text)) == (not backward)
+
+
 def _hybrid_program(program, one, rows=1):
     """`_engine_program` for granite-4.0-h-micro at its published sizes,
     all 40 layers, at the serving cell's 48 slots x 1024 (`rows` chunks of

@@ -423,3 +423,141 @@ def test_the_softmax_block_of_a_whole_model_is_unchanged_bit_for_bit(
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
     np.testing.assert_array_equal(np.asarray(stats["counts"]),
                                   np.asarray(want_counts))
+
+
+# The grouped products the four sparse serving cells launch, `(m, k, n)` of
+# the gate and up product and of the down product at a decode step's rows
+# (`bench/configs/`: OLMoE, dots.vlm1's share, LFM2-24B-A2B's stage,
+# Solar-Open2's share), and the weight tile each was fastest or within a
+# point of fastest with on the chip (`tools/gmm_sweep.py`, PR 64).
+CELL_PRODUCTS = {
+    "olmoe gate-up": ((128, 2048, 1024), (2048, 1024)),
+    "olmoe down": ((128, 1024, 2048), (1024, 2048)),
+    "dots gate-up": ((512, 7168, 2048), (1024, 2048)),
+    "dots down": ((512, 2048, 7168), (2048, 1024)),
+    "lfm2 gate-up": ((384, 2048, 1536), (2048, 768)),
+    "lfm2 down": ((384, 1536, 2048), (1536, 1024)),
+    "solar gate-up": ((1024, 4096, 1280), (4096, 640)),
+    "solar down": ((1024, 1280, 4096), (1280, 1024)),
+}
+OTHER_PRODUCTS = {
+    "a pass's rows": ((4096, 4096, 1280), (4096, 640)),
+    "float32 operands": ((128, 2048, 1536), (2048, 512)),
+    # 1,408 is 11 x 128: whole or not at all, and `k` whole does not fit it.
+    "a width only itself divides": ((128, 4096, 1408), (1024, 1408)),
+    # 4,736 is 37 x 128 and does not fit whole: the plain tile, remainders
+    # and all, as every width had before the rule.
+    "no divisor fits": ((128, 4096, 4736), (1024, 1024)),
+    "no multiple of 128 divides": ((128, 5000, 2048), (1024, 1024)),
+    "toy widths": ((24, 64, 96), (64, 96)),
+}
+
+
+def step_bytes(tiling, itemsize):
+    """What one grid step of the kernel holds in VMEM: two buffers of the
+    weight tile, of the rows and of the float32 result, and the float32
+    accumulator."""
+    tm, tk, tn = tiling
+    return 2 * (tk * tn + tm * tk) * itemsize + 3 * tm * tn * 4
+
+
+@pytest.mark.parametrize("product", [*CELL_PRODUCTS, *OTHER_PRODUCTS])
+def test_the_tiles_of_a_grouped_product_divide_its_shape(product):
+    """`gmm_tiling` from `(m, k, n)` and the item size alone: no remainder
+    in `k` (the kernel would mask both operands on the last step) or in `n`
+    (a step mostly past the edge) wherever a multiple of 128 of at least
+    half the plain tile divides and fits the bound on a step's bytes, `k`
+    in one tile where that fits (the rows' tile is then fetched once a row
+    tile and not once a group), the plain tile of 1,024 where nothing
+    divides."""
+    from ray_tpu.parallel import moe
+
+    itemsize = 4 if product == "float32 operands" else 2
+    (m, k, n), tile = {**CELL_PRODUCTS, **OTHER_PRODUCTS}[product]
+    tm, tk, tn = moe.gmm_tiling(m, k, n, itemsize)
+    assert tm == min(128, -(-m // 8) * 8)
+    assert (tk, tn) == tile
+    assert step_bytes((tm, tk, tn), itemsize) <= moe._STEP_BYTES < 16 << 20
+    if product.startswith("no "):
+        assert (k % tk, n % tn) != (0, 0)
+    else:
+        assert (k % tk, n % tn) == (0, 0)
+    if product in CELL_PRODUCTS:
+        assert tk % 128 == 0 and tn % 128 == 0 and min(tk, tn) >= 512
+        # `k` whole wherever it fits beside half a plain tile of `n`.
+        assert (tk == k) == (step_bytes((tm, k, 512), 2) <= moe._STEP_BYTES)
+
+
+def per_group_products(x, w, sizes):
+    """Each group's rows times its own matrix, a `jnp.dot` a group in
+    float32; rows behind the last group stay zero."""
+    out = np.zeros((x.shape[0], w.shape[-1]), np.float32)
+    start = 0
+    for g, size in enumerate(np.asarray(sizes)):
+        if size:
+            out[start:start + size] = jnp.dot(
+                x[start:start + size].astype(jnp.float32),
+                w[g].astype(jnp.float32),
+                precision=jax.lax.Precision.HIGHEST)
+        start += size
+    return out
+
+
+@pytest.mark.parametrize("k,n", [(512, 1280), (1280, 512), (2560, 1280),
+                                 (512, 1536), (1536, 512)])
+@pytest.mark.parametrize("case", ["ragged", "empty groups", "rows behind",
+                                  "one layer of a stack"])
+def test_grouped_matmul_at_widths_of_1280_and_1536(k, n, case):
+    """The kernel in the interpreter with the rule's tiles at the widths
+    that are no whole tile of 1,024, against a plain product a group: the
+    order of the float32 sums over `k` is all that may differ."""
+    from ray_tpu.parallel.moe import gmm_tiling, grouped_matmul
+
+    sizes = {"ragged": [5, 1, 130, 3, 17, 40],
+             "empty groups": [0, 9, 0, 0, 131, 0],
+             # A held share: 37 of the 200 rows belong to groups held here.
+             "rows behind": [4, 0, 6, 20, 0, 7],
+             # Layer 1 of 3 in a stack of 3 x 2 groups.
+             "one layer of a stack": [0, 0, 11, 150, 0, 0]}[case]
+    m = 200 if case == "rows behind" else sum(sizes)
+    dtype = jnp.bfloat16 if (k, n) == (512, 1280) else jnp.float32
+    x = jax.random.normal(jax.random.PRNGKey(k), (m, k), dtype)
+    w = jax.random.normal(jax.random.PRNGKey(n), (len(sizes), k, n), dtype)
+    sizes = jnp.asarray(sizes, jnp.int32)
+    tm, tk, tn = gmm_tiling(m, k, n, x.dtype.itemsize)
+    assert k % tk == 0 and n % tn == 0 and 1024 < max(tk, tn) <= 2560
+    live = int(sizes.sum())
+    got = np.asarray(jax.jit(grouped_matmul)(x, w, sizes))[:live]
+    want = per_group_products(x, w, sizes)[:live]
+    assert got.shape == want.shape
+    assert np.abs(got - want).max() <= 1e-5 * np.abs(want).max()
+
+
+def test_grouped_matmul_gradients_against_a_plain_product_a_group():
+    """The cotangents of the rows and of the weights through the kernel's
+    own backward products (which keep the plain tile whatever tile the
+    forward product took), against `jax.grad` of a product a group."""
+    from ray_tpu.parallel.moe import gmm_tiling, grouped_matmul
+
+    m, k, n, sizes = 50, 2048, 1280, [7, 0, 30, 13]
+    assert gmm_tiling(m, k, n, 4)[1:] not in ((k, n), (1024, 1024))
+    x = jax.random.normal(jax.random.PRNGKey(0), (m, k), jnp.float32)
+    w = jax.random.normal(jax.random.PRNGKey(1), (len(sizes), k, n))
+    weigh = jax.random.normal(jax.random.PRNGKey(2), (m, n))
+    starts = np.concatenate([[0], np.cumsum(sizes)])
+
+    def plain(x, w):
+        rows = [jnp.dot(x[a:b], w[g], precision=jax.lax.Precision.HIGHEST)
+                for g, (a, b) in enumerate(zip(starts, starts[1:]))]
+        return (jnp.concatenate(rows) * weigh).sum()
+
+    def kernel(x, w):
+        return (grouped_matmul(x, w, jnp.asarray(sizes, jnp.int32))
+                * weigh).sum()
+
+    want = jax.grad(plain, argnums=(0, 1))(x, w)
+    got = jax.jit(jax.grad(kernel, argnums=(0, 1)))(x, w)
+    for g, wnt in zip(got, want):
+        assert g.shape == wnt.shape
+        assert np.abs(np.asarray(g - wnt)).max() <= 1e-5 * np.abs(wnt).max()
+    assert not np.asarray(got[1][1]).any()   # a group without rows
