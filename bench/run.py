@@ -252,7 +252,30 @@ def main(argv=None) -> int:
         say(f"no result: the workers ran on {device}, the cell needs "
             f"{cell['chips']} {args.platform} device(s)")
         return 1
-    setup_s = setup_seconds(window["t"], T_START, waited)
+    return finish(args, cell, result, run_dir,
+                  setup_seconds(window["t"], T_START, waited))
+
+
+def trace_fault(trace, platform: str):
+    """Why a traced run has no result, or None: `--trace 1` was asked and
+    there is no reduced trace to print `busy_s` and `window_s` from."""
+    if not trace:
+        return "the run gathered no trace"
+    if trace.get("error"):
+        return str(trace["error"])
+    if "busy_s" not in trace or "window_s" not in trace:
+        return "the reduced trace has no busy_s or no window_s"
+    if platform == "tpu" and not (0.0 < trace["busy_s"] <= trace["window_s"]):
+        return (f"the reduced trace reads busy_s {trace['busy_s']!r} over "
+                f"window_s {trace['window_s']!r} on {trace.get('n_devices')} "
+                "device plane(s): no operation ran on a device in the window")
+    return None
+
+
+def finish(args, cell, result, run_dir: str, setup_s: float) -> int:
+    """From a cell's result to the line, printed last; a traced run with
+    no reduced trace says why and prints none."""
+    device = dict(result["device"])
     line = {
         "correct": result["correct"], "attempted": result["attempted"],
         "failed": result["failed"],
@@ -260,11 +283,28 @@ def main(argv=None) -> int:
         "device": device,
     }
     if args.trace:
-        trace = result["sources"].get("trace") or {}
-        device["busy_s"] = trace.get("busy_s", 0.0)
-        device["window_s"] = trace.get("window_s", 0.0)
+        trace = result["sources"].get("trace")
+        fault = trace_fault(trace, args.platform)
+        if fault is not None:
+            say(f"no result: --trace 1 and no reduced trace: {fault}")
+            return 1
+        device["busy_s"] = trace["busy_s"]
+        device["window_s"] = trace["window_s"]
         line["breakdown"] = {"device_ops": trace.get("device_ops", []),
                              "idle_gaps": trace.get("idle_gaps", [])}
+        say(f"trace: window {trace['window_s']:.6f}s "
+            + ("marked in the trace" if trace.get("window_marked")
+               else "by the host's clock (no span in the trace)")
+            + f" (the host's clock between its ends "
+            f"{trace.get('clocked_window_s')}), busy {trace['busy_s']:.6f}s "
+            f"inside it of {trace.get('busy_unclipped_s')} recorded, "
+            f"outside_s {trace.get('outside_s')} before and after; by device "
+            + str({n: {k: d.get(k) for k in (
+                "busy_s", "outside_s", "busy_unclipped_s", "edge_idle_s")}
+                for n, d in (trace.get("devices") or {}).items()})
+            + "; the profiler's calls took "
+            + str({k: trace.get(k) for k in (
+                "start_trace_s", "stop_trace_s", "reduce_s")}))
         say(f"trace: device time by opcode {trace.get('by_opcode')}; programs "
             + str({m["name"]: (m["launches"], round(m["total_s"], 4))
                    for m in (trace.get("modules") or {}).values()}))

@@ -31,6 +31,9 @@ LOGITS_TOLERANCE = 0.08
 # cache row is a random one, some 4 rms under the top of 151,936 logits.
 # Measured on the chip: 0.030-0.032 for the logits, margins up to 0.023.
 MARGIN_TOLERANCE = 0.25
+# How long `offer` waits, after the callers' end, for the thread that
+# started and stopped the profiler in the middle of the window.
+TRACER_JOIN_S = 120.0
 
 
 class BenchReplica(LLMReplica):
@@ -89,6 +92,8 @@ class BenchReplica(LLMReplica):
     def trace_start(self, trace_dir: str) -> bool:
         import jax
 
+        from xplane import reduce as xr
+
         # Host spans around the engine loop's own calls, so that an idle
         # gap on the device can be named by what the host was doing. The
         # loop looks these methods up on the instance at every call.
@@ -107,20 +112,49 @@ class BenchReplica(LLMReplica):
             setattr(eng, name, spanned)
         opts = jax.profiler.ProfileOptions()
         opts.python_tracer_level = 0
+        t_in = time.perf_counter()
         jax.profiler.start_trace(trace_dir, profiler_options=opts)
-        self._trace = (trace_dir, time.perf_counter())
+        # The window's two ends are events in the trace itself, made after
+        # the profiler has started and before it is stopped: the engine's
+        # thread dispatches through both calls, and the reduction clips
+        # what it records to the span between the marks. Two short marks,
+        # since this call and `trace_stop` may run on different threads
+        # and an annotation is its thread's.
+        with jax.profiler.TraceAnnotation(xr.WINDOW_START):
+            t0 = time.perf_counter()
+        self._trace = {"dir": trace_dir, "t0": t0, "start_trace_s": t0 - t_in}
         return True
 
-    def trace_stop(self) -> Dict:
+    def trace_stop(self) -> bool:
+        """Mark the window's end and stop the profiler. The trace is read
+        and reduced by `trace_reduce`, once the `stats` window has closed:
+        seconds of Python that would hold the interpreter against the
+        engine's thread inside the window."""
         import jax
 
         from xplane import reduce as xr
 
-        trace_dir, t0 = self._trace
-        window_s = time.perf_counter() - t0
+        with jax.profiler.TraceAnnotation(xr.WINDOW_END):
+            t1 = time.perf_counter()
         jax.profiler.stop_trace()
-        self._trace = None
-        return xr.reduce_dir(trace_dir, window_s)
+        self._trace.update(clocked_window_s=t1 - self._trace.pop("t0"),
+                           stop_trace_s=time.perf_counter() - t1)
+        return True
+
+    def trace_reduce(self) -> Dict:
+        from xplane import reduce as xr
+
+        took, self._trace = self._trace, None
+        t_in = time.perf_counter()
+        # BENCH_KEEP_TRACE=1 leaves the .xplane.pb in the run's directory,
+        # for a look by hand; the line is the same.
+        out = xr.reduce_dir(took.pop("dir"),
+                            keep=bool(os.environ.get("BENCH_KEEP_TRACE")))
+        # For the log alone: the host's clock between the two marks, which
+        # was the window until PR 66, and what the profiler's two calls
+        # (inside the `stats` window) and this one (outside it) took.
+        out.update(took, reduce_s=time.perf_counter() - t_in)
+        return out
 
     def prefill_logits(self, prompt: List[int]):
         """Next-token logits, float32 [vocab], of `prompt` from the
@@ -162,6 +196,28 @@ class BenchReplica(LLMReplica):
                                  for s in out),
                 "margin_ok": all(s["max_margin_rms"] <= MARGIN_TOLERANCE
                                  for s in out)}
+
+
+def reduced_trace(handle, traced: Dict, tracer: threading.Thread) -> Dict:
+    """The traced stretch reduced inside the replica, after the `stats`
+    window has closed; `{"error": reason}` where there is no trace to
+    reduce or the reduction fails (`run.trace_fault` ends the run on it)."""
+    if tracer.is_alive():
+        return {"error": "the tracer thread had not returned "
+                         f"{TRACER_JOIN_S:g} s after the callers' end"}
+    if not traced.get("stopped"):
+        return {"error": traced.get("error", "the profiler was not stopped")}
+    try:
+        return _call(handle, "trace_reduce", timeout=300.0)
+    except Exception as e:  # noqa: BLE001
+        return {"error": f"the trace could not be reduced: {_brief(e)}"}
+
+
+def _brief(e: BaseException) -> str:
+    """A remote failure carries the worker's whole traceback; its last
+    line says what failed."""
+    rows = [row for row in str(e).splitlines() if row.strip()]
+    return f"{type(e).__name__}: {rows[-1].strip() if rows else ''}"
 
 
 def _call(handle, method: str, *args, timeout: float = 300.0):
@@ -241,9 +297,12 @@ def offer(ctx: Dict, handle, mix: Dict, seconds: float, trace: bool) -> Dict:
         """One traced stretch in the middle of the window."""
         trace_dir = os.path.join(ctx["run_dir"], "trace")
         time.sleep(max(0.0, t_window + 0.4 * seconds - time.perf_counter()))
-        _call(handle, "trace_start", trace_dir)
-        time.sleep(float(mix.get("trace_s", 3.0)))
-        traced.update(_call(handle, "trace_stop"))
+        try:
+            _call(handle, "trace_start", trace_dir)
+            time.sleep(float(mix.get("trace_s", 3.0)))
+            traced["stopped"] = _call(handle, "trace_stop")
+        except Exception as e:  # noqa: BLE001 — the run says why it has no trace
+            traced["error"] = f"the profiler could not be run: {_brief(e)}"
 
     def open_window():
         time.sleep(max(0.0, t_window - time.perf_counter()))
@@ -265,8 +324,10 @@ def offer(ctx: Dict, handle, mix: Dict, seconds: float, trace: bool) -> Dict:
                                      vocab, drain_s,
                                      float(mix.get("stagger_s", 0.0)))
     for th in side:
-        th.join(timeout=120.0)
+        th.join(timeout=TRACER_JOIN_S)
     closed = _call(handle, "window", False)
+    if trace:
+        traced = reduced_trace(handle, traced, side[-1])
     summary = client.summarize(stamps, t_window, seconds, mix.get("limits"))
     end = t_window + seconds
     summary["decode_tokens_in_window"] = sum(

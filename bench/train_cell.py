@@ -94,6 +94,7 @@ def train_loop(config: Dict):
 
     import spec
     import weights
+    from xplane import reduce as xr
     from ray_tpu import train
     from ray_tpu.models import loss_fn, param_logical_axes
     from ray_tpu.parallel import MeshConfig, build_mesh, logical_shardings
@@ -190,7 +191,10 @@ def train_loop(config: Dict):
             jax.profiler.start_trace(trace_dir, profiler_options=opts)
             t_tr = time.perf_counter()
             n_tr = int(mix.get("trace_steps", 3))
-            losses.append(run_steps(n_tr))
+            # The window is this span in the trace itself, inside the
+            # profiler's two calls; the reduction clips to it.
+            with jax.profiler.TraceAnnotation(xr.WINDOW):
+                losses.append(run_steps(n_tr))
             tr_s = time.perf_counter() - t_tr
             jax.profiler.stop_trace()
             traced_span = (time.perf_counter() - t_in, tr_s, n_tr)
@@ -204,10 +208,11 @@ def train_loop(config: Dict):
     counts = listener.close_window()
     steps = n_step - steps0 - traced_span[2]
     if traced_span[2]:
-        from xplane import reduce as xr
-
-        traced = xr.reduce_dir(trace_dir, traced_span[1])
+        traced = xr.reduce_dir(trace_dir)
         traced["steps"] = traced_span[2]
+        # For the log alone: the host's clock around the traced steps,
+        # which was the window until PR 66.
+        traced["clocked_window_s"] = traced_span[1]
     records = [dict(r, steps=every) for r in prof.records()]
     peaks = [(d.memory_stats() or {}).get("peak_bytes_in_use", 0)
              for d in jax.local_devices()]
