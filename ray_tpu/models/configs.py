@@ -6,7 +6,9 @@ scale for CPU tests. The hybrids: `granite-4.0-h-micro` (Mamba-2 among
 attention layers, dense MLPs; `tiny_granite_h`) and `lfm2-24b-a2b` (gated
 short convolutions among attention layers, routed experts after two dense
 layers; `lfm2-24b-a2b-l10`, its first ten layers, is what one chip serves;
-`tiny_lfm2_moe`).
+`tiny_lfm2_moe`). `sdar-30b-a3b` generates by diffusion over blocks of 4
+positions (`sdar-30b-a3b-l6`, its first six layers, is what one chip
+serves; `tiny_sdar_moe`).
 """
 
 from __future__ import annotations
@@ -537,6 +539,58 @@ solar_open2_250b_ep8_l4 = replace(
     layer_pattern=solar_open2_250b.layer_pattern[:4], vocab_size=24576,
     experts_held=40, expert_share=0)
 
+# SDAR-30B-A3B-Chat (arXiv:2510.06303; the model's public config.json,
+# `model_type` sdar_moe, derived from Qwen3-MoE's modelling code): 48 layers
+# of grouped-query attention (32 query and 4 key-value heads of 128, a
+# QK-norm a head) and 128 experts of width 768, 8 a token, softmax scores
+# renormalised over the chosen 8, none shared; `intermediate_size` 6144 is
+# published and no layer uses it. Untied 151,936 rows. Generation by
+# diffusion over blocks: the block length (4, the -Chat models' released
+# default), the mask token (the tokenizer's `<|MASK|>`) and the passes a
+# block (2) are the released generation settings, not in config.json.
+sdar_30b_a3b = TransformerConfig(
+    vocab_size=151936,
+    d_model=2048,
+    n_layers=48,
+    n_heads=32,
+    n_kv_heads=4,
+    d_ff=6144,
+    max_seq=4096,
+    rope_theta=1000000.0,
+    norm_eps=1e-6,
+    num_experts=128,
+    experts_per_token=8,
+    moe_intermediate_size=768,
+    norm_topk_prob=True,
+    qk_norm=True,
+    custom_head_dim=128,
+    block_length=4,
+    mask_token_id=151669,
+    denoise_steps=2,
+)
+sdar_30b_a3b_l6 = replace(sdar_30b_a3b, n_layers=6)
+
+tiny_sdar_moe = TransformerConfig(
+    vocab_size=256,
+    d_model=64,
+    n_layers=2,
+    n_heads=4,
+    n_kv_heads=2,
+    d_ff=128,
+    max_seq=128,
+    dtype=jnp.float32,
+    remat=False,
+    num_experts=8,
+    experts_per_token=2,
+    moe_intermediate_size=32,
+    norm_topk_prob=True,
+    qk_norm=True,
+    custom_head_dim=16,
+    block_length=4,
+    mask_token_id=255,
+    denoise_steps=2,
+)
+
 NAMED_CONFIGS = {
     "tiny": tiny,
     "tiny_gqa": tiny_gqa,
@@ -564,6 +618,9 @@ NAMED_CONFIGS = {
     "tiny_solar_open2": tiny_solar_open2,
     "solar-open2-250b": solar_open2_250b,
     "solar-open2-250b-ep8-l4": solar_open2_250b_ep8_l4,
+    "tiny_sdar_moe": tiny_sdar_moe,
+    "sdar-30b-a3b": sdar_30b_a3b,
+    "sdar-30b-a3b-l6": sdar_30b_a3b_l6,
 }
 
 

@@ -226,6 +226,11 @@ def generate(
     cached per (config, sampling signature): repeat calls at the same
     shapes pay a single dispatch, no per-step host traffic.
     """
+    if cfg.block_length:
+        raise NotImplementedError(
+            "a block-diffusion model fills in a block of positions at once: "
+            "the serving engine generates with it (serve.llm), this "
+            "one-token-a-step loop does not")
     b, _ = prompt.shape
     if max_new_tokens <= 0:
         return jnp.zeros((b, 0), dtype=jnp.int32)

@@ -30,6 +30,7 @@ from jax.sharding import PartitionSpec as P
 
 from ray_tpu.ops import apply_rope, flash_attention, rmsnorm, rope_frequencies, softmax_cross_entropy
 from ray_tpu.ops.cross_entropy import chunked_lm_head_ce
+from ray_tpu.ops.paged_attention import grouped_attention
 from ray_tpu.ops.rope import yarn_inv_freq, yarn_mscale
 from ray_tpu.models import kda, mamba2, shortconv
 from ray_tpu.parallel.mesh import DEFAULT_RULES, with_sharding_constraint
@@ -189,6 +190,17 @@ class TransformerConfig:
     rope_beta_slow: float = 1.0
     rope_mscale: float = 1.0
     rope_mscale_all_dim: float = 0.0
+    # Generation by diffusion over blocks (SDAR, arXiv:2510.06303):
+    # `block_length` positions are filled in at once (0: a next-token
+    # model). Position p lies in block p // block_length and attends to
+    # every earlier block and to its own block whole, later positions of it
+    # too; the logits at p are for the token AT p. A position not yet
+    # filled is fed `mask_token_id`; a block is filled in `denoise_steps`
+    # passes of block_length / denoise_steps positions each and then
+    # committed (serve/paged_kv.block_pass_paged).
+    block_length: int = 0
+    mask_token_id: int = 0
+    denoise_steps: int = 1
 
     @property
     def head_dim(self) -> int:
@@ -592,14 +604,14 @@ def init_params(key: jax.Array, cfg: TransformerConfig) -> Dict:
             }
         )
     else:
-        E = cfg.num_experts
+        E, eff = cfg.num_experts, cfg.expert_ff
         sub = jax.random.split(keys[4], 3)
         layer.update(
             {
                 "router": stack(keys[7], (d, E), scale),
-                "w_gate": stack(sub[0], (E, d, ff), scale),
-                "w_up": stack(sub[1], (E, d, ff), scale),
-                "w_down": stack(sub[2], (E, ff, d), scale * (2 * L) ** -0.5),
+                "w_gate": stack(sub[0], (E, d, eff), scale),
+                "w_up": stack(sub[1], (E, d, eff), scale),
+                "w_down": stack(sub[2], (E, eff, d), scale * (2 * L) ** -0.5),
             }
         )
     return _with_tables(layer, keys, cfg)
@@ -791,7 +803,22 @@ def gate_attention(attn, h, lp):
         return (attn.astype(jnp.float32) * gate).astype(attn.dtype)
 
 
+def block_causal(q_pos, k_pos, block_length: int):
+    """Which keys a query of a block-diffusion model sees, `[.., Lq, Lk]`
+    of positions `q_pos [.., Lq]` and `k_pos [.., Lk]`: every key whose
+    block is not after the query's."""
+    return (k_pos[..., None, :] // block_length
+            <= q_pos[..., :, None] // block_length)
+
+
 def _attention(cfg: TransformerConfig, q, k, v, mesh, positions):
+    if cfg.block_length:
+        # The plain form: the flash kernel's mask is causal and no other.
+        pos = (jnp.arange(q.shape[1])[None] if positions is None
+               else positions)
+        return grouped_attention(
+            q, k.astype(jnp.float32), v.astype(jnp.float32),
+            block_causal(pos, pos, cfg.block_length), cfg.attention_scale)
     if cfg.attn_impl == "ring" and mesh is not None and mesh.shape.get("sp", 1) > 1:
         from ray_tpu.parallel.ring_attention import ring_attention
 
@@ -1281,6 +1308,11 @@ def loss_fn(params, tokens, cfg: TransformerConfig, mesh=None,
     parameter lies, the partitioner gathers it inside every chunk's body,
     forward and backward, and reduce-scatters its gradient once a chunk.
     """
+    if cfg.block_length:
+        raise NotImplementedError(
+            "a block-diffusion model's loss is over noised blocks, and its "
+            "noise schedule is not in the published config: nothing trains "
+            "it here, and the next-token loss does not stand in")
     labels = tokens[:, 1:]
     if mesh is not None and mesh.shape.get("pp", 1) > 1:
         logits, aux = forward_pipelined(params, tokens[:, :-1], cfg, mesh)

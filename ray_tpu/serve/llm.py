@@ -23,6 +23,14 @@ scheduling, built TPU-first:
     (a prompt cannot resume below a shared prefix without the state at
     that boundary, and no snapshots are kept) and without tensor
     parallelism (the mixer is not partitioned).
+  * A block-diffusion model (`cfg.block_length`: SDAR's generation by
+    diffusion over blocks) fills in a block of B positions a slot at once:
+    a step of the loop is then a PASS of every slot's block
+    (`paged_kv.block_pass_paged`), which hands a slot nothing (a denoising
+    pass filled some of its block's masked positions) or B tokens (the
+    block's commit pass). A slot's block lives on the device and its phase
+    is counted on the host; prefill covers a prompt's whole blocks and
+    emits no token. `stats()["diffusion"]` counts the passes.
   * Static shapes everywhere: the decode step is jitted ONCE for the
     slot count and prompts prefill in fixed-size CHUNKS, one PASS of
     them between decode steps: a pass is one program over one row of a
@@ -141,6 +149,12 @@ def _engine_metrics() -> Dict:
                     "serve_llm_batch_occupancy",
                     "Decoding slots in use / total slots, sampled every "
                     "engine step (how full the continuous batch runs)",
+                ),
+                "tokens_per_pass": Gauge(
+                    "serve_llm_tokens_per_pass",
+                    "Generated tokens a block-diffusion engine handed out "
+                    "over the live slot-passes it ran, cumulative (a block "
+                    "of B tokens costs denoise_steps + 1 passes)",
                 ),
                 "waiting": Gauge(
                     "serve_llm_waiting_requests",
@@ -730,6 +744,20 @@ class ContinuousBatchingEngine:
         self.page_size = max(
             1, min(int(page_size or rcfg.serve_kv_page_size), max_len)
         )
+        # A block-diffusion model: a pass yields 0 or B tokens a slot.
+        self._block = cfg.block_length
+        if self._block:
+            if (self._block % cfg.denoise_steps
+                    or self.prefill_chunk % self._block
+                    or self.page_size % self._block):
+                raise ValueError(
+                    f"a block of {self._block} positions must be whole "
+                    f"passes (denoise_steps {cfg.denoise_steps}), and "
+                    f"divide the prefill chunk ({self.prefill_chunk}) and "
+                    f"the page ({self.page_size})")
+            if cfg.layer_pattern or cfg.kv_lora_rank:
+                raise ValueError("block diffusion serves over pages of "
+                                 "keys and values a head alone")
         self._pages_per_slot = -(-max_len // self.page_size)
         self.kv_pages = int(kv_pages or rcfg.serve_kv_pages or 0)
         if self.kv_pages <= 0:
@@ -805,6 +833,42 @@ class ContinuousBatchingEngine:
             ),
             donate_argnums=(5, 6), donate_argnames=("rec",),
         )
+        if self._block:
+            # A slot's block on the device (`paged_kv.init_block_state`),
+            # what a pass takes and returns in the token buffer's place,
+            # and the host's mirrors of it: how many of a decoding slot's
+            # block are still masked (under the static strategies a count
+            # the host keeps: nothing is fetched to know a slot's phase)
+            # and how many of its first block are the prompt's remainder.
+            self._blk = jax.tree.map(
+                self._replicated, paged_kv.init_block_state(cfg, num_slots))
+            self._blk_masked = np.zeros(num_slots, dtype=np.int64)
+            self._blk_skip = np.zeros(num_slots, dtype=np.int64)
+            self._diffusion = dict.fromkeys((
+                "passes", "slot_passes_offered", "slot_passes",
+                "denoise_slot_passes", "commit_slot_passes",
+                "tokens_committed", "blocks_committed", "head_rows",
+                "head_rows_used"), 0)
+            self._block_sampled = jax.jit(
+                lambda p, st, k, v, ln, a, bt, tp, tk, tpp, key, **tail:
+                paged_kv.block_pass_paged(
+                    p, st, k, v, ln, a, bt, tp, tk, tpp, key, cfg, max_len,
+                    mesh, **tail),
+                donate_argnums=(2, 3),
+            )
+            self._block_greedy = jax.jit(
+                lambda p, st, k, v, ln, a, bt, **tail:
+                paged_kv.block_pass_paged(
+                    p, st, k, v, ln, a, bt, None, None, None, None, cfg,
+                    max_len, mesh, **tail),
+                donate_argnums=(2, 3),
+            )
+            self._start_blocks = jax.jit(paged_kv.start_blocks)
+            self._block_logits = jax.jit(
+                lambda p, st, k, v, ln, a, bt: paged_kv.block_logits(
+                    p, st, k, v, ln, a, bt, cfg, max_len, mesh),
+                donate_argnums=(2, 3),
+            )
         self._cow = jax.jit(paged_kv.cow_copy_page, donate_argnums=(0, 1))
         self._pick_first = jax.jit(_pick_first_tokens)
         self._lock = threading.Lock()
@@ -914,33 +978,44 @@ class ContinuousBatchingEngine:
         # The loop's own two-way split (and the unpacking's unstack).
         self._rng, k1 = jax.random.split(self._rng)
         zero = np.int32(0)
-        (_, self._k, self._v, self._lengths,
-         *tail) = self._decode_greedy(
-            self.params, self._tokens_dev, self._k, self._v,
-            self._lengths, self._active_dev, self._bt_dev, **self._tail,
-        )
-        self._tail = dict(zip(self._tail, tail))
-        (_, self._k, self._v, self._lengths,
-         *tail) = self._decode_sampled(
-            self.params, self._tokens_dev, self._k, self._v,
-            self._lengths, self._active_dev, self._bt_dev,
-            self._temps_dev, self._top_ks_dev, self._top_ps_dev, k1,
-            **self._tail,
-        )
-        self._tail = dict(zip(self._tail, tail))
-        # Each pass with one real row (slot 0) and the rest inert, then
-        # the first-token pick over its logits (and the key's split), and
-        # the token buffer as it was.
+        if self._block:
+            # Both block programs over idle slots, and the start of a
+            # slot's first block with no slot starting.
+            self._begin_blocks({})
+            self._dispatch_block(None)
+            self._dispatch_block(k1)
+            self._begin_blocks({})
+        else:
+            (_, self._k, self._v, self._lengths,
+             *tail) = self._decode_greedy(
+                self.params, self._tokens_dev, self._k, self._v,
+                self._lengths, self._active_dev, self._bt_dev, **self._tail,
+            )
+            self._tail = dict(zip(self._tail, tail))
+            (_, self._k, self._v, self._lengths,
+             *tail) = self._decode_sampled(
+                self.params, self._tokens_dev, self._k, self._v,
+                self._lengths, self._active_dev, self._bt_dev,
+                self._temps_dev, self._top_ks_dev, self._top_ps_dev, k1,
+                **self._tail,
+            )
+            self._tail = dict(zip(self._tail, tail))
+        # Each pass with one real row (slot 0: a token, or a whole block)
+        # and the rest inert, then (a next-token model) the first-token
+        # pick over its logits (and the key's split), and the token buffer
+        # as it was.
         tokens = self._tokens_dev
         for rows in self._pass_rows:
             row = np.zeros(rows, dtype=np.int32)
-            n_valid = np.arange(rows, dtype=np.int32) == 0
+            n_valid = ((np.arange(rows, dtype=np.int32) == 0)
+                       * max(1, self._block))
             logits = self._dispatch_prefill(
                 np.zeros((rows, self.prefill_chunk), dtype=np.int32),
                 n_valid.astype(np.int32), row, row)
-            self._first_tokens(logits, row, np.full(rows, 0.5, np.float32),
-                               np.ones(rows, np.int32),
-                               np.ones(rows, np.float32))
+            if not self._block:
+                self._first_tokens(
+                    logits, row, np.full(rows, 0.5, np.float32),
+                    np.ones(rows, np.int32), np.ones(rows, np.float32))
         self._tokens_dev = tokens
         # Warm the copy-on-write page fork too (NULL page onto itself:
         # contents never observable).
@@ -988,6 +1063,49 @@ class ContinuousBatchingEngine:
         except Exception:  # rtlint: disable=RT007 — optional prefetch; sharded layouts fetch at the drain
             pass
         return toks_dev
+
+    # Single-writer: as _dispatch_prefill.
+    def _dispatch_block(self, key):  # rtlint: disable=RT006,RT010 — loop-thread-only (and warm-up, before the thread starts)
+        """One pass of every slot's block (`paged_kv.block_pass_paged`),
+        the greedy-only program where `key` is None. The slots' blocks,
+        the pools and the lengths advance on the device; returns the
+        blocks' tokens after the pass, on the device."""
+        args = (self.params, self._blk, self._k, self._v, self._lengths,
+                self._active_dev, self._bt_dev)
+        if key is None:
+            out = self._block_greedy(*args, **self._tail)
+        else:
+            out = self._block_sampled(
+                *args, self._temps_dev, self._top_ks_dev, self._top_ps_dev,
+                key, **self._tail)
+        block, self._blk, self._k, self._v, self._lengths, *tail = out
+        self._tail = dict(zip(self._tail, tail))
+        return block
+
+    def _begin_blocks(self, starts: Dict):  # rtlint: disable=RT006 — loop-thread-only (and warm-up, before the thread starts)
+        """The slots of `starts` (slot -> the request's prompt) begin their
+        first block, on the device: the prompt's remainder past its whole
+        blocks clean, the rest masked, behind the whole blocks' rows."""
+        self._blk, self._lengths = self._start_blocks(
+            self._blk, self._lengths,
+            *self._first_blocks(starts, self.num_slots))
+
+    def _first_blocks(self, starts: Dict, slots: int):
+        """`paged_kv.start_blocks`' host arguments for `starts` (slot ->
+        prompt) among `slots` slots: which slots start, their block's clean
+        tokens and masked bits, and the rows committed behind it."""
+        b = self._block
+        start = np.zeros(slots, dtype=bool)
+        tokens = np.zeros((slots, b), dtype=np.int32)
+        masked = np.ones((slots, b), dtype=bool)
+        committed = np.zeros(slots, dtype=np.int32)
+        for slot, prompt in starts.items():
+            rest = len(prompt) % b
+            start[slot] = True
+            committed[slot] = len(prompt) - rest
+            tokens[slot, :rest] = prompt[len(prompt) - rest:]
+            masked[slot, :rest] = False
+        return start, tokens, masked, committed
 
     # Single-writer: every *_dev array is owned by the engine thread
     # (this runs on it); submit() only flips _params_dirty under
@@ -1099,6 +1217,11 @@ class ContinuousBatchingEngine:
             raise ValueError("empty prompt")
         limit = self.max_len - 2
         detail = f"max_len - 2 = {self.max_len - 2} positions"
+        if self._block and self._last_block_end() - 1 < limit:
+            # Room for one generated token in the last whole block.
+            limit = self._last_block_end() - 1
+            detail = (f"the last whole block of {self._block} ends at "
+                      f"{limit + 1}")
         # The pool must hold the whole prompt plus one generated token
         # (+1 margin row for the pipelined in-flight step).
         pool_limit = self._pool.usable * self.page_size - 2
@@ -1176,6 +1299,11 @@ class ContinuousBatchingEngine:
         self._work.set()
         return h
 
+    def _last_block_end(self) -> int:
+        """The positions a block-diffusion slot may fill: whole blocks
+        within `max_len`."""
+        return self.max_len // self._block * self._block
+
     def set_tenant_weight(self, tenant: str, weight: float) -> None:
         """Give a tenant a WFQ share (> 1 admits proportionally more per
         rotation, < 1 less; default 1.0 — equal shares)."""
@@ -1211,7 +1339,9 @@ class ContinuousBatchingEngine:
         }
 
     def prefill_logits(self, prompt) -> np.ndarray:
-        """Next-token logits [vocab], float32, for `prompt`: the engine's
+        """Next-token logits [vocab], float32, for `prompt` (a block-
+        diffusion model's: the logits at the first masked position of the
+        block that follows the prompt's whole blocks): the engine's
         own prefill program run on a scratch cache of ONE slot, made of
         whatever the engine's cache is made of (a slot's pages and the
         NULL page; a model with recurrent layers' one row of state), i.e.
@@ -1233,8 +1363,9 @@ class ContinuousBatchingEngine:
                 for name, acc in self._tail.items()}
         table = self._replicated(np.arange(1, pages + 1, dtype=np.int32)[None])
         c = self.prefill_chunk
-        for off in range(0, len(prompt), c):
-            chunk = prompt[off:off + c]
+        end = len(prompt) - (len(prompt) % self._block if self._block else 0)
+        for off in range(0, end, c):
+            chunk = prompt[off:min(off + c, end)]
             padded = np.zeros((1, c), dtype=np.int32)
             padded[0, :len(chunk)] = chunk
             logits, k, v, lengths, *out = self._prefill(
@@ -1242,6 +1373,19 @@ class ContinuousBatchingEngine:
                 np.int32(0), np.int32(off), k, v, lengths, table, **tail,
             )
             tail = dict(zip(tail, out))
+        if self._block:
+            # A block-diffusion model: ONE pass of the block that follows
+            # the prompt's whole blocks (its remainder clean, the rest
+            # masked), and the logits at the block's first masked position:
+            # what the first denoising pass draws its first token from.
+            state, lengths = paged_kv.start_blocks(
+                paged_kv.init_block_state(self.cfg, 1), lengths,
+                *self._first_blocks({0: prompt}, 1))
+            logits, _, _ = self._block_logits(
+                self.params, jax.tree.map(self._replicated, state), k, v,
+                self._replicated(lengths),
+                self._replicated(np.ones(1, bool)), table)
+            return np.asarray(logits[0, len(prompt) - end], dtype=np.float32)
         return np.asarray(logits, dtype=np.float32)[0]
 
     def _moe_stats(self) -> Dict:
@@ -1280,6 +1424,18 @@ class ContinuousBatchingEngine:
         if "rec_count" in self._tail:
             tail["ssm"] = self._ssm_stats()
         with self._lock:
+            if self._block:
+                # A block-diffusion engine's passes, cumulative, from the
+                # host's own counts (`_next_block_pass_locked` at a dispatch,
+                # `_distribute_blocks` at a drain): passes dispatched;
+                # slots a pass offers and the live ones it ran, a denoising
+                # or a commit pass each; generated tokens and blocks handed
+                # to the handles (a prompt's remainder and what is cut off
+                # a last block are not among the tokens); rows the head and
+                # the sampler computed, and those whose draw was kept.
+                tail["diffusion"] = {
+                    **self._diffusion, "block_length": self._block,
+                    "denoise_steps": self.cfg.denoise_steps}
             return {
                 **tail,
                 # The device this engine's programs run on, as JAX
@@ -1421,7 +1577,13 @@ class ContinuousBatchingEngine:
             # tokens; submit() guarantees that is >= 1. Clamp to what
             # will actually be delivered.
             h.max_new_tokens = min(
-                h.max_new_tokens, self.max_len - 1 - len(h.prompt)
+                h.max_new_tokens,
+                # A block-diffusion slot fills whole blocks and is evicted
+                # before one would pass max_len; nothing is in flight past
+                # a request's last block that could write a page of its
+                # own (rows past a slot's pages park in the NULL page).
+                (self._last_block_end() if self._block
+                 else self.max_len - 1) - len(h.prompt)
             )
             # Reserve EVERY page the request can ever touch now: decode
             # then never allocates, so the block table (like the sampling
@@ -1477,6 +1639,8 @@ class ContinuousBatchingEngine:
         # Footprint: prompt + generated tokens + one margin row for the
         # pipelined in-flight step, capped by addressable positions.
         rows = min(p_len + h.max_new_tokens + 1, self.max_len)
+        if self._block:  # to the end of the block that holds the last token
+            rows = -(-(p_len + h.max_new_tokens) // self._block) * self._block
         need = -(-rows // ps) - len(shared)
         try:
             own = self._pool.alloc(need)
@@ -1499,6 +1663,11 @@ class ContinuousBatchingEngine:
         # seed the first generated token, and a partial tail page is
         # never cached anyway.
         skip = min(len(shared) * ps, p_len - 1)
+        if self._block:
+            # No token comes out of prefill, so nothing is recomputed: the
+            # shared pages are whole blocks, and the slot writes behind
+            # them.
+            skip = len(shared) * ps
         m = _engine_metrics()
         if hashes:
             if shared:
@@ -1548,7 +1717,10 @@ class ContinuousBatchingEngine:
         the engine's two widths (one row, or `PASS_ROWS` within
         `PASS_TOKENS`) that holds what is owed, the rest inert; what does
         not fit waits a turn. A request whose final chunk lands emits its
-        first token and joins the decode set.
+        first token and joins the decode set. (A block-diffusion model's:
+        its prompt's whole blocks are the chunks, no token comes out of
+        them and nothing is fetched; the request joins the decode set with
+        its first block started on the device, `_publish_blocks`.)
 
         First tokens stay ON DEVICE through admission: one pick over the
         pass's logits feeds _tokens_dev device-to-device, and ONE fetch
@@ -1594,8 +1766,12 @@ class ContinuousBatchingEngine:
                     self._free.append(slot)
                     self._pool.release(entry["pages"])
                 continue
+            # A block-diffusion model prefills a prompt's whole blocks; the
+            # remainder enters the first block clean (`_begin_blocks`).
+            entry["end"] = len(h.prompt) - (
+                len(h.prompt) % self._block if self._block else 0)
             owing.append((slot, entry, range(
-                entry["offset"], len(h.prompt), c)[:per_slot]))
+                entry["offset"], entry["end"], c)[:per_slot]))
         # A row for every slot first, so that a short prompt never waits
         # for a long one's chunks, then the rows left to the oldest.
         room = self._pass_rows[-1]
@@ -1608,7 +1784,14 @@ class ContinuousBatchingEngine:
         rows = [(slot, entry, off)
                 for (slot, entry, owed), n in zip(owing, take)
                 for off in owed[:n]]
+        # Prompts with nothing (left) to prefill: shorter than a block, or
+        # every whole block found in the prefix cache.
+        began = [(slot, entry["h"], entry) for slot, entry, _ in owing
+                 if self._block and entry["offset"] == entry["end"]]
         if not rows:
+            if began:
+                with self._phase("prefill_publish"):
+                    self._publish_blocks(began)
             return
         with self._phase("prefill_dispatch"):
             width = next(p for p in self._pass_rows if p >= len(rows))
@@ -1623,11 +1806,11 @@ class ContinuousBatchingEngine:
             finished = []  # (row, slot, handle, entry)
             for r, (slot, entry, off) in enumerate(rows):
                 h = entry["h"]
-                chunk = h.prompt[off:off + c]
+                chunk = h.prompt[off:min(off + c, entry["end"])]
                 tokens[r, :len(chunk)] = chunk
                 n_valid[r], slots[r], offsets[r] = len(chunk), slot, off
                 entry["offset"] = off + len(chunk)
-                if entry["offset"] == len(h.prompt):
+                if entry["offset"] == entry["end"]:
                     ends[r] = slot
                     temps[r], top_ks[r], top_ps[r] = (
                         h.temperature, h.top_k, h.top_p)
@@ -1635,13 +1818,24 @@ class ContinuousBatchingEngine:
             logits = self._dispatch_prefill(tokens, n_valid, slots, offsets)
             self._phase.prefill_rows += len(rows)
             self._phase.newest = logits
-            if not finished:
-                return
-            # Final chunks: their first tokens, fed to the decode loop
-            # device-side (no host round trip), the copy started for the
-            # handle push below.
-            toks_dev = self._first_tokens(logits, ends, temps, top_ks, top_ps)
-            self._phase.newest = toks_dev
+            if self._block:
+                began += [(slot, h, entry) for _, slot, h, entry in finished]
+            elif finished:
+                # Final chunks: their first tokens, fed to the decode loop
+                # device-side (no host round trip), the copy started for
+                # the handle push below.
+                toks_dev = self._first_tokens(logits, ends, temps, top_ks,
+                                              top_ps)
+                self._phase.newest = toks_dev
+        if self._block:
+            # No token comes out of prefill and nothing is fetched: the
+            # slots' first blocks start on the device, behind the pass.
+            if began:
+                with self._phase("prefill_publish"):
+                    self._publish_blocks(began)
+            return
+        if not finished:
+            return
         with self._phase("prefill_first_token_wait"):
             toks_np = jax.device_get(toks_dev)
         with self._phase("prefill_publish"):
@@ -1663,29 +1857,82 @@ class ContinuousBatchingEngine:
                     else False) or h.produced >= h.max_new_tokens
             self._phase.lock_waited("handle", h._push(tok, done))
             with self._lock_loop:
-                if self._prefix_cache is not None:
-                    # Publish the prompt's full pages NOW (not at
-                    # request completion): a concurrent same-prefix
-                    # request admitted next tick already shares them.
-                    hashes = entry.get("hashes") or []
-                    if hashes:
-                        self._prefix_cache.insert(
-                            hashes, entry["pages"][:len(hashes)]
-                        )
-                del self._prefilling[slot]
+                self._prefilled_locked(slot, entry)
                 if done:
                     self._free.append(slot)
                     self._pool.release(entry["pages"])
                 else:
-                    self._slot_pages[slot] = entry["pages"]
-                    self._slots[slot] = h
-                    self._rows_host[slot] = len(h.prompt)
-                    self._gen[slot] += 1
-                    self._temps[slot] = h.temperature
-                    self._top_ks[slot] = h.top_k
-                    self._top_ps[slot] = h.top_p
-                    self._active[slot] = True
-                    self._params_dirty = True
+                    self._join_decode_locked(slot, h, entry, len(h.prompt))
+
+    def _prefilled_locked(self, slot: int, entry: Dict):
+        """A prompt's prefill is done: its full pages go to the prefix
+        cache NOW (not at request completion: a concurrent same-prefix
+        request admitted next tick already shares them) and it leaves the
+        prefilling set."""
+        hashes = entry.get("hashes") or []
+        if self._prefix_cache is not None and hashes:
+            self._prefix_cache.insert(hashes, entry["pages"][:len(hashes)])
+        del self._prefilling[slot]
+
+    def _join_decode_locked(self, slot: int, h, entry: Dict, rows: int):
+        """Slot `slot` joins the decode set behind `rows` cached rows."""
+        self._slot_pages[slot] = entry["pages"]
+        self._slots[slot] = h
+        self._rows_host[slot] = rows
+        self._gen[slot] += 1
+        self._temps[slot] = h.temperature
+        self._top_ks[slot] = h.top_k
+        self._top_ps[slot] = h.top_p
+        self._active[slot] = True
+        self._params_dirty = True
+
+    def _publish_blocks(self, began):  # rtlint: disable=RT006 — loop-thread-only, see _advance_prefills
+        """`_publish_first_tokens` for a block-diffusion model: the
+        requests `began [(slot, handle, entry)]`, whose prompts' whole
+        blocks are in the pages, start their first block on the device and
+        join the decode set. No token is pushed: the first arrive with the
+        first block's commit."""
+        self._begin_blocks({slot: h.prompt for slot, h, _ in began})
+        b = self._block
+        with self._lock_loop:
+            for slot, h, entry in began:
+                h.admitted_at_step = self._steps
+                self._prefilled_locked(slot, entry)
+                self._join_decode_locked(slot, h, entry, entry["end"])
+                self._blk_skip[slot] = len(h.prompt) - entry["end"]
+                self._blk_masked[slot] = b - self._blk_skip[slot]
+
+    def _next_block_pass_locked(self, snapshot):
+        """The pass about to be dispatched, from the host's mirrors alone:
+        `snapshot`'s entries with whether the slot's pass is its block's
+        commit, the mirrors advanced as the device will advance the slots
+        (a denoising pass fills B / denoise_steps of the masked positions,
+        a commit pass adds B rows and starts the next block all masked),
+        and the pass counted."""
+        b, d = self._block, self._diffusion
+        n = b // self.cfg.denoise_steps
+        out, used = [], 0
+        for s, gen, h in snapshot:
+            commit = bool(self._blk_masked[s] == 0)
+            out.append((s, gen, h, commit))
+            if commit:
+                self._blk_masked[s] = b
+                self._rows_host[s] += b
+            else:
+                fills = min(n, self._blk_masked[s])
+                self._blk_masked[s] -= fills
+                used += fills
+        commits = sum(e[3] for e in out)
+        d["passes"] += 1
+        d["slot_passes_offered"] += self.num_slots
+        d["slot_passes"] += len(out)
+        d["commit_slot_passes"] += commits
+        d["denoise_slot_passes"] += len(out) - commits
+        # The rows the head and the sampler compute (n a slot, idle and
+        # committing slots' too), and those whose draw fills a position.
+        d["head_rows"] += self.num_slots * n
+        d["head_rows_used"] += int(used)
+        return out
 
     def _note_hol(self, prefill_s: float, n_active: int):
         """Attribute a slow prefill pass to the decode slots it stalled.
@@ -1721,8 +1968,9 @@ class ContinuousBatchingEngine:
     # the ledger; shared host state is touched under self._lock.
     def _turn(self):  # rtlint: disable=RT006,RT010
         """One loop iteration with work, inside the ledger's `turn` span:
-        admit, advance prefills by a pass, dispatch decode step k+1,
-        drain and distribute step k."""
+        admit, advance prefills by a pass, dispatch decode step k+1 (a
+        block-diffusion model's: pass k+1 of every slot's block), drain
+        and distribute step k."""
         phase = self._phase
         with phase("admit"):
             self._apply_kv_chaos()
@@ -1745,12 +1993,18 @@ class ContinuousBatchingEngine:
             ]
             if snapshot:
                 # The step about to be dispatched: each decoding slot
-                # attends to its rows and the one it writes, in whole
-                # pages; the pool holds max_len rows for every slot.
+                # attends to its rows and the one it writes (a block-
+                # diffusion slot: its committed rows and its block's), in
+                # whole pages; the pool holds max_len rows for every slot.
                 live = list(self._slots)
-                self._rows_host[live] += 1
-                self._attn_rows_live += int(self._rows_host[live].sum())
-                pages = -(-self._rows_host[live] // self.page_size)
+                if self._block:
+                    reach = self._rows_host[live] + self._block
+                    snapshot = self._next_block_pass_locked(snapshot)
+                else:
+                    self._rows_host[live] += 1
+                    reach = self._rows_host[live]
+                self._attn_rows_live += int(reach.sum())
+                pages = -(-reach // self.page_size)
                 self._attn_rows_read += int(pages.sum()) * self.page_size
                 self._attn_rows_held += self.num_slots * self.max_len
         new_inflight = None
@@ -1760,25 +2014,30 @@ class ContinuousBatchingEngine:
             if self._bt_dirty:
                 self._upload_block_table()
             with phase("decode_dispatch"):
+                step_key = None
                 if self._sampled_active:
                     self._rng, step_key = jax.random.split(self._rng)
-                    (next_dev, self._k, self._v, self._lengths,
-                     *tail) = self._decode_sampled(
-                        self.params, self._tokens_dev,
-                        self._k, self._v, self._lengths,
-                        self._active_dev, self._bt_dev,
-                        self._temps_dev, self._top_ks_dev,
-                        self._top_ps_dev, step_key, **self._tail,
-                    )
+                if self._block:
+                    next_dev = phase.newest = self._dispatch_block(step_key)
                 else:
-                    (next_dev, self._k, self._v, self._lengths,
-                     *tail) = self._decode_greedy(
-                        self.params, self._tokens_dev,
-                        self._k, self._v, self._lengths,
-                        self._active_dev, self._bt_dev, **self._tail,
-                    )
-                self._tail = dict(zip(self._tail, tail))
-                self._tokens_dev = phase.newest = next_dev
+                    if self._sampled_active:
+                        (next_dev, self._k, self._v, self._lengths,
+                         *tail) = self._decode_sampled(
+                            self.params, self._tokens_dev,
+                            self._k, self._v, self._lengths,
+                            self._active_dev, self._bt_dev,
+                            self._temps_dev, self._top_ks_dev,
+                            self._top_ps_dev, step_key, **self._tail,
+                        )
+                    else:
+                        (next_dev, self._k, self._v, self._lengths,
+                         *tail) = self._decode_greedy(
+                            self.params, self._tokens_dev,
+                            self._k, self._v, self._lengths,
+                            self._active_dev, self._bt_dev, **self._tail,
+                        )
+                    self._tail = dict(zip(self._tail, tail))
+                    self._tokens_dev = phase.newest = next_dev
                 # Start the D2H copy NOW: it lands while this thread
                 # distributes the previous step's tokens and the next
                 # turn dispatches — the drain below then finds a
@@ -1802,7 +2061,10 @@ class ContinuousBatchingEngine:
                     (prev_tokens, prev_lengths)
                 )
             with phase("distribute"):
-                self._distribute(prev_snapshot, toks, lengths_np)
+                if self._block:
+                    self._distribute_blocks(prev_snapshot, toks, lengths_np)
+                else:
+                    self._distribute(prev_snapshot, toks, lengths_np)
                 # The drained step's device arrays die here, inside a
                 # phase, not at scope exit: releasing two buffers the
                 # in-flight step still reads costs 1.6 ms a turn on the
@@ -1830,6 +2092,61 @@ class ContinuousBatchingEngine:
         self._params_dirty = True
         self._release_slot_pages_locked(s)
 
+    def _expired_locked(self, s: int, h, now_wall: float) -> bool:
+        """Whether decoding slot `s`'s request was cancelled or ran out of
+        deadline: dead work never holds a TPU slot, so it is evicted
+        mid-decode and the handle failed (cancel() already did for the
+        cancelled case). Under the engine's lock."""
+        if not (h.cancelled or (h.deadline_ts and now_wall > h.deadline_ts)):
+            return False
+        if not h.cancelled:
+            self._deadline_expired += 1
+            self._phase.lock_waited("handle", h._fail(
+                RequestCancelledError(
+                    f"deadline expired mid-decode "
+                    f"(request {h.request_id}, "
+                    f"{h.produced} tokens produced)",
+                    reason="deadline", rid=str(h.request_id),
+                )))
+            observatory.record_deadline_expired("", "engine_decode")
+        self._evict_locked(s)
+        return True
+
+    def _distribute_blocks(self, prev_snapshot, blocks, lengths_np):
+        """`_distribute` for a drained PASS of a block-diffusion model: a
+        slot whose pass was its block's commit is handed the block's
+        tokens (a first block less the prompt's remainder, a last one cut
+        at `max_new_tokens`); `eos_id` ends a request at the end of the
+        block that holds it, and a slot is evicted before a block would
+        pass `max_len`."""
+        now_wall, phase, d = time.time(), self._phase, self._diffusion
+        with self._lock_loop:
+            self._steps += 1
+            for s, gen, h, commit in prev_snapshot:
+                if self._gen[s] != gen or self._slots.get(s) is not h:
+                    continue  # evicted under the lag
+                if self._expired_locked(s, h, now_wall):
+                    continue
+                if not commit:
+                    continue
+                skip, self._blk_skip[s] = self._blk_skip[s], 0
+                toks = blocks[s, skip:][:h.max_new_tokens - h.produced]
+                last = (h.produced + len(toks) >= h.max_new_tokens
+                        or (self.eos_id is not None
+                            and self.eos_id in toks)
+                        or int(lengths_np[s]) + self._block
+                        > self._last_block_end())
+                for j, tok in enumerate(toks):
+                    h.produced += 1
+                    phase.lock_waited("handle", h._push(
+                        int(tok), last and j == len(toks) - 1))
+                d["blocks_committed"] += 1
+                d["tokens_committed"] += len(toks)
+                if last:
+                    self._evict_locked(s)
+        _engine_metrics()["tokens_per_pass"].set(
+            d["tokens_committed"] / max(1, d["slot_passes"]))
+
     def _distribute(self, prev_snapshot, toks, lengths_np):
         """Push a drained step's tokens to their handles; evict what
         finished, was cancelled or ran out of deadline."""
@@ -1842,25 +2159,7 @@ class ContinuousBatchingEngine:
             for s, gen, h in prev_snapshot:
                 if self._gen[s] != gen or self._slots.get(s) is not h:
                     continue  # evicted under the lag
-                if h.cancelled or (
-                    h.deadline_ts and now_wall > h.deadline_ts
-                ):
-                    # Dead work never holds a TPU slot: evict mid-decode
-                    # and fail the handle (cancel() already did for the
-                    # cancelled case).
-                    if not h.cancelled:
-                        self._deadline_expired += 1
-                        phase.lock_waited("handle", h._fail(
-                            RequestCancelledError(
-                                f"deadline expired mid-decode "
-                                f"(request {h.request_id}, "
-                                f"{h.produced} tokens produced)",
-                                reason="deadline", rid=str(h.request_id),
-                            )))
-                        observatory.record_deadline_expired(
-                            "", "engine_decode"
-                        )
-                    self._evict_locked(s)
+                if self._expired_locked(s, h, now_wall):
                     continue
                 tok = int(toks[s])
                 h.produced += 1
@@ -1944,6 +2243,10 @@ class ContinuousBatchingEngine:
                     self._bt_dirty = False
                     self._tokens_dev = self._replicated(
                         np.zeros(self.num_slots, dtype=np.int32))
+                    if self._block:
+                        self._blk = jax.tree.map(
+                            self._replicated, paged_kv.init_block_state(
+                                self.cfg, self.num_slots))
                     self._gen += 1  # orphan any in-flight snapshot
                     self._active[:] = False
                     self._temps[:] = 0.0

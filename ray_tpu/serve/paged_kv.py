@@ -80,6 +80,7 @@ from ray_tpu.models.transformer import (
     TransformerConfig,
     _embed_tokens,
     at_layer,
+    block_causal,
     dense_mlp,
     expand_latent,
     gate_attention,
@@ -1058,6 +1059,12 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     end = (offset + n_valid)[:, None]                           # [P, 1]
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (p_, c, width), 2)
     valid = (k_pos <= positions[:, :, None]) & (k_pos < end[:, :, None])
+    if cfg.block_length:
+        # A block-diffusion model's prompt: a position sees its own block
+        # whole. The engine hands it whole blocks (`n_valid` and `offset`
+        # multiples of the block), so no block is cut by `end`.
+        valid = (block_causal(positions, k_pos[:, 0], cfg.block_length)
+                 & (k_pos < end[:, :, None]))
     bt_rows = block_tables[slot]                                # [P, mp]
     in_range = (positions < end) & (positions < max_len)
     page_of = jnp.minimum(positions // ps, mp - 1)
@@ -1128,6 +1135,165 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     return _with_recurrent(out, rec, rec_count,
                            prefill_tokens_valid=n_valid.sum(),
                            prefill_tokens_computed=p_ * c)
+
+
+def init_block_state(cfg: TransformerConfig, slots: int) -> Dict:
+    """What a block-diffusion engine keeps a slot on the device beside its
+    pages: the current block's `tokens [slots, B]` and which of its
+    positions are still `masked [slots, B]` (a bit of its own: a prompt may
+    hold the mask token's id). A slot's phase is read off the bits: a block
+    with none masked has its commit pass next."""
+    b = cfg.block_length
+    return {
+        "tokens": jnp.full((slots, b), cfg.mask_token_id, jnp.int32),
+        "masked": jnp.ones((slots, b), bool),
+    }
+
+
+def start_blocks(state, lengths, start, tokens, masked, committed):
+    """The slots `start [S] bool` begin their first block: `tokens [S, B]`
+    (a prompt's remainder, clean) with `masked [S, B]` elsewhere, behind
+    `committed [S]` rows of cache. Other slots keep what they have."""
+    row = start[:, None]
+    return {
+        "tokens": jnp.where(row, tokens, state["tokens"]),
+        "masked": jnp.where(row, masked, state["masked"]),
+    }, jnp.where(start, committed, lengths)
+
+
+def _fold_block(q, kv_heads: int):
+    """A block's queries `[S, B, H, D]` as `paged_decode_attention` takes a
+    slot's heads, `[S, KVH * (B * H/KVH), D]`: all B positions of a block
+    see the same rows, so they are one group of queries B times as large on
+    each key-value head."""
+    s_, b, h, d = q.shape
+    return q.reshape(s_, b, kv_heads, h // kv_heads, d).transpose(
+        0, 2, 1, 3, 4).reshape(s_, b * h, d)
+
+
+def _unfold_block(out, b: int, kv_heads: int):
+    """`_fold_block`'s inverse on the attention's result: `[S, B, H, D]`."""
+    s_, bh, d = out.shape
+    return out.reshape(s_, kv_heads, b, bh // (b * kv_heads), d).transpose(
+        0, 2, 1, 3, 4).reshape(s_, b, bh // b, d)
+
+
+def _block_hidden(params, state, k_pages, v_pages, lengths, active,
+                  block_tables, cfg, max_len, mesh):
+    """One pass of every slot's current block through the layers: the
+    block's B rows (its tokens where filled, the mask token where not) are
+    written into the slot's pages at positions `lengths ..` and attend to
+    the `lengths + B` rows the slot then holds, the block itself whole.
+    Returns the final-norm hidden states `[S, B, d]`, the pools and the
+    routing counts."""
+    s_, b = state["tokens"].shape
+    ps, mp = k_pages.shape[2], block_tables.shape[1]
+    kvh, hd = cfg.n_kv_heads, cfg.head_dim
+    with jax.named_scope("diffusion.embed"):
+        fed = jnp.where(state["masked"], cfg.mask_token_id, state["tokens"])
+        x = _embed_tokens(params, fed, cfg)                     # [S, B, d]
+    cos, sin = rope_tables(cfg, max_len)
+    positions = lengths[:, None] + jnp.arange(b, dtype=jnp.int32)[None]
+    page_of = positions // ps
+    # An idle slot's rows, and rows past what a slot may hold, park in the
+    # NULL page (a table's entries past a slot's pages name it already).
+    kept = active[:, None] & (positions < max_len) & (page_of < mp)
+    pages_w = jnp.where(
+        kept, jnp.take_along_axis(block_tables, jnp.minimum(page_of, mp - 1),
+                                  1), NULL_PAGE).reshape(-1)
+    rows_w = (positions % ps).reshape(-1)
+    rows_att = jnp.where(active, jnp.minimum(lengths + b, max_len), 0)
+
+    def attend(i, kc, vc, q, k, v):
+        with jax.named_scope("diffusion.attend"):
+            kc = kc.at[i, pages_w, rows_w].set(
+                _rows(k.reshape(s_ * b, kvh, hd), kc))
+            vc = vc.at[i, pages_w, rows_w].set(
+                _rows(v.reshape(s_ * b, kvh, hd), vc))
+            attn = paged_decode_attention(
+                _fold_block(q, kvh), kc, vc, i, block_tables, rows_att,
+                cfg.attention_scale, mesh=mesh)
+            return kc, vc, _unfold_block(attn, b, kvh)
+
+    x, k_new, v_new, counts = _scan_layers(
+        params, x, k_pages, v_pages, attend, cfg, cos, sin, positions, mesh)
+    return (rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh),
+            k_new, v_new, counts)
+
+
+def block_logits(params, state, k_pages, v_pages, lengths, active,
+                 block_tables, cfg: TransformerConfig, max_len: int,
+                 mesh=None):
+    """The logits `[S, B, vocab]` float32 of one pass of every slot's
+    block, and nothing advanced but the pools' rows the pass wrote: what
+    `block_pass_paged` draws a pass's tokens from (`engine.prefill_logits`
+    and the tests read it)."""
+    x, k_new, v_new, _ = _block_hidden(
+        params, state, k_pages, v_pages, lengths, active, block_tables, cfg,
+        max_len, mesh)
+    return project_logits(x, params, cfg).astype(jnp.float32), k_new, v_new
+
+
+def block_pass_paged(params, state, k_pages, v_pages, lengths, active,
+                     block_tables, temps, top_ks, top_ps, key,
+                     cfg: TransformerConfig, max_len: int, mesh=None,
+                     moe=None):
+    """One PASS of a block-diffusion model for every slot at once: what
+    `decode_paged` is to a next-token model. A slot's phase is data:
+
+      * a block with masked positions has a DENOISING pass: its B rows
+        run against the slot's committed rows and themselves, and the
+        first `n = B / denoise_steps` masked positions from the left (the
+        released code's "sequential" strategy) are filled with tokens
+        drawn from the logits AT those positions (greedy, or under the
+        slot's temperature, top-k and top-p; `temps` None compiles the
+        greedy-only program);
+      * a block with none has its COMMIT pass: the B clean tokens run,
+        the keys and values they write are the block's, `lengths` grows
+        by B and the next block starts all masked.
+
+    Every pass writes the block's B rows into the pages at the block's
+    positions, a later pass over an earlier one's, so the two kinds are
+    one program. The head and the sampler run on the `n` rows a slot a
+    pass may fill and no others (which positions is known before the
+    logits); a committing or idle slot's rows ride along and their draws
+    are dropped.
+
+    Returns `(block [S, B]` the block's tokens after this pass's fills, a
+    committing slot's the tokens it committed`, state, k_pages, v_pages,
+    lengths)` and the advanced `moe`. An idle slot keeps its state and its
+    length."""
+    s_, b = state["tokens"].shape
+    n = b // cfg.denoise_steps
+    x, k_new, v_new, counts = _block_hidden(
+        params, state, k_pages, v_pages, lengths, active, block_tables, cfg,
+        max_len, mesh)
+    tokens, masked = state["tokens"], state["masked"]
+    with jax.named_scope("diffusion.unmask"):
+        fill = masked & (jnp.cumsum(masked, axis=1) <= n) & active[:, None]
+        # The n positions to fill first; a slot with fewer brings others
+        # along, whose draws `fill` drops.
+        at = jnp.argsort(~fill, axis=1, stable=True)[:, :n]
+        logits = project_logits(
+            jnp.take_along_axis(x, at[:, :, None], axis=1).reshape(s_ * n, -1),
+            params, cfg)
+        if temps is None:
+            picked = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+        else:
+            picked = _pick_tokens(logits, *(jnp.repeat(a, n) for a in
+                                            (temps, top_ks, top_ps)), key)
+        drawn = jnp.zeros_like(tokens).at[
+            jnp.arange(s_)[:, None], at].set(picked.reshape(s_, n))
+        block = jnp.where(fill, drawn, tokens)
+    commit = active & ~masked.any(axis=1)
+    done = commit[:, None]
+    new_state = {
+        "tokens": jnp.where(done, cfg.mask_token_id, block),
+        "masked": jnp.where(done, True, masked & ~fill),
+    }
+    new_lengths = jnp.where(commit, lengths + b, lengths)
+    return _count_routing(
+        (block, new_state, k_new, v_new, new_lengths), moe, counts, cfg)
 
 
 def cow_copy_page(k_pages, v_pages, src, dst):

@@ -597,6 +597,7 @@ GROUPED_PRODUCTS = {
     "dots": ((512, 4096), 7168, 2048, 5 * 16),
     "lfm2": ((384, 2048), 2048, 1536, 8 * 64),
     "solar": ((1024, 4096), 4096, 1280, 4 * 40),
+    "sdar": ((3072, 4096), 2048, 768, 6 * 128),
 }
 
 
@@ -1256,3 +1257,144 @@ def test_delta_rule_decode_passes_over_its_state_once(v5e, monkeypatch):
     text = jax.jit(fn, donate_argnums=donated).lower(*args).compile().as_text()
     assert "kda_update" not in text
     assert _takes_the_state_pool(text, SOLAR_POOL)
+
+
+SDAR_SLOTS, SDAR_MAX_LEN, SDAR_CHUNK = 96, 2560, 256
+SDAR_FILE = "sdar-30b-a3b-serve.json"
+
+
+def _sdar_program(program, one, rows=1):
+    """`block_pass_paged` (sampled, or `block_pass_greedy`) or
+    `prefill_chunk_paged` at the sizes of the cell `serve-sdar-blockgen`
+    (SDAR-30B-A3B-Chat's first six layers, 96 slots x 2560, pages of 16,
+    blocks of 4, a prefill pass of `rows` rows of 256), on shapes, with the
+    routing accumulator in the tail as the engine passes it: (fn, donated,
+    args, the cache's shapes, cfg)."""
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.serve import paged_kv
+
+    cfg = dataclasses.replace(configs.get_config("sdar-30b-a3b-l6"),
+                              remat=False)
+    slots, per_slot = SDAR_SLOTS, SDAR_MAX_LEN // PAGE
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    def struct(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, slots, slots * per_slot + 1, PAGE, per_slot)))
+    moe = on_chip(jax.eval_shape(
+        lambda: paged_kv.init_routing_counters(cfg)))
+    pool = (cache["k"], cache["v"], cache["lengths"])
+    if program.startswith("block_pass"):
+        state = on_chip(jax.eval_shape(
+            lambda: paged_kv.init_block_state(cfg, slots)))
+        sampling = ((None,) * 4 if program == "block_pass_greedy" else (
+            struct((slots,), jnp.float32), struct((slots,)),
+            struct((slots,), jnp.float32), struct((2,), jnp.uint32)))
+        fn = lambda p, st, k, v, ln, a, bt, tp, tk, tpp, key, moe: (  # noqa: E731
+            paged_kv.block_pass_paged(p, st, k, v, ln, a, bt, tp, tk, tpp,
+                                      key, cfg, SDAR_MAX_LEN, None, moe))
+        args = (params, state, *pool, struct((slots,), jnp.bool_),
+                cache["block_tables"], *sampling, moe)
+        return fn, (2, 3), args, cache, cfg
+    fn = lambda p, t, n, s, o, k, v, ln, bt, moe: (  # noqa: E731
+        paged_kv.prefill_chunk_paged(p, t, n, s, o, k, v, ln, bt, cfg,
+                                     SDAR_MAX_LEN, None, moe))
+    row = struct((rows,))
+    args = (params, struct((rows, SDAR_CHUNK)), row, row, row, *pool,
+            cache["block_tables"], moe)
+    return fn, (5, 6), args, cache, cfg
+
+
+# The cell's per-layer metrics that read a trace by an operation's name,
+# and the step program in which each has to find one.
+SDAR_TRACE_METRICS = {
+    "block_pass": ("moe.expert_time_share.sdar",
+                   "moe.dispatch_time_share.sdar",
+                   "moe.expert_roofline_share.sdar",
+                   "attn.decode_time_share.sdar",
+                   "sampler.time_share.sdar"),
+    "block_pass_greedy": ("moe.expert_time_share.sdar",
+                          "attn.decode_time_share.sdar"),
+    "prefill_chunk_paged": ("moe.expert_time_share.sdar",
+                            "moe.dispatch_time_share.sdar"),
+}
+
+
+@pytest.mark.parametrize("program,rows", [
+    ("block_pass", 1), ("block_pass_greedy", 1), ("prefill_chunk_paged", 1),
+    ("prefill_chunk_paged", 2)],
+    ids=["block-pass", "block-pass-greedy", "prefill-1", "prefill-2"])
+def test_block_diffusion_steps_fit_and_read_their_stacks_in_place(
+        v5e, program, rows, monkeypatch):
+    """The cell `serve-sdar-blockgen`'s step programs at its own sizes
+    (`_sdar_program`): the arguments are the bytes its configuration file
+    states, the pages come back in the buffers they came in, a block pass
+    runs the decode-attention kernel ONCE (a block's 4 positions folded
+    into its groups of queries: 4 groups of 32 rows a slot, which the
+    kernel's queries, results and page buffers fit beside in 16 MiB of
+    scoped VMEM at 96 slots) and a prefill pass does not, the expert
+    stacks `[6, 128, ...]` are read in place by the scanned layer's three
+    grouped products, and every trace metric the cell adds finds an
+    operation of its pattern among the names the compiler prints."""
+    import json
+
+    _bench_on_path()
+    import spec
+    from xplane import reduce
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, donated, args, cache, cfg = _sdar_program(
+        program, SingleDeviceSharding(v5e[0]), rows)
+    assert cache["k"].shape == (6, 96 * 160 + 1, PAGE, 512)
+    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    with open(os.path.join(spec.BENCH, "configs", SDAR_FILE)) as f:
+        stated = json.load(f)["compiled"]
+    key = program if program.startswith("block_pass") else f"prefill_{rows}"
+    assert memory.argument_size_in_bytes == stated[key]["arguments_bytes"]
+    assert memory.temp_size_in_bytes <= stated[key]["temporaries_bytes_at_most"]
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 12.1e9
+    pools = sum(a.size * a.dtype.itemsize for a in (cache["k"], cache["v"]))
+    assert memory.alias_size_in_bytes >= pools
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = f32\[", text)) == 3
+    kernel = re.findall(r"%paged_decode_attention[.\d]* = f32\[([\d,]+)\]",
+                        text)
+    assert kernel == (["96,4,32,128"] if program.startswith("block_pass")
+                      else [])
+    for inner in ("2048,768", "768,2048"):
+        assert not re.findall(
+            rf"= bf16\[(?:1,|6,)?128,{inner}\]\S* (?:copy|fusion)\(", text)
+        assert not re.findall(rf"= bf16\[768,{inner}\]\S* copy\(", text)
+    short = [reduce._short(line.strip().removeprefix("ROOT "))
+             for line in text.splitlines() if " = " in line]
+    for metric in SDAR_TRACE_METRICS[program]:
+        how = spec.layer_metric_spec(metric)
+        assert any(re.search(how["match"], op) for op in short), metric
+    if program == "block_pass":  # each part of the sampler's pattern
+        for op in ("%fusion.1 f32[192,64]", "%select_reduce_fusion.7 f32[192]",
+                   "%iota_reduce_fusion bf16[192]", "%xor.3 u32[192,151936]"):
+            assert re.search(spec.layer_metric_spec(
+                "sampler.time_share.sdar")["match"], op)
+        ran = [reduce._short(line.strip().removeprefix("ROOT "))
+               for line in text.splitlines()
+               if re.search(r" (?:fusion|custom-call)\(", line)]
+        names = " ".join(ran)
+        assert "select_reduce_fusion" in names and "f32[192,64]" in names
+    step = ("decode.device_ms_per_step.sdar"
+            if program.startswith("block_pass")
+            else "prefill.device_ms_per_chunk.sdar")
+    other = ("prefill.device_ms_per_chunk.sdar"
+             if program.startswith("block_pass")
+             else "decode.device_ms_per_step.sdar")
+    assert any(re.search(spec.layer_metric_spec(step)["contains_op"], op)
+               for op in short)
+    assert not any(re.search(spec.layer_metric_spec(other)["contains_op"], op)
+                   for op in short)

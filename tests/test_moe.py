@@ -11,6 +11,7 @@ hundred times under what bfloat16 anywhere on the path (3e-3 a rounding,
 
 import os
 import sys
+import time
 from dataclasses import replace
 
 import jax
@@ -334,6 +335,10 @@ def test_engine_counts_every_assignment_and_fetches_them_in_stats_only():
         eng.submit(PROMPT, max_new_tokens=6).result(timeout=120)
         eng.submit(PROMPT[:5], max_new_tokens=3,
                    temperature=0.7).result(timeout=120)
+        # The loop publishes its last turn and goes idle: `stats()` fetches
+        # the device's counters before it reads a ledger that publishes at
+        # a turn's end, and in between a turn may end.
+        time.sleep(0.6)
         stats = eng.stats()
     finally:
         eng.shutdown()
@@ -425,11 +430,12 @@ def test_the_softmax_block_of_a_whole_model_is_unchanged_bit_for_bit(
                                   np.asarray(want_counts))
 
 
-# The grouped products the four sparse serving cells launch, `(m, k, n)` of
+# The grouped products the five sparse serving cells launch, `(m, k, n)` of
 # the gate and up product and of the down product at a decode step's rows
 # (`bench/configs/`: OLMoE, dots.vlm1's share, LFM2-24B-A2B's stage,
-# Solar-Open2's share), and the weight tile each was fastest or within a
-# point of fastest with on the chip (`tools/gmm_sweep.py`, PR 64).
+# Solar-Open2's share, SDAR-30B-A3B's stage at a block pass's rows), and the
+# weight tile each was fastest or within a point of fastest with on the chip
+# (`tools/gmm_sweep.py`, PR 64; SDAR's, PR 68).
 CELL_PRODUCTS = {
     "olmoe gate-up": ((128, 2048, 1024), (2048, 1024)),
     "olmoe down": ((128, 1024, 2048), (1024, 2048)),
@@ -439,6 +445,8 @@ CELL_PRODUCTS = {
     "lfm2 down": ((384, 1536, 2048), (1536, 1024)),
     "solar gate-up": ((1024, 4096, 1280), (4096, 640)),
     "solar down": ((1024, 1280, 4096), (1280, 1024)),
+    "sdar gate-up": ((3072, 2048, 768), (2048, 768)),
+    "sdar down": ((3072, 768, 2048), (768, 2048)),
 }
 OTHER_PRODUCTS = {
     "a pass's rows": ((4096, 4096, 1280), (4096, 640)),

@@ -1,5 +1,5 @@
 """The grouped expert product alone on the chip, over tilings, at the sizes
-the four sparse serving cells launch it with (`parallel/moe.py`
+the five sparse serving cells launch it with (`parallel/moe.py`
 `grouped_matmul`, `%gmm.*` in a trace).
 
     chiprun -- python tools/gmm_sweep.py                  # every product
@@ -57,6 +57,9 @@ CELLS = {
                                  "pass": (1024, 1024, 64)}),
     "solar": (4096, 1280, 4, 40, {"decode": (1024, 130, 28),
                                   "pass": (2048, 256, 40)}),
+    # A block pass of 96 slots x 4 rows x 8 choices, every expert hit.
+    "sdar": (2048, 768, 6, 128, {"decode": (3072, 3072, 128),
+                                 "pass": (2048, 2048, 128)}),
 }
 TINY = {"tiny": (256, 384, 2, 4, {"decode": (32, 20, 3),
                                   "pass": (64, 64, 4)})}
