@@ -922,6 +922,13 @@ class ContinuousBatchingEngine:
         self._active_dev = jnp.asarray(self._active)
         self._params_dirty = False
         self._sampled_active = False
+        # stats()["sampler"]: sampled decode (or block) dispatches, and
+        # those among them in which a live slot asks for a top-k, whose
+        # passes over the vocabulary the step then pays for
+        # (`paged_kv._pick_tokens`). From the host mirrors, loop-thread-
+        # only: nothing is fetched for it.
+        self._top_k_active = False
+        self._sampler = {"dispatches": 0, "top_k_dispatches": 0}
         self._param_uploads = 0  # refresh events (tests pin steady state)
         # stats()["attention"]: how much of the cache decode attention is
         # given to read. `_rows_host` mirrors a decoding slot's device
@@ -1122,6 +1129,8 @@ class ContinuousBatchingEngine:
             self._active_dev = jnp.asarray(self._active)
             self._sampled_active = bool(
                 (self._temps[self._active] > 0).any())
+            self._top_k_active = bool(
+                (self._top_ks[self._active] > 0).any())
             self._params_dirty = False
             self._param_uploads += 1
 
@@ -1458,6 +1467,10 @@ class ContinuousBatchingEngine:
                 "warm_compiles": self._warm_compiles,
                 "recompiles_post_warm": compiles - self._warm_compiles,
                 "param_uploads": self._param_uploads,
+                # Cumulative: sampled decode (or block) steps dispatched,
+                # and those in which a live slot's `top_k` made the step
+                # find every row's k-th largest logit.
+                "sampler": dict(self._sampler),
                 # Cumulative over dispatched decode steps: rows in the
                 # pages decode attention was given to read (each decoding
                 # slot's length in whole pages, a latent pool's too), rows
@@ -2017,6 +2030,8 @@ class ContinuousBatchingEngine:
                 step_key = None
                 if self._sampled_active:
                     self._rng, step_key = jax.random.split(self._rng)
+                    self._sampler["dispatches"] += 1
+                    self._sampler["top_k_dispatches"] += self._top_k_active
                 if self._block:
                     next_dev = phase.newest = self._dispatch_block(step_key)
                 else:

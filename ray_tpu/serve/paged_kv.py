@@ -833,60 +833,133 @@ def _count_routing(out, moe, counts, cfg):
     })
 
 
-MAX_TOP_K = 64  # per-slot top-k cap (static shape for lax.top_k)
-_SIGN_BIT, _ALL_BITS = np.uint32(1 << 31), np.uint32(0xFFFFFFFF)
+MAX_TOP_K = 64  # per-slot top-k cap: what `submit()` checks
+
+
+def _bit_pattern(dtype):
+    """(the unsigned type of a float type's width, its sign bit, all of
+    its bits)."""
+    bits = jnp.finfo(dtype).bits
+    uint = jnp.dtype(f"uint{bits}").type
+    return uint, uint(1 << (bits - 1)), uint((1 << bits) - 1)
 
 
 def _ordered_bits(x):
-    """float32 -> uint32 whose unsigned order is the floats' order: the
+    """A float -> the unsigned integer of its own width (uint32 of a
+    float32, uint16 of a bfloat16) whose order is the floats' order: the
     sign bit set on a positive, every bit flipped on a negative.
     `_from_ordered_bits` is its inverse. -0.0 is folded into +0.0, so
     that equal floats have equal images."""
-    bits = jax.lax.bitcast_convert_type(jnp.where(x == 0, 0.0, x), jnp.uint32)
-    return bits ^ jnp.where(bits >= _SIGN_BIT, _ALL_BITS, _SIGN_BIT)
+    uint, sign, ones = _bit_pattern(x.dtype)
+    bits = jax.lax.bitcast_convert_type(
+        jnp.where(x == 0, jnp.zeros_like(x), x), uint)
+    return bits ^ jnp.where(bits >= sign, ones, sign)
 
 
-def _from_ordered_bits(u):
-    bits = u ^ jnp.where(u >= _SIGN_BIT, _SIGN_BIT, _ALL_BITS)
-    return jax.lax.bitcast_convert_type(bits, jnp.float32)
+def _from_ordered_bits(u, dtype):
+    _, sign, ones = _bit_pattern(dtype)
+    return jax.lax.bitcast_convert_type(
+        u ^ jnp.where(u >= sign, sign, ones), dtype)
 
 
-def _top_p_threshold(scaled, top_ps):
-    """Top-p's cut for every row of `scaled [S, V]` float32 (logits over
-    the temperature, `-inf` where top-k masked): `thr [S, 1]`, the
-    smallest of the row's values `v` such that the softmax mass of the
-    entries strictly above `v` is under `top_ps [S]`. Keeping
-    `scaled >= thr` keeps the token that crosses `top_p` and every tie at
-    the cut, which is what sorting the row, a `cumsum` over the sorted
-    softmax and `min(where(cum - probs < top_p, sorted, inf))` give.
+def _bisection_passes(dtype, wanted=None):
+    """The trips of a bisection among the values of `dtype`: as many as
+    the type has bits, or, under a traced flag `wanted`, none where it is
+    false."""
+    bits = jnp.finfo(dtype).bits
+    return bits if wanted is None else jnp.where(wanted, bits, 0)
 
-    No sort: the mass above `v` only falls as `v` rises, so the cut is
-    found by bisection over the ordered integer image of float32, from
-    `-inf` to the row's maximum. Each pass is one masked sum over the row,
-    and 32 of them (float32's width, nothing to set) end on one
-    representable value, which is a value of the row: between two
-    neighbouring values of the row the mass above does not change, so the
-    smallest passing cut sits on one (or on `-inf`, which keeps the row).
-    Values are compared as integers, so nothing depends on how the
-    device treats denormals. Agrees with the sort but where a partial sum
-    lands within float32 rounding of `top_p`, which the sort's `cumsum`
-    decides by its summation order too."""
-    top = jnp.max(scaled, axis=-1, keepdims=True)
-    weights = jnp.exp(scaled - top)
-    budget = top_ps[:, None] * jnp.sum(weights, axis=-1, keepdims=True)
-    image = _ordered_bits(scaled)
+
+def _smallest_passing(logits, weigh, budget, floor, wanted=None):
+    """For every row of `logits [S, V]`, the smallest value `c [S, 1]` of
+    the logits' own type, not under `floor [S, 1]`, such that the weights
+    `weigh(logits)` (`[S, V]` float32, or a scalar) summed over the
+    entries strictly above `c` are under `budget [S, 1]`. With the
+    softmax's weights and `top_p` of their sum that is top-p's cut; with
+    ones and `k`, the row's k-th largest value.
+
+    No sort: the sum above `c` only falls as `c` rises, so the cut is
+    found by bisection over the ordered integer image of the logits' type
+    (`_ordered_bits`), from `floor` to the row's maximum. Each pass is one
+    masked sum over the row, and as many passes as the type has bits (16
+    for the bfloat16 a head's product is, 32 for float32: read off the
+    input, nothing to set) end on one representable value. That is a
+    value of the row (or `floor`): between two neighbouring values of the
+    row the sum above does not change, so the smallest passing cut sits
+    on one. Values are compared as integers, so nothing depends on how
+    the device treats denormals.
+
+    A pass works the image and the weights out of the logits again: the
+    logits are then all it reads, 2 bytes an entry from wherever they
+    lie, where an image and float32 weights held across the loop are 6,
+    and on the v5e the recomputation hides behind that read (at 192 rows
+    of 151,936 a pass takes the 0.07 ms its 58 MB take).
+
+    `wanted`, a traced flag, makes the passes none where it is false: the
+    loop's bound is then data, the result the row's maximum and the
+    caller's to drop."""
+    dtype = logits.dtype
 
     def halve(_, bounds):
         lo, hi = bounds  # the cut is in [lo, hi]; `hi` passes
         mid = lo + (hi - lo) // 2
-        above = jnp.sum(jnp.where(image > mid, weights, 0.0), axis=-1,
-                        keepdims=True)
+        above = jnp.sum(
+            jnp.where(_ordered_bits(logits) > mid, weigh(logits), 0.0),
+            axis=-1, keepdims=True)
         passes = above < budget
         return jnp.where(passes, lo, mid + 1), jnp.where(passes, mid, hi)
 
-    bounds = _ordered_bits(jnp.full_like(top, -jnp.inf)), _ordered_bits(top)
-    _, cut = jax.lax.fori_loop(0, 32, halve, bounds)
-    return _from_ordered_bits(cut)
+    top = jnp.max(logits, axis=-1, keepdims=True)
+    bounds = _ordered_bits(floor), _ordered_bits(top)
+    _, cut = jax.lax.fori_loop(0, _bisection_passes(dtype, wanted), halve,
+                               bounds)
+    return _from_ordered_bits(cut, dtype)
+
+
+def _top_k_floor(logits, top_ks):
+    """Top-k's cut for every row of `logits [S, V]`: `floor [S, 1]` of
+    the logits' type, a row's `top_ks`-th largest value (the smallest
+    with fewer than `k` entries strictly above it, so ties at the k-th
+    stay and a `k` past the vocabulary keeps the row), `-inf` where a row
+    asks for none (`top_ks` 0). No pass over the vocabulary is made
+    unless some row asks."""
+    none = jnp.full((logits.shape[0], 1), -jnp.inf, logits.dtype)
+    kth = _smallest_passing(
+        logits, lambda x: 1.0, top_ks[:, None].astype(jnp.float32), none,
+        wanted=jnp.any(top_ks > 0))
+    return jnp.where(top_ks[:, None] > 0, kth, none)
+
+
+def _top_p_threshold(logits, top_ps, scale=lambda x: x, floor=None):
+    """Top-p's cut for every row of `scale(logits) [S, V]` float32 (the
+    logits over the temperature; `scale` must not decrease): `thr [S, 1]`,
+    the smallest of the row's values `v` at or above `scale(floor)` (what
+    top-k left; the whole row without one) such that the softmax mass of
+    the entries strictly above `v` is under `top_ps [S]` of the mass at or
+    above the floor. Keeping `scaled >= thr` keeps the token that crosses
+    `top_p` and every tie at the cut, which is what masking the row under
+    the floor, sorting it, a `cumsum` over the sorted softmax and
+    `min(where(cum - probs < top_p, sorted, inf))` give.
+
+    The cut is bisected among the values of the logits' own type
+    (`_smallest_passing`) and carried into the row's space by `scale`, the
+    expression that made the row, so it is a value of the row bit for bit:
+    a row of bfloat16 logits holds at most 65,536 distinct values whatever
+    its temperature, and 16 passes tell them apart. Agrees with the sort
+    but where a partial sum lands within float32 rounding of `top_p`,
+    which the sort's `cumsum` decides by its summation order too."""
+    if floor is None:
+        floor = jnp.full((logits.shape[0], 1), -jnp.inf, logits.dtype)
+    scaled = scale(logits)
+    top = jnp.max(scaled, axis=-1, keepdims=True)
+
+    def weigh(x):
+        return jnp.exp(scale(x) - top)
+
+    budget = top_ps[:, None] * jnp.sum(
+        jnp.where(scaled >= scale(floor), weigh(logits), 0.0), axis=-1,
+        keepdims=True)
+    return scale(_smallest_passing(logits, weigh, budget, floor))
 
 
 def _pick_tokens(logits, temps, top_ks, top_ps, key):
@@ -895,21 +968,19 @@ def _pick_tokens(logits, temps, top_ks, top_ps, key):
     (0 = off, capped at MAX_TOP_K) and top-p (1.0 = off) filtering —
     generate.py's sampling semantics, vectorized over slots so mixed
     greedy/sampled requests share one decode batch. Top-k masks first,
-    top-p cuts the softmax of what is left; the cut is found without
-    sorting the vocabulary and equals the sort's (`_top_p_threshold`)."""
-    logits = logits.astype(jnp.float32)
-    greedy = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-    scaled = logits / jnp.maximum(temps, 1e-6)[:, None]
-    # top-k: threshold each row at its k-th largest value. The static k
-    # clamps to the vocab so models with vocab_size < MAX_TOP_K don't
-    # crash the jitted step (lax.top_k requires k <= last dim).
-    k = min(MAX_TOP_K, logits.shape[-1])
-    topv = jax.lax.top_k(scaled, k)[0]  # [S, K] sorted desc
-    idx = jnp.clip(top_ks - 1, 0, k - 1)
-    kth = jnp.take_along_axis(topv, idx[:, None], axis=-1)
-    scaled = jnp.where((top_ks > 0)[:, None] & (scaled < kth),
-                       -jnp.inf, scaled)
-    thr = _top_p_threshold(scaled, top_ps)
+    top-p cuts the softmax of what is left; both cuts are found without
+    sorting the vocabulary and equal the sort's (`_smallest_passing`), in
+    as many passes over the logits as their type has bits, and top-k's
+    only in a step where some slot asks for one."""
+    greedy = jnp.argmax(logits.astype(jnp.float32), axis=-1).astype(jnp.int32)
+    over = jnp.maximum(temps, 1e-6)[:, None]
+
+    def scale(x):  # strictly increasing within a row
+        return x.astype(jnp.float32) / over
+
+    thr = _top_p_threshold(logits, top_ps, scale,
+                           _top_k_floor(logits, top_ks))
+    scaled = scale(logits)
     scaled = jnp.where(scaled < thr, -jnp.inf, scaled)
     sampled = jax.random.categorical(key, scaled, axis=-1).astype(jnp.int32)
     return jnp.where(temps > 0, sampled, greedy)

@@ -482,17 +482,25 @@ def test_sampled_decode_sorts_no_vocabulary(v5e, model, slots, depth,
     the sampled decode program at a serving cell's slots and vocabulary
     has no sort over the vocabulary: with one (`sort.6 f32[24,151936]`)
     it was the largest single name on the device in both Qwen3 cells,
-    5.7 ms of a 28 ms step. What `lax.top_k` compiles to is its own
-    custom fusion over `[slots, MAX_TOP_K]` and stays."""
+    5.7 ms of a 28 ms step. Top-k's cut is bisected too, in a loop that
+    makes no trip unless a slot asks for one: `lax.top_k`'s custom fusion
+    over `[slots, MAX_TOP_K]` is in no sampled program, and no
+    conditional is, which would take the logits out of the chip's vector
+    memory to hand them to its branch."""
     monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
     cfg = dataclasses.replace(configs.get_config(model), n_layers=depth)
     fn, donated, args, _ = _engine_program(
         "decode_paged", cfg, SingleDeviceSharding(v5e[0]), slots)
     compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
     vocabulary_wide = re.compile(rf"[\[,]{cfg.vocab_size}\]")
-    sorts = [line for line in compiled.as_text().splitlines()
+    text = compiled.as_text()
+    sorts = [line for line in text.splitlines()
              if " sort(" in line and vocabulary_wide.search(line)]
     assert not sorts
+    assert not [line for line in text.splitlines()
+                if 'custom_call_target="TopK"' in line
+                and "moe.route" not in line]  # the router's is its own
+    assert not re.findall(r" conditional\(", text)
     # The sorted float32 copy and its cumsum went with it: what is left
     # is under one float32 `[slots, vocabulary]` (0.76 MB where the
     # sort's program had 15.26 MB; 2.0 MB at OLMoE's, which held its
@@ -1383,11 +1391,29 @@ def test_block_diffusion_steps_fit_and_read_their_stacks_in_place(
                    "%iota_reduce_fusion bf16[192]", "%xor.3 u32[192,151936]"):
             assert re.search(spec.layer_metric_spec(
                 "sampler.time_share.sdar")["match"], op)
+        # What the sampler runs now: two bisections over the 16 bits of
+        # the bfloat16 logits, each a masked sum a trip that works the
+        # image and the weights out of the logits again: top-k's, whose
+        # trip count is data (none unless a slot asks), and top-p's. So
+        # the head's product is the one array of `[192, 151936]` any
+        # fusion writes; no `lax.top_k`, so nothing of `[192, MAX_TOP_K]`
+        # anywhere; no conditional for the pool or the logits to be
+        # handed to; and the temporaries inside what the configuration
+        # states (above), under one bfloat16 copy of the logits.
         ran = [reduce._short(line.strip().removeprefix("ROOT "))
                for line in text.splitlines()
                if re.search(r" (?:fusion|custom-call)\(", line)]
-        names = " ".join(ran)
-        assert "select_reduce_fusion" in names and "f32[192,64]" in names
+        assert " ".join(ran).count("%select_reduce_fusion") >= 2
+        assert "[192,64]" not in text
+        assert 'custom_call_target="TopK"' not in text
+        assert not [line for line in text.splitlines()
+                    if " sort(" in line and "151936]" in line]
+        assert not re.findall(r" conditional\(", text)
+        written = [m for line in text.splitlines() if " fusion(" in line
+                   for m in re.findall(r"(\w+)\[192,151936\]",
+                                       line.split(" fusion(")[0])]
+        assert written == ["bf16"]
+        assert memory.temp_size_in_bytes < 2 * 192 * 151936
     step = ("decode.device_ms_per_step.sdar"
             if program.startswith("block_pass")
             else "prefill.device_ms_per_chunk.sdar")

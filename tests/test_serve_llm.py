@@ -461,6 +461,48 @@ def test_attention_counters_follow_the_decoding_slots():
         eng.shutdown()
 
 
+def test_sampler_counters_follow_the_slots_that_ask_for_a_top_k():
+    """stats()['sampler']: sampled decode steps dispatched, and those of
+    them with a live slot whose `top_k > 0`, in which the step finds
+    every row's k-th largest logit; cumulative, from the host's mirrors.
+    Both stay 0 while every request is greedy (warm-up's steps are not
+    counted, and a greedy step runs the greedy-only program)."""
+    from ray_tpu.serve.llm import ContinuousBatchingEngine
+
+    params, cfg = _tiny_model()
+    eng = ContinuousBatchingEngine(params, cfg, num_slots=2, max_len=64)
+    try:
+        none = {"dispatches": 0, "top_k_dispatches": 0}
+        assert eng.stats()["sampler"] == none
+        eng.submit([3, 7, 11], max_new_tokens=6).result(timeout=180)
+        # A top_k on a greedy request asks the sampler for nothing.
+        eng.submit([3, 7], max_new_tokens=4, top_k=5).result(timeout=180)
+        assert eng.stats()["sampler"] == none
+        eng.submit([5, 1], max_new_tokens=6, temperature=0.9,
+                   top_p=0.9).result(timeout=180)
+        first = eng.stats()["sampler"]
+        # Five steps gave tokens 2..6; the loop may have dispatched one
+        # more before it saw the last token.
+        assert first["dispatches"] in (5, 6)
+        assert first["top_k_dispatches"] == 0
+        eng.submit([2, 9], max_new_tokens=6, temperature=0.7,
+                   top_k=16).result(timeout=180)
+        second = eng.stats()["sampler"]
+        asked = second["dispatches"] - first["dispatches"]
+        assert asked in (5, 6)
+        assert second["top_k_dispatches"] == asked
+        # A slot without a top-k beside one with: the step pays for both.
+        a = eng.submit([4, 4, 6], max_new_tokens=6, temperature=1.1)
+        b = eng.submit([2, 9], max_new_tokens=6, temperature=0.7, top_k=3)
+        a.result(timeout=180), b.result(timeout=180)
+        third = eng.stats()["sampler"]
+        assert third["dispatches"] > second["dispatches"]
+        assert second["top_k_dispatches"] < third["top_k_dispatches"] <= (
+            third["dispatches"] - first["dispatches"])
+    finally:
+        eng.shutdown()
+
+
 # -- the loop's phase ledger --------------------------------------------
 
 _LEDGER_CHILDREN = ("admit", "prefill_dispatch", "prefill_first_token_wait",
