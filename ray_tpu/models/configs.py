@@ -591,6 +591,68 @@ tiny_sdar_moe = TransformerConfig(
     denoise_steps=2,
 )
 
+# SmallThinker-21BA3B-Instruct (PowerInfer, arXiv:2507.20984; the model's
+# public config.json, `model_name` smallthinker_21b_instruct): 52 layers of
+# grouped-query attention (28 query and 4 key-value heads of 128, no bias,
+# no QK-norm) and 64 ReGLU experts of width 768, 6 a token, softmax over the
+# chosen six, none shared and no dense layer. `sliding_window_layout` and
+# `rope_layout` are the same published list, [0, 1, 1, 1] x 13: layers 0, 4,
+# 8, ... attend to everything behind them and have no position embedding;
+# the three between attend over `sliding_window_size` 4,096 and rotate. The
+# router reads the layer's input, before attention's norm. Untied 151,936
+# rows.
+_SMALLTHINKER_PERIOD = (0, 1, 1, 1)
+smallthinker_21b_a3b = TransformerConfig(
+    vocab_size=151936,
+    d_model=2560,
+    n_layers=52,
+    n_heads=28,
+    n_kv_heads=4,
+    d_ff=768,
+    max_seq=16384,  # max_position_embeddings
+    rope_theta=1.5e6,
+    norm_eps=1e-6,
+    num_experts=64,
+    experts_per_token=6,
+    moe_intermediate_size=768,
+    norm_topk_prob=True,
+    activation="relu",
+    custom_head_dim=128,
+    sliding_window_size=4096,
+    sliding_window_layout=_SMALLTHINKER_PERIOD * 13,
+    rope_layout=_SMALLTHINKER_PERIOD * 13,
+    router_reads="layer_input",
+)
+smallthinker_21b_a3b_l8 = replace(
+    smallthinker_21b_a3b, n_layers=8,
+    sliding_window_layout=_SMALLTHINKER_PERIOD * 2,
+    rope_layout=_SMALLTHINKER_PERIOD * 2)
+
+# A window that is no multiple of the engine tests' page (4) and shorter
+# than their sequences: the window's edge, a page in the window only in
+# part and the ring's wrap are in every test.
+tiny_smallthinker = TransformerConfig(
+    vocab_size=256,
+    d_model=64,
+    n_layers=4,
+    n_heads=4,
+    n_kv_heads=2,
+    d_ff=32,
+    max_seq=128,
+    dtype=jnp.float32,
+    remat=False,
+    num_experts=8,
+    experts_per_token=2,
+    moe_intermediate_size=32,
+    norm_topk_prob=True,
+    activation="relu",
+    custom_head_dim=16,
+    sliding_window_size=6,
+    sliding_window_layout=_SMALLTHINKER_PERIOD,
+    rope_layout=_SMALLTHINKER_PERIOD,
+    router_reads="layer_input",
+)
+
 NAMED_CONFIGS = {
     "tiny": tiny,
     "tiny_gqa": tiny_gqa,
@@ -621,6 +683,9 @@ NAMED_CONFIGS = {
     "tiny_sdar_moe": tiny_sdar_moe,
     "sdar-30b-a3b": sdar_30b_a3b,
     "sdar-30b-a3b-l6": sdar_30b_a3b_l6,
+    "tiny_smallthinker": tiny_smallthinker,
+    "smallthinker-21b-a3b": smallthinker_21b_a3b,
+    "smallthinker-21b-a3b-l8": smallthinker_21b_a3b_l8,
 }
 
 

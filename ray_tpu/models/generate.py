@@ -23,10 +23,13 @@ from ray_tpu.models.transformer import (
     TransformerConfig,
     _embed_tokens,
     dense_mlp,
+    layers_inputs,
     project_logits,
     project_qkv,
+    rotate,
+    router_input,
 )
-from ray_tpu.ops import apply_rope, rmsnorm, rope_frequencies
+from ray_tpu.ops import rmsnorm, rope_frequencies
 from ray_tpu.parallel.moe import moe_block
 
 NEG_INF = -1e30
@@ -47,11 +50,13 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int) -> Dict:
     }
 
 
-def _cached_attention(q, k_cache, v_cache, cache_len):
+def _cached_attention(q, k_cache, v_cache, cache_len, window=None):
     """q: [B, Lq, H, D] against cache [B, Lmax, KVH, D] (first cache_len
     valid). GQA via grouped einsum — decode is HBM-bandwidth-bound, so the
     cache must be read at its native size, never repeat-materialized in
-    the hot loop. Causal masking by absolute position."""
+    the hot loop. Causal masking by absolute position; `window`, where a
+    layer has one: a query sees itself and the `window - 1` keys before
+    it (the cache holds every position all the same)."""
     b, lq, h, d = q.shape
     kvh = k_cache.shape[2]
     group = h // kvh
@@ -63,6 +68,8 @@ def _cached_attention(q, k_cache, v_cache, cache_len):
     )
     k_pos = jax.lax.broadcasted_iota(jnp.int32, (lq, lmax), 1)
     valid = (k_pos <= q_pos) & (k_pos < cache_len)
+    if window is not None:
+        valid &= q_pos - k_pos < window
     kf = k_cache.astype(jnp.float32)
     vf = v_cache.astype(jnp.float32)
     if group == 1:  # MHA: plain 4-D einsum (the 5-D form costs ~10%)
@@ -89,29 +96,38 @@ def _forward_with_cache(params, tokens, cache, cfg: TransformerConfig):
     start = cache["length"]
     positions = start + jnp.arange(lq, dtype=jnp.int32)[None, :]
 
+    per_layer = bool(cfg.window_layout or cfg.rope_layout)
+
     def layer(carry, inputs):
         x = carry
-        lp, k_cache_l, v_cache_l = inputs
+        layer_in, k_cache_l, v_cache_l = inputs
+        # A model with per-layer lists: the layer's two flags, whether it
+        # rotates and whether it has the window (`layers_inputs`).
+        lp, rope, window = layer_in if per_layer else (layer_in, None, None)
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
         q, k, v = project_qkv(h, lp, cfg)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
+        q, k = rotate(q, k, cos, sin, positions, rope)
         k_cache_l = jax.lax.dynamic_update_slice(
             k_cache_l, k.astype(k_cache_l.dtype), (0, start, 0, 0)
         )
         v_cache_l = jax.lax.dynamic_update_slice(
             v_cache_l, v.astype(v_cache_l.dtype), (0, start, 0, 0)
         )
-        attn = _cached_attention(q, k_cache_l, v_cache_l, start + lq)
-        x = x + (attn.reshape(b, lq, -1) @ lp["wo"]).astype(x.dtype)
+        attn = _cached_attention(
+            q, k_cache_l, v_cache_l, start + lq,
+            None if window is None else jnp.where(
+                window, cfg.sliding_window_size, lmax + 1))
+        x_in, x = x, x + (attn.reshape(b, lq, -1) @ lp["wo"]).astype(x.dtype)
         h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
         if cfg.num_experts:
-            y, _ = moe_block(h.reshape(b * lq, -1), lp, cfg)
+            y, _ = moe_block(h.reshape(b * lq, -1), lp, cfg,
+                             router_input=router_input(x_in, cfg))
             return x + y.reshape(b, lq, -1), (k_cache_l, v_cache_l)
         return x + dense_mlp(h, lp, cfg), (k_cache_l, v_cache_l)
 
     x, (k_new, v_new) = jax.lax.scan(
-        layer, x, (params["layers"], cache["k"], cache["v"])
+        layer, x, (layers_inputs(params["layers"], cfg), cache["k"],
+                   cache["v"])
     )
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps)
     logits = project_logits(x[:, -1], params, cfg)  # [B, vocab]

@@ -201,6 +201,22 @@ class TransformerConfig:
     block_length: int = 0
     mask_token_id: int = 0
     denoise_steps: int = 1
+    # Attention over a window in some layers (SmallThinker, arXiv:2507.20984,
+    # under its public config's keys): a layer `l` with
+    # `sliding_window_layout[l]` set sees itself and the
+    # `sliding_window_size - 1` positions before it, any other layer
+    # everything behind it (empty, or a size of 0: no layer has a window).
+    # `rope_layout[l]` says whether layer `l` rotates its queries and keys
+    # (empty: `position_embedding_type` decides for every layer). Two
+    # published lists, and two fields, whether or not they are equal.
+    sliding_window_size: int = 0
+    sliding_window_layout: Tuple[int, ...] = ()
+    rope_layout: Tuple[int, ...] = ()
+    # What a layer's router multiplies: "mlp_input", the normed stream
+    # behind attention that its experts multiply too, or "layer_input", the
+    # layer's own input before any norm (SmallThinker's router, which can
+    # so be worked out before attention).
+    router_reads: str = "mlp_input"
 
     @property
     def head_dim(self) -> int:
@@ -248,6 +264,35 @@ class TransformerConfig:
     @property
     def recurrent_layers(self) -> int:
         return len(self.layer_pattern) - self.attention_layers
+
+    @property
+    def window_layout(self) -> Tuple[bool, ...]:
+        """For every layer, whether it attends over the window; empty for a
+        model none of whose layers does."""
+        if not (self.sliding_window_size and any(self.sliding_window_layout)):
+            return ()
+        return tuple(bool(w) for w in self.sliding_window_layout)
+
+    @property
+    def window_layers(self) -> int:
+        return sum(self.window_layout)
+
+    @property
+    def rope_layers(self) -> Tuple[bool, ...]:
+        """For every layer, whether it rotates its queries and keys."""
+        if self.rope_layout:
+            return tuple(bool(r) for r in self.rope_layout)
+        return (self.position_embedding_type == "rope",) * self.n_layers
+
+    @property
+    def layer_period(self) -> int:
+        """The shortest run of layers after which the per-layer lists
+        repeat: 1 for a model whose layers are all alike."""
+        lists = [t for t in (self.window_layout, self.rope_layers) if t]
+        return next(p for p in range(1, self.n_layers + 1)
+                    if self.n_layers % p == 0
+                    and all(t[i] == t[i % p] for t in lists
+                            for i in range(self.n_layers)))
 
 
 # The published strings of a hybrid's attention layers (granitemoehybrid's,
@@ -726,6 +771,8 @@ def _act(cfg: TransformerConfig):
         return jax.nn.silu
     if cfg.activation == "gelu":
         return lambda x: jax.nn.gelu(x, approximate=True)
+    if cfg.activation == "relu":
+        return jax.nn.relu
     raise ValueError(f"unknown activation {cfg.activation!r}")
 
 
@@ -811,14 +858,30 @@ def block_causal(q_pos, k_pos, block_length: int):
             <= q_pos[..., :, None] // block_length)
 
 
-def _attention(cfg: TransformerConfig, q, k, v, mesh, positions):
-    if cfg.block_length:
+def window_causal(q_pos, k_pos, window: int):
+    """Which keys a query of a layer with a window sees, `[.., Lq, Lk]` of
+    positions `q_pos [.., Lq]` and `k_pos [.., Lk]`: itself and the
+    `window - 1` positions before it."""
+    behind = q_pos[..., :, None] - k_pos[..., None, :]
+    return (behind >= 0) & (behind < window)
+
+
+def _attention(cfg: TransformerConfig, q, k, v, mesh, positions,
+               window=None):
+    """`window`: a flag (traced, a layer's own) of a model with window
+    layers, None for any other model."""
+    if cfg.block_length or window is not None:
         # The plain form: the flash kernel's mask is causal and no other.
         pos = (jnp.arange(q.shape[1])[None] if positions is None
                else positions)
+        if cfg.block_length:
+            seen = block_causal(pos, pos, cfg.block_length)
+        else:
+            seen = window_causal(pos, pos, jnp.where(
+                window, cfg.sliding_window_size, q.shape[1] + 1))
         return grouped_attention(
-            q, k.astype(jnp.float32), v.astype(jnp.float32),
-            block_causal(pos, pos, cfg.block_length), cfg.attention_scale)
+            q, k.astype(jnp.float32), v.astype(jnp.float32), seen,
+            cfg.attention_scale)
     if cfg.attn_impl == "ring" and mesh is not None and mesh.shape.get("sp", 1) > 1:
         from ray_tpu.parallel.ring_attention import ring_attention
 
@@ -841,6 +904,28 @@ def dense_mlp(h, lp, cfg: TransformerConfig):
     gate = _act(cfg)((h @ lp["w_gate"]).astype(jnp.float32))
     up = (h @ lp["w_up"]).astype(jnp.float32)
     return ((gate * up).astype(h.dtype)) @ lp["w_down"]
+
+
+def rotate(q, k, cos, sin, positions, rope=None):
+    """q and k with the position embedding applied; `rope`, a flag (traced,
+    a layer's own) of a model with a per-layer list: only where it is set."""
+    rotated = (apply_rope(q, cos, sin, positions),
+               apply_rope(k, cos, sin, positions))
+    if rope is None:
+        return rotated
+    return jnp.where(rope, rotated[0], q), jnp.where(rope, rotated[1], k)
+
+
+def router_input(x, cfg: TransformerConfig):
+    """What a layer's router multiplies, `[tokens, d]` of the layer's input
+    `x [B, L, D]`, for `moe_block`: None where it reads what the experts
+    read (`cfg.router_reads`)."""
+    if cfg.router_reads == "mlp_input":
+        return None
+    if cfg.router_reads != "layer_input":
+        raise ValueError(f"unknown router_reads {cfg.router_reads!r}: "
+                         "expected 'mlp_input' or 'layer_input'")
+    return x.reshape(-1, x.shape[-1])
 
 
 def residual(x, y, cfg: TransformerConfig):
@@ -1067,17 +1152,21 @@ def _latent_layers(params, x, cfg: TransformerConfig, mesh, positions):
 
 
 def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
-    """Build the per-layer body used by lax.scan."""
+    """Build the per-layer body used by lax.scan. A model with per-layer
+    lists (`layers_inputs`) is scanned over its layers and each layer's
+    two flags, whether it rotates and whether it has the window."""
+    per_layer = bool(cfg.window_layout or cfg.rope_layout)
 
-    def body(x, lp):
+    def body(x, inputs):
         # x: [B, L, D]
+        lp, rope, window = inputs if per_layer else (inputs, None, None)
+        x_in = x
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh,
                     spec=_ACT_SPEC)
         b, l, d = h.shape
         q, k, v = project_qkv(h, lp, cfg)
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-        attn = _attention(cfg, q, k, v, mesh, positions)
+        q, k = rotate(q, k, cos, sin, positions, rope)
+        attn = _attention(cfg, q, k, v, mesh, positions, window)
         x = x + (gate_attention(attn.reshape(b, l, -1), h, lp)
                  @ lp["wo"]).astype(x.dtype)
 
@@ -1087,7 +1176,9 @@ def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
             mlp_out = dense_mlp(h, lp, cfg)
             routing = None
         else:
-            mlp_flat, routing = moe_block(h.reshape(b * l, d), lp, cfg)
+            mlp_flat, routing = moe_block(
+                h.reshape(b * l, d), lp, cfg,
+                router_input=router_input(x_in, cfg))
             mlp_out = mlp_flat.reshape(b, l, d)
         x = x + mlp_out
         return x, routing
@@ -1110,6 +1201,20 @@ def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
                 "expected 'full', 'dots', or 'dots_nobatch'"
             )
     return body
+
+
+def layers_inputs(layers: Dict, cfg: TransformerConfig):
+    """What `_layer_fn`'s body is scanned over: the stacked layers, and for
+    a model with per-layer lists each layer's two flags beside them."""
+    if not (cfg.window_layout or cfg.rope_layout):
+        return layers
+    window = cfg.window_layout or (False,) * cfg.n_layers
+    for name, flags in (("rope_layout", cfg.rope_layers),
+                        ("sliding_window_layout", window)):
+        if len(flags) != cfg.n_layers:
+            raise ValueError(f"{name} names {len(flags)} layers, n_layers "
+                             f"is {cfg.n_layers}")
+    return layers, jnp.asarray(cfg.rope_layers), jnp.asarray(window)
 
 
 def _aux_loss(routing, tokens: int):
@@ -1140,7 +1245,8 @@ def forward(
     else:
         cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
         body = _layer_fn(cfg, mesh, cos, sin, positions)
-        x, routing = jax.lax.scan(body, x, params["layers"])
+        x, routing = jax.lax.scan(body, x,
+                                  layers_inputs(params["layers"], cfg))
     aux = _aux_loss(routing, tokens.size)
     x = rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh,
                 spec=_ACT_SPEC)
@@ -1196,11 +1302,13 @@ def forward_pipelined(
             "pipeline parallelism currently supports dense layers only "
             "(the MoE aux loss does not thread through the pp schedule)"
         )
-    if cfg.layer_pattern or cfg.kv_lora_rank:
+    if (cfg.layer_pattern or cfg.kv_lora_rank or cfg.window_layout
+            or cfg.rope_layout):
         raise ValueError(
             "pipeline parallelism needs stages of like layers: stacks of "
             "unlike kinds (a hybrid's mixers, dense layers before expert "
-            "layers) do not split into pp stages")
+            "layers, window layers among full ones) do not split into pp "
+            "stages")
 
     x = _embed_tokens(params, tokens, cfg)
     cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)

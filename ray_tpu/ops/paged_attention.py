@@ -24,6 +24,14 @@ On other backends the plain form runs: the slot's whole table gathered,
 cast and scored, masked by the count (`grouped_attention`, which the
 prefill program uses too).
 
+A RING pool (a model with layers that attend over a window: `[window
+layers, 1 + slots * pages a ring, page_size, kv_heads * head_dim]`, a slot's
+own run of pages written round and round) is read by the same kernel under
+a name of its own, `window_decode_attention`: the table it is handed starts
+at the page of the window's oldest position and follows the ring from
+there, and the rows of that first page that have left the window are masked
+(`skip`), so that it fetches the pages that hold the window and no others.
+
 A LATENT pool `[layers, pages, page_size, row]` (DeepSeek-V2/V3's: a row
 is a token's latent and its one rope key, nothing a head, and there is
 no pool of values) has a kernel of its own, `latent_decode_attention`,
@@ -107,11 +115,13 @@ def kernel_takes(k_pool, head_dim: int) -> bool:
 
 def _kernel(layer_ref, rows_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
             k_buf, v_buf, sems, m_ref, l_ref, acc_ref, *, scale: float,
-            ppb: int):
+            ppb: int, skip_ref=None):
     """Every slot in turn, its blocks of `ppb` pages inside that.
 
     layer_ref [1], rows_ref [S] (rows a slot attends to) and tables_ref
-    [S * pages_per_slot] are in SMEM. q_ref, o_ref [S, G, R, W] float32 in
+    [S * pages_per_slot] are in SMEM; so is skip_ref [S], where the caller
+    has one (`_window_kernel`): the rows at the table's start that a slot
+    fetches with their page and does not attend to. q_ref, o_ref [S, G, R, W] float32 in
     VMEM: W lanes hold one head, or several narrow ones side by side, each
     with its own query rows and zeros in the others' lanes, so that one
     product over W lanes gives every head its own scores; G such groups
@@ -169,6 +179,8 @@ def _kernel(layer_ref, rows_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
         k_pos = b * block_rows + jax.lax.broadcasted_iota(
             jnp.int32, (q_ref.shape[2], width), 1)
         live = k_pos < rows_ref[s]
+        if skip_ref is not None:
+            live = jnp.logical_and(live, k_pos >= skip_ref[s])
         heads = [(buf, pl.ds(0, width), pl.ds(g * lanes, lanes))
                  for g in range(groups)]
         q = q_ref[s].astype(k_buf.dtype)
@@ -249,6 +261,14 @@ def _kernel(layer_ref, rows_ref, tables_ref, q_ref, k_hbm, v_hbm, o_ref,
     jax.lax.while_loop(lambda state: state[0] < slots, block, (*first, 0))
 
 
+def _window_kernel(layer_ref, rows_ref, tables_ref, skip_ref, *refs,
+                   **static):
+    """`_kernel` with a fourth scalar operand: a slot attends to rows
+    `[skip, rows)` from its table's start."""
+    _kernel(layer_ref, rows_ref, tables_ref, *refs, skip_ref=skip_ref,
+            **static)
+
+
 def _pack_queries(q, kv_heads: int, lanes: int):
     """q [S, H, D] as the kernel reads it, [S, G, R, W] float32: group g
     holds `W // D` KV heads, the queries of its a-th head in rows
@@ -275,8 +295,11 @@ def _unpack_outputs(out, h: int, d: int):
                       jnp.eye(per, dtype=out.dtype)).reshape(s_, h, d)
 
 
-def _pallas(q, k_pool, v_pool, layer, block_tables, rows, *, scale: float,
-            interpret: bool = False):
+def _pallas(q, k_pool, v_pool, layer, block_tables, rows, skip=None, *,
+            scale: float, interpret: bool = False):
+    """The kernel over every slot's rows `[0, rows)` from its table's
+    start, `paged_decode_attention` in a trace; with `skip [S]`, over rows
+    `[skip, rows)`, and `window_decode_attention` there."""
     from jax.experimental import pallas as pl
     from jax.experimental.pallas import tpu as pltpu
 
@@ -290,10 +313,14 @@ def _pallas(q, k_pool, v_pool, layer, block_tables, rows, *, scale: float,
     whole = pl.BlockSpec(memory_space=pltpu.VMEM)
     in_place = pl.BlockSpec(memory_space=pl.ANY)
     buffers = pltpu.VMEM((2, ppb * page_size, width), k_pool.dtype)
+    scalars = [jnp.reshape(layer, (1,)), rows, block_tables.reshape(-1)]
+    if skip is not None:
+        scalars.append(skip)
     out = pl.pallas_call(
-        functools.partial(_kernel, scale=scale, ppb=ppb),
+        functools.partial(_kernel if skip is None else _window_kernel,
+                          scale=scale, ppb=ppb),
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=3,
+            num_scalar_prefetch=len(scalars),
             grid=(1,),
             in_specs=[whole, in_place, in_place],
             out_specs=whole,
@@ -306,9 +333,9 @@ def _pallas(q, k_pool, v_pool, layer, block_tables, rows, *, scale: float,
         ),
         out_shape=jax.ShapeDtypeStruct(packed.shape, jnp.float32),
         interpret=pltpu.InterpretParams() if interpret else False,
-        name="paged_decode_attention",
-    )(jnp.reshape(layer, (1,)).astype(jnp.int32), rows.astype(jnp.int32),
-      block_tables.reshape(-1).astype(jnp.int32), packed, k_pool, v_pool)
+        name=("paged_decode_attention" if skip is None
+              else "window_decode_attention"),
+    )(*(a.astype(jnp.int32) for a in scalars), packed, k_pool, v_pool)
     return _unpack_outputs(out, h, d).astype(q.dtype)
 
 
@@ -335,6 +362,65 @@ def paged_decode_attention(q: jax.Array, k_pool: jax.Array,
     heads, pool = P(None, "tp", None), P(None, None, None, "tp")
     return per_shard(kernel, mesh, (heads, pool, pool, P(), P(), P()),
                      heads)(q, k_pool, v_pool, layer, block_tables, rows)
+
+
+def ring_tables(pool, slots: int):
+    """The pages of every slot's ring in a ring pool `[layers, 1 + slots *
+    pages a ring, page_size, ..]`, `[slots, pages a ring]`: each slot's own
+    run, in the ring's order, behind the NULL page. Nothing allocates
+    them, so nothing stores them."""
+    per_ring = (pool.shape[1] - 1) // slots
+    return 1 + (jnp.arange(slots, dtype=jnp.int32)[:, None] * per_ring
+                + jnp.arange(per_ring, dtype=jnp.int32)[None])
+
+
+def window_decode_attention(q: jax.Array, k_ring: jax.Array,
+                            v_ring: jax.Array, layer, newest: jax.Array,
+                            window: int, scale: float,
+                            use_pallas: Optional[bool] = None,
+                            interpret: bool = False) -> jax.Array:
+    """Every slot's one query against the last `window` positions of its
+    RING in one layer of a ring pool: q [S, H, D]; k_ring, v_ring [layers,
+    1 + S * pages a ring, page_size, KVH * D], position `p` of a slot in
+    row `p mod ring` of its own run of pages (`ring_tables`); `layer` a
+    scalar; newest [S], the position of the newest row a slot holds, the
+    query's own (-1: the slot attends to nothing, and its result is finite
+    and means nothing). The slot attends to positions `max(0, newest -
+    window + 1) .. newest`, which the ring must hold (`window` rows and a
+    page more). Returns [S, H, D] in q's dtype.
+
+    The kernel is `paged_decode_attention`'s under a name of its own, over
+    a table that starts at the page of the window's oldest position and
+    follows the ring round from there: it fetches the pages that hold the
+    window and no others, and masks the rows of the first page that have
+    left the window. Elsewhere the plain form: the whole ring gathered,
+    each row's position worked out from `newest`, masked by the window.
+    One chip: nothing here shards a ring (the engine refuses a model with
+    window layers under `tp` > 1)."""
+    s_, _, hd = q.shape
+    page_size = k_ring.shape[2]
+    tables = ring_tables(k_ring, s_)
+    per_ring = tables.shape[1]
+    ring = per_ring * page_size
+    live = newest >= 0
+    if use_pallas is None:
+        use_pallas = jax.default_backend() == "tpu"
+    if not ((use_pallas or interpret) and kernel_takes(k_ring, hd)):
+        at = jnp.arange(ring, dtype=jnp.int32)[None]
+        behind = (newest[:, None] - at) % ring       # of the row's position
+        valid = live[:, None] & (behind <= newest[:, None]) & (behind < window)
+        k, v = (pool[layer, tables].reshape(s_, ring, -1, hd).astype(
+            jnp.float32) for pool in (k_ring, v_ring))
+        return grouped_attention(q[:, None], k, v, valid[:, None],
+                                 scale)[:, 0]
+    oldest = jnp.maximum(newest - window + 1, 0)
+    turned = jnp.take_along_axis(
+        tables, (oldest[:, None] // page_size
+                 + jnp.arange(per_ring, dtype=jnp.int32)[None]) % per_ring, 1)
+    skip = jnp.where(live, oldest % page_size, 0)
+    rows = jnp.where(live, skip + newest - oldest + 1, 0)
+    return _pallas(q, k_ring, v_ring, layer, turned, rows, skip, scale=scale,
+                   interpret=interpret)
 
 
 def latent_kernel_takes(pool, heads: int, rank: int) -> bool:

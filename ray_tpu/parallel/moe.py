@@ -194,8 +194,13 @@ def route(logits: jax.Array, cfg, bias: Optional[jax.Array] = None):
 
 
 def moe_block(h: jax.Array, lp: Dict, cfg,
-              layer: Optional[jax.Array] = None) -> Tuple[jax.Array, Dict]:
+              layer: Optional[jax.Array] = None,
+              router_input: Optional[jax.Array] = None
+              ) -> Tuple[jax.Array, Dict]:
     """The expert half of a layer on normed activations `h [tokens, d]`.
+    The router multiplies `router_input [tokens, d]` where a caller hands
+    one (a model whose router reads another tensor than its experts do:
+    `cfg.router_reads`), else `h`.
 
     `lp` holds the layer's `router [d, E]` and the expert stacks `w_gate`,
     `w_up [E, d, ff]`, `w_down [E, ff, d]`. A caller that walks the depth
@@ -226,7 +231,8 @@ def moe_block(h: jax.Array, lp: Dict, cfg,
     k, num_experts = cfg.experts_per_token, cfg.num_experts
     held = cfg.experts_held or num_experts
     with jax.named_scope("moe.route"):
-        logits = jnp.dot(h, lp["router"], preferred_element_type=jnp.float32)
+        logits = jnp.dot(h if router_input is None else router_input,
+                         lp["router"], preferred_element_type=jnp.float32)
         probs, weights, chosen = route(logits, cfg, lp.get("router_bias"))
         flat = chosen.reshape(-1)
         counts = jnp.bincount(flat, length=num_experts).astype(jnp.int32)

@@ -31,6 +31,14 @@ scheduling, built TPU-first:
     block's commit pass). A slot's block lives on the device and its phase
     is counted on the host; prefill covers a prompt's whole blocks and
     emits no token. `stats()["diffusion"]` counts the passes.
+  * A model with window layers (`cfg.sliding_window_layout`: SmallThinker's
+    three layers in four) keeps those layers' keys and values in a RING a
+    slot (`paged_kv.init_ring_pool`: the window and one prefill chunk,
+    written at `position mod ring`), beside the full layers' pages and
+    tables. A slot owns its ring: admission reserves the full layers' pages
+    by the request's footprint and nothing for the ring. No prefix cache
+    (what lay in a ring at a prefix's end is gone once the slot runs on),
+    one row of a pass a prompt, one chip.
   * Static shapes everywhere: the decode step is jitted ONCE for the
     slot count and prompts prefill in fixed-size CHUNKS, one PASS of
     them between decode steps: a pass is one program over one row of a
@@ -735,6 +743,12 @@ class ContinuousBatchingEngine:
                     "row of its pool is nothing a head, and is not sharded "
                     f"over tp={mesh.shape['tp']}"
                 )
+            if cfg.window_layout and mesh.shape["tp"] > 1:
+                raise ValueError(
+                    "a model with window layers serves on one chip: its "
+                    "ring of pages a slot is not sharded over "
+                    f"tp={mesh.shape['tp']}"
+                )
             if cfg.kv_lora_rank and mesh.shape.get("pp", 1) > 1:
                 raise ValueError(
                     "stacks of unlike layers (dense before expert layers) "
@@ -755,9 +769,12 @@ class ContinuousBatchingEngine:
                     f"passes (denoise_steps {cfg.denoise_steps}), and "
                     f"divide the prefill chunk ({self.prefill_chunk}) and "
                     f"the page ({self.page_size})")
-            if cfg.layer_pattern or cfg.kv_lora_rank:
+            if cfg.layer_pattern or cfg.kv_lora_rank or cfg.window_layout:
                 raise ValueError("block diffusion serves over pages of "
                                  "keys and values a head alone")
+        if cfg.window_layout and (cfg.layer_pattern or cfg.kv_lora_rank):
+            raise ValueError("window layers beside recurrent layers or "
+                             "latent attention are not written")
         self._pages_per_slot = -(-max_len // self.page_size)
         self.kv_pages = int(kv_pages or rcfg.serve_kv_pages or 0)
         if self.kv_pages <= 0:
@@ -766,10 +783,15 @@ class ContinuousBatchingEngine:
         self._pool = paged_kv.PagePool(self.kv_pages, self.page_size)
         # A model with recurrent layers builds no prefix cache: pages
         # below a shared prefix are of no use without the recurrent state
-        # at that boundary, and no one keeps snapshots of it.
+        # at that boundary, and no one keeps snapshots of it. Nor does a
+        # model with window layers: what lay in a slot's ring at a
+        # prefix's end is gone once the slot has run on. Either holds
+        # something a slot that no page holds, so a prompt takes one row
+        # of a prefill pass (`_advance_prefills`).
+        self._slot_state = bool(cfg.layer_pattern or cfg.window_layout)
         self._prefix_cache = (
             paged_kv.PrefixCache(self._pool)
-            if rcfg.serve_prefix_cache and not cfg.layer_pattern else None
+            if rcfg.serve_prefix_cache and not self._slot_state else None
         )
         self._prefix_wanted = bool(rcfg.serve_prefix_cache)
         self._prefix_reuse_skipped = 0
@@ -802,6 +824,8 @@ class ContinuousBatchingEngine:
         # reads them from another thread, and an array no program
         # consumes can be fetched at any time. Written by the loop thread
         # only.
+        # `ring`: a model with window layers' second pool, part of the
+        # cache and donated with it.
         self._tail: Dict = {}
         if cfg.num_experts:
             self._tail["moe"] = jax.tree.map(
@@ -810,6 +834,11 @@ class ContinuousBatchingEngine:
             self._tail["rec"] = cache["rec"]
             self._tail["rec_count"] = paged_kv.init_ssm_counters()
             self._rec_bytes = sum(a.nbytes for a in cache["rec"].values())
+        if cfg.window_layout:
+            self._tail["ring"] = cache["ring"]
+            # Rows of a slot's ring, for stats()["attention"].
+            self._ring_rows = (cache["ring"]["k"].shape[1] - 1
+                               ) // num_slots * self.page_size
         self._bt_dev = cache["block_tables"]
         self._decode_sampled = jax.jit(
             lambda p, t, k, v, ln, a, bt, tp, tk, tpp, key, **tail:
@@ -817,21 +846,21 @@ class ContinuousBatchingEngine:
                 p, t, k, v, ln, a, bt, tp, tk, tpp, key, cfg, max_len,
                 mesh, **tail,
             ),
-            donate_argnums=(2, 3), donate_argnames=("rec",),
+            donate_argnums=(2, 3), donate_argnames=("rec", "ring"),
         )
         self._decode_greedy = jax.jit(
             lambda p, t, k, v, ln, a, bt, **tail: paged_kv.decode_paged(
                 p, t, k, v, ln, a, bt, None, None, None, None, cfg,
                 max_len, mesh, **tail,
             ),
-            donate_argnums=(2, 3), donate_argnames=("rec",),
+            donate_argnums=(2, 3), donate_argnames=("rec", "ring"),
         )
         self._prefill = jax.jit(
             lambda p, t, n, s, o, k, v, ln, bt, **tail:
             paged_kv.prefill_chunk_paged(
                 p, t, n, s, o, k, v, ln, bt, cfg, max_len, mesh, **tail
             ),
-            donate_argnums=(5, 6), donate_argnames=("rec",),
+            donate_argnums=(5, 6), donate_argnames=("rec", "ring"),
         )
         if self._block:
             # A slot's block on the device (`paged_kv.init_block_state`),
@@ -938,6 +967,11 @@ class ContinuousBatchingEngine:
         self._attn_rows_read = 0
         self._attn_rows_held = 0
         self._attn_rows_live = 0
+        # A model with window layers: the rows its window layers were
+        # given to read, and what the same layers would have been given
+        # without a window.
+        self._attn_window_read = 0
+        self._attn_window_unwindowed = 0
         # The loop's phase ledger (loop-thread-only) and the copy the
         # loop publishes under the lock after each turn for stats().
         self._phase = _PhaseLedger()
@@ -1184,7 +1218,37 @@ class ContinuousBatchingEngine:
         return paged_kv.init_paged_cache(
             self.cfg, self.num_slots, self.kv_pages, self.page_size,
             self._pages_per_slot, mesh=self.mesh,
+            prefill_chunk=self.prefill_chunk,
         )
+
+    def _count_attention_locked(self, reach):
+        """stats()["attention"] advanced by the decode step about to be
+        dispatched, in which each decoding slot attends to `reach` rows,
+        the one it writes among them: the rows in the pages it is given to
+        read, the rows the pool holds and the rows the live slots hold. A
+        model with window layers: the three are summed over its layers, a
+        full layer's as any model's, a window layer's read the lesser of
+        the slot's rows and the window out of a ring a slot; and the
+        window layers' rows read are counted again apart, beside what the
+        same layers would have read without a window."""
+        ps, cfg = self.page_size, self.cfg
+        live = int(reach.sum())
+        read = int((-(-reach // ps)).sum()) * ps
+        held = self.num_slots * self.max_len
+        if not cfg.window_layout:
+            self._attn_rows_live += live
+            self._attn_rows_read += read
+            self._attn_rows_held += held
+            return
+        n_window = cfg.window_layers
+        n_full = cfg.n_layers - n_window
+        in_window = int(np.minimum(reach, cfg.sliding_window_size).sum())
+        self._attn_rows_live += n_full * live + n_window * in_window
+        self._attn_rows_read += n_full * read + n_window * in_window
+        self._attn_rows_held += n_full * held + (
+            n_window * self.num_slots * self._ring_rows)
+        self._attn_window_read += n_window * in_window
+        self._attn_window_unwindowed += n_window * live
 
     def _ssm_stats(self) -> Dict:
         """stats()["ssm"]: the recurrent pool's size (whatever its kind
@@ -1341,6 +1405,10 @@ class ContinuousBatchingEngine:
             "prefix_hit_rate": (self._prefix_hits / lookups
                                 if lookups else None),
             "prefill_tokens_skipped": self._prefill_tok_skipped,
+            # Admissions whose prompt filled a page and would have been
+            # looked up in a prefix cache, had this model one (a model
+            # with recurrent or window layers has none).
+            "prefix_reuse_skipped": self._prefix_reuse_skipped,
             "bt_uploads": self._bt_uploads,
             "chaos_held_pages": len(self._chaos_held),
             "roots": (self._prefix_cache.roots()
@@ -1366,9 +1434,10 @@ class ContinuousBatchingEngine:
             )
         pages = self._pages_per_slot
         cache = paged_kv.init_paged_cache(
-            self.cfg, 1, pages + 1, self.page_size, pages, mesh=self.mesh)
+            self.cfg, 1, pages + 1, self.page_size, pages, mesh=self.mesh,
+            prefill_chunk=self.prefill_chunk)
         k, v, lengths = cache["k"], cache["v"], cache["lengths"]
-        tail = {name: cache["rec"] if name == "rec" else acc
+        tail = {name: cache.get(name, acc)
                 for name, acc in self._tail.items()}
         table = self._replicated(np.arange(1, pages + 1, dtype=np.int32)[None])
         c = self.prefill_chunk
@@ -1476,11 +1545,16 @@ class ContinuousBatchingEngine:
                 # slot's length in whole pages, a latent pool's too), rows
                 # the pool holds (slots x max_len a step) and rows the
                 # live slots hold, from host mirrors: nothing is fetched
-                # for it.
+                # for it (`_count_attention_locked`; a model with window
+                # layers also has the two counts of those layers alone).
                 "attention": {
                     "decode_rows_read": self._attn_rows_read,
                     "decode_rows_held": self._attn_rows_held,
                     "decode_rows_live": self._attn_rows_live,
+                    **({"window_rows_read": self._attn_window_read,
+                        "window_rows_unwindowed":
+                            self._attn_window_unwindowed}
+                       if self.cfg.window_layout else {}),
                 },
                 # Where the loop's time goes (EQuARX discipline — you
                 # cannot shrink a step you cannot decompose). _total
@@ -1646,7 +1720,7 @@ class ContinuousBatchingEngine:
         p_len = len(h.prompt)
         hashes = (paged_kv.page_hashes(h.prompt, ps)
                   if self._prefix_cache is not None else [])
-        if self.cfg.layer_pattern and self._prefix_wanted and p_len >= ps:
+        if self._slot_state and self._prefix_wanted and p_len >= ps:
             self._prefix_reuse_skipped += 1
         shared = self._prefix_cache.match(hashes) if hashes else []
         # Footprint: prompt + generated tokens + one margin row for the
@@ -1758,7 +1832,7 @@ class ContinuousBatchingEngine:
             for e in self._prefilling.values()
         ]
         owing = []  # (slot, entry, the offsets of the chunks it owes)
-        per_slot = 1 if self.cfg.layer_pattern else self._pass_rows[-1]
+        per_slot = 1 if self._slot_state else self._pass_rows[-1]
         now_wall = time.time()
         for slot, entry in list(self._prefilling.items()):
             h = entry["h"]
@@ -2016,10 +2090,7 @@ class ContinuousBatchingEngine:
                 else:
                     self._rows_host[live] += 1
                     reach = self._rows_host[live]
-                self._attn_rows_live += int(reach.sum())
-                pages = -(-reach // self.page_size)
-                self._attn_rows_read += int(pages.sum()) * self.page_size
-                self._attn_rows_held += self.num_slots * self.max_len
+                self._count_attention_locked(reach)
         new_inflight = None
         if snapshot:
             if self._params_dirty:
@@ -2242,8 +2313,9 @@ class ContinuousBatchingEngine:
                     cache = self._fresh_cache()
                     self._k, self._v = cache["k"], cache["v"]
                     self._lengths = cache["lengths"]
-                    if "rec" in cache:
-                        self._tail["rec"] = cache["rec"]
+                    for name in ("rec", "ring"):
+                        if name in cache:
+                            self._tail[name] = cache[name]
                     # Every outstanding page reference pointed into the
                     # dead cache: reset the allocator, drop the prefix
                     # cache WITHOUT releasing (the refs are void), zero

@@ -53,6 +53,17 @@ blocks of every slot's pages with a running softmax; a prefill pass
 keeps that loop, each block expanded into every head's keys and values.
 The host half counts pages, not what a page holds, and is the same.
 
+A model with layers that attend over a WINDOW (SmallThinker's three in
+four) keeps those layers' rows in a second pool, a RING of pages a slot
+(`init_ring_pool`): the window and one prefill chunk, position `p` at row `p
+mod ring` of the slot's own run of pages. Nothing allocates a ring and
+nothing reserves it by a request's length; the full layers keep the tables
+and the pool above. A decode step's query reads the last `window` rows of
+its slot's ring in place (`ops.window_decode_attention`), a prefill chunk's
+queries the slot's whole ring, each row at the position it holds once the
+chunk is written. `_scan_layers` walks such a model a period of its
+per-layer lists at a time, both pools in its carry.
+
 Page 0 is reserved as the NULL/scratch page: block-table entries
 default to it, inactive-slot decode writes park in it, and prefill
 padding rows drop into it — it is never read unmasked, so its
@@ -92,6 +103,7 @@ from ray_tpu.models.transformer import (
     project_qkv,
     residual,
     rope_tables,
+    router_input,
 )
 from ray_tpu.ops import apply_rope, rmsnorm
 from ray_tpu.ops.paged_attention import (
@@ -99,6 +111,8 @@ from ray_tpu.ops.paged_attention import (
     latent_decode_attention,
     latent_kernel_takes,
     paged_decode_attention,
+    ring_tables,
+    window_decode_attention,
 )
 from ray_tpu.parallel.moe import EXPERT_LEAVES, moe_block
 
@@ -310,9 +324,35 @@ def prefix_route_key(tokens, page_size: int) -> Optional[str]:
     ).hexdigest()
 
 
+def ring_pages(cfg: TransformerConfig, page_size: int, pages_per_slot: int,
+               prefill_chunk: int) -> int:
+    """Pages of a slot's ring in a window layer: the window and, beyond
+    it, the longer of one prefill chunk and a page (a chunk's rows are all
+    written before its first query reads, and the window's oldest page is
+    in it only in part), in whole pages; no more than a slot's table has,
+    where nothing wraps."""
+    rows = cfg.sliding_window_size + max(prefill_chunk, page_size)
+    return min(-(-rows // page_size), pages_per_slot)
+
+
+def init_ring_pool(cfg: TransformerConfig, slots: int, page_size: int,
+                   pages: int) -> Dict:
+    """The second pool of a model with window layers: keys and values `[window
+    layers, 1 + slots * pages, page_size, kv_heads * head_dim]`, a RING of
+    `pages` pages a slot and window layer behind the NULL page, position
+    `p` in row `p mod (pages * page_size)` of the slot's own run
+    (`ops.paged_attention.ring_tables`). A slot owns its ring: nothing is
+    reserved by a request's length and nothing grows. It rides in the layer
+    walk's carry beside the full layers' pages and is donated with them."""
+    shape = (cfg.window_layers, 1 + slots * pages, page_size,
+             cfg.n_kv_heads * cfg.head_dim)
+    return {"k": jnp.zeros(shape, dtype=cfg.dtype),
+            "v": jnp.zeros(shape, dtype=cfg.dtype)}
+
+
 def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
                      page_size: int, pages_per_slot: int,
-                     mesh=None) -> Dict:
+                     mesh=None, prefill_chunk: int = 0) -> Dict:
     """Device state of the paged cache: the page pool, per-slot lengths,
     and the block table (all entries NULL_PAGE). KV heads shard over
     "tp"; everything else is replicated. A hybrid's pool has its
@@ -321,8 +361,12 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
     latent attention has ONE pool, under `k`: a row is a token's latent and
     its rope key side by side and zeros up to whole lanes
     (`latent_row_width`), nothing a head, and `v` is None, an argument
-    without a buffer (the engine refuses such a pool under `tp` > 1)."""
-    kv_layers = cfg.attention_layers if cfg.layer_pattern else cfg.n_layers
+    without a buffer (the engine refuses such a pool under `tp` > 1). A
+    model with window layers has its full layers alone in the pool, indexed
+    by their own count, and the cache gains `ring`, the window layers'
+    (`init_ring_pool`, sized for chunks of `prefill_chunk`)."""
+    kv_layers = (cfg.attention_layers if cfg.layer_pattern
+                 else cfg.n_layers - cfg.window_layers)
     # A row's heads side by side, one layout for every program that
     # touches the pool: a page is then whole tiles whatever a head's
     # width, which is how the decode kernel fetches it, and a head is a
@@ -356,6 +400,9 @@ def init_paged_cache(cfg: TransformerConfig, slots: int, num_pages: int,
         }
     if cfg.layer_pattern:
         cache["rec"] = init_recurrent_pool(cfg, slots)
+    if cfg.window_layout:
+        cache["ring"] = init_ring_pool(cfg, slots, page_size, ring_pages(
+            cfg, page_size, pages_per_slot, prefill_chunk))
     return cache
 
 
@@ -387,9 +434,10 @@ def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
     layer's output, its caches and, for a layer with a router among its
     leaves, the assignments each expert received `[E]` (else None); `layer` is
     `moe_block`'s: the index at which `lp`'s expert stacks, then the
-    whole model's, are read in place. `cos` is None for a model without
-    a position embedding."""
+    whole model's, are read in place. `cos` is None for a model, or a
+    layer, without a position embedding."""
     b, l = x.shape[:2]
+    read_by_router = router_input(x, cfg)
     h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh)
     q, k, v = project_qkv(h, lp, cfg)
     if cos is not None:
@@ -400,7 +448,8 @@ def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
     x = residual(x, (attn @ lp["wo"]).astype(x.dtype), cfg)
     h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh)
     if "router" in lp:
-        y, routing = moe_block(h.reshape(b * l, -1), lp, cfg, layer)
+        y, routing = moe_block(h.reshape(b * l, -1), lp, cfg, layer,
+                               read_by_router)
         return (x + y.reshape(b, l, -1), k_cache_l, v_cache_l,
                 routing["counts"])
     return residual(x, dense_mlp(h, lp, cfg), cfg), k_cache_l, v_cache_l, None
@@ -757,7 +806,7 @@ def _with_recurrent(out, rec, count, **added):
 
 
 def _scan_layers(params, x, k_cache, v_cache, attend, cfg, cos, sin,
-                 positions, mesh=None):
+                 positions, mesh=None, ring=None, attend_ring=None):
     """Every layer in turn, the whole KV cache `[layers, ...]` riding in
     the scan's carry: the one way a step threads its cache through the
     layers. `attend(i, kc, vc, q, k, v)` is `_layer_body`'s with the
@@ -773,27 +822,62 @@ def _scan_layers(params, x, k_cache, v_cache, attend, cfg, cos, sin,
     The expert stacks of a model that has them stay out of the scan for
     the same reason: every layer reads them whole at its own index
     (`moe_block`), where a layer sliced out for a grouped matmul would be
-    copied first. Also returns the assignments each layer's experts
-    received in this call `[layers, E]`, None for a dense model."""
+    copied first.
+
+    The scan's body is a PERIOD of the model's per-layer lists
+    (`cfg.layer_period`: 1 where the layers are all alike, 4 for full,
+    window, window, window), the period's layers written out in it, each
+    reading its weights where they lie in the stacks (`at_layer`, which
+    compiles to what a scan's own slice of its inputs does), so that each
+    layer's kind is known where it is traced and no pool passes through
+    a branch that does not touch it: a layer without rope gets no
+    `cos`; a window layer gets `ring = {"k", "v"}`, the window layers'
+    pool, for its cache and `attend_ring` for its `attend`. Either pool is
+    indexed by the layer's count among its own kind. Every buffer is still
+    one loop carry from the first layer to the last.
+
+    Returns x, the caches, `ring` (None as it came) and the assignments
+    each layer's experts received in this call `[layers, E]`, None for a
+    dense model."""
     layers, experts = params["layers"], {}
     if cfg.num_experts:
         experts = {n: layers[n] for n in EXPERT_LEAVES}
         layers = {n: w for n, w in layers.items() if n not in experts}
+    period = cfg.layer_period
+    window = (cfg.window_layout or (False,) * period)[:period]
+    rotates = cfg.rope_layers[:period]
+    # A layer's place among its own kind within a period, and how many of
+    # each kind a period has.
+    before = [sum(w == window[t] for w in window[:t]) for t in range(period)]
+    of_kind = {w: sum(v == w for v in window) for w in (False, True)}
 
-    def layer(carry, inputs):
-        x, kc, vc = carry
-        lp, i = inputs
-        x, kc, vc, counts = _layer_body(
-            x, {**lp, **experts}, kc, vc, cfg, cos, sin, positions,
-            functools.partial(attend, i), mesh, i if experts else None,
-        )
-        return (x, kc, vc), counts
+    def run(carry, j):
+        x, kc, vc, ring = carry
+        counts = []
+        for t in range(period):
+            at = j * of_kind[window[t]] + before[t]
+            i = j * period + t
+            args = (cfg, cos if rotates[t] else None, sin, positions)
+            lp = {**at_layer(layers, i), **experts}
+            if window[t]:
+                x, *pools, got = _layer_body(
+                    x, lp, ring["k"], ring["v"], *args,
+                    functools.partial(attend_ring, at), mesh,
+                    i if experts else None)
+                ring = dict(zip(("k", "v"), pools))
+            else:
+                x, kc, vc, got = _layer_body(
+                    x, lp, kc, vc, *args, functools.partial(attend, at),
+                    mesh, i if experts else None)
+            counts.append(got)
+        return (x, kc, vc, ring), (jnp.stack(counts) if experts else None)
 
-    index = jnp.arange(k_cache.shape[0], dtype=jnp.int32)
-    (x, k_cache, v_cache), counts = jax.lax.scan(
-        layer, (x, k_cache, v_cache), (layers, index)
-    )
-    return x, k_cache, v_cache, counts
+    runs = cfg.n_layers // period
+    (x, k_cache, v_cache, ring), counts = jax.lax.scan(
+        run, (x, k_cache, v_cache, ring), jnp.arange(runs, dtype=jnp.int32))
+    if counts is not None:
+        counts = counts.reshape((cfg.n_layers,) + counts.shape[2:])
+    return x, k_cache, v_cache, ring, counts
 
 
 def init_routing_counters(cfg: TransformerConfig) -> Dict:
@@ -989,7 +1073,7 @@ def _pick_tokens(logits, temps, top_ks, top_ps, key):
 def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
                  block_tables, temps, top_ks, top_ps, key,
                  cfg: TransformerConfig, max_len: int, mesh=None, moe=None,
-                 rec=None, rec_count=None):
+                 rec=None, rec_count=None, ring=None):
     """One decode step for every slot at once, K/V read through the
     block table.
 
@@ -1000,7 +1084,12 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
     advanced accumulator comes back as a fifth result (`_count_routing`).
     With `rec`, a hybrid's recurrent pool (donate it), the pool and then
     the advanced `rec_count` (`init_ssm_counters`) come back last: a slot
-    that is not active keeps its state and its convolution inputs.
+    that is not active keeps its state and its convolution inputs. With
+    `ring`, a model with window layers' second pool (donate it), the pool
+    comes back last: `k_pages` and `v_pages` are then the full layers'
+    alone, and a window layer writes the slot's row at `lengths mod ring`
+    of the slot's ring and attends to the window's last rows there
+    (`ops.window_decode_attention`).
 
     Each active slot writes its new K/V row into page
     `block_tables[slot, lengths[slot] // page_size]` at row
@@ -1042,6 +1131,22 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
             mesh=mesh)
         return kc, vc, attn[:, None]
 
+    attend_ring = None
+    if ring is not None:
+        ring_bt = ring_tables(ring["k"], s_)
+        ring_pages_w = jnp.where(
+            active, ring_bt[slot_idx, pos_w // ps % ring_bt.shape[1]],
+            NULL_PAGE)
+        newest = jnp.where(active, pos_w, -1)
+
+        def attend_ring(i, kc, vc, q, k, v):
+            kc = kc.at[i, ring_pages_w, rows_w].set(_rows(k[:, 0], kc))
+            vc = vc.at[i, ring_pages_w, rows_w].set(_rows(v[:, 0], vc))
+            attn = window_decode_attention(
+                q[:, 0], kc, vc, i, newest, cfg.sliding_window_size,
+                cfg.attention_scale)
+            return kc, vc, attn[:, None]
+
     if cfg.kv_lora_rank:
         # One row a slot into the one pool, then the absorbed form against
         # the rows the slot holds, the one just written the last of them.
@@ -1051,9 +1156,9 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
                 absorbed=True, mesh=mesh), cfg, cos, sin, positions, mesh)
         v_new = None
     elif rec is None:
-        x, k_new, v_new, counts = _scan_layers(
+        x, k_new, v_new, ring, counts = _scan_layers(
             params, x, k_pages, v_pages, attend, cfg, cos, sin, positions,
-            mesh,
+            mesh, ring, attend_ring,
         )
     else:
         # Every slot's row of a layer at once, advanced where it lies in
@@ -1070,6 +1175,8 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
         next_tokens = _pick_tokens(logits, temps, top_ks, top_ps, key)
     out = _count_routing((next_tokens, k_new, v_new, new_lengths), moe,
                          counts, cfg)
+    if ring is not None:
+        return (*out, ring)
     return _with_recurrent(out, rec, rec_count,
                            decode_rows_live=active.sum(dtype=jnp.int32),
                            decode_rows_computed=s_)
@@ -1078,7 +1185,7 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
 def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
                         v_pages, lengths, block_tables,
                         cfg: TransformerConfig, max_len: int, mesh=None,
-                        moe=None, rec=None, rec_count=None):
+                        moe=None, rec=None, rec_count=None, ring=None):
     """CHUNKED prefill, one pass of it: `P` rows of one fixed-size chunk
     each, row `r` being `n_valid[r]` tokens of a prompt into slot
     `slot[r]` at row `offset[r]`. The rows share one read of the weights,
@@ -1115,7 +1222,15 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     chunk before it left, so the real rows of a pass are of different
     slots. A prompt cannot resume below a shared prefix without the state
     at that boundary, which no one keeps. The pool and the advanced
-    `rec_count` come back last."""
+    `rec_count` come back last.
+
+    With `ring`, a model with window layers' second pool (donate it; it
+    comes back last): a window layer writes a row's keys and values at
+    their positions `mod ring` of the slot's ring, then the row's queries
+    meet the slot's whole ring, each ring row at the position it holds
+    once the chunk is written, under the window: the one form, whatever
+    the prompt's length. The ring holds the window and a chunk beyond it,
+    so the rows of a pass are of different slots, as with `rec`."""
     p_, c = tokens.shape
     n_valid, slot, offset = (
         jnp.reshape(a, (-1,)).astype(jnp.int32)
@@ -1153,6 +1268,37 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
             q, k_att.astype(jnp.float32), v_att.astype(jnp.float32), valid,
             cfg.attention_scale)
 
+    attend_ring = None
+    if ring is not None:
+        ring_bt = ring_tables(ring["k"], lengths.shape[0])[slot]  # [P, pages]
+        n_ring = ring_bt.shape[1] * ps
+        if n_ring < min(cfg.sliding_window_size + c, width):
+            raise ValueError(
+                f"a ring of {n_ring} rows does not hold the window "
+                f"({cfg.sliding_window_size}) and a chunk of {c}")
+        ring_pages_w = jnp.where(
+            in_range, jnp.take_along_axis(
+                ring_bt, positions // ps % ring_bt.shape[1], 1),
+            NULL_PAGE).reshape(-1)
+        # The position ring row `r` holds once the chunk is written: the
+        # last one under `end` that is `r mod ring`.
+        held = end - 1 - (end - 1 - jnp.arange(n_ring, dtype=jnp.int32)[None]
+                          ) % n_ring                              # [P, ring]
+        behind = positions[:, :, None] - held[:, None, :]
+        ring_valid = ((held[:, None, :] >= 0) & (behind >= 0)
+                      & (behind < cfg.sliding_window_size))
+
+        def attend_ring(i, kc, vc, q, k, v):
+            kc = kc.at[i, ring_pages_w, rows_w].set(
+                _rows(k.reshape(p_ * c, kvh, hd), kc))
+            vc = vc.at[i, ring_pages_w, rows_w].set(
+                _rows(v.reshape(p_ * c, kvh, hd), vc))
+            k_att = kc[i, ring_bt].reshape(p_, n_ring, kvh, hd)
+            v_att = vc[i, ring_bt].reshape(p_, n_ring, kvh, hd)
+            return kc, vc, grouped_attention(
+                q, k_att.astype(jnp.float32), v_att.astype(jnp.float32),
+                ring_valid, cfg.attention_scale)
+
     if cfg.kv_lora_rank:
         # The expanded form: a chunk's scores and weighted sum are as wide
         # as a head, not as a cached row, and that outweighs expanding each
@@ -1164,9 +1310,9 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
             cfg, cos, sin, positions, mesh)
         v_new = None
     elif rec is None:
-        x, k_new, v_new, counts = _scan_layers(
+        x, k_new, v_new, ring, counts = _scan_layers(
             params, x, k_pages, v_pages, attend, cfg, cos, sin, positions,
-            mesh,
+            mesh, ring, attend_ring,
         )
     else:
         carried = offset > 0
@@ -1203,6 +1349,8 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
             new_lengths)
     out = _count_routing((logits, k_new, v_new, new_lengths), moe, counts,
                          cfg)
+    if ring is not None:
+        return (*out, ring)
     return _with_recurrent(out, rec, rec_count,
                            prefill_tokens_valid=n_valid.sum(),
                            prefill_tokens_computed=p_ * c)
@@ -1286,7 +1434,7 @@ def _block_hidden(params, state, k_pages, v_pages, lengths, active,
                 cfg.attention_scale, mesh=mesh)
             return kc, vc, _unfold_block(attn, b, kvh)
 
-    x, k_new, v_new, counts = _scan_layers(
+    x, k_new, v_new, _, counts = _scan_layers(
         params, x, k_pages, v_pages, attend, cfg, cos, sin, positions, mesh)
     return (rmsnorm(x, params["final_norm"], cfg.norm_eps, mesh=mesh),
             k_new, v_new, counts)

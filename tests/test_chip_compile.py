@@ -1424,3 +1424,141 @@ def test_block_diffusion_steps_fit_and_read_their_stacks_in_place(
                for op in short)
     assert not any(re.search(spec.layer_metric_spec(other)["contains_op"], op)
                    for op in short)
+
+
+SWA_SLOTS, SWA_MAX_LEN, SWA_CHUNK = 32, 16384, 512
+SWA_FILE = "smallthinker-21b-a3b-serve.json"
+
+
+def _swa_program(program, one):
+    """`decode_paged` (sampled, or `decode_greedy`) or `prefill_chunk_paged`
+    at the sizes of the cell `serve-swa-longdoc` (SmallThinker-21BA3B's
+    first eight layers, 32 slots x 16,384, pages of 16, a prefill pass of
+    one row of 512), on shapes, with the routing accumulator and the window
+    layers' ring pool in the tail as the engine passes them: (fn, donated,
+    args, the cache's shapes, cfg)."""
+    from ray_tpu.models.transformer import init_params
+    from ray_tpu.serve import paged_kv
+
+    cfg = dataclasses.replace(configs.get_config("smallthinker-21b-a3b-l8"),
+                              remat=False)
+    slots, per_slot = SWA_SLOTS, SWA_MAX_LEN // PAGE
+
+    def on_chip(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one), tree)
+
+    def struct(shape, dtype=jnp.int32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one)
+
+    params = on_chip(jax.eval_shape(
+        lambda: init_params(jax.random.PRNGKey(0), cfg)))
+    cache = on_chip(jax.eval_shape(lambda: paged_kv.init_paged_cache(
+        cfg, slots, slots * per_slot + 1, PAGE, per_slot,
+        prefill_chunk=SWA_CHUNK)))
+    moe = on_chip(jax.eval_shape(
+        lambda: paged_kv.init_routing_counters(cfg)))
+    pool = (cache["k"], cache["v"], cache["lengths"])
+    if program.startswith("decode"):
+        sampling = ((None,) * 4 if program == "decode_greedy" else (
+            struct((slots,), jnp.float32), struct((slots,)),
+            struct((slots,), jnp.float32), struct((2,), jnp.uint32)))
+        fn = lambda p, t, k, v, ln, a, bt, tp, tk, tpp, key, moe, ring: (  # noqa: E731
+            paged_kv.decode_paged(p, t, k, v, ln, a, bt, tp, tk, tpp, key,
+                                  cfg, SWA_MAX_LEN, None, moe, ring=ring))
+        args = (params, struct((slots,)), *pool, struct((slots,), jnp.bool_),
+                cache["block_tables"], *sampling, moe, cache["ring"])
+        return fn, (2, 3, 12), args, cache, cfg
+    fn = lambda p, t, n, s, o, k, v, ln, bt, moe, ring: (  # noqa: E731
+        paged_kv.prefill_chunk_paged(p, t, n, s, o, k, v, ln, bt, cfg,
+                                     SWA_MAX_LEN, None, moe, ring=ring))
+    row = struct((1,))
+    args = (params, struct((1, SWA_CHUNK)), row, row, row, *pool,
+            cache["block_tables"], moe, cache["ring"])
+    return fn, (5, 6, 10), args, cache, cfg
+
+
+# The cell's per-layer metrics that read a trace by an operation's name,
+# and the step program in which each has to find one.
+SWA_TRACE_METRICS = {
+    "decode": ("moe.expert_time_share.swa", "moe.dispatch_time_share.swa",
+               "moe.expert_roofline_share.swa",
+               "attn.window_decode_time_share",
+               "attn.window_decode_roofline_share",
+               "attn.full_decode_time_share.swa", "sampler.time_share.swa"),
+    "decode_greedy": ("moe.expert_time_share.swa",
+                      "attn.window_decode_time_share",
+                      "attn.full_decode_time_share.swa"),
+    "prefill_chunk_paged": ("moe.expert_time_share.swa",
+                            "moe.dispatch_time_share.swa",
+                            "attn.prefill_time_share.swa"),
+}
+
+
+@pytest.mark.parametrize(
+    "program", ["decode", "decode_greedy", "prefill_chunk_paged"])
+def test_window_model_steps_fit_and_keep_both_pools_in_place(
+        v5e, program, monkeypatch):
+    """The cell `serve-swa-longdoc`'s step programs at its own sizes
+    (`_swa_program`): the compiler takes 32 slots x 16,384; the arguments
+    are the bytes its configuration file states (the full layers' pages
+    `[2, 32 x 1024 + 1, ..]` and the window layers' rings `[6, 32 x 288 + 1,
+    ..]` where one table for all eight layers would be 8.59 GB); both pools
+    come back in the buffers they came in; a decode step runs the full
+    layers' kernel once and the ring's kernel three times in the scanned
+    period (full, window, window, window) under its own name, and a
+    prefill pass runs neither; the expert stacks `[8, 64, ...]` are read in
+    place; and every trace metric the cell adds finds an operation of its
+    pattern among the names the compiler prints."""
+    import json
+
+    _bench_on_path()
+    import spec
+    from xplane import reduce
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    fn, donated, args, cache, cfg = _swa_program(
+        program, SingleDeviceSharding(v5e[0]))
+    assert cache["k"].shape == (2, 32 * 1024 + 1, PAGE, 512)
+    assert cache["ring"]["k"].shape == (6, 32 * 288 + 1, PAGE, 512)
+    compiled = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
+    memory = compiled.memory_analysis()
+    with open(os.path.join(spec.BENCH, "configs", SWA_FILE)) as f:
+        stated = json.load(f)["compiled"]
+    key = program if program.startswith("decode") else "prefill_1"
+    assert memory.argument_size_in_bytes == stated[key]["arguments_bytes"]
+    assert memory.temp_size_in_bytes <= stated[key]["temporaries_bytes_at_most"]
+    assert memory.argument_size_in_bytes + memory.temp_size_in_bytes < 13.0e9
+    pools = sum(a.size * a.dtype.itemsize
+                for a in (cache["k"], cache["v"], *cache["ring"].values()))
+    assert round(pools / 1e9, 2) == 3.96
+    assert memory.alias_size_in_bytes >= pools
+    text = compiled.as_text()
+    assert len(re.findall(r"%gmm[.\d]* = f32\[", text)) == 12
+    kernels = {name: re.findall(rf"%{name}[.\d]* = f32\[([\d,]+)\]", text)
+               for name in ("paged_decode_attention",
+                            "window_decode_attention")}
+    if program.startswith("decode"):
+        assert kernels == {"paged_decode_attention": ["32,4,8,128"],
+                           "window_decode_attention": ["32,4,8,128"] * 3}
+    else:
+        assert kernels == {"paged_decode_attention": [],
+                           "window_decode_attention": []}
+    for inner in ("2560,768", "768,2560"):
+        assert not re.findall(
+            rf"= bf16\[(?:1,|8,)?64,{inner}\]\S* (?:copy|fusion)\(", text)
+        assert not re.findall(rf"= bf16\[512,{inner}\]\S* copy\(", text)
+    assert not re.findall(r" conditional\(", text)
+    short = [reduce._short(line.strip().removeprefix("ROOT "))
+             for line in text.splitlines() if " = " in line]
+    for metric in SWA_TRACE_METRICS[program]:
+        how = spec.layer_metric_spec(metric)
+        assert any(re.search(how["match"], op) for op in short), metric
+    step, other = ("decode.device_ms_per_step.swa",
+                   "prefill.device_ms_per_chunk.swa")
+    if not program.startswith("decode"):
+        step, other = other, step
+    assert any(re.search(spec.layer_metric_spec(step)["contains_op"], op)
+               for op in short)
+    assert not any(re.search(spec.layer_metric_spec(other)["contains_op"], op)
+                   for op in short)
