@@ -21,8 +21,8 @@ follows, the next live slot's first included, are in flight while a
 block is computed.
 
 On other backends the plain form runs: the slot's whole table gathered,
-cast and scored, masked by the count (`grouped_attention`, which the
-prefill program uses too).
+cast and scored, masked by the count (`grouped_attention`; a prefill
+pass's queries walk the table a block at a time, `paged_kv._paged_attention`).
 
 A RING pool (a model with layers that attend over a window: `[window
 layers, 1 + slots * pages a ring, page_size, kv_heads * head_dim]`, a slot's

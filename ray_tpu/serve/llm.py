@@ -972,6 +972,11 @@ class ContinuousBatchingEngine:
         # without a window.
         self._attn_window_read = 0
         self._attn_window_unwindowed = 0
+        # What the prefill passes' walks gathered of the tables (and
+        # rings), and the same rows at full width
+        # (`_count_prefill_walk_locked`).
+        self._prefill_rows_walked = 0
+        self._prefill_rows_held = 0
         # The loop's phase ledger (loop-thread-only) and the copy the
         # loop publishes under the lock after each turn for stats().
         self._phase = _PhaseLedger()
@@ -1249,6 +1254,33 @@ class ContinuousBatchingEngine:
             n_window * self.num_slots * self._ring_rows)
         self._attn_window_read += n_window * in_window
         self._attn_window_unwindowed += n_window * live
+
+    def _count_prefill_walk_locked(self, offsets, n_valid):
+        """stats()["attention"] advanced by the prefill pass about to be
+        dispatched: for each of its real rows, the rows of the blocks the
+        pass's walk visits of the slot's table (`paged_kv._walk_blocks`: as
+        far as the longest real row's end, an inert row lengthening
+        nothing), summed over the layers that walk, and the same rows at
+        the table's full width; a model with window layers adds its rings
+        by the same rule. Keys and values: a latent pool's walk has a block
+        of its own and is not counted."""
+        ps, cfg = self.page_size, self.cfg
+        real = n_valid > 0
+        if self._v is None or not real.any():
+            return
+        rows = int(real.sum())
+        reach = int((offsets + n_valid)[real].max())
+        n_tables, n_window = self._k.shape[0], cfg.window_layers
+        per_slot = self._pages_per_slot
+        self._prefill_rows_walked += rows * n_tables * paged_kv.rows_walked(
+            reach, ps, per_slot)
+        self._prefill_rows_held += rows * n_tables * per_slot * ps
+        if n_window:
+            ring = self._ring_rows
+            self._prefill_rows_walked += (
+                rows * n_window * paged_kv.rows_walked(
+                    min(reach, ring), ps, ring // ps))
+            self._prefill_rows_held += rows * n_window * ring
 
     def _ssm_stats(self) -> Dict:
         """stats()["ssm"]: the recurrent pool's size (whatever its kind
@@ -1547,10 +1579,15 @@ class ContinuousBatchingEngine:
                 # live slots hold, from host mirrors: nothing is fetched
                 # for it (`_count_attention_locked`; a model with window
                 # layers also has the two counts of those layers alone).
+                # And over dispatched prefill passes: rows in the blocks
+                # the passes' walks visited, and the same rows at the
+                # tables' full width (`_count_prefill_walk_locked`).
                 "attention": {
                     "decode_rows_read": self._attn_rows_read,
                     "decode_rows_held": self._attn_rows_held,
                     "decode_rows_live": self._attn_rows_live,
+                    "prefill_rows_walked": self._prefill_rows_walked,
+                    "prefill_rows_held": self._prefill_rows_held,
                     **({"window_rows_read": self._attn_window_read,
                         "window_rows_unwindowed":
                             self._attn_window_unwindowed}
@@ -1904,6 +1941,8 @@ class ContinuousBatchingEngine:
                     finished.append((r, slot, h, entry))
             logits = self._dispatch_prefill(tokens, n_valid, slots, offsets)
             self._phase.prefill_rows += len(rows)
+            with self._lock_loop:
+                self._count_prefill_walk_locked(offsets, n_valid)
             self._phase.newest = logits
             if self._block:
                 began += [(slot, h, entry) for _, slot, h, entry in finished]

@@ -20,8 +20,11 @@ shapes and zero steady-state host traffic:
     block table, reading each slot's live pages where they lie
     (`ops.paged_attention`: a kernel on the chip, a gather of the whole
     table elsewhere); prefill scatters rows into the pages the table
-    names and gathers its one slot's. Program shapes depend only on the
-    pool and table geometry, so compilation stays bounded.
+    names and walks its slot's table a block of pages at a time with a
+    running softmax, as far as the chunk's end and no further
+    (`_paged_attention` over `_walk_blocks`, the walk a latent pool's
+    attention shares). Program shapes depend only on the pool and table
+    geometry, so compilation stays bounded.
     The pool is donated to the step and carried whole through the scan
     over layers; layer i scatters into `pool[i, pages, rows]` and reads
     `pool[i]` through the table, so a step touches the rows it writes
@@ -60,9 +63,9 @@ mod ring` of the slot's own run of pages. Nothing allocates a ring and
 nothing reserves it by a request's length; the full layers keep the tables
 and the pool above. A decode step's query reads the last `window` rows of
 its slot's ring in place (`ops.window_decode_attention`), a prefill chunk's
-queries the slot's whole ring, each row at the position it holds once the
-chunk is written. `_scan_layers` walks such a model a period of its
-per-layer lists at a time, both pools in its carry.
+queries walk the slot's ring as they walk a table, each row at the position
+it holds once the chunk is written. `_scan_layers` walks such a model a
+period of its per-layer lists at a time, both pools in its carry.
 
 Page 0 is reserved as the NULL/scratch page: block-table entries
 default to it, inactive-slot decode writes park in it, and prefill
@@ -107,7 +110,6 @@ from ray_tpu.models.transformer import (
 )
 from ray_tpu.ops import apply_rope, rmsnorm
 from ray_tpu.ops.paged_attention import (
-    grouped_attention,
     latent_decode_attention,
     latent_kernel_takes,
     paged_decode_attention,
@@ -474,17 +476,100 @@ def _latent_rows(latent, k_r, pool):
     return jnp.pad(rows, ((0, 0), (0, pool.shape[-1] - rows.shape[-1])))
 
 
-# Cached rows one step of `_latent_attention`'s loop gathers of each batch
+# Cached rows one step of `_latent_attention`'s walk gathers of each batch
 # row, at most (the size the chip's readings were taken at).
 LATENT_BLOCK_ROWS = 512
 
 
 def latent_block_pages(page_size: int, pages_per_slot: int) -> int:
-    """Pages of a slot's table that one step of `_latent_attention`'s loop
+    """Pages of a slot's table that one step of `_latent_attention`'s walk
     gathers: an eighth of the table, so that the loop overshoots the longest
     live slot by an eighth of a slot's width at most, and no more than
     `LATENT_BLOCK_ROWS` rows' worth, which bounds a block's scores."""
     return max(1, min(pages_per_slot // 8, LATENT_BLOCK_ROWS // page_size))
+
+
+# Cached rows one step of `_paged_attention`'s walk gathers of each batch
+# row. By the chip's readings at the serving cells' shapes
+# (`tools/walk_sweep.py`; PERF.md section 6, PR 71): a trip costs 0.03-0.04
+# ms whatever it holds, a table of 16,384 or 2,560 rows reads fastest in
+# blocks of 512 at the lengths the cells' prompts have (128 takes 1.7 times
+# as long; 1,024 gains 8% behind the longest prompts, loses 40% behind the
+# shortest and spills a block's scores at 64 heads), and a table of 1,024
+# in one block costs what the plain form did at every length where blocks
+# of 128 cost up to 3.5 times that.
+WALK_BLOCK_ROWS = 512
+
+
+def walk_block_pages(page_size: int, pages_per_slot: int) -> int:
+    """Pages of a slot's table (or ring) that one step of
+    `_paged_attention`'s walk gathers: `WALK_BLOCK_ROWS` rows' worth; a
+    table of up to two such blocks is one block, read in one step with no
+    loop (the trips would cost more than stopping early saves)."""
+    if pages_per_slot * page_size <= 2 * WALK_BLOCK_ROWS:
+        return pages_per_slot
+    return max(1, WALK_BLOCK_ROWS // page_size)
+
+
+def rows_walked(reach: int, page_size: int, pages_per_slot: int) -> int:
+    """Rows of the blocks `_paged_attention`'s walk visits of a table
+    `pages_per_slot` pages wide when the longest row of the batch sees
+    `reach` rows: the host's count of what a pass's walk gathers (the
+    engine's `stats()["attention"]`), by the walk's own arithmetic."""
+    pages = walk_block_pages(page_size, pages_per_slot)
+    n_max = -(-pages_per_slot // pages)
+    n_blocks = 1 if n_max == 1 else min(-(-reach // (pages * page_size)),
+                                        n_max)
+    return n_blocks * pages * page_size
+
+
+def _walk_blocks(tables, pages: int, page_size: int, live, stat, width: int,
+                 block_of):
+    """The running softmax over blocks of `pages` pages of `page_size` rows
+    of each batch row's table `tables [B, pages a slot]`: the one walk of
+    cached rows where they lie, a latent pool's (`_latent_attention`) and
+    that of a pool of keys and one of values (`_paged_attention`). As many
+    steps as the longest row's `live [B]` rows need and no more: the trip
+    count is data, so neither the rows read nor the scores held grow with
+    the table's width, and a table one block wide takes its one step with
+    no loop.
+
+    `block_of(j, at)`, of a block's index and its pages `at [B, pages]` (the
+    NULL page past the table's end), gives the block's float32 `scores
+    [*stat, rows]`, which of them are `seen` (a shape that broadcasts to
+    theirs) and `weigh(weights [*stat, rows] float32) -> [*stat, width]`,
+    their float32 product with the block's values. Maximum, sum and
+    weighted sum are carried in float32. Returns the weighted mean `[*stat,
+    width]` float32; a row that sees nothing gives zeros."""
+    f32 = jnp.float32
+    mp = tables.shape[1]
+    n_max = -(-mp // pages)
+    low = jnp.asarray(-1e30, f32)
+    padded = jnp.pad(tables, ((0, 0), (0, n_max * pages - mp)))  # NULL_PAGE
+
+    def step(j, carry):
+        top, total, acc = carry                 # stat, stat, stat + [width]
+        at = jax.lax.dynamic_slice_in_dim(padded, j * pages, pages, axis=1)
+        scores, seen, weigh = block_of(j, at)
+        scores = jnp.where(seen, scores, low)
+        new_top = jnp.maximum(top, scores.max(-1))
+        weights = jnp.where(seen, jnp.exp(scores - new_top[..., None]), 0.0)
+        keep = jnp.exp(top - new_top)
+        added = weigh(weights)
+        return (new_top, total * keep + weights.sum(-1),
+                acc * keep[..., None] + added)
+
+    def start():
+        return (jnp.full(stat, low), jnp.zeros(stat, f32),
+                jnp.zeros(stat + (width,), f32))
+
+    if n_max == 1:
+        _, total, acc = step(0, start())
+    else:
+        n_blocks = jnp.minimum(-(-jnp.max(live) // (pages * page_size)),
+                               n_max)
+        _, total, acc = jax.lax.fori_loop(0, n_blocks, step, start())
+    return acc / jnp.maximum(total, 1e-30)[..., None]
 
 
 def absorbed_queries(q_n, q_r, lp, cfg, row: int):
@@ -527,11 +612,9 @@ def _latent_attention(q_n, q_r, lp, pool, layer, tables, q_pos, end, cfg,
     that sees nothing (an idle slot, an inert row) gives zeros. Returns
     `[B, Q, H * dv]` in the queries' dtype."""
     b, n_q, h, _ = q_n.shape
-    ps, mp, row = pool.shape[2], tables.shape[1], pool.shape[3]
+    ps, row = pool.shape[2], pool.shape[3]
     rank, dv = cfg.kv_lora_rank, cfg.v_head_dim
     dtype, f32 = q_n.dtype, jnp.float32
-    pages = latent_block_pages(ps, mp)
-    block, n_max = pages * ps, -(-mp // pages)
     w_uv = lp["w_uv"].reshape(rank, h, dv)
     if absorbed:
         q = absorbed_queries(q_n, q_r, lp, cfg, row)
@@ -540,12 +623,9 @@ def _latent_attention(q_n, q_r, lp, pool, layer, tables, q_pos, end, cfg,
         q = jnp.concatenate([q_n, q_r], -1) * jnp.asarray(
             cfg.attention_scale, dtype)
         stat, width = (b, h, n_q), dv
-    low = jnp.asarray(-1e30, f32)
-    padded = jnp.pad(tables, ((0, 0), (0, n_max * pages - mp)))  # NULL_PAGE
 
-    def step(j, carry):
-        top, total, acc = carry                 # stat, stat, stat + [width]
-        at = jax.lax.dynamic_slice_in_dim(padded, j * pages, pages, axis=1)
+    def block_of(j, at):
+        block = at.shape[1] * ps
         rows = pool[layer, at].reshape(b, block, row)
         k_pos = j * block + jnp.arange(block, dtype=jnp.int32)
         if q_pos is None:
@@ -566,15 +646,9 @@ def _latent_attention(q_n, q_r, lp, pool, layer, tables, q_pos, end, cfg,
             seen = seen[:, None]
             scores = jnp.einsum("bqhd,bkhd->bhqk", q, keys,
                                 preferred_element_type=f32)
-        scores = jnp.where(seen, scores, low)
-        new_top = jnp.maximum(top, scores.max(-1))
-        weights = jnp.where(seen, jnp.exp(scores - new_top[..., None]), 0.0)
-        keep = jnp.exp(top - new_top)
-        added = jnp.einsum(
+        return scores, seen, lambda weights: jnp.einsum(
             "bqhk,bkr->bqhr" if absorbed else "bhqk,bkhr->bhqr",
             weights.astype(dtype), values, preferred_element_type=f32)
-        return (new_top, total * keep + weights.sum(-1),
-                acc * keep[..., None] + added)
 
     in_place = (absorbed and q_pos is None and n_q == 1
                 and jax.default_backend() == "tpu"
@@ -584,17 +658,51 @@ def _latent_attention(q_n, q_r, lp, pool, layer, tables, q_pos, end, cfg,
             out = latent_decode_attention(q[:, 0], pool, layer, tables, end,
                                           rank, mesh=mesh)
         else:
-            n_blocks = jnp.minimum(-(-jnp.max(end) // block), n_max)
-            _, total, acc = jax.lax.fori_loop(
-                0, n_blocks, step,
-                (jnp.full(stat, low), jnp.zeros(stat, f32),
-                 jnp.zeros(stat + (width,), f32)))
-            out = (acc / jnp.maximum(total, 1e-30)[..., None]).astype(dtype)
+            out = _walk_blocks(
+                tables, latent_block_pages(ps, tables.shape[1]), ps, end,
+                stat, width, block_of).astype(dtype)
         if absorbed:
             out = jnp.einsum("bqhr,rhv->bqhv", out, w_uv)
         else:
             out = out.transpose(0, 2, 1, 3)
         return out.reshape(b, n_q, h * dv)
+
+
+def _paged_attention(q, k_pool, v_pool, layer, tables, live, seen, scale):
+    """Attention of `C` queries a batch row, `q [P, C, H, D]`, against the
+    rows its table `tables [P, pages]` names in `k_pool[layer]` and
+    `v_pool[layer]`, where they lie (`_walk_blocks`): a slot's table or its
+    ring.
+
+    `live [P]`: how many of the table's rows, from its first, a batch row
+    may see at all, the table's width at most; what bounds the walk (0: an
+    inert row, which lengthens no walk and gives zeros). `seen(rows [K]) ->
+    [P, C, K]`: which of the table's rows `rows` each query sees among
+    those, the caller's mask made a block at a time. Keys and values are
+    cast to float32 a block at a time and scores, weights and accumulators
+    are float32, the operands `grouped_attention` multiplies, so the two
+    differ by the order of a sum.
+    Returns `[P, C, H, D]` in the queries' dtype."""
+    p_, c, h, d = q.shape
+    ps, mp = k_pool.shape[2], tables.shape[1]
+    kvh = k_pool.shape[3] // d
+    f32 = jnp.float32
+    qg = q.reshape(p_, c, kvh, h // kvh, d).astype(f32)
+    live = jnp.minimum(live, mp * ps)  # the pad past the table is not rows
+
+    def block_of(j, at):
+        block = at.shape[1] * ps
+        keys = k_pool[layer, at].reshape(p_, block, kvh, d).astype(f32)
+        values = v_pool[layer, at].reshape(p_, block, kvh, d).astype(f32)
+        rows = j * block + jnp.arange(block, dtype=jnp.int32)
+        scores = jnp.einsum("sqhgd,skhd->shgqk", qg, keys) * scale
+        visible = seen(rows) & (rows < live[:, None, None])
+        return scores, visible[:, None, None], lambda weights: jnp.einsum(
+            "shgqk,skhd->shgqd", weights, values)
+
+    out = _walk_blocks(tables, walk_block_pages(ps, mp), ps, live,
+                       (p_, kvh, h // kvh, c), d, block_of)
+    return out.transpose(0, 3, 1, 2, 4).reshape(p_, c, h, d).astype(q.dtype)
 
 
 def _latent_through_pool(pages_w, rows_w, tables, q_pos, end, cfg,
@@ -1197,7 +1305,9 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     and values scatter into the pages its slot's block-table row names
     (padding and anything past `max_len` drop into the NULL page) before
     any row reads; then each row's queries attend causally, by their own
-    positions, against their own slot's gathered page run, earlier chunks
+    positions, against the rows of their own slot's table under the
+    chunk's end, read a block of pages at a time (`_paged_attention`: the
+    walk is as long as the longest real row's end needs), earlier chunks
     included. So two consecutive chunks of ONE prompt may be two rows of a
     pass (the earlier chunk the earlier row): the later one finds the
     earlier one's keys in the pages. Sets lengths[slot] = offset + n_valid
@@ -1227,10 +1337,11 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     With `ring`, a model with window layers' second pool (donate it; it
     comes back last): a window layer writes a row's keys and values at
     their positions `mod ring` of the slot's ring, then the row's queries
-    meet the slot's whole ring, each ring row at the position it holds
-    once the chunk is written, under the window: the one form, whatever
-    the prompt's length. The ring holds the window and a chunk beyond it,
-    so the rows of a pass are of different slots, as with `rec`."""
+    walk the slot's ring (as far as the rows it holds yet), each ring row
+    at the position it holds once the chunk is written, under the window:
+    the one form, whatever the prompt's length. The ring holds the window
+    and a chunk beyond it, so the rows of a pass are of different slots, as
+    with `rec`."""
     p_, c = tokens.shape
     n_valid, slot, offset = (
         jnp.reshape(a, (-1,)).astype(jnp.int32)
@@ -1243,14 +1354,15 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     cos, sin = rope_tables(cfg, max_len)
     positions = offset[:, None] + jnp.arange(c, dtype=jnp.int32)[None, :]
     end = (offset + n_valid)[:, None]                           # [P, 1]
-    k_pos = jax.lax.broadcasted_iota(jnp.int32, (p_, c, width), 2)
-    valid = (k_pos <= positions[:, :, None]) & (k_pos < end[:, :, None])
     if cfg.block_length:
         # A block-diffusion model's prompt: a position sees its own block
         # whole. The engine hands it whole blocks (`n_valid` and `offset`
         # multiples of the block), so no block is cut by `end`.
-        valid = (block_causal(positions, k_pos[:, 0], cfg.block_length)
-                 & (k_pos < end[:, :, None]))
+        def seen(k_pos):
+            return block_causal(positions, k_pos, cfg.block_length)
+    else:
+        def seen(k_pos):
+            return k_pos <= positions[:, :, None]
     bt_rows = block_tables[slot]                                # [P, mp]
     in_range = (positions < end) & (positions < max_len)
     page_of = jnp.minimum(positions // ps, mp - 1)
@@ -1258,15 +1370,15 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
                         NULL_PAGE).reshape(-1)
     rows_w = (positions % ps).reshape(-1)
     real = n_valid > 0
+    # Rows of its slot's table a pass's row may see: those under its chunk's
+    # end, an inert row none.
+    live = jnp.where(real, end[:, 0], 0)
 
     def attend(i, kc, vc, q, k, v):
         kc = kc.at[i, pages_w, rows_w].set(_rows(k.reshape(p_ * c, kvh, hd), kc))
         vc = vc.at[i, pages_w, rows_w].set(_rows(v.reshape(p_ * c, kvh, hd), vc))
-        k_att = kc[i, bt_rows].reshape(p_, width, kvh, hd)
-        v_att = vc[i, bt_rows].reshape(p_, width, kvh, hd)
-        return kc, vc, grouped_attention(
-            q, k_att.astype(jnp.float32), v_att.astype(jnp.float32), valid,
-            cfg.attention_scale)
+        return kc, vc, _paged_attention(q, kc, vc, i, bt_rows, live, seen,
+                                        cfg.attention_scale)
 
     attend_ring = None
     if ring is not None:
@@ -1280,24 +1392,21 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
             in_range, jnp.take_along_axis(
                 ring_bt, positions // ps % ring_bt.shape[1], 1),
             NULL_PAGE).reshape(-1)
-        # The position ring row `r` holds once the chunk is written: the
-        # last one under `end` that is `r mod ring`.
-        held = end - 1 - (end - 1 - jnp.arange(n_ring, dtype=jnp.int32)[None]
-                          ) % n_ring                              # [P, ring]
-        behind = positions[:, :, None] - held[:, None, :]
-        ring_valid = ((held[:, None, :] >= 0) & (behind >= 0)
-                      & (behind < cfg.sliding_window_size))
+        def ring_seen(r):
+            # The position ring row `r` holds once the chunk is written:
+            # the last one under `end` that is `r mod ring` (none where `r`
+            # is not under `end`, which `live` says).
+            held = end - 1 - (end - 1 - r[None]) % n_ring         # [P, K]
+            behind = positions[:, :, None] - held[:, None, :]
+            return (behind >= 0) & (behind < cfg.sliding_window_size)
 
         def attend_ring(i, kc, vc, q, k, v):
             kc = kc.at[i, ring_pages_w, rows_w].set(
                 _rows(k.reshape(p_ * c, kvh, hd), kc))
             vc = vc.at[i, ring_pages_w, rows_w].set(
                 _rows(v.reshape(p_ * c, kvh, hd), vc))
-            k_att = kc[i, ring_bt].reshape(p_, n_ring, kvh, hd)
-            v_att = vc[i, ring_bt].reshape(p_, n_ring, kvh, hd)
-            return kc, vc, grouped_attention(
-                q, k_att.astype(jnp.float32), v_att.astype(jnp.float32),
-                ring_valid, cfg.attention_scale)
+            return kc, vc, _paged_attention(q, kc, vc, i, ring_bt, live,
+                                            ring_seen, cfg.attention_scale)
 
     if cfg.kv_lora_rank:
         # The expanded form: a chunk's scores and weighted sum are as wide

@@ -1509,7 +1509,20 @@ def test_window_model_steps_fit_and_keep_both_pools_in_place(
     period (full, window, window, window) under its own name, and a
     prefill pass runs neither; the expert stacks `[8, 64, ...]` are read in
     place; and every trace metric the cell adds finds an operation of its
-    pattern among the names the compiler prints."""
+    pattern among the names the compiler prints.
+
+    A prefill pass walks blocks of 512 rows of a table or a ring with a
+    running softmax (`paged_kv._paged_attention`, PR 71): one loop a
+    walking layer of the period, the full layer's over its table of 1,024
+    pages and each window layer's over its ring of 288, no float32 result
+    as wide as a table or a ring anywhere, and the temporaries a fiftieth
+    of the plain form's 948 MB. Of `attn.prefill_time_share.swa`'s
+    alternatives only `f32[4,7,512]` still finds an operation (the running
+    maximum and sum a block): the gathers, the mask, the scores and the
+    weighted sum it names by a width of 16,384 or 4,608 or as `[4,128,7,
+    512]` are no longer made (a block's are `bf16[32,16,512]`,
+    `pred[512,512]`, `f32[1,4,7,512,512]`, `f32[1,4,7,512,128]`), so the
+    entry reads a part of the attention (`PERF.md` section 7)."""
     import json
 
     _bench_on_path()
@@ -1544,6 +1557,18 @@ def test_window_model_steps_fit_and_keep_both_pools_in_place(
     else:
         assert kernels == {"paged_decode_attention": [],
                            "window_decode_attention": []}
+        assert memory.temp_size_in_bytes < 0.2e9
+        assert not re.findall(r"f32\[[\d,]*,(?:16384|4608)\]", text)
+        walks = [line for line in text.splitlines()
+                 if " while(" in line and "f32[1,4,7,512,128]" in line]
+        assert sorted(re.search(r"s32\[1,(\d+)\]", line).group(1)
+                      for line in walks) == ["1024", "288", "288", "288"]
+        how = spec.layer_metric_spec("attn.prefill_time_share.swa")
+        found = {op.split(" ", 1)[1] for op in (
+            reduce._short(line.strip().removeprefix("ROOT "))
+            for line in text.splitlines() if " = " in line)
+            if re.search(how["match"], op)}
+        assert found == {"f32[4,7,512]"}
     for inner in ("2560,768", "768,2560"):
         assert not re.findall(
             rf"= bf16\[(?:1,|8,)?64,{inner}\]\S* (?:copy|fusion)\(", text)

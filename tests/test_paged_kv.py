@@ -320,7 +320,8 @@ def test_carried_pool_matches_a_layer_by_layer_reference(program):
     """`decode_paged` and `prefill_chunk_paged` carry the whole pool
     through the layer scan and index it by layer; the arithmetic is that
     of a loop over per-layer pools `[pages, page_size, kv_heads,
-    head_dim]`, each gathered whole through the table and masked. Slots 0
+    head_dim]`, each gathered whole through the table and masked (a decode
+    step's to the bit, a pass's to the order of a sum). Slots 0
     and 1 share their first page (a prefix-cache hit), slot 2 is inactive
     (it attends to nothing), and the prefill chunk is a final one: 5 real
     rows and 3 of padding, starting mid-page. Each side is one jitted
@@ -406,8 +407,19 @@ def test_carried_pool_matches_a_layer_by_layer_reference(program):
     got = jax.jit(step)(k_pool.reshape(flat), v_pool.reshape(flat))
     want = jax.jit(reference)(k_pool, v_pool)
     for name, g, w in zip(("out", "k", "v", "lengths"), got, want):
-        np.testing.assert_array_equal(
-            np.asarray(g), np.asarray(w).reshape(g.shape), err_msg=name)
+        g, w = np.asarray(g), np.asarray(w).reshape(g.shape)
+        if program == "decode" or name == "lengths":
+            np.testing.assert_array_equal(g, w, err_msg=name)
+        else:
+            # A pass walks the table a block at a time with a running
+            # softmax (`_paged_attention`): the reference's numbers in
+            # another order of summation, so float32's last bits on the
+            # logits and, rarely, a bfloat16 row's last bit in the pool.
+            np.testing.assert_allclose(
+                g.astype(np.float32), w.astype(np.float32), rtol=1e-2,
+                atol=1e-5, err_msg=name)
+            if name == "out":
+                np.testing.assert_allclose(g, w, rtol=1e-4, atol=5e-6)
     # Both wrote: the comparison above is not of two untouched pools.
     assert not np.array_equal(np.asarray(got[1]),
                               np.asarray(k_pool).reshape(flat))
@@ -1083,3 +1095,145 @@ def test_a_latent_pools_page_forks_without_a_second_pool():
                                   np.asarray(pool[:, 3]))
     np.testing.assert_array_equal(np.asarray(forked[:, 2:]),
                                   np.asarray(pool[:, 2:]))
+
+
+# A pass's attention (`paged_kv._paged_attention`) against the plain form it
+# replaced: (pages a slot, rows' `offset`, rows' `n_valid`, the mask). Page
+# size 4, chunks of 8 queries, and `WALK_BLOCK_ROWS` set to 8 (`small_blocks`):
+# a table of up to 16 rows is one block, a wider one walks blocks of 8.
+WALKS = {
+    "one_block": (4, [5], [8], "causal"),
+    "several_blocks": (16, [40], [8], "causal"),
+    "block_does_not_divide": (19, [68], [8], "causal"),
+    "rows_of_different_ends_and_an_inert_row":
+        (16, [8, 33, 50, 0], [8, 7, 0, 5], "causal"),
+    "block_causal": (16, [24, 4], [8, 8], "block"),
+    "ring_before_its_wrap": (12, [16, 5], [8, 3], "ring"),
+    "ring_across_its_wrap": (12, [44, 90], [8, 6], "ring"),
+}
+_WINDOW = 40  # with a chunk of 8, what a ring of 12 pages of 4 holds
+
+
+@pytest.fixture
+def small_blocks(monkeypatch):
+    monkeypatch.setattr(paged_kv, "WALK_BLOCK_ROWS", 8)
+
+
+def _walk_case(pages_per_slot, offset, n_valid, mask, poison=False):
+    """`(walked, plain, real)` of one case of `WALKS`: the walk's result,
+    `grouped_attention`'s over the gathered table under the dense mask, and
+    which rows of the pass are real. `poison`: every page past the blocks
+    the walk should visit holds NaN."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models.transformer import block_causal
+    from ray_tpu.ops.paged_attention import grouped_attention
+
+    ps, c, h, kvh, d, layer = 4, 8, 4, 2, 16, 1
+    p_, mp = len(offset), pages_per_slot
+    width = mp * ps
+    offset, n_valid = (np.asarray(a, np.int32) for a in (offset, n_valid))
+    end = offset + n_valid
+    keys = jax.random.split(jax.random.PRNGKey(7), 3)
+    shape = (2, 1 + p_ * mp, ps, kvh * d)
+    kc = np.array(jax.random.normal(keys[0], shape, jnp.float32))
+    vc = np.array(jax.random.normal(keys[1], shape, jnp.float32))
+    q = jax.random.normal(keys[2], (p_, c, h, d), jnp.float32)
+    tables = 1 + np.random.default_rng(0).permutation(p_ * mp).reshape(p_, mp)
+    positions = offset[:, None] + np.arange(c)[None]
+    rows = np.arange(width)
+    if mask == "ring":
+        # By brute force: ring row r holds the last position under `end`
+        # that is r mod the ring.
+        held = np.full((p_, width), -1)
+        for r_, e in enumerate(end):
+            for pos in range(e):
+                held[r_, pos % width] = pos
+        behind = positions[:, :, None] - held[:, None]
+        valid = (held[:, None] >= 0) & (behind >= 0) & (behind < _WINDOW)
+        live = np.minimum(end, width)
+
+        def seen(r):
+            at = end[:, None] - 1 - (end[:, None] - 1 - r[None]) % width
+            gap = positions[:, :, None] - at[:, None]
+            return (gap >= 0) & (gap < _WINDOW)
+    else:
+        live = end
+        if mask == "block":
+            def seen(k):
+                return block_causal(jnp.asarray(positions), k, 4)
+        else:
+            def seen(k):
+                return k <= positions[:, :, None]
+        valid = np.asarray(seen(jnp.asarray(rows))) & (rows < end[:, None, None])
+    real = n_valid > 0
+    live = np.where(real, live, 0)
+    if poison:
+        walked = paged_kv.rows_walked(int(live.max()), ps, mp)
+        for pool in (kc, vc):
+            pool[:, tables[:, walked // ps:].reshape(-1)] = np.nan
+    kc, vc, tables = jnp.asarray(kc), jnp.asarray(vc), jnp.asarray(tables)
+    got = jax.jit(lambda *a: paged_kv._paged_attention(
+        *a, layer, tables, jnp.asarray(live), seen, d ** -0.5))(q, kc, vc)
+    plain = grouped_attention(
+        q, kc[layer, tables].reshape(p_, width, kvh, d),
+        vc[layer, tables].reshape(p_, width, kvh, d), jnp.asarray(valid),
+        d ** -0.5)
+    return np.asarray(got), np.asarray(plain), real
+
+
+@pytest.mark.parametrize("case", WALKS)
+def test_a_pass_walks_its_table_to_the_plain_forms_numbers(case, small_blocks):
+    """`_paged_attention` (blocks of a row's table, a running softmax, as
+    many steps as the longest real row's end needs) gives what
+    `grouped_attention` gives over the whole gathered table under the same
+    mask, to float32's rounding: a table of two blocks' rows, which is one
+    block (one step and no loop), several, a width the block does not divide, four rows of
+    different ends one of them inert at an arbitrary offset (it sees
+    nothing and gives zeros), a block-diffusion model's mask, and a ring's
+    held positions before and across its wrap."""
+    import jax
+
+    got, plain, real = _walk_case(*WALKS[case])
+    np.testing.assert_allclose(got[real], plain[real], rtol=2e-5, atol=2e-6)
+    assert np.abs(plain[real]).max() > 0.1
+    np.testing.assert_array_equal(got[~real], 0.0)
+    if case == "one_block":
+        pages, offset, n_valid, _ = WALKS[case]
+        def traced(pages):
+            return str(jax.make_jaxpr(
+                lambda q, kc, vc: paged_kv._paged_attention(
+                    q, kc, vc, 0, np.ones((1, pages), np.int32),
+                    np.asarray([3], np.int32), lambda k: k[None, None] >= 0,
+                    1.0))(np.zeros((1, 8, 4, 16), np.float32),
+                          *(np.zeros((1, 2, 4, 32), np.float32),) * 2))
+
+        assert "while" not in traced(pages) and "while" in traced(pages + 1)
+
+
+@pytest.mark.parametrize("case", [
+    "several_blocks", "rows_of_different_ends_and_an_inert_row",
+    "ring_before_its_wrap"])
+def test_a_pass_reads_no_page_past_the_block_that_holds_its_end(
+        case, small_blocks):
+    """Every page past the blocks the walk visits (`rows_walked` of the
+    longest real row's end) filled with NaN: the walk's result is finite
+    and the clean one's to the bit, so those rows are not read. The plain
+    form multiplies them by a weight of zero and gives NaN: proof the test
+    can fail."""
+    clean, _, real = _walk_case(*WALKS[case])
+    got, plain, _ = _walk_case(*WALKS[case], poison=True)
+    assert np.isfinite(got).all()
+    np.testing.assert_array_equal(got, clean)
+    assert np.isnan(plain[real]).any()
+
+
+@pytest.mark.parametrize("rows, block", [
+    (1024, 1024), (2560, 512), (4608, 512), (16384, 512), (512, 512)])
+def test_a_walks_block_is_worked_out_from_the_tables_width(rows, block):
+    """`walk_block_pages`, the one rule: blocks of 512 rows, and a table of
+    up to 1,024 rows whole (the serving cells' widths, at pages of 16)."""
+    assert paged_kv.walk_block_pages(16, rows // 16) * 16 == block
+    assert paged_kv.rows_walked(1, 16, rows // 16) == block
+    assert paged_kv.rows_walked(rows, 16, rows // 16) == rows

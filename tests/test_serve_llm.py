@@ -441,7 +441,8 @@ def test_attention_counters_follow_the_decoding_slots():
     try:
         assert eng.stats()["attention"] == {
             "decode_rows_read": 0, "decode_rows_held": 0,
-            "decode_rows_live": 0}
+            "decode_rows_live": 0, "prefill_rows_walked": 0,
+            "prefill_rows_held": 0}
         eng.submit([3, 7, 11], max_new_tokens=6).result(timeout=180)
         first = eng.stats()["attention"]
         # Lengths 3..7 at the five steps that gave tokens 2..6, each with
@@ -457,6 +458,49 @@ def test_attention_counters_follow_the_decoding_slots():
         assert second["decode_rows_read"] > first["decode_rows_read"]
         assert second["decode_rows_held"] > first["decode_rows_held"]
         assert second["decode_rows_read"] <= second["decode_rows_held"]
+    finally:
+        eng.shutdown()
+
+
+def test_prefill_walk_counters_follow_the_passes_rows(monkeypatch):
+    """stats()['attention']['prefill_rows_walked'] and ['prefill_rows_held']:
+    for each real row of a dispatched prefill pass, the rows of the blocks
+    the pass's walk visits of the slot's table (as far as the longest real
+    row's end, in whole blocks: `paged_kv.rows_walked`), summed over the
+    layers, and the same rows at the table's full width; cumulative, from
+    the pass's own `(offset, n_valid)`, nothing fetched, warm-up's passes
+    not counted. With `WALK_BLOCK_ROWS` at 8 a table of 16 pages of 4 walks
+    blocks of 8 rows (at the program's 512 it would be one block)."""
+    from ray_tpu.serve import paged_kv
+    from ray_tpu.serve.llm import ContinuousBatchingEngine
+
+    monkeypatch.setattr(paged_kv, "WALK_BLOCK_ROWS", 8)
+    params, cfg = _tiny_model()
+    eng = ContinuousBatchingEngine(params, cfg, num_slots=2, max_len=64,
+                                   page_size=4, prefill_chunk=8)
+    try:
+        def counts():
+            att = eng.stats()["attention"]
+            return att["prefill_rows_walked"], att["prefill_rows_held"]
+
+        assert counts() == (0, 0)
+        layers = cfg.n_layers
+        # One pass of one row, (0, 3): a block of 8 rows of the table's 64.
+        eng.submit([3, 7, 11], max_new_tokens=1).result(timeout=180)
+        assert counts() == (layers * 8, layers * 64)
+        # One pass of three chunks of one prompt, (0, 8), (8, 8), (16, 1),
+        # in the four-row program: the walk goes to row 17, three blocks,
+        # for each of the three real rows; the inert row adds nothing.
+        eng.submit(list(range(1, 18)), max_new_tokens=1).result(timeout=180)
+        assert counts() == (layers * (8 + 3 * 24), layers * (64 + 3 * 64))
+        # The host's arithmetic by hand: two real rows and an inert one
+        # whose offset lengthens nothing.
+        before = counts()
+        with eng._lock:
+            eng._count_prefill_walk_locked(np.asarray([0, 40, 8]),
+                                           np.asarray([8, 0, 2]))
+        assert counts() == (before[0] + layers * 2 * 16,
+                            before[1] + layers * 2 * 64)
     finally:
         eng.shutdown()
 

@@ -8,14 +8,18 @@ any chip time is spent.
 
 One JSON line: for each program `<model>:<program>` the hash of its jaxpr
 (addresses out), traced on the CPU with the kernels' branch taken
-(`jax.default_backend` answers "tpu", as on the chip). With `--compiled`
-each program is also compiled for a described, not attached, v5e
-(`on-chip-measurement`, section 2) and the line carries its temporaries'
-bytes and `ops`, a hash of WHAT it computes: the sorted multiset of (opcode,
-result shape and layout) over every instruction but the tuples, their
-elements and the parameters, so that two programs that differ in the order
-of a loop's state alone hash alike. Equal jaxprs are the same program;
-equal `ops` and temporaries are the same work laid out the same way.
+(`jax.default_backend` answers "tpu", as on the chip), and `live`, the hash
+of the same jaxpr with the equations no result depends on taken out (a mask
+or a padded table that a model's branch never reads is traced all the same:
+two programs that differ in such equations alone are the same program).
+With `--compiled` each program is also compiled for a described, not
+attached, v5e (`on-chip-measurement`, section 2) and the line carries its
+temporaries' bytes and `ops`, a hash of WHAT it computes: the sorted
+multiset of (opcode, result shape and layout) over every instruction but
+the tuples, their elements and the parameters, so that two programs that
+differ in the order of a loop's state alone hash alike. Equal jaxprs are
+the same program; equal `ops` and temporaries are the same work laid out
+the same way.
 
 Shapes are small (8 slots x 512, chunks of 64) and the depth is cut to a few
 periods of layers: structure, not size, is what is compared. `--tree` runs
@@ -42,6 +46,10 @@ SLOTS, MAX_LEN, PAGE, CHUNK = 8, 512, 16, 64
 
 def _sha(text: str) -> str:
     return hashlib.sha256(text.encode()).hexdigest()[:16]
+
+
+def _text(jaxpr) -> str:
+    return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
 
 
 def ops_hash(hlo: str) -> str:
@@ -147,9 +155,11 @@ def _compiled(fn, donated, args, one) -> dict:
 
 
 def fingerprints(models, compiled: bool = False, one=None) -> dict:
-    """`{"<model>:<program>": {"jaxpr": .., ["temporaries": .., "ops": ..]}}`;
+    """`{"<model>:<program>": {"jaxpr": .., "live": .., ["temporaries": ..,
+    "ops": ..]}}`;
     `one` is the described chip's sharding for `compiled`."""
     import jax
+    from jax.interpreters import partial_eval as pe
 
     from ray_tpu.models import configs
 
@@ -161,8 +171,10 @@ def fingerprints(models, compiled: bool = False, one=None) -> dict:
         if name in TRAINED:
             todo.append(train_loss(name))
         for tag, fn, donated, args in todo:
-            text = re.sub(r"0x[0-9a-f]+", "0x", str(jax.make_jaxpr(fn)(*args)))
-            got = {"jaxpr": _sha(text)}
+            traced = jax.make_jaxpr(fn)(*args)
+            live, _ = pe.dce_jaxpr(traced.jaxpr,
+                                   [True] * len(traced.out_avals))
+            got = {"jaxpr": _sha(_text(traced)), "live": _sha(_text(live))}
             if compiled:
                 got.update(_compiled(fn, donated, args, one))
             out[f"{name}:{tag}"] = got
