@@ -4,7 +4,11 @@ The inference half of the model stack: prefill runs the full forward once
 (flash attention), then decode steps append one token at a time against a
 preallocated KV cache — static shapes throughout so the decode step
 compiles once and stays on the TPU (`lax.scan` over steps, masked
-attention against the cache).
+attention against the cache). What a layer computes is
+`transformer.attention_layer`'s, the train step's and the serving engine's
+too; this module's own are the cache, the `attend(q, k, v)` that writes and
+reads it, and the sampler: an independent loop over an independent cache,
+the tests' reference for the engine's tokens.
 
 The reference has no analog (models live in user code); this is what
 `serve`-ing an LLM on TPU needs: one jitted `prefill` + one jitted
@@ -22,15 +26,12 @@ import jax.numpy as jnp
 from ray_tpu.models.transformer import (
     TransformerConfig,
     _embed_tokens,
-    dense_mlp,
+    attention_layer,
     layers_inputs,
     project_logits,
-    project_qkv,
-    rotate,
-    router_input,
+    rope_tables,
 )
-from ray_tpu.ops import rmsnorm, rope_frequencies
-from ray_tpu.parallel.moe import moe_block
+from ray_tpu.ops import rmsnorm
 
 NEG_INF = -1e30
 
@@ -50,18 +51,18 @@ def init_kv_cache(cfg: TransformerConfig, batch: int, max_len: int) -> Dict:
     }
 
 
-def _cached_attention(q, k_cache, v_cache, cache_len, window=None):
+def _cached_attention(q, k_cache, v_cache, cache_len, scale, window=None):
     """q: [B, Lq, H, D] against cache [B, Lmax, KVH, D] (first cache_len
-    valid). GQA via grouped einsum — decode is HBM-bandwidth-bound, so the
-    cache must be read at its native size, never repeat-materialized in
-    the hot loop. Causal masking by absolute position; `window`, where a
-    layer has one: a query sees itself and the `window - 1` keys before
-    it (the cache holds every position all the same)."""
+    valid), scores times `scale`. GQA via grouped einsum — decode is
+    HBM-bandwidth-bound, so the cache must be read at its native size,
+    never repeat-materialized in the hot loop. Causal masking by absolute
+    position; `window`, where a layer has one: a query sees itself and the
+    `window - 1` keys before it (the cache holds every position all the
+    same)."""
     b, lq, h, d = q.shape
     kvh = k_cache.shape[2]
     group = h // kvh
     lmax = k_cache.shape[1]
-    scale = d ** -0.5
     # Query i sits at absolute position cache_len - lq + i; key j at j.
     q_pos = cache_len - lq + jax.lax.broadcasted_iota(
         jnp.int32, (lq, lmax), 0
@@ -90,40 +91,33 @@ def _forward_with_cache(params, tokens, cache, cfg: TransformerConfig):
     """Forward over `tokens` (appended at cache['length']); returns
     (logits for the final position, updated cache)."""
     x = _embed_tokens(params, tokens, cfg)
-    b, lq = tokens.shape
+    lq = tokens.shape[1]
     lmax = cache["k"].shape[2]
-    cos, sin = rope_frequencies(cfg.head_dim, lmax, cfg.rope_theta)
+    cos, sin = rope_tables(cfg, lmax)
     start = cache["length"]
     positions = start + jnp.arange(lq, dtype=jnp.int32)[None, :]
 
     per_layer = bool(cfg.window_layout or cfg.rope_layout)
 
-    def layer(carry, inputs):
-        x = carry
+    def layer(x, inputs):
         layer_in, k_cache_l, v_cache_l = inputs
         # A model with per-layer lists: the layer's two flags, whether it
         # rotates and whether it has the window (`layers_inputs`).
         lp, rope, window = layer_in if per_layer else (layer_in, None, None)
-        h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
-        q, k, v = project_qkv(h, lp, cfg)
-        q, k = rotate(q, k, cos, sin, positions, rope)
-        k_cache_l = jax.lax.dynamic_update_slice(
-            k_cache_l, k.astype(k_cache_l.dtype), (0, start, 0, 0)
-        )
-        v_cache_l = jax.lax.dynamic_update_slice(
-            v_cache_l, v.astype(v_cache_l.dtype), (0, start, 0, 0)
-        )
-        attn = _cached_attention(
-            q, k_cache_l, v_cache_l, start + lq,
-            None if window is None else jnp.where(
-                window, cfg.sliding_window_size, lmax + 1))
-        x_in, x = x, x + (attn.reshape(b, lq, -1) @ lp["wo"]).astype(x.dtype)
-        h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps)
-        if cfg.num_experts:
-            y, _ = moe_block(h.reshape(b * lq, -1), lp, cfg,
-                             router_input=router_input(x_in, cfg))
-            return x + y.reshape(b, lq, -1), (k_cache_l, v_cache_l)
-        return x + dense_mlp(h, lp, cfg), (k_cache_l, v_cache_l)
+
+        def attend(q, k, v):
+            kc = jax.lax.dynamic_update_slice(
+                k_cache_l, k.astype(k_cache_l.dtype), (0, start, 0, 0))
+            vc = jax.lax.dynamic_update_slice(
+                v_cache_l, v.astype(v_cache_l.dtype), (0, start, 0, 0))
+            return _cached_attention(
+                q, kc, vc, start + lq, cfg.attention_scale,
+                None if window is None else jnp.where(
+                    window, cfg.sliding_window_size, lmax + 1)), (kc, vc)
+
+        x, _, cache_l = attention_layer(x, lp, cfg, cos, sin, positions,
+                                        attend, rope=rope)
+        return x, cache_l
 
     x, (k_new, v_new) = jax.lax.scan(
         layer, x, (layers_inputs(params["layers"], cfg), cache["k"],

@@ -335,7 +335,8 @@ def _check_hybrid(cfg: TransformerConfig) -> None:
     a layer's experts or one chip's share of them (`experts_held`), with or
     without shared experts beside them, softmax or sigmoid scores, a choice
     bias. Refused by name: an unknown kind, recurrent layers of two kinds
-    in one model, latent attention beside recurrent layers."""
+    in one model, latent attention or a router on the layer's input beside
+    recurrent layers."""
     kinds = set(cfg.layer_pattern)
     if not kinds <= {*RECURRENT_KINDS, *ATTENTION_KINDS}:
         raise ValueError(f"unknown layer kinds {sorted(kinds)} in "
@@ -355,6 +356,9 @@ def _check_hybrid(cfg: TransformerConfig) -> None:
     if cfg.kv_lora_rank:
         raise ValueError("latent attention beside recurrent layers is not "
                          "written")
+    if cfg.router_reads != "mlp_input":
+        raise ValueError("a router that reads the layer's input beside "
+                         "recurrent layers is not written")
     if cfg.num_experts:
         if not 0 <= cfg.first_k_dense_replace < cfg.n_layers:
             raise ValueError("first_k_dense_replace must lie in [0, n_layers)")
@@ -882,6 +886,9 @@ def _attention(cfg: TransformerConfig, q, k, v, mesh, positions,
         return grouped_attention(
             q, k.astype(jnp.float32), v.astype(jnp.float32), seen,
             cfg.attention_scale)
+    if cfg.attention_scale != cfg.head_dim ** -0.5:
+        # The kernels scale scores by head_dim ** -0.5: q carries the rest.
+        q = q * jnp.asarray(cfg.attention_scale * cfg.head_dim ** 0.5, q.dtype)
     if cfg.attn_impl == "ring" and mesh is not None and mesh.shape.get("sp", 1) > 1:
         from ray_tpu.parallel.ring_attention import ring_attention
 
@@ -935,6 +942,68 @@ def residual(x, y, cfg: TransformerConfig):
     return x + cfg.residual_multiplier * y
 
 
+def attention_mixer(x, lp, cfg: TransformerConfig, cos, sin, positions,
+                    attend, mesh=None, spec=P(), rope=None):
+    """The attention half of a layer on `x [B, L, D]`, for the train step,
+    `generate` and the engine's walks alike: the norm, the heads
+    (`project_qkv`), the position embedding, `attend`, the output gate
+    (`gate_attention`) and the product with `wo`. What it returns is what
+    the mixer adds, BEFORE the residual (a hybrid's training walk takes it
+    under a `lax.cond` beside the recurrent mixer), and `attend`'s cache.
+
+    `attend(q, k, v) -> (attention [B, L, H, D], cache)` is how the queries
+    meet the keys, and nothing else says it: over the sequence itself
+    (`_attention`; no cache: None), through `generate`'s one-length cache,
+    through pages, a ring or a block (serve/paged_kv.py). The score scale
+    is `attend`'s too: every one honours `cfg.attention_scale`.
+
+    `cos` None: a model, or a layer, without a position embedding. `rope`:
+    a flag (traced, a layer's own) where the caller scans stacked layers of
+    a model with a per-layer list (`rotate`). `mesh`, `spec`: the norm
+    kernel's (`_ACT_SPEC` in training; the engine's activations are whole
+    on every device)."""
+    b, l, _ = x.shape
+    h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh, spec=spec)
+    q, k, v = project_qkv(h, lp, cfg)
+    if cos is not None:
+        q, k = rotate(q, k, cos, sin, positions, rope)
+    attn, cache = attend(q, k, v)
+    attn = gate_attention(attn.reshape(b, l, -1), h, lp)
+    return (attn @ lp["wo"]).astype(x.dtype), cache
+
+
+def mlp_half(x, lp, cfg: TransformerConfig, mesh=None, spec=P(), layer=None,
+             read_by_router=None):
+    """The MLP half of a layer of any kind on `x [B, L, D]`, residual
+    included: the norm, then `dense_mlp` where `lp` has no router and
+    `moe_block` where it has (`layer` is `moe_block`'s: the index at which
+    `lp`'s expert stacks, then the whole model's, are read in place;
+    `read_by_router`, the layer's `router_input`). Returns x and
+    `moe_block`'s statistics whole (None for a dense layer): a cached walk
+    takes `["counts"]`, the train step its auxiliary loss."""
+    b, l, d = x.shape
+    h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh, spec=spec)
+    if "router" not in lp:
+        return residual(x, dense_mlp(h, lp, cfg), cfg), None
+    y, routing = moe_block(h.reshape(b * l, d), lp, cfg, layer,
+                           read_by_router)
+    return residual(x, y.reshape(b, l, d), cfg), routing
+
+
+def attention_layer(x, lp, cfg: TransformerConfig, cos, sin, positions,
+                    attend, mesh=None, spec=P(), rope=None, layer=None):
+    """One attention layer on `x [B, L, D]`: `attention_mixer`, the
+    residual, `mlp_half`; their arguments. Returns the layer's output, its
+    routing statistics (None for a dense layer) and `attend`'s cache, as
+    `latent_layer` does."""
+    read_by_router = router_input(x, cfg)
+    y, cache = attention_mixer(x, lp, cfg, cos, sin, positions, attend, mesh,
+                               spec, rope)
+    x, routing = mlp_half(residual(x, y, cfg), lp, cfg, mesh, spec, layer,
+                          read_by_router)
+    return x, routing, cache
+
+
 def layer_kinds(cfg: TransformerConfig):
     """For every layer of a hybrid: whether it is a recurrent layer `[n]
     bool` and its index within its own kind's stack `[n] int32`."""
@@ -981,42 +1050,30 @@ def _hybrid_layers(params, x, cfg: TransformerConfig, mesh, positions):
     routing statistics (None without experts)."""
     _check_hybrid(cfg)
     layers = params["layers"]
-    b, l, d = x.shape
+    b, l, _ = x.shape
     stack_name, recurrent = RECURRENT_KINDS[cfg.recurrent_kind]
     fresh = at_layer(recurrent.init_state(cfg, 1, b), 0)
     every_row = jnp.full((b,), l, jnp.int32)
     cos, sin = rope_tables(cfg, cfg.max_seq)
 
-    def norm(x, w):
-        return rmsnorm(x, w, cfg.norm_eps, mesh=mesh, spec=_ACT_SPEC)
+    def attend(q, k, v):
+        return _attention(cfg, q, k, v, mesh, positions), None
 
     def recurrent_mixer(x, j):
         lp = at_layer(layers[stack_name], j)
-        return mix_recurrent(norm(x, lp["norm"]), lp, cfg, fresh,
-                             every_row)[0]
+        h = rmsnorm(x, lp["norm"], cfg.norm_eps, mesh=mesh, spec=_ACT_SPEC)
+        return mix_recurrent(h, lp, cfg, fresh, every_row)[0]
 
     def attn_mixer(x, j):
-        lp = at_layer(layers["attn"], j)
-        h = norm(x, lp["attn_norm"])
-        q, k, v = project_qkv(h, lp, cfg)
-        if cos is not None:
-            q = apply_rope(q, cos, sin, positions)
-            k = apply_rope(k, cos, sin, positions)
-        # The kernels scale scores by head_dim ** -0.5: q carries the rest.
-        q = q * jnp.asarray(cfg.attention_scale * cfg.head_dim ** 0.5, q.dtype)
-        attn = _attention(cfg, q, k, v, mesh, positions)
-        return gate_attention(attn.reshape(b, l, -1), h, lp) @ lp["wo"]
+        return attention_mixer(x, at_layer(layers["attn"], j), cfg, cos, sin,
+                               positions, attend, mesh, _ACT_SPEC)[0]
 
     def body(x, inputs):
         mlp, is_recurrent, j = inputs
         x = residual(x, jax.lax.cond(
             is_recurrent, recurrent_mixer, attn_mixer, x, j).astype(x.dtype),
             cfg)
-        h = norm(x, mlp["mlp_norm"])
-        if "router" not in mlp:
-            return residual(x, dense_mlp(h, mlp, cfg), cfg), None
-        y, routing = moe_block(h.reshape(b * l, d), mlp, cfg)
-        return residual(x, y.reshape(b, l, d), cfg), routing
+        return mlp_half(x, mlp, cfg, mesh, _ACT_SPEC)
 
     if cfg.remat:
         body = jax.checkpoint(body)
@@ -1109,15 +1166,11 @@ def latent_layer(x, lp, cfg: TransformerConfig, cos, sin, positions, attend,
     writes. The MLP is dense where `lp` has no router; `layer` is
     `moe_block`'s. Returns the layer's output, its routing statistics
     (None for a dense layer) and `attend`'s cache."""
-    b, l, d = x.shape
     h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh, spec=_ACT_SPEC)
     attn, cache = attend(*project_latent(h, lp, cfg, cos, sin, positions))
-    x = x + (attn @ lp["wo"]).astype(x.dtype)
-    h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh, spec=_ACT_SPEC)
-    if "router" not in lp:
-        return x + dense_mlp(h, lp, cfg), None, cache
-    y, routing = moe_block(h.reshape(b * l, d), lp, cfg, layer)
-    return x + y.reshape(b, l, d), routing, cache
+    x = residual(x, (attn @ lp["wo"]).astype(x.dtype), cfg)
+    x, routing = mlp_half(x, lp, cfg, mesh, _ACT_SPEC, layer)
+    return x, routing, cache
 
 
 def latent_stacks(params):
@@ -1160,28 +1213,12 @@ def _layer_fn(cfg: TransformerConfig, mesh, cos, sin, positions):
     def body(x, inputs):
         # x: [B, L, D]
         lp, rope, window = inputs if per_layer else (inputs, None, None)
-        x_in = x
-        h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh,
-                    spec=_ACT_SPEC)
-        b, l, d = h.shape
-        q, k, v = project_qkv(h, lp, cfg)
-        q, k = rotate(q, k, cos, sin, positions, rope)
-        attn = _attention(cfg, q, k, v, mesh, positions, window)
-        x = x + (gate_attention(attn.reshape(b, l, -1), h, lp)
-                 @ lp["wo"]).astype(x.dtype)
 
-        h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh,
-                    spec=_ACT_SPEC)
-        if cfg.num_experts == 0:
-            mlp_out = dense_mlp(h, lp, cfg)
-            routing = None
-        else:
-            mlp_flat, routing = moe_block(
-                h.reshape(b * l, d), lp, cfg,
-                router_input=router_input(x_in, cfg))
-            mlp_out = mlp_flat.reshape(b, l, d)
-        x = x + mlp_out
-        return x, routing
+        def attend(q, k, v):
+            return _attention(cfg, q, k, v, mesh, positions, window), None
+
+        return attention_layer(x, lp, cfg, cos, sin, positions, attend, mesh,
+                               _ACT_SPEC, rope)[:2]
 
     if cfg.remat:
         if cfg.remat_policy == "dots_nobatch":
@@ -1243,7 +1280,7 @@ def forward(
     elif cfg.kv_lora_rank:
         x, routing = _latent_layers(params, x, cfg, mesh, positions)
     else:
-        cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
+        cos, sin = rope_tables(cfg, cfg.max_seq)
         body = _layer_fn(cfg, mesh, cos, sin, positions)
         x, routing = jax.lax.scan(body, x,
                                   layers_inputs(params["layers"], cfg))
@@ -1311,7 +1348,7 @@ def forward_pipelined(
             "stages")
 
     x = _embed_tokens(params, tokens, cfg)
-    cos, sin = rope_frequencies(cfg.head_dim, cfg.max_seq, cfg.rope_theta)
+    cos, sin = rope_tables(cfg, cfg.max_seq)
     body = _layer_fn(cfg, mesh, cos, sin, None)
 
     def stage_fn(stage_layers, act):

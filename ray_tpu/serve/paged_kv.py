@@ -2,8 +2,12 @@
 
 The host half (`PagePool`, `PrefixCache`, the page hashes) decides which
 pages a request owns; the device half (`init_paged_cache`, `decode_paged`,
-`prefill_chunk_paged`, `cow_copy_page`, and the layer body, layer scan,
-sampler and routing counters they share) is everything the engine jits.
+`prefill_chunk_paged`, `cow_copy_page`, and the layer walks, sampler and
+routing counters they share) is everything the engine jits. What a layer
+computes is `models.transformer`'s (`attention_layer`, `mlp_half`,
+`latent_layer`), the train step's and `generate`'s too: a step program
+says how a layer's queries meet its cache, the `attend(q, k, v)` it hands
+the layer, and a walk how the layers are iterated and what is carried.
 `ray_tpu.serve.llm`, the host scheduler, imports this module; nothing
 here imports the scheduler.
 
@@ -94,21 +98,19 @@ from ray_tpu.models.transformer import (
     TransformerConfig,
     _embed_tokens,
     at_layer,
+    attention_layer,
     block_causal,
-    dense_mlp,
     expand_latent,
-    gate_attention,
     latent_layer,
     latent_stacks,
     layer_kinds,
     mix_recurrent,
+    mlp_half,
     project_logits,
-    project_qkv,
     residual,
     rope_tables,
-    router_input,
 )
-from ray_tpu.ops import apply_rope, rmsnorm
+from ray_tpu.ops import rmsnorm
 from ray_tpu.ops.paged_attention import (
     latent_decode_attention,
     latent_kernel_takes,
@@ -116,7 +118,7 @@ from ray_tpu.ops.paged_attention import (
     ring_tables,
     window_decode_attention,
 )
-from ray_tpu.parallel.moe import EXPERT_LEAVES, moe_block
+from ray_tpu.parallel.moe import EXPERT_LEAVES
 
 # The reserved NULL/scratch page (see module docstring).
 NULL_PAGE = 0
@@ -424,39 +426,6 @@ def init_recurrent_pool(cfg: TransformerConfig, slots: int) -> Dict:
         cfg, cfg.recurrent_layers, slots)
 
 
-def _layer_body(x, lp, k_cache_l, v_cache_l, cfg, cos, sin, positions,
-                attend, mesh=None, layer=None):
-    """One transformer layer, shared by the decode and prefill programs.
-
-    The two callers differ only in how K/V land in the cache and how the
-    queries meet it: `attend(kc, vc, q, k, v) -> (kc, vc, attention
-    [B, L, H, D])` encapsulates that. `mesh` is the engine's: activations
-    are replicated over it, so the norm kernel runs whole on every
-    device, and KV heads lie over its "tp". Returns the
-    layer's output, its caches and, for a layer with a router among its
-    leaves, the assignments each expert received `[E]` (else None); `layer` is
-    `moe_block`'s: the index at which `lp`'s expert stacks, then the
-    whole model's, are read in place. `cos` is None for a model, or a
-    layer, without a position embedding."""
-    b, l = x.shape[:2]
-    read_by_router = router_input(x, cfg)
-    h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps, mesh=mesh)
-    q, k, v = project_qkv(h, lp, cfg)
-    if cos is not None:
-        q = apply_rope(q, cos, sin, positions)
-        k = apply_rope(k, cos, sin, positions)
-    k_cache_l, v_cache_l, attn = attend(k_cache_l, v_cache_l, q, k, v)
-    attn = gate_attention(attn.reshape(b, l, -1), h, lp)
-    x = residual(x, (attn @ lp["wo"]).astype(x.dtype), cfg)
-    h = rmsnorm(x, lp["mlp_norm"], cfg.norm_eps, mesh=mesh)
-    if "router" in lp:
-        y, routing = moe_block(h.reshape(b * l, -1), lp, cfg, layer,
-                               read_by_router)
-        return (x + y.reshape(b, l, -1), k_cache_l, v_cache_l,
-                routing["counts"])
-    return residual(x, dense_mlp(h, lp, cfg), cfg), k_cache_l, v_cache_l, None
-
-
 def _rows(new, pool):
     """Rows `new [R, kv_heads, head_dim]` as `pool` holds a row."""
     return new.astype(pool.dtype).reshape(new.shape[:1] + pool.shape[3:])
@@ -724,9 +693,10 @@ def _walk_latent(params, x, pool, attend, cfg, cos, sin, positions,
     """A latent-attention decoder's layers in turn, `_scan_layers` for a
     stack a kind: the dense layers' scan, then the expert layers', the one
     pool `[layers, ...]` riding in both carries. `attend(i, pool, lp, q_n,
-    q_r, c, k_r) -> (attention [B, L, H * dv], pool)` writes and reads the
-    pool at layer `i`. The expert stacks stay out of the scan and are read
-    whole at the layer's index within its kind (`moe_block`). Returns x,
+    q_r, c, k_r) -> (attention [B, L, H * dv], pool)`, `latent_layer`'s
+    `attend(q_n, q_r, c, k_r)` with the layer's index, the pool and the
+    layer's leaves in front, writes and reads the pool at layer `i`. The
+    expert stacks stay out of the scan and are read whole at the layer's index within its kind (`moe_block`). Returns x,
     the pool and the assignments each expert layer's experts received
     `[expert layers, E]` (None without experts)."""
     counts = None
@@ -755,11 +725,12 @@ def _walk_latent(params, x, pool, attend, cfg, cos, sin, positions,
 
 def _walk_hybrid(params, x, k_cache, v_cache, rec, attend, rec_io,
                  n_valid, cfg, cos, sin, positions, mesh=None):
-    """A hybrid's layers in turn, `_scan_layers` for layers of two kinds:
-    the KV pool (attention layers only, indexed by their own count) and
-    the recurrent pool `rec` both ride in the carry and are updated in
-    place. ONE scan over the attention layers; each of its steps first
-    walks the run of recurrent layers that stands before its attention
+    """A hybrid's layers in turn, `_scan_layers` for layers of two kinds
+    (`attend` is that walk's, an attention layer `attention_layer`, a
+    recurrent layer its mixer and `mlp_half`): the KV pool (attention layers
+    only, indexed by their own count) and the recurrent pool `rec` both ride
+    in the carry and are updated in place. ONE scan over the attention
+    layers; each of its steps first walks the run of recurrent layers that stands before its attention
     layer (a `fori_loop` whose bounds are the scan's inputs: 5, 9, 9, 9 for
     granite-4.0-h-micro), and the recurrent layers after the last
     attention layer follow in a loop of their own. So a recurrent layer is
@@ -776,7 +747,7 @@ def _walk_hybrid(params, x, k_cache, v_cache, rec, attend, rec_io,
     dense (`layers["mlp"]`; a model may have none), then the expert layers,
     each reading its router, its choice bias and its shared expert from
     `layers["moe"]` and the expert stacks whole at its own index among them
-    (`moe_block(..., layer=)`, as `_scan_layers` does): all of a layer's
+    (`moe_block`'s `layer`, as `_scan_layers` does): all of a layer's
     experts or the held share (`cfg.experts_held`). The assignments each
     expert layer's experts received, held here or not, ride in the carry
     too, `[expert layers, E]` (`_count_routing` counts the held ones' hits,
@@ -832,13 +803,10 @@ def _walk_hybrid(params, x, k_cache, v_cache, rec, attend, rec_io,
             out, rec = mix(h, lp, rec, rec_first + t)
             x = residual(x, out, cfg)
             mlp, j = mlp_at(first + t, dense)
-            h = rmsnorm(x, mlp["mlp_norm"], cfg.norm_eps, mesh=mesh)
-            if dense:
-                return residual(x, dense_mlp(h, mlp, cfg), cfg), rec, counts
-            b, l, d = x.shape
-            y, routing = moe_block(h.reshape(b * l, d), mlp, cfg, j)
-            return (x + y.reshape(b, l, d), rec,
-                    counts.at[j].set(routing["counts"]))
+            x, routing = mlp_half(x, mlp, cfg, mesh, layer=j)
+            if routing is not None:
+                counts = counts.at[j].set(routing["counts"])
+            return x, rec, counts
 
         return jax.lax.fori_loop(0, count, one, (x, rec, counts))
 
@@ -859,11 +827,11 @@ def _walk_hybrid(params, x, k_cache, v_cache, rec, attend, rec_io,
                 x, rec, counts = recurrent_run(
                     x, rec, counts, first, first - a, at - first, dense)
                 mlp, j = mlp_at(at, dense)
-                x, kc, vc, got = _layer_body(
-                    x, {**attn, **mlp}, kc, vc, cfg, cos, sin, positions,
-                    functools.partial(attend, a), mesh, j)
-                if got is not None:
-                    counts = counts.at[j].set(got)
+                x, routing, (kc, vc) = attention_layer(
+                    x, {**attn, **mlp}, cfg, cos, sin, positions,
+                    functools.partial(attend, a, kc, vc), mesh, layer=j)
+                if routing is not None:
+                    counts = counts.at[j].set(routing["counts"])
                 return (x, kc, vc, rec, counts), None
 
             carry, _ = jax.lax.scan(
@@ -917,9 +885,10 @@ def _scan_layers(params, x, k_cache, v_cache, attend, cfg, cos, sin,
                  positions, mesh=None, ring=None, attend_ring=None):
     """Every layer in turn, the whole KV cache `[layers, ...]` riding in
     the scan's carry: the one way a step threads its cache through the
-    layers. `attend(i, kc, vc, q, k, v)` is `_layer_body`'s with the
-    layer index in front; it writes and reads the whole cache at
-    `[i, ...]`.
+    layers. `attend(i, kc, vc, q, k, v) -> (attention [B, L, H, D], (kc,
+    vc))` is `attention_layer`'s `attend(q, k, v)` with the layer's index
+    and the caches in front; it writes and reads the whole cache at `[i,
+    ...]`.
 
     A carry is one buffer from the first layer to the last, so with the
     caches donated each layer's rows are scattered into the caller's own
@@ -965,19 +934,17 @@ def _scan_layers(params, x, k_cache, v_cache, attend, cfg, cos, sin,
         for t in range(period):
             at = j * of_kind[window[t]] + before[t]
             i = j * period + t
-            args = (cfg, cos if rotates[t] else None, sin, positions)
-            lp = {**at_layer(layers, i), **experts}
+            through = (functools.partial(attend_ring, at, ring["k"], ring["v"])
+                       if window[t] else functools.partial(attend, at, kc, vc))
+            x, routing, pools = attention_layer(
+                x, {**at_layer(layers, i), **experts}, cfg,
+                cos if rotates[t] else None, sin, positions, through, mesh,
+                layer=i if experts else None)
             if window[t]:
-                x, *pools, got = _layer_body(
-                    x, lp, ring["k"], ring["v"], *args,
-                    functools.partial(attend_ring, at), mesh,
-                    i if experts else None)
                 ring = dict(zip(("k", "v"), pools))
             else:
-                x, kc, vc, got = _layer_body(
-                    x, lp, kc, vc, *args, functools.partial(attend, at),
-                    mesh, i if experts else None)
-            counts.append(got)
+                kc, vc = pools
+            counts.append(None if routing is None else routing["counts"])
         return (x, kc, vc, ring), (jnp.stack(counts) if experts else None)
 
     runs = cfg.n_layers // period
@@ -1237,7 +1204,7 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
         attn = paged_decode_attention(
             q[:, 0], kc, vc, i, block_tables, rows_att, cfg.attention_scale,
             mesh=mesh)
-        return kc, vc, attn[:, None]
+        return attn[:, None], (kc, vc)
 
     attend_ring = None
     if ring is not None:
@@ -1253,7 +1220,7 @@ def decode_paged(params, tokens, k_pages, v_pages, lengths, active,
             attn = window_decode_attention(
                 q[:, 0], kc, vc, i, newest, cfg.sliding_window_size,
                 cfg.attention_scale)
-            return kc, vc, attn[:, None]
+            return attn[:, None], (kc, vc)
 
     if cfg.kv_lora_rank:
         # One row a slot into the one pool, then the absorbed form against
@@ -1377,8 +1344,8 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
     def attend(i, kc, vc, q, k, v):
         kc = kc.at[i, pages_w, rows_w].set(_rows(k.reshape(p_ * c, kvh, hd), kc))
         vc = vc.at[i, pages_w, rows_w].set(_rows(v.reshape(p_ * c, kvh, hd), vc))
-        return kc, vc, _paged_attention(q, kc, vc, i, bt_rows, live, seen,
-                                        cfg.attention_scale)
+        return _paged_attention(q, kc, vc, i, bt_rows, live, seen,
+                                cfg.attention_scale), (kc, vc)
 
     attend_ring = None
     if ring is not None:
@@ -1405,8 +1372,8 @@ def prefill_chunk_paged(params, tokens, n_valid, slot, offset, k_pages,
                 _rows(k.reshape(p_ * c, kvh, hd), kc))
             vc = vc.at[i, ring_pages_w, rows_w].set(
                 _rows(v.reshape(p_ * c, kvh, hd), vc))
-            return kc, vc, _paged_attention(q, kc, vc, i, ring_bt, live,
-                                            ring_seen, cfg.attention_scale)
+            return _paged_attention(q, kc, vc, i, ring_bt, live, ring_seen,
+                                    cfg.attention_scale), (kc, vc)
 
     if cfg.kv_lora_rank:
         # The expanded form: a chunk's scores and weighted sum are as wide
@@ -1541,7 +1508,7 @@ def _block_hidden(params, state, k_pages, v_pages, lengths, active,
             attn = paged_decode_attention(
                 _fold_block(q, kvh), kc, vc, i, block_tables, rows_att,
                 cfg.attention_scale, mesh=mesh)
-            return kc, vc, _unfold_block(attn, b, kvh)
+            return _unfold_block(attn, b, kvh), (kc, vc)
 
     x, k_new, v_new, _, counts = _scan_layers(
         params, x, k_pages, v_pages, attend, cfg, cos, sin, positions, mesh)

@@ -129,6 +129,54 @@ def test_paged_engine_matches_generate_on_mixed_lengths():
     assert outs == refs
 
 
+# -- one layer: trained, generated and served by one definition -----------
+@pytest.mark.parametrize("field, value", [
+    ("residual_multiplier", 0.5), ("attention_multiplier", 0.5),
+    ("attn_output_gate", True)])
+def test_a_stack_of_like_layers_trains_and_generates_as_it_is_served(
+        field, value):
+    """A dense stack of like attention layers (no `layer_pattern`) with one
+    of the fields a hybrid's attention layers may carry: `forward`'s logits
+    at the prompt's last position, `generate`'s prefill logits and the
+    engine's `prefill_logits` are one layer's arithmetic
+    (`transformer.attention_layer`), so they agree, and the field is in all
+    three (the logits are not those of the stack without it)."""
+    import jax
+    import jax.numpy as jnp
+
+    from ray_tpu.models import configs, forward, init_params
+    from ray_tpu.models.generate import init_kv_cache, prefill
+    from ray_tpu.serve.llm import ContinuousBatchingEngine
+
+    plain = replace(configs.tiny_gqa, dtype=jnp.float32)
+    cfg = replace(plain, **{field: value})
+    params = init_params(jax.random.PRNGKey(0), cfg)
+    if cfg.attn_output_gate:  # a hybrid's leaf, drawn here for a plain stack
+        params["layers"]["wg"] = 0.3 * jax.random.normal(
+            jax.random.PRNGKey(1),
+            (cfg.n_layers, cfg.d_model, cfg.n_heads * cfg.head_dim))
+    prompt = jnp.asarray(_prompt(21, seed=5))[None]
+
+    def last_logits(params, cfg):
+        return np.asarray(forward(params, prompt, cfg)[0][0, -1])
+
+    trained = last_logits(params, cfg)
+    generated = np.asarray(prefill(
+        params, prompt, init_kv_cache(cfg, 1, 32), cfg)[0][0])
+    eng = ContinuousBatchingEngine(params, cfg, num_slots=2, max_len=64,
+                                   page_size=16, prefill_chunk=8)
+    try:
+        served = eng.prefill_logits(np.asarray(prompt[0]))
+    finally:
+        eng.shutdown()
+    scale = np.abs(trained).max()
+    assert np.abs(generated - trained).max() < 1e-4 * scale
+    assert np.abs(served - trained).max() < 1e-4 * scale
+    without = last_logits({**params, "layers": {
+        n: w for n, w in params["layers"].items() if n != "wg"}}, plain)
+    assert np.abs(without - trained).max() > 1e-2 * scale
+
+
 # -- one arrow: the scheduler imports the programs, never the reverse -----
 _TRACE_WITHOUT_THE_SCHEDULER = """
 import sys
@@ -207,7 +255,7 @@ def test_the_deleted_kv_options_are_gone():
 def _layerwise(params, cfg, x, k_pool, v_pool, write_kv, positions, valid,
                max_len):
     """The plain reference for a step's layers: a loop that hands
-    `_layer_body` one layer's pages `pool[i]` at a time and restacks the
+    `attention_layer` one layer's pages `pool[i]` at a time and restacks the
     pool; `write_kv` scatters into and gathers from that layer, with no
     layer index anywhere. The loop is a `lax.scan` over the pools: the
     same loop unrolled in Python compiles to other fusions, whose float32
@@ -216,23 +264,23 @@ def _layerwise(params, cfg, x, k_pool, v_pool, write_kv, positions, valid,
 
     import jax.numpy as jnp
 
+    from ray_tpu.models.transformer import attention_layer
     from ray_tpu.ops import rmsnorm, rope_frequencies
     from ray_tpu.ops.paged_attention import grouped_attention
-    from ray_tpu.serve.paged_kv import _layer_body
 
     cos, sin = rope_frequencies(cfg.head_dim, max_len, cfg.rope_theta)
 
-    def attend(kc, vc, q, k, v):
-        kc, vc, k_att, v_att = write_kv(kc, vc, k, v)
-        return kc, vc, grouped_attention(
-            q, k_att.astype(jnp.float32), v_att.astype(jnp.float32), valid,
-            cfg.attention_scale)
-
     def layer(x, inputs):
         lp, k_layer, v_layer = inputs
-        x, k_layer, v_layer, _ = _layer_body(
-            x, lp, k_layer, v_layer, cfg, cos, sin, positions, attend)
-        return x, (k_layer, v_layer)
+
+        def attend(q, k, v):
+            kc, vc, k_att, v_att = write_kv(k_layer, v_layer, k, v)
+            return grouped_attention(
+                q, k_att.astype(jnp.float32), v_att.astype(jnp.float32),
+                valid, cfg.attention_scale), (kc, vc)
+
+        x, _, pools = attention_layer(x, lp, cfg, cos, sin, positions, attend)
+        return x, pools
 
     x, (k_pool, v_pool) = jax.lax.scan(
         layer, x, (params["layers"], k_pool, v_pool))
@@ -244,8 +292,8 @@ def test_held_back_products_change_no_head_and_no_gradient(extent):
     """`project_qkv` holds its three products back from the QK-norm
     (`optimization_barrier`: fused into a product, a per-head sum of
     squares has the chip's compiler copy `wq` and `wk` out of the stack
-    every layer). The hold is no arithmetic: the heads `_layer_body` hands
-    to `attend`, and the gradients a train step takes through
+    every layer). The hold is no arithmetic: the heads `attention_layer`
+    hands to `attend`, and the gradients a train step takes through
     `project_qkv`, equal the same lines written without it bit for bit,
     at every extent of the norm, in bfloat16 as both run. That is the
     CPU's word: on the chip the norm now reads the product as rounded to
@@ -254,9 +302,12 @@ def test_held_back_products_change_no_head_and_no_gradient(extent):
     import jax.numpy as jnp
 
     from ray_tpu.models import configs, init_params
-    from ray_tpu.models.transformer import at_layer, project_qkv
+    from ray_tpu.models.transformer import (
+        at_layer,
+        attention_layer,
+        project_qkv,
+    )
     from ray_tpu.ops import rmsnorm
-    from ray_tpu.serve.paged_kv import _layer_body
 
     cfg = replace(configs.tiny_qwen, dtype=jnp.bfloat16,
                   qk_norm=extent is not None, qk_norm_extent=extent or "head")
@@ -268,14 +319,12 @@ def test_held_back_products_change_no_head_and_no_gradient(extent):
     x = jax.random.normal(jax.random.PRNGKey(2), (3, 5, cfg.d_model),
                           cfg.dtype)
 
-    def through_the_layer_body(x, lp):
-        def attend(kc, vc, q, k, v):
-            return (q, k), v, jnp.zeros_like(q)
+    def through_the_layer(x, lp):
+        def attend(q, k, v):
+            return jnp.zeros_like(q), (q, k, v)
 
         # No position embedding: `attend` is handed the heads as projected.
-        _, (q, k), v, _ = _layer_body(x, lp, None, None, cfg, None, None,
-                                      None, attend)
-        return q, k, v
+        return attention_layer(x, lp, cfg, None, None, None, attend)[2]
 
     def plain(x, lp):
         h = rmsnorm(x, lp["attn_norm"], cfg.norm_eps)
@@ -302,7 +351,7 @@ def test_held_back_products_change_no_head_and_no_gradient(extent):
             np.testing.assert_array_equal(np.asarray(a, np.float32),
                                           np.asarray(b, np.float32))
 
-    same(jax.jit(through_the_layer_body)(x, lp), jax.jit(plain)(x, lp))
+    same(jax.jit(through_the_layer)(x, lp), jax.jit(plain)(x, lp))
 
     def grads(project):
         def loss(x, lp):

@@ -1,8 +1,8 @@
 """Fingerprints of the engine's step programs and the train loss, for every
 model the benchmark runs, from whatever tree is given: what a PR that
-edits shared code (`paged_kv._layer_body`, `_scan_layers`, `_walk_hybrid`,
-`moe_block`, `decode_paged`) compares between its parent and itself, before
-any chip time is spent.
+edits shared code (`transformer.attention_layer`, `mlp_half`, `latent_layer`,
+`paged_kv._scan_layers`, `_walk_hybrid`, `moe_block`, `decode_paged`)
+compares between its parent and itself, before any chip time is spent.
 
     python tools/step_programs.py [--tree DIR] [--compiled] [--models a b ..]
 
@@ -20,6 +20,13 @@ the tuples, their elements and the parameters, so that two programs that
 differ in the order of a loop's state alone hash alike. Equal jaxprs are
 the same program; equal `ops` and temporaries are the same work laid out
 the same way.
+
+The train loss's gradient is traced twice for every model: on one device
+(`loss_grad`) and under the four-chip cell's layout, a 2 x 2 "fsdp" x "tp"
+mesh of four host devices (`loss_grad.fsdp2tp2`: the norm's `spec` and the
+kernels' per-device regions are in the program only there; with
+`--compiled` the mesh is of the described chips). A block-diffusion model
+has no loss here: the gradient of its forward's hidden states stands in.
 
 Shapes are small (8 slots x 512, chunks of 64) and the depth is cut to a few
 periods of layers: structure, not size, is what is compared. `--tree` runs
@@ -40,7 +47,7 @@ import sys
 SERVED = ("qwen3-4b", "olmoe-1b-7b", "granite-4.0-h-micro", "dots-vlm1-ep16",
           "lfm2-24b-a2b-l10", "solar-open2-250b-ep8-l4", "sdar-30b-a3b-l6",
           "smallthinker-21b-a3b-l8")
-TRAINED = ("qwen3-4b", "olmoe-1b-7b", "granite-4.0-h-micro")
+TRAINED = SERVED
 SLOTS, MAX_LEN, PAGE, CHUNK = 8, 512, 16, 64
 
 
@@ -49,7 +56,12 @@ def _sha(text: str) -> str:
 
 
 def _text(jaxpr) -> str:
-    return re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr))
+    """A jaxpr's text with what differs between two runs of one program
+    taken out: addresses, and the order a set of axis names prints in."""
+    return re.sub(
+        r"frozenset\(\{([^}]*)\}\)",
+        lambda m: "frozenset({%s})" % ", ".join(sorted(m[1].split(", "))),
+        re.sub(r"0x[0-9a-f]+", "0x", str(jaxpr)))
 
 
 def ops_hash(hlo: str) -> str:
@@ -65,6 +77,20 @@ def ops_hash(hlo: str) -> str:
     return _sha(repr(sorted(seen.items())))
 
 
+def _shallow(cfg, periods: int):
+    """`cfg` at `periods` periods of its per-layer lists (1 layer a period
+    where the layers are all alike), which tell what the whole depth does;
+    a hybrid, whose pattern is its depth, as it is."""
+    if cfg.layer_pattern:
+        return cfg
+    depth = periods * getattr(cfg, "layer_period", 1)
+    lists = {field: getattr(cfg, field)[:depth]
+             for field in ("sliding_window_layout", "rope_layout")
+             if getattr(cfg, field, ())}
+    return dataclasses.replace(cfg, n_layers=min(cfg.n_layers, depth),
+                               **lists)
+
+
 def programs(name: str):
     """`(tag, function, donated, argument shapes)` for each step program of
     the named model, as the engine calls them."""
@@ -75,15 +101,8 @@ def programs(name: str):
     from ray_tpu.models.transformer import init_params
     from ray_tpu.serve import paged_kv
 
-    cfg = configs.get_config(name)
-    if not cfg.layer_pattern:  # two periods of layers tell what 36 do
-        period = getattr(cfg, "layer_period", 1)
-        depth = {field: getattr(cfg, field)[:2 * period]
-                 for field in ("sliding_window_layout", "rope_layout")
-                 if getattr(cfg, field, ())}
-        cfg = dataclasses.replace(cfg, n_layers=min(
-            cfg.n_layers, max(2 * period, 2)), **depth)
-    cfg = dataclasses.replace(cfg, remat=False)
+    cfg = dataclasses.replace(_shallow(configs.get_config(name), 2),
+                              remat=False)
     shape = jax.ShapeDtypeStruct
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
     per_slot = MAX_LEN // PAGE
@@ -126,38 +145,57 @@ def programs(name: str):
                         row, *pools, cache["block_tables"], tail))
 
 
-def train_loss(name: str):
+def train_loss(name: str, mesh=None):
+    """The gradient of the named model's train loss, on one device or
+    under `mesh`: parameters and tokens lie as the train step places them."""
     import jax
     import jax.numpy as jnp
 
-    from ray_tpu.models import configs
-    from ray_tpu.models.transformer import init_params, loss_fn
+    from ray_tpu.models import configs, param_logical_axes
+    from ray_tpu.models.transformer import forward, init_params, loss_fn
+    from ray_tpu.parallel import logical_shardings
 
-    cfg = configs.get_config(name)
-    if not cfg.layer_pattern:
-        cfg = dataclasses.replace(cfg, n_layers=4)
-    cfg = dataclasses.replace(cfg, ce_chunk=256)
+    cfg = dataclasses.replace(_shallow(configs.get_config(name), 4),
+                              ce_chunk=256)
     params = jax.eval_shape(lambda: init_params(jax.random.PRNGKey(0), cfg))
-    return ("loss_grad", jax.grad(lambda p, t: loss_fn(p, t, cfg)), (),
-            (params, jax.ShapeDtypeStruct((2, 257), jnp.int32)))
+    tokens = jax.ShapeDtypeStruct((2, 257), jnp.int32)
+    if getattr(cfg, "block_length", 0):
+        def loss(p, t):
+            hidden, aux = forward(p, t[:, :-1], cfg, mesh, return_hidden=True)
+            return hidden.astype(jnp.float32).sum() + aux
+    else:
+        def loss(p, t):
+            return loss_fn(p, t, cfg, mesh)
+    if mesh is None:
+        return "loss_grad", jax.grad(loss), (), (params, tokens)
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    placed = (logical_shardings(param_logical_axes(cfg), mesh),
+              NamedSharding(mesh, P(("dp", "fsdp"), None)))
+    return "loss_grad.fsdp2tp2", jax.grad(loss), (), (params, tokens), placed
 
 
-def _compiled(fn, donated, args, one) -> dict:
-    """`fn` compiled for the chip `one` describes: its temporaries' bytes
-    and the hash of its operations."""
+def _compiled(fn, donated, args, placed) -> dict:
+    """`fn` compiled for the described chips: its temporaries' bytes and
+    the hash of its operations. `placed`: one sharding for every argument,
+    or a tree of them as `args`'s."""
     import jax
 
-    placed = jax.tree.map(lambda a: jax.ShapeDtypeStruct(
-        a.shape, a.dtype, sharding=one), args)
-    exe = jax.jit(fn, donate_argnums=donated).lower(*placed).compile()
+    if isinstance(placed, jax.sharding.Sharding):
+        placed = jax.tree.map(lambda _: placed, args)
+    args = jax.tree.map(lambda a, sharding: jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=sharding), args, placed)
+    exe = jax.jit(fn, donate_argnums=donated).lower(*args).compile()
     return {"temporaries": exe.memory_analysis().temp_size_in_bytes,
             "ops": ops_hash(exe.as_text())}
 
 
-def fingerprints(models, compiled: bool = False, one=None) -> dict:
+def fingerprints(models, compiled: bool = False, one=None,
+                 mesh=None) -> dict:
     """`{"<model>:<program>": {"jaxpr": .., "live": .., ["temporaries": ..,
-    "ops": ..]}}`;
-    `one` is the described chip's sharding for `compiled`."""
+    "ops": ..]}}`; `one` is the described chip's sharding for `compiled`,
+    `mesh` the 2 x 2 mesh the train loss is also traced under (None: on one
+    device alone)."""
     import jax
     from jax.interpreters import partial_eval as pe
 
@@ -170,13 +208,18 @@ def fingerprints(models, compiled: bool = False, one=None) -> dict:
         todo = list(programs(name))
         if name in TRAINED:
             todo.append(train_loss(name))
-        for tag, fn, donated, args in todo:
+            if mesh is not None:
+                todo.append(train_loss(name, mesh))
+        for tag, fn, donated, args, *placed in todo:
             traced = jax.make_jaxpr(fn)(*args)
             live, _ = pe.dce_jaxpr(traced.jaxpr,
                                    [True] * len(traced.out_avals))
             got = {"jaxpr": _sha(_text(traced)), "live": _sha(_text(live))}
-            if compiled:
-                got.update(_compiled(fn, donated, args, one))
+            # The grouped matmul of a model with experts is a kernel that
+            # no mesh partitions: traced under the mesh, compiled on one chip.
+            if compiled and not (placed and configs.get_config(
+                    name).num_experts):
+                got.update(_compiled(fn, donated, args, *placed or [one]))
             out[f"{name}:{tag}"] = got
     return out
 
@@ -189,6 +232,8 @@ def main() -> None:
     parser.add_argument("--models", nargs="+", default=SERVED)
     args = parser.parse_args()
     os.environ["JAX_PLATFORMS"] = "cpu"
+    os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "")
+                               + " --xla_force_host_platform_device_count=4")
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     tree = os.path.abspath(args.tree)
     sys.path.insert(0, tree)
@@ -196,16 +241,20 @@ def main() -> None:
 
     import ray_tpu
     assert os.path.abspath(ray_tpu.__file__).startswith(tree), ray_tpu.__file__
-    one = None
+    from ray_tpu.parallel import MeshConfig, build_mesh
+
+    one, devices = None, jax.devices()[:4]
     if args.compiled:
         from jax.experimental import topologies
         from jax.sharding import SingleDeviceSharding
 
         jax.config.update("jax_enable_compilation_cache", False)
-        one = SingleDeviceSharding(topologies.get_topology_desc(
-            platform="tpu", topology_name="v5e:2x2").devices[0])
+        devices = topologies.get_topology_desc(
+            platform="tpu", topology_name="v5e:2x2").devices
+        one = SingleDeviceSharding(devices[0])
+    mesh = build_mesh(MeshConfig(fsdp=2, tp=2), devices)
     jax.default_backend = lambda: "tpu"  # the kernels' branch, as on the chip
-    print(json.dumps(fingerprints(args.models, args.compiled, one)))
+    print(json.dumps(fingerprints(args.models, args.compiled, one, mesh)))
 
 
 if __name__ == "__main__":
